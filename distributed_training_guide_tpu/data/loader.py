@@ -124,17 +124,12 @@ class ShardedBatchLoader:
         """Back batch assembly with the C++ loader (csrc/token_loader.cpp):
         mmap + worker threads + bounded prefetch, no GIL. A memmap dataset in
         the raw token-file layout (``--mmap-data``) is mmap'd IN PLACE — no
-        second on-disk copy of the corpus (reference C26)."""
+        second on-disk copy of the corpus (reference C26). Raises when the
+        library cannot be built: ``--native-loader`` was asked for."""
         import tempfile
 
-        from .native_loader import NativeTokenLoader, native_available, write_token_file
+        from .native_loader import NativeTokenLoader, write_token_file
 
-        if not native_available():
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "native loader unavailable (no g++); using python assembly")
-            return None
         path = self._native_compatible_backing()
         if path is None:
             tmp = tempfile.NamedTemporaryFile(suffix=".tokens.bin", delete=False)

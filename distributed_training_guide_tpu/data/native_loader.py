@@ -5,64 +5,58 @@ workers; this is our equivalent: ``csrc/token_loader.cpp`` mmaps a flat int32
 token file and assembles shuffled batches on C++ threads (no GIL), with a
 bounded prefetch queue. The Python side stays a thin iterator.
 
-The shared library is compiled once with g++ on first use and cached next to
-the source. Anything without a toolchain falls back to the pure-Python loader
-(``data/loader.py``) — same semantics, different shuffle order.
+The shared library is compiled with g++ on first use and cached next to
+the source (git ignores it; no commit carries a binary). Asking for the
+native loader where it cannot be built is an error, not a quiet switch to
+the pure-Python loader (``data/loader.py``), whose shuffle order differs.
 """
 from __future__ import annotations
 
 import ctypes
-import logging
+import os
 import subprocess
-import tempfile
 from pathlib import Path
 from typing import Iterator, Optional
 
 import numpy as np
 
-LOGGER = logging.getLogger(__name__)
-
 _CSRC = Path(__file__).parent.parent / "csrc"
 _LIB: Optional[ctypes.CDLL] = None
-_BUILD_FAILED = False
 
 
-def _build_library(force: bool = False) -> Optional[Path]:
+def _build_library() -> Path:
     src = _CSRC / "token_loader.cpp"
     out = _CSRC / "libtokenloader.so"
-    if not force and out.exists() and out.stat().st_mtime > src.stat().st_mtime:
+    if out.exists() and out.stat().st_mtime > src.stat().st_mtime:
         return out
+    # build beside the target and rename: several processes (test workers)
+    # may build at once, and none may load a half-written library
+    tmp = out.with_name(f"{out.name}.{os.getpid()}.tmp")
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", str(out), str(src), "-lpthread"]
+           "-o", str(tmp), str(src), "-lpthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return out
+        os.replace(tmp, out)
     except (OSError, subprocess.SubprocessError) as e:
-        LOGGER.warning(f"native loader build failed ({e}); using python loader")
-        return None
+        tmp.unlink(missing_ok=True)
+        detail = getattr(e, "stderr", b"") or b""
+        raise RuntimeError(
+            f"native loader build failed ({e}): "
+            f"{detail.decode(errors='replace')[-2000:]}") from e
+    return out
 
 
-def get_library() -> Optional[ctypes.CDLL]:
-    global _LIB, _BUILD_FAILED
-    if _LIB is not None or _BUILD_FAILED:
+def get_library() -> ctypes.CDLL:
+    """The loaded library, built on first use; raises ``RuntimeError`` when
+    it cannot be built or loaded."""
+    global _LIB
+    if _LIB is not None:
         return _LIB
     path = _build_library()
-    if path is None:
-        _BUILD_FAILED = True
-        return None
     try:
         lib = ctypes.CDLL(str(path))
-    except OSError:
-        # stale/foreign binary (e.g. different arch) — rebuild once, then
-        # fall back to the python loader
-        path = _build_library(force=True)
-        try:
-            lib = ctypes.CDLL(str(path)) if path else None
-        except OSError:
-            lib = None
-        if lib is None:
-            _BUILD_FAILED = True
-            return None
+    except OSError as e:
+        raise RuntimeError(f"native loader {path} does not load: {e}") from e
     lib.tl_open.restype = ctypes.c_void_p
     lib.tl_open.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
                             ctypes.c_uint64, ctypes.c_int, ctypes.c_int]
@@ -80,7 +74,12 @@ def get_library() -> Optional[ctypes.CDLL]:
 
 
 def native_available() -> bool:
-    return get_library() is not None
+    """For the tests' skip mark: whether the library builds and loads here."""
+    try:
+        get_library()
+    except RuntimeError:
+        return False
+    return True
 
 
 def write_token_file(dataset: np.ndarray, path: str | Path) -> Path:
@@ -100,10 +99,7 @@ class NativeTokenLoader:
 
     def __init__(self, token_file: str | Path, seq_len: int, batch: int,
                  seed: int = 0, threads: int = 2, prefetch: int = 4):
-        lib = get_library()
-        if lib is None:
-            raise RuntimeError("native loader unavailable (no g++?)")
-        self._lib = lib
+        lib = self._lib = get_library()
         self._handle = lib.tl_open(str(token_file).encode(), seq_len, batch,
                                    seed, threads, prefetch)
         if not self._handle:

@@ -26,35 +26,36 @@ LOGGER = logging.getLogger(__name__)
 def maybe_initialize_distributed() -> None:
     """Idempotent; no-op for single-process runs.
 
+    Three cases, in order: an explicit env contract (coordinator, process
+    count and id given — JAX then looks nothing up); a TPU pod of SEVERAL
+    worker hosts (zero-argument auto-discovery); neither — one process,
+    which on a TPU host drives all its local chips and needs no distributed
+    runtime (a single-host slice sets ``TPU_WORKER_HOSTNAMES`` too, to one
+    name: that is not a pod). A rendezvous that was asked for and fails
+    raises; the run does not carry on as a single process.
+
     NB: must not touch ``jax.devices()``/``jax.process_count()`` before
     deciding — querying them initializes the local backend, after which
     ``jax.distributed.initialize`` raises.
     """
-    try:
-        if jax.distributed.is_initialized():
-            return
-    except AttributeError:  # older jax
-        from jax._src import distributed as _dist
-
-        if _dist.global_state.client is not None:
-            return
+    if jax.distributed.is_initialized():
+        return
 
     coord = os.environ.get("COORDINATOR_ADDRESS")
     if coord is None and os.environ.get("MASTER_ADDR"):
         coord = f"{os.environ['MASTER_ADDR']}:{os.environ.get('MASTER_PORT', '8476')}"
     nproc = os.environ.get("NUM_PROCESSES") or os.environ.get("WORLD_SIZE")
     pid = os.environ.get("PROCESS_ID") or os.environ.get("RANK")
+    workers = [h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+               if h.strip()]
 
-    try:
-        if coord and nproc is not None and pid is not None:
-            jax.distributed.initialize(coordinator_address=coord,
-                                       num_processes=int(nproc),
-                                       process_id=int(pid))
-            LOGGER.info(f"distributed: initialized process {pid}/{nproc} via {coord}")
-        elif os.environ.get("TPU_WORKER_HOSTNAMES") or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"):
-            jax.distributed.initialize()  # TPU pod auto-discovery
-            LOGGER.info(
-                f"distributed: TPU pod auto-init, process "
-                f"{jax.process_index()}/{jax.process_count()}")
-    except Exception as e:  # single-host dev boxes: fall through
-        LOGGER.warning(f"distributed init skipped: {e}")
+    if coord and nproc is not None and pid is not None:
+        jax.distributed.initialize(coordinator_address=coord,
+                                   num_processes=int(nproc),
+                                   process_id=int(pid))
+        LOGGER.info(f"distributed: initialized process {pid}/{nproc} via {coord}")
+    elif len(workers) > 1 or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"):
+        jax.distributed.initialize()  # TPU pod auto-discovery
+        LOGGER.info(
+            f"distributed: TPU pod auto-init, process "
+            f"{jax.process_index()}/{jax.process_count()}")

@@ -12,11 +12,13 @@ wrapping this launcher, so a crash of any rank becomes one nonzero gang exit
 the supervisor restarts as a unit (reference ``related-topics/
 elastic-training/README.md:5-16``).
 
-On real TPU pods JAX runs one process per host and rendezvous comes from the
-pod metadata, so this launcher is for: CPU/GPU-style multi-process hosts,
-and — with ``--devices-per-proc K`` — simulating an N-process pod on one
-machine with K virtual CPU devices per process (the regime the multi-process
-tests run; ``tests/test_multiprocess.py``).
+On a TPU host ONE process drives all local chips: a chip belongs to one
+process at a time, so N ranks that each initialise JAX on the same host
+would all ask for the same chips and fail or hang. The launcher therefore
+refuses ``--nproc > 1`` unless the ranks are held to the CPU — either
+``--devices-per-proc K`` (simulating an N-process pod on one machine with K
+virtual CPU devices per process, the regime ``tests/test_multiprocess.py``
+runs) or ``JAX_PLATFORMS=cpu`` in the environment.
 
 Usage:
     python -m distributed_training_guide_tpu.launch.local --nproc 2 \
@@ -60,6 +62,14 @@ def launch_gang(
     Rank 0 inherits this process's stdout/stderr; other ranks write to
     ``<log_dir>/rank<i>.{out,err}`` (or are silenced without a log_dir).
     """
+    platforms = {**os.environ, **(env_extra or {})}.get("JAX_PLATFORMS", "")
+    if nproc > 1 and not devices_per_proc and platforms != "cpu":
+        raise ValueError(
+            f"refusing to start {nproc} JAX processes on one host: a TPU "
+            f"chip belongs to one process at a time, and one process drives "
+            f"all local chips (run the chapter script directly). For a "
+            f"CPU pod simulation pass --devices-per-proc K, or set "
+            f"JAX_PLATFORMS=cpu")
     port = port or free_port()
     procs: list[subprocess.Popen] = []
     files: list = []
@@ -145,9 +155,13 @@ def main():
     cmd = args.cmd[1:] if args.cmd and args.cmd[0] == "--" else args.cmd
     if not cmd:
         parser.error("no worker command given (use: local [opts] -- cmd ...)")
-    sys.exit(launch_gang(cmd, args.nproc, port=args.port,
+    try:
+        rc = launch_gang(cmd, args.nproc, port=args.port,
                          devices_per_proc=args.devices_per_proc,
-                         log_dir=args.log_dir))
+                         log_dir=args.log_dir)
+    except ValueError as exc:
+        parser.error(str(exc))
+    sys.exit(rc)
 
 
 if __name__ == "__main__":

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from .dispatch import note_attention, note_choice
 from .flash_attention import check_static_window
 
 
@@ -68,6 +69,27 @@ def _xla_attention(
                            "attn_out")
 
 
+def resolve_attention_impl(impl: str, q_len: int, kv_len: int, head_dim: int,
+                           *, causal: bool = True,
+                           standard_layout: bool = True) -> tuple[str, str]:
+    """``(impl, reason)`` for one attention shape. A named impl is the
+    user's choice and comes back unchanged (an ineligible shape then raises
+    in the kernel); ``"auto"`` picks flash on a TPU backend when the call is
+    causal, tile-aligned and in the standard contiguous position layout,
+    and the einsum reference otherwise — and says which and why."""
+    if impl != "auto":
+        return impl, "forced"
+    backend = jax.default_backend()
+    if backend != "tpu":
+        return "xla", f"auto: backend is {backend}, not tpu"
+    if q_len % 128 or kv_len % 128 or head_dim % 64:
+        return "xla", (f"auto: seq {q_len}/{kv_len} % 128 or head_dim "
+                       f"{head_dim} % 64 is not tile-aligned")
+    if not (causal and standard_layout):
+        return "xla", "auto: non-causal or non-contiguous position layout"
+    return "flash", "auto: tpu backend, causal, tile-aligned"
+
+
 def multihead_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -106,12 +128,13 @@ def multihead_attention(
             "window (sliding-window attention) requires causal=True — a "
             "non-causal banded mask is not implemented on either path")
     check_static_window(window)
+    reason = "forced"
     if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        aligned = (q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
-                   and q.shape[-1] % 64 == 0)
-        impl = ("flash" if (on_tpu and aligned and causal and standard_layout)
-                else "xla")
+        impl, reason = resolve_attention_impl(
+            impl, q.shape[1], k.shape[1], q.shape[-1], causal=causal,
+            standard_layout=standard_layout)
+        note_choice("multihead_attention", impl, reason)
+    note_attention(impl, reason)
     if impl == "flash":
         from .flash_attention import flash_attention
 

@@ -36,14 +36,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable on CPU builds too; guard only for exotic setups
-    from jax.experimental.pallas import tpu as pltpu
+from .dispatch import note_attention, note_choice, resolve_interpret
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 NEG_INF = -1e30
 
@@ -209,7 +206,7 @@ def _resolve_band(window):
 
 
 def _band_spec():
-    return pl.BlockSpec(memory_space=pltpu.SMEM if pltpu is not None else None)
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _flash_fwd(q, k, v, causal, window, block_q, block_k, interpret,
@@ -552,6 +549,21 @@ def resolve_wrapper_mesh(mesh):
     return jax.sharding.get_abstract_mesh() if _in_manual_context() else mesh
 
 
+def wrapper_shard_map(mesh, **kwargs):
+    """``jax.shard_map`` for an attention wrapper whose body holds Pallas
+    calls: manual over EVERY axis of the wrapper mesh that the context has
+    not already made manual. The chip's lowering refuses a Mosaic kernel in
+    a region where any mesh axis is still auto ("cannot be automatically
+    partitioned"), size-1 axes included, so a shard_map manual over only
+    the axes that shard attention does not compile there. Axes the specs
+    do not name are replicated, which is what they were under GSPMD."""
+    wmesh = resolve_wrapper_mesh(mesh)
+    names = (frozenset(wmesh.axis_names)
+             - frozenset(getattr(wmesh, "manual_axes", ())))
+    return functools.partial(jax.shard_map, mesh=wmesh, axis_names=names,
+                             check_vma=False, **kwargs)
+
+
 def resolve_attention_manual_axes(mesh, batch_axes, head_axis):
     """Shared preamble for the manual-axes attention wrappers (this module's
     sharded flash, ``ring_attention``, and the Ulysses wrapper): keep only
@@ -621,8 +633,9 @@ def make_sharded_flash_attention(
     (output sharding comes back replicated) — mesh_size x wasted attention
     FLOPs on a real pod. This factory returns an attention callable (the
     same contract as ``make_ring_attention``) whose pallas calls run inside
-    a shard_map that is manual over exactly the axes that shard attention's
-    data-parallel dims: batch over ``batch_axes``, heads over ``head_axis``.
+    a shard_map (manual over every mesh axis — ``wrapper_shard_map``) whose
+    specs shard attention's data-parallel dims: batch over ``batch_axes``,
+    heads over ``head_axis``.
     Attention has no cross-batch or cross-head interaction, so the body
     needs no collectives; the sequence dim stays unsharded (cp>1 uses the
     ring instead).
@@ -653,7 +666,7 @@ def make_sharded_flash_attention(
         resolve_attention_manual_axes(mesh, batch_axes, head_axis)
     if not manual:
         return None
-    interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(None)
     spec_bshd = P(b_spec, None, head_axis, None)   # q/k/v/do/out [B, S, H, D]
     spec_bhsd = P(b_spec, head_axis, None, None)   # residuals    [B, H, S, D]
     spec_bhs = P(b_spec, head_axis, None)          # lse          [B, H, S]
@@ -700,8 +713,7 @@ def make_sharded_flash_attention(
     band_spec = P(None)   # [3] int32, replicated across every manual axis
 
     def _maps(dyn=False):
-        sm = functools.partial(jax.shard_map, mesh=resolve_wrapper_mesh(mesh),
-                               axis_names=manual, check_vma=False)
+        sm = wrapper_shard_map(mesh)
         if dyn:
             fwd = sm(fwd_body_dyn, in_specs=(band_spec, *(spec_bshd,) * 3),
                      out_specs=(spec_bshd, spec_bhs))
@@ -797,6 +809,14 @@ def make_sharded_flash_attention(
                     f"heads={hq}/{hkv}, batch={q.shape[0]}, "
                     f"seq={q.shape[1]}, head_dim={d} — pad, or use "
                     f"impl='xla'")
+            reason = (f"auto: heads={hq}/{hkv}, batch={q.shape[0]}, "
+                      f"seq={q.shape[1]}, head_dim={d}, causal={causal} is "
+                      f"not a shape the sharded kernel takes")
+            note_choice(
+                "sharded flash attention",
+                "caller's fallback" if fallback is not None else "xla",
+                reason)
+            note_attention("xla", reason)
             if fallback is not None:
                 return fallback(q, k, v, standard_layout=standard_layout,
                                 window=wcall, **kwargs)
@@ -806,6 +826,8 @@ def make_sharded_flash_attention(
                                        scale=scale,
                                        logit_softcap=logit_softcap,
                                        impl="xla")
+        note_attention("flash", "forced" if forced else
+                       "auto: a shape the sharded kernel takes")
         in_manual = _in_manual_context()
         if wcall is window_default or (isinstance(wcall, int)
                                        and wcall == window_default):
@@ -858,8 +880,7 @@ def flash_attention(
     if window is not None and not causal:
         raise ValueError("window (sliding-window attention) requires causal=True")
     check_static_window(window)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     d = q.shape[-1]
     if not interpret and (q.shape[1] % 8 or k.shape[1] % 8 or d % 64):
         # without a tile-divisible block the kernel would fall back to one

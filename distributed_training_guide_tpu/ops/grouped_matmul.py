@@ -41,13 +41,19 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .dispatch import note_choice, resolve_interpret
 
 _HAS_RAGGED_DOT = hasattr(jax.lax, "ragged_dot")
 
 
 def _resolve_impl(impl: str) -> str:
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "scan"
+        backend = jax.default_backend()
+        impl = "pallas" if backend == "tpu" else "scan"
+        note_choice("grouped_matmul", impl, f"auto: backend is {backend}")
+        return impl
     if impl not in ("pallas", "scan", "ragged", "einsum"):
         raise ValueError(f"unknown grouped_matmul impl {impl!r}; use "
                          f"'auto', 'pallas', 'scan', 'ragged', or 'einsum'")
@@ -158,10 +164,11 @@ def _gmm_kernel(offs_ref, wg_ref, wm_ref, nvalid_ref, lhs_ref, rhs_ref,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    rows = mt * bm + jax.lax.broadcasted_iota(jnp.int32, (bm,), 0)
+    # a [bm, 1] column from the start: Mosaic has no 1-D -> 2-D shape cast
+    rows = mt * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
     member = ((rows >= offs_ref[g]) & (rows < offs_ref[g + 1])
               & (w < nvalid_ref[0]))
-    x = jnp.where(member[:, None], lhs_ref[...], 0)
+    x = jnp.where(member, lhs_ref[...], 0)
     acc_ref[...] += jnp.dot(x, rhs_ref[0],
                             preferred_element_type=jnp.float32)
 
@@ -184,12 +191,14 @@ def _tgmm_kernel(offs_ref, wg_ref, wm_ref, nvalid_ref, lhs_ref, dy_ref,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    rows = mt * bm + jax.lax.broadcasted_iota(jnp.int32, (bm,), 0)
+    # a [bm, 1] column from the start: Mosaic has no 1-D -> 2-D shape cast
+    rows = mt * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
     member = ((rows >= offs_ref[g]) & (rows < offs_ref[g + 1])
               & (w < nvalid_ref[0]))
-    x = jnp.where(member[:, None], lhs_ref[...], 0)
-    acc_ref[...] += jnp.dot(x.T, dy_ref[...],
-                            preferred_element_type=jnp.float32)
+    x = jnp.where(member, lhs_ref[...], 0)
+    acc_ref[...] += jax.lax.dot_general(
+        x, dy_ref[...], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     is_last = jnp.logical_or(w == nw - 1,
                              wg_ref[jnp.minimum(w + 1, nw - 1)] != g)
@@ -200,8 +209,6 @@ def _tgmm_kernel(offs_ref, wg_ref, wm_ref, nvalid_ref, lhs_ref, dy_ref,
 
 
 def _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-
     m, k = lhs.shape
     g, _, n = rhs.shape
     m_tiles = pl.cdiv(m, bm)
@@ -235,8 +242,6 @@ def _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret):
 
 def _pallas_tgmm_raw(lhs, dy, group_sizes, g, out_dtype, bm, bn, interpret):
     """d_rhs [G, K, N] = per-group lhs_g^T @ dy_g (the 'tgmm')."""
-    from jax.experimental.pallas import tpu as pltpu
-
     m, k = lhs.shape
     n = dy.shape[1]
     m_tiles = pl.cdiv(m, bm)
@@ -290,6 +295,36 @@ def _pallas_gmm_bwd(out_dtype, bm, bn, interpret, res, dy):
 _pallas_gmm.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
 
 
+# Bytes of VMEM one kernel instance may plan for: the chip's compiler gives
+# a kernel 16 MiB of scoped VMEM by default (v5e), and refuses the program
+# when the double-buffered blocks plus the accumulator exceed it.
+_VMEM_BUDGET = 12 * 2**20
+
+
+def _fit_blocks(bm: int, bn: int, k: int) -> tuple[int, int]:
+    """Halve the larger of (bn, bm) until the larger of the gmm and tgmm footprints
+    fits ``_VMEM_BUDGET``. Sized at 4 B/element whatever the input dtype:
+    the backward runs both kernels in fp32 with these same blocks. The K
+    dim stays resident, so a K too wide for 128x128 blocks is an error."""
+
+    def footprint(bm, bn):
+        gmm = 2 * (bm * k + k * bn + bm * bn) * 4 + bm * bn * 4
+        tgmm = 2 * (bm * k + bm * bn + k * bn) * 4 + k * bn * 4
+        return max(gmm, tgmm)
+
+    while footprint(bm, bn) > _VMEM_BUDGET:
+        if max(bm, bn) <= 128:
+            raise ValueError(
+                f"grouped_matmul impl='pallas' keeps the contraction dim "
+                f"resident in VMEM; K={k} does not fit {_VMEM_BUDGET} bytes "
+                f"even at 128x128 blocks — use impl='ragged'")
+        if bn >= bm:
+            bn //= 2
+        else:
+            bm //= 2
+    return bm, bn
+
+
 # ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
@@ -332,9 +367,9 @@ def grouped_matmul(
             preferred_element_type=preferred_element_type)
     if impl == "einsum":
         return _gmm_einsum(lhs, rhs, group_sizes, out_dtype)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    bm = min(block_rows, lhs.shape[0])
-    bn = min(block_cols, rhs.shape[2])
+    interpret = resolve_interpret(interpret)
+    bm, bn = _fit_blocks(min(block_rows, lhs.shape[0]),
+                         min(block_cols, rhs.shape[2]),
+                         max(lhs.shape[1], rhs.shape[2]))
     return _pallas_gmm(lhs, rhs, group_sizes, jnp.dtype(out_dtype), bm, bn,
                        interpret)

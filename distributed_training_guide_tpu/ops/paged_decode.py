@@ -80,13 +80,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from jax.experimental.pallas import tpu as pltpu
+
+from .dispatch import lane_column, resolve_interpret
 from .flash_attention import (NEG_INF, _band_live, _pack_band,
                               check_static_window)
-
-try:  # pltpu imports on CPU builds; guard only for exotic setups
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
 
 
 def _rows_band_mask(window, m_idx, block_q, groups, page, q_off):
@@ -121,6 +119,7 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     s_idx = pl.program_id(0)
+    h_idx = pl.program_id(1)
     m_idx = pl.program_id(2)
     q_pos = lens_ref[s_idx]          # the FIRST new token's position; row
                                      # r sits at q_pos + r // groups
@@ -144,9 +143,9 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # [T*G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # [page, D]
+        k = k_ref[0].astype(jnp.float32)             # [page, D]
         if quantized:   # in-tile dequant: int8 payload x per-vector scale
-            k = k * ks_ref[0, :, 0][:, None]
+            k = k * lane_column(ks_ref[0], h_idx)   # [page, Hkv] -> head
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap is not None:  # Gemma-2: tanh cap BEFORE the mask
@@ -168,9 +167,9 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_ref, v_ref, *rest,
         # tiles
         p = jnp.where(mask, p, 0.0)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)    # [page, D]
+        v = v_ref[0].astype(jnp.float32)             # [page, D]
         if quantized:
-            v = v * vs_ref[0, :, 0][:, None]
+            v = v * lane_column(vs_ref[0], h_idx)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha + pv
@@ -184,20 +183,24 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
-def paged_decode_eligible(head_dim: int, page_size: int,
-                          quantized: bool = False) -> bool:
-    """Mosaic tile-divisibility gate for the COMPILED kernel (the interpret
-    path takes any shape): head_dim on the lane axis, page on sublanes.
-    int8 payloads pack (32, 128) native tiles, so the quantized gate is
-    stricter on the sublane (page) axis — conservative until the TPU
-    pool drains the queued kvq rungs. T-independent by construction (the
-    query-tile row count only sizes VMEM scratch), which is what lets
-    ``attend_impl='auto'`` resolve decode, verify, and chunk forwards to
-    the SAME family: a shape either takes the kernel for all three or
-    for none."""
-    if quantized:
-        return head_dim % 64 == 0 and page_size % 32 == 0
-    return head_dim % 64 == 0 and page_size % 8 == 0
+PAGED_GATE = "head_dim % 128 == 0 and page_size % 8 == 0"
+
+
+def paged_decode_eligible(head_dim: int, page_size: int) -> bool:
+    """Shape gate for the COMPILED kernel (the interpret path takes any
+    shape), set from what the v5e compiler accepts
+    (``tests/test_chip_compile.py``). The pool rides as
+    ``[P, page, Hkv*D]`` and head h's kv block is the ``[page, D]`` lane
+    window at ``h*D``: a lane-dim block must be a multiple of 128, so
+    head_dim 64 models take the gather path. The page axis is a
+    whole-dimension block, which Mosaic tiles for fp32, bf16 and int8
+    payloads alike at every page size the compiler was shown (8..128), so
+    one rule serves float and quantized pools. T-independent by
+    construction (the query-tile row count only sizes VMEM scratch), which
+    is what lets ``attend_impl='auto'`` resolve decode, verify, and chunk
+    forwards to the SAME family: a shape either takes the kernel for all
+    three or for none."""
+    return head_dim % 128 == 0 and page_size % 8 == 0
 
 
 def paged_flash_attend(
@@ -254,14 +257,11 @@ def paged_flash_attend(
     tg = t * groups
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if not interpret and not paged_decode_eligible(d, page,
-                                                   quantized=quantized):
+    interpret = resolve_interpret(interpret)
+    if not interpret and not paged_decode_eligible(d, page):
         raise ValueError(
-            f"paged flash attend (compiled) needs head_dim % 64 == 0 and "
-            f"page_size % {32 if quantized else 8} == 0; got head_dim={d}, "
-            f"page_size={page} — use impl='xla' or adjust page_size")
+            f"paged flash attend (compiled) needs {PAGED_GATE}; got "
+            f"head_dim={d}, page_size={page} — use impl='xla'")
     band = _pack_band(window)     # [window|2**30, 0, 0] int32 — the same
                                   # dynamic-band contract as the training
                                   # kernels; traced per-layer windows ride it
@@ -278,19 +278,24 @@ def paged_flash_attend(
     # the point of the kernel: the kv BlockSpecs read THROUGH the block
     # table — step (s, h, m) DMAs physical page tables[s, m]; a quantized
     # pool's scale rows ride the SAME index map as two more operands
-    table_kv = pl.BlockSpec((1, page, 1, d),
+    # the pool rides as [P, page, Hkv*D] (a free reshape): head h's
+    # [page, D] window is then a lane-dim block, which Mosaic tiles; a
+    # one-row block of the Hkv axis of the 4-D pool is refused
+    table_kv = pl.BlockSpec((1, page, d),
                             lambda s_, h, m_, lens, tabs, band_:
-                            (tabs[s_, m_], 0, h, 0))
-    table_scale = pl.BlockSpec((1, page, 1),
+                            (tabs[s_, m_], 0, h))
+    table_scale = pl.BlockSpec((1, page, hkv),
                                lambda s_, h, m_, lens, tabs, band_:
-                               (tabs[s_, m_], 0, h))
+                               (tabs[s_, m_], 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, tg, d),
                      lambda s_, h, m_, lens, tabs, band_: (s_, h, 0, 0)),
         table_kv,
         table_kv,
     ]
-    operands = [qr, k_pages, v_pages]
+    n_phys = k_pages.shape[0]
+    operands = [qr, k_pages.reshape(n_phys, page, hkv * d),
+                v_pages.reshape(n_phys, page, hkv * d)]
     if quantized:
         in_specs += [table_scale, table_scale]
         operands += [k_scale.astype(jnp.float32),

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .dispatch import lane_column, note_choice, resolve_interpret
+
 __all__ = ["quantized_matmul", "quantized_matmul_eligible", "quantized_take"]
 
 
@@ -117,7 +119,9 @@ def _matmul_t_xla(x2d: jax.Array, q: jax.Array, scale: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _dequant_matmul_kernel(x_ref, q_ref, s_ref, o_ref):
-    wblk = q_ref[...].astype(jnp.float32) * s_ref[...]    # [K, bs] in VMEM
+    # the [K, nb] scale table rides whole; block b's column is picked out
+    col = lane_column(s_ref[...], pl.program_id(0))       # [K, 1]
+    wblk = q_ref[...].astype(jnp.float32) * col           # [K, bs] in VMEM
     o_ref[...] = jnp.dot(x_ref[...].astype(jnp.float32), wblk,
                          preferred_element_type=jnp.float32)
 
@@ -133,7 +137,7 @@ def _matmul_pallas(x2d: jax.Array, q: jax.Array, scale: jax.Array,
         in_specs=[
             pl.BlockSpec((m, k), lambda b: (0, 0)),       # whole activations
             pl.BlockSpec((k, bs), lambda b: (0, b)),      # int8 block b
-            pl.BlockSpec((k, 1), lambda b: (0, b)),       # its scale column
+            pl.BlockSpec((k, nb), lambda b: (0, 0)),      # all scale columns
         ],
         out_specs=pl.BlockSpec((m, bs), lambda b: (0, b)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
@@ -189,9 +193,15 @@ def quantized_matmul(x: jax.Array, w, *, transpose: bool = False,
                          f"quantized weight {q.shape}"
                          f"{'.T' if transpose else ''}")
     x2d = x.reshape(-1, kdim)
+    eligible = quantized_matmul_eligible(w, transpose=transpose)
     if impl == "auto":
-        use_pallas = (jax.default_backend() == "tpu"
-                      and quantized_matmul_eligible(w, transpose=transpose))
+        use_pallas = jax.default_backend() == "tpu" and eligible
+        note_choice(
+            "quantized_matmul", "pallas" if use_pallas else "xla",
+            f"auto: backend is {jax.default_backend()}, weight "
+            f"{tuple(q.shape)}{'.T' if transpose else ''} in "
+            f"{_geometry(q, scale)[1]}-wide blocks is "
+            f"{'' if eligible else 'not '}a shape the kernel takes")
     else:
         use_pallas = impl == "pallas"
     if use_pallas:
@@ -199,8 +209,13 @@ def quantized_matmul(x: jax.Array, w, *, transpose: bool = False,
             raise NotImplementedError("pallas quantized_matmul has no "
                                       "transpose (tied lm_head) form; use "
                                       "impl='xla'")
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret(interpret)
+        if not interpret and not eligible:
+            raise ValueError(
+                f"quantized_matmul impl='pallas' (compiled) needs whole "
+                f"blocks, block width % 128 == 0 and K % 32 == 0; got "
+                f"weight {tuple(q.shape)} in "
+                f"{_geometry(q, scale)[1]}-wide blocks — use impl='xla'")
         out = _matmul_pallas(x2d, q, scale, interpret)
     elif transpose:
         out = _matmul_t_xla(x2d, q, scale)

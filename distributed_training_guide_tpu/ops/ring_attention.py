@@ -55,6 +55,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .dispatch import note_attention, resolve_interpret
 from .flash_attention import (_flash_fwd, _pack_band, check_static_window,
                               flash_bwd_with_stats)
 
@@ -430,17 +431,16 @@ def make_ring_attention(mesh: Mesh, *, axis_name: str = "cp",
     from .flash_attention import (_UNSET, _in_manual_context,
                                   attention_divisibility_error,
                                   resolve_attention_manual_axes,
-                                  resolve_wrapper_mesh)
+                                  wrapper_shard_map)
 
     if window is not None and not causal:
         raise ValueError(
             "window (sliding-window attention) requires causal=True")
     check_static_window(window)
     cp = mesh.shape[axis_name]
-    batch_axes, head_axis, tp, batch_div, b_spec, manual = \
+    batch_axes, head_axis, tp, batch_div, b_spec, _ = \
         resolve_attention_manual_axes(mesh, data_axes, head_axis)
-    manual = manual | {axis_name}
-    interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(None)
     spec = P(b_spec, axis_name, head_axis, None)   # [B, S_loc, H, D]
     lse_spec = P(b_spec, axis_name, head_axis)     # [B, S_loc, H]
 
@@ -460,8 +460,7 @@ def make_ring_attention(mesh: Mesh, *, axis_name: str = "cp",
         # check_vma=False: pallas interpret mode (the CPU test path) trips
         # the vma checker inside its own lowering ("dynamic_slice requires
         # varying manual axes to match")
-        sm = functools.partial(jax.shard_map, mesh=resolve_wrapper_mesh(mesh),
-                               axis_names=manual, check_vma=False)
+        sm = wrapper_shard_map(mesh)
         member = P(axis_name)   # [cp] iota -> each member's ring position
         if banded:
             # the window rides as a replicated [1] int32 operand so traced
@@ -559,6 +558,7 @@ def make_ring_attention(mesh: Mesh, *, axis_name: str = "cp",
             raise ValueError(
                 "window (sliding-window attention) requires causal=True")
         check_static_window(wcall)
+        note_attention("ring", f"cp={cp}: context-parallel ring")
         hq, hkv = q.shape[2], k.shape[2]
         if hq % tp or hkv % tp or q.shape[0] % batch_div:
             raise ValueError(attention_divisibility_error(

@@ -112,6 +112,9 @@ def main(argv=None) -> int:
             "--baseline group needs --group-size >= 2: singleton groups "
             "make every advantage (r - mean_g)/std_g exactly zero, so "
             "the loop would train nothing while looking busy")
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax.numpy as jnp
 
     from ..models import get_model

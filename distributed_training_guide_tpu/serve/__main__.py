@@ -67,11 +67,8 @@ def main(argv=None) -> None:
                         "payloads with per-(position, kv-head) fp32 scales "
                         "— ~3x more pages per pool byte, dequantized "
                         "in-kernel on the decode read; the kv_report line "
-                        "prices it. Pair with --page-size 32 on TPU: the "
-                        "int8 kernel tiles need page_size %% 32 == 0 (an "
-                        "engine whose page size would demote an otherwise "
-                        "kernel-eligible model to the gather path warns at "
-                        "construction)")
+                        "prices it. The compiled kernel takes int8 pools at "
+                        "the same page sizes as float ones")
     parser.add_argument("--weight-dtype", default=None,
                         choices=("fp32", "bf16", "int8"),
                         help="param storage (default: the model dtype). "
@@ -142,6 +139,9 @@ def main(argv=None) -> None:
                         "of running the offline batch")
     args = parser.parse_args(argv)
 
+    from ..utils.compile_cache import enable_compile_cache
+
+    cache = enable_compile_cache()
     import jax
     import jax.numpy as jnp
 
@@ -151,6 +151,13 @@ def main(argv=None) -> None:
     from .scheduler import Request
 
     bundle = get_model(args.model_name, dtype=jnp.float32)
+    # a forced 'flash' the compiled kernel cannot take raises here
+    from ..utils.logging import print_device_line
+    from .kv_pages import resolve_attend_impl
+
+    print_device_line("attend", resolve_attend_impl(
+        args.attend_impl, bundle.config.head_size, args.page_size),
+        cache.directory)
     tokenizer = None
     if args.prompt or args.http_port is not None:
         try:
@@ -308,6 +315,7 @@ def main(argv=None) -> None:
             line["text"] = tokenizer.decode(res.token_ids)
         print(json.dumps(line))
     print(json.dumps({"stats": throughput_stats(results, wall, engine)}))
+    cache.print_line()
 
 
 if __name__ == "__main__":

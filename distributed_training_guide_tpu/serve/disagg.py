@@ -69,7 +69,7 @@ from .engine import (LatencyMeter, ModelPrograms, adapter_metrics,
                      resolve_drafter, run_bucket_prefill,
                      run_decode_iteration, run_fork, spec_metrics,
                      validate_prefill_buckets)
-from .kv_pages import (check_kv_page_geometry, kv_page_bytes, PagePool,
+from .kv_pages import (resolve_attend_impl, kv_page_bytes, PagePool,
                        pages_for_tokens, pool_nbytes)
 from .scheduler import Admission, Request, RequestResult, Scheduler
 from .spec import new_spec_counters
@@ -604,9 +604,8 @@ class DisaggEngine:
         self.weight_dtype = self.programs.weight_dtype
         max_len, self.max_model_len, self.max_pages = \
             resolve_context_bounds(self.config, max_len, page_size)
-        check_kv_page_geometry(self.config, page_size=page_size,
-                               kv_dtype=self.kv_dtype,
-                               attend_impl=self.programs.attend_impl)
+        resolve_attend_impl(self.programs.attend_impl,
+                            self.config.head_size, page_size)
         self.page_size = page_size
         self.n_slots = n_slots
         self.n_prefill_slots = n_prefill_slots

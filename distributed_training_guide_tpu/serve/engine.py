@@ -67,7 +67,7 @@ from ..train.precision import Quantized
 from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        adapter_nbytes, adapter_pool_bytes, adapter_shapes,
                        init_adapter_stacks, validate_adapter_params)
-from .kv_pages import (check_kv_page_geometry, commit_prefill, copy_pages,
+from .kv_pages import (resolve_attend_impl, commit_prefill, copy_pages,
                        init_pages, kv_dtype_name, kv_page_bytes, make_attend,
                        PagePool, pages_for_tokens, pool_nbytes, TRASH_PAGE)
 from .scheduler import Admission, Request, RequestResult, Scheduler
@@ -1616,9 +1616,10 @@ class ServeEngine:
         self.prefill_chunk = prefill_chunk
         max_len, self.max_model_len, self.max_pages = \
             resolve_context_bounds(self.config, max_len, page_size)
-        check_kv_page_geometry(self.config, page_size=page_size,
-                               kv_dtype=self.kv_dtype,
-                               attend_impl=self.attend_impl)
+        # a forced 'flash' the compiled kernel cannot take fails here, at
+        # construction, not inside the first forward of a live request
+        resolve_attend_impl(self.attend_impl, self.config.head_size,
+                            page_size)
         self.page_size = page_size
         self.n_slots = n_slots
         if n_pages is None:

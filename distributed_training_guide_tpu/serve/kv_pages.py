@@ -91,7 +91,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import multihead_attention
-from ..ops.paged_decode import paged_decode_eligible, paged_flash_attend
+from ..ops.dispatch import note_choice
+from ..ops.paged_decode import (PAGED_GATE, paged_decode_eligible,
+                                paged_flash_attend)
 from ..train.precision import (Quantized, dequantize_blockwise,
                                quantize_blockwise)
 
@@ -149,35 +151,37 @@ def pool_nbytes(pages: dict) -> int:
     return int(sum(x.nbytes for x in jax.tree.leaves(pages)))
 
 
-def check_kv_page_geometry(config, *, page_size: int, kv_dtype,
-                           attend_impl: str) -> None:
-    """Warn at ENGINE CONSTRUCTION when the chosen (kv_dtype, page_size)
-    cannot take the compiled flash-decode kernel on TPU: int8 payloads
-    pack stricter Mosaic tiles (page_size % 32), so the default
-    page_size=16 pool would silently fall back to the gather program
-    under ``attend_impl='auto'`` — paying ~3x the kernel's decode
-    traffic and contradicting the in-kernel-dequant pitch. Only fires
-    when int8 REGRESSES eligibility — a shape the fp32 kernel also
-    couldn't tile (debug models' head_dim 16) never had the flash path
-    to lose, and stays silent. Off-TPU nothing changes (the gather path
-    is the CPU default regardless), but the warning fires anywhere so
-    the misconfiguration is caught in CI, not on the pod."""
-    if kv_dtype_name(config, kv_dtype) != "int8" or attend_impl == "xla":
-        return
-    if (paged_decode_eligible(config.head_size, page_size)
-            and not paged_decode_eligible(config.head_size, page_size,
-                                          quantized=True)):
-        import warnings
-
-        warnings.warn(
-            f"kv_dtype='int8' with page_size={page_size} (head_dim "
-            f"{config.head_size}) is not eligible for the compiled "
-            f"paged flash kernel (int8 Mosaic tiles need page_size % 32 "
-            f"== 0 and head_dim % 64 == 0): on TPU the decode, verify, "
-            f"and chunk forwards will all run the gather path at ~3x the "
-            f"kernel's HBM traffic. Use page_size=32 to keep the "
-            f"in-kernel dequant.",
-            stacklevel=3)
+def resolve_attend_impl(impl: str, head_dim: int,
+                        page_size: int) -> tuple[str, str]:
+    """``(impl, reason)`` for the paged attend family of one engine —
+    decode, verify and chunk forwards all resolve the same way, because
+    the kernel's shape gate is T-independent. ``"xla"`` is the gather
+    reference. ``"flash"`` is the user's choice: on a TPU backend a shape
+    the compiled kernel cannot take raises HERE, at engine construction,
+    instead of inside the first forward of a live request (off-TPU the
+    kernel runs interpreted and takes any shape). ``"auto"`` picks the
+    kernel on TPU when the shape passes the gate and the gather path
+    otherwise, and says which and why."""
+    if impl not in ("auto", "flash", "xla"):
+        raise ValueError(f"attend_impl must be 'auto', 'flash' or 'xla', "
+                         f"got {impl!r}")
+    backend = jax.default_backend()
+    eligible = paged_decode_eligible(head_dim, page_size)
+    if impl == "flash":
+        if backend == "tpu" and not eligible:
+            raise ValueError(
+                f"attend_impl='flash': head_dim {head_dim} with page_size "
+                f"{page_size} is not a shape the compiled paged flash "
+                f"kernel takes ({PAGED_GATE}) — use attend_impl='xla'")
+        return impl, "forced"
+    if impl == "xla":
+        return impl, "forced"
+    if backend != "tpu":
+        return "xla", f"auto: backend is {backend}, not tpu"
+    if not eligible:
+        return "xla", (f"auto: head_dim {head_dim} / page_size {page_size} "
+                       f"fails the kernel's gate ({PAGED_GATE})")
+    return "flash", "auto: tpu backend, shape passes the kernel's gate"
 
 
 def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
@@ -422,10 +426,8 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
         v_pages = v_pages.at[phys, off].set(v_new.astype(v_pages.dtype))
 
     if impl == "auto":
-        impl = ("flash" if (jax.default_backend() == "tpu"
-                            and paged_decode_eligible(q.shape[-1], page,
-                                                      quantized=quantized))
-                else "xla")
+        impl, reason = resolve_attend_impl(impl, q.shape[-1], page)
+        note_choice("paged_attend", impl, reason)
     if impl == "flash":
         # block_q = T: the same kernel serves the decode step (T == 1),
         # the verify forward, and a prefill chunk — the scatter above

@@ -56,8 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.registry import ModelBundle, family_module
-from ..ops.paged_decode import paged_decode_eligible
-from .kv_pages import PagePool, init_pages, make_attend, pages_for_tokens
+from .kv_pages import (PagePool, init_pages, make_attend, pages_for_tokens,
+                       resolve_attend_impl)
 
 
 def new_spec_counters() -> dict:
@@ -172,9 +172,6 @@ class DraftModelDrafter(Drafter):
             raise ValueError(f"k must be >= 1, got {k}")
         if chunk < 1:
             raise ValueError(f"chunk must be >= 1, got {chunk}")
-        if attend_impl not in ("auto", "flash", "xla"):
-            raise ValueError(f"attend_impl must be 'auto', 'flash' or "
-                             f"'xla', got {attend_impl!r}")
         self.bundle = bundle
         self.config = bundle.config
         self.mod = family_module(bundle.family)
@@ -187,19 +184,10 @@ class DraftModelDrafter(Drafter):
         max_pos = getattr(self.config, "max_position_embeddings", None)
         self.max_len = min(max_len, max_pos) if max_pos else max_len
         self.page_size = page_size
-        if (attend_impl == "flash" and jax.default_backend() == "tpu"
-                and not paged_decode_eligible(self.config.head_size,
-                                              page_size)):
-            # the DRAFT model's geometry gates the compiled kernel, not
-            # the target's — surface the mismatch here instead of inside
-            # the first draft forward of a live decode iteration
-            raise ValueError(
-                f"attend_impl='flash': draft model head_size "
-                f"{self.config.head_size} with page_size {page_size} is "
-                f"not eligible for the compiled paged flash kernel "
-                f"(head_dim % 64 == 0 and page_size % 8 == 0) — use "
-                f"attend_impl='auto' (gather fallback) or adjust "
-                f"page_size")
+        # the DRAFT model's geometry gates the compiled kernel, not the
+        # target's — a forced 'flash' it cannot take raises here instead
+        # of inside the first draft forward of a live decode iteration
+        resolve_attend_impl(attend_impl, self.config.head_size, page_size)
         self.max_pages = pages_for_tokens(self.max_len, page_size)
         n_pages = 1 + n_slots * self.max_pages
         self.pool = PagePool(n_pages, page_size)

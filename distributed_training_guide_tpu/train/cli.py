@@ -219,11 +219,10 @@ def get_parser() -> argparse.ArgumentParser:
                              "still hard: each step consumes the previous "
                              "state on device")
     parser.add_argument("--timer-sync", action="store_true",
-                        help="device-fence the per-phase timers (reference "
-                             "LocalTimer/cuda.synchronize semantics) instead "
-                             "of relying on the loss host-read; use on "
-                             "healthy pools — see BENCH.md on why the fence "
-                             "is not the default here")
+                        help="device-fence both edges of the per-phase "
+                             "timers (reference LocalTimer/cuda.synchronize "
+                             "semantics) in addition to the loss host-read "
+                             "that ends every timed step")
     parser.add_argument("--profile-dir", default=None,
                         help="capture a jax.profiler trace of steps 10-15 into this dir "
                              "(view with xprof/tensorboard; see diagnosing-errors/)")
@@ -244,6 +243,31 @@ def get_parser() -> argparse.ArgumentParser:
     return parser
 
 
+def _compile_step(lowered, n_chips: int):
+    """Compile the lowered step ONCE; the executable that comes back takes
+    every step of the run, and the JSON line printed here describes it: the
+    compile seconds and, on a multi-device mesh, the collectives it holds
+    (``utils/hlo.collective_summary``)."""
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    program = {"compile_s": round(time.perf_counter() - t0, 3)}
+    if n_chips > 1:
+        from ..utils.hlo import collective_summary
+
+        program["collectives"] = collective_summary(compiled.as_text())
+    if jax.process_index() == 0:
+        print(json.dumps({"step_program": program}), flush=True)
+    return compiled
+
+
+def _print_device_memory() -> None:
+    """Every local device's ``bytes_in_use``, as one JSON line: after the
+    first step of a sharded run it shows the state really is spread."""
+    print(json.dumps({"device_memory": [
+        {"id": d.id, "bytes_in_use": (d.memory_stats() or {}).get(
+            "bytes_in_use")} for d in jax.local_devices()]}), flush=True)
+
+
 def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = None,
                  pretrained_dir: Optional[str] = None,
                  offload_opt_state: bool = False,
@@ -257,6 +281,9 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
     # failing later would strand an unfinished wandb run and leak the loader
     if getattr(args, "fence_every", 1) < 1:
         raise SystemExit(f"--fence-every must be >= 1, got {args.fence_every}")
+    from ..utils.compile_cache import enable_compile_cache
+
+    cache = enable_compile_cache()
     from ..checkpoint import CheckpointIO, restore_train_state
     from ..data import ShardedBatchLoader, get_tokenizer, load_and_preprocess_data
     from ..models import get_model
@@ -265,6 +292,8 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
     from ..train.state import host_state_dict
     from ..utils import (LocalTimer, compute_mfu, get_mem_stats, init_logging,
                          is_process0, transformer_flops_per_token)
+    from ..utils.logging import print_device_line
+    from ..utils.mfu import device_peak_flops
 
     init_logging(jax.process_index(), jax.process_count())
     LOGGER.info({k: v for k, v in os.environ.items() if k.startswith(("JAX", "XLA", "TPU"))})
@@ -340,6 +369,20 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                              seq_length=seq_length,
                              target_device=getattr(args, "preflight_target",
                                                    None))
+
+    # Trace and lower the step now, before anything is loaded or placed: the
+    # start-up line then names the attention implementation(s) the step
+    # TRACED (ops/dispatch.record_attention), not what the flags were expected
+    # to resolve to, and a shape or sharding the step cannot take fails here
+    from ..ops.dispatch import describe_attention, record_attention
+    from .step import lower_step
+
+    with record_attention() as traced:
+        lowered, _ = lower_step(trainer, global_batch=global_batch,
+                                seq_length=seq_length)
+    if is_process0():
+        print_device_line("attention", describe_attention(traced),
+                          cache.directory)
 
     tokenizer = get_tokenizer(args.model_name)
     dataset = load_and_preprocess_data(
@@ -417,6 +460,12 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
         bundle.num_active_params(), cfg.num_layers, cfg.hidden_size, seq_length,
         vocab_size=cfg.vocab_size)
     n_chips = plan.mesh.size
+    # MFU is reported only against a peak that is on record for this device
+    # kind; elsewhere (the CPU included) the field is absent
+    try:
+        peak_flops = device_peak_flops()
+    except ValueError:
+        peak_flops = None
     tok_per_step = trainer.tokens_per_step(args.batch_size, seq_length)
     last_info: dict = {}
 
@@ -436,6 +485,14 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
 
     profile_started = profile_done = False
     profile_start_step = 0
+    # One executable for the whole run, compiled ahead of time from the
+    # program lowered above. Under host offload step_fn is a Python wrapper
+    # around its jit (transfers outside it) and compiles on its first call.
+    first_step = host_state["global_step"]
+    step_fn = trainer.step_fn
+    if not hasattr(step_fn, "jitted"):
+        step_fn = _compile_step(lowered, n_chips)
+    del lowered
     done = False
     pending_losses = []  # (step, loss, notfinite) banked between fences
 
@@ -461,7 +518,7 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                 with timers["data"]:
                     batch = next(batches)
                 with timers["step"]:
-                    state, metrics = trainer.step_fn(state, batch)
+                    state, metrics = step_fn(state, batch)
                     # --fence-every 1 (default): force sync now, like the
                     # reference's per-step loss.item() (01:163). N>1: bank
                     # the device scalar and let the host dispatch ahead;
@@ -485,6 +542,9 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                 host_state["global_step"] += 1
                 host_state["epoch_step"] += 1
                 heartbeat.beat(host_state["global_step"])
+                if (n_chips > 1 and host_state["global_step"] == first_step + 1
+                        and is_process0()):
+                    _print_device_memory()
                 if progress:
                     progress.update(1)
 
@@ -515,7 +575,9 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                         "num_batches_remaining": steps_per_epoch - i_step,
                         **get_mem_stats(),
                         "tokens_per_s": tokens_per_s,
-                        "mfu": compute_mfu(tokens_per_s, flops_per_token, n_chips),
+                        **({"mfu": compute_mfu(tokens_per_s, flops_per_token,
+                                               n_chips, peak_flops)}
+                           if peak_flops else {}),
                         "time/total": ms_per_step,
                         **{f"time/{k}": t.avg_elapsed_ms() for k, t in timers.items()},
                         **({"guard_skipped": guard.total_skipped}
@@ -567,4 +629,6 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
         loader.close()
         if progress:
             progress.close()
+    if is_process0():
+        cache.print_line()
     return {"host_state": host_state, "last_info": last_info, "state": state}

@@ -279,33 +279,10 @@ def run_preflight(trainer, *, global_batch: int, seq_length: int,
     "v5p") when preflighting from a non-TPU login host; defaults to the
     local device on TPU, v5p otherwise.
     """
-    from ..checkpoint import abstract_train_state
+    from .step import lower_step
 
-    state = abstract_train_state(trainer)
-    if global_batch % trainer.grad_accum:
-        # a silent floor-div here would lower a SMALLER step than training
-        # runs, making both the budget and the "it lowers" signal wrong
-        raise ValueError(
-            f"global batch {global_batch} is not divisible by "
-            f"gradient accumulation {trainer.grad_accum}")
-    if trainer.grad_accum > 1:  # leading scanned microbatch axis
-        shape = (trainer.grad_accum, global_batch // trainer.grad_accum,
-                 seq_length)
-    else:
-        shape = (global_batch, seq_length)
-    batch = {
-        k: jax.ShapeDtypeStruct(shape, np.int32, sharding=sh)
-        for k, sh in trainer.batch_shardings().items()
-    }
-    # under host offload, step_fn is a python wrapper (transfers outside jit);
-    # lower its compiled core against the device-resident shardings it expects
-    step = trainer.step_fn
-    if hasattr(step, "jitted"):
-        step = step.jitted
-        state = jax.tree.map(
-            lambda sds, sh: jax.ShapeDtypeStruct(sds.shape, sds.dtype, sharding=sh),
-            state, trainer._device_state_shardings)
-    lowered = step.lower(state, batch)  # raises on sharding bugs
+    lowered, state = lower_step(trainer, global_batch=global_batch,
+                                seq_length=seq_length)
 
     params_b = _per_device_bytes(state.params, trainer.param_shardings)
     opt_b = _per_device_bytes(

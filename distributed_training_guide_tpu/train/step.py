@@ -764,6 +764,43 @@ class Trainer:
         return self.plan.data_parallel_size * per_device_batch * seq_len * self.grad_accum
 
 
+def lower_step(trainer: "Trainer", *, global_batch: int, seq_length: int):
+    """Trace and lower the train step against ABSTRACT inputs — the state
+    layout a restore would target, one global batch of token ids — and
+    return ``(lowered, state_avals)``. Nothing is placed on a device.
+
+    The training loop compiles the returned program once and takes every
+    step with that executable (so what it logs about the program — compile
+    seconds, collectives — describes the program that runs); the preflight
+    reads its memory analysis. Under host offload ``step_fn`` is a Python
+    wrapper (transfers outside the jit): its compiled core is lowered, against
+    the device-resident shardings it expects."""
+    from ..checkpoint import abstract_train_state
+
+    state = abstract_train_state(trainer)
+    if global_batch % trainer.grad_accum:
+        # a silent floor-div here would lower a SMALLER step than training
+        # runs, making both the budget and the "it lowers" signal wrong
+        raise ValueError(
+            f"global batch {global_batch} is not divisible by "
+            f"gradient accumulation {trainer.grad_accum}")
+    if trainer.grad_accum > 1:  # leading scanned microbatch axis
+        shape = (trainer.grad_accum, global_batch // trainer.grad_accum,
+                 seq_length)
+    else:
+        shape = (global_batch, seq_length)
+    batch = {k: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
+             for k, sh in trainer.batch_shardings().items()}
+    step = trainer.step_fn
+    if hasattr(step, "jitted"):
+        step = step.jitted
+        state = jax.tree.map(
+            lambda sds, sh: jax.ShapeDtypeStruct(sds.shape, sds.dtype,
+                                                 sharding=sh),
+            state, trainer._device_state_shardings)
+    return step.lower(state, batch), state  # raises on sharding bugs
+
+
 # ---------------------------------------------------------------------------
 # post-training: masked ragged rollout objectives (post/loop.py's update step)
 # ---------------------------------------------------------------------------

@@ -46,8 +46,11 @@ _COMPUTATION_RE = re.compile(  # params may be tuple-typed (nested parens)
     r"^\s*(?:ENTRY\s+)?(%[\w.\-]+)\s*(?:\(.*\))?\s*->.*\{\s*$")
 # the result type may be tuple-shaped with spaces — async collective
 # -start ops always are on TPU: "%ag-start = (f32[8], f32[32]) all-gather-start(..."
+# — and the chip's tiled layouts nest parentheses inside it
+# ("(bf16[2,128]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) collective-permute-start(...")
+# so the tuple runs lazily up to the ") opcode(" that closes it
 _OP_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=\s*(?:\([^)]*\)|\S+)\s+([\w\-]+)\(")
+    r"^\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=\s*(?:\(.*?\)|\S+)\s+([\w\-]+)\(")
 
 
 def _iter_ops(text: str):
@@ -79,6 +82,53 @@ def find_collectives(text: str, kinds: Sequence[str] = COLLECTIVE_KINDS
                                     line=line, is_start=is_start,
                                     is_done=is_done))
     return out
+
+
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+                "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+                "f64": 8}
+_SHAPE_RE = re.compile(r"\b([a-z]+\d+|pred)\[([\d,]*)\]")
+# the chip's compiler emits a reduce-scatter as a custom fusion whose callee
+# it names all-reduce-scatter (the all-reduce inside it is the fusion's
+# implementation, not a collective of the program's)
+_RS_FUSION = "%all-reduce-scatter"
+
+
+def _result_bytes(line: str) -> int:
+    """Bytes of an op's result (summed over a tuple), from its text."""
+    m = _OP_RE.match(line)
+    total = 0
+    for dtype, dims in _SHAPE_RE.findall(line[m.end(1):m.start(2)]):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        total += n * _DTYPE_BYTES.get(dtype, 4)
+    return total
+
+
+def collective_summary(text: str) -> dict:
+    """What a compiled step program moves between devices, for a log line:
+    how many collectives of each kind it holds (a start/done pair is one),
+    how many fused reduce-scatters (``all-reduce-scatter`` custom fusions,
+    the chip compiler's form), and the bytes of its largest all-reduce
+    outside those fusions — a sharded-state program reduces its gradients by
+    reduce-scatter (as an op, as such a fusion, or decomposed into a ring of
+    collective-permutes around the partial dots), so a parameter-sized
+    all-reduce is the sign that it does not."""
+    counts: dict = {}
+    largest_all_reduce = 0
+    for name, op, comp, _, line in _iter_ops(text):
+        if op == "fusion" and f"calls={_RS_FUSION}" in line:
+            counts["reduce-scatter-fusion"] = \
+                counts.get("reduce-scatter-fusion", 0) + 1
+            continue
+        base = op[:-len("-start")] if op.endswith("-start") else op
+        if base not in COLLECTIVE_KINDS or comp.startswith(_RS_FUSION):
+            continue
+        counts[base] = counts.get(base, 0) + 1
+        if base == "all-reduce":
+            largest_all_reduce = max(largest_all_reduce, _result_bytes(line))
+    return {"counts": counts, "largest_all_reduce_bytes": largest_all_reduce}
 
 
 _CALLEE_RE = re.compile(

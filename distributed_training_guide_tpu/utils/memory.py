@@ -2,8 +2,9 @@
 
 Parity with the reference's ``get_mem_stats`` (``01-single-gpu/train_llm.py:248-257``),
 which reports current/peak allocated+reserved GB from the CUDA caching
-allocator. On TPU the runtime exposes ``Device.memory_stats()``; CPU backends
-may expose nothing, in which case we report zeros so the log dict stays stable.
+allocator. On TPU the runtime exposes ``Device.memory_stats()`` and a failure
+to read it is an error. The CPU backend reports nothing, and only there the
+fields are zeros so the log dict keeps its keys.
 """
 from __future__ import annotations
 
@@ -14,9 +15,11 @@ import jax
 
 def get_mem_stats(device: Optional[jax.Device] = None) -> dict:
     device = device or jax.local_devices()[0]
-    try:
-        stats = device.memory_stats() or {}
-    except Exception:
+    stats = device.memory_stats()
+    if stats is None:
+        if device.platform != "cpu":
+            raise RuntimeError(
+                f"{device} ({device.platform}) returned no memory_stats()")
         stats = {}
     gb = 1e-9
     return {

@@ -53,38 +53,47 @@ def banded_attention_kv_length(cfg, seq_len: int) -> float:
     return float(seq_len)
 
 
-# Peak bf16 dense FLOP/s per chip by device kind substring.
-_PEAK_FLOPS = [
-    ("v6e", 918e12),
-    ("v6", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5 lite", 197e12),
-    ("v5litepod", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-]
+# One table of per-chip peaks, keyed by the ``device_kind`` JAX reports
+# (lower-cased, without the "TPU " prefix) plus the short names people type
+# for ``--preflight-target``. Values: (bf16 dense FLOP/s, ICI bytes/s egress
+# over all links in one direction, source). A kind that is not here —
+# "cpu" included — is an error, never a default: a utilization against an
+# invented peak is not a measurement.
+_CLOUD_DOCS = "Google Cloud TPU documentation, system architecture, "
+CHIP_PEAKS: dict[str, tuple[float, float, str]] = {
+    "v5 lite": (197e12, 1600e9 / 8, _CLOUD_DOCS + "TPU v5e"),
+    "v5e": (197e12, 1600e9 / 8, _CLOUD_DOCS + "TPU v5e"),
+    "v5litepod": (197e12, 1600e9 / 8, _CLOUD_DOCS + "TPU v5e"),
+    "v5p": (459e12, 4800e9 / 8, _CLOUD_DOCS + "TPU v5p"),
+    "v5": (459e12, 4800e9 / 8, _CLOUD_DOCS + "TPU v5p"),
+    "v6 lite": (918e12, 3584e9 / 8, _CLOUD_DOCS + "TPU v6e"),
+    "v6e": (918e12, 3584e9 / 8, _CLOUD_DOCS + "TPU v6e"),
+    "v4": (275e12, 2400e9 / 8, _CLOUD_DOCS + "TPU v4"),
+    "v3": (123e12, 1400e9 / 8, _CLOUD_DOCS + "TPU v3"),
+}
+
+
+def chip_peaks(device: "jax.Device | None" = None,
+               device_kind: str | None = None) -> tuple[float, float, str]:
+    """``(bf16 FLOP/s, ICI bytes/s, source)`` of one chip. ``device_kind``
+    names a TARGET chip (e.g. "v5p") without probing a local device — the
+    preflight roofline prices pod plans from CPU hosts. Raises
+    ``ValueError`` for a kind the table does not have."""
+    if device_kind is None:
+        device = device or jax.local_devices()[0]
+        device_kind = getattr(device, "device_kind", "") or device.platform
+    key = device_kind.lower().removeprefix("tpu").strip()
+    if key not in CHIP_PEAKS:
+        raise ValueError(
+            f"no peak FLOP/s or ICI bandwidth on record for device kind "
+            f"{device_kind!r}; known kinds: {sorted(CHIP_PEAKS)} — add it "
+            f"to utils/mfu.py CHIP_PEAKS with its source")
+    return CHIP_PEAKS[key]
 
 
 def device_peak_flops(device: "jax.Device | None" = None,
                       device_kind: str | None = None) -> float:
-    """``device_kind`` names a TARGET chip (e.g. "v5p") without probing a
-    local device — the preflight roofline prices pod plans from CPU hosts."""
-    if device_kind is not None:
-        kind = device_kind.lower()
-        for key, flops in _PEAK_FLOPS:
-            if key in kind:
-                return flops
-        return 459e12  # v5p, the 405B recipe's stated target
-    device = device or jax.local_devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, flops in _PEAK_FLOPS:
-        if key in kind:
-            return flops
-    if device.platform == "cpu":
-        return 1e12  # nominal, so CPU tests produce finite MFU
-    return 197e12
+    return chip_peaks(device, device_kind)[0]
 
 
 def compute_mfu(tokens_per_s: float, flops_per_token: float, n_chips: int = 1,
@@ -93,32 +102,10 @@ def compute_mfu(tokens_per_s: float, flops_per_token: float, n_chips: int = 1,
     return (tokens_per_s * flops_per_token) / (peak * n_chips)
 
 
-# Aggregate ICI bandwidth per chip (bytes/s, all links, one direction) by
-# device kind substring — public spec-sheet numbers (v5p: 4800 Gbit/s ICI
-# per chip; v5e: 1600; v4: 2400; v6e: 3584). The preflight roofline
-# (train/preflight.py) divides ring-collective bytes by this, the standard
-# scaling-book first-order model; real meshes split it over links/axes, so
-# treat results as a best-case bound, not a simulator.
-_ICI_BYTES_PER_S = [
-    ("v6e", 3584e9 / 8),
-    ("v6", 3584e9 / 8),
-    ("v5p", 4800e9 / 8),
-    ("v5e", 1600e9 / 8),
-    ("v5 lite", 1600e9 / 8),
-    ("v5litepod", 1600e9 / 8),
-    ("v4", 2400e9 / 8),
-    ("v3", 1400e9 / 8),
-]
-
-
 def device_ici_bandwidth(device: "jax.Device | None" = None,
                          device_kind: str | None = None) -> float:
-    """Bytes/s of ICI egress per chip; ``device_kind`` overrides probing so
-    a CPU login host can run the roofline for a target pod (preflight)."""
-    kind = (device_kind if device_kind is not None
-            else getattr(device or jax.local_devices()[0], "device_kind", "")
-            ).lower()
-    for key, bw in _ICI_BYTES_PER_S:
-        if key in kind:
-            return bw
-    return 4800e9 / 8  # default to the v5p target the 405B recipe names
+    """Bytes/s of ICI egress per chip. The preflight roofline
+    (train/preflight.py) divides ring-collective bytes by this, the standard
+    first-order model; real meshes split it over links/axes, so treat
+    results as a best-case bound, not a simulator."""
+    return chip_peaks(device, device_kind)[1]

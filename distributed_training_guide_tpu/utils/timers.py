@@ -20,13 +20,11 @@ def device_sync() -> None:
     """Device fence for ``LocalTimer(sync_fn=...)`` — the reference C17
     semantics (``01-single-gpu/train_llm.py:260-286``, cuda.synchronize).
 
-    Enqueues a trivial computation on every local device and blocks on it:
-    the runtime executes programs in launch order per device, so the fence
-    completes only after all previously dispatched work. Default timers use
-    the loss host-read instead (see ``_default_sync``) because on some
-    remote TPU pools ``block_until_ready`` returns early (BENCH.md "pool
-    timeline"); ``--timer-sync`` restores this per-phase mode on healthy
-    hardware."""
+    Enqueues a trivial computation on every local device and blocks on it
+    with ``jax.block_until_ready``: the runtime executes programs in launch
+    order per device, so the fence completes only after all previously
+    dispatched work. ``--timer-sync`` puts it on both edges of every phase
+    timer."""
     import jax.numpy as jnp
 
     jax.block_until_ready([jnp.zeros((), jnp.int32, device=d) + 1
@@ -34,13 +32,12 @@ def device_sync() -> None:
 
 
 def _default_sync() -> None:
-    # Intentionally a no-op. JAX has no global device fence (dispatch queues
-    # are per-array, and on some remote TPU platforms even block_until_ready
-    # returns early), so honest phase timing requires the measured region
-    # itself to end with a host read of its outputs — the training loop's
-    # ``float(metrics["loss"])`` is that read, exactly like the reference's
+    # A no-op: JAX has no global device fence (dispatch queues are
+    # per-array), so a timed region is honest when it ends by waiting for
+    # its own outputs. The training loop's ``float(metrics["loss"])`` inside
+    # the step timer is that wait, exactly like the reference's
     # ``loss.item()`` (``02-distributed-data-parallel/train_llm.py:163``).
-    # Callers measuring raw dispatch can pass an explicit sync_fn.
+    # Callers that time a region with no such read pass ``device_sync``.
     return None
 
 

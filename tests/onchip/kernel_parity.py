@@ -1,0 +1,260 @@
+#!/usr/bin/env python3
+"""Each Pallas kernel of the main paths against its XLA reference, ON THE CHIP.
+
+``chip_smoke.py`` runs this as its last one-chip phase. The serve phase's
+greedy tokens are a coarse check: how much an argmax depends on attention is
+a property of the (random) weights — on a tiny model a kernel that returns
+ZEROS still reproduces most of the reference's tokens — so the kernels
+themselves are held to their references here, on random inputs at the
+smoke's own shapes, through the functions the entry points call:
+
+- ``serve/kv_pages.paged_attend(impl="flash")`` vs ``impl="xla"`` (the gather
+  reference) at qwen3-0.6b's heads (16/8 of 128), fp32 pool, page 16, T = 1
+  (decode) and T = 64 (a prefill chunk), slots of several lengths;
+- ``ops/attention.multihead_attention(impl="flash")`` vs ``impl="xla"``,
+  forward and backward, at the training shape (seq 2048, bf16).
+
+A case passes when ``max|kernel - reference| <= RTOL * max(1, max|reference|)``.
+Then the CONTROL: the same paged comparison with the kernel sabotaged four
+ways (returns zeros / reads the value pool as keys / walks the block table
+rolled by one page / masks one position too many); the bound must REFUSE
+every one, or it proves nothing.
+
+``--all`` adds what is off the smoke's path but in ``ops/``: bf16 and int8
+pools, page 32, T = 5 (speculative verify), banded and soft-capped flash,
+``gmm``/``tgmm`` at hidden 2048 x expert width 768, the int8 matmul at
+1024 x 3072 (128-wide blocks). A builder runs that by hand.
+
+One process; fails (no last line, exit 1) off the chip. Prints the entry
+points' start-up device line, one JSON line per case, and last
+``{"kernel_parity_ok": true, "cases": N, "controls_refused": 4}``.
+"""
+import importlib
+import json
+import sys
+from pathlib import Path
+from types import SimpleNamespace
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
+
+from distributed_training_guide_tpu.utils.compile_cache import \
+    enable_compile_cache  # noqa: E402
+
+CACHE = enable_compile_cache()
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from distributed_training_guide_tpu.ops.attention import \
+    multihead_attention  # noqa: E402
+from distributed_training_guide_tpu.serve import kv_pages  # noqa: E402
+from distributed_training_guide_tpu.utils.logging import \
+    print_device_line  # noqa: E402
+
+EXPECT_PLATFORM = "tpu"
+# bf16 MXU passes on fp32 operands, one or two bf16 ulps on bf16 outputs.
+# Read on the chip: 5e-3..7e-3 on fp32/int8-pool outputs of magnitude 2..3,
+# 0.016 on bf16 ones, gradients within 0.021 of their magnitude; the
+# sabotaged kernels of the control are off by 0.48..1.5 of it
+RTOL = 0.05
+HQ, HKV, D = 16, 8, 128          # qwen3-0.6b attention
+N_SLOTS, PAGES_PER_SLOT, POOL_PAGES = 4, 8, 64
+SEQ, BATCH = 2048, 2
+
+RNG = np.random.default_rng(0)
+FAILED: list = []
+N_CASES = 0
+
+
+def normal(shape, dtype):
+    return jnp.asarray(RNG.standard_normal(shape), dtype)
+
+
+def worst(got, want) -> tuple[float, float]:
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    if not np.isfinite(got).all():
+        return float("inf"), float(np.max(np.abs(want)))
+    return float(np.max(np.abs(got - want))), float(np.max(np.abs(want)))
+
+
+def case(name: str, pairs: dict, **shape) -> bool:
+    """Record one comparison; ``pairs`` maps a label to (kernel, reference)."""
+    global N_CASES
+    N_CASES += 1
+    errs = {k: worst(a, b) for k, (a, b) in pairs.items()}
+    ok = all(e <= RTOL * max(1.0, m) for e, m in errs.values())
+    print(json.dumps({"kernel": name, **shape, "ok": ok, "rtol": RTOL,
+                      "max_abs_err_and_ref_max": errs}), flush=True)
+    if not ok:
+        FAILED.append(name)
+    return ok
+
+
+# ---- the serve attend --------------------------------------------------------
+
+def paged_inputs(pool: str, page: int, t: int):
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    q = normal((N_SLOTS, t, HQ, D), dtype)
+    k_new, v_new = (normal((N_SLOTS, t, HKV, D), dtype) for _ in range(2))
+    k_pool, v_pool = (normal((POOL_PAGES, page, HKV, D), dtype)
+                      for _ in range(2))
+    if pool == "int8":
+        k_pool, v_pool = (kv_pages.quantize_kv(x) for x in (k_pool, v_pool))
+    tables = jnp.asarray(RNG.permutation(np.arange(1, POOL_PAGES))
+                         [:N_SLOTS * PAGES_PER_SLOT]
+                         .reshape(N_SLOTS, PAGES_PER_SLOT), jnp.int32)
+    room = PAGES_PER_SLOT * page - t
+    # a short slot (where one masked position is a large share), one just
+    # past a page, one mid-table, one that fills its table
+    lengths = jnp.asarray([3, page + 1, room // 2, room - 1], jnp.int32)
+    return q, k_new, v_new, k_pool, v_pool, tables, lengths
+
+
+def attend(impl: str, args):
+    fn = jax.jit(lambda *a: kv_pages.paged_attend(*a, impl=impl)[0])
+    return fn(*args)
+
+
+def paged_case(pool: str, page: int, t: int) -> None:
+    args = paged_inputs(pool, page, t)
+    case("paged_attend", {"out": (attend("flash", args), attend("xla", args))},
+         pool=pool, page=page, T=t)
+
+
+def sabotaged(mode: str):
+    real = kv_pages.paged_flash_attend
+
+    def wrapped(q, k_pages, v_pages, tables, lengths, **kw):
+        if mode == "swap_k_v":
+            k_pages, v_pages = v_pages, k_pages
+        elif mode == "wrong_pages":
+            tables = jnp.roll(tables, 1, axis=1)
+        elif mode == "drop_newest":
+            lengths = jnp.maximum(lengths - 1, 0)
+        out = real(q, k_pages, v_pages, tables, lengths, **kw)
+        return jnp.zeros_like(out) if mode == "zeros" else out
+
+    return wrapped
+
+
+def controls() -> int:
+    """The bound must refuse every sabotaged kernel; returns how many it
+    did. (Not a ``case``: a refusal here is the pass.)"""
+    args = paged_inputs("fp32", 16, 1)
+    want = attend("xla", args)
+    refused = 0
+    real = kv_pages.paged_flash_attend
+    for mode in ("zeros", "swap_k_v", "wrong_pages", "drop_newest"):
+        kv_pages.paged_flash_attend = sabotaged(mode)
+        try:
+            err, ref = worst(attend("flash", args), want)
+        finally:
+            kv_pages.paged_flash_attend = real
+        caught = err > RTOL * max(1.0, ref)
+        refused += caught
+        print(json.dumps({"control": mode, "max_abs_err": err, "ref_max": ref,
+                          "rtol": RTOL, "refused": caught}), flush=True)
+    return refused
+
+
+# ---- the training attention --------------------------------------------------
+
+def flash_case(**kw) -> None:
+    q = normal((BATCH, SEQ, HQ, D), jnp.bfloat16)
+    k, v = (normal((BATCH, SEQ, HKV, D), jnp.bfloat16) for _ in range(2))
+
+    def run(impl):
+        def loss(q, k, v):
+            out = multihead_attention(q, k, v, impl=impl, **kw)
+            return (out.astype(jnp.float32) ** 2).sum(), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return out, grads
+
+    (out_f, g_f), (out_x, g_x) = run("flash"), run("xla")
+    case("flash_attention fwd+bwd",
+         {"out": (out_f, out_x), "dq": (g_f[0], g_x[0]),
+          "dk": (g_f[1], g_x[1]), "dv": (g_f[2], g_x[2])},
+         seq=SEQ, heads=f"{HQ}/{HKV}", head_dim=D, **kw)
+
+
+# ---- off the smoke's path (--all) -------------------------------------------
+
+def gmm_case(dtype) -> None:
+    gm = importlib.import_module(
+        "distributed_training_guide_tpu.ops.grouped_matmul")
+    rows, k, n, groups = 4096, 2048, 768, 128
+    sizes = jnp.asarray(RNG.multinomial(rows - 100, np.ones(groups) / groups),
+                        jnp.int32)
+    lhs = normal((rows, k), dtype)
+    rhs = normal((groups, k, n), dtype) * jnp.asarray(0.05, dtype)
+
+    def run(impl):
+        def loss(lhs, rhs):
+            out = gm.grouped_matmul(lhs, rhs, sizes, impl=impl)
+            return (out.astype(jnp.float32) ** 2).sum(), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(lhs, rhs)
+        return out, grads
+
+    (out_p, g_p), (out_e, g_e) = run("pallas"), run("einsum")
+    case("gmm/tgmm", {"out": (out_p, out_e), "dlhs": (g_p[0], g_e[0]),
+                      "drhs": (g_p[1], g_e[1])},
+         dtype=jnp.dtype(dtype).name, hidden=k, expert_width=n, experts=groups)
+
+
+def int8_matmul_case() -> None:
+    qm = importlib.import_module(
+        "distributed_training_guide_tpu.ops.quantized_matmul")
+    k, n, block = 1024, 3072, 128
+    w = RNG.standard_normal((k, n)).astype(np.float32).reshape(k, n // block,
+                                                               block)
+    scale = np.abs(w).max(-1) / 127.0
+    payload = np.clip(np.round(w / scale[..., None]), -127, 127)
+    weight = SimpleNamespace(q=jnp.asarray(payload.reshape(k, n), jnp.int8),
+                             scale=jnp.asarray(scale, jnp.float32))
+    x = normal((8, k), jnp.float32)
+    got, want = (jax.jit(lambda x, impl=impl: qm.quantized_matmul(
+        x, weight, impl=impl))(x) for impl in ("pallas", "xla"))
+    case("quantized_matmul", {"out": (got, want)}, k=k, n=n, block=block)
+
+
+def main(argv) -> int:
+    everything = argv == ["--all"]
+    if argv and not everything:
+        raise SystemExit("usage: kernel_parity.py [--all]")
+    print_device_line("attend", ("flash", "forced"), CACHE.directory)
+    if jax.devices()[0].platform != EXPECT_PLATFORM:
+        print(f"kernel_parity FAILED: runs on "
+              f"{jax.devices()[0].platform!r}, not {EXPECT_PLATFORM!r}",
+              file=sys.stderr)
+        return 1
+    for t in (1, 64):
+        paged_case("fp32", 16, t)
+    flash_case()
+    if everything:
+        for pool in ("fp32", "bf16", "int8"):
+            for page in (16, 32):
+                for t in (1, 5, 64):
+                    if (pool, page, t) not in (("fp32", 16, 1),
+                                               ("fp32", 16, 64)):
+                        paged_case(pool, page, t)
+        flash_case(window=512)
+        flash_case(logit_softcap=30.0)
+        for dtype in (jnp.bfloat16, jnp.float32):
+            gmm_case(dtype)
+        int8_matmul_case()
+    refused = controls()
+    CACHE.print_line()
+    if FAILED or refused != 4:
+        print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
+              f"sabotaged kernels refused: {refused} of 4", file=sys.stderr)
+        return 1
+    print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
+                      "controls_refused": refused}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -32,10 +32,6 @@ CH02 = REPO / "02-distributed-data-parallel" / "train_llm.py"
 
 pytestmark = pytest.mark.chaos
 
-# shared with tests/test_multiprocess.py so compiles amortize across suites
-MP_COMPILE_CACHE = os.path.join(
-    os.environ.get("TMPDIR", "/tmp"), "dtg_tpu_mp_compile_cache")
-
 TRAIN_FLAGS = ["-m", "llama-debug", "-d", "synthetic:60000", "-s", "64",
                "-b", "1", "--num-epochs", "2", "--log-freq", "1"]
 
@@ -43,9 +39,10 @@ TRAIN_FLAGS = ["-m", "llama-debug", "-d", "synthetic:60000", "-s", "64",
 def _env(**extra):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # the children share the suite's compile cache: the environment goes
+    # through, and utils/compile_cache.py decides the directory
     env.update(JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               JAX_COMPILATION_CACHE_DIR=MP_COMPILE_CACHE)
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
     env.update(extra)
     return env
 
@@ -67,7 +64,6 @@ def losses_by_step(text: str) -> dict:
 
 
 def run_ch02(flags, *, env_extra=None, timeout=420):
-    os.makedirs(MP_COMPILE_CACHE, exist_ok=True)
     proc = subprocess.run([sys.executable, str(CH02), *TRAIN_FLAGS, *flags],
                           capture_output=True, text=True, timeout=timeout,
                           cwd=REPO, env=_env(**(env_extra or {})))
@@ -94,7 +90,6 @@ def test_sigkill_restart_resume_matches_uninterrupted(tmp_path):
            sys.executable, str(CH02), *TRAIN_FLAGS,
            "--max-steps", "6", "--ckpt-freq", "2",
            "-e", "drill", "--save-dir", str(work)]
-    os.makedirs(MP_COMPILE_CACHE, exist_ok=True)
     proc = subprocess.run(
         cmd, capture_output=True, text=True, timeout=600, cwd=REPO,
         env=_env(**{faults.ENV_CRASH_STEP: "4"}))

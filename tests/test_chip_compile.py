@@ -1,0 +1,192 @@
+"""Compile every Pallas kernel of the main paths for a DESCRIBED TPU v5e.
+
+The one file in the suite that describes a chip. The TPU's compiler is
+installed here and compiles for a ``v5e:2x2`` that is described and not
+attached, so what it refuses (a block shape Mosaic cannot tile, more VMEM
+than a kernel may plan for, a shape cast it has no layout for) is found at
+no chip time. Every case passes ``interpret=False`` explicitly — under
+``JAX_PLATFORMS=cpu`` an ``interpret=None`` default resolves to the
+interpreter, which would test nothing — and asserts that the kernel is in
+the compiled program (``tpu_custom_call``).
+
+Shapes are real ones: qwen3-0.6b's attention (seq 2048, 16/8 heads of 128),
+the serve CLI's page sizes for fp32, bf16 and int8 pools at T=1 (decode),
+T=5 (spec verify) and one prefill-chunk size, qwen3-30b-a3b's expert GEMMs
+(hidden 2048 x expert width 768) and an int8 projection (1024 x 3072).
+
+A pass here is a compile, never a run: nothing executes, and no result or
+time comes out of it.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load libtpu, and every xdist worker imports every test
+file), compiles happen in this process, and the persistent compile cache is
+off around them (an entry compiled for a described chip cannot be read back
+without one, and would warn on every later run).
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_training_guide_tpu.ops.flash_attention import flash_attention
+from distributed_training_guide_tpu.ops.paged_decode import (
+    paged_decode_eligible, paged_flash_attend)
+
+# ops/__init__ re-exports the grouped_matmul FUNCTION under the module's name
+gmm_mod = importlib.import_module(
+    "distributed_training_guide_tpu.ops.grouped_matmul")
+qmm_mod = importlib.import_module(
+    "distributed_training_guide_tpu.ops.quantized_matmul")
+
+SEQ, HQ, HKV, D = 2048, 16, 8, 128          # qwen3-0.6b attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip_compile(one_chip):
+    """``compile(fn, *(shape, dtype))`` -> compiled HLO text for the chip,
+    with the persistent cache off while this module's tests run."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *specs):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+# ---- training attention ----------------------------------------------------
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("extras", [{}, {"window": 512},
+                                    {"logit_softcap": 30.0}],
+                         ids=["causal", "banded", "softcap"])
+def test_flash_attention_compiles(chip_compile, extras, backward):
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               **extras)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    qs = ((2, SEQ, HQ, D), jnp.bfloat16)
+    ks = ((2, SEQ, HKV, D), jnp.bfloat16)
+    text = chip_compile(fwd_bwd if backward else fwd, qs, ks, ks)
+    assert "tpu_custom_call" in text
+
+
+# ---- the serve attend: decode, verify, chunk ------------------------------
+
+@pytest.mark.parametrize("t", [1, 5, 64], ids=["decode", "verify", "chunk"])
+@pytest.mark.parametrize("pool,page", [
+    ("fp32", 16), ("fp32", 32), ("bf16", 16), ("bf16", 32),
+    ("int8", 16), ("int8", 32)])
+def test_paged_attend_compiles(chip_compile, pool, page, t):
+    """The CLI's default ``--page-size 16`` and its int8 advice of 32, for
+    every pool dtype: the page axis is a whole-dimension block and the head
+    a 128-lane window of the ``[P, page, Hkv*D]`` pool view, which Mosaic
+    tiles for all three payloads."""
+    assert paged_decode_eligible(D, page)
+    n_slots, n_pages, table = 4, 128, 32
+    q_dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    pool_dtype = {"fp32": jnp.float32, "bf16": jnp.bfloat16,
+                  "int8": jnp.int8}[pool]
+    specs = [((n_slots, t, HQ, D), q_dtype),
+             ((n_pages, page, HKV, D), pool_dtype),
+             ((n_pages, page, HKV, D), pool_dtype),
+             ((n_slots, table), jnp.int32), ((n_slots,), jnp.int32)]
+    if pool == "int8":
+        specs += [((n_pages, page, HKV), jnp.float32)] * 2
+
+        def attend(q, k, v, tabs, lens, ks, vs):
+            return paged_flash_attend(q, k, v, tabs, lens, k_scale=ks,
+                                      v_scale=vs, interpret=False)
+    else:
+        def attend(q, k, v, tabs, lens):
+            return paged_flash_attend(q, k, v, tabs, lens, interpret=False)
+
+    assert "tpu_custom_call" in chip_compile(attend, *specs)
+
+
+def test_paged_attend_gate_matches_the_compiler(chip_compile):
+    """Where the gate says no, the compiler says no, and the forced path
+    raises the gate's own error first: head_dim 64 is half a lane tile of
+    the ``[P, page, Hkv*D]`` view."""
+    assert not paged_decode_eligible(64, 16)
+    specs = [((4, 1, 16, 64), jnp.float32), ((64, 16, 8, 64), jnp.float32),
+             ((64, 16, 8, 64), jnp.float32), ((4, 8), jnp.int32),
+             ((4,), jnp.int32)]
+    with pytest.raises(ValueError, match="head_dim % 128"):
+        chip_compile(lambda *a: paged_flash_attend(*a, interpret=False),
+                     *specs)
+
+
+# ---- MoE expert GEMMs and the int8 projection ------------------------------
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_grouped_matmul_compiles_forward_and_backward(chip_compile, dtype):
+    """``gmm`` forward plus the backward's ``gmm`` (d_lhs) and ``tgmm``
+    (d_rhs) at hidden 2048 x expert width 768, 128 experts. The blocks are
+    fitted to the scoped-VMEM budget (512x512 fp32 blocks at K=2048 are
+    refused: 19 MiB of the 16 MiB a kernel may plan for)."""
+    m, k, n, g = 8192, 2048, 768, 128
+
+    def fwd_bwd(lhs, rhs, sizes):
+        def loss(lhs, rhs):
+            out = gmm_mod.grouped_matmul(lhs, rhs, sizes, impl="pallas",
+                                         interpret=False)
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        return jax.grad(loss, argnums=(0, 1))(lhs, rhs)
+
+    text = chip_compile(fwd_bwd, ((m, k), dtype), ((g, k, n), dtype),
+                        ((g,), jnp.int32))
+    assert text.count("tpu_custom_call") >= 3       # gmm, gmm^T, tgmm
+
+
+def test_quantized_matmul_compiles_and_refuses_narrow_blocks(chip_compile):
+    """The int8 dequant matmul at 1024 x 3072: 128-wide blocks compile; the
+    32-wide blocks ``serve/weights.py`` stores are not a lane tile, so the
+    forced path raises (and ``auto`` says it took XLA)."""
+    from types import SimpleNamespace
+
+    k, n = 1024, 3072
+
+    def matmul(x, q, scale):
+        return qmm_mod.quantized_matmul(x, SimpleNamespace(q=q, scale=scale),
+                                        impl="pallas", interpret=False)
+
+    specs = lambda bs: (((8, k), jnp.float32), ((k, n), jnp.int8),
+                        ((k, n // bs), jnp.float32))
+    assert "tpu_custom_call" in chip_compile(matmul, *specs(128))
+    with pytest.raises(ValueError, match="block width % 128"):
+        chip_compile(matmul, *specs(32))

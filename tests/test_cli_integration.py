@@ -37,6 +37,37 @@ def test_run_training_ddp(tmp_path, eight_devices):
     assert out["last_info"]["tokens_per_s"] > 0
 
 
+@pytest.mark.parametrize("attn_impl,traced", [("flash", "flash"),
+                                              ("auto", "xla")])
+def test_start_up_lines_describe_the_step_that_runs(tmp_path, eight_devices,
+                                                    capsys, attn_impl, traced):
+    """The JSON lines chip_smoke.py holds a run to. The device line names
+    the attention implementation the lowered step TRACED (not one re-derived
+    from the flags), the step is compiled once ahead of time and that
+    executable is the one described (compile seconds; on a mesh its
+    collectives) and the one that takes the steps, and after the first step
+    every device's bytes_in_use is printed."""
+    import json
+
+    args = make_args(tmp_path, attn_impl=attn_impl, batch_size=1)
+    out = run_training(args, lambda: make_plan(
+        "fsdp", make_mesh(fsdp=4, devices=eight_devices[:4])))
+    assert out["host_state"]["global_step"] == 4
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith("{")]
+    device = next(l for l in lines if "device" in l)
+    assert device["device"] == {"platform": "cpu", "device_kind": "cpu",
+                                "count": len(jax.devices())}
+    assert device["attention"]["impl"] == traced
+    program = next(l["step_program"] for l in lines if "step_program" in l)
+    assert program["compile_s"] > 0
+    assert program["collectives"]["counts"]["all-gather"] > 0
+    assert isinstance(program["collectives"]["largest_all_reduce_bytes"], int)
+    memory = next(l["device_memory"] for l in lines if "device_memory" in l)
+    assert [d["id"] for d in memory] == [d.id for d in jax.local_devices()]
+    assert sum("step_program" in l for l in lines) == 1
+
+
 def test_run_training_sliding_window_flag(tmp_path, eight_devices):
     """--sliding-window W overrides the model config and trains through the
     banded attention; loss differs from the full-causal run (the band binds)."""

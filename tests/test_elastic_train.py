@@ -395,8 +395,6 @@ def test_worker_world_env_forces_device_count():
 # incarnations at different device counts + a golden run)
 # ---------------------------------------------------------------------------
 
-MP_COMPILE_CACHE = os.path.join(
-    os.environ.get("TMPDIR", "/tmp"), "dtg_tpu_mp_compile_cache")
 CH02 = REPO / "02-distributed-data-parallel" / "train_llm.py"
 TRAIN_FLAGS = ["-m", "llama-debug", "-d", "synthetic:60000", "-s", "64",
                "--num-epochs", "2", "--log-freq", "1"]
@@ -421,8 +419,7 @@ def _losses_by_step(text: str) -> dict:
 def _drill_env(**extra):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
-    env.update(JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=MP_COMPILE_CACHE)
+    env.update(JAX_PLATFORMS="cpu")
     env.update(extra)
     return env
 
@@ -441,7 +438,6 @@ def test_supervisor_slice_loss_renegotiates_and_resumes(tmp_path):
     trajectory (rtol covers the cross-mesh reduction-order change), and
     elastic.jsonl must record the 2->1 membership timeline."""
     n_steps = 60        # checkpoint-every-2 pacing keeps the run long
-    os.makedirs(MP_COMPILE_CACHE, exist_ok=True)
     # golden: uninterrupted 8-device run at global batch 8 (no -e, so no
     # checkpoint I/O — pure trajectory)
     golden_proc = subprocess.run(

@@ -224,38 +224,39 @@ def test_int8_flash_kernel_matches_int8_gather():
                                    - np.asarray(ref32)))) < ATTEND_ATOL
 
 
-def test_int8_flash_ineligible_page_size_warns_at_construction(llama):
-    """An int8 pool whose page_size can't take the compiled kernel's
-    int8 tiles (page % 32) must say so when the engine is BUILT — on TPU
-    'auto' would otherwise silently run the ~3x-traffic gather path at
-    the default page_size=16, contradicting the in-kernel-dequant pitch.
-    It fires only when int8 REGRESSED eligibility: a head_dim the fp32
-    kernel couldn't tile either (the debug models) never had flash to
-    lose, and an explicit attend_impl='xla' is a gather choice."""
-    import warnings
+@pytest.mark.parametrize("impl,head_dim,page,backend,want", [
+    ("auto", 128, 16, "tpu", "flash"),
+    ("auto", 64, 16, "tpu", "xla"),       # head_dim fails the kernel's gate
+    ("auto", 128, 16, "cpu", "xla"),      # off-TPU the gather path is faster
+    ("flash", 16, 16, "cpu", "flash"),    # interpreted: any shape
+    ("xla", 128, 16, "tpu", "xla"),
+    ("flash", 64, 16, "tpu", ValueError),  # forced + ineligible: loud
+    ("pallas", 128, 16, "tpu", ValueError),
+])
+def test_resolve_attend_impl(monkeypatch, impl, head_dim, page, backend, want):
+    """The one place the paged attend family is chosen: 'auto' says which
+    path it took and why, and a forced 'flash' the compiled kernel cannot
+    take raises at engine construction instead of quietly gathering."""
+    from distributed_training_guide_tpu.serve import kv_pages
 
-    from distributed_training_guide_tpu.serve.kv_pages import \
-        check_kv_page_geometry
+    monkeypatch.setattr(kv_pages.jax, "default_backend", lambda: backend)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="attend_impl"):
+            kv_pages.resolve_attend_impl(impl, head_dim, page)
+        return
+    got, reason = kv_pages.resolve_attend_impl(impl, head_dim, page)
+    assert got == want and reason
+    assert reason.startswith("auto" if impl == "auto" else "forced")
 
-    big = type("C", (), {"head_size": 128, "num_heads": 8,
-                         "dtype": jnp.float32})()
-    with pytest.warns(UserWarning, match="page_size % 32"):
-        check_kv_page_geometry(big, page_size=16, kv_dtype="int8",
-                               attend_impl="auto")
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        # eligible page, explicit gather, fp32 pool: all silent
-        check_kv_page_geometry(big, page_size=32, kv_dtype="int8",
-                               attend_impl="auto")
-        check_kv_page_geometry(big, page_size=16, kv_dtype="int8",
-                               attend_impl="xla")
-        check_kv_page_geometry(big, page_size=16, kv_dtype=None,
-                               attend_impl="auto")
-        # and through the engine: llama-debug's head_dim 16 never had the
-        # compiled kernel, so its int8 engines build without noise
-        bundle, params = llama
+
+def test_int8_engine_builds_on_debug_geometry(llama):
+    """llama-debug's head_dim 16 never had the compiled kernel, so its int8
+    engines build under 'auto' and under a forced 'flash' (interpreted
+    off-TPU)."""
+    bundle, params = llama
+    for impl in ("auto", "flash"):
         ServeEngine(bundle, params, n_slots=1, page_size=16, max_len=64,
-                    kv_dtype="int8")
+                    kv_dtype="int8", attend_impl=impl)
 
 
 def test_paged_flash_decode_scale_validation_and_eligibility():
@@ -265,10 +266,11 @@ def test_paged_flash_decode_scale_validation_and_eligibility():
                            jnp.zeros((1, 2), jnp.int32),
                            jnp.zeros(1, jnp.int32),
                            k_scale=jnp.zeros((4, 4, 2)), interpret=True)
-    # int8 compiled tiles are stricter on the sublane (page) axis
-    assert paged_decode_eligible(64, 32, quantized=True)
-    assert not paged_decode_eligible(64, 16, quantized=True)
-    assert paged_decode_eligible(64, 16, quantized=False)
+    # one gate for float and int8 pools: the page axis is a whole-dimension
+    # block, which the chip's compiler tiles for either payload
+    # (tests/test_chip_compile.py compiles int8 at page 16)
+    assert paged_decode_eligible(128, 16)
+    assert not paged_decode_eligible(64, 16)
 
 
 # ---- scale lifecycle -------------------------------------------------------

@@ -27,27 +27,13 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 from pathlib import Path
 
 import jax
-import pytest
-
-# jax < 0.5 hard-fails any sharded computation spanning processes on CPU
-# ("INVALID_ARGUMENT: Multiprocess computations aren't implemented on the
-# CPU backend" out of the first jitted program) — the gang-TRAINING tests
-# cannot pass there and each burns a full gang spawn before failing, starving
-# the rest of the tier-1 time budget. Barrier/loader scenarios (no sharded
-# compute) still run. Drop this gate when the environment's jax moves >= 0.5.
-_JAX_VERSION = tuple(int(p) for p in jax.__version__.split(".")[:2])
-requires_mp_compute = pytest.mark.skipif(
-    _JAX_VERSION < (0, 5),
-    reason="jax<0.5 CPU backend cannot run multiprocess computations")
 
 REPO = Path(__file__).parent.parent
 CH02 = REPO / "02-distributed-data-parallel" / "train_llm.py"
 CH04 = REPO / "04-fully-sharded-data-parallel" / "train_llm.py"
-MP_COMPILE_CACHE = os.path.join(tempfile.gettempdir(), "dtg_tpu_mp_compile_cache")
 
 TRAIN_FLAGS = ["-m", "llama-debug", "-d", "synthetic:60000", "-s", "64",
                "-b", "1", "--num-epochs", "2", "--log-freq", "1"]
@@ -55,10 +41,10 @@ TRAIN_FLAGS = ["-m", "llama-debug", "-d", "synthetic:60000", "-s", "64",
 
 def _clean_env(**extra) -> dict:
     """Worker env: the launcher overrides the conftest's 8-device XLA_FLAGS
-    with per-process counts; the shared compile cache spans gangs."""
+    with per-process counts; the compile cache is the suite's own
+    (utils/compile_cache.py decides the directory in every process)."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
-    env["JAX_COMPILATION_CACHE_DIR"] = MP_COMPILE_CACHE
     env.update(extra)
     return env
 
@@ -113,11 +99,6 @@ def mp_results(text: str) -> list:
             for line in text.splitlines() if line.startswith("MPRESULT ")]
 
 
-@pytest.fixture(scope="module")
-def warm_cache():
-    os.makedirs(MP_COMPILE_CACHE, exist_ok=True)
-
-
 def single_process_losses(script, flags: list, save_dir) -> dict:
     """Golden: the same chapter entry on 1 process x 8 virtual devices."""
     sp = subprocess.run(
@@ -129,8 +110,7 @@ def single_process_losses(script, flags: list, save_dir) -> dict:
     return losses_by_step(sp.stdout + sp.stderr)
 
 
-@requires_mp_compute
-def test_gang_ddp_matches_single_process(tmp_path, warm_cache):
+def test_gang_ddp_matches_single_process(tmp_path):
     """2 procs x 4 devices and 1 proc x 8 devices build the same dp=8 mesh
     over the same global batch: the logged loss trajectory must agree. This
     is the process-layout invariance the reference gets from DDP's defined
@@ -156,8 +136,7 @@ def test_gang_ddp_matches_single_process(tmp_path, warm_cache):
         assert abs(loss - sp_losses[step]) < 1e-4, (step, loss, sp_losses[step])
 
 
-@requires_mp_compute
-def test_gang_fence_every_matches_per_step(tmp_path, warm_cache):
+def test_gang_fence_every_matches_per_step(tmp_path):
     """--fence-every across a REAL process boundary: each process banks its
     own device-loss reads and drains at the (log-freq) boundary; the logged
     running_loss windows must equal a per-step-fenced single-process run.
@@ -179,8 +158,7 @@ def test_gang_fence_every_matches_per_step(tmp_path, warm_cache):
         assert abs(loss - sp_losses[step]) < 1e-4, (step, loss, sp_losses)
 
 
-@requires_mp_compute
-def test_gang_fsdp_trains_with_cross_process_shards(tmp_path, warm_cache):
+def test_gang_fsdp_trains_with_cross_process_shards(tmp_path):
     """fsdp shards every parameter over all 8 devices, i.e. ACROSS the two
     processes: init, step collectives, and the loader all have to handle
     arrays where each process owns only half the shards."""
@@ -196,8 +174,7 @@ def test_gang_fsdp_trains_with_cross_process_shards(tmp_path, warm_cache):
     assert "strategy=fsdp" in rank0
 
 
-@requires_mp_compute
-def test_gang_tp_spans_process_boundary(tmp_path, warm_cache):
+def test_gang_tp_spans_process_boundary(tmp_path):
     """tp=8 on a 2-process x 4-device gang: every tensor-parallel group
     crosses the process boundary, so the per-layer megatron all-reduces run
     over the inter-process transport (the DCN analogue) — the sharding
@@ -214,8 +191,7 @@ def test_gang_tp_spans_process_boundary(tmp_path, warm_cache):
     assert "'tp': 8" in rank0
 
 
-@requires_mp_compute
-def test_gang_ring_cp_spans_process_boundary(tmp_path, warm_cache):
+def test_gang_ring_cp_spans_process_boundary(tmp_path):
     """cp=8 on a 2-process x 4-device gang: the zigzag ring's ppermute hops
     cross the process boundary every cycle — the long-context regime a
     real pod runs (ring over ICI/DCN), never reachable single-process."""
@@ -231,8 +207,7 @@ def test_gang_ring_cp_spans_process_boundary(tmp_path, warm_cache):
     assert "'cp': 8" in rank0
 
 
-@requires_mp_compute
-def test_gang_pipeline_stage_per_process(tmp_path, warm_cache):
+def test_gang_pipeline_stage_per_process(tmp_path):
     """pp=2 on a 2-process x 4-device gang with the pp axis outermost:
     each pipeline stage lives on one process, so every 1F1B activation /
     cotangent handoff crosses the process boundary — how a pod actually
@@ -250,8 +225,7 @@ def test_gang_pipeline_stage_per_process(tmp_path, warm_cache):
     assert "'pp': 2" in rank0
 
 
-@requires_mp_compute
-def test_gang_moe_ep_spans_process_boundary(tmp_path, warm_cache):
+def test_gang_moe_ep_spans_process_boundary(tmp_path):
     """ep=8 on a 2-process x 4-device gang: the MoE token all-to-all
     dispatches across the process boundary (each process hosts half the
     experts). With ddp/fsdp (all-reduce/all-gather), tp (per-layer
@@ -272,8 +246,7 @@ def test_gang_moe_ep_spans_process_boundary(tmp_path, warm_cache):
     assert "'ep': 8" in rank0
 
 
-@requires_mp_compute
-def test_gang_checkpoint_resume_bitexact(tmp_path, warm_cache):
+def test_gang_checkpoint_resume_bitexact(tmp_path):
     """Multihost Orbax save (every process writes its shards, process 0
     swings state.json behind a barrier) + restore in a FRESH gang, compared
     bit-exact against an uninterrupted run — the reference's resume contract
@@ -303,7 +276,7 @@ def test_gang_checkpoint_resume_bitexact(tmp_path, warm_cache):
         assert resumed[step] == golden[step], (step, resumed[step], golden[step])
 
 
-def test_gang_procguards_ordering(tmp_path, warm_cache):
+def test_gang_procguards_ordering(tmp_path):
     """process0_first over real processes: rank 1 must observe the file rank
     0 wrote inside the guard, despite rank 0 sleeping first."""
     worker = [sys.executable, str(REPO / "tests" / "mp_worker.py"), "guard",
@@ -315,7 +288,7 @@ def test_gang_procguards_ordering(tmp_path, warm_cache):
     assert results[1]["saw_marker_on_entry"] is True
 
 
-def test_gang_loader_materializes_only_local_shards(tmp_path, warm_cache):
+def test_gang_loader_materializes_only_local_shards(tmp_path):
     """The per-host data-footprint claim, measured: over a full epoch each
     process fetches exactly its 1/nproc share of every batch's rows from the
     corpus (so a disk-backed corpus costs each host ~batch/nproc RAM), and
@@ -333,8 +306,7 @@ def test_gang_loader_materializes_only_local_shards(tmp_path, warm_cache):
         assert r["rows_fetched"] == r["n_batches"] * r["global_batch"] // 2
 
 
-@requires_mp_compute
-def test_supervisor_restarts_gang_and_resumes(tmp_path, warm_cache):
+def test_supervisor_restarts_gang_and_resumes(tmp_path):
     """The torchrun-elasticity loop end to end: rank 1 crashes after the
     step-3 checkpoint; fail-fast takes the gang down; the supervisor
     restarts it as a unit; the restarted gang resumes from the checkpoint

@@ -133,9 +133,9 @@ def test_kernel_validates_bad_static_window_and_tiles():
         paged_flash_decode(q, jnp.asarray(k_pages), jnp.asarray(v_pages),
                            jnp.asarray(tables), jnp.zeros(1, jnp.int32),
                            window=0, interpret=True)
-    assert paged_decode_eligible(64, 8)
-    assert not paged_decode_eligible(8, 8)      # head_dim not tiled
-    assert not paged_decode_eligible(64, 4)     # page not tiled
+    assert paged_decode_eligible(128, 8)
+    assert not paged_decode_eligible(64, 8)     # head_dim is a lane block
+    assert not paged_decode_eligible(128, 4)    # page not tiled
 
 
 def test_paged_attend_flash_matches_xla_dispatch():

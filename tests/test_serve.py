@@ -825,10 +825,17 @@ def test_serve_cli_offline_batch(capsys):
           "--page-size", "4", "--max-len", "16"])
     lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
              if l.startswith("{")]
-    assert "kv_report" in lines[0]
+    # first the device line (what the run is on, which attend resolved and
+    # why), last the compile-cache use; the reports and results between
+    assert lines[0]["device"]["platform"] == "cpu"
+    assert lines[0]["attend"] == {"impl": "xla",
+                                  "reason": "auto: backend is cpu, not tpu"}
+    assert "kv_report" in lines[1]
     results = [l for l in lines if "token_ids" in l]
     assert len(results) == 2
     assert all(len(r["token_ids"]) == len(p) + 4
                for r, p in zip(results, ([3, 17, 42], [5, 6])))
-    stats = lines[-1]["stats"]
+    assert set(lines[-1]["compile_cache_use"]) == {"directory", "hits",
+                                                   "misses"}
+    stats = lines[-2]["stats"]
     assert stats["n_requests"] == 2 and stats["generated_tokens"] == 8
