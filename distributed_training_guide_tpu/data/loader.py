@@ -24,6 +24,8 @@ from typing import Iterator, Optional
 import jax
 import numpy as np
 
+from ..utils.trace import span
+
 
 class ShardedBatchLoader:
     def __init__(
@@ -80,8 +82,9 @@ class ShardedBatchLoader:
         """Global array from an already-assembled host batch (the native
         path: the C++ loader hands back the full batch by contract)."""
         np_batch = np_batch.reshape(self._leading_shape() + np_batch.shape[-1:])
-        return jax.make_array_from_callback(
-            np_batch.shape, self.sharding, lambda idx: np_batch[idx])
+        with span("data.put"):
+            return jax.make_array_from_callback(
+                np_batch.shape, self.sharding, lambda idx: np_batch[idx])
 
     def _assemble_batch(self, idx: np.ndarray) -> jax.Array:
         """Global array materializing ONLY the rows this process's devices
@@ -98,12 +101,14 @@ class ShardedBatchLoader:
         seq = self.dataset.shape[1]
 
         def fetch(shard_index):
-            sel = idx_nd[shard_index[:-1]]
-            rows = np.asarray(self.dataset[sel.ravel()], dtype=np.int32)
-            return rows.reshape(sel.shape + (seq,))[..., shard_index[-1]]
+            with span("data.assemble"):   # a child of data.put: the callback
+                sel = idx_nd[shard_index[:-1]]
+                rows = np.asarray(self.dataset[sel.ravel()], dtype=np.int32)
+                return rows.reshape(sel.shape + (seq,))[..., shard_index[-1]]
 
-        return jax.make_array_from_callback(
-            idx_nd.shape + (seq,), self.sharding, fetch)
+        with span("data.put"):
+            return jax.make_array_from_callback(
+                idx_nd.shape + (seq,), self.sharding, fetch)
 
     def _native_compatible_backing(self):
         """Path of the dataset's own backing file when the C++ loader can
@@ -167,7 +172,12 @@ class ShardedBatchLoader:
             # same pending-queue H2D overlap as the python path, on top of the
             # C++ assembly prefetch
             pending: list[dict] = []
-            for np_batch in self._native.epoch_batches(self.epoch, start_step):
+            native = iter(self._native.epoch_batches(self.epoch, start_step))
+            while True:
+                with span("data.assemble"):   # the wait for the C++ workers
+                    np_batch = next(native, None)
+                if np_batch is None:
+                    break
                 ids = self._make_global_array(np_batch)
                 pending.append({"input_ids": ids, "labels": ids})
                 if len(pending) > self.prefetch:

@@ -115,6 +115,7 @@ def _layernorm(x, p, eps):
     return (y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)).astype(dtype)
 
 
+@jax.named_scope("attn")
 def _attn_sublayer(config, y, layer, positions, attn_impl,
                    standard_layout=True, kv_cache=None, return_kv=False,
                    attend_override=None):
@@ -164,6 +165,7 @@ def _attn_sublayer(config, y, layer, positions, attn_impl,
     return out
 
 
+@jax.named_scope("mlp")
 def _mlp_sublayer(config, y, layer):
     """ln2'd input -> gelu MLP (no residual, no psum, no row bias)."""
     cdt = config.dtype
@@ -199,6 +201,7 @@ def _block(config: GPT2Config, x, layer, positions, attn_impl,
     return x + y + layer["mlp"]["bo"].astype(cdt)
 
 
+@jax.named_scope("embed")
 def embed_tokens(config: GPT2Config, params: dict, input_ids: jnp.ndarray,
                  positions: jnp.ndarray) -> jnp.ndarray:
     """Token + learned-position embedding (pipeline stage-0 entry)."""
@@ -212,6 +215,7 @@ def output_weights(config: GPT2Config, params: dict) -> jnp.ndarray:
     return params["wte"].T.astype(config.dtype)
 
 
+@jax.named_scope("embed")
 def tp_embed(config: GPT2Config, params: dict, input_ids: jnp.ndarray,
              positions: jnp.ndarray, axis: str) -> jnp.ndarray:
     """Stage-0 embedding when tp is a manual axis: vocab-sharded token table
@@ -224,10 +228,12 @@ def tp_embed(config: GPT2Config, params: dict, input_ids: jnp.ndarray,
     return tok + pos
 
 
+@jax.named_scope("final_norm")
 def final_hidden(config: GPT2Config, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     return _layernorm(x, params["lnf"], config.layer_norm_eps)
 
 
+@jax.named_scope("loss_head")
 def lm_head_logits(config: GPT2Config, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     """Final LN + tied output projection (pipeline last-stage exit)."""
     return jnp.dot(final_hidden(config, params, x), output_weights(config, params),

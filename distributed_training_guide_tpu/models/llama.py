@@ -272,6 +272,7 @@ def attention_extras(config):
             getattr(config, "attn_logit_softcap", None))
 
 
+@jax.named_scope("attn")
 def attention_sublayer(config, x: jnp.ndarray, attn_params: dict, norm_scale,
                        positions: jnp.ndarray, attn_impl,
                        standard_layout: bool = True,
@@ -399,6 +400,7 @@ def attention_sublayer(config, x: jnp.ndarray, attn_params: dict, norm_scale,
     return out
 
 
+@jax.named_scope("mlp")
 def mlp_sublayer(config, x: jnp.ndarray, layer: dict,
                  tp_axis: Optional[str] = None,
                  wmat_override=None) -> jnp.ndarray:
@@ -443,11 +445,13 @@ def _block(config: LlamaConfig, x: jnp.ndarray, layer: dict,
         attn = attention_sublayer(config, x, layer["attn"], None,
                                   positions, attn_impl, standard_layout,
                                   tp_axis, window_override=window_override)
-        x = constrain(x + _rmsnorm(attn, layer["attn_out_norm"],
-                                   config.rms_norm_eps, plus_one))
+        with jax.named_scope("attn"):   # the output norm is the sublayer's
+            x = constrain(x + _rmsnorm(attn, layer["attn_out_norm"],
+                                       config.rms_norm_eps, plus_one))
         mlp = mlp_sublayer(config, x, layer, tp_axis)
-        return constrain(x + _rmsnorm(mlp, layer["mlp_out_norm"],
-                                      config.rms_norm_eps, plus_one))
+        with jax.named_scope("mlp"):
+            return constrain(x + _rmsnorm(mlp, layer["mlp_out_norm"],
+                                          config.rms_norm_eps, plus_one))
 
     if getattr(config, "sandwich_norm", False):   # Gemma-2 wiring: norms on
         # both sides of each sublayer; mlp_sublayer's pre-norm reads the
@@ -456,11 +460,13 @@ def _block(config: LlamaConfig, x: jnp.ndarray, layer: dict,
                                   layer["input_norm"], positions, attn_impl,
                                   standard_layout, tp_axis,
                                   window_override=window_override)
-        x = constrain(x + _rmsnorm(attn, layer["attn_out_norm"],
-                                   config.rms_norm_eps, plus_one))
+        with jax.named_scope("attn"):   # the output norm is the sublayer's
+            x = constrain(x + _rmsnorm(attn, layer["attn_out_norm"],
+                                       config.rms_norm_eps, plus_one))
         mlp = mlp_sublayer(config, x, layer, tp_axis)
-        return constrain(x + _rmsnorm(mlp, layer["mlp_out_norm"],
-                                      config.rms_norm_eps, plus_one))
+        with jax.named_scope("mlp"):
+            return constrain(x + _rmsnorm(mlp, layer["mlp_out_norm"],
+                                          config.rms_norm_eps, plus_one))
 
     attn = attention_sublayer(config, x, layer["attn"], layer["input_norm"],
                               positions, attn_impl, standard_layout, tp_axis,
@@ -469,6 +475,7 @@ def _block(config: LlamaConfig, x: jnp.ndarray, layer: dict,
     return constrain(x + mlp_sublayer(config, x, layer, tp_axis))
 
 
+@jax.named_scope("embed")
 def embed_tokens(config: LlamaConfig, params: dict, input_ids: jnp.ndarray,
                  positions: jnp.ndarray) -> jnp.ndarray:
     """Embedding sub-forward (pipeline stage-0 entry)."""
@@ -500,6 +507,7 @@ def _output_container(config: LlamaConfig, params: dict):
     return params["lm_head"], False
 
 
+@jax.named_scope("embed")
 def tp_embed(config: LlamaConfig, params: dict, input_ids: jnp.ndarray,
              positions: jnp.ndarray, axis: str) -> jnp.ndarray:
     """Stage-0 embedding when tp is a manual axis (pipeline schedule):
@@ -514,12 +522,14 @@ def tp_embed(config: LlamaConfig, params: dict, input_ids: jnp.ndarray,
     return x
 
 
+@jax.named_scope("final_norm")
 def final_hidden(config: LlamaConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     """Final norm only — pair with ``output_weights`` for chunked losses."""
     return _rmsnorm(x, params["final_norm"], config.rms_norm_eps,
                     getattr(config, "norm_plus_one", False))
 
 
+@jax.named_scope("loss_head")
 def lm_head_logits(config: LlamaConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     """Final norm + output projection (pipeline last-stage exit)."""
     w, transpose = _output_container(config, params)
@@ -601,7 +611,10 @@ def apply(
         policy = remat_policy or jax.checkpoint_policies.nothing_saveable
         scan_body = jax.checkpoint(scan_body, policy=policy, prevent_cse=False)
 
-    x, _ = jax.lax.scan(scan_body, x, scan_xs)
+    # the scan's own work (slicing the stacked leaves, stacking what the
+    # backward pass keeps and the weight gradients) is neither sublayer's
+    with jax.named_scope("layers"):
+        x, _ = jax.lax.scan(scan_body, x, scan_xs)
 
     if return_hidden:
         return final_hidden(config, params, x)
@@ -621,11 +634,13 @@ def _decode_residuals(config, x, layer, attn, wmat_override=None):
     plus_one = getattr(config, "norm_plus_one", False)
     if getattr(config, "post_norm", False) or getattr(config, "sandwich_norm",
                                                       False):
-        x = x + _rmsnorm(attn, layer["attn_out_norm"], config.rms_norm_eps,
-                         plus_one)
-        x = x + _rmsnorm(mlp_sublayer(config, x, layer,
-                                      wmat_override=wmat_override),
-                         layer["mlp_out_norm"], config.rms_norm_eps, plus_one)
+        with jax.named_scope("attn"):
+            x = x + _rmsnorm(attn, layer["attn_out_norm"],
+                             config.rms_norm_eps, plus_one)
+        mlp = mlp_sublayer(config, x, layer, wmat_override=wmat_override)
+        with jax.named_scope("mlp"):
+            x = x + _rmsnorm(mlp, layer["mlp_out_norm"], config.rms_norm_eps,
+                             plus_one)
     else:
         x = x + attn
         x = x + mlp_sublayer(config, x, layer, wmat_override=wmat_override)
@@ -722,11 +737,12 @@ def _scan_kv_layers(body, x, params, cache, wins):
     columns — the one adapter shared by every family's prefill/decode scans.
     ``wins`` None (uniform window config) scans without the window column so
     the traced program stays identical to the pre-schedule form."""
-    if wins is None:
-        return jax.lax.scan(lambda c, inp: body(c, (*inp, None)), x,
-                            (params["layers"], cache["k"], cache["v"]))
-    return jax.lax.scan(body, x,
-                        (params["layers"], cache["k"], cache["v"], wins))
+    with jax.named_scope("layers"):   # the scan's slicing of weights and pools
+        if wins is None:
+            return jax.lax.scan(lambda c, inp: body(c, (*inp, None)), x,
+                                (params["layers"], cache["k"], cache["v"]))
+        return jax.lax.scan(body, x,
+                            (params["layers"], cache["k"], cache["v"], wins))
 
 
 def init_cache(config: LlamaConfig, batch: int, max_len: int) -> dict:
@@ -778,8 +794,9 @@ def prefill(config: LlamaConfig, params: dict, input_ids: jnp.ndarray,
     if lora is None:
         x, (ks, vs) = _scan_kv_layers(body, x, params, cache, wins)
     else:
-        x, (ks, vs) = jax.lax.scan(body, x,
-                                   _lora_scan_xs(params, cache, wins, lora))
+        with jax.named_scope("layers"):
+            x, (ks, vs) = jax.lax.scan(
+                body, x, _lora_scan_xs(params, cache, wins, lora))
     # slice BEFORE the head: projecting all P positions to [B, P, V] fp32
     # only to keep one row would cost P x the lm_head matmul and a
     # prompt-length-scaled logits buffer (norm + projection are per-position)
@@ -900,8 +917,9 @@ def paged_decode_step(config: LlamaConfig, params: dict,
     if lora is None:
         x, (ks, vs) = _scan_kv_layers(body, x, params, cache, wins)
     else:
-        x, (ks, vs) = jax.lax.scan(body, x,
-                                   _lora_scan_xs(params, cache, wins, lora))
+        with jax.named_scope("layers"):
+            x, (ks, vs) = jax.lax.scan(
+                body, x, _lora_scan_xs(params, cache, wins, lora))
     return (paged_logits_at(lm_head_logits, config, params, x, last_index,
                             all_logits),
             {"k": ks, "v": vs})

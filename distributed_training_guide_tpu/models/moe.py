@@ -302,6 +302,7 @@ def _ragged_dispatch(config: MoELlamaConfig, xt: jnp.ndarray, topk_idx,
     return _ragged_combine(out_sorted, order, weight_flat, k, t, cdt)
 
 
+@jax.named_scope("experts")
 def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
              tp_axis: Optional[str] = None, no_drop: bool = False,
              moe_ep=None):
@@ -351,15 +352,18 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
     cdt = config.dtype
 
     xt = x.reshape(t, d)
-    router_logits = (xt.astype(jnp.float32)
-                     @ moe["router"].astype(jnp.float32))       # [T, E]
-    probs = jax.nn.softmax(router_logits, axis=-1)
+    with jax.named_scope("router"):
+        router_logits = (xt.astype(jnp.float32)
+                         @ moe["router"].astype(jnp.float32))   # [T, E]
+        probs = jax.nn.softmax(router_logits, axis=-1)
 
-    topk_probs, topk_idx = jax.lax.top_k(probs, k)               # [T, k]
-    if getattr(config, "norm_topk_prob", True):
-        # renormalize the chosen weights (Mixtral: always; Qwen3-MoE: the
-        # norm_topk_prob flag — off, the raw softmax mass is the weight)
-        topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1, keepdims=True)
+        topk_probs, topk_idx = jax.lax.top_k(probs, k)           # [T, k]
+        if getattr(config, "norm_topk_prob", True):
+            # renormalize the chosen weights (Mixtral: always; Qwen3-MoE:
+            # the norm_topk_prob flag — off, the raw softmax mass is the
+            # weight)
+            topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1,
+                                              keepdims=True)
 
     if dispatch == "ragged":
         if moe_ep is not None:
@@ -436,10 +440,11 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
     # Switch load-balance loss over ALL k dispatched choices (normalized by
     # k): E * sum_e (choice fraction)_e * (mean prob)_e — counting only the
     # first choice would never penalize second-choice hot spots
-    token_frac = jnp.mean(jax.nn.one_hot(topk_idx, ex, dtype=jnp.float32),
-                          axis=(0, 1))
-    prob_frac = jnp.mean(probs, axis=0)
-    aux = ex * jnp.sum(token_frac * prob_frac)
+    with jax.named_scope("router"):
+        token_frac = jnp.mean(
+            jax.nn.one_hot(topk_idx, ex, dtype=jnp.float32), axis=(0, 1))
+        prob_frac = jnp.mean(probs, axis=0)
+        aux = ex * jnp.sum(token_frac * prob_frac)
     return y.reshape(b, s, d), aux, dropped_frac
 
 
@@ -612,7 +617,8 @@ def _block(config: MoELlamaConfig, carry, layer: dict, positions, attn_impl,
                               window_override=window_override)
     x = x + attn
 
-    h = _rmsnorm(x, layer["post_attn_norm"], config.rms_norm_eps)
+    with jax.named_scope("experts"):   # the FFN's pre-norm is its own
+        h = _rmsnorm(x, layer["post_attn_norm"], config.rms_norm_eps)
     y, aux, dropped = _moe_ffn(config, h, layer["moe"], tp_axis,
                                moe_ep=moe_ep)
     return (x + y, aux_acc + aux, dropped_acc + dropped)
@@ -688,8 +694,9 @@ def apply_with_aux(
 
         scan_xs = (params["layers"] if wins is None
                    else (params["layers"], wins))
-        (x, aux, dropped), _ = jax.lax.scan(scan_body, (x, zero, zero),
-                                            scan_xs)
+        with jax.named_scope("layers"):   # the scan's own slicing and stacking
+            (x, aux, dropped), _ = jax.lax.scan(scan_body, (x, zero, zero),
+                                                scan_xs)
 
     out = (llama.final_hidden(config, params, x) if return_hidden
            else llama.lm_head_logits(config, params, x))

@@ -161,6 +161,7 @@ def _rope_partial(x: jnp.ndarray, positions: jnp.ndarray, config) -> jnp.ndarray
                             passthrough], axis=-1)
 
 
+@jax.named_scope("attn")
 def _attn_branch(config, y, layer, positions, attn_impl,
                  standard_layout=True, kv_cache=None, return_kv=False,
                  attend_override=None):
@@ -207,6 +208,7 @@ def _attn_branch(config, y, layer, positions, attn_impl,
     return out
 
 
+@jax.named_scope("mlp")
 def _mlp_branch(config, y, layer):
     """ln'd input -> gelu MLP (no residual, no psum, no row bias)."""
     cdt = config.dtype
@@ -257,6 +259,7 @@ def _block(config: NeoXConfig, x, layer, positions, attn_impl,
     return x + mlp + layer["mlp"]["bo"].astype(cdt)
 
 
+@jax.named_scope("embed")
 def embed_tokens(config: NeoXConfig, params: dict, input_ids: jnp.ndarray,
                  positions: jnp.ndarray) -> jnp.ndarray:
     """Token embedding (pipeline stage-0 entry); rope happens inside blocks."""
@@ -269,6 +272,7 @@ def output_weights(config: NeoXConfig, params: dict) -> jnp.ndarray:
     return params["embed_out"].astype(config.dtype)
 
 
+@jax.named_scope("embed")
 def tp_embed(config: NeoXConfig, params: dict, input_ids: jnp.ndarray,
              positions: jnp.ndarray, axis: str) -> jnp.ndarray:
     """Stage-0 embedding when tp is a manual axis: megatron vocab
@@ -280,10 +284,12 @@ def tp_embed(config: NeoXConfig, params: dict, input_ids: jnp.ndarray,
                                 input_ids, axis)
 
 
+@jax.named_scope("final_norm")
 def final_hidden(config: NeoXConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     return _layernorm(x, params["lnf"], config.layer_norm_eps)
 
 
+@jax.named_scope("loss_head")
 def lm_head_logits(config: NeoXConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     """Final LN + untied output projection (pipeline last-stage exit)."""
     return jnp.dot(final_hidden(config, params, x), output_weights(config, params),

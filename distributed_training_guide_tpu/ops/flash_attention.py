@@ -265,6 +265,7 @@ def _flash_fwd(q, k, v, causal, window, block_q, block_k, interpret,
         ],
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return o, lse[..., 0]
 
@@ -441,6 +442,7 @@ def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
         scratch_shapes=[_VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(*dq_args)
 
     # dk/dv: walk (b, kv-head, kv-block, group-member, q-block); q-side refs
@@ -479,6 +481,7 @@ def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         interpret=interpret,
+        name="flash_dkv",
     )(*dkv_args)
 
     return dq, dk, dv

@@ -233,6 +233,7 @@ def _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
+        name="gmm",
     )(offs, wg, wm, n_valid, lhs, rhs)
     # row-tiles past the last group are never visited (their memory is
     # whatever the buffer held); the contract says zeros
@@ -265,6 +266,7 @@ def _pallas_tgmm_raw(lhs, dy, group_sizes, g, out_dtype, bm, bn, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, k, n), out_dtype),
         interpret=interpret,
+        name="tgmm",
     )(offs, wg, wm, n_valid, lhs, dy)
     # empty groups own no work item, so their out block is never written
     return jnp.where((group_sizes > 0)[:, None, None], out, 0)

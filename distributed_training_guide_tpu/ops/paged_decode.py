@@ -318,6 +318,7 @@ def paged_flash_attend(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, hkv, tg, d), q.dtype),
         interpret=interpret,
+        name="paged_attend",
     )(lengths.astype(jnp.int32), tables.astype(jnp.int32), band, *operands)
     out = (out.reshape(s, hkv, t, groups, d)
               .transpose(0, 2, 1, 3, 4).reshape(s, t, hq, d))

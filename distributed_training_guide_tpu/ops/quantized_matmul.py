@@ -142,6 +142,7 @@ def _matmul_pallas(x2d: jax.Array, q: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((m, bs), lambda b: (0, b)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="qmm",
     )(x2d, q, scale)
 
 

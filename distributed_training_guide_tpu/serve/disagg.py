@@ -59,6 +59,7 @@ import queue as queue_mod
 from typing import Optional
 
 from ..models.registry import ModelBundle
+from ..utils.trace import span
 from .adapters import DEFAULT_TARGETS
 from .engine import (LatencyMeter, ModelPrograms, adapter_metrics,
                      advance_prefill_chunks, build_adapter_report,
@@ -329,8 +330,7 @@ class PrefillEngine:
         if not adm.resumed:
             t0 = self.programs.sample_one(logit, adm.request,
                                           len(adm.tokens))
-            res = sched.record_token(adm.slot_idx, int(t0),
-                                     from_decode=False)
+            res = sched.record_token(adm.slot_idx, t0, from_decode=False)
             if res is not None:            # finished on the first token
                 return res
         slot, submitted_at = sched.release_slot(adm.slot_idx)
@@ -985,18 +985,23 @@ class DisaggEngine:
                 "before swap_generation would decode old-policy k/v "
                 "under the new weights; run the swap first")
         self.stats_seq += 1
+        with span("serve.step", seq=self.stats_seq):
+            return self._iterate()
+
+    def _iterate(self) -> list[RequestResult]:
         if self.host_tier is not None:
-            self._restore_decode_queued()
-            p = self.prefill.sched
-            if p.queue and p.cache is not None:
-                head = p.queue[0].request
-                restore_prefixes(
-                    p.cache, self.host_tier, list(head.prompt_ids),
-                    ns=int(getattr(head, "adapter_id", 0) or 0),
-                    alloc=self._tier_alloc_prefill,
-                    scatter=lambda ids, payload: self.pages.update(
-                        scatter_payload(self.pages, ids, payload)),
-                    free=self.pool.free)
+            with span("serve.restore"):
+                self._restore_decode_queued()
+                p = self.prefill.sched
+                if p.queue and p.cache is not None:
+                    head = p.queue[0].request
+                    restore_prefixes(
+                        p.cache, self.host_tier, list(head.prompt_ids),
+                        ns=int(getattr(head, "adapter_id", 0) or 0),
+                        alloc=self._tier_alloc_prefill,
+                        scatter=lambda ids, payload: self.pages.update(
+                            scatter_payload(self.pages, ids, payload)),
+                        free=self.pool.free)
         finished = self.prefill.step()
         finished.extend(self._expire_in_transit())
         decoded, preempted = self.decode.step()

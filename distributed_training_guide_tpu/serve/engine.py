@@ -64,6 +64,7 @@ import numpy as np
 
 from ..models.registry import ModelBundle, family_module
 from ..train.precision import Quantized
+from ..utils.trace import named, span
 from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        adapter_nbytes, adapter_pool_bytes, adapter_shapes,
                        init_adapter_stacks, validate_adapter_params)
@@ -80,6 +81,7 @@ from .weights import (params_nbytes, quantized_param_shardings,
                       weight_dtype_name)
 
 
+@jax.named_scope("sample")
 def _sample_tokens(logits, seeds, positions, temps, top_ks, top_ps):
     """Per-slot temperature / top-k / top-p sampling, greedy at temp 0.
 
@@ -168,8 +170,7 @@ def derived_pool_metrics(*, pool: PagePool, cached_pages: int, n_slots: int,
     held = pool.capacity - pool.n_free
     tier_tail = tier.gauges() if tier is not None else {
         "host_tier_bytes": 0, "host_tier_budget_bytes": 0,
-        "host_tier_records": 0, "spilled_pages": 0,
-        "restore_hits": 0, "restore_misses": 0}
+        "spilled_pages": 0, "restore_hits": 0, "restore_misses": 0}
     return {
         **tier_tail,
         "n_slots": n_slots,
@@ -365,9 +366,10 @@ def run_fork(programs: "ModelPrograms", pages: dict, adm: Admission) -> None:
     into the slot's private replacement first. Mutates ``pages`` in
     place (the dict is the engine-shared handle)."""
     src, dst = adm.fork
-    pages["k"], pages["v"] = programs._copy_fn(
-        pages["k"], pages["v"],
-        jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
+    with span("serve.fork", request_id=adm.request.request_id):
+        pages["k"], pages["v"] = programs._copy_fn(
+            pages["k"], pages["v"],
+            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
 
 
 def run_bucket_prefill(programs: "ModelPrograms", pages: dict,
@@ -384,17 +386,19 @@ def run_bucket_prefill(programs: "ModelPrograms", pages: dict,
     if bucket is None:
         raise ValueError(f"prompt length {n} exceeds the largest prefill "
                          f"bucket {buckets[-1]}")
-    ids = np.zeros((1, bucket), np.int32)
-    ids[0, :n] = tokens
-    programs.prefill_calls += 1
-    logit, kd, vd = programs.prefill_for(bucket)(
-        programs.params, jnp.asarray(ids), jnp.asarray(n - 1),
-        *programs.lora_call_args([adm.request.adapter_id]))
-    table_row = jnp.asarray(sched.table_row(adm.slot_idx))
-    pages["k"], pages["v"] = programs._commit_fn(
-        pages["k"], pages["v"], kd, vd, table_row,
-        jnp.asarray(n), jnp.asarray(adm.shared_len))
-    sched.commit_tokens(adm.slot_idx, n - adm.shared_len)
+    with span("serve.prefill", request_id=adm.request.request_id, tokens=n,
+              program=f"serve_prefill_b{bucket}"):
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :n] = tokens
+        programs.prefill_calls += 1
+        logit, kd, vd = programs.prefill_for(bucket)(
+            programs.params, jnp.asarray(ids), jnp.asarray(n - 1),
+            *programs.lora_call_args([adm.request.adapter_id]))
+        table_row = jnp.asarray(sched.table_row(adm.slot_idx))
+        pages["k"], pages["v"] = programs._commit_fn(
+            pages["k"], pages["v"], kd, vd, table_row,
+            jnp.asarray(n), jnp.asarray(adm.shared_len))
+        sched.commit_tokens(adm.slot_idx, n - adm.shared_len)
     return logit
 
 
@@ -426,17 +430,19 @@ def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
         # slots with short final chunks run N full-width forwards in one
         # iteration, exactly the latency spike the budget bounds
         budget -= chunk
-        ids = np.zeros((1, chunk), np.int32)
-        ids[0, :real] = adm.tokens[start:start + real]
-        programs.prefill_calls += 1
-        logit, pages["k"], pages["v"] = programs.chunk_for(chunk)(
-            programs.params, pages["k"], pages["v"],
-            jnp.asarray(ids), jnp.asarray([start], jnp.int32),
-            jnp.asarray(sched.table_row(slot_idx)[None]),
-            jnp.asarray(real - 1, jnp.int32),
-            jnp.asarray([real], jnp.int32),
-            *programs.lora_call_args([adm.request.adapter_id]))
-        sched.commit_tokens(slot_idx, real)
+        with span("serve.prefill", request_id=adm.request.request_id,
+                  tokens=real, program=f"serve_chunk_t{chunk}"):
+            ids = np.zeros((1, chunk), np.int32)
+            ids[0, :real] = adm.tokens[start:start + real]
+            programs.prefill_calls += 1
+            logit, pages["k"], pages["v"] = programs.chunk_for(chunk)(
+                programs.params, pages["k"], pages["v"],
+                jnp.asarray(ids), jnp.asarray([start], jnp.int32),
+                jnp.asarray(sched.table_row(slot_idx)[None]),
+                jnp.asarray(real - 1, jnp.int32),
+                jnp.asarray([real], jnp.int32),
+                *programs.lora_call_args([adm.request.adapter_id]))
+            sched.commit_tokens(slot_idx, real)
         if not sched.slots[slot_idx].prefilling:   # final chunk landed
             pending.pop(slot_idx)
             res = on_complete(adm, logit)
@@ -503,59 +509,67 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
             # the verify scatter targets positions up to cache_len +
             # n_drafts, which must stay inside the position table
             sched.max_len - 1 - slot.cache_len))
-    proposals = drafter.propose_many(contexts, budgets)
+    with span("serve.draft", k=k):
+        proposals = drafter.propose_many(contexts, budgets)
     if not any(proposals.get(i) and budgets[i] > 0 for i in active):
         return None
     ids = np.zeros((sched.n_slots, t), np.int32)
     n_valid = np.ones(sched.n_slots, np.int32)
     grew = False
-    for i in active:
-        slot = sched.slots[i]
-        ids[i, 0] = slot.generated[slot.replay_pos]
-        props = [int(x) for x in (proposals.get(i) or [])][:budgets[i]]
-        n_pages_before = len(slot.pages)
-        granted = sched.ensure_lookahead(i, len(props))
-        grew = grew or len(slot.pages) != n_pages_before
-        props = props[:granted]
-        ids[i, 1:1 + len(props)] = props
-        n_valid[i] = 1 + len(props)
+    with span("serve.reserve", lookahead=k):
+        for i in active:
+            slot = sched.slots[i]
+            ids[i, 0] = slot.generated[slot.replay_pos]
+            props = [int(x) for x in (proposals.get(i) or [])][:budgets[i]]
+            n_pages_before = len(slot.pages)
+            granted = sched.ensure_lookahead(i, len(props))
+            grew = grew or len(slot.pages) != n_pages_before
+            props = props[:granted]
+            ids[i, 1:1 + len(props)] = props
+            n_valid[i] = 1 + len(props)
     if dev is None or dev.get("kind") != "spec":
-        arr = sched.decode_arrays()
-        dev = {"kind": "spec",
-               **{key: jnp.asarray(arr[key])
-                  for key in ("lengths", "tables", "seeds", "temps",
-                              "top_ks", "top_ps", "actives", "adapters")}}
+        with span("serve.build"):
+            arr = sched.decode_arrays()
+            dev = {"kind": "spec",
+                   **{key: jnp.asarray(arr[key])
+                      for key in ("lengths", "tables", "seeds", "temps",
+                                  "top_ks", "top_ps", "actives", "adapters")}}
     elif grew:      # lookahead growth extended a block table mid-flight
-        dev["tables"] = jnp.asarray(sched.decode_arrays()["tables"])
+        with span("serve.build"):
+            dev["tables"] = jnp.asarray(sched.decode_arrays()["tables"])
     # static greedy specialization: when every active slot decodes at
     # temperature 0 the target draw is argmax and the verify program
     # skips the t-position sorted-space sampler entirely (exact — see
     # verify_for); a single stochastic slot switches the whole batch to
     # the full sampler program
     greedy = all(sched.slots[i].request.temperature == 0.0 for i in active)
-    targets, n_acc, dev["lengths"], pages["k"], pages["v"] = \
-        programs.verify_for(t, greedy=greedy)(
-            programs.params, pages["k"], pages["v"], jnp.asarray(ids),
-            dev["lengths"], dev["tables"], dev["seeds"], dev["temps"],
-            dev["top_ks"], dev["top_ps"], dev["actives"],
-            jnp.asarray(n_valid),
-            *programs.lora_call_args(dev["adapters"]))
-    targets = np.asarray(targets)
-    n_acc = np.asarray(n_acc)
+    with span("serve.dispatch", program=f"serve_verify_t{t}"):
+        targets, n_acc, dev["lengths"], pages["k"], pages["v"] = \
+            programs.verify_for(t, greedy=greedy)(
+                programs.params, pages["k"], pages["v"], jnp.asarray(ids),
+                dev["lengths"], dev["tables"], dev["seeds"], dev["temps"],
+                dev["top_ks"], dev["top_ps"], dev["actives"],
+                jnp.asarray(n_valid),
+                *programs.lora_call_args(dev["adapters"]))
+    with span("serve.wait"):
+        targets = np.asarray(targets)
+        n_acc = np.asarray(n_acc)
     finished, emitted_total = [], 0
-    for i in active:
-        n_d = int(n_valid[i]) - 1
-        acc = int(n_acc[i])
-        spec["tokens_drafted"] += n_d
-        spec["tokens_accepted"] += acc
-        spec["tokens_rejected"] += n_d - acc
-        for j in range(acc + 1):
-            emitted_total += 1
-            res = sched.record_token(i, int(targets[i, j]),
-                                     from_decode=True)
-            if res is not None:     # eos/length mid-run: the rest of the
-                finished.append(res)   # accepted tokens are dropped with
-                break                  # the slot (clean boundary)
+    with span("serve.book") as sp:
+        for i in active:
+            n_d = int(n_valid[i]) - 1
+            acc = int(n_acc[i])
+            spec["tokens_drafted"] += n_d
+            spec["tokens_accepted"] += acc
+            spec["tokens_rejected"] += n_d - acc
+            for j in range(acc + 1):
+                emitted_total += 1
+                res = sched.record_token(i, int(targets[i, j]),
+                                         from_decode=True)
+                if res is not None:     # eos/length mid-run: the rest of
+                    finished.append(res)   # the accepted tokens are dropped
+                    break                  # with the slot (clean boundary)
+        sp.set_metadata(tokens=emitted_total)
     spec["spec_steps"] += 1
     return finished, emitted_total, dev
 
@@ -586,22 +600,26 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
         if out is not None:
             return out
     if dev is None or dev.get("kind") != "plain":
-        dev = {"kind": "plain",
-               **{key: jnp.asarray(v)
-                  for key, v in sched.decode_arrays().items()}}
-    nxt, new_len, pages["k"], pages["v"] = programs._decode_fn(
-        programs.params, pages["k"], pages["v"],
-        dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
-        dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
-        *programs.lora_call_args(dev["adapters"]))
+        with span("serve.build"):
+            dev = {"kind": "plain",
+                   **{key: jnp.asarray(v)
+                      for key, v in sched.decode_arrays().items()}}
+    with span("serve.dispatch", program="serve_decode"):
+        nxt, new_len, pages["k"], pages["v"] = programs._decode_fn(
+            programs.params, pages["k"], pages["v"],
+            dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
+            dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
+            *programs.lora_call_args(dev["adapters"]))
     dev["tokens"], dev["lengths"] = nxt, new_len
-    nxt_host = np.asarray(nxt)
+    with span("serve.wait"):
+        nxt_host = np.asarray(nxt)
     finished = []
-    for slot_idx in active:
-        res = sched.record_token(slot_idx, int(nxt_host[slot_idx]),
-                                 from_decode=True)
-        if res is not None:
-            finished.append(res)
+    with span("serve.book", tokens=len(active)):
+        for slot_idx in active:
+            res = sched.record_token(slot_idx, int(nxt_host[slot_idx]),
+                                     from_decode=True)
+            if res is not None:
+                finished.append(res)
     return finished, len(active), dev
 
 
@@ -612,9 +630,10 @@ def horizon_dev(sched: Scheduler) -> dict:
     (host and device state agree there); between boundaries the horizon
     program itself carries tokens/lengths/live/budgets forward ON DEVICE
     — the host never reads them back."""
-    return {"kind": "horizon",
-            **{key: jnp.asarray(v)
-               for key, v in sched.decode_arrays().items()}}
+    with span("serve.build"):
+        return {"kind": "horizon",
+                **{key: jnp.asarray(v)
+                   for key, v in sched.decode_arrays().items()}}
 
 
 def dispatch_horizon(programs: "ModelPrograms", pages: dict,
@@ -637,19 +656,21 @@ def dispatch_horizon(programs: "ModelPrograms", pages: dict,
     Returns the in-flight record ``process_horizon_block`` consumes:
     the ``[n_slots, k]`` token-block future, the realized k, and the
     (slot, request_id) pairs active at dispatch."""
-    tables = np.zeros((sched.n_slots, sched.max_pages), np.int32)
-    active = []
-    for i in sched.active_indices():
-        tables[i] = sched.table_row(i)
-        active.append((i, sched.slots[i].request.request_id))
-    dev["tables"] = jnp.asarray(tables)
-    (block, dev["tokens"], dev["lengths"], dev["actives"], dev["budgets"],
-     pages["k"], pages["v"]) = programs.horizon_for(k)(
-        programs.params, pages["k"], pages["v"],
-        dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
-        dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
-        dev["budgets"], dev["eos_ids"],
-        *programs.lora_call_args(dev["adapters"]))
+    with span("serve.build"):
+        tables = np.zeros((sched.n_slots, sched.max_pages), np.int32)
+        active = []
+        for i in sched.active_indices():
+            tables[i] = sched.table_row(i)
+            active.append((i, sched.slots[i].request.request_id))
+        dev["tables"] = jnp.asarray(tables)
+    with span("serve.dispatch", program=f"serve_horizon_k{k}"):
+        (block, dev["tokens"], dev["lengths"], dev["actives"],
+         dev["budgets"], pages["k"], pages["v"]) = programs.horizon_for(k)(
+            programs.params, pages["k"], pages["v"],
+            dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
+            dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
+            dev["budgets"], dev["eos_ids"],
+            *programs.lora_call_args(dev["adapters"]))
     return {"block": block, "k": k, "active": active}
 
 
@@ -663,19 +684,22 @@ def process_horizon_block(sched: Scheduler, inflight: dict) \
     device lane died (everything past it is masked zeros). A slot that
     already finished in an EARLIER block (or was evicted at a boundary)
     is skipped by request-id match. Returns (finished, tokens_emitted)."""
-    block = np.asarray(inflight["block"])
+    with span("serve.wait"):
+        block = np.asarray(inflight["block"])
     finished, emitted = [], 0
-    for slot_idx, rid in inflight["active"]:
-        slot = sched.slots[slot_idx]
-        if slot is None or slot.request.request_id != rid:
-            continue
-        for j in range(inflight["k"]):
-            res = sched.record_token(slot_idx, int(block[slot_idx, j]),
-                                     from_decode=True)
-            emitted += 1
-            if res is not None:
-                finished.append(res)
-                break
+    with span("serve.book") as sp:
+        for slot_idx, rid in inflight["active"]:
+            slot = sched.slots[slot_idx]
+            if slot is None or slot.request.request_id != rid:
+                continue
+            for j in range(inflight["k"]):
+                res = sched.record_token(slot_idx, int(block[slot_idx, j]),
+                                         from_decode=True)
+                emitted += 1
+                if res is not None:
+                    finished.append(res)
+                    break
+        sp.set_metadata(tokens=emitted)
     return finished, emitted
 
 
@@ -927,7 +951,8 @@ class ModelPrograms:
             # ONE compiled insert for every slot: the slot index is a
             # TRACED scalar, so publishing into slot 3 and slot 7 hit the
             # same executable (jit-cache-flat across inserts)
-            self._insert_fn = jax.jit(self._adapter_insert)
+            self._insert_fn = jax.jit(named(self._adapter_insert,
+                                            "serve_adapter_insert"))
 
         kv_out = ((self._kv_sharding, self._kv_sharding)
                   if self.shard_kv else None)
@@ -937,21 +962,23 @@ class ModelPrograms:
         self._horizon_fns = {}
         # one jit wrapper; each prefill bucket's [L, Pb, ...] shape gets its
         # own cached executable automatically
-        self._commit_fn = jax.jit(commit_impl, donate_argnums=(0, 1),
+        self._commit_fn = jax.jit(named(commit_impl, "serve_commit"),
+                                  donate_argnums=(0, 1),
                                   **({"out_shardings": kv_out}
                                      if kv_out else {}))
-        self._copy_fn = jax.jit(copy_impl, donate_argnums=(0, 1),
+        self._copy_fn = jax.jit(named(copy_impl, "serve_copy"),
+                                donate_argnums=(0, 1),
                                 **({"out_shardings": kv_out}
                                    if kv_out else {}))
         self._decode_fn = jax.jit(
-            self._decode, donate_argnums=(1, 2),
+            named(self._decode, "serve_decode"), donate_argnums=(1, 2),
             **({"out_shardings": (self._repl, self._repl,
                                   self._kv_sharding, self._kv_sharding)}
                if self.shard_kv else {}))
-        self._sample_one = jax.jit(
+        self._sample_one = jax.jit(named(
             lambda logit, seed, pos, t, tk, tp: _sample_tokens(
                 logit[None], seed[None], pos[None], t[None], tk[None],
-                tp[None])[0])
+                tp[None])[0], "serve_sample_one"))
         # weight-publish bookkeeping (post-training: post/loop.py). A
         # publish swaps refreshed buffers into self.params WITHOUT touching
         # the jit caches above — the programs take params as an argument,
@@ -1070,7 +1097,7 @@ class ModelPrograms:
             # ROADMAP caveat-(c) glibc-heap corruption in a new coat.
             # Re-try the donating twin when jaxlib is upgraded.
             self._snapshot_fn = jax.jit(
-                lambda p: jax.tree.map(jnp.copy, p),
+                named(lambda p: jax.tree.map(jnp.copy, p), "serve_snapshot"),
                 out_shardings=shardings)
         self.params = self._snapshot_fn(new_params)
         self.publish_count += 1
@@ -1108,7 +1135,8 @@ class ModelPrograms:
                                      self.params)
             store = self._store_weights
             self._requant_fn = jax.jit(
-                lambda p: jax.tree.map(jnp.copy, store(p)),
+                named(lambda p: jax.tree.map(jnp.copy, store(p)),
+                      "serve_requant"),
                 out_shardings=shardings)
         self.params = self._requant_fn(new_params)
         self.publish_count += 1
@@ -1375,7 +1403,7 @@ class ModelPrograms:
                       + (self._kv_sharding, self._kv_sharding)
                       if self.shard_kv else None)
             self._horizon_fns[k] = jax.jit(
-                fn, donate_argnums=(1, 2),
+                named(fn, f"serve_horizon_k{k}"), donate_argnums=(1, 2),
                 **({"out_shardings": kv_out} if kv_out else {}))
         return self._horizon_fns[k]
 
@@ -1389,7 +1417,8 @@ class ModelPrograms:
                        if lora_args else {}))
                 return logit[0], cache["k"][:, 0], cache["v"][:, 0]
 
-            self._prefill_fns[bucket] = jax.jit(fn)
+            self._prefill_fns[bucket] = jax.jit(
+                named(fn, f"serve_prefill_b{bucket}"))
         return self._prefill_fns[bucket]
 
     def chunk_for(self, t: int):
@@ -1416,7 +1445,7 @@ class ModelPrograms:
             kv_out = ((self._repl, self._kv_sharding, self._kv_sharding)
                       if self.shard_kv else None)
             self._chunk_fns[t] = jax.jit(
-                fn, donate_argnums=(1, 2),
+                named(fn, f"serve_chunk_t{t}"), donate_argnums=(1, 2),
                 **({"out_shardings": kv_out} if kv_out else {}))
         return self._chunk_fns[t]
 
@@ -1484,18 +1513,23 @@ class ModelPrograms:
                        self._kv_sharding, self._kv_sharding)
                       if self.shard_kv else None)
             self._verify_fns[key] = jax.jit(
-                fn, donate_argnums=(1, 2),
+                named(fn, f"serve_verify_t{t}" + ("_greedy" if greedy else "")),
+                donate_argnums=(1, 2),
                 **({"out_shardings": kv_out} if kv_out else {}))
         return self._verify_fns[key]
 
-    def sample_one(self, logit, request: Request, position: int):
-        """Batch-1 sample off prefill logits (the request's first token)."""
-        return self._sample_one(
-            logit.astype(jnp.float32), jnp.asarray(request.seed, jnp.int32),
-            jnp.asarray(position, jnp.int32),
-            jnp.asarray(request.temperature, jnp.float32),
-            jnp.asarray(request.top_k, jnp.int32),
-            jnp.asarray(request.top_p, jnp.float32))
+    def sample_one(self, logit, request: Request, position: int) -> int:
+        """Batch-1 sample off prefill logits (the request's first token),
+        read back to the host: the span holds the wait for the prefill
+        that made the logits."""
+        with span("serve.sample", request_id=request.request_id):
+            return int(self._sample_one(
+                logit.astype(jnp.float32),
+                jnp.asarray(request.seed, jnp.int32),
+                jnp.asarray(position, jnp.int32),
+                jnp.asarray(request.temperature, jnp.float32),
+                jnp.asarray(request.top_k, jnp.int32),
+                jnp.asarray(request.top_p, jnp.float32)))
 
     def check_prompt(self, request: Request) -> None:
         """Range-check prompt ids (the scheduler is model-agnostic): under
@@ -1881,7 +1915,7 @@ class ServeEngine:
         """First token off the prefill logits (skipped for preempted
         sequences — their next token was generated before preemption)."""
         t0 = self.programs.sample_one(logit, adm.request, len(adm.tokens))
-        return self.scheduler.record_token(adm.slot_idx, int(t0),
+        return self.scheduler.record_token(adm.slot_idx, t0,
                                            from_decode=False)
 
     def _on_prefill_complete(self, adm: Admission,
@@ -1951,6 +1985,13 @@ class ServeEngine:
                 "mixed-policy tokens; run the swap (or build the new "
                 "generation without params=)")
         self.stats_seq += 1
+        # the whole iteration as one host span; its children (expire,
+        # restore, admit, fork, prefill, sample, reserve, build, dispatch,
+        # wait, book) are emitted where that work happens
+        with span("serve.step", seq=self.stats_seq):
+            return self._iterate()
+
+    def _iterate(self) -> list[RequestResult]:
         finished = []
         sched = self.scheduler
         if self._inflight is not None:
@@ -1996,16 +2037,17 @@ class ServeEngine:
             # cache so the ordinary shared-prefix admission path finds
             # them. Both paths allocate from the SAME free list admission
             # uses, so the audit identity is untouched.
-            if restore_queued(sched, self.host_tier, self.scatter_pages,
-                              self._tier_alloc):
-                self._dev = None
-            if sched.queue and sched.cache is not None:
-                head = sched.queue[0].request
-                restore_prefixes(
-                    sched.cache, self.host_tier, list(head.prompt_ids),
-                    ns=int(getattr(head, "adapter_id", 0) or 0),
-                    alloc=self._tier_alloc, scatter=self.scatter_pages,
-                    free=sched.pool.free)
+            with span("serve.restore"):
+                if restore_queued(sched, self.host_tier, self.scatter_pages,
+                                  self._tier_alloc):
+                    self._dev = None
+                if sched.queue and sched.cache is not None:
+                    head = sched.queue[0].request
+                    restore_prefixes(
+                        sched.cache, self.host_tier, list(head.prompt_ids),
+                        ns=int(getattr(head, "adapter_id", 0) or 0),
+                        alloc=self._tier_alloc, scatter=self.scatter_pages,
+                        free=sched.pool.free)
         admissions = sched.try_admit()
         for adm in admissions:
             self._dev = None

@@ -398,8 +398,19 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
 
     Returns (attn [S, T, Hq, D], (k_pages, v_pages) updated).
     """
+    k_pages, v_pages, t_idx = _scatter_new(k_new, v_new, k_pages, v_pages,
+                                           tables, lengths, n_valid)
+    return _attend_pages(q, k_pages, v_pages, tables, lengths, t_idx,
+                         window=window, scale=scale, softcap=softcap,
+                         impl=impl)
+
+
+@jax.named_scope("kv_write")
+def _scatter_new(k_new, v_new, k_pages, v_pages, tables, lengths, n_valid):
+    """The write half of :func:`paged_attend`: each slot's T new k/v rows
+    into its pages. Returns the pools and the [S, T] absolute positions."""
     quantized = isinstance(k_pages, Quantized)
-    s, t = q.shape[0], q.shape[1]
+    s, t = k_new.shape[0], k_new.shape[1]
     page = (k_pages.q if quantized else k_pages).shape[1]
     m = tables.shape[1]
     slot = jnp.arange(s)
@@ -424,7 +435,17 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
     else:
         k_pages = k_pages.at[phys, off].set(k_new.astype(k_pages.dtype))
         v_pages = v_pages.at[phys, off].set(v_new.astype(v_pages.dtype))
+    return k_pages, v_pages, t_idx
 
+
+@jax.named_scope("attend")
+def _attend_pages(q, k_pages, v_pages, tables, lengths, t_idx, *, window,
+                  scale, softcap, impl):
+    """The read half of :func:`paged_attend`: q over each slot's block-table
+    context, after the new tokens were scattered."""
+    quantized = isinstance(k_pages, Quantized)
+    s = q.shape[0]
+    page = (k_pages.q if quantized else k_pages).shape[1]
     if impl == "auto":
         impl, reason = resolve_attend_impl(impl, q.shape[-1], page)
         note_choice("paged_attend", impl, reason)
@@ -481,6 +502,7 @@ def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None):
     return attend
 
 
+@jax.named_scope("kv_write")
 def commit_prefill(k_pages, v_pages, k_dense, v_dense, table_row, n_tokens,
                    start=0):
     """Scatter a bucketed prefill's dense cache into one slot's pages.
@@ -518,6 +540,7 @@ def commit_prefill(k_pages, v_pages, k_dense, v_dense, table_row, n_tokens,
     return k_pages, v_pages
 
 
+@jax.named_scope("kv_write")
 def copy_pages(k_pages, v_pages, src, dst):
     """Copy-on-write fork: duplicate physical page ``src`` into ``dst``
     across every layer ([L, P, page, kvh, hd] pools; src/dst are traced

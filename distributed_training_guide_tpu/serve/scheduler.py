@@ -68,6 +68,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..utils.trace import span
 from .kv_pages import TRASH_PAGE, PagePool, pages_for_tokens
 
 
@@ -775,92 +776,106 @@ class Scheduler:
         admission's fork copy + prefill, reporting progress through
         ``commit_tokens``."""
         admissions = []
-        page = self.pool.page_size
         while self.queue:
             slot_idx = next((i for i, s in enumerate(self.slots)
                              if s is None), None)
             if slot_idx is None:
                 break
             entry = self.queue[0]
-            req = entry.request
-            # the prefill target is the PROMPT alone, resumed or not: a
-            # preempted sequence's generated tokens replay through the
-            # decode program after the prompt is back (bitwise recompute)
-            tokens = list(req.prompt_ids)
-            full, partial = ([], None) if self.cache is None else \
-                self.cache.match(tokens, self.allow_partial_share,
-                                 ns=int(req.adapter_id))
-            k_full = len(full)
-            shared_len = k_full * page + (partial[1] if partial else 0)
-            n_priv = pages_for_tokens(len(tokens), page) - k_full
-            # take the references on every matched page BEFORE allocation:
-            # _alloc's cache-eviction pressure may drop the matched nodes
-            # themselves (their cache ref could be the only one), and a
-            # share-after-evict would either crash on a dead page or hand
-            # this slot a page alloc just re-issued as its own private one
-            shared_pages = [node.page for node in full]
-            self.pool.share(shared_pages)
-            protect = [partial[0].page] if partial else []
-            if protect:              # the CoW source must survive too — the
-                self.pool.share(protect)   # engine copies it after we return
-            # headroom: every running decode may need a page within one
-            # page_size worth of steps — admitting into that margin would
-            # trade one prompt's admission for immediate preemption churn
-            # (decodes running in a sibling scheduler count via the hook).
-            # Under speculation each decode can consume 1 + spec_lookahead
-            # positions per iteration, and under a K-step horizon K
-            # positions per BOUNDARY, so the margin scales to the pages
-            # that worth of tokens can claim.
-            per_decode = pages_for_tokens(
-                self.decode_horizon + self.spec_lookahead, page)
-            headroom = (len(self.active_indices()) + (
-                self._headroom_fn() if self._headroom_fn else 0)) * per_decode
-            priv = self._alloc(n_priv, headroom=headroom)
-            if protect:
-                # safe to release now: if the source node was evicted
-                # above, its page can only be re-issued to a LATER
-                # admission in this same loop, and the engine executes
-                # each admission's fork copy before any later admission's
-                # writes — the copy always reads the original bytes
-                self.pool.free(protect)
-            if priv is None:
-                # backpressure: head blocks (strict FIFO), decode goes on —
-                # release the speculative references and stay queued
-                self.pool.free(shared_pages)
-                self.stats["admission_blocked"] += 1
+            now = self._clock()
+            rid = entry.request.request_id
+            with span("serve.admit", request_id=rid, queue_ms=round(
+                    1e3 * (now - self._submit_times[rid]), 3)):
+                adm = self._admit_head(entry, slot_idx, now)
+            if adm is None:
                 break
-            fork = None
-            if partial is not None:
-                # the first private page starts life as a CoW fork of the
-                # partially-matched shared page: the remainder prefill is
-                # about to write into its territory
-                fork = (partial[0].page, priv[0])
-                self.stats["cow_forks"] += 1
-            if shared_len:
-                self.stats["prefix_hits"] += 1
-                self.stats["prefix_tokens_shared"] += shared_len
-            self.queue.pop(0)
-            if self._tier is not None and entry.generated:
-                # recompute admission won over a pending restore (its
-                # allocation kept failing, or the share-aware grant here
-                # was simply cheaper): the spilled record is stale now —
-                # drop it and count the miss. The replay that follows is
-                # still bitwise; only the recompute savings are lost.
-                if self._tier.drop(("seq", req.request_id)):
-                    self._tier.note_miss()
-            self.slots[slot_idx] = _Slot(
-                request=req, pages=shared_pages + priv,
-                generated=list(entry.generated), cache_len=shared_len,
-                admitted_at=self._clock(), seq=next(self._seq),
-                target_len=len(tokens), prefilling=True,
-                shared_len=shared_len, resumed=bool(entry.generated),
-                replay_pos=0, first_token_at=entry.first_token_at)
-            self.stats["admitted"] += 1
-            admissions.append(Admission(
-                slot_idx=slot_idx, request=req, tokens=tokens,
-                shared_len=shared_len, fork=fork,
-                resumed=bool(entry.generated)))
+            admissions.append(adm)
         return admissions
+
+    def _admit_head(self, entry: _QueueEntry, slot_idx: int,
+                    now: float) -> Optional[Admission]:
+        """One admission: the queue head into ``slot_idx`` at time ``now``,
+        or None when the pool (after prefix sharing) cannot grant its
+        pages — the head then blocks and stays queued."""
+        page = self.pool.page_size
+        req = entry.request
+        # the prefill target is the PROMPT alone, resumed or not: a
+        # preempted sequence's generated tokens replay through the
+        # decode program after the prompt is back (bitwise recompute)
+        tokens = list(req.prompt_ids)
+        full, partial = ([], None) if self.cache is None else \
+            self.cache.match(tokens, self.allow_partial_share,
+                             ns=int(req.adapter_id))
+        k_full = len(full)
+        shared_len = k_full * page + (partial[1] if partial else 0)
+        n_priv = pages_for_tokens(len(tokens), page) - k_full
+        # take the references on every matched page BEFORE allocation:
+        # _alloc's cache-eviction pressure may drop the matched nodes
+        # themselves (their cache ref could be the only one), and a
+        # share-after-evict would either crash on a dead page or hand
+        # this slot a page alloc just re-issued as its own private one
+        shared_pages = [node.page for node in full]
+        self.pool.share(shared_pages)
+        protect = [partial[0].page] if partial else []
+        if protect:              # the CoW source must survive too — the
+            self.pool.share(protect)   # engine copies it after we return
+        # headroom: every running decode may need a page within one
+        # page_size worth of steps — admitting into that margin would
+        # trade one prompt's admission for immediate preemption churn
+        # (decodes running in a sibling scheduler count via the hook).
+        # Under speculation each decode can consume 1 + spec_lookahead
+        # positions per iteration, and under a K-step horizon K
+        # positions per BOUNDARY, so the margin scales to the pages
+        # that worth of tokens can claim.
+        per_decode = pages_for_tokens(
+            self.decode_horizon + self.spec_lookahead, page)
+        headroom = (len(self.active_indices()) + (
+            self._headroom_fn() if self._headroom_fn else 0)) * per_decode
+        priv = self._alloc(n_priv, headroom=headroom)
+        if protect:
+            # safe to release now: if the source node was evicted
+            # above, its page can only be re-issued to a LATER
+            # admission in this same loop, and the engine executes
+            # each admission's fork copy before any later admission's
+            # writes — the copy always reads the original bytes
+            self.pool.free(protect)
+        if priv is None:
+            # backpressure: head blocks (strict FIFO), decode goes on —
+            # release the speculative references and stay queued
+            self.pool.free(shared_pages)
+            self.stats["admission_blocked"] += 1
+            return None
+        fork = None
+        if partial is not None:
+            # the first private page starts life as a CoW fork of the
+            # partially-matched shared page: the remainder prefill is
+            # about to write into its territory
+            fork = (partial[0].page, priv[0])
+            self.stats["cow_forks"] += 1
+        if shared_len:
+            self.stats["prefix_hits"] += 1
+            self.stats["prefix_tokens_shared"] += shared_len
+        self.queue.pop(0)
+        if self._tier is not None and entry.generated:
+            # recompute admission won over a pending restore (its
+            # allocation kept failing, or the share-aware grant here
+            # was simply cheaper): the spilled record is stale now —
+            # drop it and count the miss. The replay that follows is
+            # still bitwise; only the recompute savings are lost.
+            if self._tier.drop(("seq", req.request_id)):
+                self._tier.note_miss()
+        self.slots[slot_idx] = _Slot(
+            request=req, pages=shared_pages + priv,
+            generated=list(entry.generated), cache_len=shared_len,
+            admitted_at=now, seq=next(self._seq),
+            target_len=len(tokens), prefilling=True,
+            shared_len=shared_len, resumed=bool(entry.generated),
+            replay_pos=0, first_token_at=entry.first_token_at)
+        self.stats["admitted"] += 1
+        return Admission(
+            slot_idx=slot_idx, request=req, tokens=tokens,
+            shared_len=shared_len, fork=fork,
+            resumed=bool(entry.generated))
 
     # ---- prefill progress --------------------------------------------------
     def commit_tokens(self, slot_idx: int, n: int) -> None:
@@ -920,6 +935,12 @@ class Scheduler:
         LOWEST-PRIORITY live sequence is preempted, youngest first within
         a class (possibly the grower itself, when nothing cheaper is left)
         and its pages fund the others. Returns (pages_grown, preempted)."""
+        with span("serve.reserve") as sp:
+            grown, preempted = self._grow_for_decode()
+            sp.set_metadata(grown=grown, preempted=preempted)
+        return grown, preempted
+
+    def _grow_for_decode(self) -> tuple[int, int]:
         grown = preempted = 0
         order = sorted((i for i, s in enumerate(self.slots)
                         if s is not None and not s.prefilling),
@@ -995,6 +1016,12 @@ class Scheduler:
         them (no un-grow, same as speculation's lookahead)."""
         if want < 1:
             raise ValueError(f"horizon must be >= 1, got {want}")
+        with span("serve.reserve", want=want) as sp:
+            covered = self._reserve_horizon(want)
+            sp.set_metadata(covered=covered)
+        return covered
+
+    def _reserve_horizon(self, want: int) -> int:
         page = self.pool.page_size
         covered = want
         for slot_idx in self.active_indices():
@@ -1090,8 +1117,11 @@ class Scheduler:
         every iteration boundary — expiry is always an orderly eviction,
         never a mid-iteration abort (the invariant all scheduling shares:
         refuse or cleanly evict/preempt, never corrupt)."""
-        now = self._clock() if now is None else now
+        with span("serve.expire"):
+            return self._expire_deadlines(
+                self._clock() if now is None else now)
 
+    def _expire_deadlines(self, now: float) -> list[RequestResult]:
         def expired(req: Request) -> bool:
             return (req.deadline_s is not None
                     and now - self._submit_times[req.request_id]

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.registry import ModelBundle, family_module
+from ..utils.trace import named
 from .kv_pages import (PagePool, init_pages, make_attend, pages_for_tokens,
                        resolve_attend_impl)
 
@@ -204,8 +205,10 @@ class DraftModelDrafter(Drafter):
         self._consumed: list[list] = [[] for _ in range(n_slots)]
         self._counters = {"draft_model_steps": 0, "catchup_tokens": 0,
                           "resyncs": 0}
-        self._step_fn = jax.jit(self._step, donate_argnums=(1, 2))
-        self._chunk_fn = jax.jit(self._catchup, donate_argnums=(1, 2))
+        self._step_fn = jax.jit(named(self._step, "serve_draft_step"),
+                                donate_argnums=(1, 2))
+        self._chunk_fn = jax.jit(named(self._catchup, "serve_draft_catchup"),
+                                 donate_argnums=(1, 2))
 
     # ---- compiled draft programs (the drafter's own jit cache) -------------
     def _step(self, params, kp, vp, tokens, lengths, tables):

@@ -131,7 +131,6 @@ class HostTier:
         self.bytes_used = 0
         self.counters = {"spills": 0, "spill_rejects": 0, "evictions": 0,
                          "restore_hits": 0, "restore_misses": 0,
-                         "dropped": 0, "bytes_spilled": 0,
                          "bytes_restored": 0}
 
     def __len__(self) -> int:
@@ -167,7 +166,6 @@ class HostTier:
                                         nbytes=nbytes, pages=int(pages))
         self.bytes_used += nbytes
         self.counters["spills"] += 1
-        self.counters["bytes_spilled"] += nbytes
         return True
 
     def get(self, key) -> Optional[TierRecord]:
@@ -194,7 +192,6 @@ class HostTier:
         if rec is None:
             return False
         self.bytes_used -= rec.nbytes
-        self.counters["dropped"] += 1
         return True
 
     def note_miss(self) -> None:
@@ -233,15 +230,12 @@ class HostTier:
         """The stats()/healthz surface (lock-free host reads)."""
         return {"host_tier_bytes": self.bytes_used,
                 "host_tier_budget_bytes": self.budget_bytes,
-                "host_tier_records": len(self._records),
                 "spilled_pages": self.spilled_pages,
                 "restore_hits": self.counters["restore_hits"],
                 "restore_misses": self.counters["restore_misses"],
                 "tier_spills": self.counters["spills"],
                 "tier_spill_rejects": self.counters["spill_rejects"],
                 "tier_evictions": self.counters["evictions"],
-                "tier_dropped": self.counters["dropped"],
-                "tier_bytes_spilled": self.counters["bytes_spilled"],
                 "tier_bytes_restored": self.counters["bytes_restored"]}
 
 

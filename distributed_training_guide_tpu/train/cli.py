@@ -290,6 +290,7 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
     from ..train import Trainer
     from ..train.optimizer import OPTIMIZERS, lr_at_step
     from ..train.state import host_state_dict
+    from ..utils.trace import span
     from ..utils import (LocalTimer, compute_mfu, get_mem_stats, init_logging,
                          is_process0, transformer_flops_per_token)
     from ..utils.logging import print_device_line
@@ -455,7 +456,9 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
     if getattr(args, "timer_sync", False):
         from ..utils.timers import device_sync
         sync_fn = device_sync
-    timers = {k: LocalTimer(sync_fn=sync_fn) for k in ["data", "step"]}
+    # each timer is also the host span dtg.train.<k> (utils/trace.py)
+    timers = {k: LocalTimer(sync_fn=sync_fn, name=f"train.{k}")
+              for k in ["data", "step"]}
     flops_per_token = transformer_flops_per_token(
         bundle.num_active_params(), cfg.num_layers, cfg.hidden_size, seq_length,
         vocab_size=cfg.vocab_size)
@@ -497,16 +500,20 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
     pending_losses = []  # (step, loss, notfinite) banked between fences
 
     def drain_losses():
-        for step_no, l, flag in pending_losses:
-            # host read = hard fence. The guard monitor sees every step's
-            # flag (abort may thus surface a fence group late — the error
-            # file still names the offending step); skipped steps stay out
-            # of running_loss so one NaN doesn't poison every later window
-            if flag is not None and guard.observe(
-                    float(flag), step_no, {"loss": float(l)}):
-                continue
-            host_state["running_loss"] += float(l)
-        pending_losses.clear()
+        if not pending_losses:
+            return
+        with span("train.fence", step=pending_losses[-1][0]):
+            for step_no, l, flag in pending_losses:
+                # host read = hard fence. The guard monitor sees every
+                # step's flag (abort may thus surface a fence group late —
+                # the error file still names the offending step); skipped
+                # steps stay out of running_loss so one NaN doesn't poison
+                # every later window
+                if flag is not None and guard.observe(
+                        float(flag), step_no, {"loss": float(l)}):
+                    continue
+                host_state["running_loss"] += float(l)
+            pending_losses.clear()
     try:
         for epoch in range(host_state["epoch"], args.num_epochs):
             host_state["epoch"] = epoch
@@ -515,9 +522,10 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
             batches = loader.epoch_batches(start_step=host_state["epoch_step"])
 
             for i_step in range(host_state["epoch_step"], steps_per_epoch):
-                with timers["data"]:
+                step_no = host_state["global_step"] + 1
+                with timers["data"](step=step_no):
                     batch = next(batches)
-                with timers["step"]:
+                with timers["step"](step=step_no):
                     state, metrics = step_fn(state, batch)
                     # --fence-every 1 (default): force sync now, like the
                     # reference's per-step loss.item() (01:163). N>1: bank
@@ -560,36 +568,37 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                         LOGGER.info(f"profiler trace written to {args.profile_dir}")
 
                 if host_state["global_step"] % args.log_freq == 0:
-                    drain_losses()  # no-op: the in-timer drain above fired
-                    ms_per_step = sum(t.avg_elapsed_ms() for t in timers.values())
-                    tokens_per_s = 1000 * tok_per_step / max(ms_per_step, 1e-9)
-                    info = {
-                        "global_step": host_state["global_step"],
-                        "lr": lr_at_step(host_state["global_step"], args.lr),
-                        "running_loss": host_state["running_loss"] / args.log_freq,
-                        "grad_norm": float(metrics["grad_norm"]),
-                        **{k: float(v) for k, v in metrics.items()
-                           if k not in ("loss", "grad_norm")},
-                        "epoch": epoch,
-                        "epoch_progress": host_state["epoch_step"] / steps_per_epoch,
-                        "num_batches_remaining": steps_per_epoch - i_step,
-                        **get_mem_stats(),
-                        "tokens_per_s": tokens_per_s,
-                        **({"mfu": compute_mfu(tokens_per_s, flops_per_token,
-                                               n_chips, peak_flops)}
-                           if peak_flops else {}),
-                        "time/total": ms_per_step,
-                        **{f"time/{k}": t.avg_elapsed_ms() for k, t in timers.items()},
-                        **({"guard_skipped": guard.total_skipped}
-                           if guard.enabled else {}),
-                        **(extra_log or {}),
-                    }
-                    LOGGER.info(info)
-                    tracker.log(info, step=host_state["global_step"])
-                    last_info = info
-                    host_state["running_loss"] = 0.0
-                    for t in timers.values():
-                        t.reset()
+                    with span("train.log", step=host_state["global_step"]):
+                        drain_losses()  # no-op: the in-timer drain above fired
+                        ms_per_step = sum(t.avg_elapsed_ms() for t in timers.values())
+                        tokens_per_s = 1000 * tok_per_step / max(ms_per_step, 1e-9)
+                        info = {
+                            "global_step": host_state["global_step"],
+                            "lr": lr_at_step(host_state["global_step"], args.lr),
+                            "running_loss": host_state["running_loss"] / args.log_freq,
+                            "grad_norm": float(metrics["grad_norm"]),
+                            **{k: float(v) for k, v in metrics.items()
+                               if k not in ("loss", "grad_norm")},
+                            "epoch": epoch,
+                            "epoch_progress": host_state["epoch_step"] / steps_per_epoch,
+                            "num_batches_remaining": steps_per_epoch - i_step,
+                            **get_mem_stats(),
+                            "tokens_per_s": tokens_per_s,
+                            **({"mfu": compute_mfu(tokens_per_s, flops_per_token,
+                                                   n_chips, peak_flops)}
+                               if peak_flops else {}),
+                            "time/total": ms_per_step,
+                            **{f"time/{k}": t.avg_elapsed_ms() for k, t in timers.items()},
+                            **({"guard_skipped": guard.total_skipped}
+                               if guard.enabled else {}),
+                            **(extra_log or {}),
+                        }
+                        LOGGER.info(info)
+                        tracker.log(info, step=host_state["global_step"])
+                        last_info = info
+                        host_state["running_loss"] = 0.0
+                        for t in timers.values():
+                            t.reset()
 
                 if io is not None and host_state["global_step"] % args.ckpt_freq == 0:
                     # host_state is about to be persisted. Timing caveat
@@ -602,7 +611,8 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                     # benchmark-grade numbers (bench.py's harness does)
                     drain_losses()
                     LOGGER.info("Saving checkpoint.")
-                    io.save(state, host_state)
+                    with span("train.ckpt", step=host_state["global_step"]):
+                        io.save(state, host_state)
 
                 # after the checkpoint block: an injected crash at step N
                 # leaves the step-N checkpoint (if any) published, matching

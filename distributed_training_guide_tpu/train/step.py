@@ -562,10 +562,12 @@ class Trainer:
 
                 fused = make_fused_loss(self.plan, num_chunks=n_chunks)
 
+                @jax.named_scope("loss_head")
                 def chunked_ce(params, hidden, labels):
                     w_out = chunk_mod.output_weights(cfg, params)
                     return fused(hidden, w_out, labels)
             else:
+                @jax.named_scope("loss_head")
                 def chunked_ce(params, hidden, labels):
                     w_out = chunk_mod.output_weights(cfg, params)
                     return chunked_causal_lm_loss(
@@ -627,7 +629,8 @@ class Trainer:
                 else:
                     if logits_sharding is not None:
                         out = jax.lax.with_sharding_constraint(out, logits_sharding)
-                    ce = self.loss_fn(out, mb["labels"])
+                    with jax.named_scope("loss_head"):
+                        ce = self.loss_fn(out, mb["labels"])
                 return ce + aux_coef * aux, jax.lax.stop_gradient(moe_metrics)
         elif chunked_ce is not None:
             def loss_on_microbatch(params, mb):
@@ -649,7 +652,8 @@ class Trainer:
                                layer_schedule=layer_schedule)
                 if logits_sharding is not None:  # loss-parallel (vocab sharded)
                     logits = jax.lax.with_sharding_constraint(logits, logits_sharding)
-                return self.loss_fn(logits, mb["labels"]), {}
+                with jax.named_scope("loss_head"):
+                    return self.loss_fn(logits, mb["labels"]), {}
 
         if grad_fn is None:
             grad_fn = jax.value_and_grad(loss_on_microbatch, has_aux=True)
@@ -700,11 +704,16 @@ class Trainer:
             if nan_fault_step is not None:
                 loss = jnp.where(state.step == nan_fault_step, jnp.nan, loss)
 
-            updates, new_opt = self.optimizer.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            # clipping, the update and the precision policy's casts (the
+            # policy wraps the optimizer), and the gradient norm it logs
+            with jax.named_scope("optimizer"):
+                updates, new_opt = self.optimizer.update(grads, opt_state,
+                                                         params)
+                new_params = optax.apply_updates(params, updates)
+                grad_norm = optax.global_norm(grads).astype(jnp.float32)
             metrics = {
                 "loss": loss.astype(jnp.float32),
-                "grad_norm": optax.global_norm(grads).astype(jnp.float32),
+                "grad_norm": grad_norm,
                 **{k: v.astype(jnp.float32) for k, v in extras.items()},
             }
             new_state = TrainState(step=state.step + 1, params=new_params,

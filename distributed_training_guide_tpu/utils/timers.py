@@ -15,6 +15,8 @@ from typing import Callable, Optional
 
 import jax
 
+from .trace import span
+
 
 def device_sync() -> None:
     """Device fence for ``LocalTimer(sync_fn=...)`` — the reference C17
@@ -46,19 +48,35 @@ class LocalTimer:
 
     Usage::
 
-        timers = {k: LocalTimer() for k in ["data", "step"]}
-        with timers["step"]:
+        timers = {k: LocalTimer(name=f"train.{k}") for k in ["data", "step"]}
+        with timers["step"](step=n):
             loss = train_step(state, batch)   # async dispatch
             # sync happens on __exit__
+
+    With a ``name`` the phase is also the host span ``dtg.<name>``
+    (``utils/trace.py``) over exactly the timed interval, so a profiler
+    session sees what the timer measured; calling the timer binds the span's
+    arguments for the next entry.
     """
 
-    def __init__(self, sync_fn: Optional[Callable[[], None]] = None):
+    def __init__(self, sync_fn: Optional[Callable[[], None]] = None,
+                 name: Optional[str] = None):
         self.synchronize = sync_fn or _default_sync
+        self.name = name
         self.measurements: list[float] = []
         self.start_time: Optional[float] = None
+        self._span_args: dict = {}
+        self._span = None
+
+    def __call__(self, **span_args) -> "LocalTimer":
+        self._span_args = span_args
+        return self
 
     def __enter__(self) -> "LocalTimer":
         self.synchronize()
+        if self.name is not None:
+            self._span = span(self.name, **self._span_args)
+            self._span.__enter__()
         self.start_time = time.perf_counter()
         return self
 
@@ -66,6 +84,9 @@ class LocalTimer:
         if traceback is None:
             self.synchronize()
             self.measurements.append(time.perf_counter() - self.start_time)
+        if self._span is not None:
+            self._span.__exit__(exc_type, value, traceback)
+            self._span = None
         self.start_time = None
 
     def avg_elapsed_ms(self) -> float:

@@ -1,0 +1,74 @@
+"""The program's own names in a profiler trace.
+
+One mechanism and no switch. On the host, :func:`span` is a
+``jax.profiler.TraceAnnotation`` named ``dtg.<name>``: without a profiler
+session it is a flag test and records nothing; inside one
+(``--profile-dir``, ``benchmarks/run.py --trace 1``, an operator's
+``jax.profiler.start_trace``) it lands on a host thread's line on the same
+clock as the device's ``XLA Ops`` line. Spans nest by containment on one
+thread. Arguments known only when the work is done go on with
+``set_metadata`` before the ``with`` block ends::
+
+    with span("serve.reserve") as s:
+        grown, preempted = sched.grow_for_decode()
+        s.set_metadata(grown=grown, preempted=preempted)
+
+On the device the names are HLO metadata and cost nothing at run time:
+``jax.named_scope`` per model part (:data:`SCOPES`), a stable ``__name__`` on
+every jitted program (:data:`PROGRAMS`; the trace's ``XLA Modules`` line then
+reads ``jit_train_step`` or ``jit_serve_decode``) and ``name=`` on every
+``pallas_call`` (:data:`KERNELS`). The tuples below are the whole vocabulary;
+tests hold the package to them and the benchmark's readers match on them.
+"""
+from __future__ import annotations
+
+import jax
+
+PREFIX = "dtg."
+
+# host spans, without the prefix (parent first within a family)
+SPANS = (
+    "serve.step", "serve.expire", "serve.restore", "serve.admit",
+    "serve.fork", "serve.prefill", "serve.sample", "serve.draft",
+    "serve.reserve", "serve.build", "serve.dispatch", "serve.wait",
+    "serve.book",
+    "data.assemble", "data.put",
+    "train.data", "train.step", "train.fence", "train.log", "train.ckpt",
+)
+
+# jax.named_scope names: model parts (`layers` is the layer scan's own work,
+# outside any sublayer), the train step's tail, the paged serve path
+SCOPES = (
+    "embed", "layers", "attn", "mlp", "final_norm", "loss_head", "optimizer",
+    "router", "experts", "attend", "kv_write", "sample",
+)
+
+# pallas_call names (ops/)
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend", "gmm",
+           "tgmm", "qmm")
+
+# jitted programs; a name ending in _k, _b or _t takes the static size that
+# keys the program (serve_horizon_k4, serve_prefill_b128, serve_chunk_t64,
+# serve_verify_t5 and its all-greedy twin serve_verify_t5_greedy)
+PROGRAMS = (
+    "train_step", "serve_decode", "serve_horizon_k", "serve_prefill_b",
+    "serve_chunk_t", "serve_verify_t", "serve_commit", "serve_copy",
+    "serve_sample_one", "serve_adapter_insert", "serve_snapshot",
+    "serve_requant", "serve_draft_step", "serve_draft_catchup",
+)
+
+
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A host span ``dtg.<name>`` with ``args`` as its stats."""
+    return jax.profiler.TraceAnnotation(PREFIX + name, **args)
+
+
+def named(fn, name: str):
+    """``fn`` (called positionally) under a stable ``__name__``:
+    ``jax.jit(named(fn, "x"))`` compiles a module called ``jit_x`` whatever
+    the closure, lambda or bound method was called."""
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program

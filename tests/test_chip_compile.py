@@ -190,3 +190,70 @@ def test_quantized_matmul_compiles_and_refuses_narrow_blocks(chip_compile):
     assert "tpu_custom_call" in chip_compile(matmul, *specs(128))
     with pytest.raises(ValueError, match="block width % 128"):
         chip_compile(matmul, *specs(32))
+
+
+# ---- the kernels' own names in the compiled program -------------------------
+
+def _flash_fwd_bwd(q, k, v):
+    return jax.grad(lambda *a: flash_attention(
+        *a, causal=True, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _gmm_fwd_bwd(lhs, rhs, sizes):
+    return jax.grad(lambda l, r: (gmm_mod.grouped_matmul(
+        l, r, sizes, impl="pallas", interpret=False).astype(
+            jnp.float32) ** 2).sum(), argnums=(0, 1))(lhs, rhs)
+
+
+def _qmm(x, q, scale):
+    from types import SimpleNamespace
+
+    return qmm_mod.quantized_matmul(x, SimpleNamespace(q=q, scale=scale),
+                                    impl="pallas", interpret=False)
+
+
+KERNEL_NAME_CASES = {
+    "flash": (_flash_fwd_bwd,
+              [((2, SEQ, HQ, D), jnp.bfloat16)]
+              + [((2, SEQ, HKV, D), jnp.bfloat16)] * 2,
+              ("flash_fwd", "flash_dq", "flash_dkv")),
+    "paged": (lambda *a: paged_flash_attend(*a, interpret=False),
+              [((4, 1, HQ, D), jnp.bfloat16)]
+              + [((128, 16, HKV, D), jnp.bfloat16)] * 2
+              + [((4, 32), jnp.int32), ((4,), jnp.int32)],
+              ("paged_attend",)),
+    "grouped": (_gmm_fwd_bwd,
+                [((1024, 256), jnp.bfloat16), ((8, 256, 256), jnp.bfloat16),
+                 ((8,), jnp.int32)],
+                ("gmm", "tgmm")),
+    "int8": (_qmm, [((8, 1024), jnp.float32), ((1024, 3072), jnp.int8),
+                    ((1024, 24), jnp.float32)], ("qmm",)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_NAME_CASES))
+def test_kernels_carry_their_names(chip_compile, case):
+    """Each ``pallas_call``'s ``name=`` (``utils/trace.py: KERNELS``) names
+    the custom call in the program the chip's compiler makes, which is the
+    name a trace's ``XLA Ops`` line prints: bare (``%flash_fwd.17``) where a
+    ``named_scope`` encloses the call, as in the model, and inside the
+    transform's wrapper (``%transpose_jvp_flash_dq__.1``) where none does, as
+    here. The call is still a ``tpu_custom_call``, which the benchmark's
+    roofline readers match."""
+    import re
+
+    from distributed_training_guide_tpu.utils.trace import KERNELS
+
+    fn, specs, names = KERNEL_NAME_CASES[case]
+    text = chip_compile(fn, *specs)
+    calls = re.findall(r'%([\w.]+) = [^\n]*custom-call\([^\n]*'
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls
+    for name in names:
+        assert name in KERNELS
+        assert any(re.search(rf"(^|_){name}(_|\.|$)", c) for c in calls), (
+            name, calls)
+    for call in calls:      # and no kernel without a name
+        assert any(re.search(rf"(^|_){name}(_|\.|$)", call)
+                   for name in names), call

@@ -1,0 +1,305 @@
+"""The program names its own work (``utils/trace.py``): host spans under a
+profiler session on the CPU, nothing without one, and the scope, program and
+kernel names in the lowered programs. The kernels' names where the chip's
+compiler lowers them are in ``tests/test_chip_compile.py``; the readers of
+these names are under ``tests/benchmarks/``."""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from distributed_training_guide_tpu.models import get_model
+from distributed_training_guide_tpu.parallel import make_mesh, make_plan
+from distributed_training_guide_tpu.serve import Request, ServeEngine
+from distributed_training_guide_tpu.train import Trainer
+from distributed_training_guide_tpu.train.step import lower_step
+from distributed_training_guide_tpu.utils import trace as trace_mod
+from distributed_training_guide_tpu.utils.trace import (KERNELS, PREFIX,
+                                                        PROGRAMS, SCOPES,
+                                                        SPANS, named, span)
+
+SERVE_CHILDREN = {s for s in SPANS if s.startswith("serve.")} - {"serve.step"}
+
+
+def program_events(trace_dir):
+    """``(name, start_ns, end_ns, thread, stats)`` of every ``dtg.`` host
+    event of the one trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    path = next(trace_dir.rglob("*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(PREFIX):
+                    out.append((e.name[len(PREFIX):], e.start_ns,
+                                e.start_ns + e.duration_ns, line.name,
+                                dict(e.stats)))
+    return out
+
+
+def covered(intervals):
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+@pytest.fixture(scope="module")
+def debug_model():
+    bundle = get_model("llama-debug")
+    return bundle, bundle.init(bundle.config, jax.random.key(0))
+
+
+def tight_engine(debug_model, **kw):
+    """Two slots over a pool that holds one and a half requests: the second
+    request's growth preempts, so the run has prefill, decode, a preemption
+    and the re-admission."""
+    bundle, params = debug_model
+    return ServeEngine(bundle, params, n_slots=2, page_size=8, max_len=64,
+                       n_pages=7, **kw)
+
+
+def run_requests(engine, n_new=20):
+    for i, prompt in enumerate(([3, 17, 42, 5, 6, 9, 11], [8, 1, 30, 2])):
+        engine.submit(Request(prompt_ids=prompt, max_new_tokens=n_new,
+                              temperature=0.0, eos_id=None, seed=i))
+    done = []
+    while engine.has_work:
+        done.extend(engine.step())
+    return {r.request_id: list(r.generated_ids) for r in done}
+
+
+# ---- (a) the span tree under a session --------------------------------------
+
+@pytest.mark.parametrize("engine_kw", [{}, {"prefill_chunk": 4},
+                                       {"decode_horizon": 4}],
+                         ids=["bucketed", "chunked", "horizon4"])
+def test_serve_span_tree(debug_model, tmp_path, engine_kw):
+    engine = tight_engine(debug_model, **engine_kw)
+    run_requests(engine, n_new=4)          # compile outside the session
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tokens = run_requests(engine)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(tokens) == 2 and all(len(t) == 20 for t in tokens.values())
+    assert engine.stats()["preemptions"] >= 1
+    events = program_events(tmp_path)
+    names = {e[0] for e in events}
+    assert names <= set(SPANS), names - set(SPANS)
+    want = {"serve.step", "serve.expire", "serve.admit", "serve.prefill",
+            "serve.sample", "serve.reserve", "serve.build", "serve.dispatch",
+            "serve.wait", "serve.book"}
+    assert want <= names, want - names
+    steps = [e for e in events if e[0] == "serve.step"]
+    children = [e for e in events if e[0] in SERVE_CHILDREN]
+    # every iteration number once, in order
+    seqs = [s[4]["seq"] for s in sorted(steps, key=lambda s: s[1])]
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+    # containment: every child lies inside exactly one step, on its thread
+    for name, a, b, thread, _ in children:
+        inside = [s for s in steps
+                  if s[3] == thread and s[1] <= a and b <= s[2]]
+        assert len(inside) == 1, (name, a, b)
+    # the children cover the steps but for a stated remainder: the engine's
+    # own glue between them (slot lists, counters, the latency meter),
+    # under a quarter of the step time at debug width on the CPU
+    step_ns = sum(b - a for _, a, b, _, _ in steps)
+    child_ns = covered((a, b) for _, a, b, _, _ in children)
+    assert child_ns >= 0.75 * step_ns, (child_ns, step_ns)
+    # the per-request spans carry the request, admission its queue wait
+    admits = [e for e in events if e[0] == "serve.admit"]
+    assert {e[4]["request_id"] for e in admits} == set(tokens)
+    assert all(e[4]["queue_ms"] >= 0 for e in admits)
+    assert len(admits) >= 3                # two requests and a re-admission
+    assert all("request_id" in e[4] for e in events
+               if e[0] in ("serve.prefill", "serve.sample"))
+    assert all(e[4]["program"].startswith("serve_") for e in events
+               if e[0] == "serve.dispatch")
+    reserves = [e[4] for e in events if e[0] == "serve.reserve"]
+    assert sum(r.get("preempted", 0) for r in reserves) >= 1
+
+
+def test_train_loop_span_tree(tmp_path, eight_devices):
+    from distributed_training_guide_tpu.train.cli import (get_parser,
+                                                          run_training)
+
+    args = get_parser().parse_args(["-m", "llama-debug"])
+    args.dataset_name, args.seq_length, args.batch_size = "synthetic:60000", 64, 1
+    args.num_epochs, args.log_freq, args.max_steps = 1, 1, 3
+    args.save_dir, args.experiment_name, args.ckpt_freq = str(tmp_path), "t", 3
+    trace_dir = tmp_path / "trace"
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        out = run_training(args, lambda: make_plan("ddp", make_mesh()))
+    finally:
+        jax.profiler.stop_trace()
+    assert out["host_state"]["global_step"] == 3
+    events = program_events(trace_dir)
+    names = {e[0] for e in events}
+    assert names <= set(SPANS), names - set(SPANS)
+    by_name = lambda n: sorted((e for e in events if e[0] == n),
+                               key=lambda e: e[1])
+    for name in ("train.data", "train.step", "train.fence", "train.log"):
+        assert [e[4]["step"] for e in by_name(name)] == [1, 2, 3], name
+    assert [e[4]["step"] for e in by_name("train.ckpt")] == [3]
+    # the fence (the host read of the loss) lies inside its step's span, the
+    # loader's work inside the data span or ahead of it (prefetch)
+    for fence, step in zip(by_name("train.fence"), by_name("train.step")):
+        assert step[1] <= fence[1] and fence[2] <= step[2]
+    puts, assembles = by_name("data.put"), by_name("data.assemble")
+    assert len(puts) >= 3 and len(assembles) >= len(puts)
+    for a in assembles:
+        assert any(p[1] <= a[1] and a[2] <= p[2] for p in puts)
+    # data, step, log and checkpoint follow each other with nothing else of
+    # the loop between them: they cover the loop from the first step's data
+    # to the last step's log but for the heartbeat and the progress bar
+    loop = [e for e in events if e[0] in ("train.data", "train.step",
+                                          "train.log", "train.ckpt")]
+    lo, hi = min(e[1] for e in loop), max(e[2] for e in loop)
+    assert covered((e[1], e[2]) for e in loop) >= 0.75 * (hi - lo)
+
+
+# ---- (b) no session: nothing recorded, nothing changed ---------------------
+
+def test_span_records_nothing_without_a_session(debug_model, tmp_path):
+    engine = tight_engine(debug_model)
+    with span("serve.step", seq=0):
+        run_requests(engine, n_new=4)
+    jax.profiler.start_trace(str(tmp_path))
+    jnp.zeros(4).block_until_ready()
+    jax.profiler.stop_trace()
+    assert program_events(tmp_path) == []
+
+
+class _NullSpan(contextlib.nullcontext):
+    def __enter__(self):
+        return self
+
+    def set_metadata(self, **_):
+        pass
+
+
+def test_tokens_do_not_depend_on_the_spans(debug_model, monkeypatch):
+    with_spans = run_requests(tight_engine(debug_model))
+    from distributed_training_guide_tpu.serve import engine, scheduler
+
+    for mod in (engine, scheduler):
+        monkeypatch.setattr(mod, "span", lambda name, **args: _NullSpan())
+    assert run_requests(tight_engine(debug_model)) == with_spans
+
+
+# ---- (c) names in the lowered programs -------------------------------------
+
+def scope_components(text):
+    """Every component of every location path in a lowered program's debug
+    text, wrappers (``transpose(jvp(attn))``) taken off."""
+    out = set()
+    for path in re.findall(r'loc\("([^"]+)"', text):
+        for part in path.split("/"):
+            out.add(re.sub(r"^(?:[\w.\-]+\()*([^()]*)\)*$", r"\1", part))
+    return out
+
+
+def test_train_step_carries_scopes_and_module_name():
+    plan = make_plan("single", make_mesh(devices=jax.devices()[:1]))
+    trainer = Trainer(bundle=get_model("llama-debug"),
+                      optimizer=optax.adamw(1e-3), plan=plan, remat=True,
+                      loss_chunks=4)
+    lowered, _ = lower_step(trainer, global_batch=2, seq_length=64)
+    text = lowered.as_text(debug_info=True)
+    assert "module @jit_train_step" in text
+    want = {"embed", "layers", "attn", "mlp", "final_norm", "loss_head",
+            "optimizer"}
+    assert want <= scope_components(text), want - scope_components(text)
+    assert want <= set(SCOPES)
+
+
+def test_moe_step_carries_router_and_experts():
+    plan = make_plan("single", make_mesh(devices=jax.devices()[:1]))
+    trainer = Trainer(bundle=get_model("moe-debug"),
+                      optimizer=optax.adamw(1e-3), plan=plan)
+    lowered, _ = lower_step(trainer, global_batch=2, seq_length=32)
+    found = scope_components(lowered.as_text(debug_info=True))
+    assert {"router", "experts", "attn", "layers", "loss_head"} <= found
+
+
+@pytest.mark.parametrize("family", ["gpt2-debug", "neox-debug"])
+def test_other_families_carry_the_shared_scopes(family):
+    bundle = get_model(family)
+    params = jax.eval_shape(lambda: bundle.init(bundle.config,
+                                                jax.random.key(0)))
+    ids = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    text = jax.jit(lambda p, x: bundle.apply(bundle.config, p, x)).lower(
+        params, ids).as_text(debug_info=True)
+    assert {"embed", "attn", "mlp", "final_norm",
+            "loss_head"} <= scope_components(text)
+
+
+def test_serve_programs_have_stable_names_and_scopes(debug_model):
+    bundle, params = debug_model
+    engine = ServeEngine(bundle, params, n_slots=2, page_size=8, max_len=64,
+                         prefill_chunk=4, speculate="ngram", spec_k=2)
+    run_requests(engine, n_new=6)
+    plain = tight_engine(debug_model, decode_horizon=2)
+    run_requests(plain, n_new=6)
+    programs = engine.programs
+    arrays = {k: jnp.asarray(v)
+              for k, v in engine.scheduler.decode_arrays().items()}
+    text = programs._decode_fn.lower(
+        programs.params, engine.pages["k"], engine.pages["v"],
+        *(arrays[k] for k in ("tokens", "lengths", "tables", "seeds",
+                              "temps", "top_ks", "top_ps", "actives"))
+    ).as_text(debug_info=True)
+    assert "module @jit_serve_decode" in text
+    want = {"embed", "layers", "attn", "attend", "kv_write", "mlp",
+            "final_norm", "loss_head", "sample"}
+    assert want <= scope_components(text), want - scope_components(text)
+    # every jitted program of the two engines, by the name jit gave it
+    fns = [programs._decode_fn, programs._commit_fn, programs._copy_fn,
+           programs._sample_one, *programs._chunk_fns.values(),
+           *programs._verify_fns.values(),
+           *plain.programs._prefill_fns.values(),
+           *plain.programs._horizon_fns.values()]
+    got = {fn.__name__ for fn in fns}
+    assert {"serve_decode", "serve_commit", "serve_copy", "serve_sample_one",
+            "serve_chunk_t4", "serve_verify_t3_greedy",
+            "serve_horizon_k2"} <= got
+    assert any(n.startswith("serve_prefill_b") for n in got)
+    for name in got:
+        assert name != "fn" and "lambda" not in name
+        assert any(name == p or (name.startswith(p) and p[-1] in "kbt")
+                   for p in PROGRAMS), name
+
+
+def test_named_gives_jit_the_name():
+    fn = jax.jit(named(lambda x: x + 1, "serve_copy"))
+    assert "module @jit_serve_copy" in fn.lower(jnp.zeros(2)).as_text()
+
+
+def test_the_vocabulary_is_what_the_package_uses():
+    """``grep`` over the package: every ``span("...")``, ``named_scope("...")``
+    and ``pallas_call`` name is in the tuples, and ``TraceAnnotation`` is
+    used by ``utils/trace.py`` alone."""
+    from pathlib import Path
+
+    root = Path(trace_mod.__file__).resolve().parents[1]
+    spans, scopes, kernels, annot = set(), set(), set(), []
+    for path in root.rglob("*.py"):
+        src = path.read_text()
+        if "TraceAnnotation" in src:
+            annot.append(path.name)
+        spans |= set(re.findall(r'\bspan\(\s*"([\w.]+)"', src))
+        scopes |= set(re.findall(r'named_scope\("(\w+)"\)', src))
+        if path.parent.name == "ops":
+            kernels |= set(re.findall(r'^\s+name="(\w+)",$', src, re.M))
+    assert annot == ["trace.py"]
+    assert spans | {"train.data", "train.step"} == set(SPANS)
+    assert scopes == set(SCOPES)
+    assert kernels == set(KERNELS)
