@@ -1,7 +1,7 @@
 """What the kernels in ``ops/`` share: how ``interpret`` is resolved, a log
 line that says once which path a program took, the record of which attention
 implementations a program traced, and the one in-kernel idiom
-the chip's compiler forced on two of them (``lane_column``).
+the chip's compiler forced on the int8 matmul (``lane_column``).
 
 A Pallas kernel runs compiled (Mosaic) on a TPU backend and interpreted
 everywhere else — the interpreter is how the CPU test suite checks kernel
@@ -83,8 +83,7 @@ def lane_column(table, index):
     VMEM, as a ``[rows, 1]`` vector — for use INSIDE a kernel. A one-lane
     block of the table is not a shape Mosaic tiles, so the table is DMA'd
     whole and the column picked with a masked lane reduction, which needs
-    no dynamic lane slice. (The per-head scale of an int8 KV page, the
-    per-block scale of an int8 weight.)"""
+    no dynamic lane slice. (The per-block scale of an int8 weight.)"""
     lane = jax.lax.broadcasted_iota(jnp.int32, table.shape, 1)
     return jnp.sum(jnp.where(lane == index, table, 0.0), axis=1,
                    keepdims=True)

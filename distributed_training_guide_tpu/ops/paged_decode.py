@@ -8,68 +8,94 @@ before attending — per forward that is an O(n_slots * max_len) HBM
 round-trip (read the pages, WRITE the gathered copy, read it back),
 whatever the live context actually is. This kernel is the PagedAttention
 analog of ``ops/flash_attention.py`` (Kwon et al., arXiv:2309.06180;
-FlashAttention-2, Dao arXiv:2307.08691): the grid walks
-(slot, kv-head, kv-page), the block table rides as a SCALAR-PREFETCH
-operand so each kv BlockSpec DMAs the slot's next *physical* page
-directly from the pool, and the online-softmax partial (m, l, acc) is
-carried across page steps in VMEM scratch — the same accumulation
-``_fwd_kernel`` uses. Nothing context-sized is ever materialized: reads
-are O(live pages) per forward and the only write is the [S, T, Hq, D]
-output.
+FlashAttention-2, Dao arXiv:2307.08691): online softmax over the pages a
+slot's block table names, nothing context-sized ever materialized.
+
+How the table is walked and in what units the pool is read:
+
+- The grid is ``(slot, head block)``; for a decode step the head block is
+  all of the device's kv heads, so a step of the grid is one slot. Inside
+  it a ``fori_loop`` walks ONLY the pages the slot's length makes live:
+  from the page holding the oldest position any row's window still
+  reaches (page 0 without a window) to the page holding position
+  ``lengths[s] + T - 1``. Its bounds are read from the scalar-prefetched
+  ``lengths`` and band, so a short slot costs a short walk and a table's
+  dead columns cost nothing.
+- The pool stays in HBM in the layout it is stored in
+  (``memory_space=pl.ANY``; ``[P, page, Hkv, D]`` seen as
+  ``[P, page*Hkv, D]``, the same bytes: no copy before the call). A page
+  is read for ALL kv heads in one DMA (``pool.at[tables[s, i]]``, 128 KB
+  of bf16 at 32 heads of 128) into a double-buffered VMEM block of
+  ``n`` pages (4 at most), the next block in flight while this one is
+  attended to.
+  Sub-pages of the last block past the live range read the trash page 0
+  (finite, and masked like every position past a row's own).
+- A page's rows are ``(position, head)`` pairs. For ``hb`` heads at once
+  the products are ``q[hb*T*G, D] . rows[page*hb, D]^T`` and
+  ``p[hb*T*G, page*hb] . rows[page*hb, D]``, with the pairs whose heads
+  differ masked like positions outside the band (their ``p`` is exactly
+  0). ``hb`` is all heads while the query tile is small (decode, verify:
+  the MXU streams each k and v row once either way, and no row is picked
+  out of a page); a large tile (a prefill chunk) takes the heads that
+  share a 32-bit word of the pool's dtype (1 fp32, 2 bf16, 4 int8),
+  picked out of the VMEM block with one strided word load, so its
+  products are not wasted on the mask (head_dim 128, whose rows are one
+  lane tile, which that load needs; a wider head keeps all heads in one
+  product at every T). How many heads a grid step holds and how many
+  pages a block holds follow from the static shapes and a VMEM budget
+  (``_plan``); nothing is chosen by a caller.
+
+The arithmetic is what it was: scores, running max and sum, ``p`` and
+the accumulator in fp32. q and k meet the MXU in their stored dtype when
+both are bf16 (bf16 products in an fp32 accumulator are exact); ``p``
+stays fp32.
 
 Scope — the whole [S, T] serve contract, one kernel form:
 
-- **T == 1** is the batched decode step (the original block_q==1
-  specialist, bitwise unchanged: the query tile is the [groups, hd] GQA
-  group and each page step's math is identical op for op).
-- **T > 1** carries a ``[T*groups, hd]`` query tile per (slot, kv-head):
-  slot s's row r is its token ``r // groups`` at absolute position
-  ``lengths[s] + r // groups``, so the shared band machinery
-  (`_band_live` at block_q=T for the tile skip, `_band_mask` generalized
-  per query row by ``_rows_band_mask``) drives each row's causal
-  frontier independently — within-tile causality included, because the
-  caller scatters the T new tokens into the pool BEFORE the attend and
-  the mask is pure position arithmetic. This is the speculative
-  verification forward (``ModelPrograms.verify_for``, T = k+1 candidates
-  per slot) and the chunked-prefill chunk ([1, T] attending over its own
-  tokens plus the committed history) — both previously exiled to the
-  ~3x-byte gather path, and both now reading the context exactly once
-  per forward with the read amortized over T tokens.
+- **T == 1** is the batched decode step: the query tile is the
+  ``[Hkv*groups, D]`` block of every head's GQA group.
+- **T > 1** carries ``T*groups`` rows per kv head: slot s's row r is its
+  token ``r // groups`` at absolute position ``lengths[s] + r // groups``,
+  so each row's causal frontier (and window edge) is its own — within-tile
+  causality included, because the caller scatters the T new tokens into
+  the pool BEFORE the attend and the mask is pure position arithmetic.
+  This is the speculative verification forward
+  (``ModelPrograms.verify_for``, T = k+1 candidates per slot) and the
+  chunked-prefill chunk ([1, T] attending over its own tokens plus the
+  committed history).
 
-Feature parity with the serving attend contract rides the multi-token
-form unchanged (Gemma-2 verifies and chunk-prefills through this):
-``window`` (static, or traced per-layer schedules riding the same [3]
-int32 band operand the training kernels use), ``scale``, ``softcap``.
-Positions past a query row's own (trash-page rows, a final chunk's
-``n_valid`` pad tail, stale rejected-draft garbage) are cut by the
-per-row causal mask exactly as in the gather path — pad query rows
-compute ignored garbage over the SAME pool bytes the gather view would
-read, so flash-vs-gather parity holds on every row, not just live ones.
+Feature parity with the serving attend contract rides every T unchanged
+(Gemma-2 verifies and chunk-prefills through this): ``window`` (static,
+or traced per-layer schedules riding the same [3] int32 band operand the
+training kernels use), ``scale``, ``softcap``. Positions past a query
+row's own (trash-page rows, a final chunk's ``n_valid`` pad tail, stale
+rejected-draft garbage) are cut by the per-row causal mask exactly as in
+the gather path — pad query rows compute ignored garbage over the SAME
+pool bytes the gather view would read, so flash-vs-gather parity holds on
+every row, not just live ones.
 
 QUANTIZED pools (``serve/kv_pages.py`` ``kv_dtype="int8"``): pass the
 per-(position, kv-head) fp32 scales as ``k_scale``/``v_scale``
-``[P, page, Hkv]`` and the kernel dequantizes IN the tile loop — the
-scale blocks ride their own block-table BlockSpec, so step (s, h, m)
-DMAs physical page ``tables[s, m]``'s payload AND its scale row in the
-same prefetch-driven pattern, multiplies them in fp32 inside the
-online-softmax accumulation, and still writes only the float output.
-The read drops to ~1/4 of the fp32 bytes (int8 payload + 4 B/vector
-scales) with no float pool ever materialized — at any T.
+``[P, page, Hkv]``. They ride the same walk, one small DMA a page beside
+the payload's, and are applied where they are lane vectors: the k scale
+to the score columns, the v scale to ``p``'s columns (``q . (k*s)`` is
+``(q . k) * s``), so the int8 payload meets the MXU as it is read and no
+float pool is ever materialized — at any T.
 
 ``interpret=True`` runs the kernel on CPU — the tier-1 parity grids in
 ``tests/test_paged_decode.py`` pin it against the XLA gather path at
-1e-5 across GQA/window/scale/softcap, shuffled physical layouts, and
-multi-token tiles with ``n_valid`` tails.
+1e-5 across GQA/MHA/window/scale/softcap, shuffled physical layouts,
+lengths either side of every page and block edge, and multi-token tiles
+with ``n_valid`` tails.
 
 Under the SHARDED page pool (``serve/sharding.py``) this kernel runs
 inside a full-manual shard_map with a per-chip pool slice: GSPMD cannot
 partition a ``pallas_call``, so the manual region is what takes the
 kernel from "replicated over a replicated pool" to "each chip reads its
-own kvh/tp heads' pages". Nothing here changes — the grid's kv-head axis
-is just smaller (possibly 1), block tables/lengths arrive replicated,
-and the GQA group count is per-KV-head and therefore shard-invariant;
-the chunk and verify programs ride the same manual region the decode
-does.
+own kvh/tp heads' pages". Nothing here changes — a page just holds fewer
+heads (possibly 1), block tables/lengths arrive replicated, and the GQA
+group count is per-KV-head and therefore shard-invariant; the chunk and
+verify programs ride the same manual region the decode does.
 """
 from __future__ import annotations
 
@@ -82,105 +108,196 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-from .dispatch import lane_column, resolve_interpret
-from .flash_attention import (NEG_INF, _band_live, _pack_band,
-                              check_static_window)
+from .dispatch import resolve_interpret
+from .flash_attention import NEG_INF, _pack_band, check_static_window
+
+# _plan's budgets. A head block's q, out and accumulator tiles and the
+# walk's page buffers each stay under their share of VMEM_LIMIT (the v5e
+# has 128 MiB; the compiler's default plan is 16).
+VMEM_LIMIT = 64 * 2 ** 20
+TILE_BUDGET = 8 * 2 ** 20       # q + out (double-buffered) + m, l, acc
+PAGES_BUDGET = 4 * 2 ** 20      # k + v page buffers, both halves
+SCORE_BUDGET = 2 * 2 ** 20      # a block's fp32 scores
+ROWS_ALL_HEADS = 256            # query rows up to which hb = all heads
+MAX_BLOCK_PAGES = 4             # the block loop is unrolled over these
+DEPTH = 2                       # page blocks in flight
 
 
-def _rows_band_mask(window, m_idx, block_q, groups, page, q_off):
-    """``ops/flash_attention._band_mask`` generalized to the paged query
-    tile's ``[block_q * groups, page]`` row layout: the GQA group axis is
-    folded into rows, so query row r is the slot's token ``r // groups``
-    at absolute position ``q_off + r // groups``, and key column j is
-    position ``m_idx * page + j``. Same (causal, ``< window``) band,
-    driven per query row — each row's causal frontier is its own
-    ``length + t``. ``window`` is the kernel's [3] SMEM band value (2**30
-    encodes "no window"), so the band term is always applied."""
-    shape = (block_q * groups, page)
-    q_pos = q_off + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // groups
-    k_pos = m_idx * page + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return (q_pos >= k_pos) & ((q_pos - k_pos) < window)
+def _lane_tiles(columns: int) -> int:
+    """``columns`` rounded up to whole 128-lane tiles."""
+    return -(-columns // 128) * 128
 
 
-def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_ref, v_ref, *rest,
-                   scale, softcap, page, num_page_blocks, quantized,
-                   block_q, groups):
-    """Grid (slot, kv_head, page_block); page_block innermost so the
-    (m, l, acc) scratch carries the online softmax across the slot's
-    pages. The query tile is ``[block_q * groups, hd]`` — block_q tokens
-    per slot with the GQA group folded into rows — and the tile's first
-    token sits at ``lengths[slot]``, which drives the shared band
-    machinery per row. block_q == 1 is the original decode specialist,
-    op for op. Under ``quantized`` two more inputs follow k/v: the
-    page's k/v scale rows, DMA'd through the same block-table index map
-    and multiplied into the int8 payload right here in the tile loop."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+def _plan(t, groups, hkv, d, page, max_pages, q_dtype, pool_dtype):
+    """Block parameters from the static shapes: ``(hs, hb, n)`` = kv heads
+    a grid step holds, kv heads one product takes (``hs % hb == 0``), pages
+    a block of the walk holds."""
+    tg = t * groups
+    words = 4 // jnp.dtype(pool_dtype).itemsize     # rows sharing 32 bits
+    # the strided load takes a VMEM block whose rows are one 128-lane tile
+    if hkv * tg <= ROWS_ALL_HEADS or hkv % words or d != 128:
+        hb = hkv
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        hb = words
+    q_item = jnp.dtype(q_dtype).itemsize
+    per_head = tg * (4 * d * q_item + d * 4 + 2 * 128 * 4)
+    # a head block's rows are a block of q's second-minor dimension
+    fits = [h for h in range(hb, hkv + 1, hb) if hkv % h == 0
+            and h * per_head <= TILE_BUDGET
+            and (h == hkv or (h * tg) % 16 == 0)]
+    hs = max(fits) if fits else hkv
+    page_bytes = page * hkv * d * jnp.dtype(pool_dtype).itemsize
+    n = min(MAX_BLOCK_PAGES, max_pages,
+            PAGES_BUDGET // (2 * DEPTH * page_bytes),
+            SCORE_BUDGET // (hb * tg * _lane_tiles(page * hb) * 4))
+    return hs, hb, max(1, n)
+
+
+def _head_rows(buf, slot, i, head, *, hb, hkv, page):
+    """Rows ``(position, head .. head + hb)`` of sub-page i of a VMEM page
+    block, ``[page * hb, D]``. All heads are the page as it lies; fewer are
+    the heads of one 32-bit word, one strided word load."""
+    ref = buf.at[slot, i]                            # [page * hkv, D]
+    if hb == hkv:
+        return ref[...]
+    if hb == 1:
+        return ref[pl.ds(head, page, stride=hkv), :]
+    words = ref.bitcast(jnp.int32)                   # [page * hkv / hb, D]
+    picked = words[pl.ds(head // hb, page, stride=hkv // hb), :]
+    return pltpu.bitcast(picked, buf.dtype)
+
+
+def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_hbm, v_hbm, *rest,
+                   scale, softcap, page, hkv, hs, hb, n, quantized,
+                   block_q, groups):
+    """Grid (slot, head block). The walk over the slot's live pages is the
+    ``fori_loop`` below; (m, l, acc) carry the online softmax across its
+    blocks in VMEM scratch. Query row ``r`` of a head is the slot's token
+    ``r // groups`` at position ``lengths[slot] + r // groups``. Under
+    ``quantized`` the pages' k/v scale rows come along the same walk."""
+    if quantized:
+        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sems,
+         m_scr, l_scr, acc_scr) = rest
+    else:
+        o_ref, kbuf, vbuf, sems, m_scr, l_scr, acc_scr = rest
     s_idx = pl.program_id(0)
     h_idx = pl.program_id(1)
-    m_idx = pl.program_id(2)
+    max_pages = tabs_ref.shape[1]
+    tg = block_q * groups
     q_pos = lens_ref[s_idx]          # the FIRST new token's position; row
                                      # r sits at q_pos + r // groups
     window = band_ref[0]             # [window, q_off, k_off] contract;
                                      # 2**30 encodes "no window"
+    # live pages [lo, hi): the newest row's frontier is q_pos + block_q - 1,
+    # the oldest row's window edge is q_pos - (window - 1)
+    hi = jnp.minimum(pl.cdiv(q_pos + block_q, page), max_pages)
+    lo = jnp.minimum(jnp.maximum(q_pos - (window - 1), 0) // page, hi - 1)
+    n_blocks = pl.cdiv(hi - lo, n)
 
-    @pl.when(m_idx == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # page fully outside every row's (causal, window) band -> no compute:
-    # the newest row's frontier is q_pos + block_q - 1, the oldest row's
-    # window edge is q_pos - (window - 1) — exactly _band_live at
-    # block_q = T. Dead tiles past the slot's table alias the trash page
-    # (table rows are 0-filled), so consecutive skipped steps
-    # re-reference one block.
-    live = _band_live(True, window, 0, m_idx, block_q, page, q_off=q_pos)
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)          # [T*G, D]
-        k = k_ref[0].astype(jnp.float32)             # [page, D]
-        if quantized:   # in-tile dequant: int8 payload x per-vector scale
-            k = k * lane_column(ks_ref[0], h_idx)   # [page, Hkv] -> head
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap is not None:  # Gemma-2: tanh cap BEFORE the mask
-            s = jnp.tanh(s / softcap) * softcap
-        # [T*G, page] mask: each query row's own causal/window frontier
-        mask = _rows_band_mask(window, m_idx, block_q, groups, page, q_pos)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[:, 0:1]                       # [T*G, 1]
-        l_prev = l_scr[:, 0:1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                       # [T*G, page]
-        # a live page can still be fully masked for some rows (the
-        # window's lower edge, or an early row of a tile kept live by a
-        # later one): exp(NEG_INF - NEG_INF) = 1 would poison l — zero
-        # masked lanes explicitly, as the training kernel does for SWA
-        # tiles
-        p = jnp.where(mask, p, 0.0)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)             # [page, D]
+    def copies(b, slot):
+        """Block b's DMAs into buffer half ``slot``: n pages of k and of
+        v through the table (and their scale rows)."""
+        pairs = [(k_hbm, kbuf), (v_hbm, vbuf)]
         if quantized:
-            v = v * lane_column(vs_ref[0], h_idx)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            pairs += [(ks_hbm, ksbuf), (vs_hbm, vsbuf)]
+        out = []
+        for i in range(n):
+            col = lo + b * n + i
+            # past the live range: the trash page (table tails name it too)
+            phys = jnp.where(col < hi,
+                             tabs_ref[s_idx, jnp.minimum(col, max_pages - 1)],
+                             0)
+            for j, (hbm, buf) in enumerate(pairs):
+                out.append(pltpu.make_async_copy(
+                    hbm.at[phys], buf.at[slot, i], sems.at[j, slot]))
+        return out
 
-    @pl.when(m_idx == num_page_blocks - 1)
-    def _finalize():
-        l = l_scr[:, 0:1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    # a product's rows are (head, token, group), its columns (position,
+    # head) of one page. rel = the row's token minus the column's position
+    # in the page, or far below zero where the heads differ, so that one
+    # shifted comparison is the (head, causal, window) mask of any page
+    shape = (hb * tg, page * hb)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    rel = jnp.where(row // tg == col % hb,
+                    (row % tg) // groups - col // hb, -2 ** 30)
+
+    # bf16 q and k meet the MXU as stored (their products are exact in its
+    # fp32 accumulator); anything else as fp32
+    mxu = (jnp.bfloat16 if q_ref.dtype == kbuf.dtype == jnp.bfloat16
+           else jnp.float32)
+
+    for c in copies(0, 0):
+        c.start()
+
+    def block(b, carry):
+        slot = jax.lax.rem(b, DEPTH)
+
+        @pl.when(b + 1 < n_blocks)
+        def _prefetch():
+            for c in copies(b + 1, jax.lax.rem(b + 1, DEPTH)):
+                c.start()
+
+        for c in copies(b, slot):
+            c.wait()
+        first = q_pos - (lo + b * n) * page   # q_pos seen from the block
+        for g in range(hs // hb):
+            rows = slice(g * hb * tg, (g + 1) * hb * tg)
+            head = h_idx * hs + g * hb
+            q = q_ref[0, rows, :].astype(mxu)
+            scores, masks = [], []
+            for i in range(n):
+                k = _head_rows(kbuf, slot, i, head, hb=hb, hkv=hkv, page=page)
+                s = jax.lax.dot_general(
+                    q, k.astype(mxu), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quantized:   # the k scale, on the score's columns
+                    s = s * ksbuf[slot, i, pl.ds(head // hb, 1),
+                                  :page * hb]
+                if softcap is not None:  # Gemma-2: tanh cap BEFORE the mask
+                    s = jnp.tanh(s / softcap) * softcap
+                x = rel + (first - i * page)
+                mask = (x >= 0) & (x < window)
+                scores.append(jnp.where(mask, s, NEG_INF))
+                masks.append(mask)
+
+            m_prev = m_scr[rows, 0:1]                    # [hb*T*G, 1]
+            l_prev = l_scr[rows, 0:1]
+            m_new = m_prev
+            for s in scores:
+                m_new = jnp.maximum(m_new, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev
+            acc = acc_scr[rows, :] * alpha
+            for i, (s, mask) in enumerate(zip(scores, masks)):
+                # a live page can still be fully masked for some rows (the
+                # window's lower edge, another head's columns, an early row
+                # of a tile kept live by a later one): exp(NEG_INF -
+                # NEG_INF) = 1 would poison l — zero masked lanes
+                # explicitly, as the training kernel does for SWA tiles
+                p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+                l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
+                if quantized:   # the v scale, on p's columns
+                    p = p * vsbuf[slot, i, pl.ds(head // hb, 1),
+                                  :page * hb]
+                v = _head_rows(vbuf, slot, i, head, hb=hb, hkv=hkv, page=page)
+                acc = acc + jax.lax.dot_general(
+                    p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            acc_scr[rows, :] = acc
+            m_scr[rows, :] = jnp.broadcast_to(m_new, (hb * tg, 128))
+            l_scr[rows, :] = jnp.broadcast_to(l_new, (hb * tg, 128))
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    l = l_scr[:, 0:1]
+    safe_l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
 PAGED_GATE = "head_dim % 128 == 0 and page_size % 8 == 0"
@@ -189,14 +306,13 @@ PAGED_GATE = "head_dim % 128 == 0 and page_size % 8 == 0"
 def paged_decode_eligible(head_dim: int, page_size: int) -> bool:
     """Shape gate for the COMPILED kernel (the interpret path takes any
     shape), set from what the v5e compiler accepts
-    (``tests/test_chip_compile.py``). The pool rides as
-    ``[P, page, Hkv*D]`` and head h's kv block is the ``[page, D]`` lane
-    window at ``h*D``: a lane-dim block must be a multiple of 128, so
-    head_dim 64 models take the gather path. The page axis is a
-    whole-dimension block, which Mosaic tiles for fp32, bf16 and int8
-    payloads alike at every page size the compiler was shown (8..128), so
-    one rule serves float and quantized pools. T-independent by
-    construction (the query-tile row count only sizes VMEM scratch), which
+    (``tests/test_chip_compile.py``). A page is DMA'd as its
+    ``[page * Hkv, D]`` rows: D is the lane dimension of the VMEM block and
+    of both products, so it is whole 128-lane tiles and head_dim 64 models
+    take the gather path; the rows are whole sublane tiles of fp32, bf16
+    and int8 payloads at every page size the compiler was shown (8..128),
+    so one rule serves float and quantized pools. T-independent by
+    construction (the query-tile row count only sizes VMEM blocks), which
     is what lets ``attend_impl='auto'`` resolve decode, verify, and chunk
     forwards to the SAME family: a shape either takes the kernel for all
     three or for none."""
@@ -244,7 +360,7 @@ def paged_flash_attend(
     if squeeze:
         q = q[:, None]
     s, t, hq, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    n_phys, page, hkv, _ = k_pages.shape
     m = tables.shape[1]
     if hkv < 1 or hq % hkv:
         # a silent floor-division here would drop query heads (the
@@ -265,58 +381,58 @@ def paged_flash_attend(
     band = _pack_band(window)     # [window|2**30, 0, 0] int32 — the same
                                   # dynamic-band contract as the training
                                   # kernels; traced per-layer windows ride it
-    # fold (token, group) into one row axis per (slot, kv-head): row
-    # r = t * groups + g, so the kernel recovers the token as r // groups.
-    # For T == 1 the transpose is a no-op and qr is byte-identical to the
-    # original decode layout [s, hkv, groups, d].
+    hs, hb, n = _plan(t, groups, hkv, d, page, m, q.dtype, k_pages.dtype)
+    # rows (head, token, group): row r of a kv head is token r // groups.
+    # For T == 1 the transpose is a no-op.
     qr = (q.reshape(s, t, hkv, groups, d)
-           .transpose(0, 2, 1, 3, 4).reshape(s, hkv, tg, d))
+           .transpose(0, 2, 1, 3, 4).reshape(s, hkv * tg, d))
 
     kernel = functools.partial(_attend_kernel, scale=scale, softcap=softcap,
-                               page=page, num_page_blocks=m,
+                               page=page, hkv=hkv, hs=hs, hb=hb, n=n,
                                quantized=quantized, block_q=t, groups=groups)
-    # the point of the kernel: the kv BlockSpecs read THROUGH the block
-    # table — step (s, h, m) DMAs physical page tables[s, m]; a quantized
-    # pool's scale rows ride the SAME index map as two more operands
-    # the pool rides as [P, page, Hkv*D] (a free reshape): head h's
-    # [page, D] window is then a lane-dim block, which Mosaic tiles; a
-    # one-row block of the Hkv axis of the 4-D pool is refused
-    table_kv = pl.BlockSpec((1, page, d),
-                            lambda s_, h, m_, lens, tabs, band_:
-                            (tabs[s_, m_], 0, h))
-    table_scale = pl.BlockSpec((1, page, hkv),
-                               lambda s_, h, m_, lens, tabs, band_:
-                               (tabs[s_, m_], 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, tg, d),
-                     lambda s_, h, m_, lens, tabs, band_: (s_, h, 0, 0)),
-        table_kv,
-        table_kv,
-    ]
-    n_phys = k_pages.shape[0]
-    operands = [qr, k_pages.reshape(n_phys, page, hkv * d),
-                v_pages.reshape(n_phys, page, hkv * d)]
+    # the pool is handed over where it lies: a page's (position, head)
+    # pairs as rows, the same bytes as [P, page, Hkv, D]
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    tile = pl.BlockSpec((1, hs * tg, d),
+                        lambda s_, h, lens, tabs, band_: (s_, h, 0))
+    in_specs = [tile, in_hbm, in_hbm]
+    operands = [qr, k_pages.reshape(n_phys, page * hkv, d),
+                v_pages.reshape(n_phys, page * hkv, d)]
+    scratch = [pltpu.VMEM((DEPTH, n, page * hkv, d), k_pages.dtype),
+               pltpu.VMEM((DEPTH, n, page * hkv, d), v_pages.dtype)]
     if quantized:
-        in_specs += [table_scale, table_scale]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # a page's scales as one lane vector per product: [hkv/hb,
+        # page * hb], columns (position, head) like the score's, padded
+        # to whole 128-lane tiles (what a DMA moves)
+        width = _lane_tiles(page * hb)
+
+        def lanes(x):
+            x = (x.astype(jnp.float32).reshape(n_phys, page, hkv // hb, hb)
+                  .transpose(0, 2, 1, 3)
+                  .reshape(n_phys, hkv // hb, page * hb))
+            return jnp.pad(x, ((0, 0), (0, 0), (0, width - page * hb)))
+        in_specs += [in_hbm, in_hbm]
+        operands += [lanes(k_scale), lanes(v_scale)]
+        scratch += [pltpu.VMEM((DEPTH, n, hkv // hb, width),
+                               jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((4 if quantized else 2, DEPTH)),
+        pltpu.VMEM((hs * tg, 128), jnp.float32),   # running max
+        pltpu.VMEM((hs * tg, 128), jnp.float32),   # running sum
+        pltpu.VMEM((hs * tg, d), jnp.float32),     # output accumulator
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # lengths, tables, band
-        grid=(s, hkv, m),
+        grid=(s, hkv // hs),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, tg, d),
-                               lambda s_, h, m_, lens, tabs, band_:
-                               (s_, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tg, 128), jnp.float32),   # running max
-            pltpu.VMEM((tg, 128), jnp.float32),   # running sum
-            pltpu.VMEM((tg, d), jnp.float32),     # output accumulator
-        ],
+        out_specs=tile,
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, hkv, tg, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, hkv * tg, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="paged_attend",
     )(lengths.astype(jnp.int32), tables.astype(jnp.int32), band, *operands)

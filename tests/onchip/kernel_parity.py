@@ -10,7 +10,9 @@ smoke's own shapes, through the functions the entry points call:
 
 - ``serve/kv_pages.paged_attend(impl="flash")`` vs ``impl="xla"`` (the gather
   reference) at qwen3-0.6b's heads (16/8 of 128), fp32 pool, page 16, T = 1
-  (decode) and T = 64 (a prefill chunk), slots of several lengths;
+  (decode) and T = 64 (a prefill chunk), slots of several lengths, and the
+  benchmark's serve cell (16 slots of 32/32 heads, bf16, 256 table columns,
+  1344 pages) at T = 1 with lengths from 0 to 3000 in one call;
 - ``ops/attention.multihead_attention(impl="flash")`` vs ``impl="xla"``,
   forward and backward, at the training shape (seq 2048, bf16).
 
@@ -21,7 +23,8 @@ rolled by one page / masks one position too many); the bound must REFUSE
 every one, or it proves nothing.
 
 ``--all`` adds what is off the smoke's path but in ``ops/``: bf16 and int8
-pools, page 32, T = 5 (speculative verify), banded and soft-capped flash,
+pools, page 32, T = 5 (speculative verify), the cell's shape at T = 5 and at
+its chunk of 512 over a 3000-token history, banded and soft-capped flash,
 ``gmm``/``tgmm`` at hidden 2048 x expert width 768, the int8 matmul at
 1024 x 3072 (128-wide blocks). A builder runs that by hand.
 
@@ -120,6 +123,34 @@ def paged_case(pool: str, page: int, t: int) -> None:
     args = paged_inputs(pool, page, t)
     case("paged_attend", {"out": (attend("flash", args), attend("xla", args))},
          pool=pool, page=page, T=t)
+
+
+# the benchmark's serve cell (olmo2-7b-l12.serve.decode16): 16 slots of 32/32
+# heads, page 16, 256 table columns, 1344 pages, bf16; lengths from an empty
+# slot over both sides of a page and of a block of the walk to a long context
+CELL = dict(heads=32, page=16, columns=256, pages=1344)
+CELL_LENGTHS = [0, 1, 15, 16, 17, 63, 64, 65, 300, 530, 580, 612, 630, 970,
+                2047, 3000]
+
+
+def cell_case(t: int) -> None:
+    heads, page = CELL["heads"], CELL["page"]
+    lengths = CELL_LENGTHS if t <= 8 else CELL_LENGTHS[-1:]
+    n = len(lengths)
+    q = normal((n, t, heads, D), jnp.bfloat16)
+    k_new, v_new = (normal((n, t, heads, D), jnp.bfloat16) for _ in range(2))
+    k_pool, v_pool = (normal((CELL["pages"], page, heads, D), jnp.bfloat16)
+                      for _ in range(2))
+    tables = np.zeros((n, CELL["columns"]), np.int32)
+    free = iter(RNG.permutation(np.arange(1, CELL["pages"])))
+    for i, length in enumerate(lengths):
+        need = -(-(length + t) // page)
+        tables[i, :need] = [next(free) for _ in range(need)]
+    args = (q, k_new, v_new, k_pool, v_pool, jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+    case("paged_attend", {"out": (attend("flash", args), attend("xla", args))},
+         pool="bf16", page=page, T=t, heads=f"{heads}/{heads}", slots=n,
+         lengths=f"{min(lengths)}..{max(lengths)}")
 
 
 def sabotaged(mode: str):
@@ -232,8 +263,11 @@ def main(argv) -> int:
         return 1
     for t in (1, 64):
         paged_case("fp32", 16, t)
+    cell_case(1)
     flash_case()
     if everything:
+        for t in (5, 512):
+            cell_case(t)
         for pool in ("fp32", "bf16", "int8"):
             for page in (16, 32):
                 for t in (1, 5, 64):

@@ -11,7 +11,9 @@ the compiled program (``tpu_custom_call``).
 
 Shapes are real ones: qwen3-0.6b's attention (seq 2048, 16/8 heads of 128),
 the serve CLI's page sizes for fp32, bf16 and int8 pools at T=1 (decode),
-T=5 (spec verify) and one prefill-chunk size, qwen3-30b-a3b's expert GEMMs
+T=5 (spec verify) and one prefill-chunk size, the benchmark's serve cell
+(16 slots of 32/32 heads, 256 table columns, 1344 pages; its chunk of 512, a
+GQA pool, an int8 pool, a one-head slice of a sharded pool), qwen3-30b-a3b's expert GEMMs
 (hidden 2048 x expert width 768) and an int8 projection (1024 x 3072).
 
 A pass here is a compile, never a run: nothing executes, and no result or
@@ -24,6 +26,8 @@ off around them (an entry compiled for a described chip cannot be read back
 without one, and would warn on every later run).
 """
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +86,16 @@ def chip_compile(one_chip):
     compilation_cache.reset_cache()
 
 
+def kernel_calls(text: str) -> list:
+    """Names of the ``tpu_custom_call`` instructions of a compiled program."""
+    return re.findall(r'%([\w.]+) = [^\n]*custom-call\([^\n]*'
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
+def named(call: str, name: str) -> bool:
+    return re.search(rf"(^|_){name}(_|\.|$)", call) is not None
+
+
 # ---- training attention ----------------------------------------------------
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
@@ -111,9 +125,9 @@ def test_flash_attention_compiles(chip_compile, extras, backward):
     ("int8", 16), ("int8", 32)])
 def test_paged_attend_compiles(chip_compile, pool, page, t):
     """The CLI's default ``--page-size 16`` and its int8 advice of 32, for
-    every pool dtype: the page axis is a whole-dimension block and the head
-    a 128-lane window of the ``[P, page, Hkv*D]`` pool view, which Mosaic
-    tiles for all three payloads."""
+    every pool dtype: a page is DMA'd as its ``[page * Hkv, D]`` rows, whole
+    sublane tiles of all three payloads, and the chunk takes the heads of
+    one 32-bit word with a strided load."""
     assert paged_decode_eligible(D, page)
     n_slots, n_pages, table = 4, 128, 32
     q_dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
@@ -136,10 +150,87 @@ def test_paged_attend_compiles(chip_compile, pool, page, t):
     assert "tpu_custom_call" in chip_compile(attend, *specs)
 
 
+# the serve cell's real shapes (olmo2-7b-l12.serve.decode16: 16 slots, 32/32
+# heads of 128, page 16, max_len 4096 = 256 table columns, 1344 pages, bf16)
+# and what else runs the kernel: (slots, T, Hq, Hkv, pool, q dtype)
+CELL_PAGES, CELL_PAGE, CELL_COLUMNS = 1344, 16, 256
+CELL_CASES = {
+    "cell-decode": (16, 1, 32, 32, jnp.bfloat16, jnp.bfloat16),
+    "cell-verify": (16, 5, 32, 32, jnp.bfloat16, jnp.bfloat16),
+    "cell-chunk512": (1, 512, 32, 32, jnp.bfloat16, jnp.bfloat16),
+    "cell-int8": (16, 1, 32, 32, jnp.int8, jnp.bfloat16),
+    "gqa-decode": (16, 1, 32, 8, jnp.bfloat16, jnp.bfloat16),
+    "gqa-chunk512": (1, 512, 32, 8, jnp.bfloat16, jnp.bfloat16),
+    # llama's 32/8 heads on tp=8: serve/sharding.py hands each chip one head
+    "one-head-slice": (16, 1, 4, 1, jnp.bfloat16, jnp.bfloat16),
+    "one-head-chunk512": (1, 512, 4, 1, jnp.bfloat16, jnp.bfloat16),
+}
+
+
+def _cell_attend(chip_compile, case):
+    slots, t, hq, hkv, pool_dtype, q_dtype = CELL_CASES[case]
+    pool = ((CELL_PAGES, CELL_PAGE, hkv, D), pool_dtype)
+    specs = [((slots, t, hq, D), q_dtype), pool, pool,
+             ((slots, CELL_COLUMNS), jnp.int32), ((slots,), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        specs += [((CELL_PAGES, CELL_PAGE, hkv), jnp.float32)] * 2
+        return pool, chip_compile(
+            lambda q, k, v, tabs, lens, ks, vs: paged_flash_attend(
+                q, k, v, tabs, lens, k_scale=ks, v_scale=vs,
+                interpret=False), *specs)
+    return pool, chip_compile(
+        lambda *a: paged_flash_attend(*a, interpret=False), *specs)
+
+
+@pytest.mark.parametrize("case", sorted(CELL_CASES))
+def test_paged_attend_compiles_at_the_serve_cells_shapes(chip_compile, case):
+    _, text = _cell_attend(chip_compile, case)
+    calls = kernel_calls(text)
+    assert len(calls) == 1 and named(calls[0], "paged_attend"), calls
+
+
+@pytest.mark.parametrize("case", ["cell-decode", "cell-chunk512",
+                                  "gqa-decode", "one-head-slice"])
+def test_paged_attend_moves_nothing_pool_sized(chip_compile, case):
+    """The kernel reads the pool where it lies. Besides the parameters and
+    the custom call, the compiled attend has no instruction whose result has
+    the pool's element count but the two ``bitcast``s that rename
+    ``[P, page, Hkv, D]`` as ``[P, page * Hkv, D]`` (the same bytes in the
+    same tiled layout: a bitcast moves nothing). The parent's kernel took
+    ``[P, page, Hkv * D]``, a change of tiled layout, and paid a ``copy`` of
+    each pool in every layer of every decode step."""
+    (shape, _), text = _cell_attend(chip_compile, case)
+    pool_elements = math.prod(shape)
+    sized = []
+    for line in text.splitlines():
+        found = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\][^ ]* "
+                         r"([\w\-]+)\(", line)
+        if found and found.group(1) and math.prod(
+                int(x) for x in found.group(1).split(",")) == pool_elements:
+            sized.append(found.group(2))
+    assert sorted(sized) == ["bitcast", "bitcast", "parameter", "parameter"], (
+        sized)
+
+
+@pytest.mark.parametrize("slots,t", [(16, 1), (1, 512)],
+                         ids=["decode", "chunk512"])
+def test_paged_attend_compiles_at_head_dim_256(chip_compile, slots, t):
+    """Gemma-2-9b's heads (16/8 of 256, a window, a softcap): the gate takes
+    head_dim 256, and a chunk there takes all heads in one product (the
+    strided pick of one word's heads needs rows of one 128-lane tile)."""
+    pool = ((512, 16, 8, 256), jnp.bfloat16)
+    text = chip_compile(
+        lambda *a: paged_flash_attend(*a, window=4096, softcap=50.0,
+                                      interpret=False),
+        ((slots, t, 16, 256), jnp.bfloat16), pool, pool,
+        ((slots, 256), jnp.int32), ((slots,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def test_paged_attend_gate_matches_the_compiler(chip_compile):
     """Where the gate says no, the compiler says no, and the forced path
     raises the gate's own error first: head_dim 64 is half a lane tile of
-    the ``[P, page, Hkv*D]`` view."""
+    a page's ``[page * Hkv, D]`` rows."""
     assert not paged_decode_eligible(64, 16)
     specs = [((4, 1, 16, 64), jnp.float32), ((64, 16, 8, 64), jnp.float32),
              ((64, 16, 8, 64), jnp.float32), ((4, 8), jnp.int32),
@@ -241,19 +332,13 @@ def test_kernels_carry_their_names(chip_compile, case):
     transform's wrapper (``%transpose_jvp_flash_dq__.1``) where none does, as
     here. The call is still a ``tpu_custom_call``, which the benchmark's
     roofline readers match."""
-    import re
-
     from distributed_training_guide_tpu.utils.trace import KERNELS
 
     fn, specs, names = KERNEL_NAME_CASES[case]
-    text = chip_compile(fn, *specs)
-    calls = re.findall(r'%([\w.]+) = [^\n]*custom-call\([^\n]*'
-                       r'custom_call_target="tpu_custom_call"', text)
+    calls = kernel_calls(chip_compile(fn, *specs))
     assert calls
     for name in names:
         assert name in KERNELS
-        assert any(re.search(rf"(^|_){name}(_|\.|$)", c) for c in calls), (
-            name, calls)
+        assert any(named(c, name) for c in calls), (name, calls)
     for call in calls:      # and no kernel without a name
-        assert any(re.search(rf"(^|_){name}(_|\.|$)", call)
-                   for name in names), call
+        assert any(named(call, name) for name in names), call

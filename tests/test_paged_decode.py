@@ -2,7 +2,9 @@
 gather reference across the serving feature grid (GQA, sliding window —
 static and traced, score scale, softcap, shuffled physical page layouts,
 page-boundary lengths) at EVERY query-tile size — T=1 decode, T>1
-verify/chunk tiles with ``n_valid`` pad tails, int8 and bf16 pools —
+verify/chunk tiles with ``n_valid`` pad tails, int8 and bf16 pools — and
+over the walk itself (lengths at every page and block edge, mixed and dead
+slots in one call, a window's lower edge mid-walk, the large-tile form) —
 plus the engine-level pins: flash and xla attends produce identical
 tokens, and the flash decode/chunk/verify programs' HLO carries no
 [S, M*page, Hkv, D] gathered view (the xla programs show it)."""
@@ -13,6 +15,7 @@ import pytest
 
 from distributed_training_guide_tpu.ops.attention import multihead_attention
 from distributed_training_guide_tpu.utils import hlo as hlo_util
+from distributed_training_guide_tpu.ops import paged_decode
 from distributed_training_guide_tpu.ops.paged_decode import (
     paged_decode_eligible, paged_flash_attend, paged_flash_decode)
 from distributed_training_guide_tpu.serve.kv_pages import (paged_attend,
@@ -162,6 +165,182 @@ def test_paged_attend_flash_matches_xla_dispatch():
     # the scatter is shared: pools must be BITWISE identical
     np.testing.assert_array_equal(outs["flash"][1], outs["xla"][1])
     np.testing.assert_array_equal(outs["flash"][2], outs["xla"][2])
+
+
+# ---- the walk: live pages only, blocks of pages, all heads at once ----------
+
+def _walk_state(rng, lengths, *, t, m, page, hq, hkv, d, dtype=np.float32):
+    """One call's inputs for slots of the given lengths: each slot owns the
+    shuffled physical pages its ``length + t`` tokens need, the rest of its
+    table row is the trash page 0 (as the engine leaves it), and a slot of
+    length -1 is a DEAD one: length 0, an all-trash row."""
+    s = len(lengths)
+    lens = np.maximum(np.asarray(lengths, np.int32), 0)
+    need = [0 if l < 0 else -(-(int(l) + t) // page) for l in lengths]
+    n_pages = sum(need) + 3
+    phys = rng.permutation(np.arange(1, n_pages))
+    tables = np.zeros((s, m), np.int32)
+    at = 0
+    for i, k in enumerate(need):
+        tables[i, :k] = phys[at:at + k]
+        at += k
+    mk = lambda *shape: rng.standard_normal(shape).astype(dtype)
+    return (tables, mk(n_pages, page, hkv, d), mk(n_pages, page, hkv, d),
+            lens, mk(s, t, hq, d), mk(s, t, hkv, d), mk(s, t, hkv, d))
+
+
+def _flash_and_xla(q, k_new, v_new, k_pages, v_pages, tables, lengths, **kw):
+    return [paged_attend(jnp.asarray(q), jnp.asarray(k_new),
+                         jnp.asarray(v_new), k_pages, v_pages,
+                         jnp.asarray(tables), jnp.asarray(lengths),
+                         impl=impl, **kw)[0] for impl in ("flash", "xla")]
+
+
+def _edge_lengths(t, m, page, n):
+    """0, page - 1, page, one either side of every multiple of the block
+    (the last position of a block is ``k * n * page - 1``), the full
+    table, and a dead slot."""
+    full = m * page - t
+    edges = {0, page - 1, page, full, full - 1}
+    for k in range(1, m // n + 1):
+        edges |= {k * n * page + e - t for e in (-1, 0, 1)}
+    return [-1] + sorted(e for e in edges if 0 <= e <= full)
+
+
+WALK_HEADS = [(4, 2), (32, 32), (8, 1), (4, 4)]
+
+
+@pytest.mark.parametrize("hq,hkv", WALK_HEADS)
+@pytest.mark.parametrize("t", [1, 3, 8], ids=["decode", "verify", "chunk"])
+def test_walk_edges_mixed_lengths_one_call(hq, hkv, t):
+    """Slots of very different lengths in ONE call, a dead slot among them,
+    shuffled physical pages: lengths of 0, page - 1, page, one either side
+    of every multiple of the pages a block holds, and the full table. The
+    chunk form carries ``n_valid`` tails (full, partial, one token)."""
+    page, m, d = 4, 20, 8
+    _, _, n = paged_decode._plan(t, hq // hkv, hkv, d, page, m,
+                                 jnp.float32, jnp.float32)
+    assert 1 < n < m, "the table must hold several blocks of the walk"
+    lengths = _edge_lengths(t, m, page, n)
+    rng = np.random.default_rng(21)
+    tables, kp, vp, lens, q, k_new, v_new = _walk_state(
+        rng, lengths, t=t, m=m, page=page, hq=hq, hkv=hkv, d=d)
+    n_valid = np.array([(t, max(1, t - 1), 1)[i % 3]
+                        for i in range(len(lens))], np.int32)
+    flash, xla = _flash_and_xla(q, k_new, v_new, jnp.asarray(kp),
+                                jnp.asarray(vp), tables, lens,
+                                n_valid=jnp.asarray(n_valid))
+    np.testing.assert_allclose(np.asarray(flash), np.asarray(xla),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("pool", ["fp32", "bf16", "int8"])
+@pytest.mark.parametrize("t", [1, 5], ids=["decode", "verify"])
+def test_walk_pool_dtypes(pool, t):
+    """Every pool dtype over a walk of several blocks, MHA: bf16 q and k
+    meet the MXU as stored, an int8 pool's scales ride the walk."""
+    page, m, hq, hkv, d = 8, 12, 4, 4, 8
+    rng = np.random.default_rng(22)
+    lengths = [0, 7, 8, 63, 64, 65, m * page - t, -1]
+    tables, kp, vp, lens, q, k_new, v_new = _walk_state(
+        rng, lengths, t=t, m=m, page=page, hq=hq, hkv=hkv, d=d)
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
+    if pool == "int8":
+        kp, vp = quantize_kv(kp), quantize_kv(vp)
+    flash, xla = _flash_and_xla(
+        *(jnp.asarray(x, dtype) for x in (q, k_new, v_new)), kp, vp,
+        tables, lens, scale=0.3)
+    tol = 3e-2 if pool == "bf16" else 1e-5
+    assert flash.dtype == dtype
+    np.testing.assert_allclose(np.asarray(flash, np.float32),
+                               np.asarray(xla, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("window", [3, 10, 37, 70])
+@pytest.mark.parametrize("t", [1, 4], ids=["decode", "tile"])
+def test_walk_window_lower_edge_inside_the_walk(window, t):
+    """A window whose lower edge falls inside the walk: the first block
+    starts at the page that holds the oldest position any row still sees
+    (mid-table, not on a block edge), static and traced windows alike."""
+    page, m, hq, hkv, d = 4, 20, 4, 2, 8
+    rng = np.random.default_rng(23)
+    lengths = [0, 2, 9, 31, 33, 50, 64, m * page - t]
+    tables, kp, vp, lens, q, k_new, v_new = _walk_state(
+        rng, lengths, t=t, m=m, page=page, hq=hq, hkv=hkv, d=d)
+    flash, xla = _flash_and_xla(q, k_new, v_new, jnp.asarray(kp),
+                                jnp.asarray(vp), tables, lens,
+                                window=window, softcap=30.0)
+    np.testing.assert_allclose(np.asarray(flash), np.asarray(xla),
+                               rtol=1e-5, atol=1e-5)
+    traced = jax.jit(lambda w: paged_flash_attend(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(tables), jnp.asarray(lens), window=w, softcap=30.0,
+        interpret=True))
+    static = paged_flash_attend(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(tables), jnp.asarray(lens), window=window, softcap=30.0,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(traced(jnp.asarray(window))),
+                               np.asarray(static), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.paged_multitok
+@pytest.mark.parametrize("pool,hb", [("fp32", 1), ("bf16", 2), ("int8", 4)])
+@pytest.mark.parametrize("split", [False, True], ids=["one-block", "split"])
+def test_large_tile_takes_the_heads_of_one_word(pool, hb, split, monkeypatch):
+    """A query tile too large for all heads at once (a prefill chunk) takes
+    the heads that share a 32-bit word of the pool's dtype, picked out of
+    the page block by a strided word load, and (``split``) fewer heads a
+    grid step: same numbers as the gather path, ``n_valid`` tail and a
+    windowed layer included."""
+    page, m, hq, hkv, d, t = 8, 10, 8, 4, 128, 48    # the pick needs D = 128
+    if split:   # a tile budget that holds two heads a grid step
+        monkeypatch.setattr(paged_decode, "TILE_BUDGET", 2 * t * 2 * (
+            4 * d * 4 + d * 4 + 2 * 128 * 4))
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    pool_dtype = {"fp32": jnp.float32, "bf16": jnp.bfloat16,
+                  "int8": jnp.int8}[pool]
+    plan = paged_decode._plan(t, hq // hkv, hkv, d, page, m, dtype,
+                              pool_dtype)
+    assert plan[1] == hb and (plan[0] < hkv) == (split and hb < 4), plan
+    rng = np.random.default_rng(24)
+    lengths = [0, 13, m * page - t]
+    tables, kp, vp, lens, q, k_new, v_new = _walk_state(
+        rng, lengths, t=t, m=m, page=page, hq=hq, hkv=hkv, d=d)
+    kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
+    if pool == "int8":
+        kp, vp = quantize_kv(kp), quantize_kv(vp)
+    flash, xla = _flash_and_xla(
+        *(jnp.asarray(x, dtype) for x in (q, k_new, v_new)), kp, vp,
+        tables, lens, n_valid=jnp.asarray([t, 5, t - 1], jnp.int32),
+        window=29)
+    tol = 3e-2 if pool == "bf16" else 1e-5
+    np.testing.assert_allclose(np.asarray(flash, np.float32),
+                               np.asarray(xla, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("case,want", [
+    # the serve cell: 16 slots of 32/32 heads of 128, page 16, bf16
+    (dict(t=1, groups=1, hkv=32, pool=jnp.bfloat16), (32, 32, 4)),
+    (dict(t=5, groups=1, hkv=32, pool=jnp.bfloat16), (32, 32, 4)),
+    (dict(t=512, groups=1, hkv=32, pool=jnp.bfloat16), (4, 2, 4)),
+    # qwen3's GQA 16/8, an int8 pool, a one-head slice of a sharded pool
+    (dict(t=1, groups=2, hkv=8, pool=jnp.bfloat16), (8, 8, 4)),
+    (dict(t=64, groups=2, hkv=8, pool=jnp.int8), (8, 4, 4)),
+    (dict(t=512, groups=4, hkv=1, pool=jnp.bfloat16), (1, 1, 2)),
+])
+def test_plan_follows_the_static_shapes(case, want):
+    """``(heads a grid step, heads a product, pages a block)`` at real
+    shapes: all heads while the query tile is small, the heads of one word
+    for a chunk, fewer pages where the scores would outgrow their budget."""
+    q_dtype = jnp.float32 if case["pool"] == jnp.int8 else jnp.bfloat16
+    hs, hb, n = paged_decode._plan(case["t"], case["groups"], case["hkv"],
+                                   128, 16, 256, q_dtype, case["pool"])
+    assert (hs, hb, n) == want
+    assert case["hkv"] % hs == 0 and hs % hb == 0 and n >= 1
 
 
 # ---- engine-level pins ------------------------------------------------------
