@@ -157,22 +157,34 @@ def run_child(name: str, cmd: list, limit_s: float) -> list[str]:
     reader = threading.Thread(target=pump, daemon=True)
     reader.start()
     seen, why = 0, None
+
+    def other_platform():
+        """Why, if a device line read since the last look names another
+        platform."""
+        nonlocal seen
+        found = None
+        for line in lines[seen:]:
+            dev = _json_line(line, "device")
+            if dev and dev["device"].get("platform") != EXPECT_PLATFORM:
+                found = (f"runs on platform "
+                         f"{dev['device'].get('platform')!r}, not "
+                         f"{EXPECT_PLATFORM!r}")
+        seen = len(lines)
+        return found
+
     try:
         while proc.poll() is None and why is None:
             time.sleep(0.1)
             if time.monotonic() - t0 > limit_s:
                 why = f"exceeded its {limit_s:.0f} s limit"
-            for line in lines[seen:]:
-                dev = _json_line(line, "device")
-                if dev and dev["device"].get("platform") != EXPECT_PLATFORM:
-                    why = (f"runs on platform "
-                           f"{dev['device'].get('platform')!r}, not "
-                           f"{EXPECT_PLATFORM!r}")
-            seen = len(lines)
+            why = other_platform() or why
     finally:
         if proc.poll() is None:
             _stop(proc)
     reader.join(timeout=10)
+    # a child that ended between two looks (a fast one, a loaded machine)
+    # still has its last lines read
+    why = why or other_platform()
     if why is None and proc.returncode != 0:
         why = f"exited with code {proc.returncode}"
     if why is not None:

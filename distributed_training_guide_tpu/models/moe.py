@@ -257,15 +257,29 @@ def _ragged_expert_compute(x_rows: jnp.ndarray, gate, up, down,
     return grouped_matmul(h, down.astype(cdt), group_sizes)
 
 
-def _ragged_sort(xt: jnp.ndarray, topk_idx, topk_probs, ex: int, k: int, cdt):
-    """Flatten (token, choice) pairs choice-rank-major, sort by expert id.
-    Returns (order, group_sizes, x_sorted [kT, D], weight_flat [kT]).
+def experts_held(config) -> tuple[int, int]:
+    """``(first, count)`` of the routed experts whose weights this program
+    holds: all ``num_experts`` unless the config states a share
+    (``experts_held``, one chip's part of an expert-parallel layer)."""
+    held = getattr(config, "experts_held", None)
+    return (0, config.num_experts) if held is None else tuple(held)
+
+
+def _ragged_sort(xt: jnp.ndarray, topk_idx, topk_probs, ex: int, k: int, cdt,
+                 first: int = 0):
+    """Flatten (token, choice) pairs choice-rank-major, sort by expert id
+    counted from expert ``first`` (mod ``ex``), so the experts of a held
+    share ``first .. first + count`` come first in the sorted buffer.
+    Returns (order, group_sizes, x_sorted [kT, D], weight_flat [kT]);
+    ``group_sizes[j]`` is expert ``(first + j) % ex``'s.
 
     Pair i is token (i mod t): sorted rows gather straight from xt — row
     movement is gather-only, like the dense path; the one int32 scatter
     lives in ``_ragged_combine``'s permutation inversion."""
     t = xt.shape[0]
     expert_flat = topk_idx.T.reshape(k * t)                      # [kT]
+    if first:
+        expert_flat = (expert_flat - first) % ex
     weight_flat = topk_probs.T.reshape(k * t)
     order = jnp.argsort(expert_flat, stable=True)
     group_sizes = jnp.bincount(expert_flat, length=ex).astype(jnp.int32)
@@ -292,21 +306,43 @@ def _ragged_dispatch(config: MoELlamaConfig, xt: jnp.ndarray, topk_idx,
     pairs by expert id, run the experts as grouped GEMMs over the sorted
     [kT, D] buffer, unsort, weight, combine. No capacity buffers, no drops;
     transients are O(k*T*D) — at decode (t == 1..few) that is O(t*k*d) vs
-    the dense no_drop path's O(E*k*t*d) worst-case buffers."""
+    the dense no_drop path's O(E*k*t*d) worst-case buffers.
+
+    With a held share (``experts_held``) the router still chooses among all
+    ``ex`` experts; the held ones sort first, the group sizes are theirs
+    alone, and the pairs of absent experts lie past ``sum(group_sizes)``,
+    where the grouped matmul returns zeros: their part of the sum is left
+    out, which is one chip's output before an expert-parallel exchange.
+    Returns ``(y, group_sizes)``."""
     t = xt.shape[0]
     ex, k = config.num_experts, config.experts_per_token
+    first, held = experts_held(config)
     order, group_sizes, x_sorted, weight_flat = _ragged_sort(
-        xt, topk_idx, topk_probs, ex, k, cdt)
+        xt, topk_idx, topk_probs, ex, k, cdt, first)
+    group_sizes = group_sizes[:held]
     out_sorted = _ragged_expert_compute(x_sorted, moe["gate"], moe["up"],
                                         moe["down"], group_sizes, cdt)
-    return _ragged_combine(out_sorted, order, weight_flat, k, t, cdt)
+    return (_ragged_combine(out_sorted, order, weight_flat, k, t, cdt),
+            group_sizes)
 
 
 @jax.named_scope("experts")
 def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
              tp_axis: Optional[str] = None, no_drop: bool = False,
-             moe_ep=None):
+             moe_ep=None, return_counts: bool = False):
     """Top-k routed FFN. x: [B, S, D]. Returns (y, aux_loss, dropped_frac).
+
+    The router is a softmax over the experts, or (``config.router_act ==
+    "sigmoid"``, DeepSeek-V3) a sigmoid per expert with the choice made on
+    ``score + moe["router_bias"]`` and the weights taken from the scores
+    alone, times ``routed_scaling_factor``. The shared expert
+    (``shared_up`` present) is gated by ``shared_gate`` where that leaf
+    exists (Qwen2-MoE) and added as it is where it does not.
+
+    ``config.experts_held = (first, count)``: the expert leaves hold that
+    share only (ragged dispatch; see ``_ragged_dispatch``).
+    ``return_counts`` adds a fourth result, int32 ``[pairs routed, pairs
+    held here, experts touched, the fullest expert's pairs]``.
 
     Two dispatch backends, selected by ``config.moe_dispatch``:
 
@@ -350,27 +386,45 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
     if no_drop:
         dispatch = "ragged"
     cdt = config.dtype
+    if experts_held(config) != (0, ex) and (dispatch != "ragged"
+                                            or moe_ep is not None):
+        raise ValueError(
+            "experts_held (one chip's share of the experts) runs the local "
+            "ragged dispatch only: set moe_dispatch='ragged', no moe_ep")
 
     xt = x.reshape(t, d)
     with jax.named_scope("router"):
         router_logits = (xt.astype(jnp.float32)
                          @ moe["router"].astype(jnp.float32))   # [T, E]
-        probs = jax.nn.softmax(router_logits, axis=-1)
-
-        topk_probs, topk_idx = jax.lax.top_k(probs, k)           # [T, k]
+        if getattr(config, "router_act", "softmax") == "sigmoid":
+            scores = jax.nn.sigmoid(router_logits)
+            choice = scores
+            if "router_bias" in moe:    # moves the choice, not the weight
+                choice = scores + moe["router_bias"].astype(jnp.float32)
+            _, topk_idx = jax.lax.top_k(choice, k)               # [T, k]
+            topk_probs = jnp.take_along_axis(scores, topk_idx, axis=-1)
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        else:
+            probs = jax.nn.softmax(router_logits, axis=-1)
+            topk_probs, topk_idx = jax.lax.top_k(probs, k)       # [T, k]
         if getattr(config, "norm_topk_prob", True):
             # renormalize the chosen weights (Mixtral: always; Qwen3-MoE:
             # the norm_topk_prob flag — off, the raw softmax mass is the
             # weight)
             topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1,
                                               keepdims=True)
+        routed_scale = getattr(config, "routed_scaling_factor", 1.0)
+        if routed_scale != 1.0:
+            topk_probs = topk_probs * routed_scale
 
+    group_sizes = None
     if dispatch == "ragged":
         if moe_ep is not None:
             y = moe_ep(xt, topk_idx, topk_probs,
                        moe["gate"], moe["up"], moe["down"])
         else:
-            y = _ragged_dispatch(config, xt, topk_idx, topk_probs, moe, cdt)
+            y, group_sizes = _ragged_dispatch(config, xt, topk_idx,
+                                              topk_probs, moe, cdt)
         dropped_frac = jnp.zeros((), jnp.float32)  # dropless by construction
     else:
         capacity = max(int(math.ceil(config.capacity_factor * k * t / ex)), 1)
@@ -421,19 +475,22 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
         # choice-rank-major layout — a reshape and a dense sum
         y = jnp.sum((y_choice * weight_flat[:, None].astype(cdt))
                     .reshape(k, t, d), axis=0)
-    if "shared_gate" in moe:   # Qwen2-MoE shared expert: dense gated MLP on
-        # every token, output scaled by a sigmoid scalar gate and ADDED to
-        # the routed combine. Under manual tp its mlp-dim-sharded down-proj
-        # is a partial sum like the routed one — the single psum below
-        # covers both (addition commutes with psum)
-        xs = xt.astype(cdt)
-        hs = jax.nn.silu(xs @ moe["shared_gate_proj"].astype(cdt))
-        hs = hs * (xs @ moe["shared_up"].astype(cdt))
-        shared_out = hs @ moe["shared_down"].astype(cdt)
-        sgate = jax.nn.sigmoid(
-            (xt.astype(jnp.float32) @ moe["shared_gate"].astype(jnp.float32)
-             )[:, None])
-        y = y + sgate.astype(cdt) * shared_out
+    if "shared_up" in moe:   # shared expert: a dense gated MLP on every
+        # token, ADDED to the routed combine, scaled by a sigmoid scalar gate
+        # where the model has one (Qwen2-MoE's shared_gate). Under manual tp
+        # its mlp-dim-sharded down-proj is a partial sum like the routed one
+        # — the single psum below covers both (addition commutes with psum)
+        with jax.named_scope("shared_expert"):
+            xs = xt.astype(cdt)
+            hs = jax.nn.silu(xs @ moe["shared_gate_proj"].astype(cdt))
+            hs = hs * (xs @ moe["shared_up"].astype(cdt))
+            shared_out = hs @ moe["shared_down"].astype(cdt)
+            if "shared_gate" in moe:
+                sgate = jax.nn.sigmoid(
+                    (xt.astype(jnp.float32)
+                     @ moe["shared_gate"].astype(jnp.float32))[:, None])
+                shared_out = sgate.astype(cdt) * shared_out
+            y = y + shared_out
     if tp_axis is not None:
         y = _psum(y, tp_axis)
 
@@ -445,6 +502,14 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
             jax.nn.one_hot(topk_idx, ex, dtype=jnp.float32), axis=(0, 1))
         prob_frac = jnp.mean(probs, axis=0)
         aux = ex * jnp.sum(token_frac * prob_frac)
+    if return_counts:
+        if group_sizes is None:
+            raise ValueError("return_counts reads the local ragged "
+                             "dispatch's group sizes")
+        counts = jnp.stack([jnp.int32(k * t), jnp.sum(group_sizes),
+                            jnp.sum(group_sizes > 0), jnp.max(group_sizes)]
+                           ).astype(jnp.int32)
+        return y.reshape(b, s, d), aux, dropped_frac, counts
     return y.reshape(b, s, d), aux, dropped_frac
 
 

@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional
 
-from . import gpt2, llama, moe, neox
+from . import gpt2, llama, mla, moe, neox
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,13 +52,15 @@ _HF_ALIASES = {
     "eleutherai/pythia-1.4b": "pythia-1.4b",
     "eleutherai/pythia-6.9b": "pythia-6.9b",
     "eleutherai/gpt-neox-20b": "gpt-neox-20b",
+    "mistralai/mistral-small-4-119b-2603": "mistral-small-4-119b",
 }
 
 
 def family_module(family: str):
     """The module implementing a model family (block/embed/head helpers used
     by the pipeline schedule and chunked losses)."""
-    mods = {"llama": llama, "gpt2": gpt2, "moe": moe, "neox": neox}
+    mods = {"llama": llama, "gpt2": gpt2, "moe": moe, "neox": neox,
+            "mla_moe": mla}
     if family not in mods:
         raise KeyError(f"unknown model family {family!r}")
     return mods[family]
@@ -66,7 +68,7 @@ def family_module(family: str):
 
 def list_models() -> list[str]:
     return (sorted(gpt2.PRESETS) + sorted(llama.PRESETS) + sorted(moe.PRESETS)
-            + sorted(neox.PRESETS))
+            + sorted(neox.PRESETS) + sorted(mla.PRESETS))
 
 
 def get_model(name: str, **overrides) -> ModelBundle:
@@ -109,6 +111,12 @@ def get_model(name: str, **overrides) -> ModelBundle:
             config = dataclasses.replace(config, **overrides)
         return ModelBundle(key, config, neox.init, neox.apply,
                            neox.param_logical_axes, family="neox")
+    if key in mla.PRESETS:
+        config = mla.PRESETS[key]
+        if overrides:
+            config = dataclasses.replace(config, **overrides)
+        return ModelBundle(key, config, mla.init, mla.apply,
+                           mla.param_logical_axes, family="mla_moe")
     raise ValueError(
         f"Unknown model {name!r}. Available: {', '.join(list_models())} "
         f"(HF aliases: {', '.join(sorted(_HF_ALIASES))})"

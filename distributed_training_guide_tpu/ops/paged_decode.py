@@ -441,6 +441,170 @@ def paged_flash_attend(
     return out[:, 0] if squeeze else out
 
 
+# ---------------------------------------------------------------------------
+# Latent (MLA) pools: one shared "head" whose keys are [c_kv | k_rope] and
+# whose values are c_kv again
+# ---------------------------------------------------------------------------
+
+LATENT_GATE = ("latent and rope widths % 128 == 0, page_size % 16 == 0, "
+               "T * heads <= 256")
+LATENT_BLOCK_PAGES = 8          # pages a block of the walk holds, at most
+LATENT_BLOCK_TOKENS = 1024      # tokens a block holds, at most
+
+
+def latent_decode_eligible(latent_dim: int, rope_width: int, page_size: int,
+                           rows: int = 1) -> bool:
+    """Shape gate of the COMPILED latent kernel (``tests/test_chip_compile``):
+    both pools' rows are whole 128-lane tiles, a page's rows whole sublane
+    tiles of bf16, and the query tile (tokens x heads) small enough that one
+    product holds it (a prefill chunk takes the decompressed path)."""
+    return (latent_dim % 128 == 0 and rope_width % 128 == 0
+            and page_size % 16 == 0 and rows <= ROWS_ALL_HEADS)
+
+
+def _latent_kernel(lens_ref, tabs_ref, qc_ref, qr_ref, c_hbm, r_hbm, o_ref,
+                   cbuf, rbuf, sems, m_scr, l_scr, acc_scr, *, scale, page,
+                   n, heads):
+    """Grid (slot,). Row ``r`` of the query tile is the slot's token
+    ``r // heads`` at position ``lengths[slot] + r // heads``. The walk is
+    ``_attend_kernel``'s: blocks of ``n`` live pages, the next block's DMAs
+    in flight while this one is attended to. A page is read ONCE: its
+    ``c_kv`` rows are the keys' first part and the values."""
+    s_idx = pl.program_id(0)
+    max_pages = tabs_ref.shape[1]
+    rows = qc_ref.shape[1]
+    block_q = rows // heads
+    q_pos = lens_ref[s_idx]
+    hi = jnp.minimum(pl.cdiv(q_pos + block_q, page), max_pages)
+    n_blocks = pl.cdiv(hi, n)
+
+    def copies(b, slot):
+        out = []
+        for i in range(n):
+            col = b * n + i
+            phys = jnp.where(col < hi,
+                             tabs_ref[s_idx, jnp.minimum(col, max_pages - 1)],
+                             0)
+            for j, (hbm, buf) in enumerate(((c_hbm, cbuf), (r_hbm, rbuf))):
+                out.append(pltpu.make_async_copy(
+                    hbm.at[phys], buf.at[slot, pl.ds(i * page, page)],
+                    sems.at[j, slot]))
+        return out
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    shape = (rows, n * page)
+    # the row's token minus the column's position inside the block
+    rel = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) // heads
+           - jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    mxu = (jnp.bfloat16 if qc_ref.dtype == cbuf.dtype == jnp.bfloat16
+           else jnp.float32)
+    qc = qc_ref[0].astype(mxu)
+    qr = qr_ref[0].astype(mxu)
+
+    for c in copies(0, 0):
+        c.start()
+
+    def block(b, carry):
+        slot = jax.lax.rem(b, DEPTH)
+
+        @pl.when(b + 1 < n_blocks)
+        def _prefetch():
+            for c in copies(b + 1, jax.lax.rem(b + 1, DEPTH)):
+                c.start()
+
+        for c in copies(b, slot):
+            c.wait()
+        ckv = cbuf[slot]                                  # [n*page, C]
+        s = jax.lax.dot_general(
+            qc, ckv.astype(mxu), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        s = s + jax.lax.dot_general(
+            qr, rbuf[slot].astype(mxu), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        mask = rel + (q_pos - b * n * page) >= 0
+        s = jnp.where(mask, s * scale, NEG_INF)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_new = alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p, ckv.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    l = l_scr[:, 0:1]
+    o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def paged_latent_attend(
+    q: jnp.ndarray,          # [S, T, H, C + R]: [absorbed nope | rope] queries
+    k_pages: jnp.ndarray,    # [P, page, 1, Rw] rope keys, R live columns
+    v_pages: jnp.ndarray,    # [P, page, 1, C] latent rows c_kv
+    tables: jnp.ndarray,     # [S, M] int32 physical page ids (0 = trash)
+    lengths: jnp.ndarray,    # [S] int32: the first query token's position
+    *,
+    scale: float,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """Absorbed latent attention (MLA decode) through the block table:
+    ``softmax(scale * (q_c . c_kv + q_r . k_rope)) . c_kv`` per head, all
+    heads against the ONE latent row a token has; returns ``[S, T, H, C]``
+    in q.dtype. The pools are read where they lie, a page's ``c_kv`` once
+    for both products. The caller has scattered the T new rows already
+    (``serve/kv_pages.paged_attend``), as for ``paged_flash_attend``."""
+    s, t, h, width = q.shape
+    n_phys, page, _, c = v_pages.shape
+    rw = k_pages.shape[-1]
+    r = width - c
+    m = tables.shape[1]
+    rows = t * h
+    interpret = resolve_interpret(interpret)
+    if not interpret and not latent_decode_eligible(c, rw, page, rows):
+        raise ValueError(
+            f"paged latent attend (compiled) needs {LATENT_GATE}; got "
+            f"latent {c}, rope width {rw}, page_size {page}, T*heads {rows}")
+    n = max(1, min(LATENT_BLOCK_PAGES, m, LATENT_BLOCK_TOKENS // page))
+    qc = q[..., :c].reshape(s, rows, c)
+    qr = jnp.pad(q[..., c:], ((0, 0),) * 3 + ((0, rw - r),)
+                 ).reshape(s, rows, rw)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+
+    def tile(w):
+        return pl.BlockSpec((1, rows, w), lambda s_, lens, tabs: (s_, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,          # lengths, tables
+        grid=(s,),
+        in_specs=[tile(c), tile(rw), in_hbm, in_hbm],
+        out_specs=tile(c),
+        scratch_shapes=[
+            pltpu.VMEM((DEPTH, n * page, c), v_pages.dtype),
+            pltpu.VMEM((DEPTH, n * page, rw), k_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, DEPTH)),
+            pltpu.VMEM((rows, 128), jnp.float32),   # running max
+            pltpu.VMEM((rows, 128), jnp.float32),   # running sum
+            pltpu.VMEM((rows, c), jnp.float32),     # output accumulator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, page=page, n=n,
+                          heads=h),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, rows, c), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="paged_latent_attend",
+    )(lengths.astype(jnp.int32), tables.astype(jnp.int32), qc, qr,
+      v_pages.reshape(n_phys, page, c), k_pages.reshape(n_phys, page, rw))
+    return out.reshape(s, t, h, c)
+
+
 # The block_q == 1 name the decode path shipped under; same kernel, same
 # contract — kept so existing callers/tests read naturally.
 paged_flash_decode = paged_flash_attend

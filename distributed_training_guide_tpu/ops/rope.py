@@ -213,13 +213,17 @@ def scaled_rope_frequencies(
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
                theta: float = 10000.0, scaling: Any = None,
-               max_position: Optional[int] = None) -> jnp.ndarray:
+               max_position: Optional[int] = None,
+               interleave: bool = False) -> jnp.ndarray:
     """Rotate ``x`` [..., seq, heads, head_dim] by position-dependent angles.
 
     ``positions`` is [..., seq] (int). Computation in float32, result cast back
     to ``x.dtype`` — rope in bf16 loses position resolution at long context.
     ``scaling``/``max_position`` select an HF rope_scaling flavor (None =
-    plain RoPE, the fast path)."""
+    plain RoPE, the fast path). ``interleave`` pairs ADJACENT elements
+    ``(2i, 2i+1)`` under angle i (the DeepSeek-V2/V3 ``rope_interleave``
+    convention; each pair is rotated where it lies) instead of the halves
+    ``(i, i + head_dim/2)``."""
     head_dim = x.shape[-1]
     if scaling is None:
         inv_freq, attn_factor = rope_frequencies(head_dim, theta), 1.0
@@ -232,6 +236,23 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., S, D/2]
     cos = jnp.cos(angles)[..., None, :] * attn_factor  # [..., S, 1, D/2]
     sin = jnp.sin(angles)[..., None, :] * attn_factor
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleave:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        rotated = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                            axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
+        rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                                  axis=-1)
     return rotated.astype(x.dtype)
+
+
+def position_query_scale(positions: jnp.ndarray, beta: float,
+                         original_max: int) -> jnp.ndarray:
+    """``1 + beta * ln(1 + floor(t / original_max))``, float32: the
+    position-dependent query scale of ``llama_4_scaling_beta`` models
+    (1 inside the original context, growing by ``beta`` per e-fold of
+    context beyond it). Applied to a query at its OWN position."""
+    blocks = jnp.floor_divide(positions, original_max).astype(jnp.float32)
+    return 1.0 + beta * jnp.log1p(blocks)

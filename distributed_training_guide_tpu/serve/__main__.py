@@ -153,11 +153,10 @@ def main(argv=None) -> None:
     bundle = get_model(args.model_name, dtype=jnp.float32)
     # a forced 'flash' the compiled kernel cannot take raises here
     from ..utils.logging import print_device_line
-    from .kv_pages import resolve_attend_impl
+    from .kv_pages import resolve_attend_for
 
-    print_device_line("attend", resolve_attend_impl(
-        args.attend_impl, bundle.config.head_size, args.page_size),
-        cache.directory)
+    print_device_line("attend", resolve_attend_for(
+        bundle.config, args.attend_impl, args.page_size), cache.directory)
     tokenizer = None
     if args.prompt or args.http_port is not None:
         try:

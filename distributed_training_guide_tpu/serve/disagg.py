@@ -58,7 +58,7 @@ import itertools
 import queue as queue_mod
 from typing import Optional
 
-from ..models.registry import ModelBundle
+from ..models.registry import ModelBundle, family_module
 from ..utils.trace import span
 from .adapters import DEFAULT_TARGETS
 from .engine import (LatencyMeter, ModelPrograms, adapter_metrics,
@@ -66,7 +66,8 @@ from .engine import (LatencyMeter, ModelPrograms, adapter_metrics,
                      build_kv_report, collect_partial_tokens,
                      default_prefill_buckets, derived_pool_metrics,
                      dispatch_horizon, drop_stale_pending, horizon_dev,
-                     process_horizon_block, resolve_context_bounds,
+                     process_horizon_block, refuse_for_family,
+                     resolve_context_bounds,
                      resolve_drafter, run_bucket_prefill,
                      run_decode_iteration, run_fork, spec_metrics,
                      validate_prefill_buckets)
@@ -583,6 +584,8 @@ class DisaggEngine:
         # monolith's contract, mirrored here — engine-generation swaps
         # depend on the new generation running the OLD generation's exact
         # programs so replayed tokens are bitwise)
+        refuse_for_family(family_module(bundle.family), bundle.family,
+                          {"disaggregation": True})
         self.programs = programs if programs is not None else ModelPrograms(
             bundle, params, plan=plan, shard_kv=shard_kv,
             attend_impl=attend_impl, kv_dtype=kv_dtype,

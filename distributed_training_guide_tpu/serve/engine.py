@@ -68,7 +68,7 @@ from ..utils.trace import named, span
 from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        adapter_nbytes, adapter_pool_bytes, adapter_shapes,
                        init_adapter_stacks, validate_adapter_params)
-from .kv_pages import (resolve_attend_impl, commit_prefill, copy_pages,
+from .kv_pages import (resolve_attend_for, commit_prefill, copy_pages,
                        init_pages, kv_dtype_name, kv_page_bytes, make_attend,
                        PagePool, pages_for_tokens, pool_nbytes, TRASH_PAGE)
 from .scheduler import Admission, Request, RequestResult, Scheduler
@@ -605,14 +605,16 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
                    **{key: jnp.asarray(v)
                       for key, v in sched.decode_arrays().items()}}
     with span("serve.dispatch", program="serve_decode"):
-        nxt, new_len, pages["k"], pages["v"] = programs._decode_fn(
+        nxt, new_len, pages["k"], pages["v"], *counted = programs._decode_fn(
             programs.params, pages["k"], pages["v"],
             dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
             dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
             *programs.lora_call_args(dev["adapters"]))
     dev["tokens"], dev["lengths"] = nxt, new_len
     with span("serve.wait"):
-        nxt_host = np.asarray(nxt)
+        nxt_host = np.asarray(counted[0] if counted else nxt)
+    if counted:
+        programs.note_routing(nxt_host[sched.n_slots:])
     finished = []
     with span("serve.book", tokens=len(active)):
         for slot_idx in active:
@@ -815,6 +817,19 @@ def build_adapter_report(programs: "ModelPrograms") -> dict:
     }
 
 
+def refuse_for_family(mod, family: str, asked: dict) -> None:
+    """Options a family's serve path does not implement REFUSE here, at
+    construction, by the option's name: a family lists them with the reason
+    in ``SERVE_REFUSES`` (``models/mla.py``); none falls back quietly.
+    ``asked`` maps each listed name to whether the caller asked for it."""
+    refuses = getattr(mod, "SERVE_REFUSES", {})
+    for option, wanted in asked.items():
+        if wanted and option in refuses:
+            raise ValueError(
+                f"family {family!r} does not serve with {option}: "
+                f"{refuses[option]}")
+
+
 class ModelPrograms:
     """The compiled-program cache for one (model, params, sharding)
     triple: the batched decode step, per-bucket prefill programs, the
@@ -838,6 +853,11 @@ class ModelPrograms:
         self.bundle = bundle
         self.config = bundle.config
         self.mod = family_module(bundle.family)
+        refuse_for_family(self.mod, bundle.family, {
+            "kv_dtype='int8'": str(kv_dtype).lower() == "int8",
+            "weight_dtype='int8'": str(weight_dtype).lower() == "int8",
+            "max_adapters": max_adapters is not None,
+            "plan / shard_kv": plan is not None or shard_kv})
         if not hasattr(self.mod, "paged_decode_step"):
             raise ValueError(
                 f"family {bundle.family!r} has no KV-cached decode — the "
@@ -851,6 +871,10 @@ class ModelPrograms:
             raise ValueError(f"attend_impl must be 'auto', 'flash' or "
                              f"'xla', got {attend_impl!r}")
         self.attend_impl = attend_impl
+        # a routing family's counters, summed over its decode steps (the
+        # decode program hands them over with its tokens): stats() reads it
+        self.routing = {"steps": 0, "pairs_routed": 0, "pairs_held": 0,
+                        "experts_touched": 0, "fullest_expert_pairs": 0}
         # the pool's storage dtype ("fp32" | "bf16" | "int8"; None inherits
         # the model dtype). int8 pools are Quantized pytrees — every
         # pool-touching program below threads them transparently, and the
@@ -1339,8 +1363,23 @@ class ModelPrograms:
         nxt = jnp.where(actives, nxt, 0)
         # the returned (tokens, lengths) ARE next step's inputs: a steady
         # decode run round-trips nothing but the sampled ids to the host
-        return nxt, jnp.where(actives, lengths + 1, lengths), \
-            cache["k"], cache["v"]
+        out = (nxt, jnp.where(actives, lengths + 1, lengths),
+               cache["k"], cache["v"])
+        if "routing" in cache:   # a routing family's counters ride the
+            # host's one read of the step, behind the sampled ids
+            out += (jnp.concatenate([nxt, cache["routing"]]),)
+        return out
+
+    def note_routing(self, counts) -> None:
+        """One decode step's ``[pairs routed, pairs held here, experts
+        touched, fullest expert's pairs]`` (``models/mla.py``)."""
+        r = self.routing
+        r["steps"] += 1
+        r["pairs_routed"] += int(counts[0])
+        r["pairs_held"] += int(counts[1])
+        r["experts_touched"] += int(counts[2])
+        r["fullest_expert_pairs"] = max(r["fullest_expert_pairs"],
+                                        int(counts[3]))
 
     def horizon_for(self, k: int):
         """The fused K-step decode program (``decode_horizon=K``): ONE
@@ -1616,6 +1655,11 @@ class ServeEngine:
                 f"multi-token, and fusing it under a horizon is named "
                 f"follow-on work. Drop one of the two knobs.")
         self.decode_horizon = decode_horizon
+        mod = programs.mod if programs is not None else family_module(
+            bundle.family)
+        refuse_for_family(mod, bundle.family, {
+            "speculate": speculate is not None,
+            "host_tier_bytes": host_tier_bytes is not None})
         self.drafter = resolve_drafter(speculate, spec_k=spec_k,
                                        n_slots=n_slots)
         self.spec = new_spec_counters()
@@ -1652,8 +1696,7 @@ class ServeEngine:
             resolve_context_bounds(self.config, max_len, page_size)
         # a forced 'flash' the compiled kernel cannot take fails here, at
         # construction, not inside the first forward of a live request
-        resolve_attend_impl(self.attend_impl, self.config.head_size,
-                            page_size)
+        resolve_attend_for(self.config, self.attend_impl, page_size)
         self.page_size = page_size
         self.n_slots = n_slots
         if n_pages is None:
@@ -2174,6 +2217,8 @@ class ServeEngine:
             "active_slots": len(sched.active_indices()),
             "prefilling_slots": len(sched.prefilling_indices()),
             "prefill_calls": self.programs.prefill_calls,
+            **({"routing": dict(self.programs.routing)}
+               if self.programs.routing["steps"] else {}),
             # committed prefix keys for the router's fleet directory —
             # read lock-free from the same snapshot, fenced by stats_seq
             "prefix_keys": (cache_prefix_keys(sched.cache)

@@ -77,6 +77,14 @@ rewritten by the same program that wrote it; comparing engines across
 prefill modes is a quality question (bounded by the attend error
 pinned in tests/test_kv_quant.py), not an identity one.
 
+WHAT a page holds is the family's (``pool_layout``): the description above
+is k and v of ``kvh x hd`` each. A latent-attention family (``models/mla.py``)
+caches ONE row a token a layer: the pool's ``v`` leaf holds the latent
+``c_kv`` (the absorbed form's values and the first part of its keys), its
+``k`` leaf the rope key all heads share, padded to whole lane tiles; the
+allocator, the tables, the scatters, the commit and the CoW copy below are
+the same code over those two leaves, and the attend is ``_attend_latent``.
+
 Device-side pieces (``paged_attend``, ``commit_prefill``, ``copy_pages``)
 are pure functions of array arguments — block tables and lengths arrive
 as int32 arrays, so requests coming and going never change a traced
@@ -92,8 +100,9 @@ import jax.numpy as jnp
 
 from ..ops.attention import multihead_attention
 from ..ops.dispatch import note_choice
-from ..ops.paged_decode import (PAGED_GATE, paged_decode_eligible,
-                                paged_flash_attend)
+from ..ops.paged_decode import (LATENT_GATE, PAGED_GATE,
+                                latent_decode_eligible, paged_decode_eligible,
+                                paged_flash_attend, paged_latent_attend)
 from ..train.precision import (Quantized, dequantize_blockwise,
                                quantize_blockwise)
 
@@ -109,8 +118,30 @@ def pages_for_tokens(n_tokens: int, page_size: int) -> int:
 
 
 def num_kv_heads(config) -> int:
-    """KV head count across families (gpt2/neox cache full heads)."""
+    """KV head count of the families whose cache is k and v of
+    ``heads x head_dim`` (gpt2/neox cache full heads). A family with another
+    cache states it in ``config.kv_layout()``: ask :func:`pool_layout`."""
     return getattr(config, "num_kv_heads", config.num_heads)
+
+
+def pool_layout(config) -> dict:
+    """``{leaf: (heads, width)}`` of one cached token in one layer, for the
+    pool's two leaves. The family says what they hold: by default k and v
+    of ``kv_heads x head_dim`` each; a latent-attention family
+    (``models/mla.py``) gives ``config.kv_layout()``: ``v`` the latent row
+    ``c_kv`` (the absorbed form's values AND the first part of its keys),
+    ``k`` the one rope key all heads share, padded to whole lane tiles."""
+    layout = getattr(config, "kv_layout", None)
+    if layout is not None:
+        return layout()
+    shape = (num_kv_heads(config), config.head_size)
+    return {"k": shape, "v": shape}
+
+
+def is_latent(config) -> bool:
+    """True where the pool holds latent rows (``config.kv_layout``): the
+    attend is then the absorbed or the decompressed latent form."""
+    return getattr(config, "kv_layout", None) is not None
 
 
 def kv_dtype_name(config, kv_dtype=None) -> str:
@@ -151,8 +182,9 @@ def pool_nbytes(pages: dict) -> int:
     return int(sum(x.nbytes for x in jax.tree.leaves(pages)))
 
 
-def resolve_attend_impl(impl: str, head_dim: int,
-                        page_size: int) -> tuple[str, str]:
+def resolve_attend_impl(impl: str, head_dim: int, page_size: int,
+                        latent_rope_width: Optional[int] = None
+                        ) -> tuple[str, str]:
     """``(impl, reason)`` for the paged attend family of one engine —
     decode, verify and chunk forwards all resolve the same way, because
     the kernel's shape gate is T-independent. ``"xla"`` is the gather
@@ -161,18 +193,29 @@ def resolve_attend_impl(impl: str, head_dim: int,
     instead of inside the first forward of a live request (off-TPU the
     kernel runs interpreted and takes any shape). ``"auto"`` picks the
     kernel on TPU when the shape passes the gate and the gather path
-    otherwise, and says which and why."""
+    otherwise, and says which and why.
+
+    A latent pool (``latent_rope_width`` given; ``head_dim`` is then the
+    latent row's width) resolves its DECODE step this way, to the
+    ``paged_latent_attend`` kernel; its prefill chunks always decompress the
+    gathered rows (``paged_attend``'s ``expand``)."""
     if impl not in ("auto", "flash", "xla"):
         raise ValueError(f"attend_impl must be 'auto', 'flash' or 'xla', "
                          f"got {impl!r}")
     backend = jax.default_backend()
-    eligible = paged_decode_eligible(head_dim, page_size)
+    if latent_rope_width is None:
+        eligible = paged_decode_eligible(head_dim, page_size)
+        kernel, gate = "paged flash", PAGED_GATE
+    else:
+        eligible = latent_decode_eligible(head_dim, latent_rope_width,
+                                          page_size)
+        kernel, gate = "paged latent", LATENT_GATE
     if impl == "flash":
         if backend == "tpu" and not eligible:
             raise ValueError(
                 f"attend_impl='flash': head_dim {head_dim} with page_size "
-                f"{page_size} is not a shape the compiled paged flash "
-                f"kernel takes ({PAGED_GATE}) — use attend_impl='xla'")
+                f"{page_size} is not a shape the compiled {kernel} "
+                f"kernel takes ({gate}) — use attend_impl='xla'")
         return impl, "forced"
     if impl == "xla":
         return impl, "forced"
@@ -180,43 +223,54 @@ def resolve_attend_impl(impl: str, head_dim: int,
         return "xla", f"auto: backend is {backend}, not tpu"
     if not eligible:
         return "xla", (f"auto: head_dim {head_dim} / page_size {page_size} "
-                       f"fails the kernel's gate ({PAGED_GATE})")
-    return "flash", "auto: tpu backend, shape passes the kernel's gate"
+                       f"fails the {kernel} kernel's gate ({gate})")
+    return "flash", (f"auto: tpu backend, shape passes the {kernel} "
+                     f"kernel's gate")
+
+
+def resolve_attend_for(config, impl: str, page_size: int) -> tuple[str, str]:
+    """:func:`resolve_attend_impl` for a model's own cache layout."""
+    if not is_latent(config):
+        return resolve_attend_impl(impl, config.head_size, page_size)
+    layout = pool_layout(config)
+    return resolve_attend_impl(impl, layout["v"][1], page_size,
+                               latent_rope_width=layout["k"][1])
 
 
 def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
                   kv_dtype=None) -> int:
     """Resident bytes of ``n_pages`` KV pages for this model at
-    ``kv_dtype`` (None = the model's storage dtype): pages x layers x 2
-    (k and v) x page_size x kv_heads x (head_dim x payload-itemsize
-    [+ 4 B fp32 scale per vector under int8 — the scales are pool state
-    and are priced, not hidden]) — the per-slot serving cost is this at
-    ``n_pages = pages_for_tokens(context)`` (train/preflight.py reports
-    that table)."""
+    ``kv_dtype`` (None = the model's storage dtype): pages x layers x
+    page_size x, summed over the pool's leaves (:func:`pool_layout`: k and
+    v of ``kv_heads x head_dim``, or a latent family's row and rope key,
+    lane padding counted), heads x (width x payload-itemsize [+ 4 B fp32
+    scale per vector under int8 — the scales are pool state and are priced,
+    not hidden]) — the per-slot serving cost is this at ``n_pages =
+    pages_for_tokens(context)`` (train/preflight.py reports that table)."""
     name = kv_dtype_name(config, kv_dtype)
-    per_vector = (config.head_size + 4 if name == "int8"
-                  else config.head_size * jnp.dtype(_KV_FLOAT[name]).itemsize)
-    return (n_pages * config.num_layers * 2 * page_size
-            * num_kv_heads(config) * per_vector)
+    per_token = sum(
+        heads * (width + 4 if name == "int8"
+                 else width * jnp.dtype(_KV_FLOAT[name]).itemsize)
+        for heads, width in pool_layout(config).values())
+    return n_pages * config.num_layers * page_size * per_token
 
 
 def init_pages(config, n_pages: int, page_size: int, kv_dtype=None) -> dict:
-    """Zeroed page pools {"k","v"}: [L, n_pages, page_size, kvh, hd]
-    arrays, or :class:`Quantized` (int8 payload of that shape + fp32
-    scales [L, n_pages, page_size, kvh, 1]) under ``kv_dtype="int8"``.
-    Zero scales dequantize to the same zero pool the float form starts
-    with."""
+    """Zeroed page pools {"k","v"}: [L, n_pages, page_size, heads, width]
+    arrays, each leaf's (heads, width) from :func:`pool_layout`, or
+    :class:`Quantized` (int8 payload of that shape + fp32 scales [L,
+    n_pages, page_size, heads, 1]) under ``kv_dtype="int8"``. Zero scales
+    dequantize to the same zero pool the float form starts with."""
     name = kv_dtype_name(config, kv_dtype)
-    shape = (config.num_layers, n_pages, page_size, num_kv_heads(config),
-             config.head_size)
-    if name == "int8":
-        def pool():
+
+    def pool(heads, width):
+        shape = (config.num_layers, n_pages, page_size, heads, width)
+        if name == "int8":
             return Quantized(q=jnp.zeros(shape, jnp.int8),
                              scale=jnp.zeros(shape[:-1] + (1,), jnp.float32))
+        return jnp.zeros(shape, _KV_FLOAT[name])
 
-        return {"k": pool(), "v": pool()}
-    return {"k": jnp.zeros(shape, _KV_FLOAT[name]),
-            "v": jnp.zeros(shape, _KV_FLOAT[name])}
+    return {leaf: pool(*shape) for leaf, shape in pool_layout(config).items()}
 
 
 class PagePool:
@@ -354,7 +408,7 @@ def pool_audit(pool: "PagePool", holder_maps, *, tier=None) -> None:
 
 def paged_attend(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
                  window=None, scale=None, softcap=None, impl: str = "auto",
-                 n_valid=None):
+                 n_valid=None, latent_rope=None, expand=None):
     """Scatter each slot's new k/v into its pages, then attend q over the
     slot's block-table context.
 
@@ -396,10 +450,27 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
     masking applies unchanged, window/scale/softcap included (Gemma-2
     decodes through this same path).
 
+    LATENT pools (``latent_rope`` = the rope key's live columns; the family
+    passes it, ``models/mla.py``): ``v`` holds a token's latent row
+    ``c_kv`` [1, C], ``k`` the rope key all heads share [1, Rw]; a token's
+    key is ``[c_kv | k_rope]`` and its value ``c_kv`` again. Two forms, the
+    same sum: ABSORBED (``expand`` None; the decode step) takes q
+    [S, T, H, C + R] with the key up-projection folded in and returns
+    [S, T, H, C], through ``paged_latent_attend`` on the pool as stored
+    under "flash" or the gathered rows under "xla"; DECOMPRESSED
+    (``expand(c_kv [S, N, C], k_rope [S, N, R]) -> (k, v) [S, N, H, D]``;
+    a prefill chunk, whose query tile no kernel here takes) gathers the
+    slot's rows whatever ``impl`` says, expands them per head and attends
+    in blocks of query rows.
+
     Returns (attn [S, T, Hq, D], (k_pages, v_pages) updated).
     """
     k_pages, v_pages, t_idx = _scatter_new(k_new, v_new, k_pages, v_pages,
                                            tables, lengths, n_valid)
+    if latent_rope is not None:
+        return _attend_latent(q, k_pages, v_pages, tables, lengths, t_idx,
+                              scale=scale, impl=impl, rope=latent_rope,
+                              expand=expand)
     return _attend_pages(q, k_pages, v_pages, tables, lengths, t_idx,
                          window=window, scale=scale, softcap=softcap,
                          impl=impl)
@@ -489,15 +560,73 @@ def _attend_pages(q, k_pages, v_pages, tables, lengths, t_idx, *, window,
     return attn, (k_pages, v_pages)
 
 
+LATENT_Q_BLOCK = 256    # query rows whose [H, rows, context] scores are live
+
+
+@jax.named_scope("attend")
+def _attend_latent(q, k_pages, v_pages, tables, lengths, t_idx, *, scale,
+                   impl, rope, expand):
+    """The read half of :func:`paged_attend` over a latent pool."""
+    if isinstance(k_pages, Quantized):
+        raise ValueError("a latent pool is stored in float: int8 KV is not "
+                         "implemented for latent attention")
+    s, t, h, _ = q.shape
+    page, c = v_pages.shape[1], v_pages.shape[-1]
+    if expand is None:
+        if impl == "auto":
+            impl, reason = resolve_attend_impl(
+                impl, c, page, latent_rope_width=k_pages.shape[-1])
+            note_choice("paged_attend", impl, reason)
+        if impl == "flash":
+            return (paged_latent_attend(q, k_pages, v_pages, tables, lengths,
+                                        scale=scale), (k_pages, v_pages))
+    tot = tables.shape[1] * page
+    ckv = v_pages[tables].reshape(s, tot, c)              # [S, N, C]
+    kr = k_pages[tables].reshape(s, tot, -1)[..., :rope]  # [S, N, R]
+    kv_pos = jnp.arange(tot)
+    if expand is None:     # absorbed, over the gathered rows: the parity
+        # baseline of the kernel, in float32 throughout (the CPU's runtime
+        # has no bf16 x bf16 = f32 product of this shape inside a scan)
+        keys = jnp.concatenate([ckv, kr], axis=-1).astype(jnp.float32)
+        scores = jnp.einsum("sthw,snw->shtn", q.astype(jnp.float32),
+                            keys) * scale
+        mask = t_idx[:, None, :, None] >= kv_pos[None, None, None, :]
+        probs = jax.nn.softmax(
+            jnp.where(mask, scores, jnp.finfo(jnp.float32).min), axis=-1)
+        attn = jnp.einsum("shtn,snc->sthc", probs, keys[..., :c])
+        return attn.astype(q.dtype), (k_pages, v_pages)
+    k, v = expand(ckv, kr)                                # [S, N, H, D]
+    kv_positions = jnp.broadcast_to(kv_pos[None, :], (s, tot))
+
+    def rows(args):     # one block of query rows against the whole context
+        qb, pb = args
+        return multihead_attention(
+            qb, k, v, causal=True, positions=pb, kv_positions=kv_positions,
+            impl="xla", standard_layout=False, scale=scale)
+
+    if t <= LATENT_Q_BLOCK or t % LATENT_Q_BLOCK:
+        attn = rows((q, t_idx))
+    else:
+        nb = t // LATENT_Q_BLOCK
+        qs = q.reshape(s, nb, LATENT_Q_BLOCK, h, -1).swapaxes(0, 1)
+        ps = t_idx.reshape(s, nb, LATENT_Q_BLOCK).swapaxes(0, 1)
+        attn = jax.lax.map(rows, (qs, ps)).swapaxes(0, 1)
+        attn = attn.reshape(s, t, h, -1)
+    return attn, (k_pages, v_pages)
+
+
 def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None):
     """Bind (tables, lengths, impl, n_valid) into the per-layer attend
-    callback the family ``paged_decode_step`` hooks expect."""
+    callback the family ``paged_decode_step`` hooks expect. A latent family
+    adds ``latent_rope`` (and ``expand`` for a chunk) to its call; see
+    :func:`paged_attend`."""
 
     def attend(q, k_new, v_new, k_pages, v_pages, *, window=None, scale=None,
-               softcap=None):
+               softcap=None, **latent):
         return paged_attend(q, k_new, v_new, k_pages, v_pages, tables,
                             lengths, window=window, scale=scale,
-                            softcap=softcap, impl=impl, n_valid=n_valid)
+                            softcap=softcap, impl=impl, n_valid=n_valid,
+                            **latent)
 
     return attend
 

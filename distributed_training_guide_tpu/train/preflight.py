@@ -381,12 +381,13 @@ def run_preflight(trainer, *, global_batch: int, seq_length: int,
 
     # serving-side KV pricing (serve/kv_pages.py): what ONE decode slot of
     # this model costs at the training context, in pages — pages x layers x
-    # 2 (k,v) x page_size x kv_heads x head_dim bytes. Training answers
+    # page_size x what the family caches for a token (kv_pages.pool_layout:
+    # k and v of kv_heads x head_dim, or a latent row). Training answers
     # "does the step fit"; this row answers the follow-on "how many
     # concurrent requests fit next to the weights when the checkpoint
     # serves" before anyone sizes a pool by trial and error.
-    from ..serve.kv_pages import KV_DTYPES, kv_page_bytes, num_kv_heads, \
-        pages_for_tokens
+    from ..serve.kv_pages import KV_DTYPES, is_latent, kv_page_bytes, \
+        num_kv_heads, pages_for_tokens
 
     page_size = 16
     pages_per_slot = pages_for_tokens(seq_length, page_size)
@@ -401,6 +402,7 @@ def run_preflight(trainer, *, global_batch: int, seq_length: int,
     tp = int(trainer.plan.mesh.shape["tp"])
     kv_shards = tp if (
         tp > 1 and all(a == "tp" for a in trainer.plan.active_axes())
+        and not is_latent(cfg)      # one latent row: no kv head to split
         and num_kv_heads(cfg) % tp == 0 and cfg.num_heads % tp == 0) else 1
     # per-generated-token decode traffic: the flash-decode kernel
     # (ops/paged_decode.py) READS the live context's pages through the

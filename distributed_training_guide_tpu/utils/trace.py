@@ -14,7 +14,7 @@ thread. Arguments known only when the work is done go on with
         s.set_metadata(grown=grown, preempted=preempted)
 
 On the device the names are HLO metadata and cost nothing at run time:
-``jax.named_scope`` per model part (:data:`SCOPES`), a stable ``__name__`` on
+``jax.named_scope`` per model part (:data:`SCOPES`, :data:`SUBSCOPES`), a stable ``__name__`` on
 every jitted program (:data:`PROGRAMS`; the trace's ``XLA Modules`` line then
 reads ``jit_train_step`` or ``jit_serve_decode``) and ``name=`` on every
 ``pallas_call`` (:data:`KERNELS`). The tuples below are the whole vocabulary;
@@ -37,15 +37,23 @@ SPANS = (
 )
 
 # jax.named_scope names: model parts (`layers` is the layer scan's own work,
-# outside any sublayer), the train step's tail, the paged serve path
+# outside any sublayer), the train step's tail, the paged serve path. The
+# benchmark's scope table (`readers/scope_time.py`) is keyed by these: an
+# operation goes to the innermost of them in its path
 SCOPES = (
     "embed", "layers", "attn", "mlp", "final_norm", "loss_head", "optimizer",
     "router", "experts", "attend", "kv_write", "sample",
 )
 
+# named_scope names INSIDE a scope above, which split it without leaving it:
+# `latent_proj` (in `attn`: MLA's low-rank projections and absorbed products)
+# and `shared_expert` (in `experts`). A reader that knows only SCOPES counts
+# their time under the parent; `readers/path_component.py` reads one alone
+SUBSCOPES = ("latent_proj", "shared_expert")
+
 # pallas_call names (ops/)
-KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend", "gmm",
-           "tgmm", "qmm")
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",
+           "paged_latent_attend", "gmm", "tgmm", "qmm")
 
 # jitted programs; a name ending in _k, _b or _t takes the static size that
 # keys the program (serve_horizon_k4, serve_prefill_b128, serve_chunk_t64,

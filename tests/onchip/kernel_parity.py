@@ -26,7 +26,14 @@ every one, or it proves nothing.
 pools, page 32, T = 5 (speculative verify), the cell's shape at T = 5 and at
 its chunk of 512 over a 3000-token history, banded and soft-capped flash,
 ``gmm``/``tgmm`` at hidden 2048 x expert width 768, the int8 matmul at
-1024 x 3072 (128-wide blocks). A builder runs that by hand.
+1024 x 3072 (128-wide blocks), and the ``--mla`` cases. A builder runs that
+by hand. ``--mla`` runs the latent-attention cell's kernels alone
+(``mistral-small-4-ep4-l6.serve.decode32-ctx8k``): the absorbed latent attend
+(``paged_latent_attend``: 32 slots of 32 heads over one latent row of 256 +
+64, page 128, 96 table columns, 3073 pages, bf16, T = 1, lengths 0..12,287 in
+one call) against the gathered rows, with two sabotaged kernels the bound
+must refuse, and ``gmm`` at hidden 4096 x expert width 2048 and back with 32
+groups of 0-4 rows in a 128-row buffer (the decode step's held pairs).
 
 One process; fails (no last line, exit 1) off the chip. Prints the entry
 points' start-up device line, one JSON line per case, and last
@@ -235,6 +242,93 @@ def gmm_case(dtype) -> None:
          dtype=jnp.dtype(dtype).name, hidden=k, expert_width=n, experts=groups)
 
 
+# the latent cell (mistral-small-4-ep4-l6.serve.decode32-ctx8k): an empty
+# slot, both sides of a page and of a block of the walk (8 pages), the
+# cell's contexts, a slot that fills its table
+LATENT = dict(heads=32, latent=256, rope=64, rope_width=128, page=128,
+              columns=96, pages=3073)
+LATENT_LENGTHS = [0, 1, 127, 128, 129, 1023, 1024, 1025, 2047, 4096, 8191,
+                  8192, 8200, 8640, 9000, 9100, 9216, 9217, 10000, 11000,
+                  12000, 12287] + [8200 + 37 * i for i in range(10)]
+
+
+def latent_inputs():
+    c = LATENT
+    n = len(LATENT_LENGTHS)
+    q = normal((n, 1, c["heads"], c["latent"] + c["rope"]), jnp.bfloat16)
+    k_new = normal((n, 1, 1, c["rope_width"]), jnp.bfloat16)
+    v_new = normal((n, 1, 1, c["latent"]), jnp.bfloat16)
+    k_pool = normal((c["pages"], c["page"], 1, c["rope_width"]), jnp.bfloat16)
+    v_pool = normal((c["pages"], c["page"], 1, c["latent"]), jnp.bfloat16)
+    tables = jnp.asarray(RNG.permutation(np.arange(1, c["pages"]))
+                         [:n * c["columns"]].reshape(n, c["columns"]),
+                         jnp.int32)
+    return (q, k_new, v_new, k_pool, v_pool, tables,
+            jnp.asarray(LATENT_LENGTHS, jnp.int32))
+
+
+def latent_attend(impl: str, args):
+    fn = jax.jit(lambda *a: kv_pages.paged_attend(
+        *a, impl=impl, scale=0.2 / LATENT["latent"] ** 0.5,
+        latent_rope=LATENT["rope"])[0])
+    return fn(*args)
+
+
+def latent_cases() -> int:
+    """The cell's decode attend against the gathered rows, then its control:
+    the kernel reading lengths one short and one page of the table rolled;
+    returns how many of the two the bound refused."""
+    args = latent_inputs()
+    want = latent_attend("xla", args)
+    case("paged_latent_attend", {"out": (latent_attend("flash", args), want)},
+         pool="bf16", page=LATENT["page"], T=1, heads=LATENT["heads"],
+         slots=len(LATENT_LENGTHS),
+         lengths=f"{min(LATENT_LENGTHS)}..{max(LATENT_LENGTHS)}")
+    real = kv_pages.paged_latent_attend
+    refused = 0
+    for mode in ("drop_newest", "wrong_pages"):
+        def wrapped(q, k_pages, v_pages, tables, lengths, **kw):
+            if mode == "wrong_pages":
+                tables = jnp.roll(tables, 1, axis=1)
+            else:
+                lengths = jnp.maximum(lengths - 1, 0)
+            return real(q, k_pages, v_pages, tables, lengths, **kw)
+        kv_pages.paged_latent_attend = wrapped
+        try:
+            err, ref = worst(latent_attend("flash", args), want)
+        finally:
+            kv_pages.paged_latent_attend = real
+        caught = err > RTOL * max(1.0, ref)
+        refused += caught
+        print(json.dumps({"control": f"latent {mode}", "max_abs_err": err,
+                          "ref_max": ref, "rtol": RTOL, "refused": caught}),
+              flush=True)
+    return refused
+
+
+def gmm_decode_case(k: int, n: int) -> None:
+    """The decode step's expert products: 32 held experts, 0-4 pairs each,
+    in the 128-row buffer of 32 tokens x top-4 (rows past the held pairs
+    come back zero)."""
+    gm = importlib.import_module(
+        "distributed_training_guide_tpu.ops.grouped_matmul")
+    rows, groups = 128, 32
+    sizes = jnp.asarray(RNG.integers(0, 5, groups), jnp.int32)
+    lhs = normal((rows, k), jnp.bfloat16)
+    rhs = normal((groups, k, n), jnp.bfloat16) * jnp.asarray(0.02, jnp.bfloat16)
+    out_p, out_e = (jax.jit(lambda a, b, impl=impl: gm.grouped_matmul(
+        a, b, sizes, impl=impl))(lhs, rhs) for impl in ("pallas", "einsum"))
+    case("gmm decode", {"out": (out_p, out_e)}, hidden=k, expert_width=n,
+         experts=groups, rows=rows, held_pairs=int(sizes.sum()))
+
+
+def mla_cases() -> int:
+    refused = latent_cases()
+    gmm_decode_case(4096, 2048)
+    gmm_decode_case(2048, 4096)
+    return refused
+
+
 def int8_matmul_case() -> None:
     qm = importlib.import_module(
         "distributed_training_guide_tpu.ops.quantized_matmul")
@@ -252,20 +346,33 @@ def int8_matmul_case() -> None:
 
 
 def main(argv) -> int:
-    everything = argv == ["--all"]
-    if argv and not everything:
-        raise SystemExit("usage: kernel_parity.py [--all]")
+    everything, mla_only = argv == ["--all"], argv == ["--mla"]
+    if argv and not (everything or mla_only):
+        raise SystemExit("usage: kernel_parity.py [--all|--mla]")
     print_device_line("attend", ("flash", "forced"), CACHE.directory)
     if jax.devices()[0].platform != EXPECT_PLATFORM:
         print(f"kernel_parity FAILED: runs on "
               f"{jax.devices()[0].platform!r}, not {EXPECT_PLATFORM!r}",
               file=sys.stderr)
         return 1
+    if mla_only:
+        refused = mla_cases()
+        CACHE.print_line()
+        if FAILED or refused != 2:
+            print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
+                  f"sabotaged latent kernels refused: {refused} of 2",
+                  file=sys.stderr)
+            return 1
+        print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
+                          "controls_refused": refused}), flush=True)
+        return 0
     for t in (1, 64):
         paged_case("fp32", 16, t)
     cell_case(1)
     flash_case()
+    latent_refused = 2
     if everything:
+        latent_refused = mla_cases()
         for t in (5, 512):
             cell_case(t)
         for pool in ("fp32", "bf16", "int8"):
@@ -281,9 +388,10 @@ def main(argv) -> int:
         int8_matmul_case()
     refused = controls()
     CACHE.print_line()
-    if FAILED or refused != 4:
+    if FAILED or refused != 4 or latent_refused != 2:
         print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
-              f"sabotaged kernels refused: {refused} of 4", file=sys.stderr)
+              f"sabotaged kernels refused: {refused} of 4, latent "
+              f"{latent_refused} of 2", file=sys.stderr)
         return 1
     print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
                       "controls_refused": refused}), flush=True)

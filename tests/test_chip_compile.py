@@ -342,3 +342,112 @@ def test_kernels_carry_their_names(chip_compile, case):
         assert any(named(c, name) for c in calls), (name, calls)
     for call in calls:      # and no kernel without a name
         assert any(named(call, name) for name in names), call
+
+
+# ---- the latent-attention family's cell -------------------------------------
+# mistral-small-4-ep4-l6.serve.decode32-ctx8k: 32 slots of 32 heads over one
+# latent row of 256 + 64 (the rope key's rows padded to 128 lanes), page 128,
+# 96 table columns, 3073 pages, 6 layers, 32 of 128 experts held, bf16
+
+LATENT_CELL = dict(slots=32, heads=32, latent=256, rope=64, rope_width=128,
+                   page=128, columns=96, pages=3073)
+
+
+def test_latent_attend_compiles_at_the_cells_shape(chip_compile):
+    from distributed_training_guide_tpu.ops.paged_decode import (
+        latent_decode_eligible, paged_latent_attend)
+
+    c = LATENT_CELL
+    assert latent_decode_eligible(c["latent"], c["rope_width"], c["page"],
+                                  rows=c["heads"])
+    text = chip_compile(
+        lambda *a: paged_latent_attend(*a, scale=0.1, interpret=False),
+        ((c["slots"], 1, c["heads"], c["latent"] + c["rope"]), jnp.bfloat16),
+        ((c["pages"], c["page"], 1, c["rope_width"]), jnp.bfloat16),
+        ((c["pages"], c["page"], 1, c["latent"]), jnp.bfloat16),
+        ((c["slots"], c["columns"]), jnp.int32), ((c["slots"],), jnp.int32))
+    calls = kernel_calls(text)
+    assert calls and all(named(x, "paged_latent_attend") for x in calls)
+    # the pools reach the kernel as they are stored: nothing pool-sized moves
+    assert not re.search(rf"(copy|transpose)\([^\n]*\[{c['pages']},", text)
+    assert f"bf16[{c['pages']},{c['page']},1,{c['latent']}]" in text
+
+
+@pytest.mark.parametrize("k,n", [(4096, 2048), (2048, 4096)])
+def test_grouped_matmul_compiles_at_the_decode_steps_shape(chip_compile, k, n):
+    """32 held experts, the 128-row buffer of 32 tokens x top-4."""
+    text = chip_compile(
+        lambda lhs, rhs, sizes: gmm_mod.grouped_matmul(
+            lhs, rhs, sizes, impl="pallas", interpret=False),
+        ((128, k), jnp.bfloat16), ((32, k, n), jnp.bfloat16),
+        ((32,), jnp.int32))
+    assert any(named(c, "gmm") for c in kernel_calls(text))
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    """The family's programs as the chip runs them: under a described
+    topology ``jax.default_backend()`` is the CPU, so ``interpret=None`` and
+    ``impl="auto"`` would pick the interpreter and the scan; the test steers
+    them here, not the program."""
+    from distributed_training_guide_tpu.ops import paged_decode
+
+    monkeypatch.setattr(paged_decode, "resolve_interpret", lambda i: False)
+    monkeypatch.setattr(gmm_mod, "resolve_interpret", lambda i: False)
+    monkeypatch.setattr(gmm_mod, "_resolve_impl",
+                        lambda impl: "pallas" if impl == "auto" else impl)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk2048"])
+def test_latent_familys_serve_programs_compile_at_the_cells_size(
+        chip_compile, one_chip, compiled_kernels, program):
+    """The whole decode step and the whole prefill chunk at the cell's size
+    (10.1 GiB of weights, the 1.69 GiB pool): the compiler fits them into the
+    chip's 15.75 GiB, the decode step holds the latent kernel and three
+    ``gmm`` calls a layer (one scan body), the chunk no latent kernel (it
+    decompresses the gathered rows)."""
+    import dataclasses
+
+    from distributed_training_guide_tpu.models import mla
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    c = LATENT_CELL
+    cfg = dataclasses.replace(
+        mla.PRESETS["mistral-small-4-119b"], num_layers=6, vocab_size=32768,
+        experts_held=(0, 32), dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: mla.init(cfg, jax.random.key(0)))
+    pools = {leaf: ((6, c["pages"], c["page"], *shape), jnp.bfloat16)
+             for leaf, shape in cfg.kv_layout().items()}
+    leaves, treedef = jax.tree.flatten(params)
+
+    def decode(kp, vp, tokens, lengths, tables, *flat):
+        p = jax.tree.unflatten(treedef, flat)
+        logits, cache = mla.paged_decode_step(
+            cfg, p, tokens[:, None], lengths, {"k": kp, "v": vp},
+            kv_pages.make_attend(tables, lengths, impl="flash"))
+        return jnp.argmax(logits, -1), cache["k"], cache["v"], cache["routing"]
+
+    def chunk(kp, vp, ids, start, table, *flat):
+        p = jax.tree.unflatten(treedef, flat)
+        logits, cache = mla.paged_decode_step(
+            cfg, p, ids, start, {"k": kp, "v": vp},
+            kv_pages.make_attend(table, start, impl="flash",
+                                 n_valid=jnp.asarray([2048])),
+            last_index=jnp.asarray(2047))
+        return logits[0], cache["k"], cache["v"]
+
+    weights = [(x.shape, x.dtype) for x in leaves]
+    if program == "decode":
+        text = chip_compile(decode, pools["k"], pools["v"],
+                            ((32,), jnp.int32), ((32,), jnp.int32),
+                            ((32, c["columns"]), jnp.int32), *weights)
+        calls = kernel_calls(text)
+        assert sum(named(x, "paged_latent_attend") for x in calls) == 1
+        assert sum(named(x, "gmm") for x in calls) == 3
+    else:
+        text = chip_compile(chunk, pools["k"], pools["v"],
+                            ((1, 2048), jnp.int32), ((1,), jnp.int32),
+                            ((1, c["columns"]), jnp.int32), *weights)
+        calls = kernel_calls(text)
+        assert not any(named(x, "paged_latent_attend") for x in calls)
+        assert sum(named(x, "gmm") for x in calls) == 3

@@ -19,7 +19,8 @@ from distributed_training_guide_tpu.train.step import lower_step
 from distributed_training_guide_tpu.utils import trace as trace_mod
 from distributed_training_guide_tpu.utils.trace import (KERNELS, PREFIX,
                                                         PROGRAMS, SCOPES,
-                                                        SPANS, named, span)
+                                                        SPANS, SUBSCOPES,
+                                                        named, span)
 
 SERVE_CHILDREN = {s for s in SPANS if s.startswith("serve.")} - {"serve.step"}
 
@@ -278,6 +279,33 @@ def test_serve_programs_have_stable_names_and_scopes(debug_model):
                    for p in PROGRAMS), name
 
 
+def test_latent_family_decode_carries_its_subscopes_and_kernel():
+    """``models/mla.py`` through ``ServeEngine``: the sub-scopes sit inside
+    their parents (``attn/latent_proj``, ``experts/shared_expert``), so the
+    scope table still adds up, and the attend is the latent kernel's."""
+    bundle = get_model("mla-moe-debug", dtype=jnp.float32)
+    params = bundle.init(bundle.config, jax.random.key(0))
+    engine = ServeEngine(bundle, params, n_slots=2, page_size=16, max_len=64,
+                         attend_impl="flash")
+    arrays = {k: jnp.asarray(v)
+              for k, v in engine.scheduler.decode_arrays().items()}
+    text = engine._decode_fn.lower(
+        engine.params, engine.pages["k"], engine.pages["v"],
+        *(arrays[k] for k in ("tokens", "lengths", "tables", "seeds",
+                              "temps", "top_ks", "top_ps", "actives"))
+    ).as_text(debug_info=True)
+    found = scope_components(text)
+    want = {"attn", "latent_proj", "attend", "paged_latent_attend", "experts",
+            "shared_expert", "router", "kv_write", "layers", "loss_head"}
+    assert want <= found, want - found
+    # a sub-scope is written inside its parent's
+    fragments = re.findall(r'loc\("([^"]+)"', text)
+    assert any(f.startswith("experts/shared_expert/") for f in fragments)
+    assert any(f.startswith("attn/latent_proj/") for f in fragments)
+    assert "paged_latent_attend" in KERNELS and set(SUBSCOPES) == {
+        "latent_proj", "shared_expert"}
+
+
 def test_named_gives_jit_the_name():
     fn = jax.jit(named(lambda x: x + 1, "serve_copy"))
     assert "module @jit_serve_copy" in fn.lower(jnp.zeros(2)).as_text()
@@ -301,5 +329,6 @@ def test_the_vocabulary_is_what_the_package_uses():
             kernels |= set(re.findall(r'^\s+name="(\w+)",$', src, re.M))
     assert annot == ["trace.py"]
     assert spans | {"train.data", "train.step"} == set(SPANS)
-    assert scopes == set(SCOPES)
+    assert scopes == set(SCOPES) | set(SUBSCOPES)
+    assert not set(SCOPES) & set(SUBSCOPES)
     assert kernels == set(KERNELS)
