@@ -101,7 +101,8 @@ PARITY_LIMIT_S = 240
 # how much an argmax depends on attention is a property of the weights — so
 # this check is not leaned on alone: the last phase,
 # tests/onchip/kernel_parity.py, holds the kernels to their references on
-# random inputs and runs those four sabotages as its own control.
+# random inputs and runs those four sabotages (and a fifth: the layer's
+# page base left out of the stacked pools) as its own control.
 MIN_FIRST_TOKENS = 2
 MIN_PREFIX_AGREEMENT = 0.6
 

@@ -361,26 +361,24 @@ def paged_decode_step(config: GPT2Config, params: dict,
     logits row for a padded chunk, ``all_logits=True`` keeps every row
     (speculative verification). The block wiring is ``_cached_block``
     — the same body the contiguous decode runs."""
-    from .llama import paged_logits_at, paged_positions
+    from .llama import (paged_logits_at, paged_positions,
+                        scan_paged_layers)
 
     pos2d = paged_positions(token_ids, positions)
     x = embed_tokens(config, params, token_ids, pos2d)
 
-    def body(x, inputs):
-        layer, kp, vp = inputs
-
+    def body(x, pools, layer, i, *_):
         def override(q, k, v, *, window, scale, softcap):
             del window, scale, softcap  # no gpt2 attention extras
-            return attend(q, k, v, kp, vp)
+            return attend(q, k, v, *pools, i)
 
-        return _cached_block(config, x, layer, pos2d, None,
-                             attend_override=override)
+        x, pools = _cached_block(config, x, layer, pos2d, None,
+                                 attend_override=override)
+        return x, pools, None
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"],
-                                         cache["k"], cache["v"]))
+    x, pools, _ = scan_paged_layers(body, x, params, cache)
     return (paged_logits_at(lm_head_logits, config, params, x, last_index,
-                            all_logits),
-            {"k": ks, "v": vs})
+                            all_logits), pools)
 
 
 PRESETS = {

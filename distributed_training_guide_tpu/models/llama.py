@@ -698,23 +698,6 @@ def _lora_wmat_override(config, lora, lstack, sort):
     return ov
 
 
-def _lora_scan_xs(params, cache, wins, lora):
-    """Scan columns for the lora-threaded layer scans: the usual
-    (layers, k, v[, wins]) plus each target's per-layer A/B pool slices
-    (stacks are ``[L, G, ...]`` — the layer axis leads, like every other
-    scanned leaf)."""
-    if wins is None:
-        return (params["layers"], cache["k"], cache["v"], lora["stacks"])
-    return (params["layers"], cache["k"], cache["v"], wins, lora["stacks"])
-
-
-def _lora_unpack(inputs, wins):
-    if wins is None:
-        layer, ck, cv, lstack = inputs
-        return layer, ck, cv, None, lstack
-    return inputs
-
-
 def _layer_window_column(config):
     """Per-layer window column for the layer scans — training AND decode
     share this one translation (None when uniform; 0 -> a band wider than
@@ -734,15 +717,47 @@ def _layer_window_column(config):
 
 def _scan_kv_layers(body, x, params, cache, wins):
     """``lax.scan`` the per-layer decode ``body`` over (layer, k, v, window)
-    columns — the one adapter shared by every family's prefill/decode scans.
+    columns — the adapter shared by every family's CONTIGUOUS-cache
+    prefill/decode scans (``models/sample.py`` generation, the engine's
+    bucketed prefill), whose ``[L, B, max_len, ...]`` caches a layer
+    rewrites whole: each layer's cache is a scanned column in and a stacked
+    column out. The PAGED steps never slice their pools so: they scan with
+    :func:`scan_paged_layers`.
     ``wins`` None (uniform window config) scans without the window column so
     the traced program stays identical to the pre-schedule form."""
-    with jax.named_scope("layers"):   # the scan's slicing of weights and pools
+    with jax.named_scope("layers"):   # the scan's slicing of weights and cache
         if wins is None:
             return jax.lax.scan(lambda c, inp: body(c, (*inp, None)), x,
                                 (params["layers"], cache["k"], cache["v"]))
         return jax.lax.scan(body, x,
                             (params["layers"], cache["k"], cache["v"], wins))
+
+
+def scan_paged_layers(body, x, params, cache, wins=None, lora_stacks=None):
+    """The layer scan of every family's PAGED step. The stacked page pools
+    ``cache["k"], cache["v"]`` ([L, P, page, heads, width] leaves) ride the
+    scan as part of the CARRY, whole, beside ``x``; the scanned columns are
+    the layers' weights, the layer's index and, where a family has them, the
+    per-layer windows and the multi-LoRA stacks (None columns are empty
+    pytrees: the scan sees nothing there). ``body(x, pools, layer, i, w,
+    lstack) -> (x, pools, ys)`` hands the pools and ``i`` to the attend
+    callback (``serve/kv_pages.paged_attend``'s contract), which writes and
+    reads them addressed by layer, and returns them whole: nothing
+    pool-sized is sliced per iteration or stacked per output, so the donated
+    pools are updated in place. ``ys`` is what really is per layer (a routing
+    family's counts; None elsewhere). Returns ``(x, {"k", "v"}, ys)``."""
+    n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
+
+    def step(carry, columns):
+        x, pools, ys = body(*carry, *columns)
+        return (x, pools), ys
+
+    with jax.named_scope("layers"):   # the scan's slicing of the weights
+        (x, (kp, vp)), ys = jax.lax.scan(
+            step, (x, (cache["k"], cache["v"])),
+            (params["layers"], jnp.arange(n_layers, dtype=jnp.int32), wins,
+             lora_stacks))
+    return x, {"k": kp, "v": vp}, ys
 
 
 def init_cache(config: LlamaConfig, batch: int, max_len: int) -> dict:
@@ -780,7 +795,7 @@ def prefill(config: LlamaConfig, params: dict, input_ids: jnp.ndarray,
             layer, ck, cv, w = inputs
             ov = None
         else:
-            layer, ck, cv, w, lstack = _lora_unpack(inputs, wins)
+            layer, ck, cv, w, lstack = inputs
             ov = _lora_wmat_override(config, lora, lstack, sort)
         attn, (k, v) = attention_sublayer(
             config, x, layer["attn"],
@@ -794,9 +809,12 @@ def prefill(config: LlamaConfig, params: dict, input_ids: jnp.ndarray,
     if lora is None:
         x, (ks, vs) = _scan_kv_layers(body, x, params, cache, wins)
     else:
+        # each target's per-layer A/B pool slices ride as one more column
+        # (stacks are [L, G, ...]); a None ``wins`` is an empty column
         with jax.named_scope("layers"):
             x, (ks, vs) = jax.lax.scan(
-                body, x, _lora_scan_xs(params, cache, wins, lora))
+                body, x, (params["layers"], cache["k"], cache["v"], wins,
+                          lora["stacks"]))
     # slice BEFORE the head: projecting all P positions to [B, P, V] fp32
     # only to keep one row would cost P x the lm_head matmul and a
     # prompt-length-scaled logits buffer (norm + projection are per-position)
@@ -872,11 +890,13 @@ def paged_decode_step(config: LlamaConfig, params: dict,
     or a speculative-decoding VERIFICATION step (S slots, T = k+1
     candidates each), which instead passes ``all_logits=True`` for the
     [S, T, V] logits at every position (one target distribution per
-    drafted token). ``cache`` holds the page pools ``{"k","v"}:
-    [L, n_pages, page, kvh, hd]`` and ``attend(q, k, v, kp, vp, *,
-    window, scale, softcap)`` (built by serve/kv_pages.py) scatters the
-    new k/v into the layer's pages and attends each slot over its own
-    block table. Returns (logits [S, V] — or [S, T, V] under
+    drafted token). ``cache`` holds the STACKED page pools ``{"k","v"}:
+    [L, n_pages, page, kvh, hd]``, carried whole through the layer scan
+    (:func:`scan_paged_layers`), and ``attend(q, k, v, kp, vp, layer, *,
+    window, scale, softcap)`` (built by serve/kv_pages.py, whose
+    ``paged_attend`` states the contract) scatters the new k/v into that
+    layer's pages of the stacked pools and attends each slot over its own
+    block table there. Returns (logits [S, V] — or [S, T, V] under
     ``all_logits`` — and the updated cache).
 
     ``lora`` (multi-LoRA serving, see ``_lora_wmat_override``):
@@ -894,35 +914,27 @@ def paged_decode_step(config: LlamaConfig, params: dict,
         g = jax.tree.leaves(lora["stacks"])[0].shape[1]
         sort = _lora_sort(lora["adapters"], token_ids.shape[1], g)
 
-    def body(x, inputs):
-        if lora is None:
-            layer, kp, vp, w = inputs
-            ov = None
-        else:
-            layer, kp, vp, w, lstack = _lora_unpack(inputs, wins)
-            ov = _lora_wmat_override(config, lora, lstack, sort)
+    def body(x, pools, layer, i, w, lstack):
+        ov = (None if lora is None
+              else _lora_wmat_override(config, lora, lstack, sort))
 
         def override(q, k, v, *, window, scale, softcap):
-            return attend(q, k, v, kp, vp, window=window, scale=scale,
+            return attend(q, k, v, *pools, i, window=window, scale=scale,
                           softcap=softcap)
 
-        attn, (nkp, nvp) = attention_sublayer(
+        attn, pools = attention_sublayer(
             config, x, layer["attn"],
             None if config.post_norm else layer["input_norm"], pos2d,
             "xla", return_kv=True, window_override=w,
             attend_override=override, wmat_override=ov)
         x, _ = _decode_residuals(config, x, layer, attn, wmat_override=ov)
-        return x, (nkp, nvp)
+        return x, pools, None
 
-    if lora is None:
-        x, (ks, vs) = _scan_kv_layers(body, x, params, cache, wins)
-    else:
-        with jax.named_scope("layers"):
-            x, (ks, vs) = jax.lax.scan(
-                body, x, _lora_scan_xs(params, cache, wins, lora))
+    x, pools, _ = scan_paged_layers(
+        body, x, params, cache, wins,
+        None if lora is None else lora["stacks"])
     return (paged_logits_at(lm_head_logits, config, params, x, last_index,
-                            all_logits),
-            {"k": ks, "v": vs})
+                            all_logits), pools)
 
 
 # ---------------------------------------------------------------------------

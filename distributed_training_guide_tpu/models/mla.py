@@ -302,7 +302,8 @@ def latent_attention_sublayer(config: MlaMoeConfig, x: jnp.ndarray, p: dict,
     decompressed; ``cache_out`` is the new latent rows ``(k_new, v_new)``
     [B, S, 1, *] (the bucketed prefill commits them). Else the serving
     engine's paged hook ``attend(q, k_new, v_new, **kw) -> (attn, pools)``
-    (``serve/kv_pages.paged_attend`` with the layer's pools bound): a query
+    (``serve/kv_pages.paged_attend`` with the stacked pools and the layer's
+    index bound): a query
     tile of up to ``ROWS_ALL_HEADS`` rows (the decode step) goes ABSORBED,
     a larger one (a prefill chunk) DECOMPRESSED; ``cache_out`` is the
     updated pools."""
@@ -412,7 +413,9 @@ def paged_decode_step(config: MlaMoeConfig, params: dict,
                       cache: dict, attend, last_index=None,
                       all_logits=False):
     """Paged multi-request decode/chunk step (``llama.paged_decode_step``'s
-    contract) over latent pools ``{"k": rope keys, "v": latent rows}``.
+    contract) over the stacked latent pools ``{"k": rope keys, "v": latent
+    rows}``, a carry of ``llama.scan_paged_layers``; the routing counts are
+    the scan's one per-layer output.
     The returned cache also carries ``"routing"``: int32 ``[pairs routed,
     pairs held here, experts touched, the fullest expert's pairs]``, the
     first three summed over the layers, which the decode program hands to
@@ -420,23 +423,21 @@ def paged_decode_step(config: MlaMoeConfig, params: dict,
     pos2d = llama.paged_positions(token_ids, positions)
     x = embed_tokens(config, params, token_ids, pos2d)
 
-    def body(x, inputs):
-        layer, kp, vp, _ = inputs
-
+    def body(x, pools, layer, i, *_):
         def bound(q, k_new, v_new, **kw):
-            return attend(q, k_new, v_new, kp, vp, **kw)
+            return attend(q, k_new, v_new, *pools, i, **kw)
 
         attn, pools = latent_attention_sublayer(
             config, x, layer["attn"], layer["input_norm"], pos2d, bound)
         x, counts = _ffn(config, x + attn, layer, return_counts=True)
-        return x, (*pools, counts)
+        return x, pools, counts
 
-    x, (ks, vs, counts) = llama._scan_kv_layers(body, x, params, cache, None)
+    x, pools, counts = llama.scan_paged_layers(body, x, params, cache)
     routing = jnp.concatenate([jnp.sum(counts[:, :3], axis=0),
                                jnp.max(counts[:, 3:], axis=0)])
     return (llama.paged_logits_at(lm_head_logits, config, params, x,
                                   last_index, all_logits),
-            {"k": ks, "v": vs, "routing": routing})
+            {**pools, "routing": routing})
 
 
 def _yarn(factor, original, **extra) -> tuple:

@@ -866,7 +866,8 @@ def paged_decode_step(config: MoELlamaConfig, params: dict,
                       cache: dict, attend, last_index=None,
                       all_logits=False):
     """Paged multi-request decode/chunk step (llama.paged_decode_step
-    contract): the routed FFN runs drop-free (ragged backend) on the
+    contract: the stacked pools ride ``llama.scan_paged_layers`` as a
+    carry): the routed FFN runs drop-free (ragged backend) on the
     [S, T] tokens — per-token routing is independent of the co-resident
     slots, so continuous batching cannot perturb a request's expert
     choices (and a speculative verification chunk cannot perturb the
@@ -877,14 +878,12 @@ def paged_decode_step(config: MoELlamaConfig, params: dict,
 
     wins = llama._layer_window_column(config)
 
-    def body(x, inputs):
-        layer, kp, vp, w = inputs
-
+    def body(x, pools, layer, i, w, _):
         def override(q, k, v, *, window, scale, softcap):
-            return attend(q, k, v, kp, vp, window=window, scale=scale,
+            return attend(q, k, v, *pools, i, window=window, scale=scale,
                           softcap=softcap)
 
-        attn, (nkp, nvp) = attention_sublayer(
+        attn, pools = attention_sublayer(
             config, x, layer["attn"], layer["input_norm"], pos2d,
             "xla", return_kv=True, window_override=w,
             attend_override=override)
@@ -892,12 +891,11 @@ def paged_decode_step(config: MoELlamaConfig, params: dict,
         h = _rmsnorm(x, layer["post_attn_norm"], config.rms_norm_eps)
         y, _, _ = _moe_ffn(config, h, layer["moe"], no_drop=True)
         x = x + y
-        return x, (nkp, nvp)
+        return x, pools, None
 
-    x, (ks, vs) = llama._scan_kv_layers(body, x, params, cache, wins)
+    x, pools, _ = llama.scan_paged_layers(body, x, params, cache, wins)
     return (llama.paged_logits_at(lm_head_logits, config, params, x,
-                                  last_index, all_logits),
-            {"k": ks, "v": vs})
+                                  last_index, all_logits), pools)
 
 
 PRESETS = {

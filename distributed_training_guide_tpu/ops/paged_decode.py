@@ -21,15 +21,17 @@ How the table is walked and in what units the pool is read:
   ``lengths[s] + T - 1``. Its bounds are read from the scalar-prefetched
   ``lengths`` and band, so a short slot costs a short walk and a table's
   dead columns cost nothing.
-- The pool stays in HBM in the layout it is stored in
-  (``memory_space=pl.ANY``; ``[P, page, Hkv, D]`` seen as
-  ``[P, page*Hkv, D]``, the same bytes: no copy before the call). A page
-  is read for ALL kv heads in one DMA (``pool.at[tables[s, i]]``, 128 KB
-  of bf16 at 32 heads of 128) into a double-buffered VMEM block of
-  ``n`` pages (4 at most), the next block in flight while this one is
-  attended to.
-  Sub-pages of the last block past the live range read the trash page 0
-  (finite, and masked like every position past a row's own).
+- The pool stays in HBM in the layout it is stored in, ALL LAYERS of it
+  (``memory_space=pl.ANY``; the stacked ``[L, P, page, Hkv, D]`` seen as
+  ``[L*P, page*Hkv, D]``, the same bytes: no slice and no copy before the
+  call, so the layer scan can carry the pools whole). The call takes the
+  layer's index; ``layer * P`` rides as one more scalar-prefetched
+  operand and a page is read for ALL kv heads in one DMA
+  (``pool.at[layer * P + tables[s, i]]``, 128 KB of bf16 at 32 heads of
+  128) into a double-buffered VMEM block of ``n`` pages (4 at most), the
+  next block in flight while this one is attended to.
+  Sub-pages of the last block past the live range read the layer's own
+  trash page 0 (finite, and masked like every position past a row's own).
 - A page's rows are ``(position, head)`` pairs. For ``hb`` heads at once
   the products are ``q[hb*T*G, D] . rows[page*hb, D]^T`` and
   ``p[hb*T*G, page*hb] . rows[page*hb, D]``, with the pairs whose heads
@@ -76,8 +78,10 @@ every row, not just live ones.
 
 QUANTIZED pools (``serve/kv_pages.py`` ``kv_dtype="int8"``): pass the
 per-(position, kv-head) fp32 scales as ``k_scale``/``v_scale``
-``[P, page, Hkv]``. They ride the same walk, one small DMA a page beside
-the payload's, and are applied where they are lane vectors: the k scale
+``[L, P, page, Hkv]``. The LAYER's scales (1/32 of its payload's bytes)
+are laid out as lane vectors before the call; they ride the same walk,
+one small DMA a page beside the payload's, and are applied where they are
+lane vectors: the k scale
 to the score columns, the v scale to ``p``'s columns (``q . (k*s)`` is
 ``(q . k) * s``), so the int8 payload meets the MXU as it is read and no
 float pool is ever materialized — at any T.
@@ -167,14 +171,16 @@ def _head_rows(buf, slot, i, head, *, hb, hkv, page):
     return pltpu.bitcast(picked, buf.dtype)
 
 
-def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_hbm, v_hbm, *rest,
-                   scale, softcap, page, hkv, hs, hb, n, quantized,
-                   block_q, groups):
+def _attend_kernel(lens_ref, tabs_ref, band_ref, base_ref, q_ref, k_hbm,
+                   v_hbm, *rest, scale, softcap, page, hkv, hs, hb, n,
+                   quantized, block_q, groups):
     """Grid (slot, head block). The walk over the slot's live pages is the
     ``fori_loop`` below; (m, l, acc) carry the online softmax across its
     blocks in VMEM scratch. Query row ``r`` of a head is the slot's token
-    ``r // groups`` at position ``lengths[slot] + r // groups``. Under
-    ``quantized`` the pages' k/v scale rows come along the same walk."""
+    ``r // groups`` at position ``lengths[slot] + r // groups``. The pools
+    are every layer's, page ``base_ref[0] + p`` being this layer's page
+    ``p``. Under ``quantized`` the pages' k/v scale rows (this layer's
+    alone) come along the same walk."""
     if quantized:
         (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sems,
          m_scr, l_scr, acc_scr) = rest
@@ -188,6 +194,7 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_hbm, v_hbm, *rest,
                                      # r sits at q_pos + r // groups
     window = band_ref[0]             # [window, q_off, k_off] contract;
                                      # 2**30 encodes "no window"
+    base = base_ref[0]               # the layer's first page in the pools
     # live pages [lo, hi): the newest row's frontier is q_pos + block_q - 1,
     # the oldest row's window edge is q_pos - (window - 1)
     hi = jnp.minimum(pl.cdiv(q_pos + block_q, page), max_pages)
@@ -197,9 +204,9 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_hbm, v_hbm, *rest,
     def copies(b, slot):
         """Block b's DMAs into buffer half ``slot``: n pages of k and of
         v through the table (and their scale rows)."""
-        pairs = [(k_hbm, kbuf), (v_hbm, vbuf)]
+        pairs = [(k_hbm, kbuf, base), (v_hbm, vbuf, base)]
         if quantized:
-            pairs += [(ks_hbm, ksbuf), (vs_hbm, vsbuf)]
+            pairs += [(ks_hbm, ksbuf, 0), (vs_hbm, vsbuf, 0)]
         out = []
         for i in range(n):
             col = lo + b * n + i
@@ -207,9 +214,9 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, q_ref, k_hbm, v_hbm, *rest,
             phys = jnp.where(col < hi,
                              tabs_ref[s_idx, jnp.minimum(col, max_pages - 1)],
                              0)
-            for j, (hbm, buf) in enumerate(pairs):
+            for j, (hbm, buf, first) in enumerate(pairs):
                 out.append(pltpu.make_async_copy(
-                    hbm.at[phys], buf.at[slot, i], sems.at[j, slot]))
+                    hbm.at[first + phys], buf.at[slot, i], sems.at[j, slot]))
         return out
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
@@ -319,17 +326,25 @@ def paged_decode_eligible(head_dim: int, page_size: int) -> bool:
     return head_dim % 128 == 0 and page_size % 8 == 0
 
 
+def _layer_base(layer, n_phys: int) -> jnp.ndarray:
+    """The scalar-prefetched ``[1]`` int32 first page of ``layer`` in pools
+    seen as ``[L * n_phys, ...]``."""
+    return (jnp.asarray(layer, jnp.int32) * n_phys).reshape(1)
+
+
 def paged_flash_attend(
     q: jnp.ndarray,          # [S, T, Hq, D] query tile per slot
                              # (rank 3 [S, Hq, D] = the T == 1 decode form)
-    k_pages: jnp.ndarray,    # [P, page, Hkv, D] — ONE layer's page pool
+    k_pages: jnp.ndarray,    # [L, P, page, Hkv, D]: EVERY layer's page pool
     v_pages: jnp.ndarray,    # (int8 payload when k_scale/v_scale given)
+    layer,                   # int32 scalar (traced in a layer scan): whose
+                             # pages ``tables`` names
     tables: jnp.ndarray,     # [S, M] int32 physical page ids (0 = trash)
     lengths: jnp.ndarray,    # [S] int32 — the FIRST query token's
                              # position; slot s's token t sits at
                              # lengths[s] + t, kv positions <= it are live
     *,
-    k_scale: Optional[jnp.ndarray] = None,   # [P, page, Hkv] fp32 — the
+    k_scale: Optional[jnp.ndarray] = None,   # [L, P, page, Hkv] fp32 — the
     v_scale: Optional[jnp.ndarray] = None,   # quantized pool's scales
     window=None,
     scale: Optional[float] = None,
@@ -341,9 +356,12 @@ def paged_flash_attend(
     (the output dtype is the QUERY's — a quantized pool still emits
     float attention).
 
-    The caller has already scattered the T new tokens' k/v into the
-    pages (``serve/kv_pages.paged_attend`` owns that write, trash-page
-    routing of ``n_valid`` pad tails included), so positions
+    The pools are the stacked ones the engine holds, handed over whole:
+    the kernel reads page ``layer * P + tables[s, i]`` of their
+    ``[L * P, page * Hkv, D]`` view, so no layer's pool is sliced out for
+    the call. The caller has already scattered the T new tokens' k/v into
+    the layer's pages (``serve/kv_pages.paged_attend`` owns that write,
+    trash-page routing of ``n_valid`` pad tails included), so positions
     ``lengths[s] .. lengths[s] + T - 1`` are resident and the per-row
     causal mask keeps everything past each row's own position (trash
     page, stale garbage, later draft rows) out — identical semantics to
@@ -360,7 +378,7 @@ def paged_flash_attend(
     if squeeze:
         q = q[:, None]
     s, t, hq, d = q.shape
-    n_phys, page, hkv, _ = k_pages.shape
+    n_layers, n_phys, page, hkv, _ = k_pages.shape
     m = tables.shape[1]
     if hkv < 1 or hq % hkv:
         # a silent floor-division here would drop query heads (the
@@ -390,23 +408,26 @@ def paged_flash_attend(
     kernel = functools.partial(_attend_kernel, scale=scale, softcap=softcap,
                                page=page, hkv=hkv, hs=hs, hb=hb, n=n,
                                quantized=quantized, block_q=t, groups=groups)
-    # the pool is handed over where it lies: a page's (position, head)
-    # pairs as rows, the same bytes as [P, page, Hkv, D]
+    # the pools are handed over where they lie: a page's (position, head)
+    # pairs as rows, every layer's pages in one run, the same bytes as
+    # [L, P, page, Hkv, D]
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     tile = pl.BlockSpec((1, hs * tg, d),
-                        lambda s_, h, lens, tabs, band_: (s_, h, 0))
+                        lambda s_, h, lens, tabs, band_, base: (s_, h, 0))
     in_specs = [tile, in_hbm, in_hbm]
-    operands = [qr, k_pages.reshape(n_phys, page * hkv, d),
-                v_pages.reshape(n_phys, page * hkv, d)]
+    operands = [qr, k_pages.reshape(n_layers * n_phys, page * hkv, d),
+                v_pages.reshape(n_layers * n_phys, page * hkv, d)]
     scratch = [pltpu.VMEM((DEPTH, n, page * hkv, d), k_pages.dtype),
                pltpu.VMEM((DEPTH, n, page * hkv, d), v_pages.dtype)]
     if quantized:
         # a page's scales as one lane vector per product: [hkv/hb,
         # page * hb], columns (position, head) like the score's, padded
-        # to whole 128-lane tiles (what a DMA moves)
+        # to whole 128-lane tiles (what a DMA moves). Only the layer's
+        # scales are laid out so: a 1/32 of its payload, not the stack
         width = _lane_tiles(page * hb)
 
         def lanes(x):
+            x = jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
             x = (x.astype(jnp.float32).reshape(n_phys, page, hkv // hb, hb)
                   .transpose(0, 2, 1, 3)
                   .reshape(n_phys, hkv // hb, page * hb))
@@ -422,7 +443,7 @@ def paged_flash_attend(
         pltpu.VMEM((hs * tg, d), jnp.float32),     # output accumulator
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,          # lengths, tables, band
+        num_scalar_prefetch=4,          # lengths, tables, band, layer base
         grid=(s, hkv // hs),
         in_specs=in_specs,
         out_specs=tile,
@@ -435,7 +456,8 @@ def paged_flash_attend(
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="paged_attend",
-    )(lengths.astype(jnp.int32), tables.astype(jnp.int32), band, *operands)
+    )(lengths.astype(jnp.int32), tables.astype(jnp.int32), band,
+      _layer_base(layer, n_phys), *operands)
     out = (out.reshape(s, hkv, t, groups, d)
               .transpose(0, 2, 1, 3, 4).reshape(s, t, hq, d))
     return out[:, 0] if squeeze else out
@@ -462,15 +484,17 @@ def latent_decode_eligible(latent_dim: int, rope_width: int, page_size: int,
             and page_size % 16 == 0 and rows <= ROWS_ALL_HEADS)
 
 
-def _latent_kernel(lens_ref, tabs_ref, qc_ref, qr_ref, c_hbm, r_hbm, o_ref,
-                   cbuf, rbuf, sems, m_scr, l_scr, acc_scr, *, scale, page,
-                   n, heads):
+def _latent_kernel(lens_ref, tabs_ref, base_ref, qc_ref, qr_ref, c_hbm, r_hbm,
+                   o_ref, cbuf, rbuf, sems, m_scr, l_scr, acc_scr, *, scale,
+                   page, n, heads):
     """Grid (slot,). Row ``r`` of the query tile is the slot's token
     ``r // heads`` at position ``lengths[slot] + r // heads``. The walk is
     ``_attend_kernel``'s: blocks of ``n`` live pages, the next block's DMAs
-    in flight while this one is attended to. A page is read ONCE: its
+    in flight while this one is attended to, page ``base_ref[0] + p`` of the
+    stacked pools being this layer's page ``p``. A page is read ONCE: its
     ``c_kv`` rows are the keys' first part and the values."""
     s_idx = pl.program_id(0)
+    base = base_ref[0]
     max_pages = tabs_ref.shape[1]
     rows = qc_ref.shape[1]
     block_q = rows // heads
@@ -487,7 +511,7 @@ def _latent_kernel(lens_ref, tabs_ref, qc_ref, qr_ref, c_hbm, r_hbm, o_ref,
                              0)
             for j, (hbm, buf) in enumerate(((c_hbm, cbuf), (r_hbm, rbuf))):
                 out.append(pltpu.make_async_copy(
-                    hbm.at[phys], buf.at[slot, pl.ds(i * page, page)],
+                    hbm.at[base + phys], buf.at[slot, pl.ds(i * page, page)],
                     sems.at[j, slot]))
         return out
 
@@ -544,8 +568,9 @@ def _latent_kernel(lens_ref, tabs_ref, qc_ref, qr_ref, c_hbm, r_hbm, o_ref,
 
 def paged_latent_attend(
     q: jnp.ndarray,          # [S, T, H, C + R]: [absorbed nope | rope] queries
-    k_pages: jnp.ndarray,    # [P, page, 1, Rw] rope keys, R live columns
-    v_pages: jnp.ndarray,    # [P, page, 1, C] latent rows c_kv
+    k_pages: jnp.ndarray,    # [L, P, page, 1, Rw] rope keys, R live columns
+    v_pages: jnp.ndarray,    # [L, P, page, 1, C] latent rows c_kv
+    layer,                   # int32 scalar: whose pages ``tables`` names
     tables: jnp.ndarray,     # [S, M] int32 physical page ids (0 = trash)
     lengths: jnp.ndarray,    # [S] int32: the first query token's position
     *,
@@ -555,11 +580,12 @@ def paged_latent_attend(
     """Absorbed latent attention (MLA decode) through the block table:
     ``softmax(scale * (q_c . c_kv + q_r . k_rope)) . c_kv`` per head, all
     heads against the ONE latent row a token has; returns ``[S, T, H, C]``
-    in q.dtype. The pools are read where they lie, a page's ``c_kv`` once
-    for both products. The caller has scattered the T new rows already
+    in q.dtype. The stacked pools are read where they lie (page
+    ``layer * P + tables[s, i]``), a page's ``c_kv`` once for both products.
+    The caller has scattered the T new rows already
     (``serve/kv_pages.paged_attend``), as for ``paged_flash_attend``."""
     s, t, h, width = q.shape
-    n_phys, page, _, c = v_pages.shape
+    n_layers, n_phys, page, _, c = v_pages.shape
     rw = k_pages.shape[-1]
     r = width - c
     m = tables.shape[1]
@@ -576,10 +602,11 @@ def paged_latent_attend(
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
 
     def tile(w):
-        return pl.BlockSpec((1, rows, w), lambda s_, lens, tabs: (s_, 0, 0))
+        return pl.BlockSpec((1, rows, w),
+                            lambda s_, lens, tabs, base: (s_, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # lengths, tables
+        num_scalar_prefetch=3,          # lengths, tables, layer base
         grid=(s,),
         in_specs=[tile(c), tile(rw), in_hbm, in_hbm],
         out_specs=tile(c),
@@ -600,8 +627,10 @@ def paged_latent_attend(
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="paged_latent_attend",
-    )(lengths.astype(jnp.int32), tables.astype(jnp.int32), qc, qr,
-      v_pages.reshape(n_phys, page, c), k_pages.reshape(n_phys, page, rw))
+    )(lengths.astype(jnp.int32), tables.astype(jnp.int32),
+      _layer_base(layer, n_phys), qc, qr,
+      v_pages.reshape(n_layers * n_phys, page, c),
+      k_pages.reshape(n_layers * n_phys, page, rw))
     return out.reshape(s, t, h, c)
 
 

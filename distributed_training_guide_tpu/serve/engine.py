@@ -1321,8 +1321,9 @@ class ModelPrograms:
 
     def make_attend(self, tables, lengths, *, impl: Optional[str] = None,
                     n_valid=None):
-        """The per-layer attend callback — shard_map'd per-chip pool
-        slices under shard_kv, the plain callback otherwise."""
+        """The attend callback every layer calls with the stacked pools and
+        its index (``kv_pages.paged_attend``'s contract) — shard_map'd
+        per-chip pool slices under shard_kv, the plain callback otherwise."""
         impl = self.attend_impl if impl is None else impl
         if self.shard_kv:
             from .sharding import make_sharded_attend

@@ -256,8 +256,11 @@ def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
 
 
 def init_pages(config, n_pages: int, page_size: int, kv_dtype=None) -> dict:
-    """Zeroed page pools {"k","v"}: [L, n_pages, page_size, heads, width]
-    arrays, each leaf's (heads, width) from :func:`pool_layout`, or
+    """Zeroed page pools {"k","v"}: STACKED [L, n_pages, page_size, heads,
+    width] arrays, which every program takes, carries through its layer scan
+    and returns whole (:func:`paged_attend`'s contract; page 0 of every
+    layer is that layer's trash page), each leaf's (heads, width) from
+    :func:`pool_layout`, or
     :class:`Quantized` (int8 payload of that shape + fp32 scales [L,
     n_pages, page_size, heads, 1]) under ``kv_dtype="int8"``. Zero scales
     dequantize to the same zero pool the float form starts with."""
@@ -406,16 +409,28 @@ def pool_audit(pool: "PagePool", holder_maps, *, tier=None) -> None:
         tier.audit()
 
 
-def paged_attend(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
-                 window=None, scale=None, softcap=None, impl: str = "auto",
+def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, tables, lengths,
+                 *, window=None, scale=None, softcap=None, impl: str = "auto",
                  n_valid=None, latent_rope=None, expand=None):
-    """Scatter each slot's new k/v into its pages, then attend q over the
-    slot's block-table context.
+    """Scatter each slot's new k/v into its pages of layer ``layer``, then
+    attend q over the slot's block-table context there.
 
-    q [S, T, Hq, D]; k_new/v_new [S, T, Hkv, D]; k_pages/v_pages
-    [P, page, Hkv, D] (ONE layer's pool — the layer scan feeds slices);
-    tables [S, M] int32 physical page ids (0-filled rows/tails route to
-    the trash page); lengths [S] int32 = tokens already cached per slot —
+    THE ATTEND CONTRACT, stated here for every caller (``make_attend``,
+    ``serve/sharding.make_sharded_attend``, the families'
+    ``paged_decode_step``, the drafter): the pools are the STACKED ones the
+    engine holds, k_pages/v_pages [L, P, page, Hkv, D] (or ``Quantized``
+    payload + scales), and ``layer`` an int32 scalar, traced in the layer
+    scan (``models/llama.scan_paged_layers``), which carries the pools
+    WHOLE. Everything here addresses them by ``layer``: the write is a
+    scatter of S x T rows at ``[layer, page, offset]``, the kernels read
+    page ``layer * P + tables[s, i]`` of the pools as they lie, the gather
+    path reads ``pool[layer, tables]``. No layer's pool is ever sliced out
+    of, or stacked back into, the ``[L, P, ...]`` arrays: that cost three
+    reads and three writes of every pool in every step.
+
+    q [S, T, Hq, D]; k_new/v_new [S, T, Hkv, D]; tables [S, M] int32
+    physical page ids (0-filled rows/tails route to the layer's trash
+    page); lengths [S] int32 = tokens already cached per slot —
     the T new tokens land at positions ``lengths[s] + 0..T-1``. T == 1 is
     the decode step; T > 1 is a prefill chunk attending over its own
     (already-scattered) tokens plus the cached history, or a speculative
@@ -463,26 +478,28 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
     slot's rows whatever ``impl`` says, expands them per head and attends
     in blocks of query rows.
 
-    Returns (attn [S, T, Hq, D], (k_pages, v_pages) updated).
+    Returns (attn [S, T, Hq, D], (k_pages, v_pages) updated, stacked).
     """
     k_pages, v_pages, t_idx = _scatter_new(k_new, v_new, k_pages, v_pages,
-                                           tables, lengths, n_valid)
+                                           layer, tables, lengths, n_valid)
     if latent_rope is not None:
-        return _attend_latent(q, k_pages, v_pages, tables, lengths, t_idx,
-                              scale=scale, impl=impl, rope=latent_rope,
+        return _attend_latent(q, k_pages, v_pages, layer, tables, lengths,
+                              t_idx, scale=scale, impl=impl, rope=latent_rope,
                               expand=expand)
-    return _attend_pages(q, k_pages, v_pages, tables, lengths, t_idx,
+    return _attend_pages(q, k_pages, v_pages, layer, tables, lengths, t_idx,
                          window=window, scale=scale, softcap=softcap,
                          impl=impl)
 
 
 @jax.named_scope("kv_write")
-def _scatter_new(k_new, v_new, k_pages, v_pages, tables, lengths, n_valid):
+def _scatter_new(k_new, v_new, k_pages, v_pages, layer, tables, lengths,
+                 n_valid):
     """The write half of :func:`paged_attend`: each slot's T new k/v rows
-    into its pages. Returns the pools and the [S, T] absolute positions."""
+    into its pages of ``layer``, S x T rows scattered into the stacked
+    pools. Returns the pools and the [S, T] absolute positions."""
     quantized = isinstance(k_pages, Quantized)
     s, t = k_new.shape[0], k_new.shape[1]
-    page = (k_pages.q if quantized else k_pages).shape[1]
+    page = (k_pages.q if quantized else k_pages).shape[2]
     m = tables.shape[1]
     slot = jnp.arange(s)
     t_idx = lengths[:, None] + jnp.arange(t)[None, :]          # [S, T]
@@ -494,29 +511,30 @@ def _scatter_new(k_new, v_new, k_pages, v_pages, tables, lengths, n_valid):
         phys = jnp.where(t_idx < (lengths + n_valid)[:, None], phys,
                          TRASH_PAGE)
     off = t_idx % page
+    at = (layer, phys, off)       # S x T rows of the stacked leaf
     if quantized:
         # quantize-at-write: each new token's [Hkv, D] vector becomes int8
         # payload + one fp32 scale, scattered to the SAME (page, offset) —
         # the scale is pool state with page identity, nothing more
         kq, vq = quantize_kv(k_new), quantize_kv(v_new)
-        k_pages = Quantized(q=k_pages.q.at[phys, off].set(kq.q),
-                            scale=k_pages.scale.at[phys, off].set(kq.scale))
-        v_pages = Quantized(q=v_pages.q.at[phys, off].set(vq.q),
-                            scale=v_pages.scale.at[phys, off].set(vq.scale))
+        k_pages = Quantized(q=k_pages.q.at[at].set(kq.q),
+                            scale=k_pages.scale.at[at].set(kq.scale))
+        v_pages = Quantized(q=v_pages.q.at[at].set(vq.q),
+                            scale=v_pages.scale.at[at].set(vq.scale))
     else:
-        k_pages = k_pages.at[phys, off].set(k_new.astype(k_pages.dtype))
-        v_pages = v_pages.at[phys, off].set(v_new.astype(v_pages.dtype))
+        k_pages = k_pages.at[at].set(k_new.astype(k_pages.dtype))
+        v_pages = v_pages.at[at].set(v_new.astype(v_pages.dtype))
     return k_pages, v_pages, t_idx
 
 
 @jax.named_scope("attend")
-def _attend_pages(q, k_pages, v_pages, tables, lengths, t_idx, *, window,
-                  scale, softcap, impl):
+def _attend_pages(q, k_pages, v_pages, layer, tables, lengths, t_idx, *,
+                  window, scale, softcap, impl):
     """The read half of :func:`paged_attend`: q over each slot's block-table
-    context, after the new tokens were scattered."""
+    context in ``layer``, after the new tokens were scattered."""
     quantized = isinstance(k_pages, Quantized)
     s = q.shape[0]
-    page = (k_pages.q if quantized else k_pages).shape[1]
+    page = (k_pages.q if quantized else k_pages).shape[2]
     if impl == "auto":
         impl, reason = resolve_attend_impl(impl, q.shape[-1], page)
         note_choice("paged_attend", impl, reason)
@@ -528,26 +546,29 @@ def _attend_pages(q, k_pages, v_pages, tables, lengths, t_idx, *, window,
         # path's semantics
         if quantized:
             attn = paged_flash_attend(
-                q, k_pages.q, v_pages.q, tables, lengths,
+                q, k_pages.q, v_pages.q, layer, tables, lengths,
                 k_scale=k_pages.scale[..., 0], v_scale=v_pages.scale[..., 0],
                 window=window, scale=scale, softcap=softcap)
         else:
-            attn = paged_flash_attend(q, k_pages, v_pages, tables,
+            attn = paged_flash_attend(q, k_pages, v_pages, layer, tables,
                                       lengths, window=window, scale=scale,
                                       softcap=softcap)
         return attn, (k_pages, v_pages)
 
+    # one gather from the stacked leaf: the layer's pages the tables name
     if quantized:
         # gather payload AND scales through the table, dequantize the
         # gathered view (context-sized transient, same as the float
         # gather) — the POOL itself never materializes in float
-        kg = dequantize_kv(Quantized(q=k_pages.q[tables],
-                                     scale=k_pages.scale[tables]), q.dtype)
-        vg = dequantize_kv(Quantized(q=v_pages.q[tables],
-                                     scale=v_pages.scale[tables]), q.dtype)
+        kg = dequantize_kv(Quantized(q=k_pages.q[layer, tables],
+                                     scale=k_pages.scale[layer, tables]),
+                           q.dtype)
+        vg = dequantize_kv(Quantized(q=v_pages.q[layer, tables],
+                                     scale=v_pages.scale[layer, tables]),
+                           q.dtype)
     else:
-        kg = k_pages[tables]                      # [S, M, page, Hkv, D]
-        vg = v_pages[tables]
+        kg = k_pages[layer, tables]               # [S, M, page, Hkv, D]
+        vg = v_pages[layer, tables]
     tot = kg.shape[1] * page
     kg = kg.reshape(s, tot, *kg.shape[3:])
     vg = vg.reshape(s, tot, *vg.shape[3:])
@@ -564,25 +585,26 @@ LATENT_Q_BLOCK = 256    # query rows whose [H, rows, context] scores are live
 
 
 @jax.named_scope("attend")
-def _attend_latent(q, k_pages, v_pages, tables, lengths, t_idx, *, scale,
-                   impl, rope, expand):
+def _attend_latent(q, k_pages, v_pages, layer, tables, lengths, t_idx, *,
+                   scale, impl, rope, expand):
     """The read half of :func:`paged_attend` over a latent pool."""
     if isinstance(k_pages, Quantized):
         raise ValueError("a latent pool is stored in float: int8 KV is not "
                          "implemented for latent attention")
     s, t, h, _ = q.shape
-    page, c = v_pages.shape[1], v_pages.shape[-1]
+    page, c = v_pages.shape[2], v_pages.shape[-1]
     if expand is None:
         if impl == "auto":
             impl, reason = resolve_attend_impl(
                 impl, c, page, latent_rope_width=k_pages.shape[-1])
             note_choice("paged_attend", impl, reason)
         if impl == "flash":
-            return (paged_latent_attend(q, k_pages, v_pages, tables, lengths,
-                                        scale=scale), (k_pages, v_pages))
+            return (paged_latent_attend(q, k_pages, v_pages, layer, tables,
+                                        lengths, scale=scale),
+                    (k_pages, v_pages))
     tot = tables.shape[1] * page
-    ckv = v_pages[tables].reshape(s, tot, c)              # [S, N, C]
-    kr = k_pages[tables].reshape(s, tot, -1)[..., :rope]  # [S, N, R]
+    ckv = v_pages[layer, tables].reshape(s, tot, c)              # [S, N, C]
+    kr = k_pages[layer, tables].reshape(s, tot, -1)[..., :rope]  # [S, N, R]
     kv_pos = jnp.arange(tot)
     if expand is None:     # absorbed, over the gathered rows: the parity
         # baseline of the kernel, in float32 throughout (the CPU's runtime
@@ -616,14 +638,14 @@ def _attend_latent(q, k_pages, v_pages, tables, lengths, t_idx, *, scale,
 
 
 def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None):
-    """Bind (tables, lengths, impl, n_valid) into the per-layer attend
-    callback the family ``paged_decode_step`` hooks expect. A latent family
-    adds ``latent_rope`` (and ``expand`` for a chunk) to its call; see
-    :func:`paged_attend`."""
+    """Bind (tables, lengths, impl, n_valid) into the attend callback the
+    family ``paged_decode_step`` hooks call in every layer with the stacked
+    pools and the layer's index (:func:`paged_attend`'s contract). A latent
+    family adds ``latent_rope`` (and ``expand`` for a chunk) to its call."""
 
-    def attend(q, k_new, v_new, k_pages, v_pages, *, window=None, scale=None,
-               softcap=None, **latent):
-        return paged_attend(q, k_new, v_new, k_pages, v_pages, tables,
+    def attend(q, k_new, v_new, k_pages, v_pages, layer, *, window=None,
+               scale=None, softcap=None, **latent):
+        return paged_attend(q, k_new, v_new, k_pages, v_pages, layer, tables,
                             lengths, window=window, scale=scale,
                             softcap=softcap, impl=impl, n_valid=n_valid,
                             **latent)

@@ -63,10 +63,10 @@ SERVE_KV_RULES = (
 )
 
 # specs for the shard_map'd regions: activations [S, T, H, D] split on
-# heads, ONE layer's pool [P, page, kvh, hd] split on kv-heads, dense
-# prefill caches [L, Pb, kvh, hd] split on kv-heads
+# heads, the stacked pools [L, P, page, kvh, hd] split on kv-heads (the
+# attend takes them whole, with the layer's index), dense prefill caches
+# [L, Pb, kvh, hd] split on kv-heads
 _HEADS = P(None, None, "tp", None)
-_POOL = P(None, None, "tp", None)
 _POOL_L = P(None, None, None, "tp", None)
 _DENSE_L = P(None, None, "tp", None)
 
@@ -139,18 +139,19 @@ def _manual(mesh: Mesh):
 
 def make_sharded_attend(mesh: Mesh, tables, lengths, *, impl: str = "auto",
                         n_valid=None):
-    """The shard_map'd twin of ``kv_pages.make_attend``: per-chip pool
-    slices and head groups, replicated tables/lengths, no collective in
-    the region (head-parallel attention needs none — the psums of a
-    sharded decode step live in GSPMD's out-projection/sampling land).
-    ``window`` may be a traced per-layer value (Gemma-2 schedules); it
-    then rides as an explicit replicated operand — shard_map must not
-    close over tracers."""
+    """The shard_map'd twin of ``kv_pages.make_attend`` (the same contract:
+    the stacked pools and the layer's index): per-chip pool slices and head
+    groups, replicated tables/lengths/layer, no collective in the region
+    (head-parallel attention needs none — the psums of a sharded decode
+    step live in GSPMD's out-projection/sampling land). ``layer`` and a
+    traced per-layer ``window`` (Gemma-2 schedules) ride as explicit
+    replicated operands — shard_map must not close over tracers."""
 
-    def attend(q, k_new, v_new, k_pages, v_pages, *, window=None,
+    def attend(q, k_new, v_new, k_pages, v_pages, layer, *, window=None,
                scale=None, softcap=None):
-        operands = [q, k_new, v_new, k_pages, v_pages, tables, lengths]
-        in_specs = [_HEADS, _HEADS, _HEADS, _POOL, _POOL, P(), P()]
+        operands = [q, k_new, v_new, k_pages, v_pages, layer, tables,
+                    lengths]
+        in_specs = [_HEADS, _HEADS, _HEADS, _POOL_L, _POOL_L, P(), P(), P()]
         if n_valid is not None:
             operands.append(n_valid)
             in_specs.append(P())
@@ -159,16 +160,16 @@ def make_sharded_attend(mesh: Mesh, tables, lengths, *, impl: str = "auto",
             operands.append(window)
             in_specs.append(P())
 
-        def body(q, kn, vn, kp, vp, tab, lens, *rest):
+        def body(q, kn, vn, kp, vp, i, tab, lens, *rest):
             rest = list(rest)
             nv = rest.pop(0) if n_valid is not None else None
             w = rest.pop(0) if dyn_window else window
-            return paged_attend(q, kn, vn, kp, vp, tab, lens, window=w,
+            return paged_attend(q, kn, vn, kp, vp, i, tab, lens, window=w,
                                 scale=scale, softcap=softcap, impl=impl,
                                 n_valid=nv)
 
         sm = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                           out_specs=(_HEADS, (_POOL, _POOL)),
+                           out_specs=(_HEADS, (_POOL_L, _POOL_L)),
                            axis_names=_manual(mesh), check_vma=False)
         return sm(*operands)
 

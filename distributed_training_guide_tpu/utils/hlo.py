@@ -266,3 +266,57 @@ def has_shape_run(text: str, shape: Iterable[int]) -> bool:
     dims = [str(int(d)) for d in shape]
     return bool(re.search(r"[\[,]" + ",".join(dims) + r"[,\]]", text)
                 or re.search(r"[<x]" + "x".join(dims) + r"[x>]", text))
+
+
+# ---------------------------------------------------------------------------
+# scan pins (jaxprs): what rides a layer scan as carry, and what its body
+# slices
+# ---------------------------------------------------------------------------
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for item in (value if isinstance(value, (list, tuple)) else [value]):
+            inner = getattr(item, "jaxpr", item)    # ClosedJaxpr or Jaxpr
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in _sub_jaxprs(eqn):
+            yield from _eqns(inner)
+
+
+def scans_holding(closed_jaxpr, shape: Iterable[int]) -> list[dict]:
+    """One entry for every ``scan`` of a traced program (nested ones too)
+    that has an operand or result of exactly ``shape``: how many arrays of
+    that shape ride it as ``carry``, as scanned inputs ``xs`` and as stacked
+    outputs ``ys``, and ``sliced``: the ``dynamic_slice`` /
+    ``dynamic_update_slice`` equations anywhere in its body whose result
+    holds at least ONE LAYER of it (``prod(shape[1:])`` elements). The
+    serve programs pin their page pools with it: carried whole (``carry``
+    2, ``xs`` and ``ys`` 0) and never sliced (``sliced`` empty)."""
+    shape = tuple(int(d) for d in shape)
+    one_layer = 1
+    for d in shape[1:]:
+        one_layer *= d
+    held = lambda vs: sum(tuple(v.aval.shape) == shape for v in vs)
+    out = []
+    for eqn in _eqns(closed_jaxpr.jaxpr):
+        if eqn.primitive.name != "scan":
+            continue
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        entry = {"carry": held(eqn.invars[nc:nc + nk]),
+                 "xs": held(eqn.invars[nc + nk:]),
+                 "ys": held(eqn.outvars[nk:])}
+        if not any(entry.values()):
+            continue
+        entry["sliced"] = [
+            (e.primitive.name, tuple(e.outvars[0].aval.shape))
+            for e in _eqns(eqn.params["jaxpr"].jaxpr)
+            if e.primitive.name in ("dynamic_slice", "dynamic_update_slice")
+            and e.outvars[0].aval.size >= one_layer]
+        out.append(entry)
+    return out

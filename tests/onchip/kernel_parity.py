@@ -16,11 +16,16 @@ smoke's own shapes, through the functions the entry points call:
 - ``ops/attention.multihead_attention(impl="flash")`` vs ``impl="xla"``,
   forward and backward, at the training shape (seq 2048, bf16).
 
+Every paged case runs on STACKED pools of three layers with different
+contents, the kernel at layer 2 against the gather path on that layer ALONE
+(a one-layer stack, where no layer can be taken for another).
+
 A case passes when ``max|kernel - reference| <= RTOL * max(1, max|reference|)``.
-Then the CONTROL: the same paged comparison with the kernel sabotaged four
+Then the CONTROL: the same paged comparison with the kernel sabotaged five
 ways (returns zeros / reads the value pool as keys / walks the block table
-rolled by one page / masks one position too many); the bound must REFUSE
-every one, or it proves nothing.
+rolled by one page / masks one position too many / the layer's page base
+left out, so that it reads layer 0); the bound must REFUSE every one, or it
+proves nothing.
 
 ``--all`` adds what is off the smoke's path but in ``ops/``: bf16 and int8
 pools, page 32, T = 5 (speculative verify), the cell's shape at T = 5 and at
@@ -31,13 +36,13 @@ by hand. ``--mla`` runs the latent-attention cell's kernels alone
 (``mistral-small-4-ep4-l6.serve.decode32-ctx8k``): the absorbed latent attend
 (``paged_latent_attend``: 32 slots of 32 heads over one latent row of 256 +
 64, page 128, 96 table columns, 3073 pages, bf16, T = 1, lengths 0..12,287 in
-one call) against the gathered rows, with two sabotaged kernels the bound
+one call) against the gathered rows, with three sabotaged kernels the bound
 must refuse, and ``gmm`` at hidden 4096 x expert width 2048 and back with 32
 groups of 0-4 rows in a 128-row buffer (the decode step's held pairs).
 
 One process; fails (no last line, exit 1) off the chip. Prints the entry
 points' start-up device line, one JSON line per case, and last
-``{"kernel_parity_ok": true, "cases": N, "controls_refused": 4}``.
+``{"kernel_parity_ok": true, "cases": N, "controls_refused": 5}``.
 """
 import importlib
 import json
@@ -71,6 +76,7 @@ RTOL = 0.05
 HQ, HKV, D = 16, 8, 128          # qwen3-0.6b attention
 N_SLOTS, PAGES_PER_SLOT, POOL_PAGES = 4, 8, 64
 SEQ, BATCH = 2048, 2
+N_LAYERS, LAYER = 3, 2           # the stacked pools, and the layer attended
 
 RNG = np.random.default_rng(0)
 FAILED: list = []
@@ -107,7 +113,7 @@ def paged_inputs(pool: str, page: int, t: int):
     dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
     q = normal((N_SLOTS, t, HQ, D), dtype)
     k_new, v_new = (normal((N_SLOTS, t, HKV, D), dtype) for _ in range(2))
-    k_pool, v_pool = (normal((POOL_PAGES, page, HKV, D), dtype)
+    k_pool, v_pool = (normal((N_LAYERS, POOL_PAGES, page, HKV, D), dtype)
                       for _ in range(2))
     if pool == "int8":
         k_pool, v_pool = (kv_pages.quantize_kv(x) for x in (k_pool, v_pool))
@@ -121,9 +127,21 @@ def paged_inputs(pool: str, page: int, t: int):
     return q, k_new, v_new, k_pool, v_pool, tables, lengths
 
 
+def layered(impl: str, args):
+    """``paged_attend``'s arguments for one side of a case: the kernel gets
+    the stacked pools and ``LAYER``; the gather reference that layer alone,
+    as layer 0 of a one-layer stack."""
+    q, k_new, v_new, k_pool, v_pool, *rest = args
+    if impl == "flash":
+        return (q, k_new, v_new, k_pool, v_pool, LAYER, *rest)
+    alone = [jax.tree.map(lambda x: x[LAYER][None], pool)
+             for pool in (k_pool, v_pool)]
+    return (q, k_new, v_new, *alone, 0, *rest)
+
+
 def attend(impl: str, args):
     fn = jax.jit(lambda *a: kv_pages.paged_attend(*a, impl=impl)[0])
-    return fn(*args)
+    return fn(*layered(impl, args))
 
 
 def paged_case(pool: str, page: int, t: int) -> None:
@@ -146,8 +164,8 @@ def cell_case(t: int) -> None:
     n = len(lengths)
     q = normal((n, t, heads, D), jnp.bfloat16)
     k_new, v_new = (normal((n, t, heads, D), jnp.bfloat16) for _ in range(2))
-    k_pool, v_pool = (normal((CELL["pages"], page, heads, D), jnp.bfloat16)
-                      for _ in range(2))
+    k_pool, v_pool = (normal((N_LAYERS, CELL["pages"], page, heads, D),
+                             jnp.bfloat16) for _ in range(2))
     tables = np.zeros((n, CELL["columns"]), np.int32)
     free = iter(RNG.permutation(np.arange(1, CELL["pages"])))
     for i, length in enumerate(lengths):
@@ -163,14 +181,16 @@ def cell_case(t: int) -> None:
 def sabotaged(mode: str):
     real = kv_pages.paged_flash_attend
 
-    def wrapped(q, k_pages, v_pages, tables, lengths, **kw):
+    def wrapped(q, k_pages, v_pages, layer, tables, lengths, **kw):
         if mode == "swap_k_v":
             k_pages, v_pages = v_pages, k_pages
         elif mode == "wrong_pages":
             tables = jnp.roll(tables, 1, axis=1)
         elif mode == "drop_newest":
             lengths = jnp.maximum(lengths - 1, 0)
-        out = real(q, k_pages, v_pages, tables, lengths, **kw)
+        elif mode == "no_layer_base":
+            layer = 0
+        out = real(q, k_pages, v_pages, layer, tables, lengths, **kw)
         return jnp.zeros_like(out) if mode == "zeros" else out
 
     return wrapped
@@ -183,7 +203,8 @@ def controls() -> int:
     want = attend("xla", args)
     refused = 0
     real = kv_pages.paged_flash_attend
-    for mode in ("zeros", "swap_k_v", "wrong_pages", "drop_newest"):
+    for mode in ("zeros", "swap_k_v", "wrong_pages", "drop_newest",
+                 "no_layer_base"):
         kv_pages.paged_flash_attend = sabotaged(mode)
         try:
             err, ref = worst(attend("flash", args), want)
@@ -258,8 +279,10 @@ def latent_inputs():
     q = normal((n, 1, c["heads"], c["latent"] + c["rope"]), jnp.bfloat16)
     k_new = normal((n, 1, 1, c["rope_width"]), jnp.bfloat16)
     v_new = normal((n, 1, 1, c["latent"]), jnp.bfloat16)
-    k_pool = normal((c["pages"], c["page"], 1, c["rope_width"]), jnp.bfloat16)
-    v_pool = normal((c["pages"], c["page"], 1, c["latent"]), jnp.bfloat16)
+    k_pool = normal((N_LAYERS, c["pages"], c["page"], 1, c["rope_width"]),
+                    jnp.bfloat16)
+    v_pool = normal((N_LAYERS, c["pages"], c["page"], 1, c["latent"]),
+                    jnp.bfloat16)
     tables = jnp.asarray(RNG.permutation(np.arange(1, c["pages"]))
                          [:n * c["columns"]].reshape(n, c["columns"]),
                          jnp.int32)
@@ -271,13 +294,13 @@ def latent_attend(impl: str, args):
     fn = jax.jit(lambda *a: kv_pages.paged_attend(
         *a, impl=impl, scale=0.2 / LATENT["latent"] ** 0.5,
         latent_rope=LATENT["rope"])[0])
-    return fn(*args)
+    return fn(*layered(impl, args))
 
 
 def latent_cases() -> int:
     """The cell's decode attend against the gathered rows, then its control:
-    the kernel reading lengths one short and one page of the table rolled;
-    returns how many of the two the bound refused."""
+    the kernel reading lengths one short, one page of the table rolled, and
+    layer 0's pages; returns how many of the three the bound refused."""
     args = latent_inputs()
     want = latent_attend("xla", args)
     case("paged_latent_attend", {"out": (latent_attend("flash", args), want)},
@@ -286,13 +309,15 @@ def latent_cases() -> int:
          lengths=f"{min(LATENT_LENGTHS)}..{max(LATENT_LENGTHS)}")
     real = kv_pages.paged_latent_attend
     refused = 0
-    for mode in ("drop_newest", "wrong_pages"):
-        def wrapped(q, k_pages, v_pages, tables, lengths, **kw):
+    for mode in ("drop_newest", "wrong_pages", "no_layer_base"):
+        def wrapped(q, k_pages, v_pages, layer, tables, lengths, **kw):
             if mode == "wrong_pages":
                 tables = jnp.roll(tables, 1, axis=1)
-            else:
+            elif mode == "drop_newest":
                 lengths = jnp.maximum(lengths - 1, 0)
-            return real(q, k_pages, v_pages, tables, lengths, **kw)
+            else:
+                layer = 0
+            return real(q, k_pages, v_pages, layer, tables, lengths, **kw)
         kv_pages.paged_latent_attend = wrapped
         try:
             err, ref = worst(latent_attend("flash", args), want)
@@ -358,9 +383,9 @@ def main(argv) -> int:
     if mla_only:
         refused = mla_cases()
         CACHE.print_line()
-        if FAILED or refused != 2:
+        if FAILED or refused != 3:
             print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
-                  f"sabotaged latent kernels refused: {refused} of 2",
+                  f"sabotaged latent kernels refused: {refused} of 3",
                   file=sys.stderr)
             return 1
         print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
@@ -370,7 +395,7 @@ def main(argv) -> int:
         paged_case("fp32", 16, t)
     cell_case(1)
     flash_case()
-    latent_refused = 2
+    latent_refused = 3
     if everything:
         latent_refused = mla_cases()
         for t in (5, 512):
@@ -388,10 +413,10 @@ def main(argv) -> int:
         int8_matmul_case()
     refused = controls()
     CACHE.print_line()
-    if FAILED or refused != 4 or latent_refused != 2:
+    if FAILED or refused != 5 or latent_refused != 3:
         print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
-              f"sabotaged kernels refused: {refused} of 4, latent "
-              f"{latent_refused} of 2", file=sys.stderr)
+              f"sabotaged kernels refused: {refused} of 5, latent "
+              f"{latent_refused} of 3", file=sys.stderr)
         return 1
     print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
                       "controls_refused": refused}), flush=True)

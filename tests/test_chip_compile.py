@@ -11,7 +11,9 @@ the compiled program (``tpu_custom_call``).
 
 Shapes are real ones: qwen3-0.6b's attention (seq 2048, 16/8 heads of 128),
 the serve CLI's page sizes for fp32, bf16 and int8 pools at T=1 (decode),
-T=5 (spec verify) and one prefill-chunk size, the benchmark's serve cell
+T=5 (spec verify) and one prefill-chunk size (always STACKED pools of three
+layers and a traced layer index, as the layer scan hands them over), the
+benchmark's serve cell
 (16 slots of 32/32 heads, 256 table columns, 1344 pages; its chunk of 512, a
 GQA pool, an int8 pool, a one-head slice of a sharded pool), qwen3-30b-a3b's expert GEMMs
 (hidden 2048 x expert width 768) and an int8 projection (1024 x 3072).
@@ -45,6 +47,8 @@ qmm_mod = importlib.import_module(
     "distributed_training_guide_tpu.ops.quantized_matmul")
 
 SEQ, HQ, HKV, D = 2048, 16, 8, 128          # qwen3-0.6b attention
+N_LAYERS = 3                                # the stacked pools of the attend
+LAYER = ((), jnp.int32)                     # its traced layer index
 
 
 @pytest.fixture(scope="module")
@@ -68,18 +72,21 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def chip_compile(one_chip):
-    """``compile(fn, *(shape, dtype))`` -> compiled HLO text for the chip,
-    with the persistent cache off while this module's tests run."""
+    """``compile(fn, *(shape, dtype), donate=())`` -> compiled HLO text for
+    the chip (``donate``: argument numbers the program donates, as the serve
+    engine donates its pools), with the persistent cache off while this
+    module's tests run."""
     from jax.experimental.compilation_cache import compilation_cache
 
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
 
-    def compile_(fn, *specs):
+    def compile_(fn, *specs, donate=()):
         args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                 for shape, dtype in specs]
-        return jax.jit(fn).lower(*args).compile().as_text()
+        return jax.jit(fn, donate_argnums=donate).lower(
+            *args).compile().as_text()
 
     yield compile_
     jax.config.update("jax_enable_compilation_cache", was_on)
@@ -94,6 +101,40 @@ def kernel_calls(text: str) -> list:
 
 def named(call: str, name: str) -> bool:
     return re.search(rf"(^|_){name}(_|\.|$)", call) is not None
+
+
+def pool_sized_ops(text: str, *pool_shapes, names: bool = False) -> list:
+    """Opcodes (with ``names``: the instructions' names too, ``opcode
+    name``) of the compiled program's instructions whose array result holds
+    as many elements as one of the stacked ``[L, ...]`` pools or as one layer
+    of it."""
+    counts = {n for shape in pool_shapes
+              for n in (math.prod(shape), math.prod(shape[1:]))}
+    sized = []
+    for line in text.splitlines():
+        found = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\][^ ]* "
+                         r"([\w\-]+)\(", line)
+        if found and found.group(2) and math.prod(
+                int(x) for x in found.group(2).split(",")) in counts:
+            sized.append(f"{found.group(3)} {found.group(1)}" if names
+                         else found.group(3))
+    return sized
+
+
+def assert_pools_carried_in_place(text: str, *pool_shapes) -> None:
+    """A whole serve program moves nothing of the pools' (or of one layer's)
+    size: what has that size are the parameters, what renames them, and the
+    two in-place ``scatter``s of the new rows with the fusions that hold
+    them. No ``copy``, no ``dynamic-slice`` or ``dynamic-update-slice`` (bare
+    or as a fusion's name), no layout ``custom-call``: each was a read and a
+    write of a whole pool in every layer of every step."""
+    sized = pool_sized_ops(text, *pool_shapes, names=True)
+    assert sum(x.startswith("scatter ") for x in sized) == 2, sized
+    moved = [x for x in sized
+             if x.split()[0] not in ("parameter", "bitcast", "scatter",
+                                     "get-tuple-element", "fusion")
+             or "slice" in x or "copy" in x]
+    assert not moved, moved
 
 
 # ---- training attention ----------------------------------------------------
@@ -134,18 +175,19 @@ def test_paged_attend_compiles(chip_compile, pool, page, t):
     pool_dtype = {"fp32": jnp.float32, "bf16": jnp.bfloat16,
                   "int8": jnp.int8}[pool]
     specs = [((n_slots, t, HQ, D), q_dtype),
-             ((n_pages, page, HKV, D), pool_dtype),
-             ((n_pages, page, HKV, D), pool_dtype),
+             ((N_LAYERS, n_pages, page, HKV, D), pool_dtype),
+             ((N_LAYERS, n_pages, page, HKV, D), pool_dtype), LAYER,
              ((n_slots, table), jnp.int32), ((n_slots,), jnp.int32)]
     if pool == "int8":
-        specs += [((n_pages, page, HKV), jnp.float32)] * 2
+        specs += [((N_LAYERS, n_pages, page, HKV), jnp.float32)] * 2
 
-        def attend(q, k, v, tabs, lens, ks, vs):
-            return paged_flash_attend(q, k, v, tabs, lens, k_scale=ks,
+        def attend(q, k, v, layer, tabs, lens, ks, vs):
+            return paged_flash_attend(q, k, v, layer, tabs, lens, k_scale=ks,
                                       v_scale=vs, interpret=False)
     else:
-        def attend(q, k, v, tabs, lens):
-            return paged_flash_attend(q, k, v, tabs, lens, interpret=False)
+        def attend(q, k, v, layer, tabs, lens):
+            return paged_flash_attend(q, k, v, layer, tabs, lens,
+                                      interpret=False)
 
     assert "tpu_custom_call" in chip_compile(attend, *specs)
 
@@ -169,14 +211,14 @@ CELL_CASES = {
 
 def _cell_attend(chip_compile, case):
     slots, t, hq, hkv, pool_dtype, q_dtype = CELL_CASES[case]
-    pool = ((CELL_PAGES, CELL_PAGE, hkv, D), pool_dtype)
-    specs = [((slots, t, hq, D), q_dtype), pool, pool,
+    pool = ((N_LAYERS, CELL_PAGES, CELL_PAGE, hkv, D), pool_dtype)
+    specs = [((slots, t, hq, D), q_dtype), pool, pool, LAYER,
              ((slots, CELL_COLUMNS), jnp.int32), ((slots,), jnp.int32)]
     if pool_dtype == jnp.int8:
-        specs += [((CELL_PAGES, CELL_PAGE, hkv), jnp.float32)] * 2
+        specs += [((N_LAYERS, CELL_PAGES, CELL_PAGE, hkv), jnp.float32)] * 2
         return pool, chip_compile(
-            lambda q, k, v, tabs, lens, ks, vs: paged_flash_attend(
-                q, k, v, tabs, lens, k_scale=ks, v_scale=vs,
+            lambda q, k, v, layer, tabs, lens, ks, vs: paged_flash_attend(
+                q, k, v, layer, tabs, lens, k_scale=ks, v_scale=vs,
                 interpret=False), *specs)
     return pool, chip_compile(
         lambda *a: paged_flash_attend(*a, interpret=False), *specs)
@@ -192,22 +234,17 @@ def test_paged_attend_compiles_at_the_serve_cells_shapes(chip_compile, case):
 @pytest.mark.parametrize("case", ["cell-decode", "cell-chunk512",
                                   "gqa-decode", "one-head-slice"])
 def test_paged_attend_moves_nothing_pool_sized(chip_compile, case):
-    """The kernel reads the pool where it lies. Besides the parameters and
-    the custom call, the compiled attend has no instruction whose result has
-    the pool's element count but the two ``bitcast``s that rename
-    ``[P, page, Hkv, D]`` as ``[P, page * Hkv, D]`` (the same bytes in the
-    same tiled layout: a bitcast moves nothing). The parent's kernel took
-    ``[P, page, Hkv * D]``, a change of tiled layout, and paid a ``copy`` of
-    each pool in every layer of every decode step."""
+    """The kernel reads the STACKED pools where they lie, at a traced layer.
+    Besides the parameters and the custom call, the compiled attend has no
+    instruction whose result has the pools' element count (or one layer's)
+    but the two ``bitcast``s that rename ``[L, P, page, Hkv, D]`` as
+    ``[L * P, page * Hkv, D]`` (the same bytes in the same tiled layout: a
+    bitcast moves nothing): no layer's pool is sliced out for the call. An
+    earlier kernel took ``[P, page, Hkv * D]``, a change of tiled layout, and
+    paid a ``copy`` of each pool in every layer of every decode step; the one
+    after it took one layer's pool, which the layer scan had to slice out."""
     (shape, _), text = _cell_attend(chip_compile, case)
-    pool_elements = math.prod(shape)
-    sized = []
-    for line in text.splitlines():
-        found = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\][^ ]* "
-                         r"([\w\-]+)\(", line)
-        if found and found.group(1) and math.prod(
-                int(x) for x in found.group(1).split(",")) == pool_elements:
-            sized.append(found.group(2))
+    sized = pool_sized_ops(text, shape)
     assert sorted(sized) == ["bitcast", "bitcast", "parameter", "parameter"], (
         sized)
 
@@ -218,11 +255,11 @@ def test_paged_attend_compiles_at_head_dim_256(chip_compile, slots, t):
     """Gemma-2-9b's heads (16/8 of 256, a window, a softcap): the gate takes
     head_dim 256, and a chunk there takes all heads in one product (the
     strided pick of one word's heads needs rows of one 128-lane tile)."""
-    pool = ((512, 16, 8, 256), jnp.bfloat16)
+    pool = ((N_LAYERS, 512, 16, 8, 256), jnp.bfloat16)
     text = chip_compile(
         lambda *a: paged_flash_attend(*a, window=4096, softcap=50.0,
                                       interpret=False),
-        ((slots, t, 16, 256), jnp.bfloat16), pool, pool,
+        ((slots, t, 16, 256), jnp.bfloat16), pool, pool, LAYER,
         ((slots, 256), jnp.int32), ((slots,), jnp.int32))
     assert "tpu_custom_call" in text
 
@@ -232,9 +269,10 @@ def test_paged_attend_gate_matches_the_compiler(chip_compile):
     raises the gate's own error first: head_dim 64 is half a lane tile of
     a page's ``[page * Hkv, D]`` rows."""
     assert not paged_decode_eligible(64, 16)
-    specs = [((4, 1, 16, 64), jnp.float32), ((64, 16, 8, 64), jnp.float32),
-             ((64, 16, 8, 64), jnp.float32), ((4, 8), jnp.int32),
-             ((4,), jnp.int32)]
+    specs = [((4, 1, 16, 64), jnp.float32),
+             ((N_LAYERS, 64, 16, 8, 64), jnp.float32),
+             ((N_LAYERS, 64, 16, 8, 64), jnp.float32), LAYER,
+             ((4, 8), jnp.int32), ((4,), jnp.int32)]
     with pytest.raises(ValueError, match="head_dim % 128"):
         chip_compile(lambda *a: paged_flash_attend(*a, interpret=False),
                      *specs)
@@ -311,8 +349,8 @@ KERNEL_NAME_CASES = {
               ("flash_fwd", "flash_dq", "flash_dkv")),
     "paged": (lambda *a: paged_flash_attend(*a, interpret=False),
               [((4, 1, HQ, D), jnp.bfloat16)]
-              + [((128, 16, HKV, D), jnp.bfloat16)] * 2
-              + [((4, 32), jnp.int32), ((4,), jnp.int32)],
+              + [((N_LAYERS, 128, 16, HKV, D), jnp.bfloat16)] * 2
+              + [LAYER, ((4, 32), jnp.int32), ((4,), jnp.int32)],
               ("paged_attend",)),
     "grouped": (_gmm_fwd_bwd,
                 [((1024, 256), jnp.bfloat16), ((8, 256, 256), jnp.bfloat16),
@@ -363,14 +401,18 @@ def test_latent_attend_compiles_at_the_cells_shape(chip_compile):
     text = chip_compile(
         lambda *a: paged_latent_attend(*a, scale=0.1, interpret=False),
         ((c["slots"], 1, c["heads"], c["latent"] + c["rope"]), jnp.bfloat16),
-        ((c["pages"], c["page"], 1, c["rope_width"]), jnp.bfloat16),
-        ((c["pages"], c["page"], 1, c["latent"]), jnp.bfloat16),
+        ((6, c["pages"], c["page"], 1, c["rope_width"]), jnp.bfloat16),
+        ((6, c["pages"], c["page"], 1, c["latent"]), jnp.bfloat16), LAYER,
         ((c["slots"], c["columns"]), jnp.int32), ((c["slots"],), jnp.int32))
     calls = kernel_calls(text)
     assert calls and all(named(x, "paged_latent_attend") for x in calls)
-    # the pools reach the kernel as they are stored: nothing pool-sized moves
-    assert not re.search(rf"(copy|transpose)\([^\n]*\[{c['pages']},", text)
-    assert f"bf16[{c['pages']},{c['page']},1,{c['latent']}]" in text
+    # the stacked pools reach the kernel as they are stored: nothing of a
+    # layer's size moves, no layer is sliced out
+    assert sorted(pool_sized_ops(
+        text, (6, c["pages"], c["page"], 1, c["rope_width"]),
+        (6, c["pages"], c["page"], 1, c["latent"]))) == [
+            "bitcast", "bitcast", "parameter", "parameter"]
+    assert f"bf16[6,{c['pages']},{c['page']},1,{c['latent']}]" in text
 
 
 @pytest.mark.parametrize("k,n", [(4096, 2048), (2048, 4096)])
@@ -405,7 +447,8 @@ def test_latent_familys_serve_programs_compile_at_the_cells_size(
     (10.1 GiB of weights, the 1.69 GiB pool): the compiler fits them into the
     chip's 15.75 GiB, the decode step holds the latent kernel and three
     ``gmm`` calls a layer (one scan body), the chunk no latent kernel (it
-    decompresses the gathered rows)."""
+    decompresses the gathered rows), and neither moves anything of the
+    donated pools' size (the layer scan carries them)."""
     import dataclasses
 
     from distributed_training_guide_tpu.models import mla
@@ -440,14 +483,58 @@ def test_latent_familys_serve_programs_compile_at_the_cells_size(
     if program == "decode":
         text = chip_compile(decode, pools["k"], pools["v"],
                             ((32,), jnp.int32), ((32,), jnp.int32),
-                            ((32, c["columns"]), jnp.int32), *weights)
+                            ((32, c["columns"]), jnp.int32), *weights,
+                            donate=(0, 1))
         calls = kernel_calls(text)
         assert sum(named(x, "paged_latent_attend") for x in calls) == 1
         assert sum(named(x, "gmm") for x in calls) == 3
     else:
         text = chip_compile(chunk, pools["k"], pools["v"],
                             ((1, 2048), jnp.int32), ((1,), jnp.int32),
-                            ((1, c["columns"]), jnp.int32), *weights)
+                            ((1, c["columns"]), jnp.int32), *weights,
+                            donate=(0, 1))
         calls = kernel_calls(text)
         assert not any(named(x, "paged_latent_attend") for x in calls)
         assert sum(named(x, "gmm") for x in calls) == 3
+    assert_pools_carried_in_place(text, *(shape for shape, _ in pools.values()))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk512"])
+def test_llama_familys_serve_programs_carry_the_pools_in_place(
+        chip_compile, compiled_kernels, program):
+    """``olmo2-7b-l12.serve.decode16``'s decode step and prefill chunk, whole,
+    at the cell's size (12 layers of 7B widths, two pools of 1344 pages of 16
+    tokens, 3.94 GiB): one kernel kind (the attend), and nothing of the
+    pools' size, or of one layer's, is sliced, updated by slice or copied.
+    With the pools as scanned inputs and stacked outputs these programs held
+    six such operations and 4.59 GiB of temporaries, a second copy of both
+    pools."""
+    import dataclasses
+
+    from distributed_training_guide_tpu.models import llama
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    cfg = dataclasses.replace(llama.PRESETS["olmo2-7b"], num_layers=12,
+                              dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: llama.init(cfg, jax.random.key(0)))
+    leaves, treedef = jax.tree.flatten(params)
+    weights = [(x.shape, x.dtype) for x in leaves]
+    pool = ((12, CELL_PAGES, CELL_PAGE, 32, D), jnp.bfloat16)
+    slots, t = (16, 1) if program == "decode" else (1, 512)
+
+    def step(kp, vp, ids, lengths, tables, *flat):
+        logits, cache = llama.paged_decode_step(
+            cfg, jax.tree.unflatten(treedef, flat), ids, lengths,
+            {"k": kp, "v": vp},
+            kv_pages.make_attend(tables, lengths, impl="flash",
+                                 n_valid=jnp.full((slots,), t)),
+            last_index=jnp.asarray(t - 1))
+        return jnp.argmax(logits, -1), cache["k"], cache["v"]
+
+    text = chip_compile(step, pool, pool, ((slots, t), jnp.int32),
+                        ((slots,), jnp.int32),
+                        ((slots, CELL_COLUMNS), jnp.int32), *weights,
+                        donate=(0, 1))
+    calls = kernel_calls(text)
+    assert len(calls) == 1 and named(calls[0], "paged_attend"), calls
+    assert_pools_carried_in_place(text, pool[0])

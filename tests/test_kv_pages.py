@@ -11,6 +11,7 @@ from distributed_training_guide_tpu.ops.attention import multihead_attention
 from distributed_training_guide_tpu.serve.kv_pages import (
     TRASH_PAGE, PagePool, commit_prefill, kv_page_bytes, paged_attend,
     pages_for_tokens)
+from tests.test_paged_decode import stacked_pool
 
 pytestmark = pytest.mark.serve
 
@@ -134,6 +135,9 @@ def _contiguous_reference(q, k_ctx, v_ctx, length):
         impl="xla", standard_layout=False)[0]
 
 
+LAYER = 1      # of the three-layer pools ``stacked_pool`` makes
+
+
 def test_paged_attend_matches_contiguous_cache():
     """Scatter a known contiguous k/v history into shuffled physical pages,
     then paged_attend must equal attention over the contiguous buffer —
@@ -162,7 +166,8 @@ def test_paged_attend_matches_contiguous_cache():
     v_new = rng.standard_normal((s, 1, hkv, d)).astype(np.float32)
 
     out, (nkp, nvp) = jax.jit(paged_attend)(
-        q, k_new, v_new, jnp.asarray(k_pages), jnp.asarray(v_pages),
+        q, k_new, v_new, stacked_pool(k_pages, LAYER),
+        stacked_pool(v_pages, LAYER), LAYER,
         jnp.asarray(tables), jnp.asarray(lengths))
 
     for i in range(s):
@@ -175,13 +180,14 @@ def test_paged_attend_matches_contiguous_cache():
                                    rtol=1e-5, atol=1e-5)
         # the write landed at the slot's own (page, offset)
         np.testing.assert_array_equal(
-            np.asarray(nkp[tables[i, n // page], n % page]), k_new[i, 0])
+            np.asarray(nkp[LAYER, tables[i, n // page], n % page]),
+            k_new[i, 0])
 
 
 def test_paged_attend_idle_slot_writes_to_trash():
     """A zeroed table row + length 0 (an idle lane of the fixed decode
-    batch) must scatter into page 0 only — allocated pages stay bitwise
-    untouched."""
+    batch) must scatter into the layer's own page 0 only — allocated pages
+    and the other layer stay bitwise untouched."""
     page, n_pages, h, d = 4, 6, 2, 8
     k_pages = jnp.asarray(
         np.random.default_rng(1).standard_normal((n_pages, page, h, d)),
@@ -190,14 +196,17 @@ def test_paged_attend_idle_slot_writes_to_trash():
     tables = jnp.zeros((1, 2), jnp.int32)
     q = jnp.ones((1, 1, h, d), jnp.float32)
     kv = jnp.ones((1, 1, h, d), jnp.float32)
-    _, (nkp, nvp) = paged_attend(q, kv, kv, k_pages, v_pages, tables,
+    kst, vst = stacked_pool(k_pages, LAYER), stacked_pool(v_pages, LAYER)
+    _, (nkp, nvp) = paged_attend(q, kv, kv, kst, vst, LAYER, tables,
                                  jnp.zeros(1, jnp.int32))
-    np.testing.assert_array_equal(np.asarray(nkp[1:]),
+    np.testing.assert_array_equal(np.asarray(nkp[LAYER, 1:]),
                                   np.asarray(k_pages[1:]))
-    np.testing.assert_array_equal(np.asarray(nvp[1:]),
+    np.testing.assert_array_equal(np.asarray(nvp[LAYER, 1:]),
                                   np.asarray(v_pages[1:]))
-    np.testing.assert_array_equal(np.asarray(nkp[TRASH_PAGE, 0]),
+    np.testing.assert_array_equal(np.asarray(nkp[LAYER, TRASH_PAGE, 0]),
                                   np.ones((h, d), np.float32))
+    np.testing.assert_array_equal(np.asarray(nkp[0]), np.asarray(kst[0]))
+    np.testing.assert_array_equal(np.asarray(nvp[0]), np.asarray(vst[0]))
 
 
 def test_paged_attend_multi_token_chunk_matches_contiguous():
@@ -219,10 +228,10 @@ def test_paged_attend_multi_token_chunk_matches_contiguous():
     q = rng.standard_normal((1, t, hq, d)).astype(np.float32)
     real = 4                                  # final-chunk padding: 2 pad
     out, (nkp, nvp) = jax.jit(paged_attend, static_argnames=())(
-        q, ctx[None, hist:], vctx[None, hist:], jnp.asarray(k_pages),
-        jnp.asarray(v_pages), jnp.asarray(tables),
+        q, ctx[None, hist:], vctx[None, hist:], stacked_pool(k_pages, LAYER),
+        stacked_pool(v_pages, LAYER), LAYER, jnp.asarray(tables),
         jnp.asarray([hist], jnp.int32), n_valid=jnp.asarray([real]))
-    nkp = np.asarray(nkp)
+    nkp = np.asarray(nkp)[LAYER]
 
     # real chunk rows equal attention over the contiguous history + chunk
     kv_pos = jnp.arange(hist + t)[None]

@@ -52,6 +52,7 @@ from distributed_training_guide_tpu.serve.kv_pages import (
 from distributed_training_guide_tpu.serve.scheduler import Request
 from distributed_training_guide_tpu.train.precision import Quantized
 from distributed_training_guide_tpu.utils import hlo as hlo_util
+from tests.test_paged_decode import stacked_pool
 
 pytestmark = [pytest.mark.serve, pytest.mark.kvquant]
 
@@ -148,6 +149,9 @@ def _paged_state(rng, *, s, m, page, n_pages, hkv, d, lengths):
     return tables, kp, vp
 
 
+LAYER = 1      # of the three-layer pools ``stacked_pool`` makes
+
+
 GRID = [
     dict(),                                    # plain causal
     dict(window=5),                            # SWA across pages
@@ -173,18 +177,20 @@ def test_int8_attend_parity_vs_fp32(hq, hkv, kw):
     v_new = rng.standard_normal((s, 1, hkv, d)).astype(np.float32)
     out32, _ = paged_attend(
         jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
-        jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
-        jnp.asarray(lengths), **kw)
+        stacked_pool(kp, LAYER), stacked_pool(vp, LAYER), LAYER,
+        jnp.asarray(tables), jnp.asarray(lengths), **kw)
     out8, (nkp, nvp) = paged_attend(
         jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
-        quantize_kv(jnp.asarray(kp)), quantize_kv(jnp.asarray(vp)),
+        quantize_kv(stacked_pool(kp, LAYER)),
+        quantize_kv(stacked_pool(vp, LAYER)), LAYER,
         jnp.asarray(tables), jnp.asarray(lengths), **kw)
     assert float(jnp.max(jnp.abs(out32 - out8))) < ATTEND_ATOL
     # the new token's quantized write landed beside its scale
     i, n = 0, int(lengths[0])
     want = quantize_kv(jnp.asarray(k_new))[0][i, 0]
     np.testing.assert_array_equal(
-        np.asarray(nkp.q[tables[i, n // page], n % page]), np.asarray(want))
+        np.asarray(nkp.q[LAYER, tables[i, n // page], n % page]),
+        np.asarray(want))
 
 
 def test_int8_flash_kernel_matches_int8_gather():
@@ -196,7 +202,7 @@ def test_int8_flash_kernel_matches_int8_gather():
     lengths = np.array([4, 0, 9, 15], np.int32)
     tables, kp, vp = _paged_state(rng, s=s, m=m, page=page, n_pages=n_pages,
                                   hkv=hkv, d=d, lengths=lengths)
-    kq, vq = quantize_kv(jnp.asarray(kp)), quantize_kv(jnp.asarray(vp))
+    kq, vq = (quantize_kv(stacked_pool(x, LAYER)) for x in (kp, vp))
     q = rng.standard_normal((s, 1, hq, d)).astype(np.float32)
     k_new = rng.standard_normal((s, 1, hkv, d)).astype(np.float32)
     v_new = rng.standard_normal((s, 1, hkv, d)).astype(np.float32)
@@ -205,7 +211,7 @@ def test_int8_flash_kernel_matches_int8_gather():
         for impl in ("flash", "xla"):
             attn, (nkp, nvp) = paged_attend(
                 jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
-                kq, vq, jnp.asarray(tables), jnp.asarray(lengths),
+                kq, vq, LAYER, jnp.asarray(tables), jnp.asarray(lengths),
                 impl=impl, **kw)
             outs[impl] = (np.asarray(attn), np.asarray(nkp.q),
                           np.asarray(nkp.scale))
@@ -218,8 +224,8 @@ def test_int8_flash_kernel_matches_int8_gather():
         # the documented quantization bound (the acceptance-criteria pin)
         ref32, _ = paged_attend(
             jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
-            jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
-            jnp.asarray(lengths), impl="xla", **kw)
+            stacked_pool(kp, LAYER), stacked_pool(vp, LAYER), LAYER,
+            jnp.asarray(tables), jnp.asarray(lengths), impl="xla", **kw)
         assert float(np.max(np.abs(outs["flash"][0]
                                    - np.asarray(ref32)))) < ATTEND_ATOL
 
@@ -260,12 +266,12 @@ def test_int8_engine_builds_on_debug_geometry(llama):
 
 
 def test_paged_flash_decode_scale_validation_and_eligibility():
-    kq = jnp.zeros((4, 4, 2, 16), jnp.int8)
+    kq = jnp.zeros((2, 4, 4, 2, 16), jnp.int8)
     with pytest.raises(ValueError, match="half-quantized"):
-        paged_flash_decode(jnp.zeros((1, 4, 16)), kq, kq,
+        paged_flash_decode(jnp.zeros((1, 4, 16)), kq, kq, 1,
                            jnp.zeros((1, 2), jnp.int32),
                            jnp.zeros(1, jnp.int32),
-                           k_scale=jnp.zeros((4, 4, 2)), interpret=True)
+                           k_scale=jnp.zeros((2, 4, 4, 2)), interpret=True)
     # one gate for float and int8 pools: the page axis is a whole-dimension
     # block, which the chip's compiler tiles for either payload
     # (tests/test_chip_compile.py compiles int8 at page 16)

@@ -112,14 +112,15 @@ def test_bucketed_prefill_commits_the_same_latent_rows_as_the_chunks(model):
 
 
 def test_absorbed_and_decompressed_attention_are_the_same_sum(model, monkeypatch):
-    """One layer, 3 slots, T = 2 new tokens over committed histories: the
-    form the decode step takes against the form a chunk takes."""
+    """One layer (the second of the stacked pools), 3 slots, T = 2 new
+    tokens over committed histories: the form the decode step takes against
+    the form a chunk takes."""
     cfg, w, bundle, params = model
     config = bundle.config
-    layer = jax.tree.map(lambda a: a[0], params["layers"])
+    layer = jax.tree.map(lambda a: a[1], params["layers"])
     rng = np.random.default_rng(6)
     pages = jax.tree.map(
-        lambda a: jnp.asarray(rng.normal(size=a.shape[1:]), a.dtype) * 0.5,
+        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype) * 0.5,
         kv_pages.init_pages(config, 16, PAGE))
     pages["k"] = pages["k"].at[..., 8:].set(0)
     tables = jnp.asarray(rng.permutation(np.arange(1, 16))[:15].reshape(3, 5))
@@ -131,7 +132,7 @@ def test_absorbed_and_decompressed_attention_are_the_same_sum(model, monkeypatch
         def bound(q, k_new, v_new, **kw):
             assert ("expand" in kw) == wide
             return kv_pages.paged_attend(q, k_new, v_new, pages["k"],
-                                         pages["v"], tables, lengths,
+                                         pages["v"], 1, tables, lengths,
                                          impl="xla", **kw)
         return mla.latent_attention_sublayer(
             config, x, layer["attn"], layer["input_norm"], pos, bound)[0]
@@ -144,35 +145,47 @@ def test_absorbed_and_decompressed_attention_are_the_same_sum(model, monkeypatch
     assert float(jnp.max(jnp.abs(absorbed - decompressed))) < 1e-5
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize("t,dtype", [(1, jnp.float32), (2, jnp.float32),
                                      (1, jnp.bfloat16)])
-def test_latent_kernel_interpreted_matches_the_gathered_rows(t, dtype):
+def test_latent_kernel_interpreted_matches_the_gathered_rows(t, dtype, layer):
     """Lengths either side of a page and of a block of the walk (8 pages of
-    16), an empty slot and a full table, through shuffled physical pages."""
+    16), an empty slot and a full table, through shuffled physical pages of
+    one layer of stacked pools whose three layers all differ: the kernel and
+    the gather path there against the gather path on that layer ALONE, and
+    the write leaves the other layers' bytes as they were."""
     rng = np.random.default_rng(8)
     h, c, r, rw, page, cols = 4, 128, 64, 128, 16, 20
     lengths = [0, 1, 15, 16, 17, 127, 128, 129, 200, cols * page - t]
     n = len(lengths)
     q = jnp.asarray(rng.normal(size=(n, t, h, c + r)), dtype)
-    kp = jnp.asarray(rng.normal(size=(1 + n * cols, page, 1, rw)), dtype)
-    vp = jnp.asarray(rng.normal(size=(1 + n * cols, page, 1, c)), dtype)
+    kp = jnp.asarray(rng.normal(size=(3, 1 + n * cols, page, 1, rw)), dtype)
+    vp = jnp.asarray(rng.normal(size=(3, 1 + n * cols, page, 1, c)), dtype)
     k_new = jnp.asarray(rng.normal(size=(n, t, 1, rw)), dtype)
     v_new = jnp.asarray(rng.normal(size=(n, t, 1, c)), dtype)
     tables = jnp.asarray(rng.permutation(np.arange(1, 1 + n * cols))
                          .reshape(n, cols), jnp.int32)
-    args = (q, k_new, v_new, kp, vp, tables, jnp.asarray(lengths, jnp.int32))
-    kw = dict(scale=0.05, latent_rope=r)
-    want, _ = kv_pages.paged_attend(*args, impl="xla", **kw)
-    got, _ = kv_pages.paged_attend(*args, impl="flash", **kw)
-    assert got.shape == (n, t, h, c) and got.dtype == dtype
-    tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
-                                 - want.astype(jnp.float32)))) < tol
-    # the rope key's pad columns are not part of the key
     lens = jnp.asarray(lengths, jnp.int32)
-    clean = paged_latent_attend(q, kp, vp, tables, lens, scale=0.05)
-    dirty = paged_latent_attend(q, kp.at[..., r:].set(7.0), vp, tables, lens,
-                                scale=0.05)
+    kw = dict(scale=0.05, latent_rope=r)
+    want, _ = kv_pages.paged_attend(q, k_new, v_new, kp[layer][None],
+                                    vp[layer][None], 0, tables, lens,
+                                    impl="xla", **kw)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    others = [i for i in range(3) if i != layer]
+    for impl in ("flash", "xla"):
+        got, (nkp, nvp) = kv_pages.paged_attend(
+            q, k_new, v_new, kp, vp, layer, tables, lens, impl=impl, **kw)
+        assert got.shape == (n, t, h, c) and got.dtype == dtype
+        assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                     - want.astype(jnp.float32)))) < tol
+        for before, after in ((kp, nkp), (vp, nvp)):
+            assert jnp.array_equal(after[jnp.asarray(others)],
+                                   before[jnp.asarray(others)])
+            assert not jnp.array_equal(after[layer], before[layer])
+    # the rope key's pad columns are not part of the key
+    clean = paged_latent_attend(q, kp, vp, layer, tables, lens, scale=0.05)
+    dirty = paged_latent_attend(q, kp.at[..., r:].set(7.0), vp, layer, tables,
+                                lens, scale=0.05)
     assert jnp.array_equal(clean, dirty)
 
 
@@ -293,6 +306,23 @@ def test_a_llama_engine_reports_no_routing():
     while engine.has_work:
         engine.step()
     assert "routing" not in engine.stats()
+
+
+def test_latent_pools_ride_the_layer_scan_as_carry(debug_engine_parts):
+    """The family's decode and chunk programs carry the two stacked latent
+    pools through the layer scan whole (the routing counts are its one
+    per-layer output) and slice no layer's pool out of them."""
+    from distributed_training_guide_tpu.utils import hlo
+    from tests.test_paged_decode import serve_program_jaxprs
+
+    bundle, params = debug_engine_parts
+    engine = ServeEngine(bundle, params, n_slots=2, page_size=16, max_len=64,
+                         attend_impl="flash", prefill_chunk=16)
+    for name, jaxpr in serve_program_jaxprs(engine).items():
+        for leaf in ("k", "v"):
+            scans = hlo.scans_holding(jaxpr, engine.pages[leaf].shape)
+            assert scans == [{"carry": 1, "xs": 0, "ys": 0, "sliced": []}], (
+                name, leaf, scans)
 
 
 def test_decode_program_carries_the_new_scopes(debug_engine_parts):

@@ -5,6 +5,8 @@ page-boundary lengths) at EVERY query-tile size — T=1 decode, T>1
 verify/chunk tiles with ``n_valid`` pad tails, int8 and bf16 pools — and
 over the walk itself (lengths at every page and block edge, mixed and dead
 slots in one call, a window's lower edge mid-walk, the large-tile form) —
+always on STACKED pools of three layers with different contents, addressed
+by a layer index (the attend contract: ``serve/kv_pages.paged_attend``) —
 plus the engine-level pins: flash and xla attends produce identical
 tokens, and the flash decode/chunk/verify programs' HLO carries no
 [S, M*page, Hkv, D] gathered view (the xla programs show it)."""
@@ -35,9 +37,23 @@ def _random_paged_state(rng, *, s, m, page, n_pages, hkv, d):
     return tables, k_pages, v_pages
 
 
+N_LAYERS = 3
+
+
+def stacked_pool(pool, layer):
+    """The ``[3, P, page, Hkv, D]`` pool whose layer ``layer`` is ``pool``
+    and whose other layers hold other numbers (its pages rolled and scaled),
+    so that an attend reading another layer's pages cannot pass."""
+    pool = jnp.asarray(pool)
+    return jnp.stack([pool if i == layer
+                      else jnp.roll(pool, i + 1, axis=0) * (i + 2)
+                      for i in range(N_LAYERS)])
+
+
 def _gather_reference(q, k_pages, v_pages, tables, lengths, *, window=None,
                       scale=None, softcap=None):
-    """The XLA logical-view attend (what serve ran before the kernel)."""
+    """The XLA logical-view attend (what serve ran before the kernel), on
+    ONE layer's pool: the test's own reference, which knows no layer."""
     s, m = tables.shape
     page = k_pages.shape[1]
     kg = k_pages[tables].reshape(s, m * page, *k_pages.shape[2:])
@@ -75,7 +91,7 @@ def test_kernel_matches_gather_reference(hq, hkv, kw):
     q = rng.standard_normal((s, hq, d)).astype(np.float32)
 
     out = paged_flash_decode(
-        jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
+        jnp.asarray(q), stacked_pool(k_pages, 2), stacked_pool(v_pages, 2), 2,
         jnp.asarray(tables), jnp.asarray(lengths), interpret=True, **kw)
     ref = _gather_reference(q, jnp.asarray(k_pages), jnp.asarray(v_pages),
                             tables, lengths, **kw)
@@ -92,8 +108,9 @@ def test_kernel_traced_window_matches_static():
         rng, s=s, m=m, page=page, n_pages=n_pages, hkv=hkv, d=d)
     lengths = np.array([5, 11, 14], np.int32)
     q = rng.standard_normal((s, hq, d)).astype(np.float32)
-    args = (jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
-            jnp.asarray(tables), jnp.asarray(lengths))
+    args = (jnp.asarray(q), stacked_pool(k_pages, 1),
+            stacked_pool(v_pages, 1), 1, jnp.asarray(tables),
+            jnp.asarray(lengths))
 
     traced = jax.jit(lambda w: paged_flash_decode(*args, window=w,
                                                   interpret=True))
@@ -118,8 +135,9 @@ def test_kernel_bf16_pages():
     vp = jnp.asarray(v_pages, jnp.bfloat16)
     lengths = np.array([3, 12], np.int32)
     q = jnp.asarray(rng.standard_normal((s, hq, d)), jnp.bfloat16)
-    out = paged_flash_decode(q, kp, vp, jnp.asarray(tables),
-                             jnp.asarray(lengths), interpret=True)
+    out = paged_flash_decode(q, stacked_pool(kp, 1), stacked_pool(vp, 1), 1,
+                             jnp.asarray(tables), jnp.asarray(lengths),
+                             interpret=True)
     ref = _gather_reference(q, kp, vp, tables, lengths)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -133,7 +151,8 @@ def test_kernel_validates_bad_static_window_and_tiles():
         rng, s=1, m=2, page=4, n_pages=4, hkv=2, d=8)
     q = jnp.zeros((1, 4, 8), jnp.float32)
     with pytest.raises(ValueError, match="window"):
-        paged_flash_decode(q, jnp.asarray(k_pages), jnp.asarray(v_pages),
+        paged_flash_decode(q, stacked_pool(k_pages, 0),
+                           stacked_pool(v_pages, 0), 0,
                            jnp.asarray(tables), jnp.zeros(1, jnp.int32),
                            window=0, interpret=True)
     assert paged_decode_eligible(128, 8)
@@ -156,9 +175,9 @@ def test_paged_attend_flash_matches_xla_dispatch():
     outs = {}
     for impl in ("flash", "xla"):
         attn, (kp, vp) = paged_attend(
-            q, k_new, v_new, jnp.asarray(k_pages), jnp.asarray(v_pages),
-            jnp.asarray(tables), lengths, impl=impl, window=6, scale=0.3,
-            softcap=30.0)
+            q, k_new, v_new, stacked_pool(k_pages, 1),
+            stacked_pool(v_pages, 1), 1, jnp.asarray(tables), lengths,
+            impl=impl, window=6, scale=0.3, softcap=30.0)
         outs[impl] = (np.asarray(attn), np.asarray(kp), np.asarray(vp))
     np.testing.assert_allclose(outs["flash"][0], outs["xla"][0],
                                rtol=1e-5, atol=1e-5)
@@ -189,9 +208,15 @@ def _walk_state(rng, lengths, *, t, m, page, hq, hkv, d, dtype=np.float32):
             lens, mk(s, t, hq, d), mk(s, t, hkv, d), mk(s, t, hkv, d))
 
 
-def _flash_and_xla(q, k_new, v_new, k_pages, v_pages, tables, lengths, **kw):
+def _flash_and_xla(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
+                   layer=1, int8=False, **kw):
+    """Both impls' attention at ``layer`` of the stacked pools made of one
+    layer's float ``k_pages`` / ``v_pages`` (quantized once stacked)."""
+    kp, vp = stacked_pool(k_pages, layer), stacked_pool(v_pages, layer)
+    if int8:
+        kp, vp = quantize_kv(kp), quantize_kv(vp)
     return [paged_attend(jnp.asarray(q), jnp.asarray(k_new),
-                         jnp.asarray(v_new), k_pages, v_pages,
+                         jnp.asarray(v_new), kp, vp, layer,
                          jnp.asarray(tables), jnp.asarray(lengths),
                          impl=impl, **kw)[0] for impl in ("flash", "xla")]
 
@@ -245,12 +270,9 @@ def test_walk_pool_dtypes(pool, t):
     tables, kp, vp, lens, q, k_new, v_new = _walk_state(
         rng, lengths, t=t, m=m, page=page, hq=hq, hkv=hkv, d=d)
     dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
-    kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
-    if pool == "int8":
-        kp, vp = quantize_kv(kp), quantize_kv(vp)
     flash, xla = _flash_and_xla(
-        *(jnp.asarray(x, dtype) for x in (q, k_new, v_new)), kp, vp,
-        tables, lens, scale=0.3)
+        *(jnp.asarray(x, dtype) for x in (q, k_new, v_new, kp, vp)),
+        tables, lens, int8=pool == "int8", scale=0.3)
     tol = 3e-2 if pool == "bf16" else 1e-5
     assert flash.dtype == dtype
     np.testing.assert_allclose(np.asarray(flash, np.float32),
@@ -275,11 +297,11 @@ def test_walk_window_lower_edge_inside_the_walk(window, t):
     np.testing.assert_allclose(np.asarray(flash), np.asarray(xla),
                                rtol=1e-5, atol=1e-5)
     traced = jax.jit(lambda w: paged_flash_attend(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), stacked_pool(kp, 2), stacked_pool(vp, 2), 2,
         jnp.asarray(tables), jnp.asarray(lens), window=w, softcap=30.0,
         interpret=True))
     static = paged_flash_attend(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), stacked_pool(kp, 2), stacked_pool(vp, 2), 2,
         jnp.asarray(tables), jnp.asarray(lens), window=window, softcap=30.0,
         interpret=True)
     np.testing.assert_allclose(np.asarray(traced(jnp.asarray(window))),
@@ -309,13 +331,10 @@ def test_large_tile_takes_the_heads_of_one_word(pool, hb, split, monkeypatch):
     lengths = [0, 13, m * page - t]
     tables, kp, vp, lens, q, k_new, v_new = _walk_state(
         rng, lengths, t=t, m=m, page=page, hq=hq, hkv=hkv, d=d)
-    kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
-    if pool == "int8":
-        kp, vp = quantize_kv(kp), quantize_kv(vp)
     flash, xla = _flash_and_xla(
-        *(jnp.asarray(x, dtype) for x in (q, k_new, v_new)), kp, vp,
-        tables, lens, n_valid=jnp.asarray([t, 5, t - 1], jnp.int32),
-        window=29)
+        *(jnp.asarray(x, dtype) for x in (q, k_new, v_new, kp, vp)),
+        tables, lens, layer=2, int8=pool == "int8",
+        n_valid=jnp.asarray([t, 5, t - 1], jnp.int32), window=29)
     tol = 3e-2 if pool == "bf16" else 1e-5
     np.testing.assert_allclose(np.asarray(flash, np.float32),
                                np.asarray(xla, np.float32),
@@ -341,6 +360,87 @@ def test_plan_follows_the_static_shapes(case, want):
                                    128, 16, 256, q_dtype, case["pool"])
     assert (hs, hb, n) == want
     assert case["hkv"] % hs == 0 and hs % hb == 0 and n >= 1
+
+
+# ---- the stacked pools, addressed by layer ---------------------------------
+
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+@pytest.mark.parametrize("pool", ["fp32", "bf16", "int8"])
+@pytest.mark.parametrize("t", [1, 4], ids=["decode", "tile"])
+def test_stacked_pools_are_written_and_read_at_the_layer(pool, t, layer):
+    """Three layers of different contents in one pool: at each layer, both
+    impls equal the gather path on THAT layer alone (a one-layer stack,
+    where no layer can be mistaken for another), over shuffled tables,
+    lengths either side of a page edge and ``n_valid`` tails; the write
+    lands in that layer, as it would in the layer alone, and leaves every
+    other layer's bytes as they were."""
+    page, m, hq, hkv, d = 4, 6, 4, 2, 8
+    rng = np.random.default_rng(31 + layer)
+    lengths = [0, page - 1, page, page + 1, 2 * page - t, m * page - t, -1]
+    tables, kp, vp, lens, q, k_new, v_new = _walk_state(
+        rng, lengths, t=t, m=m, page=page, hq=hq, hkv=hkv, d=d)
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    pools = [stacked_pool(jnp.asarray(x, dtype), layer) for x in (kp, vp)]
+    if pool == "int8":
+        pools = [quantize_kv(x) for x in pools]
+    n_valid = jnp.asarray([(t, max(1, t - 1), 1)[i % 3]
+                           for i in range(len(lens))], jnp.int32)
+    args = [jnp.asarray(x, dtype) for x in (q, k_new, v_new)]
+    rest = (jnp.asarray(tables), jnp.asarray(lens))
+    alone = [jax.tree.map(lambda x: x[layer][None], pl) for pl in pools]
+    want, want_pools = paged_attend(*args, *alone, 0, *rest, impl="xla",
+                                    n_valid=n_valid, window=7)
+    tol = 3e-2 if pool == "bf16" else 1e-5
+    for impl in ("flash", "xla"):
+        got, got_pools = paged_attend(*args, *pools, layer, *rest, impl=impl,
+                                      n_valid=n_valid, window=7)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+        for before, after, one in zip(jax.tree.leaves(pools),
+                                      jax.tree.leaves(got_pools),
+                                      jax.tree.leaves(want_pools)):
+            before, after = np.asarray(before), np.asarray(after)
+            np.testing.assert_array_equal(after[layer], np.asarray(one)[0])
+            assert not np.array_equal(after[layer], before[layer])
+            others = [i for i in range(N_LAYERS) if i != layer]
+            np.testing.assert_array_equal(after[others], before[others])
+
+
+def serve_program_jaxprs(eng):
+    """The decode and the chunk program of an engine, traced."""
+    arr = eng.scheduler.decode_arrays()
+    decode = jax.make_jaxpr(eng.programs._decode)(
+        eng.params, eng.pages["k"], eng.pages["v"],
+        *(jnp.asarray(arr[k]) for k in (
+            "tokens", "lengths", "tables", "seeds", "temps", "top_ks",
+            "top_ps", "actives")))
+    t = eng.prefill_chunk
+    chunk = jax.make_jaxpr(eng.programs.chunk_for(t))(
+        eng.params, eng.pages["k"], eng.pages["v"],
+        jnp.zeros((1, t), jnp.int32), jnp.zeros((1,), jnp.int32),
+        jnp.zeros((1, eng.max_pages), jnp.int32),
+        jnp.asarray(t - 1, jnp.int32), jnp.asarray([t], jnp.int32))
+    return {"decode": decode, "chunk": chunk}
+
+
+@pytest.mark.parametrize("impl", ["flash", "xla"])
+def test_pools_ride_the_layer_scan_as_carry(impl):
+    """The structure the decode step's time rests on: in the decode and the
+    chunk program the two stacked pools are in the layer scan's CARRY, not
+    among its scanned inputs or stacked outputs, and nothing in the scan's
+    body slices a layer's pool out of them or puts one back."""
+    from distributed_training_guide_tpu.models import get_model
+    from distributed_training_guide_tpu.serve import ServeEngine
+
+    bundle = get_model("llama-debug", dtype=jnp.float32)
+    params = bundle.init(bundle.config, jax.random.key(0))
+    eng = ServeEngine(bundle, params, n_slots=2, page_size=4, max_len=16,
+                      attend_impl=impl, prefill_chunk=8)
+    for name, jaxpr in serve_program_jaxprs(eng).items():
+        scans = hlo_util.scans_holding(jaxpr, eng.pages["k"].shape)
+        assert scans == [{"carry": 2, "xs": 0, "ys": 0, "sliced": []}], (
+            name, scans)
 
 
 # ---- engine-level pins ------------------------------------------------------
@@ -422,8 +522,8 @@ def test_multitoken_flash_matches_gather(hq, hkv, kw):
     for impl in ("flash", "xla"):
         attn, (kp, vp) = paged_attend(
             jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
-            jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(tables),
-            jnp.asarray(lengths), impl=impl,
+            stacked_pool(k_pages, 2), stacked_pool(v_pages, 2), 2,
+            jnp.asarray(tables), jnp.asarray(lengths), impl=impl,
             n_valid=jnp.asarray(n_valid), **kw)
         outs[impl] = (np.asarray(attn), np.asarray(kp), np.asarray(vp))
     np.testing.assert_allclose(outs["flash"][0], outs["xla"][0],
@@ -443,8 +543,8 @@ def test_multitoken_rank3_is_the_decode_form_bitwise():
         rng, s=3, m=4, page=4, n_pages=16, hkv=2, d=8)
     lengths = np.array([3, 7, 12], np.int32)
     q = rng.standard_normal((3, 4, 8)).astype(np.float32)
-    args = (jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(tables),
-            jnp.asarray(lengths))
+    args = (stacked_pool(k_pages, 1), stacked_pool(v_pages, 1), 1,
+            jnp.asarray(tables), jnp.asarray(lengths))
     r3 = paged_flash_decode(jnp.asarray(q), *args, window=5, interpret=True)
     r4 = paged_flash_attend(jnp.asarray(q)[:, None], *args, window=5,
                             interpret=True)
@@ -459,8 +559,9 @@ def test_multitoken_traced_window_matches_static():
     rng = np.random.default_rng(13)
     tables, k_pages, v_pages, lengths, _, q, _, _ = \
         _multitok_case(rng, hq=4, hkv=2)
-    args = (jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
-            jnp.asarray(tables), jnp.asarray(lengths))
+    args = (jnp.asarray(q), stacked_pool(k_pages, 1),
+            stacked_pool(v_pages, 1), 1, jnp.asarray(tables),
+            jnp.asarray(lengths))
     traced = jax.jit(lambda w: paged_flash_attend(*args, window=w,
                                                   interpret=True))
     static = paged_flash_attend(*args, window=6, interpret=True)
@@ -484,9 +585,10 @@ def test_multitoken_bf16_pages():
         attn, _ = paged_attend(
             jnp.asarray(q, jnp.bfloat16), jnp.asarray(k_new, jnp.bfloat16),
             jnp.asarray(v_new, jnp.bfloat16),
-            jnp.asarray(k_pages, jnp.bfloat16),
-            jnp.asarray(v_pages, jnp.bfloat16), jnp.asarray(tables),
-            jnp.asarray(lengths), impl=impl, n_valid=jnp.asarray(n_valid))
+            stacked_pool(jnp.asarray(k_pages, jnp.bfloat16), 0),
+            stacked_pool(jnp.asarray(v_pages, jnp.bfloat16), 0), 0,
+            jnp.asarray(tables), jnp.asarray(lengths), impl=impl,
+            n_valid=jnp.asarray(n_valid))
         assert attn.dtype == jnp.bfloat16
         outs[impl] = np.asarray(attn, np.float32)
     np.testing.assert_allclose(outs["flash"], outs["xla"],
@@ -503,13 +605,13 @@ def test_multitoken_int8_flash_matches_int8_gather():
     rng = np.random.default_rng(15)
     tables, k_pages, v_pages, lengths, n_valid, q, k_new, v_new = \
         _multitok_case(rng, hq=4, hkv=2)
-    kq = quantize_kv(jnp.asarray(k_pages))
-    vq = quantize_kv(jnp.asarray(v_pages))
+    kq = quantize_kv(stacked_pool(k_pages, 1))
+    vq = quantize_kv(stacked_pool(v_pages, 1))
     outs = {}
     for impl in ("flash", "xla"):
         attn, (kp, vp) = paged_attend(
             jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
-            kq, vq, jnp.asarray(tables), jnp.asarray(lengths), impl=impl,
+            kq, vq, 1, jnp.asarray(tables), jnp.asarray(lengths), impl=impl,
             n_valid=jnp.asarray(n_valid), window=6, scale=0.3, softcap=30.0)
         outs[impl] = (np.asarray(attn), kp, vp)
     np.testing.assert_allclose(outs["flash"][0], outs["xla"][0],
