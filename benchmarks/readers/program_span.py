@@ -3,7 +3,9 @@
 traced window. Spans nest by containment on one thread; ``serve.step`` is one
 engine iteration and everything else lies inside one.
 
-``stat`` picks the number:
+``stat`` picks the number. The two per-step numbers are read over DECODE
+steps alone, the steps with no ``serve.prefill`` span inside (a step that
+also runs a prefill chunk does other host work and waits twice):
 
 - ``host_ms_per_step``: a ``serve.step`` minus the ``serve.wait`` spans inside
   it (the blocking reads of the device's results), mean over the steps, ms.
@@ -26,6 +28,7 @@ from benchmarks.readers import _xplane
 
 STEP = "serve.step"
 WAIT = ("serve.wait",)
+PREFILL = "serve.prefill"
 SCHEDULE = ("serve.expire", "serve.admit", "serve.reserve", "serve.book")
 
 
@@ -40,6 +43,12 @@ def steps_with_children(spans, lo: int, hi: int) -> list:
         out.append((step, [s for s in spans if s[0] != STEP
                            and s[3] == thread and s[1] >= a and s[2] <= b]))
     return out
+
+
+def decode_steps(steps) -> list:
+    """Of ``steps_with_children``'s steps, those that ran no prefill."""
+    return [(step, children) for step, children in steps
+            if all(c[0] != PREFILL for c in children)]
 
 
 def covered_ns(children, names=None) -> int:
@@ -125,15 +134,17 @@ def reduce(ctx) -> dict | None:
         trace, path = found
         spans = _xplane.program_spans(path)
         steps = steps_with_children(spans, trace["lo_ns"], trace["hi_ns"])
-        if steps:
+        decode = decode_steps(steps)
+        if decode:
             by_span = idle_by_span(worst_device_gaps(trace), spans)
             result = {
-                "host_ms_per_step": host_ms_per_step(steps),
-                "schedule_ms_per_step": schedule_ms_per_step(steps),
+                "host_ms_per_step": host_ms_per_step(decode),
+                "schedule_ms_per_step": schedule_ms_per_step(decode),
                 "idle_unattributed_pct": idle_unattributed_pct(by_span),
             }
             print(json.dumps({"idle_by_program_span": by_span,
-                              "program_steps": len(steps)}), flush=True)
+                              "program_steps": len(steps),
+                              "decode_steps": len(decode)}), flush=True)
     ctx["program_span_stats"] = result
     return result
 

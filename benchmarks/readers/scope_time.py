@@ -8,13 +8,18 @@ path (``attn/attend/paged_attend`` is ``attend``; ``_xplane.scope_of``), or to
 made, a scan's own slicing, what a scope missed. So the scopes and
 ``unscoped`` add up to the device's busy time. ``recompute`` lies across them:
 the operations under ``jax.checkpoint``'s ``rematted_computation`` wrapper,
-forward work run a second time. Steps are the benchmark's ``step`` /
-``engine.step`` spans inside the window, as ``kernel_roofline`` counts them;
-a value is a mean over the cell's devices, in ms per step.
+forward work run a second time. Steps are the benchmark's ``step_span`` spans
+inside the window; where the metric names a ``program`` (``serve_decode``),
+only the events that ran under that jitted program count (the ``XLA Modules``
+line) and a step is one execution of it, so that a prefill chunk inside the
+window, which runs the same scopes, is not read as decode time. A value is a
+mean over the cell's devices, in ms per step.
 
 The whole table is printed once, on an earlier line
 (``{"device_ms_by_scope": ...}``), with the largest unscoped operations and,
-on several chips, the exposed-collective seconds by scope. A program that
+on several chips, the exposed-collective seconds by scope; where a program is
+named, every other program the window ran gets such a line of its own (ms per
+execution: a chunk step's split beside the decode step's). A program that
 names none of its parts (the parent of the PR that added the names) gives
 ``None`` for every metric.
 """
@@ -105,7 +110,7 @@ def op_paths_of(path) -> dict:
     return out
 
 
-def table(ctx, step_span: str) -> dict | None:
+def table(ctx, step_span: str, program: str | None = None) -> dict | None:
     """The cell's table, worked out and printed once a run."""
     if "scope_table" in ctx:
         return ctx["scope_table"]
@@ -113,10 +118,19 @@ def table(ctx, step_span: str) -> dict | None:
     if found is not None:
         trace, path = found
         lo, hi = trace["lo_ns"], trace["hi_ns"]
-        steps = sum(1 for name, a, b in trace["host_spans"]
-                    if name == step_span and a >= lo and b <= hi)
-        result = table_from(trace["device_ops"], op_paths_of(path), lo, hi,
-                            steps)
+        paths = op_paths_of(path)
+        if program is None:
+            result = table_from(trace["device_ops"], paths, lo, hi,
+                                trace_reduce.spans_inside(trace, step_span))
+        else:
+            ops, runs = trace_reduce.program_ops(trace, program)
+            result = table_from(ops, paths, lo, hi, runs)
+            for other in sorted(trace_reduce.programs_run(trace) - {program}):
+                ops, runs = trace_reduce.program_ops(trace, other)
+                split = table_from(ops, paths, lo, hi, runs)
+                if split is not None:
+                    print(json.dumps({"device_ms_by_scope": split,
+                                      "program": other}), flush=True)
     if result is not None:
         print(json.dumps({"device_ms_by_scope": result}), flush=True)
     ctx["scope_table"] = result
@@ -124,7 +138,7 @@ def table(ctx, step_span: str) -> dict | None:
 
 
 def read(ctx, params):
-    found = table(ctx, params["step_span"])
+    found = table(ctx, params["step_span"], params.get("program"))
     if found is None:
         return None
     if params["scope"] == "recompute":

@@ -266,20 +266,21 @@ def leaf_norms(tree) -> dict:
     return out
 
 
-def train_steps(cfg, opt, make_params, batches, rows_per_block, mode="highest",
-                place=None, moments_on_host=False):
-    """Follow ``len(batches)`` AdamW steps from ``make_params()`` (traceable:
-    the seed's weights). Returns each step's loss, the per-leaf norms of the
-    first gradient, and the per-leaf norms of the parameters' change after the
-    last step. The starting weights are made again for that difference rather
+def train_steps(cfg, opt, make_params, key, batches, rows_per_block,
+                mode="highest", place=None, moments_on_host=False):
+    """Follow ``len(batches)`` AdamW steps from ``make_params(key)``
+    (traceable: the seed's weights; ``key`` is an operand of the programs that
+    call it, so a new seed compiles nothing). Returns each step's loss, the
+    per-leaf norms of the first gradient, and the per-leaf norms of the
+    parameters' change after the last step. The starting weights are made again for that difference rather
     than kept: a float32 copy is gigabytes. ``place`` jits ``make_params``
     with the caller's shardings. With ``moments_on_host`` Adam's two moments
     wait in host memory while the next gradient is computed (float32
     parameters, gradients, moments and a long row's activations do not all
     fit one chip)."""
-    delta_fn = jax.jit(lambda p: leaf_norms(
-        jax.tree.map(lambda x, y: x - y, p, make_params())))
-    p = (place or jax.jit)(make_params)()
+    delta_fn = jax.jit(lambda p, key: leaf_norms(
+        jax.tree.map(lambda x, y: x - y, p, make_params(key))))
+    p = (place or jax.jit)(make_params)(key)
     shardings = jax.tree.map(lambda x: x.sharding, p)
     # zeros do not depend on their argument, so without this their sharding
     # would be the compiler's choice: one whole copy on one chip
@@ -306,7 +307,7 @@ def train_steps(cfg, opt, make_params, batches, rows_per_block, mode="highest",
         if moments_on_host and i + 1 < len(batches):
             m, v = jax.device_get(m), jax.device_get(v)
     return {"losses": losses, "grad_norms": grad_norms,
-            "delta_norms": jax.device_get(delta_fn(p))}
+            "delta_norms": jax.device_get(delta_fn(p, key))}
 
 
 def _loss_grads_norms(cfg, p, tokens, rows_per_block, mode):

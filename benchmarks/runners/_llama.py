@@ -1,8 +1,11 @@
-"""The benchmark's configuration and weights, handed to the program's llama
-family in the program's own terms (``models/llama.py``)."""
+"""The llama family's adapter, found by ``cfg["family"]``
+(``runners/_<family>.py``): the benchmark's configuration and weights handed
+to the program in the program's own terms (``models/llama.py``), and the
+family's ``weights`` module and plain ``reference``."""
 from __future__ import annotations
 
 from benchmarks import weights
+from benchmarks.reference import decoder as reference  # noqa: F401
 
 ATTN = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
 MLP = ("gate", "up", "down")
@@ -60,6 +63,7 @@ def from_program(tree: dict) -> dict:
     return {"top": top, "layers": layers}
 
 
-def program_params(cfg: dict, seed: int, dtype=None):
-    """Traceable: the program's tree for ``--seed`` (call under one jit)."""
-    return to_program(weights.stacked_weights(cfg, weights.seed_key(seed), dtype))
+def program_params(cfg: dict, key, dtype=None):
+    """Traceable: the program's tree for ``weights.seed_key(seed)``, which the
+    one jit around this takes as an operand."""
+    return to_program(weights.stacked_weights(cfg, key, dtype))

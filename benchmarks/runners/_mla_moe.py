@@ -1,8 +1,11 @@
-"""The benchmark's configuration and weights, handed to the program's
-``mla_moe`` family in the program's own terms (``models/mla.py``)."""
+"""The ``mla_moe`` family's adapter, found by ``cfg["family"]``
+(``runners/_<family>.py``): the benchmark's configuration and weights handed
+to the program in the program's own terms (``models/mla.py``), and the
+family's ``weights`` module and plain ``reference``."""
 from __future__ import annotations
 
 from benchmarks import weights_mla_moe as weights
+from benchmarks.reference import mla_moe as reference  # noqa: F401
 
 ATTN = ("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wkv_b", "wo")
 MOE = ("router", "router_bias", "gate", "up", "down", "shared_gate_proj",
@@ -59,6 +62,7 @@ def to_program(w: dict) -> dict:
     }
 
 
-def program_params(cfg: dict, seed: int, dtype=None):
-    """Traceable: the program's tree for ``--seed`` (call under one jit)."""
-    return to_program(weights.stacked_weights(cfg, weights.seed_key(seed), dtype))
+def program_params(cfg: dict, key, dtype=None):
+    """Traceable: the program's tree for ``weights.seed_key(seed)``, which the
+    one jit around this takes as an operand."""
+    return to_program(weights.stacked_weights(cfg, key, dtype))

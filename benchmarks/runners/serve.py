@@ -1,5 +1,8 @@
-"""Runner for serving cells: drives ``serve/engine.py``'s ``ServeEngine``
-(submit / step) under the traffic mix's loop, from one thread.
+"""Runner for serving cells of every family: drives ``serve/engine.py``'s
+``ServeEngine`` (submit / step) under the traffic mix's loop, from one thread.
+The family's adapter is found by name, ``runners/_<cfg["family"]>.py``: it
+gives ``bundle_for`` and ``program_params`` and names the family's ``weights``
+module and plain ``reference``, so a new family is new files alone.
 
 Set-up warms every program the traffic uses with two requests of its own,
 then starts the closed loop and lets it run ``ramp_steps`` engine steps (every
@@ -18,9 +21,7 @@ import time
 
 import numpy as np
 
-from benchmarks import weights
-from benchmarks.reference import decoder as ref
-from benchmarks.runners import _llama
+from benchmarks import harness
 from benchmarks.traffic import generate
 
 KV_BYTES = {"bf16": 2, "fp32": 4, "int8": 1}
@@ -29,6 +30,11 @@ KV_BYTES = {"bf16": 2, "fp32": 4, "int8": 1}
 class Client:
     def __init__(self):
         self.rid = None
+
+
+def family_of(cfg: dict):
+    """``runners/_<family>.py``, by the configuration's ``family``."""
+    return harness.load_module("runners", f"_{cfg['family']}")
 
 
 def run(ctx) -> dict:
@@ -45,8 +51,11 @@ def run(ctx) -> dict:
     spans, checks = ctx["spans"], ctx["checks"]
     ctx["phase"]("imports done, device checked")
     eng = job["engine"]
-    params = jax.jit(lambda: _llama.program_params(cfg, seed))()
-    engine = ServeEngine(_llama.bundle_for(cfg, ctx["cell"]["config"]),
+    family = family_of(cfg)
+    # the seed is an operand: a new seed compiles nothing
+    params = jax.jit(lambda key: family.program_params(cfg, key))(
+        family.weights.seed_key(seed))
+    engine = ServeEngine(family.bundle_for(cfg, ctx["cell"]["config"]),
                          params, **eng)
     del params
     ctx["phase"]("weights made, engine built")
@@ -59,6 +68,10 @@ def run(ctx) -> dict:
     refused = 0
     occupancy: list[float] = []
     decode_context: list[tuple] = []
+    routing_steps: list[tuple] = []   # (stamp, pairs held, experts touched)
+    # a routing family's counters, which its decode program fills with its
+    # tokens; every other family's stay at zero
+    routing = getattr(engine.programs, "routing", None) or {"steps": 0}
     tracing = False
 
     def submit_idle(now):
@@ -83,12 +96,12 @@ def run(ctx) -> dict:
         now = time.perf_counter()
         submit_idle(now)
         calls = engine.programs.prefill_calls
+        routed = dict(routing)
         with spans.span("engine.step"):
             finished = engine.step()
         t0, t1 = spans.items["engine.step"][-1]
-        # the step again under the kind of program it ran: what a mix with
-        # prefill inside its window reads a prefill share from (a later PR
-        # adds the metric as a file; it may not edit this one)
+        # the step again under the kind of program it ran: a step that ran
+        # a prefill chunk (and its decode step after it), or decode alone
         kind = ("engine.step.prefill"
                 if engine.programs.prefill_calls > calls else "engine.step.decode")
         spans.items.setdefault(kind, []).append((t0, t1))
@@ -106,10 +119,11 @@ def run(ctx) -> dict:
                 del rec["client"]
                 done.append(rec)
             if sample_stats and len(decode_context) % 8 == 0:
-                # every eighth step: stats() exports the prefix cache's keys
-                # too and costs tens of ms, which a traced run would read as
-                # device idle time
-                occupancy.append(engine.stats()["active_slots"] / n_slots)
+                # every eighth step, from the scheduler itself: stats() also
+                # exports the prefix cache's keys (half a second at 2,048
+                # cached pages), which a traced run reads as device idle time
+                occupancy.append(len(engine.scheduler.active_indices())
+                                 / n_slots)
             # live context of the slots this step decoded for: the benchmark
             # knows it from what it sent and what came back
             ctx_tokens = n_dec = 0
@@ -119,6 +133,10 @@ def run(ctx) -> dict:
                     ctx_tokens += len(rec["prompt"]) + n_gen
                     n_dec += 1
             decode_context.append((t1, ctx_tokens, n_dec))
+            if routing["steps"] > routed["steps"]:
+                routing_steps.append((
+                    t1, routing["pairs_held"] - routed["pairs_held"],
+                    routing["experts_touched"] - routed["experts_touched"]))
         return t1
 
     # ---- warm-up and ramp: part of set-up -----------------------------------
@@ -127,11 +145,14 @@ def run(ctx) -> dict:
     # as soon as their first tokens agree; a match may end inside a page only
     # if that page is a full, committed page of the earlier prompt) is
     # compiled with the chunk and decode programs before the clients start.
+    # Four tokens each: the first decode step takes arrays built on the host
+    # (its slot has just grown a page), the next ones take the step's own
+    # device outputs back, which is a second signature of the decode program.
     page = eng["page_size"]
     rng = np.random.default_rng([int(seed), 0x7761726D])
     warm = rng.integers(0, cfg["vocab_size"], size=3 * page).tolist()
     for prompt in (warm, warm[: page + page // 2] + warm[::-1][: page]):
-        engine.submit(Request(prompt_ids=prompt, max_new_tokens=2,
+        engine.submit(Request(prompt_ids=prompt, max_new_tokens=4,
                               temperature=0.0, eos_id=None))
         while engine.has_work:
             engine.step()
@@ -145,6 +166,7 @@ def run(ctx) -> dict:
 
     # ---- the window --------------------------------------------------------
     stats0 = engine.stats()
+    routing0 = dict(routing)
     compiles_before = ctx["compiles"].snapshot()
     trace_s = min(ctx["seconds"], job.get("trace_seconds", 8.0))
     trace_window = None
@@ -167,9 +189,9 @@ def run(ctx) -> dict:
         jax.profiler.stop_trace()
         trace_window = (t0, t1)
     stats1 = engine.stats()
+    routing1 = dict(routing)
     compiles_after = ctx["compiles"].snapshot()
-    from benchmarks.harness import memory_peak_bytes
-    peak = memory_peak_bytes(ctx["devices"])
+    peak = harness.memory_peak_bytes(ctx["devices"])
 
     in_window = [r for r in done[n_ramp:] if r["t_done"] <= t1]
     completed = [r for r in in_window if "tokens" in r]
@@ -195,6 +217,7 @@ def run(ctx) -> dict:
             censored += 1
     gaps = [1e3 * g for g in tap.gaps(t0, t1)]
     in_steps = [row for row in decode_context if t0 < row[0] <= t1]
+    ends = [r["t_done"] for r in completed]
     e2e = {"setup_s": setup_s,
            "serve.out_tokens_per_s": out_tokens / (t1 - t0)}
     if gaps:
@@ -212,6 +235,9 @@ def run(ctx) -> dict:
         "mean_live_context": (sum(c for t, c, n in in_steps)
                               / max(1, sum(n for t, c, n in in_steps))),
         "ramp_requests": n_ramp,
+        "most_completions_in_one_step": max(map(ends.count, ends), default=0),
+        "prefill_step_share_pct": 100.0 * sum(spans.durations_ms(
+            "engine.step.prefill", t0, t1)) / 1e3 / (t1 - t0),
         "preemptions": stats1["preemptions"] - stats0["preemptions"],
         "prefill_calls": stats1["prefill_calls"] - stats0["prefill_calls"],
         "prefix_hits": stats1.get("prefix_hits", 0) - stats0.get("prefix_hits", 0),
@@ -231,6 +257,22 @@ def run(ctx) -> dict:
         counters["ttft_samples"] = len(ttfts)
     if occupancy:
         counters["batch_occupancy_pct"] = 100.0 * sum(occupancy) / len(occupancy)
+    steps = routing1["steps"] - routing0["steps"]
+    if steps:
+        counters["routing_steps"] = routing_steps
+        routed_pairs = routing1["pairs_routed"] - routing0["pairs_routed"]
+        held_pairs = routing1["pairs_held"] - routing0["pairs_held"]
+        touched = routing1["experts_touched"] - routing0["experts_touched"]
+        counters["expert_pairs_held_pct"] = 100.0 * held_pairs / routed_pairs
+        counters["experts_touched_pct"] = 100.0 * touched / (
+            steps * cfg["num_hidden_layers"] * cfg["n_routed_experts"])
+        print(json.dumps({"routing": {
+            "decode_steps": steps, "pairs_routed_a_step": routed_pairs / steps,
+            "pairs_held_a_step": held_pairs / steps,
+            "experts_touched_a_step_a_layer":
+                touched / steps / cfg["num_hidden_layers"],
+            "fullest_expert_pairs": routing1["fullest_expert_pairs"]}}),
+            flush=True)
 
     # ---- free the engine, then the plain reference -------------------------
     del engine, stats0, stats1, partial
@@ -272,10 +314,11 @@ def reference_weights(ctx):
     import jax
 
     cfg = ctx["config"]
+    weights = family_of(cfg).weights
     key = weights.seed_key(ctx["seed"])
-    top = jax.jit(lambda: weights.top_weights(cfg, key))()
-    layer = jax.jit(lambda l: weights.layer_weights(cfg, key, l))
-    return top, lambda l: layer(np.uint32(l))
+    top = jax.jit(lambda key: weights.top_weights(cfg, key))(key)
+    layer = jax.jit(lambda key, l: weights.layer_weights(cfg, key, l))
+    return top, lambda l: layer(key, np.uint32(l))
 
 
 def sample_gaps(ctx, sample, control_mode=None) -> dict:
@@ -286,6 +329,7 @@ def sample_gaps(ctx, sample, control_mode=None) -> dict:
     widest is the number that catches one bad token; the mean is the steady
     one, which the lower precision moves most."""
     cfg = ctx["config"]
+    ref = family_of(cfg).reference
     top, layer_fn = reference_weights(ctx)
     widest, total, n_tokens, where = 0.0, 0.0, 0, ""
     for r in sample:

@@ -103,9 +103,10 @@ def build(ctx):
     del lowered
     ctx["phase"]("step compiled or loaded")
 
-    make = jax.jit(lambda: _llama.program_params(cfg, seed),
+    # the seed is an operand: a new seed compiles nothing
+    make = jax.jit(lambda key: _llama.program_params(cfg, key),
                    out_shardings=trainer.param_shardings)
-    params = make()
+    params = make(weights.seed_key(seed))
     state = trainer.init_state_from_params(params, seed & 0x7FFFFFFF)
     del params
     ctx["phase"]("weights made, state placed")
@@ -140,10 +141,10 @@ def run(ctx) -> dict:
     # ---- the first steps, through the window's own call and feed ----------
     n_check = job["check"]["steps"]
     norms = jax.jit(lambda t: ref.leaf_norms(_llama.from_program(t)))
-    delta = jax.jit(lambda p: ref.leaf_norms(jax.tree.map(
+    delta = jax.jit(lambda p, key: ref.leaf_norms(jax.tree.map(
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
         _llama.from_program(p),
-        _llama.from_program(_llama.program_params(cfg, seed)))))
+        _llama.from_program(_llama.program_params(cfg, key)))))
     seen, prog = [], {"losses": []}
     for i in range(n_check):
         with spans.span("data"):
@@ -156,7 +157,8 @@ def run(ctx) -> dict:
             b1 = job["optimizer"]["b1"]
             mu = jax.device_get(norms(_find_mu(state.opt_state)))
             prog["grad_norms"] = {k: v / (1.0 - b1) for k, v in mu.items()}
-    prog["delta_norms"] = jax.device_get(delta(state.params))
+    prog["delta_norms"] = jax.device_get(delta(state.params,
+                                               weights.seed_key(seed)))
     ctx["phase"]("first steps driven and read")
     for rows in seen:   # rows that all differ
         assert len({r.tobytes() for r in rows}) == len(rows)
@@ -286,12 +288,13 @@ def reference_steps(ctx, seen, mode="highest") -> dict:
         return NamedSharding(mesh, P())
 
     import jax.numpy as jnp
-    make = lambda: weights.stacked_weights(cfg, weights.seed_key(seed),
-                                           jnp.float32)
-    shardings = jax.tree.map(lambda s: spread(s.shape), jax.eval_shape(make))
+    key = weights.seed_key(seed)
+    make = lambda key: weights.stacked_weights(cfg, key, jnp.float32)
+    shardings = jax.tree.map(lambda s: spread(s.shape),
+                             jax.eval_shape(make, key))
     place = lambda fn: jax.jit(fn, out_shardings=shardings)
     rows = job["check"]["reference_rows_per_block"]
     batches = [jax.device_put(b, NamedSharding(mesh, P())) for b in seen]
     return ref.train_steps(
-        cfg, job["optimizer"], make, batches, rows, mode, place=place,
+        cfg, job["optimizer"], make, key, batches, rows, mode, place=place,
         moments_on_host=job["check"].get("reference_moments_on_host", False))

@@ -4,7 +4,8 @@ PR's per-layer device metrics go through.
 ``jax.profiler.ProfileData`` reads the file with nothing but JAX. A TPU trace
 has one plane per chip (``/device:TPU:<n>``) whose ``XLA Ops`` line holds one
 event per executed HLO operation (nested where an operation such as a
-``while`` contains others), and host planes whose lines are threads; the
+``while`` contains others) and whose ``XLA Modules`` line holds one event per
+executed program (``jit_serve_decode(<id>)``), and host planes whose lines are threads; the
 benchmark's own spans are ``TraceAnnotation`` events named ``bench.<span>`` on
 a host thread, on the same clock.
 
@@ -13,11 +14,13 @@ hand-made trace tests them.
 """
 from __future__ import annotations
 
+import bisect
 import re
 from pathlib import Path
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 BENCH_PREFIX = "bench."
 COLLECTIVE = re.compile(
     r"(all-gather|all-reduce|reduce-scatter|collective-permute|all-to-all)")
@@ -151,6 +154,54 @@ def kernel_events(ops, pattern: str, lo=None, hi=None) -> list:
             if rx.search(n) and (lo is None or (a >= lo and b <= hi))]
 
 
+def spans_inside(trace: dict, name: str) -> int:
+    """How many of the benchmark's ``name`` spans lie inside the reduced
+    trace's window: the steps a per-step number is divided by."""
+    return sum(1 for n, a, b in trace["host_spans"]
+               if n == name and a >= trace["lo_ns"] and b <= trace["hi_ns"])
+
+
+def program_runs(modules, program: str, lo, hi) -> list:
+    """``(start, end)`` of each execution of the jitted program ``program``
+    (``serve_decode`` is the module ``jit_serve_decode(<id>)``) inside
+    [lo, hi], sorted: what tells a decode step's events from a prefill
+    chunk's, which run the same kernels."""
+    prefix = f"jit_{program}("
+    return sorted((a, b) for name, a, b in modules
+                  if name.startswith(prefix) and a >= lo and b <= hi)
+
+
+def under(runs):
+    """A test of ``(start, end)``: inside one of the sorted disjoint ``runs``."""
+    starts = [a for a, _ in runs]
+
+    def inside(a, b):
+        i = bisect.bisect_right(starts, a) - 1
+        return i >= 0 and b <= runs[i][1]
+    return inside
+
+
+def program_ops(trace: dict, program: str) -> tuple:
+    """The reduced trace's op events that ran under the jitted program
+    ``program``, per device, and how often it ran (a device)."""
+    lo, hi = trace["lo_ns"], trace["hi_ns"]
+    ops, n_runs = {}, 0
+    for d, events in trace["device_ops"].items():
+        runs = program_runs(trace["device_modules"].get(d, ()), program, lo, hi)
+        inside = under(runs)
+        ops[d] = [e for e in events if inside(e[1], e[2])]
+        n_runs += len(runs)
+    return ops, n_runs // max(1, len(ops))
+
+
+def programs_run(trace: dict) -> set:
+    """Names of the jitted programs the traced devices ran (``jit_`` and the
+    id taken off)."""
+    return {m.group(1) for mods in trace["device_modules"].values()
+            for name, _, _ in mods
+            if (m := re.match(r"jit_(.+)\(\d+\)$", name))}
+
+
 def short_name(name: str) -> str:
     """An HLO event's name cut to what tells operations apart: the result's
     name and the opcode or custom-call target (the trace prints the whole
@@ -164,18 +215,21 @@ def short_name(name: str) -> str:
 
 # ---- reading a trace -------------------------------------------------------
 def read_planes(path: Path):
-    """``(device_ops, host_spans)``: per device index the op events, and the
-    benchmark's own spans from the host planes."""
+    """``(device_ops, host_spans, device_modules)``: per device index the op
+    events, the benchmark's own spans from the host planes, and per device
+    index the program executions."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(str(path))
-    device_ops, host_spans = {}, []
+    device_ops, host_spans, device_modules = {}, [], {}
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
             for line in plane.lines:
-                if line.name == OPS_LINE:
-                    device_ops[int(m.group(1))] = [
+                into = {OPS_LINE: device_ops,
+                        MODULES_LINE: device_modules}.get(line.name)
+                if into is not None:
+                    into[int(m.group(1))] = [
                         (e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
                         for e in line.events]
         elif plane.name.startswith("/host:"):
@@ -185,7 +239,7 @@ def read_planes(path: Path):
                         host_spans.append(
                             (e.name[len(BENCH_PREFIX):], int(e.start_ns),
                              int(e.start_ns + e.duration_ns)))
-    return device_ops, host_spans
+    return device_ops, host_spans, device_modules
 
 
 def find_xplane(trace_dir: Path):
@@ -193,7 +247,8 @@ def find_xplane(trace_dir: Path):
     return found[-1] if found else None
 
 
-def reduce_events(device_ops: dict, host_spans: list, n_devices: int) -> dict | None:
+def reduce_events(device_ops: dict, host_spans: list, n_devices: int,
+                  device_modules: dict | None = None) -> dict | None:
     """All the device numbers of one traced window. The window runs from the
     first benchmark span's start to the last one's end (the profiler's clock);
     without spans, from the first device event to the last."""
@@ -235,6 +290,8 @@ def reduce_events(device_ops: dict, host_spans: list, n_devices: int) -> dict | 
         "top_gaps": [[n, s] for n, s in sorted(gaps_by_span.items(),
                                                key=lambda kv: -kv[1])],
         "device_ops": {d: device_ops[d] for d in used},
+        "device_modules": {d: (device_modules or {}).get(d, [])
+                           for d in used},
         "host_spans": host_spans,
     }
 
@@ -243,5 +300,5 @@ def reduce_dir(trace_dir: Path, n_devices: int) -> dict | None:
     path = find_xplane(trace_dir)
     if path is None:
         return None
-    device_ops, host_spans = read_planes(path)
-    return reduce_events(device_ops, host_spans, n_devices)
+    device_ops, host_spans, device_modules = read_planes(path)
+    return reduce_events(device_ops, host_spans, n_devices, device_modules)

@@ -65,10 +65,21 @@ def length_pairs(traffic: dict, seed: int, cycle: int) -> list:
 class RequestStream:
     """An endless stream of (prompt_ids, output_len), cycle after cycle of the
     mix's set of lengths. Prompts are uniform tokens drawn from the seed and
-    share nothing."""
+    share nothing.
+
+    ``"first_output_len": "staggered"`` starts a closed loop out of lock-step:
+    the first reply of client ``i`` of ``n`` (the stream's first ``n``
+    requests) is ``(i + 1) / n`` of its drawn length, every later reply whole.
+    Clients that start together on equal sizes would otherwise end together,
+    round after round; with it one reply ends every ``length / n`` decode
+    steps, the same for every seed (it fixes a phase, not an order)."""
 
     def __init__(self, traffic: dict, vocab: int, seed: int):
         self.traffic, self.vocab, self.seed = traffic, vocab, int(seed)
+        first = traffic.get("first_output_len", "whole")
+        if first not in ("whole", "staggered"):
+            raise ValueError(f"first_output_len {first!r}: whole or staggered")
+        self.stagger_over = traffic["clients"] if first == "staggered" else 0
         self.rng = np.random.default_rng([self.seed, 0x73727665])
         self.cycle, self.pending = 0, []
         self.issued = 0
@@ -82,6 +93,8 @@ class RequestStream:
             self.cycle += 1
         n_prompt, n_out = self.pending.pop(0)
         self.issued += 1
+        if self.issued <= self.stagger_over:
+            n_out = max(1, n_out * self.issued // self.stagger_over)
         return self.rng.integers(0, self.vocab, size=n_prompt).tolist(), n_out
 
 

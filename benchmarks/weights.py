@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 MATRIX_STD = 0.02
 NORM_JITTER = 0.1
@@ -24,10 +25,13 @@ DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 _SQRT3 = 3.0 ** 0.5
 
 
-def seed_key(seed: int) -> tuple:
-    """``--seed`` may exceed 32 signed bits: its low and high 32-bit words."""
+def seed_key(seed: int) -> np.ndarray:
+    """``--seed`` may exceed 32 signed bits: its low and high 32-bit words,
+    as a ``uint32[2]`` array. Hand it to a jitted program as an OPERAND
+    (``jax.jit(lambda key: ...)(seed_key(seed))``), never through a closure:
+    a seed baked in as a constant compiles the program again for every seed."""
     seed = int(seed)
-    return (seed & 0xFFFFFFFF, (seed >> 32) & 0xFFFFFFFF)
+    return np.asarray([seed & 0xFFFFFFFF, (seed >> 32) & 0xFFFFFFFF], np.uint32)
 
 
 def _fmix32(x):

@@ -24,6 +24,7 @@ DEBUG = Path(__file__).resolve().parent / "debug"
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 TRAIN, SERVE = "debug-qwen3.train.debug", "debug-olmo2.serve.debug"
+STAGGERED = "debug-olmo2.serve.debug-staggered"
 
 
 # ---- the data files ---------------------------------------------------------
@@ -140,7 +141,7 @@ def test_traffic_repeats_for_a_seed_and_differs_across_seeds():
     assert min(lens) >= 18 and max(lens) <= 80
     fixed = harness.load_json(ROOT / "benchmarks" / "traffic" / "serve.decode16.json")
     assert {(len(p), n) for p, n in (next(generate.RequestStream(fixed, 1000, s))
-                                     for s in (5, 6))} == {(512, 768)}
+                                     for s in (5, 6))} == {(512, 48)}
     train = harness.load_json(ROOT / "benchmarks" / "traffic" / "train.seq2048.json")
     small = dict(train, sequences=8, seq_len=64)
     d1, d2 = (generate.train_dataset(small, 5000, s) for s in (7, 7))
@@ -148,6 +149,28 @@ def test_traffic_repeats_for_a_seed_and_differs_across_seeds():
     assert (generate.train_dataset(small, 5000, 8) != d1).any()
     assert generate.poisson_arrivals(5.0, 10.0, 3) == generate.poisson_arrivals(5.0, 10.0, 3)
     assert generate.percentile([1, 2, 3, 4, 5, 6, 7, 8, 9, 10], 0.9) == 9.0
+
+
+@pytest.mark.parametrize("seed", [5, 2**31 + 6])
+def test_staggered_first_replies_are_the_same_for_every_seed(seed):
+    """``first_output_len: staggered``: client i of n's FIRST reply is
+    (i + 1) / n of the mix's length, every later reply whole, whatever the
+    seed; a mix without the parameter behaves as before."""
+    mix = harness.load_json(ROOT / "benchmarks" / "traffic" / "serve.decode16.json")
+    assert mix["first_output_len"] == "staggered" and mix["clients"] == 16
+    stream = generate.RequestStream(mix, 1000, seed)
+    reqs = [next(stream) for _ in range(48)]
+    assert [n for _, n in reqs[:16]] == list(range(48, 769, 48))
+    assert {n for _, n in reqs[16:]} == {768}
+    assert {len(p) for p, _ in reqs} == {512}
+    plain = {k: v for k, v in mix.items() if k != "first_output_len"}
+    whole = generate.RequestStream(plain, 1000, seed)
+    assert [next(whole)[1] for _ in range(20)] == [768] * 20
+    # the parameter changes lengths only: the token ids are the seed's
+    again = generate.RequestStream(plain, 1000, seed)
+    assert [p for p, _ in reqs[:3]] == [next(again)[0] for _ in range(3)]
+    with pytest.raises(ValueError):
+        generate.RequestStream(dict(mix, first_output_len="random"), 1000, 1)
 
 
 # ---- the runners, end to end at debug width ----------------------------------
@@ -161,7 +184,8 @@ def make_root(tmp: Path) -> Path:
     for d in ("configs", "traffic", "workloads"):
         shutil.copytree(DEBUG / d, bench / d)
     doc = json.loads(json.dumps(BENCH))
-    cells = [(TRAIN, "debug-qwen3", "train.debug"), (SERVE, "debug-olmo2", "serve.debug")]
+    cells = [(TRAIN, "debug-qwen3", "train.debug"), (SERVE, "debug-olmo2", "serve.debug"),
+             (STAGGERED, "debug-olmo2", "serve.debug-staggered")]
     doc["configs"] = [{"name": c, "source": "debug", "reduced": [], "why": "debug",
                        "file": f"benchmarks/configs/{c}.json"}
                       for c in ("debug-qwen3", "debug-olmo2")]
@@ -222,6 +246,53 @@ def test_traced_run_reports_per_layer_metrics(cell, debug_root):
                    for n in names)
     assert ("train.step_ms_p50" in names) == (cell == TRAIN)
     assert ("serve.step_ms_p50" in names) == (cell == SERVE)
+
+
+def test_staggered_mix_completes_in_distinct_steps_with_prefill_in_the_window(
+        debug_root, capsys):
+    """The shape of ``serve.decode16`` at debug width: replies end one after
+    another, never two in one engine step, and each new prompt's chunk runs
+    inside the window."""
+    result = run(debug_root, STAGGERED, trace=True)
+    assert result["correct"] is True and result["failed"] == 0
+    lines = capsys.readouterr().out.splitlines()
+    window = next(json.loads(l)["window"] for l in lines if l.startswith('{"window"'))
+    assert window["completed"] >= 4 and window["most_completions_in_one_step"] == 1
+    assert window["prefill_calls"] >= window["completed"] - 1 > 0
+    assert 0 < window["prefill_step_share_pct"] < 50
+    assert window["preemptions"] == 0 and window["refused"] == 0
+    spans = result["ctx"]["spans"].items
+    t0, t1 = result["ctx"]["window"]
+    inside = lambda name: [s for s in spans[name] if s[0] >= t0 and s[1] <= t1]
+    assert len(inside("engine.step.prefill")) == window["prefill_calls"]
+    assert len(inside("engine.step.decode")) + len(inside("engine.step.prefill")) \
+        == len(inside("engine.step"))
+    # the step metric is read over the decode steps alone
+    from benchmarks.traffic.generate import percentile
+    assert result["metrics"]["serve.step_ms_p50"]["value"] == percentile(
+        [1e3 * (b - a) for a, b in inside("engine.step.decode")], 0.5)
+
+
+@pytest.mark.parametrize("config,adapter,weights,reference", [
+    ("debug-olmo2", "_llama", "benchmarks.weights", "benchmarks.reference.decoder"),
+    ("debug-mla-moe", "_mla_moe", "benchmarks.weights_mla_moe",
+     "benchmarks.reference.mla_moe")])
+def test_the_one_serve_runner_finds_the_family_by_name(config, adapter, weights,
+                                                      reference):
+    from benchmarks.runners import serve
+
+    cfg = harness.load_json(DEBUG / "configs" / f"{config}.json")
+    family = serve.family_of(cfg)
+    assert family.__name__ == f"benchmarks.runners.{adapter}"
+    assert family.weights.__name__ == weights
+    assert family.reference.__name__ == reference
+    assert callable(family.bundle_for) and callable(family.program_params)
+    assert callable(family.reference.served_token_gaps)
+    assert not (ROOT / "benchmarks" / "runners" / "serve_mla_moe.py").exists()
+    for job in (ROOT / "benchmarks" / "workloads").glob("*.serve.*.json"):
+        assert harness.load_json(job)["runner"] == "serve"
+    with pytest.raises(ModuleNotFoundError):
+        serve.family_of({"family": "no_such_family"})
 
 
 # ---- the timed path broken underneath ----------------------------------------

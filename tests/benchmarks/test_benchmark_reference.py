@@ -125,10 +125,11 @@ def test_train_steps_follow_adamw():
     cfg = load("debug-qwen3")
     opt = {"lr": 1e-3, "t_max": 1000, "eta_min_ratio": 0.01,
            "weight_decay": 0.01, "b1": 0.9, "b2": 0.999, "eps": 1e-8}
-    make = lambda: weights.stacked_weights(cfg, weights.seed_key(1), jnp.float32)
+    make = lambda key: weights.stacked_weights(cfg, key, jnp.float32)
     batches = [jnp.asarray(tokens_for(cfg, seed=s, batch=4, seq=32))
                for s in (1, 2)]
-    out = ref.train_steps(cfg, opt, make, batches, rows_per_block=2)
+    out = ref.train_steps(cfg, opt, make, weights.seed_key(1), batches,
+                          rows_per_block=2)
     assert len(out["losses"]) == 2 and all(np.isfinite(out["losses"]))
     assert all(np.all(v > 0) for v in out["grad_norms"].values())
     gate = out["delta_norms"]["layers/gate"]
@@ -151,3 +152,29 @@ def test_weights_same_alone_stacked_and_for_large_seeds():
     assert abs(gate.std() - weights.MATRIX_STD) < 1e-3 and abs(gate.mean()) < 1e-3
     assert weights.num_params(cfg) == sum(
         int(np.prod(x.shape)) for x in jax.tree.leaves(stacked))
+
+
+
+@pytest.mark.parametrize("config", ["debug-olmo2", "debug-qwen3", "debug-mla-moe"])
+def test_a_seed_makes_the_same_numbers_as_before_it_became_an_operand(config):
+    """``debug/weights_digest.json`` was taken at the parent of the PR that
+    made the seed an operand of the weight programs (it was a constant baked
+    into them): every leaf's bytes are what they were, and a second seed runs
+    the program compiled for the first."""
+    import hashlib
+
+    from benchmarks.runners import serve
+
+    stored = json.loads((DEBUG.parent / "weights_digest.json").read_text())
+    cfg = load(config)
+    mod = serve.family_of(cfg).weights
+    make = jax.jit(lambda key: mod.stacked_weights(cfg, key))
+    tree = make(mod.seed_key(stored["seed"]))
+    got = {f"{group}/{leaf}": hashlib.sha256(np.asarray(
+        x.astype(jnp.float32)).tobytes()).hexdigest()[:16]
+        for group in ("top", "layers") for leaf, x in tree[group].items()}
+    assert got == stored["configs"][config]
+    other = make(mod.seed_key(stored["seed"] + 1))
+    assert make._cache_size() == 1
+    assert not np.array_equal(np.asarray(other["top"]["embed"]),
+                              np.asarray(tree["top"]["embed"]))

@@ -2,7 +2,10 @@
 ``readers/program_span.py``, ``readers/_xplane.py``): on hand-made event
 lists, and on recorded v5e traces that carry the names, two training steps of
 ``qwen3-0.6b.train.seq2048`` and three decode steps of
-``olmo2-7b-l12.serve.decode16`` (``benchmarks/testdata/*_named.xplane.pb``)."""
+``olmo2-7b-l12.serve.decode16`` (``benchmarks/testdata/*_named.xplane.pb``),
+and four engine steps of that cell of which the second also runs a prefill
+chunk (``serve_chunk_and_decode_steps.xplane.pb``)."""
+import json
 import sys
 from pathlib import Path
 
@@ -13,11 +16,13 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from benchmarks import trace_reduce  # noqa: E402
-from benchmarks.readers import _xplane, program_span, scope_time  # noqa: E402
+from benchmarks.readers import (_xplane, path_component, program_span,  # noqa: E402
+                                scope_time)
 
 TESTDATA = ROOT / "benchmarks" / "testdata"
 TRAIN_NAMED = TESTDATA / "train_two_steps_named.xplane.pb"
 SERVE_NAMED = TESTDATA / "serve_three_steps_named.xplane.pb"
+SERVE_CHUNKED = TESTDATA / "serve_chunk_and_decode_steps.xplane.pb"
 
 
 # ---- a scope inside a path ---------------------------------------------------
@@ -87,6 +92,73 @@ def test_exposed_collectives_go_to_their_scope():
         "mlp": 60, "unscoped": 60}
 
 
+# ---- a prefill chunk inside the window ------------------------------------------
+
+MS = 1_000_000
+CHUNKED_PATHS = {
+    "%k": "jit(serve_decode)/layers/while/body/attn/attend/paged_attend/pallas_call:",
+    "%m": "jit(serve_decode)/layers/while/body/mlp/dot_general:"}
+
+
+def chunked_trace():
+    """One engine step that runs a prefill chunk (the same kernel and scopes
+    under ``jit_serve_chunk_t512``) and its decode step, then two decode
+    steps; a fourth decode program lies past the window."""
+    modules = [("jit_serve_chunk_t512(7)", 0, 10 * MS),
+               ("jit_serve_decode(9)", 10 * MS, 14 * MS),
+               ("jit_serve_decode(9)", 20 * MS, 24 * MS),
+               ("jit_serve_decode(9)", 30 * MS, 34 * MS),
+               ("jit_serve_decode(9)", 50 * MS, 54 * MS)]
+    ops = [("%k", 1 * MS, 6 * MS), ("%m", 6 * MS, 9 * MS),
+           ("%k", 10 * MS, 12 * MS), ("%m", 12 * MS, 14 * MS),
+           ("%k", 20 * MS, 22 * MS), ("%m", 22 * MS, 24 * MS),
+           ("%k", 30 * MS, 33 * MS), ("%m", 33 * MS, 34 * MS),
+           ("%k", 50 * MS, 54 * MS)]
+    return {"lo_ns": 0, "hi_ns": 40 * MS, "device_ops": {0: ops},
+            "device_modules": {0: modules},
+            "host_spans": [("engine.step", 0, 15 * MS),
+                           ("engine.step", 19 * MS, 25 * MS),
+                           ("engine.step", 29 * MS, 35 * MS)]}
+
+
+def test_only_the_events_under_the_named_program_count(monkeypatch, capsys):
+    trace = chunked_trace()
+    assert trace_reduce.programs_run(trace) == {"serve_decode", "serve_chunk_t512"}
+    ops, runs = trace_reduce.program_ops(trace, "serve_decode")
+    assert runs == 3 and len(ops[0]) == 6
+    assert trace_reduce.program_ops(trace, "serve_chunk_t512")[1] == 1
+    assert trace_reduce.program_ops(trace, "serve_verify")[1] == 0
+    for reader in (scope_time, path_component):
+        monkeypatch.setattr(reader._xplane, "traced", lambda ctx: (trace, "x"))
+    monkeypatch.setattr(scope_time, "op_paths_of", lambda p: CHUNKED_PATHS)
+    read = lambda scope, **kw: scope_time.read({}, {
+        "scope": scope, "step_span": "engine.step", **kw})
+    # decode steps alone, divided by their number ...
+    assert read("attend", program="serve_decode") == pytest.approx(7 / 3)
+    assert read("mlp", program="serve_decode") == pytest.approx(5 / 3)
+    # ... where every event over the engine steps would read the chunk too
+    assert read("attend") == pytest.approx(12 / 3)
+    out = capsys.readouterr().out
+    chunk = next(json.loads(l) for l in out.splitlines() if '"program"' in l)
+    assert chunk["program"] == "serve_chunk_t512"
+    assert chunk["device_ms_by_scope"]["ms_per_step"] == {"attend": 5.0, "mlp": 3.0}
+    cfg = json.loads((ROOT / "benchmarks" / "configs" / "olmo2-7b-l12.json").read_text())
+    peak = json.loads((ROOT / "benchmarks" / "peaks.json").read_text())["TPU v5 lite"]
+    ctx = {"config": cfg, "peak": peak, "trace": trace, "trace_window": (0.0, 1.0),
+           "counters": {"kv_bytes": 2, "decode_context": [
+               (0.2, 16 * 900, 16), (0.4, 16 * 901, 16), (0.6, 16 * 902, 16),
+               (1.5, 16 * 903, 16)]}}
+    spec = json.loads((ROOT / "benchmarks" / "metrics"
+                       / "paged_attend_roofline.json").read_text())
+    assert spec["params"]["program"] == "serve_decode"
+    from benchmarks import flops
+    least = flops.least_time(flops.paged_attend(cfg, 16 * 2703, 48, 2), peak)[0]
+    assert path_component.read(ctx, spec["params"]) == pytest.approx(100 * least / 7e-3)
+    step = path_component.read(ctx, {"component": "paged_attend", "as": "ms_per_step",
+                                     "program": "serve_decode"})
+    assert step == pytest.approx(7 / 3)
+
+
 # ---- the program's host spans -------------------------------------------------
 
 def spans_of_two_steps():
@@ -116,6 +188,11 @@ def test_host_and_schedule_time_per_step():
     # (100 - 70) and (80 - 40) ns of host work; (2 + 4 + 8) and (10 + 4) ns
     assert program_span.host_ms_per_step(steps) == pytest.approx(35e-6)
     assert program_span.schedule_ms_per_step(steps) == pytest.approx(14e-6)
+    # the metrics are read over decode steps: the second step ran a prefill
+    decode = program_span.decode_steps(steps)
+    assert [s[4]["seq"] for s, _ in decode] == [1]
+    assert program_span.host_ms_per_step(decode) == pytest.approx(30e-6)
+    assert program_span.schedule_ms_per_step(decode) == pytest.approx(14e-6)
 
 
 def test_idle_inside_steps_and_the_share_no_span_covers():
@@ -193,10 +270,19 @@ def test_recorded_training_steps_by_scope(tmp_path, capsys):
     # printed once, whatever the number of metrics read from it
     assert capsys.readouterr().out.count("device_ms_by_scope") == 1
     # the kernels by the names ops/ gave them
-    names = {trace_reduce.short_name(n).split(".")[0]
-             for n, _, _ in trace_reduce.kernel_events(
-                 ctx["trace"]["device_ops"][0], "tpu_custom_call")}
+    calls = trace_reduce.kernel_events(
+        ctx["trace"]["device_ops"][0], "tpu_custom_call",
+        ctx["trace"]["lo_ns"], ctx["trace"]["hi_ns"])
+    names = {trace_reduce.short_name(n).split(".")[0] for n, _, _ in calls}
     assert names == {"flash_fwd", "flash_dq", "flash_dkv"}
+    # flash_attention_roofline matches them by path component: the same
+    # events as every tpu_custom_call, while flash is the only kernel there
+    spec = json.loads((ROOT / "benchmarks" / "metrics"
+                       / "flash_attention_roofline.json").read_text())
+    got = path_component.component_seconds(
+        ctx["trace"]["device_ops"], scope_time.op_paths_of(TRAIN_NAMED),
+        spec["params"]["components"], ctx["trace"]["lo_ns"], ctx["trace"]["hi_ns"])
+    assert got == pytest.approx(sum(b - a for _, a, b in calls) / 1e9)
 
 
 def test_recorded_decode_steps_by_scope_and_span(tmp_path, capsys):
@@ -207,6 +293,12 @@ def test_recorded_decode_steps_by_scope_and_span(tmp_path, capsys):
                                   read("unscoped"))
     table = ctx["scope_table"]
     assert table["steps"] == 3
+    # a window of decode steps alone reads the same under its program's name
+    named = dict(ctx)
+    del named["scope_table"]
+    assert scope_time.read(named, {"scope": "attend", "step_span": "engine.step",
+                                   "program": "serve_decode"}) == attend
+    assert named["scope_table"] == table
     busy_ms = 1e3 * ctx["trace"]["busy_s"] / 3
     assert sum(table["ms_per_step"].values()) == pytest.approx(busy_ms,
                                                                rel=0.01)
@@ -224,6 +316,56 @@ def test_recorded_decode_steps_by_scope_and_span(tmp_path, capsys):
                                      "serve.wait", "serve.book"}
     assert all(s[4]["program"] == "serve_decode" for s in spans
                if s[0] == "serve.dispatch")
+
+
+def test_recorded_chunk_step_is_not_read_as_decode_time(tmp_path, capsys):
+    """Four engine steps of ``decode16`` on the v5e; the second admits a new
+    512-token prompt, so it runs ``jit_serve_chunk_t512`` (22.8 ms, the same
+    ``paged_attend`` kernel at its chunk tile) before its decode program."""
+    ctx = ctx_for(SERVE_CHUNKED, tmp_path)
+    trace = ctx["trace"]
+    lo, hi = trace["lo_ns"], trace["hi_ns"]
+    assert {"serve_decode", "serve_chunk_t512"} <= trace_reduce.programs_run(trace)
+    steps = sum(1 for n, a, b in trace["host_spans"] if n == "engine.step")
+    decode_ops, decode_runs = trace_reduce.program_ops(trace, "serve_decode")
+    chunk_ops, chunk_runs = trace_reduce.program_ops(trace, "serve_chunk_t512")
+    assert (steps, decode_runs, chunk_runs) == (4, 4, 1)
+    # the kernel: every tpu_custom_call of the window is a paged_attend, and
+    # only the decode programs' count for the decode steps' roofline
+    paths = scope_time.op_paths_of(SERVE_CHUNKED)
+    seconds = lambda ops: path_component.component_seconds(
+        ops, paths, "paged_attend", lo, hi)
+    calls = trace_reduce.kernel_events(trace["device_ops"][0],
+                                       "tpu_custom_call", lo, hi)
+    assert seconds(trace["device_ops"]) == pytest.approx(
+        sum(b - a for _, a, b in calls) / 1e9)
+    assert seconds(decode_ops) + seconds(chunk_ops) == pytest.approx(
+        seconds(trace["device_ops"]))
+    assert 4.5e-3 < seconds(chunk_ops) < 5.0e-3
+    # by scope: decode steps alone, divided by their number
+    read = lambda c, scope, **kw: scope_time.read(c, {
+        "scope": scope, "step_span": "engine.step", **kw})
+    named = dict(ctx)
+    attend = read(named, "attend", program="serve_decode")
+    assert attend == pytest.approx(1e3 * seconds(decode_ops) / 4)
+    assert 2.4 < attend < 2.9 and named["scope_table"]["steps"] == 4
+    assert 10.5 < named["scope_table"]["busy_ms_per_step"] < 11.5
+    assert read(named, "kv_write", program="serve_decode") < 0.1
+    # ... where every event over the four engine steps reads the chunk too
+    assert read(dict(ctx), "attend") > attend + 1e3 * seconds(chunk_ops) / 4
+    assert read(dict(ctx), "kv_write") > 0.4
+    out = capsys.readouterr().out
+    chunk = next(json.loads(l) for l in out.splitlines()
+                 if '"program": "serve_chunk_t512"' in l)["device_ms_by_scope"]
+    assert chunk["steps"] == 1 and 22 < chunk["busy_ms_per_step"] < 24
+    # the host's time a step: the three steps that ran no prefill
+    spans = _xplane.program_spans(SERVE_CHUNKED)
+    all_steps = program_span.steps_with_children(spans, lo, hi)
+    decode = program_span.decode_steps(all_steps)
+    assert (len(all_steps), len(decode)) == (4, 3)
+    host = program_span.read(ctx, {"stat": "host_ms_per_step"})
+    assert host == pytest.approx(program_span.host_ms_per_step(decode))
+    assert 2 < host < 6 < program_span.host_ms_per_step(all_steps)
 
 
 def test_the_readers_know_the_programs_scopes():

@@ -98,7 +98,7 @@ def test_short_name():
 
 @pytest.fixture(scope="module")
 def recorded():
-    device_ops, host_spans = tr.read_planes(RECORDED)
+    device_ops, host_spans, _ = tr.read_planes(RECORDED)
     return device_ops, host_spans, tr.reduce_events(device_ops, host_spans, 1)
 
 
@@ -125,7 +125,7 @@ def test_recorded_trace_kernels(recorded):
 
 @pytest.fixture(scope="module")
 def recorded_fsdp4():
-    device_ops, host_spans = tr.read_planes(RECORDED_FSDP4)
+    device_ops, host_spans, _ = tr.read_planes(RECORDED_FSDP4)
     return device_ops, tr.reduce_events(device_ops, host_spans, 4)
 
 
