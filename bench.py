@@ -1469,9 +1469,11 @@ def run_decode_check(only: str = None) -> None:
             # prefill every arm pays identically (the TTFT/ITL split:
             # this is a DECODE rung, and ~20ms of shared prefill would
             # dilute the ratio it measures). The first step() carries
-            # admission + the 8 bucket prefills plus ONE decode
-            # dispatch; its prefill share is its duration minus the
-            # median steady dispatch, subtracted from the wall. GC is
+            # admission, and each of the first 8 steps ONE prompt's
+            # prefill chunk (a chunk a step is the budget) beside its
+            # decode dispatch; their prefill share is their duration
+            # minus the median steady dispatch, subtracted from the
+            # wall. GC is
             # parked during the timed window (a collection pause lands
             # on whichever arm is mid-rep — symmetric noise, but noise).
             engine.decode_steps = engine.decode_tokens = 0
@@ -1500,10 +1502,10 @@ def run_decode_check(only: str = None) -> None:
                 wall = time.perf_counter() - t0
             finally:
                 gc.enable()
-            steady = sorted(step_ts[1:])
-            prefill_s = max(0.0, step_ts[0]
-                            - (steady[len(steady) // 2] if steady
-                               else 0.0))
+            steady = sorted(step_ts[8:])
+            prefill_s = max(0.0, sum(step_ts[:8])
+                            - 8 * (steady[len(steady) // 2] if steady
+                                   else 0.0))
             decode_wall = max(wall - prefill_s, 1e-9)
             gaps = sorted(g for ts in tok_times.values()
                           for g in (b - a for a, b in zip(ts, ts[1:])))

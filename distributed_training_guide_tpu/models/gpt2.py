@@ -117,13 +117,12 @@ def _layernorm(x, p, eps):
 
 @jax.named_scope("attn")
 def _attn_sublayer(config, y, layer, positions, attn_impl,
-                   standard_layout=True, kv_cache=None, return_kv=False,
-                   attend_override=None):
+                   standard_layout=True, attend_override=None):
     """ln'd input -> fused QKV -> attention -> out proj (no residual, no
-    psum, no row bias — the block owns those). ``kv_cache``/``return_kv``/
-    ``attend_override`` follow llama.attention_sublayer's decode contract
-    (no rope here: gpt2's positions are the learned table applied at embed
-    time)."""
+    psum, no row bias — the block owns those). ``attend_override`` follows
+    llama.attention_sublayer's decode contract: with it the call returns
+    ``(out, aux)`` (no rope here: gpt2's positions are the learned table
+    applied at embed time)."""
     b, s, e = y.shape
     d = config.head_size
     cdt = config.dtype
@@ -143,26 +142,14 @@ def _attn_sublayer(config, y, layer, positions, attn_impl,
         attn, aux = attend_override(q, k, v, window=None, scale=None,
                                     softcap=None)
         out = attn.reshape(b, s, e_loc) @ layer["attn"]["wo"].astype(cdt)
-        return (out, aux) if return_kv else out
-    if kv_cache is not None:
-        ck, cv, pos = kv_cache
-        k = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, pos, 0, 0))
-        v = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, pos, 0, 0))
-        kv_pos = jnp.broadcast_to(jnp.arange(ck.shape[1])[None, :],
-                                  (b, ck.shape[1]))
-        attn = multihead_attention(q, k, v, causal=True, positions=positions,
-                                   kv_positions=kv_pos, impl="xla",
-                                   standard_layout=False)
-    elif callable(attn_impl):  # e.g. ring attention under context parallelism
+        return out, aux
+    if callable(attn_impl):  # e.g. ring attention under context parallelism
         attn = attn_impl(q, k, v, standard_layout=standard_layout)
     else:
         attn = multihead_attention(q, k, v, causal=True, positions=positions,
                                    kv_positions=positions, impl=attn_impl,
                                    standard_layout=standard_layout)
-    out = attn.reshape(b, s, e_loc) @ layer["attn"]["wo"].astype(cdt)
-    if return_kv:
-        return out, (k, v)
-    return out
+    return attn.reshape(b, s, e_loc) @ layer["attn"]["wo"].astype(cdt)
 
 
 @jax.named_scope("mlp")
@@ -282,72 +269,21 @@ def apply(
 
 
 # ---------------------------------------------------------------------------
-# KV-cached decode (models/sample.py fast path) — same functional-cache
-# contract as llama/neox. The simplest case of the three: no rope, the
-# learned position row is added at embed time, so cached k/v are exactly
-# the projections.
+# KV-cached decode: the serving engine's paged step (llama.paged_decode_step
+# contract). The simplest case of the families: no rope, the learned
+# position row is added at embed time, so cached k/v are exactly the
+# projections.
 # ---------------------------------------------------------------------------
 
-def init_cache(config: GPT2Config, batch: int, max_len: int) -> dict:
-    shape = (config.num_layers, batch, max_len, config.num_heads,
-             config.head_size)
-    return {"k": jnp.zeros(shape, config.dtype),
-            "v": jnp.zeros(shape, config.dtype)}
-
-
-def _cached_block(config, x, layer, positions, kv_cache, attend_override=None):
+def _cached_block(config, x, layer, positions, attend_override):
     cdt = config.dtype
     y = _layernorm(x, layer["ln1"], config.layer_norm_eps)
-    attn, kv = _attn_sublayer(config, y, layer, positions, "xla",
-                              kv_cache=kv_cache, return_kv=True,
-                              attend_override=attend_override)
+    attn, pools = _attn_sublayer(config, y, layer, positions, "xla",
+                                 attend_override=attend_override)
     x = x + attn + layer["attn"]["bo"].astype(cdt)
     y = _mlp_sublayer(config, _layernorm(x, layer["ln2"],
                                          config.layer_norm_eps), layer)
-    return x + y + layer["mlp"]["bo"].astype(cdt), kv
-
-
-def prefill(config: GPT2Config, params: dict, input_ids: jnp.ndarray,
-            cache: dict, last_pos=None):
-    """Causal forward over the prompt, filling cache[:, :, :prompt_len];
-    returns (logits [B, V] at ``last_pos``, default final position, and the
-    cache)."""
-    b, p = input_ids.shape
-    positions = jnp.broadcast_to(jnp.arange(p)[None, :], (b, p))
-    x = embed_tokens(config, params, input_ids, positions)
-
-    def body(x, inputs):
-        layer, ck, cv = inputs
-        x, (k, v) = _cached_block(config, x, layer, positions, None)
-        nk = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
-        nv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
-        return x, (nk, nv)
-
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"],
-                                         cache["k"], cache["v"]))
-    x_last = (x[:, -1:] if last_pos is None
-              else jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1))
-    return (lm_head_logits(config, params, x_last)[:, 0],
-            {"k": ks, "v": vs})
-
-
-def decode_step(config: GPT2Config, params: dict, token_ids: jnp.ndarray,
-                pos, cache: dict):
-    """One cached decode step (traced ``pos`` — one compile per generation);
-    returns (logits [B, V], updated cache)."""
-    b = token_ids.shape[0]
-    positions = jnp.broadcast_to(jnp.asarray(pos)[None, None], (b, 1))
-    x = embed_tokens(config, params, token_ids, positions)
-
-    def body(x, inputs):
-        layer, ck, cv = inputs
-        x, (nk, nv) = _cached_block(config, x, layer, positions,
-                                    (ck, cv, pos))
-        return x, (nk, nv)
-
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"],
-                                         cache["k"], cache["v"]))
-    return lm_head_logits(config, params, x)[:, -1], {"k": ks, "v": vs}
+    return x + y + layer["mlp"]["bo"].astype(cdt), pools
 
 
 def paged_decode_step(config: GPT2Config, params: dict,
@@ -359,8 +295,7 @@ def paged_decode_step(config: GPT2Config, params: dict,
     [S] index the learned position table at embed time; ``attend`` owns
     the page scatter + block-table attend; ``last_index`` selects the
     logits row for a padded chunk, ``all_logits=True`` keeps every row
-    (speculative verification). The block wiring is ``_cached_block``
-    — the same body the contiguous decode runs."""
+    (speculative verification). The block wiring is ``_cached_block``."""
     from .llama import (paged_logits_at, paged_positions,
                         scan_paged_layers)
 
@@ -372,8 +307,7 @@ def paged_decode_step(config: GPT2Config, params: dict,
             del window, scale, softcap  # no gpt2 attention extras
             return attend(q, k, v, *pools, i)
 
-        x, pools = _cached_block(config, x, layer, pos2d, None,
-                                 attend_override=override)
+        x, pools = _cached_block(config, x, layer, pos2d, override)
         return x, pools, None
 
     x, pools, _ = scan_paged_layers(body, x, params, cache)

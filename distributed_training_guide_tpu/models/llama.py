@@ -277,7 +277,6 @@ def attention_sublayer(config, x: jnp.ndarray, attn_params: dict, norm_scale,
                        positions: jnp.ndarray, attn_impl,
                        standard_layout: bool = True,
                        tp_axis: Optional[str] = None,
-                       kv_cache=None, return_kv: bool = False,
                        window_override=None, attend_override=None,
                        wmat_override=None):
     """norm -> rope'd GQA attention -> output proj (residual added by caller).
@@ -291,21 +290,13 @@ def attention_sublayer(config, x: jnp.ndarray, attn_params: dict, norm_scale,
     and the output projection's partial sum is psum'd explicitly, the
     megatron Rowwise reduction GSPMD otherwise inserts.
 
-    Decode support (the sampler's KV cache, ``models/sample.py``):
-    ``kv_cache=(cached_k, cached_v, pos)`` writes this call's rope'd k/v at
-    ``pos`` into the caches and attends q over the FULL cache (explicit
-    kv_positions keep the causal mask exact; zero rows beyond ``pos`` are
-    masked out by it). ``return_kv=True`` additionally returns the (rope'd,
-    possibly cache-merged) k/v. Both default off — the training path is
-    untouched.
-
-    ``attend_override`` (the serving engine's paged-KV hook): a callable
-    ``(q, k, v, *, window, scale, softcap) -> (attn, aux)`` replacing the
-    cache merge + attend entirely — it receives the rope'd/normed per-head
-    projections and the family-resolved attention extras, and whatever
-    functional cache state it updates rides back through ``aux`` (returned
-    in place of (k, v) when ``return_kv``). Mutually exclusive with
-    ``kv_cache``.
+    ``attend_override`` (the serving engine's paged-KV hook, the only
+    cached decode there is): a callable ``(q, k, v, *, window, scale,
+    softcap) -> (attn, aux)`` replacing the attend entirely — it receives
+    the rope'd/normed per-head projections and the family-resolved
+    attention extras, and whatever functional cache state it updates rides
+    back through ``aux``: the call then returns ``(out, aux)``. Default
+    None — the training path is untouched and returns ``out`` alone.
 
     ``wmat_override`` (the multi-LoRA serving hook): a callable
     ``(name, h, w) -> out`` replacing each target projection's
@@ -363,18 +354,8 @@ def attention_sublayer(config, x: jnp.ndarray, attn_params: dict, norm_scale,
         out = wmat_override("wo", attn.reshape(b, s, -1), attn_params["wo"])
         if tp_axis is not None:
             out = _psum(out, tp_axis)
-        return (out, aux) if return_kv else out
-    if kv_cache is not None:
-        ck, cv, pos = kv_cache
-        k = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, pos, 0, 0))
-        v = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, pos, 0, 0))
-        kv_pos = jnp.broadcast_to(jnp.arange(ck.shape[1])[None, :],
-                                  (b, ck.shape[1]))
-        attn = multihead_attention(q, k, v, causal=True, positions=positions,
-                                   kv_positions=kv_pos, impl="xla",
-                                   standard_layout=False, window=window,
-                                   scale=attn_scale, logit_softcap=softcap)
-    elif callable(attn_impl):  # e.g. ring attention under context parallelism
+        return out, aux
+    if callable(attn_impl):  # e.g. ring attention under context parallelism
         # Trainer-built wrappers (sharded flash, ring, ulysses) declare
         # accepts_window and take the per-call window — uniform bands come
         # through unchanged and traced per-layer schedules (Gemma-2) ride
@@ -395,8 +376,6 @@ def attention_sublayer(config, x: jnp.ndarray, attn_params: dict, norm_scale,
     out = wmat_override("wo", attn.reshape(b, s, -1), attn_params["wo"])
     if tp_axis is not None:
         out = _psum(out, tp_axis)
-    if return_kv:
-        return out, (k, v)
     return out
 
 
@@ -622,15 +601,17 @@ def apply(
 
 
 # ---------------------------------------------------------------------------
-# KV-cached decode (the sampler's fast path, models/sample.py). Single-device
-# utility: the cache is a functional pytree carried through lax.scan over
-# layers — each decode step is one compiled program touching one token.
-# Training paths are unaffected (separate entry points).
+# KV-cached decode: the serving engine's paged step (serve/engine.py;
+# models/sample.py --kv-cache runs that engine at one slot). The cache is
+# the engine's stacked page pools, a functional pytree carried through
+# lax.scan over layers (scan_paged_layers). A family serves by exporting
+# paged_decode_step alone. Training paths are unaffected (separate entry
+# points).
 # ---------------------------------------------------------------------------
 
 def _decode_residuals(config, x, layer, attn, wmat_override=None):
-    """Shared residual wiring for the prefill/decode bodies (pre-, post-,
-    and sandwich-norm variants); returns (new_x, None)."""
+    """Shared residual wiring for the paged step's layer bodies (pre-,
+    post-, and sandwich-norm variants); returns (new_x, None)."""
     plus_one = getattr(config, "norm_plus_one", False)
     if getattr(config, "post_norm", False) or getattr(config, "sandwich_norm",
                                                       False):
@@ -715,24 +696,6 @@ def _layer_window_column(config):
     return jnp.asarray([w if w else 2 ** 30 for w in lw], jnp.int32)
 
 
-def _scan_kv_layers(body, x, params, cache, wins):
-    """``lax.scan`` the per-layer decode ``body`` over (layer, k, v, window)
-    columns — the adapter shared by every family's CONTIGUOUS-cache
-    prefill/decode scans (``models/sample.py`` generation, the engine's
-    bucketed prefill), whose ``[L, B, max_len, ...]`` caches a layer
-    rewrites whole: each layer's cache is a scanned column in and a stacked
-    column out. The PAGED steps never slice their pools so: they scan with
-    :func:`scan_paged_layers`.
-    ``wins`` None (uniform window config) scans without the window column so
-    the traced program stays identical to the pre-schedule form."""
-    with jax.named_scope("layers"):   # the scan's slicing of weights and cache
-        if wins is None:
-            return jax.lax.scan(lambda c, inp: body(c, (*inp, None)), x,
-                                (params["layers"], cache["k"], cache["v"]))
-        return jax.lax.scan(body, x,
-                            (params["layers"], cache["k"], cache["v"], wins))
-
-
 def scan_paged_layers(body, x, params, cache, wins=None, lora_stacks=None):
     """The layer scan of every family's PAGED step. The stacked page pools
     ``cache["k"], cache["v"]`` ([L, P, page, heads, width] leaves) ride the
@@ -760,94 +723,6 @@ def scan_paged_layers(body, x, params, cache, wins=None, lora_stacks=None):
     return x, {"k": kp, "v": vp}, ys
 
 
-def init_cache(config: LlamaConfig, batch: int, max_len: int) -> dict:
-    """Zeroed per-layer KV cache, [L, B, max_len, kv_heads, head_dim]."""
-    shape = (config.num_layers, batch, max_len, config.num_kv_heads,
-             config.head_size)
-    return {"k": jnp.zeros(shape, config.dtype),
-            "v": jnp.zeros(shape, config.dtype)}
-
-
-def prefill(config: LlamaConfig, params: dict, input_ids: jnp.ndarray,
-            cache: dict, last_pos=None, lora=None):
-    """Causal forward over the prompt, writing each layer's rope'd k/v into
-    cache[:, :, :prompt_len]. Returns (logits [B, V] at ``last_pos`` —
-    default the final position; the serving engine pads prompts to a bucket
-    and passes the real last index as a traced scalar — and the cache).
-
-    ``lora`` (multi-LoRA serving): ``{"scale", "adapters" [B] int32,
-    "stacks" {t: {"a" [L, G, in, r], "b" [L, G, r, out]}}, "impl"}`` —
-    each example's adapter delta is added per target projection through
-    the same grouped-GEMM dispatch the paged step uses (rows = B x P,
-    each example's P rows contiguous after the sort)."""
-    b, p = input_ids.shape
-    positions = jnp.broadcast_to(jnp.arange(p)[None, :], (b, p))
-    x = embed_tokens(config, params, input_ids, positions)
-
-    wins = _layer_window_column(config)
-    sort = None
-    if lora is not None:
-        g = jax.tree.leaves(lora["stacks"])[0].shape[1]
-        sort = _lora_sort(lora["adapters"], p, g)
-
-    def body(x, inputs):
-        if lora is None:
-            layer, ck, cv, w = inputs
-            ov = None
-        else:
-            layer, ck, cv, w, lstack = inputs
-            ov = _lora_wmat_override(config, lora, lstack, sort)
-        attn, (k, v) = attention_sublayer(
-            config, x, layer["attn"],
-            None if config.post_norm else layer["input_norm"], positions,
-            "xla", return_kv=True, window_override=w, wmat_override=ov)
-        x, _ = _decode_residuals(config, x, layer, attn, wmat_override=ov)
-        nk = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
-        nv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
-        return x, (nk, nv)
-
-    if lora is None:
-        x, (ks, vs) = _scan_kv_layers(body, x, params, cache, wins)
-    else:
-        # each target's per-layer A/B pool slices ride as one more column
-        # (stacks are [L, G, ...]); a None ``wins`` is an empty column
-        with jax.named_scope("layers"):
-            x, (ks, vs) = jax.lax.scan(
-                body, x, (params["layers"], cache["k"], cache["v"], wins,
-                          lora["stacks"]))
-    # slice BEFORE the head: projecting all P positions to [B, P, V] fp32
-    # only to keep one row would cost P x the lm_head matmul and a
-    # prompt-length-scaled logits buffer (norm + projection are per-position)
-    x_last = (x[:, -1:] if last_pos is None
-              else jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1))
-    return (lm_head_logits(config, params, x_last)[:, 0],
-            {"k": ks, "v": vs})
-
-
-def decode_step(config: LlamaConfig, params: dict, token_ids: jnp.ndarray,
-                pos, cache: dict):
-    """One cached decode step: ``token_ids`` [B, 1] at position ``pos``
-    (traced scalar — one compile serves the whole generation). Returns
-    (logits [B, V], updated cache)."""
-    b = token_ids.shape[0]
-    positions = jnp.broadcast_to(jnp.asarray(pos)[None, None], (b, 1))
-    x = embed_tokens(config, params, token_ids, positions)
-
-    wins = _layer_window_column(config)
-
-    def body(x, inputs):
-        layer, ck, cv, w = inputs
-        attn, (nk, nv) = attention_sublayer(
-            config, x, layer["attn"],
-            None if config.post_norm else layer["input_norm"], positions,
-            "xla", kv_cache=(ck, cv, pos), return_kv=True, window_override=w)
-        x, _ = _decode_residuals(config, x, layer, attn)
-        return x, (nk, nv)
-
-    x, (ks, vs) = _scan_kv_layers(body, x, params, cache, wins)
-    return lm_head_logits(config, params, x)[:, -1], {"k": ks, "v": vs}
-
-
 def paged_positions(token_ids: jnp.ndarray,
                     positions: jnp.ndarray) -> jnp.ndarray:
     """[S, T] absolute positions for a paged decode/chunk call: slot s's
@@ -860,8 +735,10 @@ def paged_positions(token_ids: jnp.ndarray,
 def paged_logits_at(lm_head, config, params, x, last_index,
                     all_logits=False):
     """Slice the hidden states at the position whose logits the caller
-    wants BEFORE the head projection (same rationale as ``prefill``: never
-    project a whole chunk to [S, T, V] fp32 to keep one row). ``None``
+    wants BEFORE the head projection: projecting a whole chunk to
+    [S, T, V] fp32 only to keep one row would cost T x the lm_head matmul
+    and a chunk-length-scaled logits buffer (norm + projection are
+    per-position). ``None``
     keeps the decode contract — the last position. ``all_logits=True``
     keeps EVERY position ([S, T, V]): the speculative-decoding
     verification forward (serve/engine.py ``verify_for``) needs one
@@ -881,9 +758,11 @@ def paged_decode_step(config: LlamaConfig, params: dict,
                       all_logits=False, lora=None):
     """One step over a PAGED multi-request cache (serve/engine.py):
     ``token_ids`` [S, T] are each slot's next T tokens starting at
-    PER-SLOT position ``positions`` [S] (the contiguous-cache
-    ``decode_step`` shares one scalar ``pos`` across the batch — useless
-    for continuous batching). T == 1 is the batched decode step; T > 1 is
+    PER-SLOT position ``positions`` [S]. This is the ONE function a family
+    exports to serve (the engine's decode, chunk-prefill, verify and
+    horizon programs and the drafter all call it; ``pool_layout`` in
+    serve/kv_pages.py sizes the family's cache rows). T == 1 is the
+    batched decode step; T > 1 is
     a chunked-prefill call (S == 1 in practice) whose queries attend over
     the committed history AND the chunk itself — ``last_index`` (traced)
     then selects the real last token's logits out of a padded chunk —
@@ -925,8 +804,8 @@ def paged_decode_step(config: LlamaConfig, params: dict,
         attn, pools = attention_sublayer(
             config, x, layer["attn"],
             None if config.post_norm else layer["input_norm"], pos2d,
-            "xla", return_kv=True, window_override=w,
-            attend_override=override, wmat_override=ov)
+            "xla", window_override=w, attend_override=override,
+            wmat_override=ov)
         x, _ = _decode_residuals(config, x, layer, attn, wmat_override=ov)
         return x, pools, None
 

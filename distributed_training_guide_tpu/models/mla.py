@@ -19,7 +19,7 @@ after rope)``, not per-head k and v: ``kv_layout`` tells the page pool
 (``serve/kv_pages.py``). Two forms compute the same sum over it:
 
 - DECOMPRESSED: expand the rows to per-head k and v and attend as usual
-  (the full forward, the bucketed prefill, a prefill chunk);
+  (the full forward, a prefill chunk);
 - ABSORBED: fold ``W_uk`` into the query and ``W_uv`` into the output,
   ``q~_h = W_uk,h q_nope_h``, ``score = q~_h . c_kv + q_rope_h . k_r``,
   ``o_h = W_uv,h^T sum p c_kv``, so every head attends the one stored row
@@ -296,23 +296,21 @@ def latent_attention_sublayer(config: MlaMoeConfig, x: jnp.ndarray, p: dict,
                               norm_scale, positions: jnp.ndarray,
                               attend=None):
     """norm -> latent attention -> output projection (the caller adds the
-    residual). Returns ``(out, cache_out)``.
+    residual). Returns ``(out, pools)``.
 
-    ``attend`` None: causal attention over the call's own tokens,
-    decompressed; ``cache_out`` is the new latent rows ``(k_new, v_new)``
-    [B, S, 1, *] (the bucketed prefill commits them). Else the serving
-    engine's paged hook ``attend(q, k_new, v_new, **kw) -> (attn, pools)``
-    (``serve/kv_pages.paged_attend`` with the stacked pools and the layer's
-    index bound): a query
-    tile of up to ``ROWS_ALL_HEADS`` rows (the decode step) goes ABSORBED,
-    a larger one (a prefill chunk) DECOMPRESSED; ``cache_out`` is the
-    updated pools."""
+    ``attend`` None (the full forward): causal attention over the call's own
+    tokens, decompressed; nothing is cached and ``pools`` is None. Else the
+    serving engine's paged hook ``attend(q, k_new, v_new, **kw) -> (attn,
+    pools)`` (``serve/kv_pages.paged_attend`` with the stacked pools and the
+    layer's index bound), which writes the new latent rows ``(k_new,
+    v_new)`` [B, S, 1, *]: a query tile of up to ``ROWS_ALL_HEADS`` rows
+    (the decode step) goes ABSORBED, a larger one (a prefill chunk)
+    DECOMPRESSED; ``pools`` is the updated pools."""
     cdt = config.dtype
     b, s, _ = x.shape
     h = _rmsnorm(x, norm_scale, config.rms_norm_eps)
     q_nope, q_rope, c_kv, k_r = latent_projections(config, h, p, positions)
     scale = config.softmax_scale()
-    k_new, v_new = _cache_rows(config, c_kv, k_r)
     rope = config.qk_rope_head_dim
     if attend is None:
         k, v = expand_latent(config, p, c_kv, k_r)
@@ -320,23 +318,25 @@ def latent_attention_sublayer(config: MlaMoeConfig, x: jnp.ndarray, p: dict,
             jnp.concatenate([q_nope, q_rope], axis=-1), k, v, causal=True,
             positions=positions, kv_positions=positions, impl="xla",
             standard_layout=False, scale=scale)
-        cache_out = (k_new, v_new)
-    elif s * config.num_heads <= ROWS_ALL_HEADS:
+        out = attn.reshape(b, s, -1).astype(cdt) @ p["wo"].astype(cdt)
+        return out, None
+    k_new, v_new = _cache_rows(config, c_kv, k_r)
+    if s * config.num_heads <= ROWS_ALL_HEADS:
         with jax.named_scope("latent_proj"):
             w_uk, w_uv = _up_weights(config, p)
             q_abs = jnp.concatenate(
                 [jnp.einsum("bshd,chd->bshc", q_nope, w_uk), q_rope], axis=-1)
-        lat, cache_out = attend(q_abs, k_new, v_new, scale=scale,
-                                latent_rope=rope)
+        lat, pools = attend(q_abs, k_new, v_new, scale=scale,
+                            latent_rope=rope)
         with jax.named_scope("latent_proj"):
             attn = jnp.einsum("bshc,chd->bshd", lat.astype(cdt), w_uv)
     else:
-        attn, cache_out = attend(
+        attn, pools = attend(
             jnp.concatenate([q_nope, q_rope], axis=-1), k_new, v_new,
             scale=scale, latent_rope=rope,
             expand=lambda c, r: expand_latent(config, p, c, r))
     out = attn.reshape(b, s, -1).astype(cdt) @ p["wo"].astype(cdt)
-    return out, cache_out
+    return out, pools
 
 
 def _ffn(config: MlaMoeConfig, x, layer, return_counts=False):
@@ -373,39 +373,6 @@ def apply(config: MlaMoeConfig, params: dict, input_ids: jnp.ndarray,
     with jax.named_scope("layers"):
         x, _ = jax.lax.scan(body, x, params["layers"])
     return lm_head_logits(config, params, x)
-
-
-def init_cache(config: MlaMoeConfig, batch: int, max_len: int) -> dict:
-    """Zeroed contiguous latent cache, the pool's leaves per position:
-    ``{"k": [L, B, max_len, 1, rope_width], "v": [L, B, max_len, 1, C]}``."""
-    return {leaf: jnp.zeros((config.num_layers, batch, max_len, *shape),
-                            config.dtype)
-            for leaf, shape in config.kv_layout().items()}
-
-
-def prefill(config: MlaMoeConfig, params: dict, input_ids: jnp.ndarray,
-            cache: dict, last_pos=None):
-    """Causal forward over the prompt, writing each layer's latent rows into
-    the cache. Returns (logits [B, V] at ``last_pos``, default the final
-    position, and the cache)."""
-    b, p = input_ids.shape
-    positions = jnp.broadcast_to(jnp.arange(p)[None, :], (b, p))
-    x = embed_tokens(config, params, input_ids, positions)
-
-    def body(x, inputs):
-        layer, ck, cv, _ = inputs
-        attn, (k, v) = latent_attention_sublayer(
-            config, x, layer["attn"], layer["input_norm"], positions)
-        x = _ffn(config, x + attn, layer)
-        nk = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
-        nv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
-        return x, (nk, nv)
-
-    x, (ks, vs) = llama._scan_kv_layers(body, x, params, cache, None)
-    x_last = (x[:, -1:] if last_pos is None
-              else jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1))
-    return (lm_head_logits(config, params, x_last)[:, 0],
-            {"k": ks, "v": vs})
 
 
 def paged_decode_step(config: MlaMoeConfig, params: dict,

@@ -787,79 +787,17 @@ tp_embed = llama.tp_embed
 
 
 # ---------------------------------------------------------------------------
-# KV-cached decode (models/sample.py --kv-cache): same functional-cache
-# contract as the dense families (llama.init_cache shape math is duck-typed
-# on num_layers/num_kv_heads/head_size/dtype), with the routed FFN in the
-# block body. Expert dispatch runs with ``no_drop=True`` — a single decode
-# token's k choices can exceed a capacity_factor-derived capacity of 1
+# KV-cached decode (the serving engine's paged step; models/sample.py
+# --kv-cache runs it at one slot): the dense families' contract, with the
+# routed FFN in the block body. Expert dispatch runs with ``no_drop=True``
+# — a single decode token's k choices can exceed a capacity_factor-derived
+# capacity of 1
 # (both choices on one expert), and a qualitative sampling path must be
 # routing-exact vs the full recompute, not throughput-shaped. no_drop
 # resolves to the RAGGED backend: dropless by construction at O(t*k*d)
 # transients (the old dense no_drop allocated worst-case C = k*t per-expert
 # buffers — O(E*k*t*d), ~2 GiB/layer on a 2k-token qwen1.5-moe prompt).
 # ---------------------------------------------------------------------------
-
-init_cache = llama.init_cache
-
-
-def prefill(config: MoELlamaConfig, params: dict, input_ids: jnp.ndarray,
-            cache: dict, last_pos=None):
-    """Causal forward over the prompt, writing each layer's rope'd k/v into
-    the cache. Returns (logits [B, V] at ``last_pos``, default final
-    position, and the cache)."""
-    b, p = input_ids.shape
-    positions = jnp.broadcast_to(jnp.arange(p)[None, :], (b, p))
-    x = embed_tokens(config, params, input_ids, positions)
-
-    wins = llama._layer_window_column(config)
-
-    def body(x, inputs):
-        layer, ck, cv, w = inputs
-        attn, (k, v) = attention_sublayer(
-            config, x, layer["attn"], layer["input_norm"], positions,
-            "xla", return_kv=True, window_override=w)
-        x = x + attn
-        h = _rmsnorm(x, layer["post_attn_norm"], config.rms_norm_eps)
-        y, _, _ = _moe_ffn(config, h, layer["moe"], no_drop=True)
-        x = x + y
-        nk = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
-        nv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
-        return x, (nk, nv)
-
-    x, (ks, vs) = llama._scan_kv_layers(body, x, params, cache, wins)
-    # slice BEFORE the head (llama.prefill rationale: don't project all P
-    # positions to [B, P, V] fp32 to keep one row)
-    x_last = (x[:, -1:] if last_pos is None
-              else jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1))
-    return (lm_head_logits(config, params, x_last)[:, 0],
-            {"k": ks, "v": vs})
-
-
-def decode_step(config: MoELlamaConfig, params: dict, token_ids: jnp.ndarray,
-                pos, cache: dict):
-    """One cached decode step (``token_ids`` [B, 1] at traced position
-    ``pos``): attention over the full cache, routed FFN on the one token.
-    Returns (logits [B, V], updated cache)."""
-    b = token_ids.shape[0]
-    positions = jnp.broadcast_to(jnp.asarray(pos)[None, None], (b, 1))
-    x = embed_tokens(config, params, token_ids, positions)
-
-    wins = llama._layer_window_column(config)
-
-    def body(x, inputs):
-        layer, ck, cv, w = inputs
-        attn, (nk, nv) = attention_sublayer(
-            config, x, layer["attn"], layer["input_norm"], positions,
-            "xla", kv_cache=(ck, cv, pos), return_kv=True, window_override=w)
-        x = x + attn
-        h = _rmsnorm(x, layer["post_attn_norm"], config.rms_norm_eps)
-        y, _, _ = _moe_ffn(config, h, layer["moe"], no_drop=True)
-        x = x + y
-        return x, (nk, nv)
-
-    x, (ks, vs) = llama._scan_kv_layers(body, x, params, cache, wins)
-    return lm_head_logits(config, params, x)[:, -1], {"k": ks, "v": vs}
-
 
 def paged_decode_step(config: MoELlamaConfig, params: dict,
                       token_ids: jnp.ndarray, positions: jnp.ndarray,
@@ -885,8 +823,7 @@ def paged_decode_step(config: MoELlamaConfig, params: dict,
 
         attn, pools = attention_sublayer(
             config, x, layer["attn"], layer["input_norm"], pos2d,
-            "xla", return_kv=True, window_override=w,
-            attend_override=override)
+            "xla", window_override=w, attend_override=override)
         x = x + attn
         h = _rmsnorm(x, layer["post_attn_norm"], config.rms_norm_eps)
         y, _, _ = _moe_ffn(config, h, layer["moe"], no_drop=True)

@@ -163,12 +163,11 @@ def _rope_partial(x: jnp.ndarray, positions: jnp.ndarray, config) -> jnp.ndarray
 
 @jax.named_scope("attn")
 def _attn_branch(config, y, layer, positions, attn_impl,
-                 standard_layout=True, kv_cache=None, return_kv=False,
-                 attend_override=None):
+                 standard_layout=True, attend_override=None):
     """ln'd input -> fused QKV -> partial rope -> attention -> out proj
-    (no residual, no psum — the block owns those). ``kv_cache``/
-    ``return_kv``/``attend_override`` follow llama.attention_sublayer's
-    decode contract."""
+    (no residual, no psum — the block owns those). ``attend_override``
+    follows llama.attention_sublayer's decode contract: with it the call
+    returns ``(out, aux)``."""
     b, s, e = y.shape
     d = config.head_size
     cdt = config.dtype
@@ -186,26 +185,14 @@ def _attn_branch(config, y, layer, positions, attn_impl,
         attn, aux = attend_override(q, k, v, window=None, scale=None,
                                     softcap=None)
         out = attn.reshape(b, s, e_loc) @ layer["attn"]["wo"].astype(cdt)
-        return (out, aux) if return_kv else out
-    if kv_cache is not None:
-        ck, cv, pos = kv_cache
-        k = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, pos, 0, 0))
-        v = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, pos, 0, 0))
-        kv_pos = jnp.broadcast_to(jnp.arange(ck.shape[1])[None, :],
-                                  (b, ck.shape[1]))
-        attn = multihead_attention(q, k, v, causal=True, positions=positions,
-                                   kv_positions=kv_pos, impl="xla",
-                                   standard_layout=False)
-    elif callable(attn_impl):  # e.g. ring attention under context parallelism
+        return out, aux
+    if callable(attn_impl):  # e.g. ring attention under context parallelism
         attn = attn_impl(q, k, v, standard_layout=standard_layout)
     else:
         attn = multihead_attention(q, k, v, causal=True, positions=positions,
                                    kv_positions=positions, impl=attn_impl,
                                    standard_layout=standard_layout)
-    out = attn.reshape(b, s, e_loc) @ layer["attn"]["wo"].astype(cdt)
-    if return_kv:
-        return out, (k, v)
-    return out
+    return attn.reshape(b, s, e_loc) @ layer["attn"]["wo"].astype(cdt)
 
 
 @jax.named_scope("mlp")
@@ -348,78 +335,28 @@ def apply(
 
 
 # ---------------------------------------------------------------------------
-# KV-cached decode (models/sample.py fast path) — same functional-cache
-# contract as llama.init_cache/prefill/decode_step; the block math here is
-# the parallel residual (x + attn + mlp in ONE update) with partial rope.
+# KV-cached decode: the serving engine's paged step (llama.paged_decode_step
+# contract); the block math here is the parallel residual (x + attn + mlp
+# in ONE update) with partial rope.
 # ---------------------------------------------------------------------------
 
-def init_cache(config: NeoXConfig, batch: int, max_len: int) -> dict:
-    shape = (config.num_layers, batch, max_len, config.num_heads,
-             config.head_size)
-    return {"k": jnp.zeros(shape, config.dtype),
-            "v": jnp.zeros(shape, config.dtype)}
-
-
-def _cached_block(config, x, layer, positions, kv_cache, attend_override=None):
-    """Parallel- or sequential-residual block through the cache path;
-    returns (x, (k, v))."""
+def _cached_block(config, x, layer, positions, attend_override):
+    """Parallel- or sequential-residual block through the paged attend;
+    returns (x, pools)."""
     eps = config.layer_norm_eps
     cdt = config.dtype
-    attn, kv = _attn_branch(config, _layernorm(x, layer["ln1"], eps),
-                            layer, positions, "xla", kv_cache=kv_cache,
-                            return_kv=True, attend_override=attend_override)
+    attn, pools = _attn_branch(config, _layernorm(x, layer["ln1"], eps),
+                               layer, positions, "xla",
+                               attend_override=attend_override)
     if config.use_parallel_residual:
         update = attn + _mlp_branch(config, _layernorm(x, layer["ln2"], eps),
                                     layer)
         biases = (layer["attn"]["bo"].astype(cdt)
                   + layer["mlp"]["bo"].astype(cdt))
-        return x + update + biases, kv
+        return x + update + biases, pools
     x = x + attn + layer["attn"]["bo"].astype(cdt)
     mlp = _mlp_branch(config, _layernorm(x, layer["ln2"], eps), layer)
-    return x + mlp + layer["mlp"]["bo"].astype(cdt), kv
-
-
-def prefill(config: NeoXConfig, params: dict, input_ids: jnp.ndarray,
-            cache: dict, last_pos=None):
-    """Causal forward over the prompt, filling cache[:, :, :prompt_len];
-    returns (logits [B, V] at ``last_pos``, default final position, and the
-    cache)."""
-    b, p = input_ids.shape
-    positions = jnp.broadcast_to(jnp.arange(p)[None, :], (b, p))
-    x = embed_tokens(config, params, input_ids, positions)
-
-    def body(x, inputs):
-        layer, ck, cv = inputs
-        x, (k, v) = _cached_block(config, x, layer, positions, None)
-        nk = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
-        nv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
-        return x, (nk, nv)
-
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"],
-                                         cache["k"], cache["v"]))
-    x_last = (x[:, -1:] if last_pos is None
-              else jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1))
-    return (lm_head_logits(config, params, x_last)[:, 0],
-            {"k": ks, "v": vs})
-
-
-def decode_step(config: NeoXConfig, params: dict, token_ids: jnp.ndarray,
-                pos, cache: dict):
-    """One cached decode step (traced ``pos`` — one compile per generation);
-    returns (logits [B, V], updated cache)."""
-    b = token_ids.shape[0]
-    positions = jnp.broadcast_to(jnp.asarray(pos)[None, None], (b, 1))
-    x = embed_tokens(config, params, token_ids, positions)
-
-    def body(x, inputs):
-        layer, ck, cv = inputs
-        x, (nk, nv) = _cached_block(config, x, layer, positions,
-                                    (ck, cv, pos))
-        return x, (nk, nv)
-
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"],
-                                         cache["k"], cache["v"]))
-    return lm_head_logits(config, params, x)[:, -1], {"k": ks, "v": vs}
+    return x + mlp + layer["mlp"]["bo"].astype(cdt), pools
 
 
 def paged_decode_step(config: NeoXConfig, params: dict,
@@ -427,8 +364,8 @@ def paged_decode_step(config: NeoXConfig, params: dict,
                       cache: dict, attend, last_index=None,
                       all_logits=False):
     """Paged multi-request decode/chunk step (llama.paged_decode_step
-    contract) through ``_cached_block`` — the same parallel-/sequential-
-    residual body the contiguous decode runs. ``all_logits=True`` keeps
+    contract) through ``_cached_block``, the parallel-/sequential-
+    residual body. ``all_logits=True`` keeps
     every position's logits (speculative verification)."""
     from .llama import (paged_logits_at, paged_positions,
                         scan_paged_layers)
@@ -441,8 +378,7 @@ def paged_decode_step(config: NeoXConfig, params: dict,
             del window, scale, softcap  # no neox attention extras
             return attend(q, k, v, *pools, i)
 
-        x, pools = _cached_block(config, x, layer, pos2d, None,
-                                 attend_override=override)
+        x, pools = _cached_block(config, x, layer, pos2d, override)
         return x, pools, None
 
     x, pools, _ = scan_paged_layers(body, x, params, cache)

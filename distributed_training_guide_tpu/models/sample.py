@@ -4,10 +4,12 @@ for trained / converted checkpoints.
 Default mode re-runs the FULL forward over a fixed-size buffer per token
 (any family, one compile) — the hermetic numerics reference. ``--kv-cache``
 delegates to the serving runtime (``serve/``): the continuous-batching
-paged-KV engine at n_slots=1 — prefill + cached one-token decode steps for
-the llama family incl. qwen3/olmo2/gemma2 wirings, gpt2, neox, and moe
-(routed FFN drop-free per decoded token; same greedy tokens, pinned per
-family by test). The real serving path (multi-request, HTTP) lives at
+paged-KV engine at n_slots=1 — the prompt through the chunk program, then
+one-token decode steps over the paged cache, both the family's
+``paged_decode_step`` — for the llama family incl. qwen3/olmo2/gemma2
+wirings, gpt2, neox, moe and mla_moe (routed FFN drop-free per decoded
+token; same greedy tokens, pinned per family by test). The real serving
+path (multi-request, HTTP) lives at
 ``python -m distributed_training_guide_tpu.serve``.
 
     # hermetic (no tokenizer): raw token ids in, ids out
@@ -32,11 +34,12 @@ def make_sampler(bundle, temperature: float = 0.0, kv_cache: bool = False):
     - recompute (default, any family): the full forward re-runs over a
       fixed buffer and the token at ``pos`` is written — O(steps x
       forward(prompt+steps));
-    - ``kv_cache=True`` (families exporting ``init_cache``/``prefill``/
-      ``paged_decode_step`` — the llama family, gpt2, neox, moe): the
-      serving engine (serve/engine.py) at n_slots=1 — one bucketed prefill
-      over the prompt, then one single-token program per step attending
-      over the paged cache — O(forward(prompt) + steps x token). Same
+    - ``kv_cache=True`` (families exporting ``paged_decode_step``: to
+      serve, a family exports that one function, and ``pool_layout`` sizes
+      its cache rows): the serving engine (serve/engine.py) at n_slots=1 —
+      the prompt through the chunk program, then one single-token program
+      per step attending over the paged cache — O(forward(prompt) + steps
+      x token). Same
       greedy tokens as recompute (pinned per family by tests/test_sample.py);
       at temperature > 0 draws come from the engine's per-request
       fold_in(seed, position) stream (deterministic in ``rng``).
@@ -65,9 +68,10 @@ def make_sampler(bundle, temperature: float = 0.0, kv_cache: bool = False):
         from .registry import family_module
 
         mod = family_module(bundle.family)
-        if not hasattr(mod, "decode_step"):
-            raise ValueError(f"family {bundle.family!r} has no KV-cached "
-                             f"decode; use kv_cache=False")
+        if not hasattr(mod, "paged_decode_step"):
+            raise ValueError(f"family {bundle.family!r} exports no "
+                             f"paged_decode_step (the one hook the serving "
+                             f"engine asks of a family); use kv_cache=False")
         engines: dict = {}
 
         def sample(params, prompt_ids, steps: int,
@@ -81,7 +85,7 @@ def make_sampler(bundle, temperature: float = 0.0, kv_cache: bool = False):
             check_length(n, steps)
             page = 16
             capacity = -(-(n + steps) // page) * page
-            # one engine (== one compiled prefill/decode pair) per page-
+            # one engine (== one compiled chunk/decode pair) per page-
             # rounded capacity; the engine holds its params so the id key
             # stays pinned to the live object
             eng = engines.get((id(params), capacity))
@@ -98,7 +102,7 @@ def make_sampler(bundle, temperature: float = 0.0, kv_cache: bool = False):
         return sample
 
     @partial(jax.jit, donate_argnums=(1,))
-    def decode_step(params, buf, pos, key):
+    def recompute_step(params, buf, pos, key):
         logits = bundle.apply(bundle.config, params, buf)
         logit = jax.lax.dynamic_index_in_dim(logits[0], pos - 1, axis=0,
                                              keepdims=False)
@@ -114,7 +118,7 @@ def make_sampler(bundle, temperature: float = 0.0, kv_cache: bool = False):
         buf = buf.at[0, :n].set(jnp.asarray(prompt_ids, jnp.int32))
         for t in range(n, n + steps):
             rng, key = jax.random.split(rng)
-            buf = decode_step(params, buf, jnp.asarray(t), key)
+            buf = recompute_step(params, buf, jnp.asarray(t), key)
         return [int(x) for x in buf[0]]
 
     return sample

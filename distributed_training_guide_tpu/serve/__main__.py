@@ -49,7 +49,7 @@ def main(argv=None) -> None:
     parser.add_argument("--prefill-chunk", type=int, default=None,
                         help="stream prompts in N-token chunks co-scheduled "
                         "with resident decodes (Sarathi chunked prefill; "
-                        "default: one bucketed prefill per prompt)")
+                        "default: the engine's own size)")
     parser.add_argument("--no-prefix-cache", action="store_true",
                         help="disable copy-on-write prefix sharing of "
                         "prompt pages across requests")

@@ -4,8 +4,7 @@ by a KV-page handoff (DistServe, Zhong et al. arXiv:2401.09670).
 The monolithic :class:`~.engine.ServeEngine` co-schedules prefill work
 inside its decode iteration: even chunked, a 32k-token prompt spends
 ``ceil(32k / chunk)`` iterations adding one chunk-forward of latency to
-every co-resident decode step, and an un-chunked bucket prefill stalls
-the whole batch for the prompt's full length. Prefill and decode also
+every co-resident decode step. Prefill and decode also
 want DIFFERENT compiled programs and batching policies — prefill is
 compute-bound (big matmuls, batch for throughput), decode is
 bandwidth-bound (one token per slot, batch for occupancy) — which is
@@ -14,8 +13,8 @@ DistServe's case for splitting them into separate engines entirely.
 Here the split is two engines over ONE refcounted page pool:
 
 - :class:`PrefillEngine`: its own scheduler (admission, prefix cache,
-  CoW) and its own compiled programs (bucketed prefill or the chunk
-  program). It never runs a decode step. When a prompt's pages are fully
+  CoW) and its own compiled program (the chunk program). It never runs
+  a decode step. When a prompt's pages are fully
   committed it samples the first token and emits a :class:`Handoff`.
 - :class:`PageHandoff`: the transfer protocol, in two implementations
   behind one interface. SAME-HOST the two engines address one physical
@@ -64,13 +63,12 @@ from .adapters import DEFAULT_TARGETS
 from .engine import (LatencyMeter, ModelPrograms, adapter_metrics,
                      advance_prefill_chunks, build_adapter_report,
                      build_kv_report, collect_partial_tokens,
-                     default_prefill_buckets, derived_pool_metrics,
-                     dispatch_horizon, drop_stale_pending, horizon_dev,
+                     derived_pool_metrics, dispatch_horizon,
+                     drop_stale_pending, horizon_dev,
                      process_horizon_block, refuse_for_family,
-                     resolve_context_bounds,
-                     resolve_drafter, run_bucket_prefill,
-                     run_decode_iteration, run_fork, spec_metrics,
-                     validate_prefill_buckets)
+                     resolve_context_bounds, resolve_drafter,
+                     resolve_prefill_chunk, run_decode_iteration, run_fork,
+                     spec_metrics)
 from .kv_pages import (resolve_attend_impl, kv_page_bytes, PagePool,
                        pages_for_tokens, pool_nbytes)
 from .scheduler import Admission, Request, RequestResult, Scheduler
@@ -303,20 +301,19 @@ class CrossHostPageHandoff:
 
 
 class PrefillEngine:
-    """The prefill half: admission + prefix sharing + (bucketed |
-    chunked) prompt computation, emitting Handoffs. Owns its scheduler;
+    """The prefill half: admission + prefix sharing + chunked prompt
+    computation, emitting Handoffs. Owns its scheduler;
     shares the ModelPrograms jit cache and the device page pool with the
     decode half."""
 
     def __init__(self, programs: ModelPrograms, pages: dict,
                  sched: Scheduler, handoff: PageHandoff, *,
-                 prefill_chunk: Optional[int], prefill_buckets: tuple):
+                 prefill_chunk: int):
         self.programs = programs
         self.pages = pages              # SHARED dict (key assignment only)
         self.sched = sched
         self.handoff = handoff
         self.prefill_chunk = prefill_chunk
-        self.prefill_buckets = prefill_buckets
         self._pending: dict[int, Admission] = {}
 
     def _finish_prefill(self, adm: Admission, logit) \
@@ -360,15 +357,7 @@ class PrefillEngine:
         for adm in self.sched.try_admit():
             if adm.fork is not None:
                 run_fork(self.programs, self.pages, adm)
-            if self.prefill_chunk is None:
-                logit = run_bucket_prefill(self.programs, self.pages,
-                                           self.sched, adm,
-                                           self.prefill_buckets)
-                res = self._finish_prefill(adm, logit)
-                if res is not None:
-                    finished.append(res)
-            else:
-                self._pending[adm.slot_idx] = adm
+            self._pending[adm.slot_idx] = adm
         if self._pending:
             # the shared chunk-budget loop (engine.py): here the only
             # thing one chunk can delay is OTHER PREFILLS — resident
@@ -536,8 +525,7 @@ class DisaggEngine:
     def __init__(self, bundle: ModelBundle, params, *, n_slots: int = 8,
                  n_prefill_slots: int = 1, page_size: int = 16,
                  n_pages: Optional[int] = None,
-                 max_len: Optional[int] = None,
-                 prefill_buckets: Optional[tuple] = None, plan=None,
+                 max_len: Optional[int] = None, plan=None,
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: bool = True, attend_impl: str = "auto",
                  shard_kv: bool = False, max_queue: Optional[int] = None,
@@ -563,9 +551,6 @@ class DisaggEngine:
         if n_prefill_slots < 1:
             raise ValueError(f"n_prefill_slots must be >= 1, got "
                              f"{n_prefill_slots}")
-        if prefill_chunk is not None and prefill_chunk < 1:
-            raise ValueError(f"prefill_chunk must be >= 1, got "
-                             f"{prefill_chunk}")
         if transport not in TRANSPORTS:
             raise ValueError(f"transport must be one of {TRANSPORTS}, got "
                              f"{transport!r}")
@@ -614,13 +599,8 @@ class DisaggEngine:
         self.n_prefill_slots = n_prefill_slots
         self.transport = transport
         self.draining = False
-        self.prefill_chunk = prefill_chunk
-        if prefill_buckets is None:
-            prefill_buckets = default_prefill_buckets(self.max_pages,
-                                                      page_size)
-        prefill_buckets = validate_prefill_buckets(
-            prefill_buckets, max_pages=self.max_pages, page_size=page_size,
-            max_model_len=self.max_model_len)
+        self.prefill_chunk = resolve_prefill_chunk(
+            prefill_chunk, max_pages=self.max_pages, page_size=page_size)
 
         if transport == "cross_host":
             # two pools, one per "host": the prefill pool holds prompts
@@ -653,7 +633,6 @@ class DisaggEngine:
             n_slots=n_prefill_slots, pool=self.pool,
             max_len=self.max_model_len, max_pages_per_slot=self.max_pages,
             prefix_cache=prefix_cache, max_queue=max_queue,
-            allow_partial_share=prefill_chunk is not None,
             # admission headroom must count the DECODE side's running
             # slots (this scheduler never decodes): without it, admission
             # would eat the last free pages out from under growing
@@ -708,7 +687,7 @@ class DisaggEngine:
 
         self.prefill = PrefillEngine(
             self.programs, self.pages, prefill_sched, self.handoff,
-            prefill_chunk=prefill_chunk, prefill_buckets=prefill_buckets)
+            prefill_chunk=self.prefill_chunk)
         self.decode = DecodeEngine(self.programs, self.decode_pages,
                                    decode_sched, self.handoff,
                                    drafter=drafter,

@@ -12,13 +12,15 @@ Compile surface (the whole point — requests come and go, programs don't):
   block_q=T: O(live pages) reads per forward, no gathered view);
   ``attend_impl=`` selects the XLA gather reference explicitly, for all
   three forwards at once (one family per engine, never a mix).
-- One prefill program per LENGTH BUCKET (powers of two up to ``max_len``)
-  — or, with ``prefill_chunk=N``, ONE chunk program: the prompt streams
-  through the paged decode path N tokens at a time, each chunk attending
-  over the already-committed pages, co-scheduled with resident decodes
-  (Sarathi-style chunked prefill, Agrawal et al. arXiv:2308.16369) so a
-  long prompt never stalls co-resident generation for its full length.
-  The chunk budget bounds the extra decode latency per iteration.
+- ONE prefill program, the chunk program: a prompt streams through the
+  paged decode path ``prefill_chunk`` tokens at a time, each chunk
+  attending over the already-committed pages, co-scheduled with resident
+  decodes (Sarathi-style chunked prefill, Agrawal et al.
+  arXiv:2308.16369) so a long prompt never stalls co-resident generation
+  for its full length. The chunk budget bounds the extra decode latency
+  per iteration. A model family therefore serves by exporting
+  ``paged_decode_step`` alone (decode, chunk, verify and horizon programs
+  all call it); ``pool_layout`` sizes its cache rows.
 - One sampling program (temperature / top-k / top-p, per-slot scalars so
   co-resident requests can run different settings under one compile) and
   its batch-1 twin for prefill logits.
@@ -68,8 +70,8 @@ from ..utils.trace import named, span
 from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        adapter_nbytes, adapter_pool_bytes, adapter_shapes,
                        init_adapter_stacks, validate_adapter_params)
-from .kv_pages import (resolve_attend_for, commit_prefill, copy_pages,
-                       init_pages, kv_dtype_name, kv_page_bytes, make_attend,
+from .kv_pages import (resolve_attend_for, copy_pages, init_pages,
+                       kv_dtype_name, kv_page_bytes, make_attend,
                        PagePool, pages_for_tokens, pool_nbytes, TRASH_PAGE)
 from .scheduler import Admission, Request, RequestResult, Scheduler
 from .spec import Drafter, NgramDrafter, new_spec_counters
@@ -306,34 +308,25 @@ def collect_partial_tokens(scheds, handoffs=()) -> dict:
     return out
 
 
-def default_prefill_buckets(max_pages: int, page_size: int) -> tuple:
-    """Power-of-two prompt buckets up to the per-slot page capacity."""
-    cap = max_pages * page_size
-    b, buckets = page_size, []
-    while b < cap:
-        buckets.append(b)
-        b *= 2
-    buckets.append(cap)
-    return tuple(buckets)
+# the engine's own chunk size, where the caller names none: the
+# ``olmo2-7b-l12.serve.decode16`` deployment's (512 tokens a chunk step is
+# what that cell's ITL carries beside 16 resident decodes), and never wider
+# than one slot can hold
+DEFAULT_PREFILL_CHUNK = 512
 
 
-def validate_prefill_buckets(buckets: tuple, *, max_pages: int,
-                             page_size: int, max_model_len: int) -> tuple:
-    """Buckets must cover every admissible prompt and stay inside the
-    page capacity (``commit_prefill`` indexes table_row[t // page]) — an
-    unservable bucket config fails at construction, not after a request
-    has been admitted and holds a slot + pages."""
-    buckets = tuple(sorted(buckets))
-    cap = max_pages * page_size
-    if buckets[-1] < min(max_model_len - 1, cap):
-        raise ValueError(
-            f"prefill_buckets {buckets} cannot cover the largest "
-            f"admissible prompt ({min(max_model_len - 1, cap)} tokens)")
-    if buckets[-1] > cap:
-        raise ValueError(
-            f"prefill bucket {buckets[-1]} exceeds the per-slot page "
-            f"capacity {cap}")
-    return buckets
+def resolve_prefill_chunk(prefill_chunk: Optional[int], *, max_pages: int,
+                          page_size: int) -> int:
+    """The chunk program's width T for one engine: the caller's number, or
+    (None) the engine's own size — single-sourced so both engines, the CLI,
+    ``models/sample.py`` and ``post/`` resolve it alike, and everything
+    downstream (the ``serve_chunk_t<T>`` program, a generation swap, the
+    reports) sees an integer."""
+    if prefill_chunk is None:
+        return min(DEFAULT_PREFILL_CHUNK, max_pages * page_size)
+    if prefill_chunk < 1:
+        raise ValueError(f"prefill_chunk must be >= 1, got {prefill_chunk}")
+    return prefill_chunk
 
 
 class LatencyMeter:
@@ -372,36 +365,6 @@ def run_fork(programs: "ModelPrograms", pages: dict, adm: Admission) -> None:
             jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
 
 
-def run_bucket_prefill(programs: "ModelPrograms", pages: dict,
-                       sched: Scheduler, adm: Admission, buckets: tuple):
-    """Whole-context prefill through the family's bucketed program +
-    commit; the commit scatter skips the shared prefix (those pages are
-    other sequences' territory) and the pad tail. Returns the real last
-    token's logits row (the first-sample input). Shared verbatim by the
-    monolithic engine and the disaggregated prefill engine — prefill
-    semantics must never fork between them."""
-    tokens = adm.tokens
-    n = len(tokens)
-    bucket = next((b for b in buckets if b >= n), None)
-    if bucket is None:
-        raise ValueError(f"prompt length {n} exceeds the largest prefill "
-                         f"bucket {buckets[-1]}")
-    with span("serve.prefill", request_id=adm.request.request_id, tokens=n,
-              program=f"serve_prefill_b{bucket}"):
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = tokens
-        programs.prefill_calls += 1
-        logit, kd, vd = programs.prefill_for(bucket)(
-            programs.params, jnp.asarray(ids), jnp.asarray(n - 1),
-            *programs.lora_call_args([adm.request.adapter_id]))
-        table_row = jnp.asarray(sched.table_row(adm.slot_idx))
-        pages["k"], pages["v"] = programs._commit_fn(
-            pages["k"], pages["v"], kd, vd, table_row,
-            jnp.asarray(n), jnp.asarray(adm.shared_len))
-        sched.commit_tokens(adm.slot_idx, n - adm.shared_len)
-    return logit
-
-
 def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
                            sched: Scheduler, pending: dict, chunk: int,
                            on_complete) -> list:
@@ -419,9 +382,7 @@ def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
     for slot_idx in sched.prefilling_indices():
         if budget <= 0:
             break
-        adm = pending.get(slot_idx)
-        if adm is None:        # pre-chunking admission (mode switch)
-            continue
+        adm = pending[slot_idx]
         slot = sched.slots[slot_idx]
         start = slot.cache_len
         real = min(chunk, slot.target_len - start)
@@ -832,8 +793,10 @@ def refuse_for_family(mod, family: str, asked: dict) -> None:
 
 class ModelPrograms:
     """The compiled-program cache for one (model, params, sharding)
-    triple: the batched decode step, per-bucket prefill programs, the
-    chunk program, commit/copy scatters, and the batch-1 sampler. Owned
+    triple: the batched decode step, the chunk program (the one prefill),
+    the verify and horizon programs, the page-copy scatter, and the
+    batch-1 sampler — every forward among them the family's
+    ``paged_decode_step``. Owned
     by a :class:`ServeEngine`, or SHARED between the disaggregated
     prefill/decode pair (``serve/disagg.py``) — both engines then reuse
     one params layout and one jit cache.
@@ -860,8 +823,10 @@ class ModelPrograms:
             "plan / shard_kv": plan is not None or shard_kv})
         if not hasattr(self.mod, "paged_decode_step"):
             raise ValueError(
-                f"family {bundle.family!r} has no KV-cached decode — the "
-                f"serving engine needs init_cache/prefill/paged_decode_step")
+                f"family {bundle.family!r} does not serve: the serving "
+                f"engine needs models/{bundle.family}.py to export "
+                f"paged_decode_step (and pool_layout, where its cache rows "
+                f"are not k/v heads)")
         if max_adapters is not None and not hasattr(self.mod, "_lora_sort"):
             raise ValueError(
                 f"family {bundle.family!r} has no batched multi-LoRA "
@@ -904,8 +869,8 @@ class ModelPrograms:
         self._kv_sharding = None
         self._repl = None
         if self.shard_kv:
-            from .sharding import (make_sharded_commit, make_sharded_copy,
-                                   serve_kv_shardings, validate_kv_shard)
+            from .sharding import (make_sharded_copy, serve_kv_shardings,
+                                   validate_kv_shard)
 
             validate_kv_shard(plan, self.config)
             # the rules-table pattern: pool sharding comes from the serve
@@ -920,10 +885,9 @@ class ModelPrograms:
             self._kv_sharding = serve_kv_shardings(
                 self.mesh, probe)["pages"]["k"]
             self._repl = plan.replicated()
-            commit_impl = make_sharded_commit(self.mesh)
             copy_impl = make_sharded_copy(self.mesh)
         else:
-            commit_impl, copy_impl = commit_prefill, copy_pages
+            copy_impl = copy_pages
         if plan is not None:
             # shardings come from the FP layout (param_shardings' axes-tree
             # walk treats tuples as leaves, and Quantized IS a NamedTuple);
@@ -980,16 +944,9 @@ class ModelPrograms:
 
         kv_out = ((self._kv_sharding, self._kv_sharding)
                   if self.shard_kv else None)
-        self._prefill_fns = {}
         self._chunk_fns = {}
         self._verify_fns = {}
         self._horizon_fns = {}
-        # one jit wrapper; each prefill bucket's [L, Pb, ...] shape gets its
-        # own cached executable automatically
-        self._commit_fn = jax.jit(named(commit_impl, "serve_commit"),
-                                  donate_argnums=(0, 1),
-                                  **({"out_shardings": kv_out}
-                                     if kv_out else {}))
         self._copy_fn = jax.jit(named(copy_impl, "serve_copy"),
                                 donate_argnums=(0, 1),
                                 **({"out_shardings": kv_out}
@@ -1011,8 +968,8 @@ class ModelPrograms:
         self._swap_in_flight = False
         self._snapshot_fn = None
         self._requant_fn = None
-        # prefill FORWARD count (bucketed prefills + chunk forwards both
-        # land here) — the zero-prefill pin for tier restores and fleet
+        # prefill FORWARD count (one per chunk program call) — the
+        # zero-prefill pin for tier restores and fleet
         # directory pulls: a restored/pulled context must seat without
         # moving this counter beyond what its warm-cache control moves it
         self.prefill_calls = 0
@@ -1290,14 +1247,11 @@ class ModelPrograms:
         weight-publish, not a recompile)."""
         sizes = {
             "decode": self._decode_fn._cache_size(),
-            "commit": self._commit_fn._cache_size(),
             "copy": self._copy_fn._cache_size(),
             "sample_one": self._sample_one._cache_size(),
         }
         if self._insert_fn is not None:
             sizes["adapter_insert"] = self._insert_fn._cache_size()
-        for b, fn in self._prefill_fns.items():
-            sizes[f"prefill_{b}"] = fn._cache_size()
         for t, fn in self._chunk_fns.items():
             sizes[f"chunk_{t}"] = fn._cache_size()
         for key, fn in self._verify_fns.items():
@@ -1447,20 +1401,6 @@ class ModelPrograms:
                 **({"out_shardings": kv_out} if kv_out else {}))
         return self._horizon_fns[k]
 
-    def prefill_for(self, bucket: int):
-        if bucket not in self._prefill_fns:
-            def fn(params, ids, last_pos, *lora_args):
-                cache = self.mod.init_cache(self.config, 1, bucket)
-                logit, cache = self.mod.prefill(
-                    self.config, params, ids, cache, last_pos=last_pos,
-                    **({"lora": self._lora_ctx(lora_args)}
-                       if lora_args else {}))
-                return logit[0], cache["k"][:, 0], cache["v"][:, 0]
-
-            self._prefill_fns[bucket] = jax.jit(
-                named(fn, f"serve_prefill_b{bucket}"))
-        return self._prefill_fns[bucket]
-
     def chunk_for(self, t: int):
         """The ONE chunk-prefill program: [1, t] tokens run the paged
         decode path — the engine's ``attend_impl`` resolves the
@@ -1515,7 +1455,7 @@ class ModelPrograms:
 
         ``greedy=True`` is a STATIC specialization the engine selects
         when every active slot decodes at temperature 0 (a host-known
-        predicate, like the prefill buckets): the per-position draw is
+        predicate): the per-position draw is
         then exactly ``argmax`` — same output, none of the sampler's
         sorted-space top-k/top-p machinery, which is t full-vocab sorts
         per iteration and dominates the verify cost on CPU. Mixed
@@ -1594,10 +1534,11 @@ class ServeEngine:
 
     ``prefix_cache`` (default on): committed prompt pages register in a
     content-keyed cache so identical prefixes share physical pages across
-    requests (refcounted, copy-on-write). ``prefill_chunk=N`` streams
-    prompts through the paged path N tokens per iteration instead of one
-    bucketed prefill (long prompts stop stalling resident decodes; also
-    unlocks mid-page prefix reuse). ``attend_impl`` picks the paged
+    requests (refcounted, copy-on-write; a match may end mid-page).
+    ``prefill_chunk=N`` streams prompts through the paged path N tokens
+    per iteration (a long prompt does not stall resident decodes for its
+    full length); None is the engine's own size
+    (:func:`resolve_prefill_chunk`). ``attend_impl`` picks the paged
     attend FAMILY for every forward (decode, spec verify, prefill
     chunk): "auto" (flash kernel on TPU, gather elsewhere), "flash",
     "xla" — one family per engine, so identity guarantees never
@@ -1633,8 +1574,7 @@ class ServeEngine:
 
     def __init__(self, bundle: ModelBundle, params, *, n_slots: int = 8,
                  page_size: int = 16, n_pages: Optional[int] = None,
-                 max_len: Optional[int] = None,
-                 prefill_buckets: Optional[tuple] = None, plan=None,
+                 max_len: Optional[int] = None, plan=None,
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: bool = True, attend_impl: str = "auto",
                  shard_kv: bool = False, max_queue: Optional[int] = None,
@@ -1689,12 +1629,10 @@ class ServeEngine:
         self.mod = self.programs.mod
         self.plan = self.programs.plan
         self.attend_impl = self.programs.attend_impl
-        if prefill_chunk is not None and prefill_chunk < 1:
-            raise ValueError(f"prefill_chunk must be >= 1, got "
-                             f"{prefill_chunk}")
-        self.prefill_chunk = prefill_chunk
         max_len, self.max_model_len, self.max_pages = \
             resolve_context_bounds(self.config, max_len, page_size)
+        self.prefill_chunk = resolve_prefill_chunk(
+            prefill_chunk, max_pages=self.max_pages, page_size=page_size)
         # a forced 'flash' the compiled kernel cannot take fails here, at
         # construction, not inside the first forward of a live request
         resolve_attend_for(self.config, self.attend_impl, page_size)
@@ -1709,21 +1647,11 @@ class ServeEngine:
             n_slots=n_slots, pool=pool, max_len=self.max_model_len,
             max_pages_per_slot=self.max_pages, prefix_cache=prefix_cache,
             max_queue=max_queue,
-            # mid-page prefix reuse needs the chunked path: a bucketed
-            # prefill recomputes from position 0 anyway, so only aligned
-            # (full-page) sharing pays for itself there
-            allow_partial_share=prefill_chunk is not None,
             # admission headroom scales to the k in-flight speculated
             # tokens a verify step can scatter per running decode
             spec_lookahead=self.drafter.k if self.drafter else 0,
             adapter_pool=self.adapter_pool,
             decode_horizon=decode_horizon)
-        if prefill_buckets is None:
-            prefill_buckets = default_prefill_buckets(self.max_pages,
-                                                      page_size)
-        self.prefill_buckets = validate_prefill_buckets(
-            prefill_buckets, max_pages=self.max_pages, page_size=page_size,
-            max_model_len=self.max_model_len)
 
         self.pages = self.programs.init_device_pages(n_pages, page_size)
 
@@ -2004,8 +1932,8 @@ class ServeEngine:
         """One scheduler iteration: expire deadlines (clean eviction at
         the boundary), grow running decodes (preempting the cheapest on
         true exhaustion), admit whatever now fits (sharing cached
-        prefixes), advance prefill work (whole-bucket, or one
-        chunk-budget's worth), then ONE batched decode over the decoding
+        prefixes), advance prefill work (one chunk-budget's worth), then
+        ONE batched decode over the decoding
         slots — a single step at decode_horizon=1, a fused K-step
         horizon program otherwise. Returns finished requests.
 
@@ -2097,15 +2025,7 @@ class ServeEngine:
             self._dev = None
             if adm.fork is not None:
                 run_fork(self.programs, self.pages, adm)
-            if self.prefill_chunk is None:
-                logit = run_bucket_prefill(self.programs, self.pages,
-                                           sched, adm,
-                                           self.prefill_buckets)
-                res = self._on_prefill_complete(adm, logit)
-                if res is not None:        # eos/length on the first token
-                    finished.append(res)
-            else:
-                self._pending[adm.slot_idx] = adm
+            self._pending[adm.slot_idx] = adm
         if self._pending:
             finished.extend(advance_prefill_chunks(
                 self.programs, self.pages, sched, self._pending,

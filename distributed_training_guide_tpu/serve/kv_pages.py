@@ -1,10 +1,11 @@
 """Paged KV cache: fixed-size blocks, a refcounted free-list allocator,
 per-sequence block tables, and the device-side attend over the table.
 
-The contiguous decode cache (``models/<family>.init_cache``) is
-``[L, B, max_len, kvh, hd]`` — a serving engine sized that way pays
-``n_slots x max_len`` resident bytes whether or not the slots are full
-(vLLM measures 60-80% of such memory as waste). Here the resident cache is
+A contiguous decode cache is ``[L, B, max_len, kvh, hd]`` — a serving
+engine sized that way pays ``n_slots x max_len`` resident bytes whether or
+not the slots are full (vLLM measures 60-80% of such memory as waste), and
+this package has none: the pool below is the only KV cache a model family
+serves from. Here the resident cache is
 a POOL of pages ``[L, n_pages, page_size, kvh, hd]`` (PagedAttention, Kwon
 et al., arXiv:2309.06180): a sequence owns ``ceil(tokens / page_size)``
 pages wired together by an int32 block table, pages return to the free
@@ -21,7 +22,7 @@ the scheduler decides when — see serve/scheduler.py's prefix cache).
 
 Physical page 0 is RESERVED as the trash page: it is never allocated, so a
 write routed to it (an idle slot in the fixed ``[n_slots]`` decode batch,
-the padded tail of a bucketed prefill or prefill chunk) lands harmlessly —
+the padded tail of a prefill chunk) lands harmlessly —
 active block tables never reference it, so garbage in page 0 can never
 enter a live slot's attend. That convention is what lets ONE compiled
 decode program serve any mix of active/idle slots with plain scatters, no
@@ -58,38 +59,37 @@ and speculative verification rewrite single tokens and must reproduce
 the original pool bytes exactly). Per-token blocks keep every write
 independent: ``quantize(x)`` is a pure function of that token's k/v, so
 replay/verify/chunk writes are bitwise identical however the token
-first arrived. Quantization happens at every write site (decode
-scatter, prefill commit, chunked-prefill/verify multi-token scatter);
-dequantization at every read site (the gather view, and inside the
+first arrived. Quantization happens at the one write site
+(``_scatter_new``: the decode step's row, a prefill chunk's or a verify
+step's T rows); dequantization at every read site (the gather view, and inside the
 flash-decode kernel's tile loop — the scale rides a second block-table
 DMA operand).
 
 One consequence to know: under int8 token identity is PROGRAM-relative.
-A chunked prefill attends over already-quantized history (every chunk
-reads the pool), while a bucket prefill computes the whole prompt in
-float and quantizes once at commit — in fp32 those two paths agree to
-~1e-7 (argmax flips are a lottery the test suite never loses), but
-under int8 the difference is a genuine 1-LSB cache rounding that CAN
-flip a downstream near-tie. Every identity guarantee the engines make
-(batch-1 invariance, spec-on == spec-off, preemption replay) holds
-bitwise WITHIN one engine configuration because each token's k/v is
-rewritten by the same program that wrote it; comparing engines across
-prefill modes is a quality question (bounded by the attend error
-pinned in tests/test_kv_quant.py), not an identity one.
+A prefill chunk attends over already-quantized history (every chunk reads
+the pool), so two engines that cut the same prompt into chunks of
+different sizes read different roundings of it — in fp32 they agree to
+~1e-7 (argmax flips are a lottery the test suite never loses), but under
+int8 the difference is a genuine 1-LSB cache rounding that CAN flip a
+downstream near-tie. Every identity guarantee the engines make (batch-1
+invariance, spec-on == spec-off, preemption replay) holds bitwise WITHIN
+one engine configuration because each token's k/v is rewritten by the
+same program that wrote it; comparing engines across chunk sizes is a
+quality question (bounded by the attend error pinned in
+tests/test_kv_quant.py), not an identity one.
 
 WHAT a page holds is the family's (``pool_layout``): the description above
 is k and v of ``kvh x hd`` each. A latent-attention family (``models/mla.py``)
 caches ONE row a token a layer: the pool's ``v`` leaf holds the latent
 ``c_kv`` (the absorbed form's values and the first part of its keys), its
 ``k`` leaf the rope key all heads share, padded to whole lane tiles; the
-allocator, the tables, the scatters, the commit and the CoW copy below are
-the same code over those two leaves, and the attend is ``_attend_latent``.
+allocator, the tables, the scatter and the CoW copy below are the same code
+over those two leaves, and the attend is ``_attend_latent``.
 
-Device-side pieces (``paged_attend``, ``commit_prefill``, ``copy_pages``)
-are pure functions of array arguments — block tables and lengths arrive
-as int32 arrays, so requests coming and going never change a traced
-shape. The allocator (``PagePool``) is host-side Python owned by the
-scheduler.
+Device-side pieces (``paged_attend``, ``copy_pages``) are pure functions
+of array arguments — block tables and lengths arrive as int32 arrays, so
+requests coming and going never change a traced shape. The allocator
+(``PagePool``) is host-side Python owned by the scheduler.
 """
 from __future__ import annotations
 
@@ -651,44 +651,6 @@ def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None):
                             **latent)
 
     return attend
-
-
-@jax.named_scope("kv_write")
-def commit_prefill(k_pages, v_pages, k_dense, v_dense, table_row, n_tokens,
-                   start=0):
-    """Scatter a bucketed prefill's dense cache into one slot's pages.
-
-    k_dense/v_dense [L, Pb, Hkv, D] (family ``prefill`` output, batch dim
-    squeezed; Pb = the padded bucket length); table_row [M] the slot's
-    block table; n_tokens the REAL prompt length — positions >= n_tokens
-    (pad garbage) route to the trash page, as do positions < ``start``
-    (tokens already resident via a shared prefix: writing them would hit
-    pages other sequences read through — the fork discipline lives in the
-    scheduler, this scatter simply never touches shared territory).
-    Returns the updated pools.
-    """
-    quantized = isinstance(k_pages, Quantized)
-    pb = k_dense.shape[1]
-    page = (k_pages.q if quantized else k_pages).shape[2]
-    m = table_row.shape[0]
-    t = jnp.arange(pb)
-    phys = jnp.where((t >= start) & (t < n_tokens),
-                     table_row[jnp.minimum(t // page, m - 1)], TRASH_PAGE)
-    off = t % page
-    if quantized:
-        # same quantize-at-write grain as the decode scatter: one scale
-        # per (position, kv-head) vector of the dense prefill output
-        kq, vq = quantize_kv(k_dense), quantize_kv(v_dense)
-        k_pages = Quantized(
-            q=k_pages.q.at[:, phys, off].set(kq.q),
-            scale=k_pages.scale.at[:, phys, off].set(kq.scale))
-        v_pages = Quantized(
-            q=v_pages.q.at[:, phys, off].set(vq.q),
-            scale=v_pages.scale.at[:, phys, off].set(vq.scale))
-    else:
-        k_pages = k_pages.at[:, phys, off].set(k_dense.astype(k_pages.dtype))
-        v_pages = v_pages.at[:, phys, off].set(v_dense.astype(v_pages.dtype))
-    return k_pages, v_pages
 
 
 @jax.named_scope("kv_write")

@@ -12,7 +12,7 @@ surface, built with failure as a first-class input:
   a rendezvous (HRW) order over the live replicas, so shared-prefix
   traffic lands where its pages already are and the per-engine cache
   pays at fleet scale. The key is a pure function of (prompt, page_size)
-  — stable across prefill modes (chunked vs bucketed), kv dtypes, and
+  — stable across prefill chunk sizes, kv dtypes, and
   processes (content hash, not Python ``hash``). Prompts too short to
   own a cacheable prefix have no key and fall to least-loaded routing.
 - **Load-aware admission** from the engines' lock-free ``stats()``

@@ -282,13 +282,14 @@ class PrefixCache:
             del self._roots[ns]
         return dropped
 
-    def match(self, tokens: list, allow_partial: bool, ns: int = 0):
+    def match(self, tokens: list, ns: int = 0):
         """Longest chain of registered pages covering a PROPER prefix of
         ``tokens`` (at least one token is always left to recompute — the
         last position's logits must come from a live forward). Returns
-        (full_nodes, partial): ``partial`` is (node, n_tokens) when
-        ``allow_partial`` and a child page's content matches ≥ 1 of the
-        remaining tokens — the CoW candidate."""
+        (full_nodes, partial): ``partial`` is (node, n_tokens) when a
+        child page's content matches ≥ 1 of the remaining tokens — the
+        CoW candidate (a prefill chunk starts mid-page, so the match pays
+        for itself at any length)."""
         page = self.page_size
         tick = next(self._tick)
         node, full, pos = self._root_for(ns), [], 0
@@ -300,7 +301,7 @@ class PrefixCache:
             full.append(child)
             node, pos = child, pos + page
         partial = None
-        if allow_partial and pos < len(tokens) - 1:
+        if pos < len(tokens) - 1:
             remaining = tokens[pos:]
             best = 0
             for child in node.children.values():
@@ -448,7 +449,6 @@ class Scheduler:
     def __init__(self, *, n_slots: int, pool: PagePool, max_len: int,
                  max_pages_per_slot: int, clock=time.monotonic,
                  prefix_cache: bool = True,
-                 allow_partial_share: bool = False,
                  max_queue: Optional[int] = None,
                  admission_headroom=None, spec_lookahead: int = 0,
                  adapter_pool=None, decode_horizon: int = 1):
@@ -476,7 +476,6 @@ class Scheduler:
         # registers or matches — admission lives on the prefill side)
         self.cache = (prefix_cache if isinstance(prefix_cache, PrefixCache)
                       else (PrefixCache(pool) if prefix_cache else None))
-        self.allow_partial_share = allow_partial_share
         # extra admission headroom beyond THIS scheduler's running decodes
         # — the disaggregated prefill scheduler has no decoding slots of
         # its own, so its engine threads the DECODE side's count through
@@ -804,8 +803,7 @@ class Scheduler:
         # decode program after the prompt is back (bitwise recompute)
         tokens = list(req.prompt_ids)
         full, partial = ([], None) if self.cache is None else \
-            self.cache.match(tokens, self.allow_partial_share,
-                             ns=int(req.adapter_id))
+            self.cache.match(tokens, ns=int(req.adapter_id))
         k_full = len(full)
         shared_len = k_full * page + (partial[1] if partial else 0)
         n_priv = pages_for_tokens(len(tokens), page) - k_full
@@ -880,9 +878,9 @@ class Scheduler:
     # ---- prefill progress --------------------------------------------------
     def commit_tokens(self, slot_idx: int, n: int) -> None:
         """The engine committed ``n`` more context tokens into the slot's
-        pages (one prefill chunk, or the whole bucket). When the target is
-        reached the slot joins the decode batch and its full prompt pages
-        register in the prefix cache."""
+        pages (one prefill chunk). When the target is reached the slot
+        joins the decode batch and its full prompt pages register in the
+        prefix cache."""
         slot = self.slots[slot_idx]
         assert slot is not None and slot.prefilling, \
             f"commit_tokens on non-prefilling slot {slot_idx}"

@@ -42,7 +42,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .kv_pages import commit_prefill, copy_pages, num_kv_heads, paged_attend
+from .kv_pages import copy_pages, num_kv_heads, paged_attend
 
 # Regex -> PartitionSpec over serve-state tree paths. The pool splits on
 # the kv-head axis (dim 3 of [L, n_pages, page, kvh, hd]); every host-side
@@ -52,7 +52,7 @@ from .kv_pages import commit_prefill, copy_pages, num_kv_heads, paged_attend
 # A QUANTIZED pool (serve/kv_pages.py kv_dtype="int8") is a Quantized
 # NamedTuple per pool: int8 payload [L, P, page, kvh, hd] plus fp32 scales
 # [L, P, page, kvh, 1] — BOTH split on the same kv-head axis (each chip's
-# heads dequantize with each chip's scales, so the manual attend/commit/
+# heads dequantize with each chip's scales, so the manual attend and
 # copy regions stay collective-free; the per-(position, head) scale grain
 # is what makes that possible — a cross-head block would need a gather).
 SERVE_KV_RULES = (
@@ -64,11 +64,9 @@ SERVE_KV_RULES = (
 
 # specs for the shard_map'd regions: activations [S, T, H, D] split on
 # heads, the stacked pools [L, P, page, kvh, hd] split on kv-heads (the
-# attend takes them whole, with the layer's index), dense prefill caches
-# [L, Pb, kvh, hd] split on kv-heads
+# attend takes them whole, with the layer's index)
 _HEADS = P(None, None, "tp", None)
 _POOL_L = P(None, None, None, "tp", None)
-_DENSE_L = P(None, None, "tp", None)
 
 
 def match_partition_rules(rules, tree):
@@ -174,24 +172,6 @@ def make_sharded_attend(mesh: Mesh, tables, lengths, *, impl: str = "auto",
         return sm(*operands)
 
     return attend
-
-
-def make_sharded_commit(mesh: Mesh):
-    """shard_map'd ``commit_prefill``: the dense prefill cache arrives
-    split on its kv-head dim and each chip scatters its slice into its
-    pool slice — the full-kv-head pool never materializes on any chip."""
-
-    def commit(k_pages, v_pages, k_dense, v_dense, table_row, n_tokens,
-               start):
-        sm = jax.shard_map(
-            commit_prefill, mesh=mesh,
-            in_specs=(_POOL_L, _POOL_L, _DENSE_L, _DENSE_L, P(), P(), P()),
-            out_specs=(_POOL_L, _POOL_L),
-            axis_names=_manual(mesh), check_vma=False)
-        return sm(k_pages, v_pages, k_dense, v_dense, table_row, n_tokens,
-                  start)
-
-    return commit
 
 
 def make_sharded_copy(mesh: Mesh):

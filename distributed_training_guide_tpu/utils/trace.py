@@ -55,14 +55,14 @@ SUBSCOPES = ("latent_proj", "shared_expert")
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",
            "paged_latent_attend", "gmm", "tgmm", "qmm")
 
-# jitted programs; a name ending in _k, _b or _t takes the static size that
-# keys the program (serve_horizon_k4, serve_prefill_b128, serve_chunk_t64,
-# serve_verify_t5 and its all-greedy twin serve_verify_t5_greedy)
+# jitted programs; a name ending in _k or _t takes the static size that
+# keys the program (serve_horizon_k4, serve_chunk_t64, serve_verify_t5 and
+# its all-greedy twin serve_verify_t5_greedy)
 PROGRAMS = (
-    "train_step", "serve_decode", "serve_horizon_k", "serve_prefill_b",
-    "serve_chunk_t", "serve_verify_t", "serve_commit", "serve_copy",
-    "serve_sample_one", "serve_adapter_insert", "serve_snapshot",
-    "serve_requant", "serve_draft_step", "serve_draft_catchup",
+    "train_step", "serve_decode", "serve_horizon_k", "serve_chunk_t",
+    "serve_verify_t", "serve_copy", "serve_sample_one",
+    "serve_adapter_insert", "serve_snapshot", "serve_requant",
+    "serve_draft_step", "serve_draft_catchup",
 )
 
 

@@ -9,7 +9,7 @@ import pytest
 
 from distributed_training_guide_tpu.ops.attention import multihead_attention
 from distributed_training_guide_tpu.serve.kv_pages import (
-    TRASH_PAGE, PagePool, commit_prefill, kv_page_bytes, paged_attend,
+    TRASH_PAGE, PagePool, _scatter_new, kv_page_bytes, paged_attend,
     pages_for_tokens)
 from tests.test_paged_decode import stacked_pool
 
@@ -272,10 +272,31 @@ def test_copy_pages_forks_one_physical_page():
     np.testing.assert_array_equal(nvp[:, others], vp[:, others])
 
 
-def test_commit_prefill_skips_shared_prefix_start():
-    """``start`` routes already-resident (shared) positions to the trash
-    page — a bucketed prefill over a shared prefix recomputes but never
-    rewrites pages other sequences read through."""
+def _scatter_every_layer(k_pages, v_pages, k_new, v_new, table_row, start,
+                         n_valid):
+    """A prefill chunk's write as the chunk program makes it: one slot's
+    ``[L, T, h, d]`` new rows through ``_scatter_new``, a layer at a time
+    (the layer scan), into the stacked pools."""
+    @jax.jit
+    def write(kp, vp):
+        for layer in range(k_new.shape[0]):
+            kp, vp, _ = _scatter_new(
+                jnp.asarray(k_new[layer])[None], jnp.asarray(v_new[layer])[None],
+                kp, vp, layer, table_row[None],
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray([n_valid], jnp.int32))
+        return kp, vp
+
+    # tree-generic: an int8 pool is a Quantized pair of leaves
+    return jax.tree.map(np.asarray, write(*jax.tree.map(
+        jnp.asarray, (k_pages, v_pages))))
+
+
+def test_chunk_scatter_skips_shared_prefix_start():
+    """A chunk that starts past a shared prefix (``lengths`` = the shared
+    length, what ``Admission.shared_len`` seats the slot at) writes its own
+    positions only — the prefix page other sequences read through is never
+    rewritten."""
     layers, page, n_pages, h, d = 2, 4, 8, 2, 4
     rng = np.random.default_rng(9)
     marker = rng.standard_normal((layers, page, h, d)).astype(np.float32)
@@ -286,34 +307,36 @@ def test_commit_prefill_skips_shared_prefix_start():
     v_dense = rng.standard_normal((layers, 8, h, d)).astype(np.float32)
     table_row = jnp.asarray([5, 3, 0, 0], jnp.int32)
 
-    nkp, _ = jax.jit(commit_prefill)(
-        jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(k_dense),
-        jnp.asarray(v_dense), table_row, jnp.asarray(6), jnp.asarray(4))
-    nkp = np.asarray(nkp)
+    # positions 0-3 are shared (page 5); the chunk is positions 4.. of a
+    # 6-token prompt, padded to 4 rows
+    nkp, _ = _scatter_every_layer(k_pages, v_pages, k_dense[:, 4:],
+                                  v_dense[:, 4:], table_row, start=4,
+                                  n_valid=2)
     np.testing.assert_array_equal(nkp[:, 5], marker)        # untouched
     for t in (4, 5):                                        # committed
         np.testing.assert_array_equal(nkp[:, 3, t % page], k_dense[:, t])
 
 
-def test_commit_prefill_routes_pad_tail_to_trash():
-    """Bucketed prefill: real tokens land in the slot's pages in logical
-    order, the padded tail goes to page 0, other pages untouched."""
+def test_chunk_scatter_routes_pad_tail_to_trash():
+    """A padded final chunk: real tokens land in the slot's pages in
+    logical order, the padded tail goes to page 0, other pages untouched."""
     layers, page, n_pages, h, d = 2, 4, 8, 2, 4
-    bucket, n_tokens = 8, 6
+    chunk, n_tokens = 8, 6
     rng = np.random.default_rng(2)
-    k_pages = jnp.zeros((layers, n_pages, page, h, d), jnp.float32)
-    v_pages = jnp.zeros_like(k_pages)
-    k_dense = rng.standard_normal((layers, bucket, h, d)).astype(np.float32)
-    v_dense = rng.standard_normal((layers, bucket, h, d)).astype(np.float32)
+    k_pages = np.zeros((layers, n_pages, page, h, d), np.float32)
+    v_pages = np.zeros_like(k_pages)
+    k_dense = rng.standard_normal((layers, chunk, h, d)).astype(np.float32)
+    v_dense = rng.standard_normal((layers, chunk, h, d)).astype(np.float32)
     table_row = jnp.asarray([5, 3, 0, 0], jnp.int32)
 
-    nkp, nvp = jax.jit(commit_prefill)(
-        k_pages, v_pages, jnp.asarray(k_dense), jnp.asarray(v_dense),
-        table_row, jnp.asarray(n_tokens))
-    nkp, nvp = np.asarray(nkp), np.asarray(nvp)
+    nkp, nvp = _scatter_every_layer(k_pages, v_pages, k_dense, v_dense,
+                                    table_row, start=0, n_valid=n_tokens)
     for t in range(n_tokens):
         pg = [5, 3][t // page]
         np.testing.assert_array_equal(nkp[:, pg, t % page], k_dense[:, t])
         np.testing.assert_array_equal(nvp[:, pg, t % page], v_dense[:, t])
     untouched = [p for p in range(1, n_pages) if p not in (5, 3)]
     assert not nkp[:, untouched].any() and not nvp[:, untouched].any()
+    # the slot's own next positions (6, 7: the pad rows' logical places)
+    # are still zero: the tail went to the trash page, not past the prompt
+    assert not nkp[:, 3, 2:].any() and not nvp[:, 3, 2:].any()

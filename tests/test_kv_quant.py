@@ -47,7 +47,7 @@ from distributed_training_guide_tpu.ops.paged_decode import (
 from distributed_training_guide_tpu.serve.api import generate_many
 from distributed_training_guide_tpu.serve.engine import ServeEngine
 from distributed_training_guide_tpu.serve.kv_pages import (
-    commit_prefill, copy_pages, dequantize_kv, init_pages, kv_dtype_name,
+    copy_pages, dequantize_kv, init_pages, kv_dtype_name,
     kv_page_bytes, paged_attend, quantize_kv)
 from distributed_training_guide_tpu.serve.scheduler import Request
 from distributed_training_guide_tpu.train.precision import Quantized
@@ -281,10 +281,13 @@ def test_paged_flash_decode_scale_validation_and_eligibility():
 
 # ---- scale lifecycle -------------------------------------------------------
 
-def test_commit_prefill_int8_writes_scales_and_respects_start():
-    """The bucket-commit write site: quantized payload + scales land at
-    the same (page, offset); ``start`` (shared-prefix territory) and the
-    pad tail route to the trash page for BOTH leaves."""
+def test_chunk_scatter_int8_writes_scales_and_respects_start():
+    """The one write site (``_scatter_new``, here as a prefill chunk that
+    starts past a shared prefix): quantized payload + scales land at the
+    same (page, offset); the shared prefix is never rewritten and the pad
+    tail routes to the trash page, for BOTH leaves."""
+    from tests.test_kv_pages import _scatter_every_layer
+
     layers, page, n_pages, h, d = 2, 4, 8, 2, 16
     rng = np.random.default_rng(5)
     pool = init_pages(
@@ -298,9 +301,11 @@ def test_commit_prefill_int8_writes_scales_and_respects_start():
     k_dense = rng.standard_normal((layers, 8, h, d)).astype(np.float32)
     v_dense = rng.standard_normal((layers, 8, h, d)).astype(np.float32)
     table_row = jnp.asarray([5, 3, 0, 0], jnp.int32)
-    nkp, nvp = jax.jit(commit_prefill)(
-        k_pages, v_pages, jnp.asarray(k_dense), jnp.asarray(v_dense),
-        table_row, jnp.asarray(6), jnp.asarray(4))
+    # a 6-token prompt whose first 4 positions are shared: the chunk holds
+    # positions 4..7, of which 2 are real
+    nkp, nvp = _scatter_every_layer(k_pages, v_pages, k_dense[:, 4:],
+                                    v_dense[:, 4:], table_row, start=4,
+                                    n_valid=2)
     want = quantize_kv(jnp.asarray(k_dense))
     # the shared page (positions < start) is untouched in BOTH leaves
     np.testing.assert_array_equal(np.asarray(nkp.q[:, 5]),
@@ -313,6 +318,12 @@ def test_commit_prefill_int8_writes_scales_and_respects_start():
         np.testing.assert_array_equal(
             np.asarray(nkp.scale[:, 3, t % page]),
             np.asarray(want.scale[:, t]))
+    # the pad rows' logical places (positions 6, 7) keep the pool's bytes
+    for got, was in ((nkp, k_pages), (nvp, v_pages)):
+        np.testing.assert_array_equal(np.asarray(got.q[:, 3, 2:]),
+                                      np.asarray(was.q[:, 3, 2:]))
+        np.testing.assert_array_equal(np.asarray(got.scale[:, 3, 2:]),
+                                      np.asarray(was.scale[:, 3, 2:]))
 
 
 def test_cow_fork_copies_scales():
@@ -438,10 +449,10 @@ def test_int8_prefix_share_and_preemption_pressure(llama):
                       n_pages=12, prefill_chunk=4, kv_dtype="int8")
     res = generate_many(eng, reqs)
     assert eng.scheduler.stats["prefix_hits"] > 0
-    # same prefill MODE as the engine under test: under int8 the chunk
-    # and bucket programs write measurably different caches (chunked
-    # prompts attend over already-quantized history), so identity is
-    # program-relative — see serve/kv_pages.py docstring
+    # same CHUNK SIZE as the engine under test: under int8 two chunkings
+    # of one prompt write measurably different caches (a chunk attends
+    # over already-quantized history), so identity is program-relative —
+    # see serve/kv_pages.py docstring
     ref = ServeEngine(bundle, params, n_slots=1, page_size=4, max_len=24,
                       prefill_chunk=4, prefix_cache=False, kv_dtype="int8")
     for r, req in zip(res, reqs):

@@ -89,26 +89,45 @@ def test_paged_prefill_then_decode_logits_match_the_reference(model, impl):
     assert float(jnp.max(jnp.abs(got - want[PROMPT - 1: -1]))) < LOGIT_TOL
 
 
-def test_bucketed_prefill_commits_the_same_latent_rows_as_the_chunks(model):
+def test_a_padded_chunk_writes_the_latent_rows_narrow_chunks_write(model):
+    """48 tokens as ONE chunk padded to 64 and as three chunks of 16 (whose
+    later chunks attend the rows the earlier ones wrote): the same latent
+    rows in the slot's pages, the pad tail nowhere but the trash page, and
+    the last token's logits those of the full forward ``mla.apply``."""
     cfg, w, bundle, params = model
     config = bundle.config
     tokens = np.random.default_rng(5).integers(0, cfg["vocab_size"], 48)
-    cache = mla.init_cache(config, 1, 64)
-    assert cache["k"].shape == (2, 1, 64, 1, 128) \
-        and cache["v"].shape == (2, 1, 64, 1, 16)
-    ids = np.zeros((1, 64), np.int32)
-    ids[0, :48] = tokens
-    logits, cache = mla.prefill(config, params, jnp.asarray(ids), cache,
-                                last_pos=jnp.asarray(47))
     want = mla.apply(config, params, jnp.asarray(tokens[None]))[0, -1]
-    assert float(jnp.max(jnp.abs(logits[0] - want))) < LOGIT_TOL
-    pages = kv_pages.init_pages(config, 6, PAGE)
-    row = jnp.arange(1, 6, dtype=jnp.int32)
-    kp, vp = kv_pages.commit_prefill(pages["k"], pages["v"], cache["k"][:, 0],
-                                     cache["v"][:, 0], row, 48)
-    # the rope key's pad columns stay zero; the rows are the cache's
+    table = jnp.arange(1, 6, dtype=jnp.int32)[None]
+
+    def prefill(chunk):
+        pages = kv_pages.init_pages(config, 6, PAGE)
+        assert pages["k"].shape == (2, 6, PAGE, 1, 128) \
+            and pages["v"].shape == (2, 6, PAGE, 1, 16)
+        for start in range(0, 48, chunk):
+            real = min(chunk, 48 - start)
+            ids = np.zeros((1, chunk), np.int32)
+            ids[0, :real] = tokens[start:start + real]
+            nv = jnp.asarray([real])
+            logits, pages = mla.paged_decode_step(
+                config, params, jnp.asarray(ids), jnp.asarray([start]), pages,
+                kv_pages.make_attend(table, jnp.asarray([start]), impl="xla",
+                                     n_valid=nv), last_index=real - 1)
+        return logits[0], pages["k"], pages["v"]
+
+    logits, kp, vp = prefill(64)
+    assert float(jnp.max(jnp.abs(logits - want))) < LOGIT_TOL
+    narrow_logits, nkp, nvp = prefill(16)
+    assert float(jnp.max(jnp.abs(narrow_logits - want))) < LOGIT_TOL
+    # the rope key's pad columns stay zero; the rows are the narrow chunks'
     assert float(jnp.max(jnp.abs(kp[..., 8:]))) == 0.0
-    assert np.allclose(vp[:, 1:4].reshape(2, 48, 16), cache["v"][:, 0, :48, 0])
+    assert float(jnp.max(jnp.abs(vp[:, 1:4]))) > 1e-3
+    assert np.allclose(vp[:, 1:4], nvp[:, 1:4], atol=1e-6)
+    assert np.allclose(kp[:, 1:4], nkp[:, 1:4], atol=1e-6)
+    # positions 48..63 of the padded chunk went to the trash page: the
+    # slot's fourth and fifth pages hold nothing
+    assert float(jnp.max(jnp.abs(vp[:, 4:]))) == 0.0
+    assert float(jnp.max(jnp.abs(kp[:, 4:]))) == 0.0
 
 
 def test_absorbed_and_decompressed_attention_are_the_same_sum(model, monkeypatch):
@@ -266,7 +285,7 @@ def test_disaggregated_engine_refuses_the_family(debug_engine_parts):
 
 @pytest.mark.parametrize("engine_kw", [{}, {"prefill_chunk": 16},
                                        {"decode_horizon": 2}],
-                         ids=["bucketed", "chunked", "horizon2"])
+                         ids=["own-size", "chunked", "horizon2"])
 def test_engine_serves_the_recompute_streams_and_counts_routing(
         debug_engine_parts, engine_kw):
     bundle, params = debug_engine_parts

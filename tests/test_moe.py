@@ -269,20 +269,28 @@ def test_ep_ragged_keeps_expert_stacks_local(eight_devices):
 
 @pytest.mark.grouped
 def test_decode_no_drop_transients_scale_with_tokens():
-    """Acceptance pin for the decode-path memory fix: lowering qwen1.5-moe
-    prefill at T=2048 must show O(t*k*d) dispatch transients (the [kT, D]
-    sorted buffer), and NONE of the old no_drop path's O(E*k*t*d)
-    worst-case capacity buffers ([E, kT, D] / [E, kT, F] — ~2 GiB a layer
-    in bf16). Abstract lowering only: no weights materialize."""
+    """Acceptance pin for the decode-path memory fix: lowering a
+    qwen1.5-moe prefill chunk of T=2048 (the serve path's chunk program:
+    ``paged_decode_step`` over the page pools) must show O(t*k*d) dispatch
+    transients (the [kT, D] sorted buffer), and NONE of the old no_drop
+    path's O(E*k*t*d) worst-case capacity buffers ([E, kT, D] /
+    [E, kT, F] — ~2 GiB a layer in bf16). Abstract lowering only: no
+    weights materialize."""
     from distributed_training_guide_tpu.models import moe
+    from distributed_training_guide_tpu.serve import kv_pages
 
     cfg = moe.PRESETS["qwen1.5-moe-a2.7b"]
-    T = 2048
+    T, page = 2048, 16
     params = jax.eval_shape(lambda: moe.init(cfg, jax.random.key(0)))
-    cache = jax.eval_shape(lambda: moe.init_cache(cfg, 1, T))
+    cache = jax.eval_shape(
+        lambda: kv_pages.init_pages(cfg, 1 + T // page, page))
     ids = jax.ShapeDtypeStruct((1, T), jnp.int32)
-    txt = jax.jit(lambda p, i, c: moe.prefill(cfg, p, i, c)).lower(
-        params, ids, cache).as_text()
+    table = jnp.arange(1, 1 + T // page, dtype=jnp.int32)[None]
+    start = jnp.zeros(1, jnp.int32)
+    txt = jax.jit(lambda p, i, c: moe.paged_decode_step(
+        cfg, p, i, start, c,
+        kv_pages.make_attend(table, start, impl="xla"),
+        last_index=T - 1)).lower(params, ids, cache).as_text()
     kT = cfg.experts_per_token * T
     E, D, F = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
     assert hlo_util.has_shape_run(txt, (kT, D)), \

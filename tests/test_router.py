@@ -60,9 +60,9 @@ def test_affinity_key_is_page_aligned_proper_prefix():
 
 def test_affinity_key_sees_only_prompt_and_page_size():
     """The stability satellite, at the source: the key is a pure
-    function of (prompt, page_size, adapter) — engine config (chunked vs
-    bucket prefill, int8 kv_dtype) cannot appear in it because it is
-    never an input. Content-hashed, so stable across processes too."""
+    function of (prompt, page_size, adapter) — engine config (the prefill
+    chunk size, int8 kv_dtype) cannot appear in it because it is never an
+    input. Content-hashed, so stable across processes too."""
     import inspect
 
     sig = inspect.signature(prefix_affinity_key)
@@ -445,17 +445,17 @@ def test_routing_choice_identical_across_engine_configs(llama):
     """The affinity-stability satellite, end to end (the heavy fleet
     grid — 6 engines; the tier-1 pin of the same property is
     test_affinity_key_sees_only_prompt_and_page_size): fleets whose
-    replicas differ in prefill mode (bucket vs chunked) and kv dtype
-    (fp32 vs int8) route the same prompts to the same replica NAMES —
+    replicas differ in prefill chunk size (the engine's own vs 4) and kv
+    dtype (fp32 vs int8) route the same prompts to the same replica NAMES —
     the key never sees engine config, so cache locality survives
     heterogeneous rollouts (e.g. an int8 canary)."""
     bundle, params = llama
     prompts = [list(range(1, 9)) + [50 + i] for i in range(3)] \
         + [[9, 8, 7, 6, 5, 4, 3, 2] + [70 + i] for i in range(3)]
     choices = {}
-    for tag, kw in (("bucket_fp32", {}),
+    for tag, kw in (("own_fp32", {}),
                     ("chunk_fp32", dict(prefill_chunk=4)),
-                    ("bucket_int8", dict(kv_dtype="int8"))):
+                    ("own_int8", dict(kv_dtype="int8"))):
         router = local_fleet(bundle, params, 2, n_slots=2, page_size=4,
                              max_len=16, **kw)
         routed = []
@@ -466,8 +466,8 @@ def test_routing_choice_identical_across_engine_configs(llama):
         choices[tag] = routed
         while router.has_work:
             router.step()
-    assert choices["bucket_fp32"] == choices["chunk_fp32"] \
-        == choices["bucket_int8"]
+    assert choices["own_fp32"] == choices["chunk_fp32"] \
+        == choices["own_int8"]
 
 
 # ---- readiness + HTTP satellites -------------------------------------------

@@ -9,6 +9,25 @@ from distributed_training_guide_tpu.models import get_model
 from distributed_training_guide_tpu.models.sample import make_sampler, main
 
 
+def _chunk_program_logits(bundle, params, prompt, chunk=16, page=16):
+    """The serve path's one prefill, as the engine calls it: the prompt
+    padded to ``chunk`` through ``ModelPrograms.chunk_for`` into one slot's
+    pages. Returns (last real position's logits [V], the k pool)."""
+    from distributed_training_guide_tpu.serve.engine import ModelPrograms
+
+    programs = ModelPrograms(bundle, params)
+    pages = programs.init_device_pages(1 + chunk // page, page)
+    ids = np.zeros((1, chunk), np.int32)
+    ids[0, :len(prompt)] = prompt
+    table = jnp.arange(1, 1 + chunk // page, dtype=jnp.int32)[None]
+    logit, kp, _ = programs.chunk_for(chunk)(
+        programs.params, pages["k"], pages["v"], jnp.asarray(ids),
+        jnp.zeros(1, jnp.int32), table,
+        jnp.asarray(len(prompt) - 1, jnp.int32),
+        jnp.asarray([len(prompt)], jnp.int32))
+    return logit, kp
+
+
 def test_greedy_matches_naive_reference():
     bundle = get_model("llama-debug", dtype=jnp.float32)
     params = bundle.init(bundle.config, jax.random.key(0))
@@ -40,9 +59,10 @@ def test_temperature_sampling_is_seeded_and_in_vocab():
 
 
 def test_kv_cache_matches_recompute():
-    """The cached decode (prefill + one-token steps over the cache) must
-    produce the same greedy tokens as the full-recompute sampler, and the
-    prefill logits must match the plain forward's last position."""
+    """The cached decode (the chunk program + one-token steps over the
+    paged cache) must produce the same greedy tokens as the full-recompute
+    sampler, and the chunk program's logits must match the plain forward's
+    last position."""
     bundle = get_model("llama-debug", dtype=jnp.float32)
     params = bundle.init(bundle.config, jax.random.key(0))
     prompt = [3, 17, 42, 7]
@@ -52,17 +72,18 @@ def test_kv_cache_matches_recompute():
     fast = make_sampler(bundle, kv_cache=True)(params, prompt, steps)
     assert fast == slow
 
-    from distributed_training_guide_tpu.models import llama
-
-    cache = llama.init_cache(bundle.config, 1, len(prompt) + steps)
+    logit, kp = _chunk_program_logits(bundle, params, prompt)
     ids = jnp.asarray(prompt, jnp.int32)[None, :]
-    logit, cache = llama.prefill(bundle.config, params, ids, cache)
     full = bundle.apply(bundle.config, params, ids)
-    np.testing.assert_allclose(np.asarray(logit), np.asarray(full[:, -1]),
+    np.testing.assert_allclose(np.asarray(logit), np.asarray(full[0, -1]),
                                rtol=1e-5, atol=1e-5)
-    assert cache["k"].shape == (2, 1, len(prompt) + steps,
-                                bundle.config.num_kv_heads,
-                                bundle.config.head_size)
+    # the cache is the page pool: the prompt's rows in the slot's page, the
+    # chunk's pad tail nowhere in it
+    assert kp.shape == (2, 2, 16, bundle.config.num_kv_heads,
+                        bundle.config.head_size)
+    kp = np.asarray(kp)
+    assert np.abs(kp[:, 1, :len(prompt)]).min(axis=(2, 3)).all()
+    assert not kp[:, 1, len(prompt):].any()
 
 
 def test_kv_cache_gqa_qwen_bias_family():
@@ -163,7 +184,7 @@ def test_kv_cache_gemma2_matches_recompute():
 
 def test_kv_cache_moe_matches_recompute():
     """The MoE cache path: routed FFN per decoded token (drop-free expert
-    dispatch in prefill/decode) through the shared cache contract. The
+    dispatch in chunk and decode) through the shared cache contract. The
     recompute side uses capacity_factor = num_experts so IT is drop-free
     too — with zero drops on both sides, per-token routing is independent
     of the other buffer rows and cached greedy must equal recompute."""
@@ -174,15 +195,26 @@ def test_kv_cache_moe_matches_recompute():
     fast = make_sampler(bundle, kv_cache=True)(params, prompt, 6)
     assert fast == slow
 
-    # prefill logits == plain forward last position (router included)
-    from distributed_training_guide_tpu.models import moe
-
-    cache = moe.init_cache(bundle.config, 1, len(prompt) + 2)
+    # the chunk program's logits == plain forward last position (router
+    # included)
+    logit, _ = _chunk_program_logits(bundle, params, prompt)
     ids = jnp.asarray(prompt, jnp.int32)[None, :]
-    logit, cache = moe.prefill(bundle.config, params, ids, cache)
     full = bundle.apply(bundle.config, params, ids)
-    np.testing.assert_allclose(np.asarray(logit), np.asarray(full[:, -1]),
+    np.testing.assert_allclose(np.asarray(logit), np.asarray(full[0, -1]),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_kv_cache_mla_moe_matches_recompute():
+    """The latent-attention family through ``kv_cache=True``: one hook
+    (``paged_decode_step``) is all the sampler asks, so the family that
+    never had a contiguous cache serves here like the others — cached
+    greedy tokens must equal the recompute sampler's."""
+    bundle = get_model("mla-moe-debug", dtype=jnp.float32)
+    params = bundle.init(bundle.config, jax.random.key(12))
+    prompt = [14, 2, 55, 9]
+    slow = make_sampler(bundle)(params, prompt, 6)
+    fast = make_sampler(bundle, kv_cache=True)(params, prompt, 6)
+    assert fast == slow
 
 
 def test_kv_cache_qwen2_moe_shared_expert_matches_recompute():

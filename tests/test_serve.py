@@ -158,9 +158,10 @@ def test_impossible_request_refused_at_submit(llama):
 
 
 def test_unservable_configs_refused_up_front(llama):
-    """Requests/configs that would crash mid-flight (seed past int32,
-    buckets that can't cover an admissible prompt) must refuse at submit /
-    construction, before any slot or page is committed."""
+    """Requests/configs that would crash mid-flight (seed past int32, a
+    chunk program of no width) must refuse at submit / construction,
+    before any slot or page is committed; the engine's own chunk size is
+    an integer no wider than one slot's capacity."""
     bundle, params = llama
     eng = ServeEngine(bundle, params, n_slots=1, page_size=4, max_len=16)
     with pytest.raises(ValueError, match="seed"):
@@ -169,12 +170,17 @@ def test_unservable_configs_refused_up_front(llama):
         eng.submit(Request(prompt_ids=[1], top_k=2 ** 31))
     with pytest.raises(ValueError, match="vocab_size"):
         eng.submit(Request(prompt_ids=[bundle.config.vocab_size]))
-    with pytest.raises(ValueError, match="cover"):
-        ServeEngine(bundle, params, n_slots=1, page_size=4, max_len=32,
-                    prefill_buckets=(4, 8))
-    with pytest.raises(ValueError, match="capacity"):
-        ServeEngine(bundle, params, n_slots=1, page_size=4, max_len=16,
-                    prefill_buckets=(64,))
+    from distributed_training_guide_tpu.serve.engine import (
+        DEFAULT_PREFILL_CHUNK, resolve_prefill_chunk)
+
+    for bad in (0, -4):
+        with pytest.raises(ValueError, match="prefill_chunk"):
+            ServeEngine(bundle, params, n_slots=1, page_size=4, max_len=32,
+                        prefill_chunk=bad)
+    assert eng.prefill_chunk == 16          # min(the ceiling, 4 pages x 4)
+    assert resolve_prefill_chunk(None, max_pages=256, page_size=16) \
+        == DEFAULT_PREFILL_CHUNK == 512
+    assert resolve_prefill_chunk(24, max_pages=4, page_size=4) == 24
 
 
 def test_engine_thread_death_fails_waiters_loudly(llama, monkeypatch):
@@ -247,10 +253,9 @@ def test_kv_residency_scales_with_pages_not_slots_times_maxlen(llama):
 
     assert eng.kv_cache_bytes() == kv_page_bytes(cfg, page_size=page,
                                                  n_pages=33)
-    from distributed_training_guide_tpu.models import llama as llama_mod
-
-    dense = llama_mod.init_cache(cfg, n_slots, max_len)
-    dense_bytes = dense["k"].nbytes + dense["v"].nbytes
+    # a dense cache: k and v of [L, n_slots, max_len, kv_heads, head_dim]
+    dense_bytes = 2 * (cfg.num_layers * n_slots * max_len * cfg.num_kv_heads
+                       * cfg.head_size * jnp.dtype(cfg.dtype).itemsize)
     assert eng.kv_cache_bytes() < dense_bytes / 3.5
 
     # (b) lower the ONE decode program and inspect its kv operands
@@ -502,14 +507,14 @@ def test_scheduler_random_trace_invariants(llama, kv_dtype, weight_dtype):
     assert len(done) == len(submitted)
     assert sched.stats["preempted"] > 0        # the trace hit real pressure
     by_id = {r.request_id: r for r in done}
-    # the int8 oracle must share the PREFILL MODE: chunked prefill
-    # attends over already-quantized history while a bucket prefill
-    # computes the whole prompt in float and quantizes once at commit —
-    # under fp32 the two agree to ~1e-7 (never flips this trace), under
-    # int8 that difference is a 1-LSB cache rounding that can. Token
-    # identity is program-relative, and the scheduling-invariance claim
-    # is engine-config-relative — so the reference runs the same chunk
-    # program (see serve/kv_pages.py docstring).
+    # the int8 oracle must share the CHUNK SIZE: a chunk attends over
+    # already-quantized history, so two chunkings of one prompt read
+    # different roundings of it — under fp32 they agree to ~1e-7 (never
+    # flips this trace), under int8 that difference is a 1-LSB cache
+    # rounding that can. Token identity is program-relative, and the
+    # scheduling-invariance claim is engine-config-relative — so the
+    # reference runs the same chunk program (see serve/kv_pages.py
+    # docstring).
     ref_eng = _ref_engine(bundle, params, page_size=4, max_len=16,
                           kv_dtype=kv_dtype, weight_dtype=weight_dtype,
                           prefill_chunk=4 if kv_dtype == "int8" else None)
@@ -725,7 +730,8 @@ def test_chunked_prefill_interleaves_with_resident_decode(llama):
 def test_chunked_prefill_across_families(name):
     """The multi-token chunk path exercises family-specific machinery
     (gpt2's learned position rows, neox's parallel residual, moe's routed
-    FFN over T tokens) — chunked output must equal the bucketed engine's
+    FFN over T tokens) — a prompt cut into chunks of 3 must give the
+    tokens of the engine's own size (here the whole prompt in one chunk)
     for each."""
     over = {"capacity_factor": 4.0} if name == "moe-debug" else {}
     bundle = get_model(name, dtype=jnp.float32, **over)
@@ -735,10 +741,10 @@ def test_chunked_prefill_across_families(name):
     chunked = generate_many(
         ServeEngine(bundle, params, n_slots=2, page_size=4, max_len=16,
                     prefill_chunk=3), [_fresh(r) for r in reqs])
-    bucketed = generate_many(
+    whole = generate_many(
         ServeEngine(bundle, params, n_slots=2, page_size=4, max_len=16),
         [_fresh(r) for r in reqs])
-    for a, b in zip(chunked, bucketed):
+    for a, b in zip(chunked, whole):
         assert a.token_ids == b.token_ids
 
 

@@ -117,8 +117,8 @@ def test_preempt_spill_restore_bitwise_identity(llama, kv_dtype):
     st = eng.stats()
     assert eng.scheduler.stats["preempted"] > 0   # real pressure
     assert st["restore_hits"] > 0                 # real spill-restores
-    # resume is scatter-and-seat, not re-prefill: one bucket prefill per
-    # ADMISSION, plus one only for each preempted entry whose restore
+    # resume is scatter-and-seat, not re-prefill: one prefill chunk per
+    # ADMISSION (every prompt here fits the engine's own chunk size), plus one only for each preempted entry whose restore
     # missed (which then re-admits through the recompute path)
     assert st["prefill_calls"] == len(reqs) + st["restore_misses"]
     ref_eng = _ref_engine(bundle, params, page_size=4, max_len=16,

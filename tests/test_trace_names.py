@@ -80,7 +80,7 @@ def run_requests(engine, n_new=20):
 
 @pytest.mark.parametrize("engine_kw", [{}, {"prefill_chunk": 4},
                                        {"decode_horizon": 4}],
-                         ids=["bucketed", "chunked", "horizon4"])
+                         ids=["own-size", "chunked", "horizon4"])
 def test_serve_span_tree(debug_model, tmp_path, engine_kw):
     engine = tight_engine(debug_model, **engine_kw)
     run_requests(engine, n_new=4)          # compile outside the session
@@ -121,6 +121,10 @@ def test_serve_span_tree(debug_model, tmp_path, engine_kw):
     assert len(admits) >= 3                # two requests and a re-admission
     assert all("request_id" in e[4] for e in events
                if e[0] in ("serve.prefill", "serve.sample"))
+    # one prefill program, whatever the engine was asked for: the chunk
+    # program at the engine's resolved size
+    assert {e[4]["program"] for e in events if e[0] == "serve.prefill"} \
+        == {f"serve_chunk_t{engine.prefill_chunk}"}
     assert all(e[4]["program"].startswith("serve_") for e in events
                if e[0] == "serve.dispatch")
     reserves = [e[4] for e in events if e[0] == "serve.reserve"]
@@ -263,19 +267,20 @@ def test_serve_programs_have_stable_names_and_scopes(debug_model):
             "final_norm", "loss_head", "sample"}
     assert want <= scope_components(text), want - scope_components(text)
     # every jitted program of the two engines, by the name jit gave it
-    fns = [programs._decode_fn, programs._commit_fn, programs._copy_fn,
+    fns = [programs._decode_fn, programs._copy_fn,
            programs._sample_one, *programs._chunk_fns.values(),
            *programs._verify_fns.values(),
-           *plain.programs._prefill_fns.values(),
+           *plain.programs._chunk_fns.values(),
            *plain.programs._horizon_fns.values()]
     got = {fn.__name__ for fn in fns}
-    assert {"serve_decode", "serve_commit", "serve_copy", "serve_sample_one",
-            "serve_chunk_t4", "serve_verify_t3_greedy",
+    # the engine that names no chunk size prefills through the chunk program
+    # at its own size: one slot's capacity here (64 < the ceiling of 512)
+    assert {"serve_decode", "serve_copy", "serve_sample_one",
+            "serve_chunk_t4", "serve_chunk_t64", "serve_verify_t3_greedy",
             "serve_horizon_k2"} <= got
-    assert any(n.startswith("serve_prefill_b") for n in got)
     for name in got:
         assert name != "fn" and "lambda" not in name
-        assert any(name == p or (name.startswith(p) and p[-1] in "kbt")
+        assert any(name == p or (name.startswith(p) and p[-1] in "kt")
                    for p in PROGRAMS), name
 
 
