@@ -696,20 +696,27 @@ def _layer_window_column(config):
     return jnp.asarray([w if w else 2 ** 30 for w in lw], jnp.int32)
 
 
-def scan_paged_layers(body, x, params, cache, wins=None, lora_stacks=None):
+def scan_paged_layers(body, x, layers, cache, wins=None, lora_stacks=None):
     """The layer scan of every family's PAGED step. The stacked page pools
     ``cache["k"], cache["v"]`` ([L, P, page, heads, width] leaves) ride the
     scan as part of the CARRY, whole, beside ``x``; the scanned columns are
-    the layers' weights, the layer's index and, where a family has them, the
+    the layers' weights (``layers``: the ``[L, ...]`` leaves the body wants
+    one layer of), the layer's index and, where a family has them, the
     per-layer windows and the multi-LoRA stacks (None columns are empty
-    pytrees: the scan sees nothing there). ``body(x, pools, layer, i, w,
+    pytrees: the scan sees nothing there). A stacked leaf that a Pallas
+    kernel reads rides WHOLE instead, as the pools do: the scan's slice of a
+    column fuses into a ``dot``'s operand, but a custom call needs it
+    materialised, which is a copy of the layer's share in every iteration.
+    So a routing family leaves its expert leaves out of ``layers``, closes
+    its body over them (loop invariants; ``moe.experts_in_place``) and lets
+    ``gmm`` address the layer by ``i``. ``body(x, pools, layer, i, w,
     lstack) -> (x, pools, ys)`` hands the pools and ``i`` to the attend
     callback (``serve/kv_pages.paged_attend``'s contract), which writes and
     reads them addressed by layer, and returns them whole: nothing
     pool-sized is sliced per iteration or stacked per output, so the donated
     pools are updated in place. ``ys`` is what really is per layer (a routing
     family's counts; None elsewhere). Returns ``(x, {"k", "v"}, ys)``."""
-    n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
+    n_layers = jax.tree.leaves(layers)[0].shape[0]
 
     def step(carry, columns):
         x, pools, ys = body(*carry, *columns)
@@ -718,7 +725,7 @@ def scan_paged_layers(body, x, params, cache, wins=None, lora_stacks=None):
     with jax.named_scope("layers"):   # the scan's slicing of the weights
         (x, (kp, vp)), ys = jax.lax.scan(
             step, (x, (cache["k"], cache["v"])),
-            (params["layers"], jnp.arange(n_layers, dtype=jnp.int32), wins,
+            (layers, jnp.arange(n_layers, dtype=jnp.int32), wins,
              lora_stacks))
     return x, {"k": kp, "v": vp}, ys
 
@@ -810,7 +817,7 @@ def paged_decode_step(config: LlamaConfig, params: dict,
         return x, pools, None
 
     x, pools, _ = scan_paged_layers(
-        body, x, params, cache, wins,
+        body, x, params["layers"], cache, wins,
         None if lora is None else lora["stacks"])
     return (paged_logits_at(lm_head_logits, config, params, x, last_index,
                             all_logits), pools)

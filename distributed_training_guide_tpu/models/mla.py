@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from . import llama
 from .llama import _rmsnorm
-from .moe import _moe_ffn, experts_held
+from .moe import _moe_ffn, experts_held, experts_in_place
 from ..ops.attention import multihead_attention
 from ..ops.paged_decode import ROWS_ALL_HEADS
 from ..ops.rope import _scaling_dict, apply_rope, position_query_scale
@@ -339,11 +339,14 @@ def latent_attention_sublayer(config: MlaMoeConfig, x: jnp.ndarray, p: dict,
     return out, pools
 
 
-def _ffn(config: MlaMoeConfig, x, layer, return_counts=False):
+def _ffn(config: MlaMoeConfig, x, layer, return_counts=False,
+         layer_index=None):
+    """``layer_index``: ``layer["moe"]`` holds the routed-expert leaves of
+    all layers (``moe.experts_in_place``), this layer's at that index."""
     with jax.named_scope("experts"):   # the FFN's pre-norm is its own
         h = _rmsnorm(x, layer["post_attn_norm"], config.rms_norm_eps)
     out = _moe_ffn(config, h, layer["moe"], no_drop=True,
-                   return_counts=return_counts)
+                   return_counts=return_counts, layer_index=layer_index)
     return (x + out[0], out[3]) if return_counts else x + out[0]
 
 
@@ -389,6 +392,7 @@ def paged_decode_step(config: MlaMoeConfig, params: dict,
     the host with its tokens."""
     pos2d = llama.paged_positions(token_ids, positions)
     x = embed_tokens(config, params, token_ids, pos2d)
+    layers, experts = experts_in_place(config, params["layers"])
 
     def body(x, pools, layer, i, *_):
         def bound(q, k_new, v_new, **kw):
@@ -396,10 +400,12 @@ def paged_decode_step(config: MlaMoeConfig, params: dict,
 
         attn, pools = latent_attention_sublayer(
             config, x, layer["attn"], layer["input_norm"], pos2d, bound)
-        x, counts = _ffn(config, x + attn, layer, return_counts=True)
+        layer = {**layer, "moe": {**layer["moe"], **experts}}
+        x, counts = _ffn(config, x + attn, layer, return_counts=True,
+                         layer_index=i if experts else None)
         return x, pools, counts
 
-    x, pools, counts = llama.scan_paged_layers(body, x, params, cache)
+    x, pools, counts = llama.scan_paged_layers(body, x, layers, cache)
     routing = jnp.concatenate([jnp.sum(counts[:, :3], axis=0),
                                jnp.max(counts[:, 3:], axis=0)])
     return (llama.paged_logits_at(lm_head_logits, config, params, x,

@@ -245,16 +245,54 @@ def param_logical_axes(config: MoELlamaConfig) -> dict:
 
 
 def _ragged_expert_compute(x_rows: jnp.ndarray, gate, up, down,
-                           group_sizes: jnp.ndarray, cdt) -> jnp.ndarray:
+                           group_sizes: jnp.ndarray, cdt,
+                           group_offset=None) -> jnp.ndarray:
     """The three expert matmuls as grouped GEMMs over a group-sorted row
     buffer (rows beyond ``sum(group_sizes)`` come back zero — the EP local
-    slice rides that contract)."""
-    h = jax.nn.silu(grouped_matmul(x_rows, gate.astype(cdt), group_sizes))
-    h = h * grouped_matmul(x_rows, up.astype(cdt), group_sizes)
+    slice rides that contract). With ``group_offset`` the three leaves are
+    stacks of more matrices than groups, read from that matrix on
+    (``grouped_matmul``'s ``group_offset``; ``experts_in_place``)."""
+    gmm = partial(grouped_matmul, group_sizes=group_sizes,
+                  group_offset=group_offset)
+    h = jax.nn.silu(gmm(x_rows, gate.astype(cdt)))
+    h = h * gmm(x_rows, up.astype(cdt))
     # tagged for REMAT_POLICIES["attn_mlp"] (the [kT, F] inner activation;
     # same role as the dense path's [E, C, F] / llama's mlp_act)
     h = checkpoint_name(h, "mlp_act")
-    return grouped_matmul(h, down.astype(cdt), group_sizes)
+    return gmm(h, down.astype(cdt))
+
+
+EXPERT_LEAVES = ("gate", "up", "down")
+
+
+def experts_in_place(config, layers: dict):
+    """Take the routed-expert leaves out of what a paged step's layer scan
+    slices: ``(layers without them, {leaf: [L * E, K, N]})``, or ``(layers,
+    {})`` where they are not stored in the compute dtype.
+
+    A scanned ``[L, E, K, N]`` leaf reaches the body as layer ``i``'s ``[E,
+    K, N]`` slice. A ``dot`` takes that slice fused into its operand; the
+    ``gmm`` Pallas call needs a materialised one, so every layer of every
+    step copied all E matrices of all three leaves (1.6 GB a layer at the
+    Mistral cell's size, 29.6 of its 48 ms step) before the kernel read the
+    touched ones. The body closes over the stacked leaves instead (loop
+    invariants, which the scan does not slice), viewed ``[L * E, K, N]`` (the
+    last two dims carry the tiled layout, so a bitcast), and
+    ``_moe_ffn(layer_index=i)`` has ``gmm`` start at matrix ``i * E``.
+
+    Decided on what the leaves are, not on the model's name: with fp32
+    leaves under a bf16 compute dtype the cast would be of the whole stack,
+    once a layer, which is worse than the copy; that case keeps the leaves
+    in the scan and casts the layer's slice. Training keeps per-layer
+    leaves too (``apply_with_aux``): a leaf's gradient is per layer there,
+    and behind an offset it would have the size of the stack."""
+    moe = layers["moe"]
+    if any(moe[k].dtype != jnp.dtype(config.dtype) for k in EXPERT_LEAVES):
+        return layers, {}
+    stacked = {k: moe[k].reshape(-1, *moe[k].shape[2:])
+               for k in EXPERT_LEAVES}
+    rest = {k: v for k, v in moe.items() if k not in EXPERT_LEAVES}
+    return {**layers, "moe": rest}, stacked
 
 
 def experts_held(config) -> tuple[int, int]:
@@ -301,7 +339,8 @@ def _ragged_combine(out_sorted: jnp.ndarray, order, weight_flat,
 
 
 def _ragged_dispatch(config: MoELlamaConfig, xt: jnp.ndarray, topk_idx,
-                     topk_probs, moe: dict, cdt) -> jnp.ndarray:
+                     topk_probs, moe: dict, cdt,
+                     layer_index=None) -> jnp.ndarray:
     """Dropless sorted dispatch (single-shard form): sort (token, choice)
     pairs by expert id, run the experts as grouped GEMMs over the sorted
     [kT, D] buffer, unsort, weight, combine. No capacity buffers, no drops;
@@ -313,6 +352,8 @@ def _ragged_dispatch(config: MoELlamaConfig, xt: jnp.ndarray, topk_idx,
     alone, and the pairs of absent experts lie past ``sum(group_sizes)``,
     where the grouped matmul returns zeros: their part of the sum is left
     out, which is one chip's output before an expert-parallel exchange.
+    ``layer_index``: the expert leaves are every layer's, stacked ``[L *
+    held, K, N]``, and this layer's begin at ``layer_index * held``.
     Returns ``(y, group_sizes)``."""
     t = xt.shape[0]
     ex, k = config.num_experts, config.experts_per_token
@@ -320,8 +361,9 @@ def _ragged_dispatch(config: MoELlamaConfig, xt: jnp.ndarray, topk_idx,
     order, group_sizes, x_sorted, weight_flat = _ragged_sort(
         xt, topk_idx, topk_probs, ex, k, cdt, first)
     group_sizes = group_sizes[:held]
-    out_sorted = _ragged_expert_compute(x_sorted, moe["gate"], moe["up"],
-                                        moe["down"], group_sizes, cdt)
+    out_sorted = _ragged_expert_compute(
+        x_sorted, moe["gate"], moe["up"], moe["down"], group_sizes, cdt,
+        None if layer_index is None else layer_index * held)
     return (_ragged_combine(out_sorted, order, weight_flat, k, t, cdt),
             group_sizes)
 
@@ -329,7 +371,7 @@ def _ragged_dispatch(config: MoELlamaConfig, xt: jnp.ndarray, topk_idx,
 @jax.named_scope("experts")
 def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
              tp_axis: Optional[str] = None, no_drop: bool = False,
-             moe_ep=None, return_counts: bool = False):
+             moe_ep=None, return_counts: bool = False, layer_index=None):
     """Top-k routed FFN. x: [B, S, D]. Returns (y, aux_loss, dropped_frac).
 
     The router is a softmax over the experts, or (``config.router_act ==
@@ -343,6 +385,9 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
     share only (ragged dispatch; see ``_ragged_dispatch``).
     ``return_counts`` adds a fourth result, int32 ``[pairs routed, pairs
     held here, experts touched, the fullest expert's pairs]``.
+    ``layer_index`` (the paged steps, ``experts_in_place``): ``moe``'s
+    ``gate`` / ``up`` / ``down`` are the leaves of ALL layers, stacked ``[L *
+    E, K, N]``, and the local ragged dispatch reads this layer's in place.
 
     Two dispatch backends, selected by ``config.moe_dispatch``:
 
@@ -424,7 +469,8 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
                        moe["gate"], moe["up"], moe["down"])
         else:
             y, group_sizes = _ragged_dispatch(config, xt, topk_idx,
-                                              topk_probs, moe, cdt)
+                                              topk_probs, moe, cdt,
+                                              layer_index)
         dropped_frac = jnp.zeros((), jnp.float32)  # dropless by construction
     else:
         capacity = max(int(math.ceil(config.capacity_factor * k * t / ex)), 1)
@@ -815,6 +861,7 @@ def paged_decode_step(config: MoELlamaConfig, params: dict,
     x = embed_tokens(config, params, token_ids, pos2d)
 
     wins = llama._layer_window_column(config)
+    layers, experts = experts_in_place(config, params["layers"])
 
     def body(x, pools, layer, i, w, _):
         def override(q, k, v, *, window, scale, softcap):
@@ -826,11 +873,12 @@ def paged_decode_step(config: MoELlamaConfig, params: dict,
             "xla", window_override=w, attend_override=override)
         x = x + attn
         h = _rmsnorm(x, layer["post_attn_norm"], config.rms_norm_eps)
-        y, _, _ = _moe_ffn(config, h, layer["moe"], no_drop=True)
+        y, _, _ = _moe_ffn(config, h, {**layer["moe"], **experts},
+                           no_drop=True, layer_index=i if experts else None)
         x = x + y
         return x, pools, None
 
-    x, pools, _ = llama.scan_paged_layers(body, x, params, cache, wins)
+    x, pools, _ = llama.scan_paged_layers(body, x, layers, cache, wins)
     return (llama.paged_logits_at(lm_head_logits, config, params, x,
                                   last_index, all_logits), pools)
 

@@ -381,7 +381,7 @@ def paged_decode_step(config: NeoXConfig, params: dict,
         x, pools = _cached_block(config, x, layer, pos2d, override)
         return x, pools, None
 
-    x, pools, _ = scan_paged_layers(body, x, params, cache)
+    x, pools, _ = scan_paged_layers(body, x, params["layers"], cache)
     return (paged_logits_at(lm_head_logits, config, params, x, last_index,
                             all_logits), pools)
 

@@ -7,6 +7,11 @@ multiplies row-block ``g`` of ``lhs`` (rows ``offs[g]:offs[g+1]`` where
 exploit that contract for expert-parallel local slices, where a worst-case
 static buffer carries a garbage tail.
 
+``group_offset`` (a traced int32 scalar) reads the G matrices out of a
+LARGER stack where it lies: ``rhs [Gtot, K, N]``, group ``g`` multiplies by
+``rhs[group_offset + g]`` (the serve path's ``[L * E, K, N]`` expert leaf
+and ``layer * E``; see :func:`grouped_matmul`).
+
 Three implementations behind one dispatch:
 
 - ``pallas``: a Mosaic kernel in the MegaBlocks spirit (Gale et al.,
@@ -153,8 +158,10 @@ def _work_list(group_sizes, m, bm, nw):
     return offs, wg, wm, jnp.asarray(n_valid, jnp.int32)[None]
 
 
-def _gmm_kernel(offs_ref, wg_ref, wm_ref, nvalid_ref, lhs_ref, rhs_ref,
-                out_ref, acc_ref, *, bm, nw):
+def _gmm_kernel(offs_ref, wg_ref, wm_ref, nvalid_ref, *refs, bm, nw):
+    # a fifth scalar (the group offset into a larger stack) is the index
+    # map's alone: rhs_ref already is the block it chose
+    lhs_ref, rhs_ref, out_ref, acc_ref = refs[-4:]
     w = pl.program_id(1)
     g = wg_ref[w]
     mt = wm_ref[w]
@@ -208,24 +215,39 @@ def _tgmm_kernel(offs_ref, wg_ref, wm_ref, nvalid_ref, lhs_ref, dy_ref,
         out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
 
-def _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret):
+def _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret,
+                    group_offset=None):
+    """``group_offset`` None: ``rhs [G, K, N]``. Else ``rhs [Gtot, K, N]``
+    and the offset is one more scalar-prefetched operand, added where
+    ``rhs``'s block is chosen and nowhere else: ``offs`` and the work list
+    stay group-local."""
     m, k = lhs.shape
-    g, _, n = rhs.shape
+    g, n = group_sizes.shape[0], rhs.shape[2]
     m_tiles = pl.cdiv(m, bm)
     n_tiles = pl.cdiv(n, bn)
     nw = m_tiles + g
-    offs, wg, wm, n_valid = _work_list(group_sizes, m, bm, nw)
+    scalars = _work_list(group_sizes, m, bm, nw)   # offs, wg, wm, n_valid
+    if group_offset is None:
+        def rhs_block(ni, w, offs, wg, wm, nv):
+            return wg[w], 0, ni
+    else:
+        # dynamic_slice's clamp (the XLA impls' contract): a block index
+        # outside the stack would be a DMA outside the operand
+        base = jnp.clip(group_offset, 0, rhs.shape[0] - g)
+        scalars = (*scalars, base[None])
+
+        def rhs_block(ni, w, offs, wg, wm, nv, base):
+            return base[0] + wg[w], 0, ni
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(scalars),
         grid=(n_tiles, nw),
         in_specs=[
-            pl.BlockSpec((bm, k), lambda ni, w, offs, wg, wm, nv: (wm[w], 0)),
-            pl.BlockSpec((1, k, bn),
-                         lambda ni, w, offs, wg, wm, nv: (wg[w], 0, ni)),
+            pl.BlockSpec((bm, k), lambda ni, w, offs, wg, wm, *_: (wm[w], 0)),
+            pl.BlockSpec((1, k, bn), rhs_block),
         ],
         out_specs=pl.BlockSpec((bm, bn),
-                               lambda ni, w, offs, wg, wm, nv: (wm[w], ni)),
+                               lambda ni, w, offs, wg, wm, *_: (wm[w], ni)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
     out = pl.pallas_call(
@@ -234,7 +256,7 @@ def _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret):
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
         name="gmm",
-    )(offs, wg, wm, n_valid, lhs, rhs)
+    )(*scalars, lhs, rhs)
     # row-tiles past the last group are never visited (their memory is
     # whatever the buffer held); the contract says zeros
     total = jnp.sum(group_sizes).astype(jnp.int32)
@@ -272,26 +294,40 @@ def _pallas_tgmm_raw(lhs, dy, group_sizes, g, out_dtype, bm, bn, interpret):
     return jnp.where((group_sizes > 0)[:, None, None], out, 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _pallas_gmm(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret):
-    return _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _pallas_gmm(lhs, rhs, group_sizes, group_offset, out_dtype, bm, bn,
+                interpret):
+    return _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn,
+                           interpret, group_offset)
 
 
-def _pallas_gmm_fwd(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret):
-    out = _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret)
-    return out, (lhs, rhs, group_sizes)
+def _pallas_gmm_fwd(lhs, rhs, group_sizes, group_offset, out_dtype, bm, bn,
+                    interpret):
+    out = _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn,
+                          interpret, group_offset)
+    return out, (lhs, rhs, group_sizes, group_offset)
 
 
 def _pallas_gmm_bwd(out_dtype, bm, bn, interpret, res, dy):
-    lhs, rhs, group_sizes = res
+    lhs, stack, group_sizes, group_offset = res
+    g = group_sizes.shape[0]
+    # with an offset the backward slices its G matrices out and writes
+    # their gradient back into zeros of the stack's shape: correct, not
+    # fast (no caller differentiates through an offset; training hands over
+    # per-layer leaves, whose gradient is per layer)
+    rhs = (stack if group_offset is None
+           else jax.lax.dynamic_slice_in_dim(stack, group_offset, g))
     dy = dy.astype(jnp.float32)
     # d_lhs: the same grouped matmul against rhs^T — rows outside every
     # group get zero gradient (matching their zero primal output)
     dlhs = _pallas_gmm_raw(dy, rhs.astype(jnp.float32).transpose(0, 2, 1),
                            group_sizes, lhs.dtype, bm, bn, interpret)
     drhs = _pallas_tgmm_raw(lhs.astype(jnp.float32), dy, group_sizes,
-                            rhs.shape[0], rhs.dtype, bm, bn, interpret)
-    return dlhs, drhs, None
+                            g, rhs.dtype, bm, bn, interpret)
+    if group_offset is None:
+        return dlhs, drhs, None, None
+    return dlhs, jax.lax.dynamic_update_slice_in_dim(
+        jnp.zeros_like(stack), drhs, group_offset, 0), None, None
 
 
 _pallas_gmm.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
@@ -336,6 +372,7 @@ def grouped_matmul(
     rhs: jnp.ndarray,          # [G, K, N] one matrix per group
     group_sizes: jnp.ndarray,  # [G] int, sum <= M
     *,
+    group_offset=None,         # int32 scalar: rhs is [Gtot, K, N], see below
     impl: str = "auto",
     block_rows: int = 512,
     block_cols: int = 512,
@@ -349,18 +386,46 @@ def grouped_matmul(
     (``jax.lax.ragged_dot``), "einsum" (masked one-hot), or "auto" (pallas
     on TPU, else scan). Rows at index >= ``sum(group_sizes)`` yield zeros
     and propagate zero gradient.
+
+    ``group_offset`` (None, or a traced int32 scalar): ``rhs`` is a stack
+    of ``Gtot >= G`` matrices, read where it lies, and group ``g``
+    multiplies by ``rhs[group_offset + g]`` (an offset past ``Gtot - G``
+    reads the last G matrices, as ``dynamic_slice`` clamps; it is never
+    negative). What rides whole is the
+    serve path's ``[L * E, K, N]`` expert leaf with ``layer * E``: the
+    Pallas kernel adds the offset to the scalar-prefetched index that
+    chooses ``rhs``'s block, and the XLA impls take a ``dynamic_slice``
+    that fuses into their dots, so no impl copies the G matrices out first
+    (a Pallas call needs a materialised operand: handed a layer sliced out
+    of the leaf, it made XLA copy all E matrices of it, every layer of every
+    step, before the kernel read the touched ones).
+    ``None`` is the call without it (``Gtot == G``), the same program as
+    before the argument existed. Gradients are correct with an offset
+    (``d_rhs`` is zeros of the stack's shape but for its G matrices) and not
+    fast: training hands over per-layer leaves.
     """
     if lhs.ndim != 2 or rhs.ndim != 3 or group_sizes.ndim != 1:
         raise ValueError(f"grouped_matmul expects lhs [M,K], rhs [G,K,N], "
                          f"group_sizes [G]; got {lhs.shape}, {rhs.shape}, "
                          f"{group_sizes.shape}")
-    if lhs.shape[1] != rhs.shape[1] or rhs.shape[0] != group_sizes.shape[0]:
+    g = group_sizes.shape[0]
+    stack_ok = rhs.shape[0] == g if group_offset is None else rhs.shape[0] >= g
+    if lhs.shape[1] != rhs.shape[1] or not stack_ok:
         raise ValueError(f"grouped_matmul shape mismatch: lhs {lhs.shape}, "
-                         f"rhs {rhs.shape}, group_sizes {group_sizes.shape}")
+                         f"rhs {rhs.shape}, group_sizes {group_sizes.shape} "
+                         f"(rhs holds G matrices; G or more behind a "
+                         f"group_offset)")
     impl = _resolve_impl(impl)
     out_dtype = preferred_element_type or jnp.promote_types(lhs.dtype,
                                                             rhs.dtype)
     group_sizes = group_sizes.astype(jnp.int32)
+    if group_offset is not None:
+        group_offset = jnp.asarray(group_offset, jnp.int32)
+        if group_offset.ndim:
+            raise ValueError(f"grouped_matmul: group_offset is one int32 "
+                             f"scalar; got shape {group_offset.shape}")
+        if impl != "pallas":
+            rhs = jax.lax.dynamic_slice_in_dim(rhs, group_offset, g)
     if impl == "scan":
         return _gmm_scan(lhs, rhs, group_sizes, out_dtype)
     if impl == "ragged":
@@ -373,5 +438,5 @@ def grouped_matmul(
     bm, bn = _fit_blocks(min(block_rows, lhs.shape[0]),
                          min(block_cols, rhs.shape[2]),
                          max(lhs.shape[1], rhs.shape[2]))
-    return _pallas_gmm(lhs, rhs, group_sizes, jnp.dtype(out_dtype), bm, bn,
-                       interpret)
+    return _pallas_gmm(lhs, rhs, group_sizes, group_offset,
+                       jnp.dtype(out_dtype), bm, bn, interpret)

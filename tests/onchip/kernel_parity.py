@@ -14,7 +14,11 @@ smoke's own shapes, through the functions the entry points call:
   benchmark's serve cell (16 slots of 32/32 heads, bf16, 256 table columns,
   1344 pages) at T = 1 with lengths from 0 to 3000 in one call;
 - ``ops/attention.multihead_attention(impl="flash")`` vs ``impl="xla"``,
-  forward and backward, at the training shape (seq 2048, bf16).
+  forward and backward, at the training shape (seq 2048, bf16);
+- ``ops/grouped_matmul.grouped_matmul(impl="pallas", group_offset=...)``
+  reading layer 4's 32 expert matrices (4096 x 2048, bf16) out of a whole
+  ``[6 * 32, K, N]`` leaf, as the MoE families' paged step hands them over,
+  vs the einsum on those matrices alone.
 
 Every paged case runs on STACKED pools of three layers with different
 contents, the kernel at layer 2 against the gather path on that layer ALONE
@@ -38,7 +42,8 @@ by hand. ``--mla`` runs the latent-attention cell's kernels alone
 64, page 128, 96 table columns, 3073 pages, bf16, T = 1, lengths 0..12,287 in
 one call) against the gathered rows, with three sabotaged kernels the bound
 must refuse, and ``gmm`` at hidden 4096 x expert width 2048 and back with 32
-groups of 0-4 rows in a 128-row buffer (the decode step's held pairs).
+groups of 0-4 rows in a 128-row buffer (the decode step's held pairs), the
+way back once more in place, at layer 5 of the stacked leaf.
 
 One process; fails (no last line, exit 1) off the chip. Prints the entry
 points' start-up device line, one JSON line per case, and last
@@ -331,26 +336,43 @@ def latent_cases() -> int:
     return refused
 
 
-def gmm_decode_case(k: int, n: int) -> None:
+def gmm_decode_case(k: int, n: int, layer=None) -> None:
     """The decode step's expert products: 32 held experts, 0-4 pairs each,
     in the 128-row buffer of 32 tokens x top-4 (rows past the held pairs
-    come back zero)."""
+    come back zero). With ``layer`` the kernel reads them where the serve
+    path's layer scan leaves them: in the whole ``[6 * 32, K, N]`` leaf, from
+    ``group_offset = layer * 32`` on (the other layers hold ones, fifty times
+    the weights' size, so a kernel that read another layer is far outside
+    the bound), against the einsum on that layer's matrices ALONE."""
     gm = importlib.import_module(
         "distributed_training_guide_tpu.ops.grouped_matmul")
-    rows, groups = 128, 32
+    rows, groups, layers = 128, 32, 6
     sizes = jnp.asarray(RNG.integers(0, 5, groups), jnp.int32)
     lhs = normal((rows, k), jnp.bfloat16)
     rhs = normal((groups, k, n), jnp.bfloat16) * jnp.asarray(0.02, jnp.bfloat16)
-    out_p, out_e = (jax.jit(lambda a, b, impl=impl: gm.grouped_matmul(
-        a, b, sizes, impl=impl))(lhs, rhs) for impl in ("pallas", "einsum"))
-    case("gmm decode", {"out": (out_p, out_e)}, hidden=k, expert_width=n,
-         experts=groups, rows=rows, held_pairs=int(sizes.sum()))
+    out_e = jax.jit(lambda a, b: gm.grouped_matmul(
+        a, b, sizes, impl="einsum"))(lhs, rhs)
+    if layer is None:
+        out_p = jax.jit(lambda a, b: gm.grouped_matmul(
+            a, b, sizes, impl="pallas"))(lhs, rhs)
+        case("gmm decode", {"out": (out_p, out_e)}, hidden=k, expert_width=n,
+             experts=groups, rows=rows, held_pairs=int(sizes.sum()))
+        return
+    stack = jax.jit(lambda b: jax.lax.dynamic_update_slice_in_dim(
+        jnp.ones((layers * groups, k, n), b.dtype), b, layer * groups, 0))(rhs)
+    out_p = jax.jit(lambda a, b, at: gm.grouped_matmul(
+        a, b, sizes, group_offset=at, impl="pallas"))(
+            lhs, stack, jnp.int32(layer * groups))
+    case("gmm decode in place", {"out": (out_p, out_e)}, hidden=k,
+         expert_width=n, experts=groups, rows=rows, layer=layer,
+         leaf=list(stack.shape), held_pairs=int(sizes.sum()))
 
 
 def mla_cases() -> int:
     refused = latent_cases()
     gmm_decode_case(4096, 2048)
     gmm_decode_case(2048, 4096)
+    gmm_decode_case(2048, 4096, layer=5)
     return refused
 
 
@@ -395,6 +417,7 @@ def main(argv) -> int:
         paged_case("fp32", 16, t)
     cell_case(1)
     flash_case()
+    gmm_decode_case(4096, 2048, layer=4)
     latent_refused = 3
     if everything:
         latent_refused = mla_cases()

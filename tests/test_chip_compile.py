@@ -137,6 +137,21 @@ def assert_pools_carried_in_place(text: str, *pool_shapes) -> None:
     assert not moved, moved
 
 
+def assert_experts_read_in_place(text: str, *leaf_shapes) -> None:
+    """The expert leaves' twin of ``assert_pools_carried_in_place``: what has
+    a stacked ``[L, E, K, N]`` expert leaf's element count, or one layer's
+    share of it, are the parameters and what renames them (``bitcast``, the
+    loop's tuple). No ``dynamic-slice``, ``copy`` or fusion of that size:
+    with the leaves among the layer scan's sliced columns each layer copied
+    all E matrices of each leaf out for ``gmm`` (three fusions named
+    ``dynamic-slice_bitcast_fusion`` in the compiled decode step)."""
+    sized = pool_sized_ops(text, *leaf_shapes, names=True)
+    assert sum(x.startswith("parameter ") for x in sized) >= len(leaf_shapes)
+    moved = [x for x in sized if x.split()[0] not in (
+        "parameter", "bitcast", "get-tuple-element")]
+    assert not moved, moved
+
+
 # ---- training attention ----------------------------------------------------
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
@@ -426,6 +441,21 @@ def test_grouped_matmul_compiles_at_the_decode_steps_shape(chip_compile, k, n):
     assert any(named(c, "gmm") for c in kernel_calls(text))
 
 
+@pytest.mark.parametrize("k,n", [(4096, 2048), (2048, 4096)])
+def test_grouped_matmul_reads_a_layer_of_the_stacked_leaf(chip_compile, k, n):
+    """The same call on the whole ``[6 * 32, K, N]`` leaf with a traced group
+    offset (``layer * 32``): the kernel takes the parameter as it lies, and
+    nothing of the leaf's size or of one layer's share is sliced or copied."""
+    text = chip_compile(
+        lambda lhs, rhs, sizes, offset: gmm_mod.grouped_matmul(
+            lhs, rhs, sizes, group_offset=offset, impl="pallas",
+            interpret=False),
+        ((128, k), jnp.bfloat16), ((6 * 32, k, n), jnp.bfloat16),
+        ((32,), jnp.int32), ((), jnp.int32))
+    assert any(named(c, "gmm") for c in kernel_calls(text))
+    assert_experts_read_in_place(text, (6, 32, k, n))
+
+
 @pytest.fixture()
 def compiled_kernels(monkeypatch):
     """The family's programs as the chip runs them: under a described
@@ -448,7 +478,8 @@ def test_latent_familys_serve_programs_compile_at_the_cells_size(
     chip's 15.75 GiB, the decode step holds the latent kernel and three
     ``gmm`` calls a layer (one scan body), the chunk no latent kernel (it
     decompresses the gathered rows), and neither moves anything of the
-    donated pools' size (the layer scan carries them)."""
+    donated pools' size (the layer scan carries them) or of an expert leaf's
+    (``gmm`` reads the layer's matrices where the stacked leaf lies)."""
     import dataclasses
 
     from distributed_training_guide_tpu.models import mla
@@ -497,6 +528,51 @@ def test_latent_familys_serve_programs_compile_at_the_cells_size(
         assert not any(named(x, "paged_latent_attend") for x in calls)
         assert sum(named(x, "gmm") for x in calls) == 3
     assert_pools_carried_in_place(text, *(shape for shape, _ in pools.values()))
+    assert_experts_read_in_place(
+        text, *(params["layers"]["moe"][leaf].shape
+                for leaf in ("gate", "up", "down")))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk512"])
+def test_moe_familys_serve_programs_read_the_experts_in_place(
+        chip_compile, compiled_kernels, program):
+    """``moe.paged_decode_step`` (the Mixtral / Qwen-MoE families) at
+    ``qwen3-30b-a3b``'s widths cut to 4 layers, bf16 leaves: the same
+    in-place read through the same code, three ``gmm`` calls a layer and
+    nothing of an expert leaf's size sliced or copied."""
+    import dataclasses
+
+    from distributed_training_guide_tpu.models import moe
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    cfg = dataclasses.replace(moe.PRESETS["qwen3-30b-a3b"], num_layers=4,
+                              dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: moe.init(cfg, jax.random.key(0)))
+    leaves, treedef = jax.tree.flatten(params)
+    weights = [(x.shape, x.dtype) for x in leaves]
+    pool = ((4, CELL_PAGES, CELL_PAGE, cfg.num_kv_heads, D), jnp.bfloat16)
+    slots, t = (16, 1) if program == "decode" else (1, 512)
+
+    def step(kp, vp, ids, lengths, tables, *flat):
+        logits, cache = moe.paged_decode_step(
+            cfg, jax.tree.unflatten(treedef, flat), ids, lengths,
+            {"k": kp, "v": vp},
+            kv_pages.make_attend(tables, lengths, impl="flash",
+                                 n_valid=jnp.full((slots,), t)),
+            last_index=jnp.asarray(t - 1))
+        return jnp.argmax(logits, -1), cache["k"], cache["v"]
+
+    text = chip_compile(step, pool, pool, ((slots, t), jnp.int32),
+                        ((slots,), jnp.int32),
+                        ((slots, CELL_COLUMNS), jnp.int32), *weights,
+                        donate=(0, 1))
+    calls = kernel_calls(text)
+    assert sum(named(x, "gmm") for x in calls) == 3, calls
+    assert sum(named(x, "paged_attend") for x in calls) == 1, calls
+    assert_pools_carried_in_place(text, pool[0])
+    assert_experts_read_in_place(
+        text, *(params["layers"]["moe"][leaf].shape
+                for leaf in ("gate", "up", "down")))
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk512"])

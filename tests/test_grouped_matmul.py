@@ -36,15 +36,34 @@ def _inputs(m, k, n, g, seed=0):
             jnp.asarray(rng.randn(g, k, n), jnp.float32))
 
 
+# ``group_offset``: the G matrices lie in a stack of 3 G, at its start, in
+# its middle or at its end (None: no stack, the call as it always was)
+STACK = 3
+OFFSETS = pytest.mark.parametrize(
+    "at", [None, 0, 1, 2], ids=["no-offset", "offset-zero", "offset-middle",
+                                "offset-last"])
+
+
+def _stacked(m, k, n, g, at, seed=0):
+    """``(lhs, rhs as handed over, its G matrices, the offset or None)``."""
+    if at is None:
+        lhs, rhs = _inputs(m, k, n, g, seed)
+        return lhs, rhs, rhs, None
+    lhs, stack = _inputs(m, k, n, STACK * g, seed)
+    return lhs, stack, stack[at * g:(at + 1) * g], jnp.int32(at * g)
+
+
+@OFFSETS
 @pytest.mark.parametrize("impl", ["scan", "einsum", "ragged", "pallas"])
 @pytest.mark.parametrize("m,k,n,sizes", SHAPES)
-def test_matches_dense_reference(impl, m, k, n, sizes):
-    lhs, rhs = _inputs(m, k, n, len(sizes))
+def test_matches_dense_reference(impl, m, k, n, sizes, at):
+    lhs, rhs, mine, offset = _stacked(m, k, n, len(sizes), at)
     sz = jnp.asarray(sizes, jnp.int32)
-    out = jax.jit(lambda l, r, s: grouped_matmul(
-        l, r, s, impl=impl, block_rows=8, block_cols=8))(lhs, rhs, sz)
+    out = jax.jit(lambda l, r, s, o: grouped_matmul(
+        l, r, s, group_offset=o, impl=impl, block_rows=8,
+        block_cols=8))(lhs, rhs, sz, offset)
     np.testing.assert_allclose(np.asarray(out),
-                               _reference(np.asarray(lhs), np.asarray(rhs),
+                               _reference(np.asarray(lhs), np.asarray(mine),
                                           sizes), rtol=1e-5, atol=1e-5)
 
 
@@ -65,18 +84,21 @@ def test_tail_rows_are_zero_with_zero_grad():
         assert bool(jnp.all(g[20:] == 0)), impl
 
 
+@OFFSETS
 @pytest.mark.parametrize("m,k,n,sizes", SHAPES[:4])
-def test_pallas_grads_match_fallback(m, k, n, sizes):
+def test_pallas_grads_match_fallback(m, k, n, sizes, at):
     """The Pallas custom_vjp (gmm for d_lhs, tgmm for d_rhs) against plain
     autodiff through the einsum fallback, on the interpret path (the same
-    kernels compile on TPU)."""
-    lhs, rhs = _inputs(m, k, n, len(sizes), seed=1)
+    kernels compile on TPU). With an offset ``d_rhs`` has the stack's shape
+    and is zero outside the G matrices that were read."""
+    g = len(sizes)
+    lhs, rhs, _, offset = _stacked(m, k, n, g, at, seed=1)
     sz = jnp.asarray(sizes, jnp.int32)
 
     def loss(impl):
         return jax.jit(jax.grad(
-            lambda l, r: jnp.sum(grouped_matmul(l, r, sz, impl=impl,
-                                                block_rows=8,
+            lambda l, r: jnp.sum(grouped_matmul(l, r, sz, group_offset=offset,
+                                                impl=impl, block_rows=8,
                                                 block_cols=8)**2),
             argnums=(0, 1)))(lhs, rhs)
 
@@ -86,6 +108,62 @@ def test_pallas_grads_match_fallback(m, k, n, sizes):
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(pal_dr), np.asarray(ref_dr),
                                rtol=1e-4, atol=1e-4)
+    if at is not None:
+        assert pal_dr.shape == rhs.shape
+        outside = np.delete(np.asarray(pal_dr),
+                            np.s_[at * g:(at + 1) * g], axis=0)
+        assert not outside.any()
+        if sum(sizes):
+            assert np.asarray(pal_dr)[at * g:(at + 1) * g].any()
+
+
+@pytest.mark.parametrize("impl", ["scan", "einsum", "ragged", "pallas"])
+def test_without_an_offset_the_program_is_the_one_it_was(impl):
+    """``group_offset=None`` traces what the call traced before the argument
+    existed: no slice of ``rhs``, four scalar-prefetched operands in the
+    Pallas call. With an offset the Pallas call takes the WHOLE stack and a
+    fifth scalar (no slice either: the index map adds it), and the XLA
+    impls take one ``dynamic_slice`` of the stack."""
+    m, k, n, g = 16, 8, 16, 4
+    lhs, stack = _inputs(m, k, n, STACK * g)
+    sz = jnp.asarray([3, 0, 9, 4], jnp.int32)
+
+    def traced(rhs, **kw):
+        return str(jax.make_jaxpr(lambda l, r, s: grouped_matmul(
+            l, r, s, impl=impl, block_rows=8, block_cols=8, **kw))(
+                lhs, rhs, sz))
+
+    plain = traced(stack[:g])
+    assert traced(stack[:g], group_offset=None) == plain
+    offset = traced(stack, group_offset=jnp.int32(g))
+    sliced = f"f32[{g},{k},{n}] = dynamic_slice"
+    assert sliced not in plain
+    if impl == "pallas":
+        def scalars(text):    # the kernel's scalar-prefetched operands
+            head = text.split("pallas_call[")[1].split("jaxpr={ lambda ;")[1]
+            return head.split(". let")[0].count("Ref<smem>")
+
+        assert scalars(plain) == 4 and scalars(offset) == 5
+        assert sliced not in offset
+    else:
+        assert offset.count(sliced) == 1
+
+
+@pytest.mark.parametrize("impl", ["scan", "einsum", "ragged", "pallas"])
+def test_an_offset_past_the_stack_clamps_as_dynamic_slice_does(impl):
+    """All four impls keep one contract at the edge too: an offset past
+    ``Gtot - G`` reads the last G matrices of the stack (the kernel must not
+    DMA from outside its operand)."""
+    m, k, n, sizes = SHAPES[0]
+    g = len(sizes)
+    lhs, stack = _inputs(m, k, n, STACK * g)
+    sz = jnp.asarray(sizes, jnp.int32)
+    out = grouped_matmul(lhs, stack, sz, group_offset=jnp.int32(STACK * g),
+                         impl=impl, block_rows=8, block_cols=8)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        _reference(np.asarray(lhs), np.asarray(stack[-g:]), sizes),
+        rtol=1e-5, atol=1e-5)
 
 
 def test_bf16_inputs_and_out_dtype():
@@ -115,3 +193,11 @@ def test_shape_and_impl_validation():
         grouped_matmul(lhs, rhs[:, :4], sz)
     with pytest.raises(ValueError, match="unknown grouped_matmul impl"):
         grouped_matmul(lhs, rhs, sz, impl="cuda")
+    # a stack may hold more matrices than groups only behind an offset, never
+    # fewer, and the offset is one scalar
+    with pytest.raises(ValueError, match="mismatch"):
+        grouped_matmul(lhs, jnp.concatenate([rhs, rhs]), sz)
+    with pytest.raises(ValueError, match="G or more behind a group_offset"):
+        grouped_matmul(lhs, rhs[:3], sz, group_offset=jnp.int32(0))
+    with pytest.raises(ValueError, match="one int32 scalar"):
+        grouped_matmul(lhs, rhs, sz, group_offset=jnp.zeros((4,), jnp.int32))

@@ -316,6 +316,50 @@ def test_engine_serves_the_recompute_streams_and_counts_routing(
     assert 1 <= routing["fullest_expert_pairs"] <= 4
 
 
+def test_paged_step_reads_the_experts_in_place_or_slices_then_casts():
+    """``tests/test_moe.py``'s check on this family's paged step: leaves in
+    the compute dtype ride the layer scan whole, fp32 leaves under bf16
+    compute are sliced and then cast, and the two agree bit for bit."""
+    from tests.test_moe import check_in_place_and_sliced_experts_agree
+
+    check_in_place_and_sliced_experts_agree(
+        mla, lambda dtype: get_model("mla-moe-debug", dtype=dtype))
+
+
+@pytest.mark.parametrize("held", [(0, 8), (2, 4), (5, 3)],
+                         ids=["all", "experts-2-5", "experts-5-7"])
+def test_in_place_experts_of_a_held_share_serve_the_recompute_tokens(
+        debug_engine_parts, held):
+    """``gmm`` starts at matrix ``layer * held`` of the stacked leaf, counted
+    in HELD experts: a share that begins past expert 0 and an odd count
+    serve the tokens the plain forward gives with the same leaves."""
+    bundle, params = debug_engine_parts
+    first, count = held
+    bundle = dataclasses.replace(bundle, config=dataclasses.replace(
+        bundle.config, experts_held=held))
+    moe_p = params["layers"]["moe"]
+    params = {**params, "layers": {**params["layers"], "moe": {
+        **moe_p, **{k: moe_p[k][:, first:first + count]
+                    for k in ("gate", "up", "down")}}}}
+    engine = ServeEngine(bundle, params, n_slots=2, page_size=16, max_len=64,
+                         prefill_chunk=16)
+    prompts = [list(range(3, 24)), [9, 1, 30, 2, 77, 4]]
+    for i, prompt in enumerate(prompts):
+        engine.submit(Request(prompt_ids=prompt, max_new_tokens=6,
+                              temperature=0.0, eos_id=None, seed=i))
+    done = []
+    while engine.has_work:
+        done.extend(engine.step())
+    for r in done:
+        cur = list(prompts[r.request_id])
+        for _ in range(6):
+            logits = bundle.apply(bundle.config, params, jnp.asarray([cur]))
+            cur.append(int(jnp.argmax(logits[0, -1])))
+        assert list(r.generated_ids) == cur[len(prompts[r.request_id]):]
+    routing = engine.stats()["routing"]
+    assert (routing["pairs_held"] < routing["pairs_routed"]) == (count < 8)
+
+
 def test_a_llama_engine_reports_no_routing():
     bundle = get_model("llama-debug")
     params = bundle.init(bundle.config, jax.random.key(0))
@@ -337,9 +381,17 @@ def test_latent_pools_ride_the_layer_scan_as_carry(debug_engine_parts):
     bundle, params = debug_engine_parts
     engine = ServeEngine(bundle, params, n_slots=2, page_size=16, max_len=64,
                          attend_impl="flash", prefill_chunk=16)
+    # off the chip ``gmm`` is the group scan, which takes its layer of the
+    # stacked expert leaves as a slice (fused into its dot): at debug widths
+    # one layer's experts outweigh one layer's pool, so they are named here
+    experts = {params["layers"]["moe"][leaf].shape[1:]
+               for leaf in ("gate", "up", "down")}
     for name, jaxpr in serve_program_jaxprs(engine).items():
         for leaf in ("k", "v"):
             scans = hlo.scans_holding(jaxpr, engine.pages[leaf].shape)
+            for scan in scans:
+                scan["sliced"] = [x for x in scan["sliced"]
+                                  if x[1] not in experts]
             assert scans == [{"carry": 1, "xs": 0, "ys": 0, "sliced": []}], (
                 name, leaf, scans)
 

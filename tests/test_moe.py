@@ -342,3 +342,143 @@ def test_moe_per_layer_windows_flash_matches_xla():
     lg_win, _ = bundle.apply_with_aux(bundle.config, params, ids)
     lg_full, _ = full.apply_with_aux(full.config, params, ids)
     assert float(jnp.max(jnp.abs(lg_win - lg_full))) > 1e-4
+
+
+# ---- the paged step's expert leaves -----------------------------------------
+
+def expert_leaves_in_the_layer_scan(closed_jaxpr, moe_leaves) -> dict:
+    """How the ``[L, E, K, N]`` routed-expert leaves reach a traced paged
+    step's layer scan: ``sliced`` counts those among its scanned columns (a
+    layer's ``[E, K, N]`` is sliced out each iteration), ``whole`` those its
+    body closes over as ``[L * E, K, N]``."""
+    stacked = {tuple(moe_leaves[k].shape) for k in ("gate", "up", "down")}
+    flat = {(s[0] * s[1], *s[2:]) for s in stacked}
+    found = {"sliced": 0, "whole": 0}
+    for eqn in hlo_util._eqns(closed_jaxpr.jaxpr):
+        if eqn.primitive.name != "scan":
+            continue
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        found["whole"] += sum(tuple(v.aval.shape) in flat
+                              for v in eqn.invars[:nc])
+        found["sliced"] += sum(tuple(v.aval.shape) in stacked
+                               for v in eqn.invars[nc + nk:])
+    return found
+
+
+def paged_step_logits(mod, config, params, tokens, page=4):
+    """``mod.paged_decode_step`` teacher-forced over ``tokens`` as a chunk of
+    all but the last and then one decode step; returns ``(jaxpr of the
+    decode step, [chunk logits, decode logits])``."""
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    n = len(tokens) - 1
+    n_pages = 2 + -(-len(tokens) // page)
+    pages = kv_pages.init_pages(config, n_pages, page)
+    table = jnp.arange(1, n_pages, dtype=jnp.int32)[None]
+
+    def step(p, kp, vp, ids, pos):
+        nv = jnp.asarray([ids.shape[1]])
+        logits, cache = mod.paged_decode_step(
+            config, p, ids, pos, {"k": kp, "v": vp},
+            kv_pages.make_attend(table, pos, impl="xla", n_valid=nv))
+        return logits, cache["k"], cache["v"]
+
+    first, kp, vp = jax.jit(step)(params, pages["k"], pages["v"],
+                                  jnp.asarray([tokens[:n]]), jnp.asarray([0]))
+    last = (params, kp, vp, jnp.asarray([tokens[n:]]), jnp.asarray([n]))
+    return jax.make_jaxpr(step)(*last), [first[0], jax.jit(step)(*last)[0][0]]
+
+
+def check_in_place_and_sliced_experts_agree(mod, bundle_for):
+    """Shared by this family and the latent one (tests/test_mla.py): leaves
+    stored in the compute dtype ride the layer scan whole and ``gmm`` reads
+    them at ``layer * E``; fp32 leaves under a bf16 compute dtype stay
+    scanned columns and the layer's slice is cast, as before. The two are the
+    same numbers: the in-place program on the bf16-cast leaves returns the
+    slice-then-cast program's logits bit for bit."""
+    bundle = bundle_for(jnp.bfloat16)
+    config = bundle.config
+    params = bundle.init(config, jax.random.key(0))
+    assert params["layers"]["moe"]["gate"].dtype == jnp.float32
+    tokens = [int(x) for x in np.random.default_rng(3).integers(
+        0, config.vocab_size, 11)]
+    jaxpr, cast_late = paged_step_logits(mod, config, params, tokens)
+    assert expert_leaves_in_the_layer_scan(
+        jaxpr, params["layers"]["moe"]) == {"sliced": 3, "whole": 0}
+
+    cast = {**params["layers"]["moe"], **{
+        k: params["layers"]["moe"][k].astype(jnp.bfloat16)
+        for k in ("gate", "up", "down")}}
+    early = {**params, "layers": {**params["layers"], "moe": cast}}
+    jaxpr, in_place = paged_step_logits(mod, config, early, tokens)
+    assert expert_leaves_in_the_layer_scan(
+        jaxpr, cast) == {"sliced": 0, "whole": 3}
+    for a, b in zip(in_place, cast_late):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+    # and in fp32 (leaves and compute) the in-place step is the plain
+    # forward, token for token
+    bundle = bundle_for(jnp.float32)
+    params = bundle.init(bundle.config, jax.random.key(0))
+    jaxpr, (chunk, decode) = paged_step_logits(mod, bundle.config, params,
+                                               tokens)
+    assert expert_leaves_in_the_layer_scan(
+        jaxpr, params["layers"]["moe"]) == {"sliced": 0, "whole": 3}
+    want = bundle.apply(bundle.config, params, jnp.asarray([tokens]))[0]
+    assert int(jnp.argmax(chunk)) == int(jnp.argmax(want[-2]))
+    assert int(jnp.argmax(decode)) == int(jnp.argmax(want[-1]))
+    np.testing.assert_allclose(np.asarray(decode), np.asarray(want[-1]),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.grouped
+def test_paged_step_reads_the_experts_in_place_or_slices_then_casts():
+    from distributed_training_guide_tpu.models import moe
+
+    check_in_place_and_sliced_experts_agree(
+        moe, lambda dtype: get_model("moe-debug", dtype=dtype,
+                                     capacity_factor=4.0))
+
+
+@pytest.mark.grouped
+def test_in_place_experts_serve_the_recompute_tokens():
+    """The engine on fp32 leaves under fp32 compute (read in place) against
+    the full recompute, token for token (a held share of the experts:
+    tests/test_mla.py, the family whose config states one)."""
+    from distributed_training_guide_tpu.serve import Request, ServeEngine
+    from distributed_training_guide_tpu.serve.api import generate_many
+
+    bundle = get_model("moe-debug", dtype=jnp.float32, moe_dispatch="ragged")
+    params = bundle.init(bundle.config, jax.random.key(0))
+    reqs = [Request(prompt_ids=[3, 17, 42, 7, 9], max_new_tokens=6),
+            Request(prompt_ids=[5, 6], max_new_tokens=8)]
+    res = generate_many(
+        ServeEngine(bundle, params, n_slots=2, page_size=4, max_len=32), reqs)
+    for r in res:
+        cur = list(r.prompt_ids)
+        for _ in r.generated_ids:
+            logits = bundle.apply(bundle.config, params, jnp.asarray([cur]))
+            cur.append(int(jnp.argmax(logits[0, -1])))
+        assert r.token_ids == cur
+
+
+def test_dense_familys_layer_scan_still_slices_every_leaf():
+    """The dense families hand ``scan_paged_layers`` every stacked leaf as a
+    scanned column, as before the MoE families took theirs out: the decode
+    step's layer scan closes over no weight (its constants are the step's
+    small arrays), so their lowered programs are what they were."""
+    from distributed_training_guide_tpu.models import llama
+
+    bundle = get_model("llama-debug", dtype=jnp.float32)
+    params = bundle.init(bundle.config, jax.random.key(0))
+    jaxpr, _ = paged_step_logits(llama, bundle.config, params,
+                                 [3, 17, 42, 7, 9])
+    (scan,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    nc, nk = scan.params["num_consts"], scan.params["num_carry"]
+    columns = [tuple(v.aval.shape) for v in scan.invars[nc + nk:]]
+    leaves = [tuple(x.shape) for x in jax.tree.leaves(params["layers"])]
+    assert sorted(columns) == sorted(leaves + [(bundle.config.num_layers,)])
+    weight_sized = min(np.prod(s) for s in leaves if len(s) > 2)
+    assert all(np.prod(v.aval.shape) < weight_sized
+               for v in scan.invars[:nc])
