@@ -696,9 +696,25 @@ def _layer_window_column(config):
     return jnp.asarray([w if w else 2 ** 30 for w in lw], jnp.int32)
 
 
+POOL_LEAVES = ("k", "v", "state")   # the page pools a cache dict may hold
+
+
+def cache_pools(cache: dict) -> tuple:
+    """The pools of a paged step's ``cache``, in ``POOL_LEAVES`` order: k and
+    v, then a family's per-page recurrent state where it has one."""
+    return tuple(cache[name] for name in POOL_LEAVES if name in cache)
+
+
+def pools_dict(cache: dict, pools: tuple) -> dict:
+    """:func:`cache_pools`' inverse: the updated pools under their names."""
+    return dict(zip((n for n in POOL_LEAVES if n in cache), pools))
+
+
 def scan_paged_layers(body, x, layers, cache, wins=None, lora_stacks=None):
     """The layer scan of every family's PAGED step. The stacked page pools
-    ``cache["k"], cache["v"]`` ([L, P, page, heads, width] leaves) ride the
+    of ``cache`` (``"k"``, ``"v"``: [L, P, page, heads, width] leaves; where
+    the family keeps recurrent state beside them, ``"state"``: [Ls, P, rows,
+    width]) ride the
     scan as part of the CARRY, whole, beside ``x``; the scanned columns are
     the layers' weights (``layers``: the ``[L, ...]`` leaves the body wants
     one layer of), the layer's index and, where a family has them, the
@@ -715,7 +731,10 @@ def scan_paged_layers(body, x, layers, cache, wins=None, lora_stacks=None):
     reads them addressed by layer, and returns them whole: nothing
     pool-sized is sliced per iteration or stacked per output, so the donated
     pools are updated in place. ``ys`` is what really is per layer (a routing
-    family's counts; None elsewhere). Returns ``(x, {"k", "v"}, ys)``."""
+    family's counts; None elsewhere). Returns ``(x, pools under their
+    names, ys)``. A family whose consecutive layers differ in kind
+    (``models/lfm2.py``) walks its layers itself and keeps the same carry:
+    ``cache_pools`` in, ``pools_dict`` out."""
     n_layers = jax.tree.leaves(layers)[0].shape[0]
 
     def step(carry, columns):
@@ -723,11 +742,11 @@ def scan_paged_layers(body, x, layers, cache, wins=None, lora_stacks=None):
         return (x, pools), ys
 
     with jax.named_scope("layers"):   # the scan's slicing of the weights
-        (x, (kp, vp)), ys = jax.lax.scan(
-            step, (x, (cache["k"], cache["v"])),
+        (x, pools), ys = jax.lax.scan(
+            step, (x, cache_pools(cache)),
             (layers, jnp.arange(n_layers, dtype=jnp.int32), wins,
              lora_stacks))
-    return x, {"k": kp, "v": vp}, ys
+    return x, pools_dict(cache, pools), ys
 
 
 def paged_positions(token_ids: jnp.ndarray,

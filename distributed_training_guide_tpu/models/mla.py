@@ -65,6 +65,8 @@ SERVE_REFUSES = {
 
 @dataclasses.dataclass(frozen=True)
 class MlaMoeConfig:
+    latent_cache = True     # the pool holds latent rows (kv_pages.is_latent)
+
     vocab_size: int = 131072
     hidden_size: int = 4096
     num_layers: int = 36

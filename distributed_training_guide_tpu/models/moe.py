@@ -456,8 +456,9 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
             # renormalize the chosen weights (Mixtral: always; Qwen3-MoE:
             # the norm_topk_prob flag — off, the raw softmax mass is the
             # weight)
-            topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1,
-                                              keepdims=True)
+            total = jnp.sum(topk_probs, axis=-1, keepdims=True)
+            eps = getattr(config, "norm_topk_eps", 0.0)   # LFM2: sum + 1e-6
+            topk_probs = topk_probs / (total + eps if eps else total)
         routed_scale = getattr(config, "routed_scaling_factor", 1.0)
         if routed_scale != 1.0:
             topk_probs = topk_probs * routed_scale

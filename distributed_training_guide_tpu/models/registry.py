@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional
 
-from . import gpt2, llama, mla, moe, neox
+from . import gpt2, lfm2, llama, mla, moe, neox
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +53,7 @@ _HF_ALIASES = {
     "eleutherai/pythia-6.9b": "pythia-6.9b",
     "eleutherai/gpt-neox-20b": "gpt-neox-20b",
     "mistralai/mistral-small-4-119b-2603": "mistral-small-4-119b",
+    "liquidai/lfm2-24b-a2b": "lfm2-24b-a2b",
 }
 
 
@@ -60,7 +61,7 @@ def family_module(family: str):
     """The module implementing a model family (block/embed/head helpers used
     by the pipeline schedule and chunked losses)."""
     mods = {"llama": llama, "gpt2": gpt2, "moe": moe, "neox": neox,
-            "mla_moe": mla}
+            "mla_moe": mla, "lfm2_moe": lfm2}
     if family not in mods:
         raise KeyError(f"unknown model family {family!r}")
     return mods[family]
@@ -68,7 +69,8 @@ def family_module(family: str):
 
 def list_models() -> list[str]:
     return (sorted(gpt2.PRESETS) + sorted(llama.PRESETS) + sorted(moe.PRESETS)
-            + sorted(neox.PRESETS) + sorted(mla.PRESETS))
+            + sorted(neox.PRESETS) + sorted(mla.PRESETS)
+            + sorted(lfm2.PRESETS))
 
 
 def get_model(name: str, **overrides) -> ModelBundle:
@@ -117,6 +119,12 @@ def get_model(name: str, **overrides) -> ModelBundle:
             config = dataclasses.replace(config, **overrides)
         return ModelBundle(key, config, mla.init, mla.apply,
                            mla.param_logical_axes, family="mla_moe")
+    if key in lfm2.PRESETS:
+        config = lfm2.PRESETS[key]
+        if overrides:
+            config = dataclasses.replace(config, **overrides)
+        return ModelBundle(key, config, lfm2.init, lfm2.apply,
+                           lfm2.param_logical_axes, family="lfm2_moe")
     raise ValueError(
         f"Unknown model {name!r}. Available: {', '.join(list_models())} "
         f"(HF aliases: {', '.join(sorted(_HF_ALIASES))})"

@@ -132,10 +132,9 @@ def _lane_tiles(columns: int) -> int:
     return -(-columns // 128) * 128
 
 
-def _plan(t, groups, hkv, d, page, max_pages, q_dtype, pool_dtype):
-    """Block parameters from the static shapes: ``(hs, hb, n)`` = kv heads
-    a grid step holds, kv heads one product takes (``hs % hb == 0``), pages
-    a block of the walk holds."""
+def _head_blocks(t, groups, hkv, d, q_dtype, pool_dtype):
+    """``(hb, fits)``: kv heads one product takes, and the head-block sizes
+    whose q, out and accumulator tiles stay inside ``TILE_BUDGET``."""
     tg = t * groups
     words = 4 // jnp.dtype(pool_dtype).itemsize     # rows sharing 32 bits
     # the strided load takes a VMEM block whose rows are one 128-lane tile
@@ -149,6 +148,28 @@ def _plan(t, groups, hkv, d, page, max_pages, q_dtype, pool_dtype):
     fits = [h for h in range(hb, hkv + 1, hb) if hkv % h == 0
             and h * per_head <= TILE_BUDGET
             and (h == hkv or (h * tg) % 16 == 0)]
+    return hb, fits
+
+
+def _query_block(t, groups, hkv, d, q_dtype, pool_dtype) -> int:
+    """Query tokens one kernel call takes: ``t`` where some head block's
+    tile fits the budget, else the largest divisor of ``t`` for which one
+    does (``t`` again if none). A chunk of 1,024 tokens at 8 query heads a
+    pool row is 8,192 rows a row, 20 MB of tiles for the smallest head block:
+    it goes in blocks of 128 tokens, each its own walk of the table."""
+    for bt in sorted((b for b in range(1, t + 1) if t % b == 0),
+                     reverse=True):
+        if _head_blocks(bt, groups, hkv, d, q_dtype, pool_dtype)[1]:
+            return bt
+    return t
+
+
+def _plan(t, groups, hkv, d, page, max_pages, q_dtype, pool_dtype):
+    """Block parameters from the static shapes: ``(hs, hb, n)`` = kv heads
+    a grid step holds, kv heads one product takes (``hs % hb == 0``), pages
+    a block of the walk holds."""
+    tg = t * groups
+    hb, fits = _head_blocks(t, groups, hkv, d, q_dtype, pool_dtype)
     hs = max(fits) if fits else hkv
     page_bytes = page * hkv * d * jnp.dtype(pool_dtype).itemsize
     n = min(MAX_BLOCK_PAGES, max_pages,
@@ -307,7 +328,8 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, base_ref, q_ref, k_hbm,
     o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
-PAGED_GATE = "head_dim % 128 == 0 and page_size % 8 == 0"
+PAGED_GATE = ("pool row width % 128 == 0 (head_dim, or two 64-wide heads a "
+              "row) and page_size % 8 == 0")
 
 
 def paged_decode_eligible(head_dim: int, page_size: int) -> bool:
@@ -315,8 +337,12 @@ def paged_decode_eligible(head_dim: int, page_size: int) -> bool:
     shape), set from what the v5e compiler accepts
     (``tests/test_chip_compile.py``). A page is DMA'd as its
     ``[page * Hkv, D]`` rows: D is the lane dimension of the VMEM block and
-    of both products, so it is whole 128-lane tiles and head_dim 64 models
-    take the gather path; the rows are whole sublane tiles of fp32, bf16
+    of both products, so it is whole 128-lane tiles. ``head_dim`` here is
+    the width of a pool ROW: a family with 64-wide heads stores two kv heads
+    in one row and places each query head in its half (``models/lfm2.py``:
+    the same bytes read, twice the memory-bound MXU work), and takes the
+    kernel; a pool of bare 64-wide rows takes the gather path. The rows are
+    whole sublane tiles of fp32, bf16
     and int8 payloads at every page size the compiler was shown (8..128),
     so one rule serves float and quantized pools. T-independent by
     construction (the query-tile row count only sizes VMEM blocks), which
@@ -396,6 +422,22 @@ def paged_flash_attend(
         raise ValueError(
             f"paged flash attend (compiled) needs {PAGED_GATE}; got "
             f"head_dim={d}, page_size={page} — use impl='xla'")
+    bt = _query_block(t, groups, hkv, d, q.dtype, k_pages.dtype)
+    if bt < t:
+        # a tile no head block fits in VMEM: the query tokens in blocks of
+        # bt, one after the other, each with its own walk from the tokens
+        # it starts at (all T new tokens are in the pool already)
+        def rows(block):
+            qb, first = block
+            return paged_flash_attend(
+                qb, k_pages, v_pages, layer, tables, lengths + first,
+                k_scale=k_scale, v_scale=v_scale, window=window, scale=scale,
+                softcap=softcap, interpret=interpret)
+
+        out = jax.lax.map(rows, (
+            q.reshape(s, t // bt, bt, hq, d).swapaxes(0, 1),
+            jnp.arange(t // bt, dtype=lengths.dtype) * bt))
+        return out.swapaxes(0, 1).reshape(s, t, hq, d)
     band = _pack_band(window)     # [window|2**30, 0, 0] int32 — the same
                                   # dynamic-band contract as the training
                                   # kernels; traced per-layer windows ride it

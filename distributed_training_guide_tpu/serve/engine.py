@@ -71,7 +71,7 @@ from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        adapter_nbytes, adapter_pool_bytes, adapter_shapes,
                        init_adapter_stacks, validate_adapter_params)
 from .kv_pages import (resolve_attend_for, copy_pages, init_pages,
-                       kv_dtype_name, kv_page_bytes, make_attend,
+                       kv_dtype_name, kv_page_bytes, make_attend, state_layout,
                        PagePool, pages_for_tokens, pool_nbytes, TRASH_PAGE)
 from .scheduler import Admission, Request, RequestResult, Scheduler
 from .spec import Drafter, NgramDrafter, new_spec_counters
@@ -360,9 +360,9 @@ def run_fork(programs: "ModelPrograms", pages: dict, adm: Admission) -> None:
     place (the dict is the engine-shared handle)."""
     src, dst = adm.fork
     with span("serve.fork", request_id=adm.request.request_id):
-        pages["k"], pages["v"] = programs._copy_fn(
-            pages["k"], pages["v"],
-            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
+        pages.update(programs._copy_fn(
+            dict(pages), jnp.asarray(src, jnp.int32),
+            jnp.asarray(dst, jnp.int32)))
 
 
 def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
@@ -396,13 +396,14 @@ def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :real] = adm.tokens[start:start + real]
             programs.prefill_calls += 1
-            logit, pages["k"], pages["v"] = programs.chunk_for(chunk)(
-                programs.params, pages["k"], pages["v"],
+            logit, pools = programs.chunk_for(chunk)(
+                programs.params, dict(pages),
                 jnp.asarray(ids), jnp.asarray([start], jnp.int32),
                 jnp.asarray(sched.table_row(slot_idx)[None]),
                 jnp.asarray(real - 1, jnp.int32),
                 jnp.asarray([real], jnp.int32),
                 *programs.lora_call_args([adm.request.adapter_id]))
+            pages.update(pools)
             sched.commit_tokens(slot_idx, real)
         if not sched.slots[slot_idx].prefilling:   # final chunk landed
             pending.pop(slot_idx)
@@ -505,13 +506,14 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
     # the full sampler program
     greedy = all(sched.slots[i].request.temperature == 0.0 for i in active)
     with span("serve.dispatch", program=f"serve_verify_t{t}"):
-        targets, n_acc, dev["lengths"], pages["k"], pages["v"] = \
+        targets, n_acc, dev["lengths"], pools = \
             programs.verify_for(t, greedy=greedy)(
-                programs.params, pages["k"], pages["v"], jnp.asarray(ids),
+                programs.params, dict(pages), jnp.asarray(ids),
                 dev["lengths"], dev["tables"], dev["seeds"], dev["temps"],
                 dev["top_ks"], dev["top_ps"], dev["actives"],
                 jnp.asarray(n_valid),
                 *programs.lora_call_args(dev["adapters"]))
+        pages.update(pools)
     with span("serve.wait"):
         targets = np.asarray(targets)
         n_acc = np.asarray(n_acc)
@@ -566,11 +568,12 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
                    **{key: jnp.asarray(v)
                       for key, v in sched.decode_arrays().items()}}
     with span("serve.dispatch", program="serve_decode"):
-        nxt, new_len, pages["k"], pages["v"], *counted = programs._decode_fn(
-            programs.params, pages["k"], pages["v"],
+        nxt, new_len, pools, *counted = programs._decode_fn(
+            programs.params, dict(pages),
             dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
             dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
             *programs.lora_call_args(dev["adapters"]))
+        pages.update(pools)
     dev["tokens"], dev["lengths"] = nxt, new_len
     with span("serve.wait"):
         nxt_host = np.asarray(counted[0] if counted else nxt)
@@ -628,12 +631,13 @@ def dispatch_horizon(programs: "ModelPrograms", pages: dict,
         dev["tables"] = jnp.asarray(tables)
     with span("serve.dispatch", program=f"serve_horizon_k{k}"):
         (block, dev["tokens"], dev["lengths"], dev["actives"],
-         dev["budgets"], pages["k"], pages["v"]) = programs.horizon_for(k)(
-            programs.params, pages["k"], pages["v"],
+         dev["budgets"], pools) = programs.horizon_for(k)(
+            programs.params, dict(pages),
             dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
             dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
             dev["budgets"], dev["eos_ids"],
             *programs.lora_call_args(dev["adapters"]))
+        pages.update(pools)
     return {"block": block, "k": k, "active": active}
 
 
@@ -942,19 +946,17 @@ class ModelPrograms:
             self._insert_fn = jax.jit(named(self._adapter_insert,
                                             "serve_adapter_insert"))
 
-        kv_out = ((self._kv_sharding, self._kv_sharding)
-                  if self.shard_kv else None)
+        kv_out = self._pool_shardings() if self.shard_kv else None
         self._chunk_fns = {}
         self._verify_fns = {}
         self._horizon_fns = {}
         self._copy_fn = jax.jit(named(copy_impl, "serve_copy"),
-                                donate_argnums=(0, 1),
+                                donate_argnums=(0,),
                                 **({"out_shardings": kv_out}
                                    if kv_out else {}))
         self._decode_fn = jax.jit(
-            named(self._decode, "serve_decode"), donate_argnums=(1, 2),
-            **({"out_shardings": (self._repl, self._repl,
-                                  self._kv_sharding, self._kv_sharding)}
+            named(self._decode, "serve_decode"), donate_argnums=(1,),
+            **({"out_shardings": (self._repl, self._repl, kv_out)}
                if self.shard_kv else {}))
         self._sample_one = jax.jit(named(
             lambda logit, seed, pos, t, tk, tp: _sample_tokens(
@@ -1267,11 +1269,15 @@ class ModelPrograms:
         pages = init_pages(self.config, n_pages, page_size,
                            kv_dtype=self.kv_dtype)
         if self.shard_kv:
-            return jax.device_put(pages, {"k": self._kv_sharding,
-                                          "v": self._kv_sharding})
+            return jax.device_put(pages, self._pool_shardings())
         if self.plan is not None:
             return jax.device_put(pages, self.plan.replicated())
         return pages
+
+    def _pool_shardings(self) -> dict:
+        """The sharded pool's placement, leaf by leaf (k and v: the mesh
+        paths refuse a family with a third leaf)."""
+        return {"k": self._kv_sharding, "v": self._kv_sharding}
 
     def make_attend(self, tables, lengths, *, impl: Optional[str] = None,
                     n_valid=None):
@@ -1306,23 +1312,22 @@ class ModelPrograms:
             return ()
         return (self.adapter_stacks, jnp.asarray(adapters, jnp.int32))
 
-    def _decode(self, params, kp, vp, tokens, lengths, tables, seeds, temps,
+    def _decode(self, params, pools, tokens, lengths, tables, seeds, temps,
                 top_ks, top_ps, actives, *lora_args):
         attend = self.make_attend(tables, lengths)
         logits, cache = self.mod.paged_decode_step(
-            self.config, params, tokens[:, None], lengths,
-            {"k": kp, "v": vp}, attend,
+            self.config, params, tokens[:, None], lengths, pools, attend,
             **({"lora": self._lora_ctx(lora_args)} if lora_args else {}))
         nxt = _sample_tokens(logits.astype(jnp.float32), seeds, lengths + 1,
                              temps, top_ks, top_ps)
         nxt = jnp.where(actives, nxt, 0)
         # the returned (tokens, lengths) ARE next step's inputs: a steady
         # decode run round-trips nothing but the sampled ids to the host
-        out = (nxt, jnp.where(actives, lengths + 1, lengths),
-               cache["k"], cache["v"])
-        if "routing" in cache:   # a routing family's counters ride the
+        routing = cache.pop("routing", None)
+        out = (nxt, jnp.where(actives, lengths + 1, lengths), cache)
+        if routing is not None:   # a routing family's counters ride the
             # host's one read of the step, behind the sampled ids
-            out += (jnp.concatenate([nxt, cache["routing"]]),)
+            out += (jnp.concatenate([nxt, routing]),)
         return out
 
     def note_routing(self, counts) -> None:
@@ -1364,16 +1369,16 @@ class ModelPrograms:
         if k < 1:
             raise ValueError(f"decode horizon must be >= 1, got {k}")
         if k not in self._horizon_fns:
-            def fn(params, kp, vp, tokens, lengths, tables, seeds, temps,
+            def fn(params, pools, tokens, lengths, tables, seeds, temps,
                    top_ks, top_ps, live, budgets, eos_ids, *lora_args):
                 def step(carry, _):
-                    kp, vp, tok, lens, live, budg = carry
+                    pools, tok, lens, live, budg = carry
                     eff_tables = jnp.where(live[:, None], tables,
                                            TRASH_PAGE)
                     attend = self.make_attend(eff_tables, lens)
                     logits, cache = self.mod.paged_decode_step(
-                        self.config, params, tok[:, None], lens,
-                        {"k": kp, "v": vp}, attend,
+                        self.config, params, tok[:, None], lens, pools,
+                        attend,
                         **({"lora": self._lora_ctx(lora_args)}
                            if lora_args else {}))
                     nxt = _sample_tokens(logits.astype(jnp.float32),
@@ -1385,19 +1390,18 @@ class ModelPrograms:
                                         False)
                     new_live = live & ~hit_eos & (new_budg > 0)
                     new_lens = jnp.where(live, lens + 1, lens)
-                    return (cache["k"], cache["v"], nxt, new_lens,
-                            new_live, new_budg), nxt
+                    cache.pop("routing", None)   # the horizon keeps no count
+                    return (cache, nxt, new_lens, new_live, new_budg), nxt
 
-                (kp, vp, tok, lens, live, budg), toks = jax.lax.scan(
-                    step, (kp, vp, tokens, lengths, live, budgets),
+                (pools, tok, lens, live, budg), toks = jax.lax.scan(
+                    step, (pools, tokens, lengths, live, budgets),
                     None, length=k)
-                return toks.T, tok, lens, live, budg, kp, vp
+                return toks.T, tok, lens, live, budg, pools
 
-            kv_out = ((self._repl,) * 5
-                      + (self._kv_sharding, self._kv_sharding)
+            kv_out = ((self._repl,) * 5 + (self._pool_shardings(),)
                       if self.shard_kv else None)
             self._horizon_fns[k] = jax.jit(
-                named(fn, f"serve_horizon_k{k}"), donate_argnums=(1, 2),
+                named(fn, f"serve_horizon_k{k}"), donate_argnums=(1,),
                 **({"out_shardings": kv_out} if kv_out else {}))
         return self._horizon_fns[k]
 
@@ -1412,20 +1416,21 @@ class ModelPrograms:
         chunk's pad tail to the trash page; ``last_index`` picks the
         real last token's logits."""
         if t not in self._chunk_fns:
-            def fn(params, kp, vp, ids, start, table, last_index, n_valid,
+            def fn(params, pools, ids, start, table, last_index, n_valid,
                    *lora_args):
                 attend = self.make_attend(table, start, n_valid=n_valid)
                 logits, cache = self.mod.paged_decode_step(
-                    self.config, params, ids, start, {"k": kp, "v": vp},
+                    self.config, params, ids, start, pools,
                     attend, last_index=last_index,
                     **({"lora": self._lora_ctx(lora_args)}
                        if lora_args else {}))
-                return logits[0], cache["k"], cache["v"]
+                cache.pop("routing", None)
+                return logits[0], cache
 
-            kv_out = ((self._repl, self._kv_sharding, self._kv_sharding)
+            kv_out = ((self._repl, self._pool_shardings())
                       if self.shard_kv else None)
             self._chunk_fns[t] = jax.jit(
-                named(fn, f"serve_chunk_t{t}"), donate_argnums=(1, 2),
+                named(fn, f"serve_chunk_t{t}"), donate_argnums=(1,),
                 **({"out_shardings": kv_out} if kv_out else {}))
         return self._chunk_fns[t]
 
@@ -1462,11 +1467,11 @@ class ModelPrograms:
         batches (any stochastic slot) take the full sampler program."""
         key = (t, bool(greedy))
         if key not in self._verify_fns:
-            def fn(params, kp, vp, ids, lengths, tables, seeds, temps,
+            def fn(params, pools, ids, lengths, tables, seeds, temps,
                    top_ks, top_ps, actives, n_valid, *lora_args):
                 attend = self.make_attend(tables, lengths, n_valid=n_valid)
                 logits, cache = self.mod.paged_decode_step(
-                    self.config, params, ids, lengths, {"k": kp, "v": vp},
+                    self.config, params, ids, lengths, pools,
                     attend, all_logits=True,
                     **({"lora": self._lora_ctx(lora_args)}
                        if lora_args else {}))
@@ -1487,14 +1492,15 @@ class ModelPrograms:
                                     axis=1).sum(axis=1)
                 new_lengths = jnp.where(actives, lengths + n_acc + 1,
                                         lengths)
-                return targets, n_acc, new_lengths, cache["k"], cache["v"]
+                cache.pop("routing", None)
+                return targets, n_acc, new_lengths, cache
 
             kv_out = ((self._repl, self._repl, self._repl,
-                       self._kv_sharding, self._kv_sharding)
+                       self._pool_shardings())
                       if self.shard_kv else None)
             self._verify_fns[key] = jax.jit(
                 named(fn, f"serve_verify_t{t}" + ("_greedy" if greedy else "")),
-                donate_argnums=(1, 2),
+                donate_argnums=(1,),
                 **({"out_shardings": kv_out} if kv_out else {}))
         return self._verify_fns[key]
 
@@ -1651,7 +1657,9 @@ class ServeEngine:
             # tokens a verify step can scatter per running decode
             spec_lookahead=self.drafter.k if self.drafter else 0,
             adapter_pool=self.adapter_pool,
-            decode_horizon=decode_horizon)
+            decode_horizon=decode_horizon,
+            # a page's recurrent-state row is the state at its last token
+            partial_page_hits=state_layout(self.config) is None)
 
         self.pages = self.programs.init_device_pages(n_pages, page_size)
 

@@ -86,6 +86,22 @@ caches ONE row a token a layer: the pool's ``v`` leaf holds the latent
 allocator, the tables, the scatter and the CoW copy below are the same code
 over those two leaves, and the attend is ``_attend_latent``.
 
+A family whose layers keep RECURRENT state beside (or instead of) k and v
+(``models/lfm2.py``: a short convolution's last rows) states it in
+``config.state_layout()`` and the pool gains a third leaf, ``"state"``
+``[state layers, n_pages, rows, width]``, ADDRESSED BY PAGE: row ``p`` of a
+sequence's page ``p`` holds the state after the last token written to that
+page. A step reads the row of the page that holds its previous token and
+writes the row of every page it writes tokens to (:func:`read_state`,
+:func:`write_state`, bound to the tables as ``attend.read_state`` /
+``attend.write_state``). There is still one allocator: the state has page
+identity, so CoW forks, the prefix cache, the host tier, an engine swap and
+preemption move it with the k and v pages (``copy_pages`` and
+``serve/transport.py`` walk every leaf). One thing follows for the prefix
+cache: a row is the state at its page's LAST token, so a hit on such a pool
+ends at a full page (``Scheduler(partial_page_hits=False)``). Only the
+layers that attend have k and v pages (``config.num_kv_layers``).
+
 Device-side pieces (``paged_attend``, ``copy_pages``) are pure functions
 of array arguments — block tables and lengths arrive as int32 arrays, so
 requests coming and going never change a traced shape. The allocator
@@ -93,6 +109,7 @@ requests coming and going never change a traced shape. The allocator
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -130,7 +147,9 @@ def pool_layout(config) -> dict:
     of ``kv_heads x head_dim`` each; a latent-attention family
     (``models/mla.py``) gives ``config.kv_layout()``: ``v`` the latent row
     ``c_kv`` (the absorbed form's values AND the first part of its keys),
-    ``k`` the one rope key all heads share, padded to whole lane tiles."""
+    ``k`` the one rope key all heads share, padded to whole lane tiles; a
+    family with 64-wide heads (``models/lfm2.py``) packs two kv heads into
+    one 128-wide row, the width the compiled kernel takes."""
     layout = getattr(config, "kv_layout", None)
     if layout is not None:
         return layout()
@@ -139,9 +158,22 @@ def pool_layout(config) -> dict:
 
 
 def is_latent(config) -> bool:
-    """True where the pool holds latent rows (``config.kv_layout``): the
+    """True where the pool holds latent rows (``config.latent_cache``): the
     attend is then the absorbed or the decompressed latent form."""
-    return getattr(config, "kv_layout", None) is not None
+    return bool(getattr(config, "latent_cache", False))
+
+
+def num_kv_layers(config) -> int:
+    """Layers that have k and v pages: all of them unless the family says
+    (``config.num_kv_layers``: a hybrid's attention layers)."""
+    return getattr(config, "num_kv_layers", config.num_layers)
+
+
+def state_layout(config) -> Optional[tuple]:
+    """``(state layers, rows, width)`` of the per-page recurrent state a
+    family keeps beside k and v (``config.state_layout()``), or None."""
+    layout = getattr(config, "state_layout", None)
+    return None if layout is None else layout()
 
 
 def kv_dtype_name(config, kv_dtype=None) -> str:
@@ -230,11 +262,16 @@ def resolve_attend_impl(impl: str, head_dim: int, page_size: int,
 
 def resolve_attend_for(config, impl: str, page_size: int) -> tuple[str, str]:
     """:func:`resolve_attend_impl` for a model's own cache layout."""
-    if not is_latent(config):
-        return resolve_attend_impl(impl, config.head_size, page_size)
     layout = pool_layout(config)
+    if not is_latent(config):   # the pool ROW's width (a packed row: 128)
+        return resolve_attend_impl(impl, layout["k"][1], page_size)
     return resolve_attend_impl(impl, layout["v"][1], page_size,
                                latent_rope_width=layout["k"][1])
+
+
+def _state_dtype(name: str):
+    """A state pool is stored in float: the pool's own, fp32 beside int8."""
+    return _KV_FLOAT["fp32" if name == "int8" else name]
 
 
 def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
@@ -246,13 +283,21 @@ def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
     lane padding counted), heads x (width x payload-itemsize [+ 4 B fp32
     scale per vector under int8 — the scales are pool state and are priced,
     not hidden]) — the per-slot serving cost is this at ``n_pages =
-    pages_for_tokens(context)`` (train/preflight.py reports that table)."""
+    pages_for_tokens(context)`` (train/preflight.py reports that table).
+    The layers are those that attend (:func:`num_kv_layers`); a page's
+    recurrent-state rows (:func:`state_layout`) are part of its price."""
     name = kv_dtype_name(config, kv_dtype)
     per_token = sum(
         heads * (width + 4 if name == "int8"
                  else width * jnp.dtype(_KV_FLOAT[name]).itemsize)
         for heads, width in pool_layout(config).values())
-    return n_pages * config.num_layers * page_size * per_token
+    per_page = num_kv_layers(config) * page_size * per_token
+    state = state_layout(config)
+    if state is not None:
+        layers, rows, width = state
+        per_page += layers * rows * width * jnp.dtype(
+            _state_dtype(name)).itemsize
+    return n_pages * per_page
 
 
 def init_pages(config, n_pages: int, page_size: int, kv_dtype=None) -> dict:
@@ -263,17 +308,26 @@ def init_pages(config, n_pages: int, page_size: int, kv_dtype=None) -> dict:
     :func:`pool_layout`, or
     :class:`Quantized` (int8 payload of that shape + fp32 scales [L,
     n_pages, page_size, heads, 1]) under ``kv_dtype="int8"``. Zero scales
-    dequantize to the same zero pool the float form starts with."""
+    dequantize to the same zero pool the float form starts with. L counts
+    the layers that attend (:func:`num_kv_layers`). A family with per-page
+    recurrent state (:func:`state_layout`) gets a third leaf ``"state"``
+    ``[state layers, n_pages, rows, width]``, in the pool's float dtype."""
     name = kv_dtype_name(config, kv_dtype)
 
     def pool(heads, width):
-        shape = (config.num_layers, n_pages, page_size, heads, width)
+        shape = (num_kv_layers(config), n_pages, page_size, heads, width)
         if name == "int8":
             return Quantized(q=jnp.zeros(shape, jnp.int8),
                              scale=jnp.zeros(shape[:-1] + (1,), jnp.float32))
         return jnp.zeros(shape, _KV_FLOAT[name])
 
-    return {leaf: pool(*shape) for leaf, shape in pool_layout(config).items()}
+    pages = {leaf: pool(*shape) for leaf, shape in pool_layout(config).items()}
+    state = state_layout(config)
+    if state is not None:
+        layers, rows, width = state
+        pages["state"] = jnp.zeros((layers, n_pages, rows, width),
+                                   _state_dtype(name))
+    return pages
 
 
 class PagePool:
@@ -650,20 +704,63 @@ def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None):
                             softcap=softcap, impl=impl, n_valid=n_valid,
                             **latent)
 
+    # the same tables address a family's per-page recurrent state
+    attend.read_state = partial(read_state, tables=tables, lengths=lengths)
+    attend.write_state = partial(write_state, tables=tables, lengths=lengths,
+                                 n_valid=n_valid)
     return attend
 
 
+def read_state(state, layer, page: int, *, tables, lengths):
+    """Each slot's recurrent state after its PREVIOUS token, ``[S, rows,
+    width]``, out of the per-page pool ``state [Ls, P, rows, width]`` at
+    state layer ``layer``: the row of the page that holds token ``lengths -
+    1``; zeros for a sequence with nothing cached yet (a reused slot starts
+    from nothing, whatever its pages' last owner left)."""
+    s, m = tables.shape
+    col = jnp.clip((lengths - 1) // page, 0, m - 1)
+    rows = state[layer, tables[jnp.arange(s), col]]
+    return jnp.where((lengths > 0)[:, None, None], rows, 0)
+
+
 @jax.named_scope("kv_write")
-def copy_pages(k_pages, v_pages, src, dst):
+def write_state(state, layer, page: int, history, *, tables, lengths,
+                n_valid=None):
+    """The write half: ``history [S, rows + T, width]`` is each slot's state
+    before the call followed by the T rows its new tokens add, so the state
+    after new token i is ``history[i + 1 : i + 1 + rows]``. Every page the
+    call writes tokens to gets the state after the LAST of them (a decode
+    step: its one page; a chunk: each page it completes and the one it ends
+    in); columns past the valid tokens go to the trash page."""
+    s, m = tables.shape
+    rows = state.shape[2]
+    t = history.shape[1] - rows
+    valid = jnp.full((s,), t, jnp.int32) if n_valid is None else n_valid
+    end = lengths + valid                               # [S] first unwritten
+    n_cols = (t + page - 2) // page + 1                 # pages T tokens touch
+    col = (lengths // page)[:, None] + jnp.arange(n_cols)[None, :]
+    last = jnp.minimum((col + 1) * page, end[:, None]) - 1      # [S, n_cols]
+    touched = last >= jnp.maximum(col * page, lengths[:, None])
+    phys = tables[jnp.arange(s)[:, None], jnp.minimum(col, m - 1)]
+    phys = jnp.where(touched, phys, TRASH_PAGE)
+    first = jnp.clip(last - lengths[:, None], 0, t - 1) + 1     # history row
+    idx = (first[..., None] + jnp.arange(rows)).reshape(s, n_cols * rows)
+    new = jnp.take_along_axis(history, idx[..., None], axis=1)
+    new = new.reshape(s, n_cols, rows, -1).astype(state.dtype)
+    return state.at[layer, phys].set(new)
+
+
+@jax.named_scope("kv_write")
+def copy_pages(pools, src, dst):
     """Copy-on-write fork: duplicate physical page ``src`` into ``dst``
-    across every layer ([L, P, page, kvh, hd] pools; src/dst are traced
-    scalars, so one compile serves every fork). The scheduler calls this
-    before any write lands in a page whose refcount is > 1. Tree-generic
-    over the pool leaves, so a quantized pool's scales fork WITH their
-    payload — a dst page whose scales still described the old content
-    would dequantize garbage."""
+    across every layer of every pool leaf ([L, P, ...]: k, v, a family's
+    per-page state; src/dst are traced scalars, so one compile serves every
+    fork). The scheduler calls this before any write lands in a page whose
+    refcount is > 1. Tree-generic over the pool leaves, so a quantized
+    pool's scales fork WITH their payload — a dst page whose scales still
+    described the old content would dequantize garbage."""
 
     def fork(a):
         return a.at[:, dst].set(a[:, src])
 
-    return jax.tree.map(fork, k_pages), jax.tree.map(fork, v_pages)
+    return jax.tree.map(fork, pools)

@@ -451,7 +451,8 @@ class Scheduler:
                  prefix_cache: bool = True,
                  max_queue: Optional[int] = None,
                  admission_headroom=None, spec_lookahead: int = 0,
-                 adapter_pool=None, decode_horizon: int = 1):
+                 adapter_pool=None, decode_horizon: int = 1,
+                 partial_page_hits: bool = True):
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if max_queue is not None and max_queue < 1:
@@ -476,6 +477,10 @@ class Scheduler:
         # registers or matches — admission lives on the prefill side)
         self.cache = (prefix_cache if isinstance(prefix_cache, PrefixCache)
                       else (PrefixCache(pool) if prefix_cache else None))
+        # False where a page carries recurrent state beside its k and v
+        # (kv_pages.state_layout): the row is the state at the page's LAST
+        # token, so a hit may not end inside a page, and no fork follows
+        self.partial_page_hits = partial_page_hits
         # extra admission headroom beyond THIS scheduler's running decodes
         # — the disaggregated prefill scheduler has no decoding slots of
         # its own, so its engine threads the DECODE side's count through
@@ -804,6 +809,8 @@ class Scheduler:
         tokens = list(req.prompt_ids)
         full, partial = ([], None) if self.cache is None else \
             self.cache.match(tokens, ns=int(req.adapter_id))
+        if not self.partial_page_hits:
+            partial = None
         k_full = len(full)
         shared_len = k_full * page + (partial[1] if partial else 0)
         n_priv = pages_for_tokens(len(tokens), page) - k_full

@@ -178,12 +178,11 @@ def make_sharded_copy(mesh: Mesh):
     """shard_map'd ``copy_pages`` (CoW fork): each chip copies its slice
     of the source page — page ids are replicated scalars."""
 
-    def copy(k_pages, v_pages, src, dst):
+    def copy(pools, src, dst):
+        spec = {"k": _POOL_L, "v": _POOL_L}
         sm = jax.shard_map(
-            copy_pages, mesh=mesh,
-            in_specs=(_POOL_L, _POOL_L, P(), P()),
-            out_specs=(_POOL_L, _POOL_L),
-            axis_names=_manual(mesh), check_vma=False)
-        return sm(k_pages, v_pages, src, dst)
+            copy_pages, mesh=mesh, in_specs=(spec, P(), P()),
+            out_specs=spec, axis_names=_manual(mesh), check_vma=False)
+        return sm(pools, src, dst)
 
     return copy

@@ -71,6 +71,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..models.llama import POOL_LEAVES
 from ..train.precision import Quantized
 from ..utils import faults
 
@@ -92,15 +93,20 @@ class TransportError(RuntimeError):
 # ---- payload <-> pool ------------------------------------------------------
 
 def pool_leaf_names(pages: dict) -> list[str]:
-    """Stable leaf order for the wire: k then v, payload before scales
-    for a quantized pool."""
+    """Stable leaf order for the wire: k then v (then a family's per-page
+    state, ``kv_pages.state_layout``), payload before scales for a
+    quantized pool."""
     names = []
-    for name in ("k", "v"):
+    for name in _pools(pages):
         if isinstance(pages[name], Quantized):
             names.extend([f"{name}.q", f"{name}.scale"])
         else:
             names.append(name)
     return names
+
+
+def _pools(pages: dict) -> list[str]:
+    return [name for name in POOL_LEAVES if name in pages]
 
 
 def _leaf(pages: dict, name: str):
@@ -138,7 +144,7 @@ def scatter_payload(pages: dict, page_ids: list[int],
         return leaf.at[:, idx].set(jnp.asarray(payload[name], leaf.dtype))
 
     out = {}
-    for name in ("k", "v"):
+    for name in _pools(pages):
         leaf = pages[name]
         if isinstance(leaf, Quantized):
             out[name] = Quantized(q=upd(leaf.q, f"{name}.q"),

@@ -46,10 +46,13 @@ SCOPES = (
 )
 
 # named_scope names INSIDE a scope above, which split it without leaving it:
-# `latent_proj` (in `attn`: MLA's low-rank projections and absorbed products)
-# and `shared_expert` (in `experts`). A reader that knows only SCOPES counts
-# their time under the parent; `readers/path_component.py` reads one alone
-SUBSCOPES = ("latent_proj", "shared_expert")
+# `latent_proj` (in `attn`: MLA's low-rank projections and absorbed products),
+# `shared_expert` (in `experts`) and `conv` (in `attn`, which for a hybrid
+# family is the operator sublayer whatever its kind: LFM2's gated short
+# convolution, its norm and projections included). A reader that knows only
+# SCOPES counts their time under the parent; `readers/path_component.py`
+# reads one alone
+SUBSCOPES = ("latent_proj", "shared_expert", "conv")
 
 # pallas_call names (ops/)
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",

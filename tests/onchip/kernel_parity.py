@@ -45,6 +45,15 @@ must refuse, and ``gmm`` at hidden 4096 x expert width 2048 and back with 32
 groups of 0-4 rows in a 128-row buffer (the decode step's held pairs), the
 way back once more in place, at layer 5 of the stacked leaf.
 
+``--lfm2`` runs the hybrid cell's attend alone
+(``lfm2-24b-a2b-l9.serve.chat64``): 64-wide heads through ``paged_attend``
+over pool rows that hold two kv heads each (``models/lfm2._packed_attend``:
+32 query / 8 kv heads, page 128, 10 table columns, 641 pages, 2 attention
+layers, bf16), the decode step (64 slots, lengths 1..1279) and a prefill
+chunk (T = 1,024, its query tokens in blocks of 128), each against the gather
+path over the same rows, with one sabotage the bound must refuse (the two
+heads of every pool row swapped).
+
 One process; fails (no last line, exit 1) off the chip. Prints the entry
 points' start-up device line, one JSON line per case, and last
 ``{"kernel_parity_ok": true, "cases": N, "controls_refused": 5}``.
@@ -376,6 +385,71 @@ def mla_cases() -> int:
     return refused
 
 
+# the hybrid cell (lfm2-24b-a2b-l9.serve.chat64): heads of 64 packed two to a row
+HYBRID = dict(hq=32, hkv=8, d=64, page=128, columns=10, pages=641, layers=2)
+
+
+def hybrid_attend(impl: str, t: int, lengths, sabotage: bool = False):
+    """One attention layer's paged call as the family makes it; ``sabotage``
+    swaps the two kv heads of every pool row, new rows and cached ones."""
+    import dataclasses
+
+    from distributed_training_guide_tpu.models import lfm2
+
+    h = HYBRID
+    cfg = dataclasses.replace(lfm2.PRESETS["lfm2-24b-a2b"],
+                              dtype=jnp.bfloat16)
+    n = len(lengths)
+    rng = np.random.default_rng(7)
+    draw = lambda shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    q = draw((n, t, h["hq"], h["d"]))
+    k_new, v_new = (draw((n, t, h["hkv"], h["d"])) for _ in range(2))
+    k_pool, v_pool = (draw((h["layers"], h["pages"], h["page"],
+                            h["hkv"] // 2, 2 * h["d"])) for _ in range(2))
+    if sabotage:
+        swap = lambda x, axis: jnp.flip(x.reshape(
+            *x.shape[:axis], -1, 2, h["d"]), axis=-2).reshape(x.shape)
+        k_new, v_new = swap(k_new, 2), swap(v_new, 2)
+        k_pool, v_pool = swap(k_pool, 3), swap(v_pool, 3)
+    tables = np.zeros((n, h["columns"]), np.int32)
+    free = iter(rng.permutation(np.arange(1, h["pages"])))
+    for i, length in enumerate(lengths):
+        need = -(-(length + t) // h["page"])
+        tables[i, :need] = [next(free) for _ in range(need)]
+    lens = jnp.asarray(lengths, jnp.int32)
+
+    def call(q, k_new, v_new, k_pool, v_pool, tables):
+        hook = lfm2._packed_attend(
+            cfg, kv_pages.make_attend(tables, lens, impl=impl),
+            (k_pool, v_pool), 1)
+        return hook(q, k_new, v_new, window=None, scale=None, softcap=None)[0]
+
+    return jax.jit(call)(q, k_new, v_new, k_pool, v_pool, jnp.asarray(tables))
+
+
+def lfm2_cases(chunks: bool = True) -> int:
+    """The decode step (the smoke's path too), and with ``chunks`` two
+    prefill chunks and the control; returns how many controls were refused."""
+    decode = [1, 2, 127, 128, 129, 255, 256, 640, 1023, 1024, 1025, 1279] * 5 \
+        + [300, 700, 900, 1100]
+    shapes = ((1, decode), (1024, [0]), (1024, [256]))
+    for t, lengths in shapes if chunks else shapes[:1]:
+        case("paged_attend packed 64-wide heads",
+             {"out": (hybrid_attend("flash", t, lengths),
+                      hybrid_attend("xla", t, lengths))},
+             pool="bf16", page=128, T=t, heads="32/8 of 64, two a row",
+             slots=len(lengths), lengths=f"{min(lengths)}..{max(lengths)}")
+    if not chunks:
+        return 0
+    want = hybrid_attend("xla", 1, decode)
+    err, ref = worst(hybrid_attend("flash", 1, decode, sabotage=True), want)
+    caught = err > RTOL * max(1.0, ref)
+    print(json.dumps({"control": "a_rows_two_heads_swapped", "max_abs_err": err,
+                      "ref_max": ref, "rtol": RTOL, "refused": caught}),
+          flush=True)
+    return int(caught)
+
+
 def int8_matmul_case() -> None:
     qm = importlib.import_module(
         "distributed_training_guide_tpu.ops.quantized_matmul")
@@ -394,14 +468,25 @@ def int8_matmul_case() -> None:
 
 def main(argv) -> int:
     everything, mla_only = argv == ["--all"], argv == ["--mla"]
-    if argv and not (everything or mla_only):
-        raise SystemExit("usage: kernel_parity.py [--all|--mla]")
+    lfm2_only = argv == ["--lfm2"]
+    if argv and not (everything or mla_only or lfm2_only):
+        raise SystemExit("usage: kernel_parity.py [--all|--mla|--lfm2]")
     print_device_line("attend", ("flash", "forced"), CACHE.directory)
     if jax.devices()[0].platform != EXPECT_PLATFORM:
         print(f"kernel_parity FAILED: runs on "
               f"{jax.devices()[0].platform!r}, not {EXPECT_PLATFORM!r}",
               file=sys.stderr)
         return 1
+    if lfm2_only:
+        refused = lfm2_cases()
+        CACHE.print_line()
+        if FAILED or refused != 1:
+            print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
+                  f"swapped heads refused: {refused} of 1", file=sys.stderr)
+            return 1
+        print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
+                          "controls_refused": refused}), flush=True)
+        return 0
     if mla_only:
         refused = mla_cases()
         CACHE.print_line()
@@ -418,6 +503,7 @@ def main(argv) -> int:
     cell_case(1)
     flash_case()
     gmm_decode_case(4096, 2048, layer=4)
+    lfm2_cases(chunks=everything)
     latent_refused = 3
     if everything:
         latent_refused = mla_cases()

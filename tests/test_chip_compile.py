@@ -281,14 +281,15 @@ def test_paged_attend_compiles_at_head_dim_256(chip_compile, slots, t):
 
 def test_paged_attend_gate_matches_the_compiler(chip_compile):
     """Where the gate says no, the compiler says no, and the forced path
-    raises the gate's own error first: head_dim 64 is half a lane tile of
-    a page's ``[page * Hkv, D]`` rows."""
+    raises the gate's own error first: a pool row of 64 columns is half a
+    lane tile of a page's ``[page * Hkv, D]`` rows (a family with 64-wide
+    heads stores two a row: ``models/lfm2.py``)."""
     assert not paged_decode_eligible(64, 16)
     specs = [((4, 1, 16, 64), jnp.float32),
              ((N_LAYERS, 64, 16, 8, 64), jnp.float32),
              ((N_LAYERS, 64, 16, 8, 64), jnp.float32), LAYER,
              ((4, 8), jnp.int32), ((4,), jnp.int32)]
-    with pytest.raises(ValueError, match="head_dim % 128"):
+    with pytest.raises(ValueError, match="row width % 128"):
         chip_compile(lambda *a: paged_flash_attend(*a, interpret=False),
                      *specs)
 
@@ -528,6 +529,69 @@ def test_latent_familys_serve_programs_compile_at_the_cells_size(
         assert not any(named(x, "paged_latent_attend") for x in calls)
         assert sum(named(x, "gmm") for x in calls) == 3
     assert_pools_carried_in_place(text, *(shape for shape, _ in pools.values()))
+    assert_experts_read_in_place(
+        text, *(params["layers"]["moe"][leaf].shape
+                for leaf in ("gate", "up", "down")))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk1024"])
+def test_hybrid_familys_serve_programs_compile_at_the_cells_size(
+        chip_compile, compiled_kernels, program):
+    """``lfm2-24b-a2b-l9.serve.chat64``'s decode step (64 slots) and prefill
+    chunk (1,024 tokens), whole, at the cell's size (9.99 GiB of weights,
+    k and v pools of 2 attention layers and the state pool of 7 conv layers
+    over 641 pages of 128): heads of 64 go through the compiled
+    ``paged_attend`` over rows of two kv heads (one call an attention layer;
+    the chunk's query tokens in blocks of 128 inside one loop), ``gmm``
+    three times an expert layer (the layers are walked, not scanned), and
+    nothing expert-sized or pool-sized is copied: every pool-sized result is
+    a parameter, a rename, an in-place ``scatter`` (two an attention layer,
+    one a conv layer) or the fusion that holds one."""
+    import dataclasses
+    import json
+    from pathlib import Path
+
+    from distributed_training_guide_tpu.models import lfm2
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    real = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                       / "configs" / "lfm2-24b-a2b-l9.json").read_text())
+    cfg = dataclasses.replace(
+        lfm2.PRESETS["lfm2-24b-a2b"], layer_types=tuple(real["layer_types"]),
+        num_dense_layers=real["num_dense_layers"], dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: lfm2.init(cfg, jax.random.key(0)))
+    leaves, treedef = jax.tree.flatten(params)
+    weights = [(x.shape, x.dtype) for x in leaves]
+    pools = jax.eval_shape(lambda: kv_pages.init_pages(cfg, 641, 128))
+    assert pools["k"].shape == (2, 641, 128, 4, 128)
+    slots, t = (64, 1) if program == "decode" else (1, 1024)
+
+    def step(kp, vp, sp, ids, lengths, tables, *flat):
+        logits, cache = lfm2.paged_decode_step(
+            cfg, jax.tree.unflatten(treedef, flat), ids, lengths,
+            {"k": kp, "v": vp, "state": sp},
+            kv_pages.make_attend(tables, lengths, impl="flash",
+                                 n_valid=jnp.full((slots,), t)),
+            last_index=jnp.asarray(t - 1))
+        return (jnp.argmax(logits, -1), cache["k"], cache["v"],
+                cache["state"], cache["routing"])
+
+    text = chip_compile(
+        step, *((pools[n].shape, pools[n].dtype) for n in ("k", "v", "state")),
+        ((slots, t), jnp.int32), ((slots,), jnp.int32),
+        ((slots, 10), jnp.int32), *weights, donate=(0, 1, 2))
+    calls = kernel_calls(text)
+    assert sum(named(x, "gmm") for x in calls) == 3 * 8, calls
+    assert sum(named(x, "paged_attend") for x in calls) == 2, calls
+    sized = pool_sized_ops(text, pools["k"].shape, pools["state"].shape,
+                           names=True)
+    assert sum(x.startswith("scatter ") for x in sized) == 2 * 2 + 7, sized
+    moved = [x for x in sized
+             if x.split()[0] not in ("parameter", "bitcast", "scatter",
+                                     "get-tuple-element", "fusion")
+             or "slice" in x or "copy" in x]
+    assert not moved, moved
     assert_experts_read_in_place(
         text, *(params["layers"]["moe"][leaf].shape
                 for leaf in ("gate", "up", "down")))

@@ -262,7 +262,7 @@ def test_copy_pages_forks_one_physical_page():
     rng = np.random.default_rng(8)
     kp = rng.standard_normal((2, 6, 4, 2, 8)).astype(np.float32)
     vp = rng.standard_normal((2, 6, 4, 2, 8)).astype(np.float32)
-    nkp, nvp = jax.jit(copy_pages)(jnp.asarray(kp), jnp.asarray(vp),
+    nkp, nvp = jax.jit(copy_pages)((jnp.asarray(kp), jnp.asarray(vp)),
                                    jnp.asarray(3), jnp.asarray(5))
     nkp, nvp = np.asarray(nkp), np.asarray(nvp)
     np.testing.assert_array_equal(nkp[:, 5], kp[:, 3])

@@ -336,7 +336,7 @@ def test_cow_fork_copies_scales():
         scale=jnp.asarray(rng.uniform(0.01, 2.0, (2, 6, 4, 2, 1)),
                           jnp.float32))
     vpool = Quantized(q=pool.q + 1, scale=pool.scale * 2)
-    nkp, nvp = jax.jit(copy_pages)(pool, vpool, jnp.asarray(3),
+    nkp, nvp = jax.jit(copy_pages)((pool, vpool), jnp.asarray(3),
                                    jnp.asarray(5))
     for got, src in ((nkp, pool), (nvp, vpool)):
         np.testing.assert_array_equal(np.asarray(got.q[:, 5]),
@@ -390,7 +390,7 @@ def test_int8_decode_hlo_pool_avals_are_int8(llama):
                       kv_dtype="int8")
     arr = eng.scheduler.decode_arrays()
     lowered = eng._decode_fn.lower(
-        eng.params, eng.pages["k"], eng.pages["v"],
+        eng.params, eng.pages,
         jnp.asarray(arr["tokens"]), jnp.asarray(arr["lengths"]),
         jnp.asarray(arr["tables"]), jnp.asarray(arr["seeds"]),
         jnp.asarray(arr["temps"]), jnp.asarray(arr["top_ks"]),

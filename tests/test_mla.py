@@ -405,7 +405,7 @@ def test_decode_program_carries_the_new_scopes(debug_engine_parts):
     arrays = {k: jnp.asarray(v)
               for k, v in engine.scheduler.decode_arrays().items()}
     text = engine._decode_fn.lower(
-        engine.params, engine.pages["k"], engine.pages["v"],
+        engine.params, engine.pages,
         *(arrays[k] for k in ("tokens", "lengths", "tables", "seeds",
                               "temps", "top_ks", "top_ps", "actives"))
     ).as_text(debug_info=True)

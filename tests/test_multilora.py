@@ -412,7 +412,7 @@ def test_decode_hlo_no_merged_weight_materialization(llama, wrapped):
     arr = eng.scheduler.decode_arrays()
     lora_args = eng.programs.lora_call_args(arr["adapters"])
     text = eng._decode_fn.lower(
-        eng.params, eng.pages["k"], eng.pages["v"],
+        eng.params, eng.pages,
         jnp.asarray(arr["tokens"]), jnp.asarray(arr["lengths"]),
         jnp.asarray(arr["tables"]), jnp.asarray(arr["seeds"]),
         jnp.asarray(arr["temps"]), jnp.asarray(arr["top_ks"]),

@@ -329,7 +329,7 @@ def test_horizon_hlo_cache_avals_pool_shaped_only(llama):
                       decode_horizon=4)
     arr = eng.scheduler.decode_arrays()
     lowered = eng.programs.horizon_for(4).lower(
-        eng.params, eng.pages["k"], eng.pages["v"],
+        eng.params, eng.pages,
         jnp.asarray(arr["tokens"]), jnp.asarray(arr["lengths"]),
         jnp.asarray(arr["tables"]), jnp.asarray(arr["seeds"]),
         jnp.asarray(arr["temps"]), jnp.asarray(arr["top_ks"]),

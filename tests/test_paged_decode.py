@@ -411,13 +411,13 @@ def serve_program_jaxprs(eng):
     """The decode and the chunk program of an engine, traced."""
     arr = eng.scheduler.decode_arrays()
     decode = jax.make_jaxpr(eng.programs._decode)(
-        eng.params, eng.pages["k"], eng.pages["v"],
+        eng.params, eng.pages,
         *(jnp.asarray(arr[k]) for k in (
             "tokens", "lengths", "tables", "seeds", "temps", "top_ks",
             "top_ps", "actives")))
     t = eng.prefill_chunk
     chunk = jax.make_jaxpr(eng.programs.chunk_for(t))(
-        eng.params, eng.pages["k"], eng.pages["v"],
+        eng.params, eng.pages,
         jnp.zeros((1, t), jnp.int32), jnp.zeros((1,), jnp.int32),
         jnp.zeros((1, eng.max_pages), jnp.int32),
         jnp.asarray(t - 1, jnp.int32), jnp.asarray([t], jnp.int32))
@@ -473,7 +473,7 @@ def test_engine_flash_decode_tokens_and_hlo_pin():
         eng = engines[impl]
         arr = eng.scheduler.decode_arrays()
         lowered = eng._decode_fn.lower(
-            eng.params, eng.pages["k"], eng.pages["v"],
+            eng.params, eng.pages,
             jnp.asarray(arr["tokens"]), jnp.asarray(arr["lengths"]),
             jnp.asarray(arr["tables"]), jnp.asarray(arr["seeds"]),
             jnp.asarray(arr["temps"]), jnp.asarray(arr["top_ks"]),
@@ -641,7 +641,7 @@ def test_chunk_and_verify_programs_flash_hlo_pin():
                           max_len=16, attend_impl=impl, prefill_chunk=8,
                           speculate="ngram", spec_k=3)
         chunk = eng.programs.chunk_for(8).lower(
-            eng.params, eng.pages["k"], eng.pages["v"],
+            eng.params, eng.pages,
             jnp.zeros((1, 8), jnp.int32), jnp.zeros((1,), jnp.int32),
             jnp.zeros((1, eng.max_pages), jnp.int32),
             jnp.asarray(7, jnp.int32), jnp.asarray([8], jnp.int32))
@@ -653,7 +653,7 @@ def test_chunk_and_verify_programs_flash_hlo_pin():
             f"{'missing' if expect_view else 'present'}")
         s = eng.n_slots
         verify = eng.programs.verify_for(4, greedy=True).lower(
-            eng.params, eng.pages["k"], eng.pages["v"],
+            eng.params, eng.pages,
             jnp.zeros((s, 4), jnp.int32), jnp.zeros((s,), jnp.int32),
             jnp.zeros((s, eng.max_pages), jnp.int32),
             jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.float32),

@@ -20,12 +20,12 @@ def _chunk_program_logits(bundle, params, prompt, chunk=16, page=16):
     ids = np.zeros((1, chunk), np.int32)
     ids[0, :len(prompt)] = prompt
     table = jnp.arange(1, 1 + chunk // page, dtype=jnp.int32)[None]
-    logit, kp, _ = programs.chunk_for(chunk)(
-        programs.params, pages["k"], pages["v"], jnp.asarray(ids),
+    logit, pools = programs.chunk_for(chunk)(
+        programs.params, pages, jnp.asarray(ids),
         jnp.zeros(1, jnp.int32), table,
         jnp.asarray(len(prompt) - 1, jnp.int32),
         jnp.asarray([len(prompt)], jnp.int32))
-    return logit, kp
+    return logit, pools["k"]
 
 
 def test_greedy_matches_naive_reference():

@@ -261,7 +261,7 @@ def test_kv_residency_scales_with_pages_not_slots_times_maxlen(llama):
     # (b) lower the ONE decode program and inspect its kv operands
     arr = eng.scheduler.decode_arrays()
     lowered = eng._decode_fn.lower(
-        eng.params, eng.pages["k"], eng.pages["v"],
+        eng.params, eng.pages,
         jnp.asarray(arr["tokens"]), jnp.asarray(arr["lengths"]),
         jnp.asarray(arr["tables"]), jnp.asarray(arr["seeds"]),
         jnp.asarray(arr["temps"]), jnp.asarray(arr["top_ks"]),

@@ -161,7 +161,7 @@ def test_sharded_pool_compiled_hlo_pin(llama, tp2_plan):
                       n_pages=9, plan=tp2_plan, shard_kv=True)
     arr = eng.scheduler.decode_arrays()
     hlo = eng._decode_fn.lower(
-        eng.params, eng.pages["k"], eng.pages["v"],
+        eng.params, eng.pages,
         jnp.asarray(arr["tokens"]), jnp.asarray(arr["lengths"]),
         jnp.asarray(arr["tables"]), jnp.asarray(arr["seeds"]),
         jnp.asarray(arr["temps"]), jnp.asarray(arr["top_ks"]),

@@ -258,7 +258,7 @@ def test_serve_programs_have_stable_names_and_scopes(debug_model):
     arrays = {k: jnp.asarray(v)
               for k, v in engine.scheduler.decode_arrays().items()}
     text = programs._decode_fn.lower(
-        programs.params, engine.pages["k"], engine.pages["v"],
+        programs.params, engine.pages,
         *(arrays[k] for k in ("tokens", "lengths", "tables", "seeds",
                               "temps", "top_ks", "top_ps", "actives"))
     ).as_text(debug_info=True)
@@ -295,7 +295,7 @@ def test_latent_family_decode_carries_its_subscopes_and_kernel():
     arrays = {k: jnp.asarray(v)
               for k, v in engine.scheduler.decode_arrays().items()}
     text = engine._decode_fn.lower(
-        engine.params, engine.pages["k"], engine.pages["v"],
+        engine.params, engine.pages,
         *(arrays[k] for k in ("tokens", "lengths", "tables", "seeds",
                               "temps", "top_ks", "top_ps", "actives"))
     ).as_text(debug_info=True)
@@ -308,7 +308,7 @@ def test_latent_family_decode_carries_its_subscopes_and_kernel():
     assert any(f.startswith("experts/shared_expert/") for f in fragments)
     assert any(f.startswith("attn/latent_proj/") for f in fragments)
     assert "paged_latent_attend" in KERNELS and set(SUBSCOPES) == {
-        "latent_proj", "shared_expert"}
+        "latent_proj", "shared_expert", "conv"}
 
 
 def test_named_gives_jit_the_name():

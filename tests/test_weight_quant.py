@@ -377,7 +377,7 @@ def test_int8_decode_hlo_no_fp32_weight_avals(llama):
                       weight_dtype="int8")
     arr = eng.scheduler.decode_arrays()
     text = eng._decode_fn.lower(
-        eng.params, eng.pages["k"], eng.pages["v"],
+        eng.params, eng.pages,
         jnp.asarray(arr["tokens"]), jnp.asarray(arr["lengths"]),
         jnp.asarray(arr["tables"]), jnp.asarray(arr["seeds"]),
         jnp.asarray(arr["temps"]), jnp.asarray(arr["top_ks"]),
