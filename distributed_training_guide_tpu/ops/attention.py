@@ -134,11 +134,13 @@ def multihead_attention(
             impl, q.shape[1], k.shape[1], q.shape[-1], causal=causal,
             standard_layout=standard_layout)
         note_choice("multihead_attention", impl, reason)
-    note_attention(impl, reason)
     if impl == "flash":
-        from .flash_attention import flash_attention
+        from .flash_attention import describe_walk, flash_attention
 
+        note_attention(impl, f"{reason}; "
+                       f"{describe_walk(q, k, causal, window)}")
         return flash_attention(q, k, v, causal=causal, window=window,
                                scale=scale, logit_softcap=logit_softcap)
+    note_attention(impl, reason)
     return _xla_attention(q, k, v, causal, positions, kv_positions, window,
                           scale, logit_softcap)

@@ -7,21 +7,40 @@ and the SURVEY.md build plan's deliberate custom-kernel deliverable.
 
 Design (standard blockwise online-softmax, laid out for the MXU/VMEM):
 
-- inputs are processed as [B, H, S, D]; the grid walks (batch, q-head,
-  q-block, kv-block) with the kv-block innermost — TPU grids execute
-  sequentially per core, so the online-softmax running state (m, l, acc)
-  lives in VMEM scratch carried across kv-block steps;
-- causal masking skips fully-masked kv blocks via ``pl.when`` (no compute
-  issued) and applies an element mask only on diagonal blocks;
+- inputs are processed as [B, H, S, D]; TPU grids execute sequentially per
+  core, so the online-softmax running state (m, l, acc) lives in VMEM scratch
+  carried across the kv tiles of a q tile's walk. m and l stay ``[BQ, 128]``,
+  one value a row replicated over the lanes, from the scratch to the
+  accumulator (``_row_stat``, ``_widen``): no one-lane column is read out of
+  them inside the tile loop;
+- under a STATIC band (causal, with or without an int window) the grid is
+  ``(batch, q-head, live tile)``: the band's live tiles are listed where the
+  call is built (``_live_tiles``) and ride in as two scalar-prefetched int32
+  operands that the index maps and the kernel read its (iq, ik) from, so a
+  dead tile costs no grid step and no DMA. Under a DYNAMIC band (a traced
+  per-layer window, the ring's chunk offsets: a [3] int32 SMEM operand) the
+  band is not known there: the grid walks (batch, q-head, q-block, kv-block)
+  whole and ``pl.when`` skips a dead tile's compute. Every live tile applies
+  the element mask: leaving it off interior tiles moved no kernel by 2% on
+  the chip (PERF.md section 6, PR 36), so there is one tile body;
+- tiles are 512 wide, and 1024 for 2-byte operands at head_dim <= 128 on
+  sequences of 4096 and more (``_pick_block``; the callers' ``block_q`` /
+  ``block_k`` are ceilings);
 - GQA is native: q-head h reads kv-head ``h // (Hq // Hkv)`` through the
   BlockSpec index maps — no materialized ``repeat`` of K/V (the XLA reference
   path in ``attention.py`` groups heads instead);
-- scores/softmax accumulate in fp32 regardless of input dtype;
+- scores/softmax accumulate in fp32 regardless of input dtype. The tiles are
+  up-cast to fp32 as they are loaded; Mosaic feeds the MXU one bf16 pass of
+  an fp32 operand at default precision, so on the chip bf16 tiles as stored
+  and p / ds rounded to bf16 give the same bits and the same time within
+  1.5% (PR 36): the up-cast stays, and the interpreter computes what the
+  text says;
 - backward recomputes attention blockwise (flash-bwd): a dq kernel with the
-  same walk, and a dk/dv kernel walking (batch, kv-head, group, kv-block,
-  q-block) that also reduces over the GQA group on-chip. The logsumexp from
-  the forward and ``delta = rowsum(dO * O)`` (cheap XLA einsum) are the only
-  residuals — activation memory is O(B*H*S), not O(B*H*S^2).
+  forward's walk, and a dk/dv kernel walking (batch, kv-head, kv-block,
+  q-block, group) — or (batch, kv-head, live tile, group) — that also
+  reduces over the GQA group on-chip. The logsumexp from the forward and
+  ``delta = rowsum(dO * O)`` (cheap XLA einsum) are the only residuals —
+  activation memory is O(B*H*S), not O(B*H*S^2).
 
 ``interpret=True`` runs the same kernels on CPU (used by the test suite's
 numerics goldens against the XLA reference implementation).
@@ -45,33 +64,129 @@ _VMEM = pltpu.VMEM
 NEG_INF = -1e30
 
 
-def _pick_block(s: int, preferred: int = 512) -> int:
-    for cand in (preferred, 256, 128, 64, 32, 16, 8):
-        if s % cand == 0 and cand <= s:
+_LANES = 128
+_TILE_CEILING = 1024    # what _pick_block may take where it fits and pays
+
+
+def _pick_block(s: int, ceiling: int, dtype, head_dim: int, window=None) -> int:
+    """The tile edge along a sequence of ``s``: the largest of 1024, 512,
+    ... 8 that divides ``s`` and is at most ``ceiling`` (the caller's
+    ``block_q`` / ``block_k``). 1024-wide tiles are taken for 2-byte
+    operands at head_dim <= 128 (the fp32 score, probability and gradient
+    tiles of a 1024 x 1024 tile are 4 MB each: fp32 operands at head_dim 256
+    do not fit the kernels' VMEM there), for walks of at least four tiles a
+    side (at seq 2048 two 1024-wide diagonal tiles of three waste the MXU
+    work that the fewer grid steps save) and, under a static window, for
+    bands at least as wide (a 1024-token band is three live 512-wide tiles
+    a row, or two of 1024: a third more work). Measured: PERF.md section 6,
+    PR 36."""
+    if (jnp.dtype(dtype).itemsize > 2 or head_dim > 128 or s < 4 * 1024
+            or (isinstance(window, int) and window < 4 * 1024)):
+        ceiling = min(ceiling, 512)
+    for cand in (1024, 512, 256, 128, 64, 32, 16, 8):
+        if cand <= min(ceiling, s) and s % cand == 0:
             return cand
     return s
 
 
 # ---------------------------------------------------------------------------
-# forward
+# the band, tile by tile
 # ---------------------------------------------------------------------------
 
-def _band_live(causal, window, iq, ik, block_q, block_k, q_off=0, k_off=0):
-    """Block-level skip predicate: False when NO (q_pos, k_pos) pair in the
-    (iq, ik) tile satisfies the causal/sliding-window band. The whole tile's
-    compute is skipped via ``pl.when`` — this is where SWA's speedup comes
-    from (tiles strictly below the band cost zero, so work is O(S*W) not
-    O(S^2) once S >> window). ``window``/``q_off``/``k_off`` may be traced
-    scalars (per-layer window schedules, ring chunk offsets) — program_id is
-    runtime-valued anyway, so the predicate was never a compile-time skip."""
+def _band_tile(causal, window, iq, ik, block_q, block_k, q_off=0, k_off=0):
+    """``(live, interior)`` of the (iq, ik) tile under the causal /
+    sliding-window band. A tile is LIVE when some (q_pos, k_pos) pair in it
+    is inside the band and INTERIOR when every pair is (the element mask
+    masks nothing there); a live tile that is not interior is an EDGE tile,
+    the rest are DEAD. The band is ``0 <= q_pos - k_pos`` (``< window``),
+    and over a tile that difference takes every value from ``q_lo - k_hi``
+    to ``q_hi - k_lo``. A dead tile's compute is skipped — this is where
+    SWA's speedup comes from (work is O(S*W) not O(S^2) once S >> window).
+    ``window``/``q_off``/``k_off`` may be traced scalars (per-layer window
+    schedules, ring chunk offsets): both predicates are scalar arithmetic
+    on the grid position, which is runtime-valued anyway."""
     if not causal:
-        return True
-    live = ik * block_k + k_off <= iq * block_q + block_q - 1 + q_off
+        return True, True
+    q_lo = iq * block_q + q_off
+    k_lo = ik * block_k + k_off
+    most = q_lo + block_q - 1 - k_lo       # newest query over oldest key
+    least = q_lo - (k_lo + block_k - 1)    # oldest query over newest key
+    live, interior = most >= 0, least >= 0
     if window is not None:
-        # newest key in the tile still inside the OLDEST query's window
-        live &= (ik * block_k + block_k - 1 + k_off
-                 >= iq * block_q + q_off - (window - 1))
-    return live
+        live &= least < window
+        interior &= most < window
+    return live, interior
+
+
+def _band_grid(causal, window, nq, nk, block_q, block_k):
+    """``_band_tile`` over a whole static band: ``(live, interior)`` as
+    ``[nq, nk]`` bool arrays, on the host."""
+    return tuple(np.broadcast_to(x, (nq, nk)) for x in _band_tile(
+        causal, window, np.arange(nq)[:, None], np.arange(nk)[None, :],
+        block_q, block_k))
+
+
+def tile_counts(causal, window, sq, sk, block_q, block_k):
+    """``(interior, edge, dead)`` tiles of one (batch row, head) walk under a
+    static band: arithmetic on the shapes, for the line a call leaves."""
+    live, interior = _band_grid(causal, window, sq // block_q, sk // block_k,
+                                block_q, block_k)
+    return (int((live & interior).sum()), int((live & ~interior).sum()),
+            int((~live).sum()))
+
+
+def describe_walk(q, k, causal, window, block_q=_TILE_CEILING,
+                  block_k=_TILE_CEILING) -> str:
+    """What ``note_attention`` says of a flash call on ``[B, S, H, D]``
+    operands: the tiles chosen and, under a static band, how many of a
+    walk's tiles are interior, edge and dead (a traced band is walked
+    whole and decides tile by tile at run time)."""
+    block_q = _pick_block(q.shape[1], block_q, q.dtype, q.shape[-1], window)
+    block_k = _pick_block(k.shape[1], block_k, k.dtype, q.shape[-1], window)
+    tiles = f"tiles {block_q}x{block_k}"
+    if not (window is None or isinstance(window, int)):
+        return f"{tiles}, traced window: the whole grid is walked"
+    interior, edge, dead = tile_counts(causal, window, q.shape[1], k.shape[1],
+                                       block_q, block_k)
+    return f"{tiles}, a walk: {interior} interior / {edge} edge / {dead} dead"
+
+
+def _live_tiles(causal, window, nq, nk, block_q, block_k, by_rows: bool):
+    """A static band's live tiles in walk order, as the two int32 operands
+    the kernels' grids are prefetched from: ``(iq, ik)`` by q row then kv
+    tile (``by_rows``: ``flash_fwd``, ``flash_dq``) or ``(ik, iq)`` by kv
+    column then q tile (``flash_dkv``). A row (column) with no live tile
+    keeps one dead entry, so its output block is still opened, zeroed and
+    written."""
+    live = _band_grid(causal, window, nq, nk, block_q, block_k)[0]
+    live = (live if by_rows else live.T).copy()
+    live[~live.any(axis=1), 0] = True
+    outer, inner = np.nonzero(live)
+    return outer.astype(np.int32), inner.astype(np.int32)
+
+
+def _take_tiles(refs, tiled):
+    """``(tiles, the other refs)``: a static band's kernels get the two
+    prefetched live-tile operands first."""
+    return (refs[:2], refs[2:]) if tiled else (None, refs)
+
+
+def _walk(tiles, n_inner):
+    """``(outer, inner, first, last)`` of this grid step: its tile, and
+    whether the step opens / closes the walk of its outer index (a q row
+    for ``flash_fwd`` / ``flash_dq``, a kv column for ``flash_dkv``).
+    ``tiles`` are the prefetched live-tile operands of a static band, whose
+    grid is ``(..., tile)``; None for the whole ``(..., outer, inner)``
+    grid a dynamic band walks."""
+    if tiles is None:
+        outer, inner = pl.program_id(2), pl.program_id(3)
+        return outer, inner, inner == 0, inner == n_inner - 1
+    outer_ref, inner_ref = tiles
+    t, n = pl.program_id(2), outer_ref.shape[0]
+    outer = outer_ref[t]
+    first = (t == 0) | (outer_ref[jnp.maximum(t - 1, 0)] != outer)
+    last = (t == n - 1) | (outer_ref[jnp.minimum(t + 1, n - 1)] != outer)
+    return outer, inner_ref[t], first, last
 
 
 def _band_mask(causal, window, iq, ik, block_q, block_k, shape,
@@ -106,25 +221,50 @@ def _softcap_fwd(s, softcap):
     return jnp.tanh(s / softcap) * softcap
 
 
-def _fwd_kernel(*refs, scale, softcap, causal, window, banded, block_q,
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+def _widen(stat, n):
+    """A lane-replicated ``[rows, 128]`` statistic at ``n`` lanes."""
+    reps = -(-n // _LANES)
+    if reps > 1:
+        stat = jnp.tile(stat, (1, reps))
+    return stat if n == reps * _LANES else stat[:, :n]
+
+
+def _row_stat(x, fold, reduce):
+    """``reduce`` (max or sum) over each row of ``x``, replicated over 128
+    lanes: the row's lane groups ``fold`` into one elementwise, and one
+    reduction crosses the lanes."""
+    n = x.shape[1]
+    if n > _LANES and n % _LANES == 0:
+        x = functools.reduce(fold, [x[:, i:i + _LANES]
+                                    for i in range(0, n, _LANES)])
+    return jnp.broadcast_to(reduce(x, axis=1, keepdims=True),
+                            (x.shape[0], _LANES))
+
+
+def _fwd_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
                 block_k, num_kv_blocks):
+    tiles, refs = _take_tiles(refs, tiled)
     if banded:  # inputs carry the trailing dynamic [3] band operand
         q_ref, k_ref, v_ref, band_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
         band_ref = None
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    iq, ik, first, last = _walk(tiles, num_kv_blocks)
     window, q_off, k_off = _unpack_band(band_ref, window)
+    d = acc_scr.shape[1]
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # band: kv block fully outside the causal/window band -> skip all compute
-    live = _band_live(causal, window, iq, ik, block_q, block_k, q_off, k_off)
+    live, _ = _band_tile(causal, window, iq, ik, block_q, block_k, q_off, k_off)
 
     @pl.when(live)
     def _compute():
@@ -139,34 +279,35 @@ def _fwd_kernel(*refs, scale, softcap, causal, window, banded, block_q,
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[:, 0:1]                        # [BQ, 1]
-        l_prev = l_scr[:, 0:1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        # the running statistics stay [BQ, 128], one value a row replicated
+        # over the lanes, from the scratch through to the accumulator: a
+        # [BQ, 1] column read out of them costs a lane broadcast of 64 vregs
+        # wherever it meets a tile (half the kernel's time at 512-wide
+        # tiles: PERF.md section 6, PR 36)
+        m_prev, l_prev = m_scr[:], l_scr[:]
+        m_new = jnp.maximum(m_prev, _row_stat(s, jnp.maximum, jnp.max))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                        # [BQ, BK]
+        p = jnp.exp(s - _widen(m_new, block_k))       # [BQ, BK]
         if window is not None and mask is not None:
             # a live SWA tile can hold FULLY-masked q rows (window's lower
             # edge crosses the tile): there m_new == NEG_INF and
             # exp(s - m_new) == exp(0) == 1 — zero those lanes explicitly.
-            # (Pure causal never hits this: with block_q == block_k every
-            # live tile's rows keep >= 1 unmasked key.)
+            # (Pure causal never hits this: every row's walk opens on a
+            # tile that holds one of its keys.)
             p = jnp.where(mask, p, 0.0)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         v = v_ref[0, 0].astype(jnp.float32)           # [BK, D]
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        l_scr[:] = alpha * l_prev + _row_stat(p, jnp.add, jnp.sum)
+        m_scr[:] = m_new
+        acc_scr[:] = acc_scr[:] * _widen(alpha, d) + pv
 
-    @pl.when(ik == num_kv_blocks - 1)
+    @pl.when(last)
     def _finalize():
-        l = l_scr[:, 0:1]
+        l = l_scr[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
-        lse = m_scr[:, 0:1] + jnp.log(safe_l)
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:]).astype(jnp.float32)
+        o_ref[0, 0] = (acc_scr[:] / _widen(safe_l, d)).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[:] + jnp.log(safe_l)
 
 
 def check_static_window(window):
@@ -195,7 +336,7 @@ def _resolve_band(window):
     offsets zero here — the ring packs nonzero chunk offsets directly).
 
     Static path (window None or a Python int): no operand — the band is
-    baked into the kernel, byte-identical to the pre-dynamic program.
+    baked into the kernel and into the list of live tiles its grid walks.
     Dynamic path (traced window): the band rides a tiny SMEM operand. A
     traced window of 2**30 (= "full attention this layer",
     _layer_window_column's encoding of 0) is wider than any supported
@@ -209,64 +350,95 @@ def _band_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+def _plan(q, k, causal, window, block_q, block_k, band):
+    """What the three pallas_calls share: the tiles chosen, the static
+    window and dynamic band operand, and whether the band is static, in
+    which case the grids hold its live tiles alone (``_live_tiles``): a dead
+    tile then costs no grid step and no DMA. A dynamic band (traced window,
+    ring offsets) is not known where the grid is built, so its kernels walk
+    every tile and skip the dead ones' compute; without a band every tile
+    is live."""
+    d = q.shape[-1]
+    if band is None:
+        window, band = _resolve_band(window)
+    else:
+        window = None  # caller-packed dynamic band (the custom_vjp/ring path)
+    block_q = _pick_block(q.shape[2], block_q, q.dtype, d, window)
+    block_k = _pick_block(k.shape[2], block_k, k.dtype, d, window)
+    return block_q, block_k, window, band, causal and band is None
+
+
+def _row_walk(tiled, causal, window, b, hq, groups, nq, nk, block_q, block_k):
+    """``flash_fwd``'s and ``flash_dq``'s walk by q row: the prefetched
+    operands, the grid, and the q-side and kv-side index maps (q-head h
+    reads kv-head ``h // groups``)."""
+    if tiled:
+        tiles = _live_tiles(causal, window, nq, nk, block_q, block_k, True)
+
+        def q_map(b_, h, t, iq_ref, ik_ref):
+            return b_, h, iq_ref[t], 0
+
+        def kv_map(b_, h, t, iq_ref, ik_ref):
+            return b_, h // groups, ik_ref[t], 0
+
+        return tiles, (b, hq, len(tiles[0])), q_map, kv_map
+
+    def q_map(b_, h, iq, ik):
+        return b_, h, iq, 0
+
+    def kv_map(b_, h, iq, ik):
+        return b_, h // groups, ik, 0
+
+    return (), (b, hq, nq, nk), q_map, kv_map
+
+
 def _flash_fwd(q, k, v, causal, window, block_q, block_k, interpret,
                scale=None, softcap=None, band=None):
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     groups = hq // hkv
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
+    block_q, block_k, window, band, tiled = _plan(q, k, causal, window,
+                                                  block_q, block_k, band)
     nq, nk = sq // block_q, sk // block_k
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if band is None:
-        window, band = _resolve_band(window)
-    else:
-        window = None  # caller-packed dynamic band (the custom_vjp/ring path)
 
-    grid = (b, hq, nq, nk)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, softcap=softcap, causal=causal,
-        window=window, banded=band is not None, block_q=block_q,
-        block_k=block_k, num_kv_blocks=nk)
-
-    out_shape = (
-        jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32),  # lse (lane-padded)
-    )
+    tiles, grid, q_map, kv_map = _row_walk(tiled, causal, window, b, hq, groups,
+                                           nq, nk, block_q, block_k)
     in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda b_, h, iq, ik: (b_, h, iq, 0),
-                     memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda b_, h, iq, ik, g=groups: (b_, h // g, ik, 0),
-                     memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda b_, h, iq, ik, g=groups: (b_, h // g, ik, 0),
-                     memory_space=_VMEM),
+        pl.BlockSpec((1, 1, block_q, d), q_map, memory_space=_VMEM),
+        pl.BlockSpec((1, 1, block_k, d), kv_map, memory_space=_VMEM),
+        pl.BlockSpec((1, 1, block_k, d), kv_map, memory_space=_VMEM),
     ]
     args = [q, k, v]
     if band is not None:
         in_specs.append(_band_spec())
         args.append(band)
     o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, iq, ik: (b_, h, iq, 0),
-                         memory_space=_VMEM),
-            pl.BlockSpec((1, 1, block_q, 128), lambda b_, h, iq, ik: (b_, h, iq, 0),
-                         memory_space=_VMEM),
+        functools.partial(
+            _fwd_kernel, scale=scale, softcap=softcap, causal=causal,
+            window=window, banded=band is not None, tiled=tiled,
+            block_q=block_q, block_k=block_k, num_kv_blocks=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tiles),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=(
+                pl.BlockSpec((1, 1, block_q, d), q_map, memory_space=_VMEM),
+                pl.BlockSpec((1, 1, block_q, 128), q_map, memory_space=_VMEM),
+            ),
+            scratch_shapes=[
+                _VMEM((block_q, 128), jnp.float32),
+                _VMEM((block_q, 128), jnp.float32),
+                _VMEM((block_q, d), jnp.float32),
+            ]),
+        out_shape=(
+            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32),  # lane-padded
         ),
-        scratch_shapes=[
-            _VMEM((block_q, 128), jnp.float32),
-            _VMEM((block_q, 128), jnp.float32),
-            _VMEM((block_q, d), jnp.float32),
-        ],
-        out_shape=out_shape,
         interpret=interpret,
         name="flash_fwd",
-    )(*args)
+    )(*tiles, *args)
     return o, lse[..., 0]
 
 
@@ -292,23 +464,23 @@ def _bwd_scores(q, k, lse, scale, softcap, mask):
     return jnp.exp(s - lse), cap_grad
 
 
-def _dq_kernel(*refs, scale, softcap, causal, window, banded, block_q,
+def _dq_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
                block_k, num_kv_blocks):
+    tiles, refs = _take_tiles(refs, tiled)
     if banded:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, band_ref,
          dq_ref, dq_scr) = refs
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
         band_ref = None
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    iq, ik, first, last = _walk(tiles, num_kv_blocks)
     window, q_off, k_off = _unpack_band(band_ref, window)
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    live = _band_live(causal, window, iq, ik, block_q, block_k, q_off, k_off)
+    live, _ = _band_tile(causal, window, iq, ik, block_q, block_k, q_off, k_off)
 
     @pl.when(live)
     def _compute():
@@ -331,16 +503,18 @@ def _dq_kernel(*refs, scale, softcap, causal, window, banded, block_q,
         dq_scr[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
-    @pl.when(ik == num_kv_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, softcap, causal, window, banded, block_q,
+def _dkv_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
                 block_k, num_q_blocks, groups):
-    # grid (b, hkv, ik, ig, iq): the kv-block ik is OUTER to the (group,
-    # q-block) accumulation dims, so the scratch is initialized exactly when a
-    # new dk/dv output block is first visited and flushed when last visited.
+    # grid (b, hkv, ik, iq, ig), or (b, hkv, tile, ig) over a static band's
+    # live tiles: the kv-block ik is OUTER to the (q-block, group member)
+    # accumulation dims, so the scratch is initialized exactly when a new
+    # dk/dv output block is first visited and flushed when last visited.
+    tiles, refs = _take_tiles(refs, tiled)
     if banded:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, band_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = refs
@@ -348,17 +522,16 @@ def _dkv_kernel(*refs, scale, softcap, causal, window, banded, block_q,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = refs
         band_ref = None
-    ik = pl.program_id(2)
-    ig = pl.program_id(3)   # GQA group member
-    iq = pl.program_id(4)
+    ik, iq, first, last = _walk(tiles, num_q_blocks)
+    ig = pl.program_id(3 if tiled else 4)   # GQA group member
     window, q_off, k_off = _unpack_band(band_ref, window)
 
-    @pl.when((iq == 0) & (ig == 0))
+    @pl.when(first & (ig == 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    live = _band_live(causal, window, iq, ik, block_q, block_k, q_off, k_off)
+    live, _ = _band_tile(causal, window, iq, ik, block_q, block_k, q_off, k_off)
 
     @pl.when(live)
     def _compute():
@@ -372,18 +545,20 @@ def _dkv_kernel(*refs, scale, softcap, causal, window, banded, block_q,
         mask = _band_mask(causal, window, iq, ik, block_q, block_k,
                           (block_q, block_k), q_off, k_off)
         p, cap_grad = _bwd_scores(q, k, lse, scale, softcap, mask)
-        dv_scr[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)                                 # [BQ, BK]
         if cap_grad is not None:
             ds = ds * cap_grad
         ds = ds * scale
+        # the two transposed products last, side by side: 2.5-6% of the
+        # kernel on the chip against dv's before dp (PERF.md, PR 36)
+        dv_scr[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
         dk_scr[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
-    @pl.when((iq == num_q_blocks - 1) & (ig == groups - 1))
+    @pl.when(last & (ig == groups - 1))
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -405,84 +580,77 @@ def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     groups = hq // hkv
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
+    block_q, block_k, window, band, tiled = _plan(q, k, causal, window,
+                                                  block_q, block_k, band)
     nq, nk = sq // block_q, sk // block_k
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if band is None:
-        window, band = _resolve_band(window)
-    else:
-        window = None
 
     lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, 128))
     delta_l = jnp.broadcast_to(delta[..., None], (*delta.shape, 128))
+    args = [q, k, v, do, lse_l, delta_l] + ([] if band is None else [band])
+    band_specs = [] if band is None else [_band_spec()]
+    static = dict(scale=scale, softcap=softcap, causal=causal, window=window,
+                  banded=band is not None, tiled=tiled, block_q=block_q,
+                  block_k=block_k)
 
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, iq, ik: (b_, h, iq, 0),
-                          memory_space=_VMEM)
-    kv_spec = pl.BlockSpec((1, 1, block_k, d),
-                           lambda b_, h, iq, ik, g_=groups: (b_, h // g_, ik, 0),
-                           memory_space=_VMEM)
-    stat_spec = pl.BlockSpec((1, 1, block_q, 128), lambda b_, h, iq, ik: (b_, h, iq, 0),
-                             memory_space=_VMEM)
-
-    dq_in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
-    dq_args = [q, k, v, do, lse_l, delta_l]
-    if band is not None:
-        dq_in_specs.append(_band_spec())
-        dq_args.append(band)
+    # dq: flash_fwd's walk
+    tiles, grid, q_map, kv_map = _row_walk(tiled, causal, window, b, hq, groups,
+                                           nq, nk, block_q, block_k)
+    q_spec = pl.BlockSpec((1, 1, block_q, d), q_map, memory_space=_VMEM)
+    kv_spec = pl.BlockSpec((1, 1, block_k, d), kv_map, memory_space=_VMEM)
+    stat_spec = pl.BlockSpec((1, 1, block_q, 128), q_map, memory_space=_VMEM)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, softcap=softcap,
-                          causal=causal, window=window,
-                          banded=band is not None,
-                          block_q=block_q, block_k=block_k, num_kv_blocks=nk),
-        grid=(b, hq, nq, nk),
-        in_specs=dq_in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[_VMEM((block_q, d), jnp.float32)],
+        functools.partial(_dq_kernel, num_kv_blocks=nk, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tiles), grid=grid,
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec,
+                      *band_specs],
+            out_specs=q_spec,
+            scratch_shapes=[_VMEM((block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_dq",
-    )(*dq_args)
+    )(*tiles, *args)
 
-    # dk/dv: walk (b, kv-head, kv-block, group-member, q-block); q-side refs
+    # dk/dv: walk (b, kv-head, kv-block, q-block, group-member); q-side refs
     # index head = hkv * groups + ig
-    def q_idx(b_, hkv_, ik, ig, iq, g_=groups):
-        return (b_, hkv_ * g_ + ig, iq, 0)
+    if tiled:
+        tiles = _live_tiles(causal, window, nq, nk, block_q, block_k, False)
+        grid = (b, hkv, len(tiles[0]), groups)
 
-    def kv_idx(b_, hkv_, ik, ig, iq):
-        return (b_, hkv_, ik, 0)
+        def q_idx(b_, hkv_, t, ig, ik_ref, iq_ref):
+            return b_, hkv_ * groups + ig, iq_ref[t], 0
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), q_idx, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_k, d), kv_idx, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_k, d), kv_idx, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_q, d), q_idx, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_q, 128), q_idx, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_q, 128), q_idx, memory_space=_VMEM),
-    ]
-    dkv_args = [q, k, v, do, lse_l, delta_l]
-    if band is not None:
-        dkv_in_specs.append(_band_spec())
-        dkv_args.append(band)
+        def kv_idx(b_, hkv_, t, ig, ik_ref, iq_ref):
+            return b_, hkv_, ik_ref[t], 0
+    else:
+        grid = (b, hkv, nk, nq, groups)
+
+        def q_idx(b_, hkv_, ik, iq, ig):
+            return b_, hkv_ * groups + ig, iq, 0
+
+        def kv_idx(b_, hkv_, ik, iq, ig):
+            return b_, hkv_, ik, 0
+
+    q_spec = pl.BlockSpec((1, 1, block_q, d), q_idx, memory_space=_VMEM)
+    kv_spec = pl.BlockSpec((1, 1, block_k, d), kv_idx, memory_space=_VMEM)
+    stat_spec = pl.BlockSpec((1, 1, block_q, 128), q_idx, memory_space=_VMEM)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, softcap=softcap,
-                          causal=causal, window=window,
-                          banded=band is not None, block_q=block_q,
-                          block_k=block_k, num_q_blocks=nq, groups=groups),
-        grid=(b, hkv, nk, groups, nq),
-        in_specs=dkv_in_specs,
-        out_specs=(
-            pl.BlockSpec((1, 1, block_k, d), kv_idx, memory_space=_VMEM),
-            pl.BlockSpec((1, 1, block_k, d), kv_idx, memory_space=_VMEM),
-        ),
-        scratch_shapes=[_VMEM((block_k, d), jnp.float32),
-                        _VMEM((block_k, d), jnp.float32)],
+        functools.partial(_dkv_kernel, num_q_blocks=nq, groups=groups,
+                          **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tiles), grid=grid,
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec,
+                      *band_specs],
+            out_specs=(kv_spec, kv_spec),
+            scratch_shapes=[_VMEM((block_k, d), jnp.float32),
+                            _VMEM((block_k, d), jnp.float32)]),
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         interpret=interpret,
         name="flash_dkv",
-    )(*dkv_args)
+    )(*tiles, *args)
 
     return dq, dk, dv
 
@@ -616,8 +784,8 @@ def make_sharded_flash_attention(
     head_axis: Optional[str] = "tp",
     causal: bool = True,
     window: Optional[int] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = _TILE_CEILING,
+    block_k: int = _TILE_CEILING,
     forced: bool = False,
     fallback=None,
     scale: Optional[float] = None,
@@ -829,8 +997,10 @@ def make_sharded_flash_attention(
                                        scale=scale,
                                        logit_softcap=logit_softcap,
                                        impl="xla")
-        note_attention("flash", "forced" if forced else
-                       "auto: a shape the sharded kernel takes")
+        note_attention("flash", ("forced" if forced else
+                                 "auto: a shape the sharded kernel takes")
+                       + "; " + describe_walk(q, k, causal, wcall, block_q,
+                                              block_k))
         in_manual = _in_manual_context()
         if wcall is window_default or (isinstance(wcall, int)
                                        and wcall == window_default):
@@ -860,8 +1030,8 @@ def flash_attention(
     *,
     causal: bool = True,
     window=None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = _TILE_CEILING,
+    block_k: int = _TILE_CEILING,
     interpret: Optional[bool] = None,
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,

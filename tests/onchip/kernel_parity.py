@@ -14,7 +14,11 @@ smoke's own shapes, through the functions the entry points call:
   benchmark's serve cell (16 slots of 32/32 heads, bf16, 256 table columns,
   1344 pages) at T = 1 with lengths from 0 to 3000 in one call;
 - ``ops/attention.multihead_attention(impl="flash")`` vs ``impl="xla"``,
-  forward and backward, at the training shape (seq 2048, bf16);
+  forward and backward, bf16, at the smoke's training shape (seq 2048) and
+  at the benchmark's three train cells' sequence lengths, GQA ratios and
+  tiles (8192 at 4/2 heads, 4096 at 8/8; batch and heads cut to what the
+  reference's ``[B, H, S, S]`` scores leave room for), with its own control:
+  the element mask left off the edge tiles, which the bound must refuse;
 - ``ops/grouped_matmul.grouped_matmul(impl="pallas", group_offset=...)``
   reading layer 4's 32 expert matrices (4096 x 2048, bf16) out of a whole
   ``[6 * 32, K, N]`` leaf, as the MoE families' paged step hands them over,
@@ -233,23 +237,54 @@ def controls() -> int:
 
 # ---- the training attention --------------------------------------------------
 
-def flash_case(**kw) -> None:
-    q = normal((BATCH, SEQ, HQ, D), jnp.bfloat16)
-    k, v = (normal((BATCH, SEQ, HKV, D), jnp.bfloat16) for _ in range(2))
+# (batch, seq, q heads, kv heads) at head_dim 128, bf16: the smoke's, then
+# the three train cells' sequence lengths and GQA ratios (qwen3-0.6b at 2048
+# and 8192, olmo2-7b at 4096), which also picks their tiles; batch and heads
+# cut to what the XLA reference's [B, H, S, S] fp32 scores leave room for
+FLASH_SHAPES = ((BATCH, SEQ, HQ, HKV), (1, 8192, 4, 2), (1, 4096, 8, 8))
 
-    def run(impl):
-        def loss(q, k, v):
-            out = multihead_attention(q, k, v, impl=impl, **kw)
-            return (out.astype(jnp.float32) ** 2).sum(), out
-        (_, out), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
-        return out, grads
 
-    (out_f, g_f), (out_x, g_x) = run("flash"), run("xla")
-    case("flash_attention fwd+bwd",
-         {"out": (out_f, out_x), "dq": (g_f[0], g_x[0]),
-          "dk": (g_f[1], g_x[1]), "dv": (g_f[2], g_x[2])},
-         seq=SEQ, heads=f"{HQ}/{HKV}", head_dim=D, **kw)
+def flash_run(impl: str, shape, **kw):
+    batch, seq, hq, hkv = shape
+    rng = np.random.default_rng(seq)
+    q, k, v = (jnp.asarray(rng.standard_normal((batch, seq, h, D)),
+                           jnp.bfloat16) for h in (hq, hkv, hkv))
+
+    def loss(q, k, v):
+        out = multihead_attention(q, k, v, impl=impl, **kw)
+        return (out.astype(jnp.float32) ** 2).sum(), out
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return dict(zip(("out", "dq", "dk", "dv"), (out, *grads)))
+
+
+def flash_case(shape=FLASH_SHAPES[0], **kw) -> None:
+    got, want = flash_run("flash", shape, **kw), flash_run("xla", shape, **kw)
+    case("flash_attention fwd+bwd", {n: (got[n], want[n]) for n in got},
+         batch=shape[0], seq=shape[1], heads=f"{shape[2]}/{shape[3]}",
+         head_dim=D, **kw)
+
+
+def flash_control() -> int:
+    """The bound must refuse the flash kernels with the element mask left
+    off their edge tiles (dead tiles still skipped: only the diagonal tiles
+    differ); 1 if it did."""
+    from distributed_training_guide_tpu.ops import flash_attention as flash
+
+    shape = FLASH_SHAPES[1]
+    want = flash_run("xla", shape)
+    real = flash._band_mask
+    flash._band_mask = lambda *a, **kw: None
+    try:
+        got = flash_run("flash", shape)
+    finally:
+        flash._band_mask = real
+    errs = {n: worst(got[n], want[n]) for n in got}
+    caught = all(e > RTOL * max(1.0, m) for e, m in errs.values())
+    print(json.dumps({"control": "flash_edge_tiles_unmasked", "rtol": RTOL,
+                      "max_abs_err_and_ref_max": errs, "refused": caught}),
+          flush=True)
+    return int(caught)
 
 
 # ---- off the smoke's path (--all) -------------------------------------------
@@ -501,7 +536,9 @@ def main(argv) -> int:
     for t in (1, 64):
         paged_case("fp32", 16, t)
     cell_case(1)
-    flash_case()
+    for shape in FLASH_SHAPES:
+        flash_case(shape)
+    flash_refused = flash_control()
     gmm_decode_case(4096, 2048, layer=4)
     lfm2_cases(chunks=everything)
     latent_refused = 3
@@ -522,13 +559,15 @@ def main(argv) -> int:
         int8_matmul_case()
     refused = controls()
     CACHE.print_line()
-    if FAILED or refused != 5 or latent_refused != 3:
+    if FAILED or refused != 5 or latent_refused != 3 or flash_refused != 1:
         print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
               f"sabotaged kernels refused: {refused} of 5, latent "
-              f"{latent_refused} of 3", file=sys.stderr)
+              f"{latent_refused} of 3, flash {flash_refused} of 1",
+              file=sys.stderr)
         return 1
     print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
-                      "controls_refused": refused}), flush=True)
+                      "controls_refused": refused + flash_refused}),
+          flush=True)
     return 0
 
 

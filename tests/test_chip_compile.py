@@ -154,11 +154,24 @@ def assert_experts_read_in_place(text: str, *leaf_shapes) -> None:
 
 # ---- training attention ----------------------------------------------------
 
+# the three train cells' attention, (batch, seq, q heads, kv heads), bf16
+# at head_dim 128: qwen3-0.6b at 2048 and 8192, olmo2-7b's one-chip share of
+# the FSDP cell
+CELL_SHAPES = {"seq2048": (8, 2048, 16, 8), "seq8192": (2, 8192, 16, 8),
+               "fsdp4.seq4096": (2, 4096, 32, 32)}
+FLASH_CASES = ([("seq2048", extras) for extras in (
+    {}, {"window": 512}, {"logit_softcap": 30.0})]
+    + [(cell, {}) for cell in ("seq8192", "fsdp4.seq4096")])
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
-@pytest.mark.parametrize("extras", [{}, {"window": 512},
-                                    {"logit_softcap": 30.0}],
-                         ids=["causal", "banded", "softcap"])
-def test_flash_attention_compiles(chip_compile, extras, backward):
+@pytest.mark.parametrize(
+    "cell,extras", FLASH_CASES,
+    ids=["causal", "banded", "softcap", "seq8192", "fsdp4.seq4096"])
+def test_flash_attention_compiles(chip_compile, cell, extras, backward):
+    """The flash kernels at the three train cells' shapes (and the smoke's
+    banded and soft-capped ones), forward and backward, under their own
+    names and no other kernel."""
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False,
                                **extras)
@@ -167,10 +180,15 @@ def test_flash_attention_compiles(chip_compile, extras, backward):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    qs = ((2, SEQ, HQ, D), jnp.bfloat16)
-    ks = ((2, SEQ, HKV, D), jnp.bfloat16)
-    text = chip_compile(fwd_bwd if backward else fwd, qs, ks, ks)
-    assert "tpu_custom_call" in text
+    b, seq, hq, hkv = CELL_SHAPES[cell]
+    qs = ((b, seq, hq, D), jnp.bfloat16)
+    ks = ((b, seq, hkv, D), jnp.bfloat16)
+    calls = kernel_calls(chip_compile(fwd_bwd if backward else fwd, qs, ks, ks))
+    names = ("flash_fwd", "flash_dq", "flash_dkv") if backward else (
+        "flash_fwd",)
+    assert len(calls) == len(names), calls
+    for name in names:
+        assert any(named(c, name) for c in calls), (name, calls)
 
 
 # ---- the serve attend: decode, verify, chunk ------------------------------

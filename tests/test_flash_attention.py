@@ -10,7 +10,22 @@ import numpy as np
 import pytest
 
 from distributed_training_guide_tpu.ops.attention import _xla_attention
+from distributed_training_guide_tpu.ops import flash_attention as fa
 from distributed_training_guide_tpu.ops.flash_attention import flash_attention
+
+# output / gradient tolerances against the XLA path by operand dtype: bf16
+# operands go to the MXU as stored and p / ds are rounded to them, as
+# ``_xla_attention`` rounds its probabilities
+TOL = {jnp.float32: (1e-5, 2e-4), jnp.bfloat16: (2e-2, 6e-2)}
+DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                                 ids=["fp32", "bf16"])
+
+
+def assert_close(got, want, tol, err_msg=""):
+    assert got.dtype == want.dtype, (got.dtype, want.dtype)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol, err_msg=err_msg)
 
 
 def make_qkv(b, s, hq, hkv, d, dtype=jnp.float32, seed=0):
@@ -21,13 +36,14 @@ def make_qkv(b, s, hq, hkv, d, dtype=jnp.float32, seed=0):
     return q, k, v
 
 
+@DTYPES
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (8, 1)])
 @pytest.mark.parametrize("s", [64, 128])
-def test_forward_matches_xla(hq, hkv, s):
-    q, k, v = make_qkv(2, s, hq, hkv, 32)
+def test_forward_matches_xla(hq, hkv, s, dtype):
+    q, k, v = make_qkv(2, s, hq, hkv, 32, dtype)
     ref = _xla_attention(q, k, v, causal=True, positions=None, kv_positions=None)
     out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+    assert_close(out, ref, TOL[dtype][0])
 
 
 def test_noncausal_forward():
@@ -37,23 +53,25 @@ def test_noncausal_forward():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
+@DTYPES
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
-def test_grads_match_xla(hq, hkv):
-    q, k, v = make_qkv(1, 64, hq, hkv, 32, seed=1)
+def test_grads_match_xla(hq, hkv, dtype):
+    q, k, v = make_qkv(1, 64, hq, hkv, 32, dtype, seed=1)
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=True, block_q=32, block_k=32, interpret=True)
+        o = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                            interpret=True).astype(jnp.float32)
         return jnp.sum(o * jnp.cos(o))
 
     def loss_ref(q, k, v):
-        o = _xla_attention(q, k, v, causal=True, positions=None, kv_positions=None)
+        o = _xla_attention(q, k, v, causal=True, positions=None,
+                           kv_positions=None).astype(jnp.float32)
         return jnp.sum(o * jnp.cos(o))
 
     g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for name, a, b in zip("qkv", g_flash, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4,
-                                   err_msg=f"d{name}")
+        assert_close(a, b, TOL[dtype][1], err_msg=f"d{name}")
 
 
 def test_uneven_blocks():
@@ -342,3 +360,148 @@ def test_per_layer_window_scan_matches_unrolled():
                                **extras)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# tiles of every kind in one grid: interior (no mask), edge (masked), dead
+# (skipped) under every band the callers pack
+# ---------------------------------------------------------------------------
+
+TILE_S, TILE_BQ, TILE_BK = 1024, 128, 256      # an 8 x 4 grid of tiles
+# (window, q_off, k_off): causal; a static window whose lower edge crosses
+# the middle of a tile (query 640 sees keys from 193 on); the ring's chunk
+# pairs: the same chunk, and a past chunk under a window
+BANDS = {"causal": (None, 0, 0), "window": (448, 0, 0),
+         "ring-diagonal": (None, 1024, 1024), "ring-past": (1400, 1024, 0)}
+
+
+def brute_force_tiles(window, q_off, k_off, s=TILE_S, bq=TILE_BQ, bk=TILE_BK):
+    """{(iq, ik): "interior" | "edge" | "dead"} from the element mask."""
+    diff = (q_off + np.arange(s))[:, None] - (k_off + np.arange(s))[None, :]
+    mask = (diff >= 0) & ((diff < window) if window is not None else True)
+    kinds = {}
+    for iq in range(s // bq):
+        for ik in range(s // bk):
+            tile = mask[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk]
+            kinds[iq, ik] = ("interior" if tile.all() else
+                             "edge" if tile.any() else "dead")
+    return kinds
+
+
+@pytest.mark.parametrize("band", sorted(BANDS))
+def test_tile_kinds_match_the_element_mask(band):
+    """``_band_tile``'s two scalar predicates against the mask itself, over
+    every tile of the grids the next test runs; each grid holds all three
+    kinds (the ring's diagonal pair as plain causal does)."""
+    window, q_off, k_off = BANDS[band]
+    want = brute_force_tiles(window, q_off, k_off)
+    assert set(want.values()) == {"interior", "edge", "dead"}
+    for (iq, ik), kind in want.items():
+        live, interior = fa._band_tile(True, window, iq, ik, TILE_BQ, TILE_BK,
+                                       q_off, k_off)
+        got = "dead" if not live else "interior" if interior else "edge"
+        assert got == kind, (iq, ik, got, kind)
+    assert fa._band_tile(False, None, 0, 3, TILE_BQ, TILE_BK) == (True, True)
+    if not (q_off or k_off):
+        counts = fa.tile_counts(True, window, TILE_S, TILE_S, TILE_BQ, TILE_BK)
+        assert counts == tuple(sum(k == kind for k in want.values())
+                               for kind in ("interior", "edge", "dead"))
+
+
+@pytest.mark.parametrize("by_rows", [True, False], ids=["rows", "columns"])
+@pytest.mark.parametrize("band", ["causal", "window"])
+def test_live_tiles_are_the_grid_a_static_band_walks(band, by_rows):
+    """The prefetched tile lists hold exactly the live tiles, outer index
+    first and each walk in order; an outer index with no live tile (kv
+    columns past the last query of a shorter q) keeps one dead entry, so its
+    output block is still written."""
+    window = BANDS[band][0]
+    kinds = brute_force_tiles(window, 0, 0)
+    nq, nk = TILE_S // TILE_BQ, TILE_S // TILE_BK
+    outer, inner = fa._live_tiles(True, window, nq, nk, TILE_BQ, TILE_BK,
+                                  by_rows)
+    walked = list(zip(outer, inner) if by_rows else zip(inner, outer))
+    assert sorted(walked) == sorted(t for t, k in kinds.items() if k != "dead")
+    assert list(zip(outer, inner)) == sorted(zip(outer, inner))
+    # half the q rows: the kv columns past them have no live tile
+    outer, inner = fa._live_tiles(True, window, nq // 2, nk, TILE_BQ, TILE_BK,
+                                  by_rows)
+    assert set(outer) == set(range(nq // 2 if by_rows else nk))
+    dead = [(o, i) for o, i in zip(outer, inner) if not fa._band_tile(
+        True, window, *((o, i) if by_rows else (i, o)), TILE_BQ, TILE_BK)[0]]
+    assert dead == ([] if by_rows else [(2, 0), (3, 0)])
+
+
+def test_the_line_a_flash_call_leaves_counts_its_tiles():
+    """``note_attention``'s line for a flash call: the tiles ``_pick_block``
+    chose (1024-wide for bf16 at head_dim 128 from four tiles a side on, 512
+    otherwise or where the caller's ceiling says so) and the walk's tile
+    counts under a static band."""
+    from distributed_training_guide_tpu.ops import dispatch
+    from distributed_training_guide_tpu.ops.attention import (
+        multihead_attention)
+
+    def line(seq, dtype, **kw):
+        q = jax.ShapeDtypeStruct((2, seq, 16, 128), dtype)
+        k = jax.ShapeDtypeStruct((2, seq, 8, 128), dtype)
+        return fa.describe_walk(q, k, True, kw.pop("window", None), **kw)
+
+    assert line(8192, jnp.bfloat16).startswith(
+        "tiles 1024x1024, a walk: 28 interior / 8 edge / 28 dead")
+    assert line(8192, jnp.bfloat16, block_q=512, block_k=512).startswith(
+        "tiles 512x512, a walk: 120 interior / 16 edge / 120 dead")
+    assert line(2048, jnp.bfloat16).startswith(
+        "tiles 512x512, a walk: 6 interior / 4 edge / 6 dead")
+    assert line(8192, jnp.float32).startswith("tiles 512x512")
+    assert line(4096, jnp.bfloat16, window=1024).startswith(
+        "tiles 512x512, a walk: 7 interior / 14 edge / 43 dead")
+    assert line(8192, jnp.bfloat16, window=4096).startswith("tiles 1024x1024")
+    assert "traced window" in line(4096, jnp.bfloat16,
+                                   window=jnp.asarray(1024))
+    q, k, v = make_qkv(1, 64, 4, 2, 32)
+    with dispatch.record_attention() as record:
+        multihead_attention(q, k, v, impl="flash")
+    assert record["flash"].startswith("forced; tiles 64x64, a walk: 0 "
+                                      "interior / 1 edge / 0 dead")
+
+
+@pytest.mark.parametrize("hq,hkv", [(4, 2), (8, 1)])
+@pytest.mark.parametrize("band", ["causal", "window", "traced-window",
+                                  "ring-diagonal", "ring-past"])
+def test_tiles_of_every_kind_fwd_and_grads_match_xla(band, hq, hkv):
+    """Forward and all three gradients against ``_xla_attention`` on a grid
+    that holds interior, edge and dead tiles at once. The static and traced
+    bands go through the public entry; the ring's offsets through
+    ``_flash_fwd`` / ``flash_bwd_with_stats`` with a packed band, as
+    ``ops/ring_attention.py`` calls them."""
+    window, q_off, k_off = BANDS["window" if band == "traced-window" else band]
+    q, k, v = make_qkv(1, TILE_S, hq, hkv, 32, seed=7)
+    do = jax.random.normal(jax.random.key(8), q.shape, q.dtype)
+    blocks = dict(block_q=TILE_BQ, block_k=TILE_BK, interpret=True)
+
+    def xla_fn(q, k, v):
+        return _xla_attention(q, k, v, True, q_off + jnp.arange(TILE_S)[None],
+                              k_off + jnp.arange(TILE_S)[None], window)
+
+    want, vjp = jax.vjp(xla_fn, q, k, v)
+    want_grads = vjp(do)
+    if band.startswith("ring"):
+        packed = fa._pack_band(window, q_off, k_off)
+        qt, kt, vt, dot = (x.transpose(0, 2, 1, 3) for x in (q, k, v, do))
+        o, lse = fa._flash_fwd(qt, kt, vt, True, None, band=packed, **blocks)
+        delta = jnp.einsum("bhsd,bhsd->bhs", dot, o)
+        grads = fa.flash_bwd_with_stats(qt, kt, vt, dot, lse, delta,
+                                        causal=True, band=packed, **blocks)
+        got, *grads = (x.transpose(0, 2, 1, 3) for x in (o, *grads))
+    else:
+        if band == "traced-window":
+            fn = jax.jit(lambda q, k, v, w: flash_attention(
+                q, k, v, causal=True, window=w, **blocks))
+            got, vjp = jax.vjp(lambda *a: fn(*a, jnp.asarray(window)), q, k, v)
+        else:
+            got, vjp = jax.vjp(lambda *a: flash_attention(
+                *a, causal=True, window=window, **blocks), q, k, v)
+        grads = vjp(do)
+    assert_close(got, want, 1e-5)
+    for name, a, b in zip("qkv", grads, want_grads):
+        assert_close(a, b, 1e-4, err_msg=f"d{name}")

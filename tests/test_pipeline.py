@@ -137,8 +137,10 @@ def test_pp_fsdp_flash_partitions_batch(golden, eight_devices):
               if "fsdp" in axes]
     assert nested, "no batch-manual flash shard_map nested in the pp region"
     batch_spec = P(("dp", "fsdp"), None, None, None)
-    assert any(specs and specs[0] == batch_spec for _, specs in nested), \
-        [s[:1] for _, s in nested]
+    # q, k and v ride in batch-sharded; the kernels' live-tile lists (small
+    # int32 constants of the static band) come first, replicated
+    assert any(list(specs).count(batch_spec) >= 3 for _, specs in nested), \
+        [s for _, s in nested]
 
     losses = []
     for _ in range(2):
