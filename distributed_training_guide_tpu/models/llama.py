@@ -696,7 +696,8 @@ def _layer_window_column(config):
     return jnp.asarray([w if w else 2 ** 30 for w in lw], jnp.int32)
 
 
-POOL_LEAVES = ("k", "v", "state")   # the page pools a cache dict may hold
+# the page pools a cache dict may hold (k_win, v_win: a second page class)
+POOL_LEAVES = ("k", "v", "state", "k_win", "v_win")
 
 
 def cache_pools(cache: dict) -> tuple:

@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional
 
-from . import gpt2, lfm2, llama, mla, moe, neox
+from . import gpt2, lfm2, llama, mimo_v2, mla, moe, neox
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,6 +54,7 @@ _HF_ALIASES = {
     "eleutherai/gpt-neox-20b": "gpt-neox-20b",
     "mistralai/mistral-small-4-119b-2603": "mistral-small-4-119b",
     "liquidai/lfm2-24b-a2b": "lfm2-24b-a2b",
+    "xiaomimimo/mimo-v2.5": "mimo-v2.5",
 }
 
 
@@ -61,7 +62,7 @@ def family_module(family: str):
     """The module implementing a model family (block/embed/head helpers used
     by the pipeline schedule and chunked losses)."""
     mods = {"llama": llama, "gpt2": gpt2, "moe": moe, "neox": neox,
-            "mla_moe": mla, "lfm2_moe": lfm2}
+            "mla_moe": mla, "lfm2_moe": lfm2, "mimo_v2": mimo_v2}
     if family not in mods:
         raise KeyError(f"unknown model family {family!r}")
     return mods[family]
@@ -70,7 +71,7 @@ def family_module(family: str):
 def list_models() -> list[str]:
     return (sorted(gpt2.PRESETS) + sorted(llama.PRESETS) + sorted(moe.PRESETS)
             + sorted(neox.PRESETS) + sorted(mla.PRESETS)
-            + sorted(lfm2.PRESETS))
+            + sorted(lfm2.PRESETS) + sorted(mimo_v2.PRESETS))
 
 
 def get_model(name: str, **overrides) -> ModelBundle:
@@ -125,6 +126,12 @@ def get_model(name: str, **overrides) -> ModelBundle:
             config = dataclasses.replace(config, **overrides)
         return ModelBundle(key, config, lfm2.init, lfm2.apply,
                            lfm2.param_logical_axes, family="lfm2_moe")
+    if key in mimo_v2.PRESETS:
+        config = mimo_v2.PRESETS[key]
+        if overrides:
+            config = dataclasses.replace(config, **overrides)
+        return ModelBundle(key, config, mimo_v2.init, mimo_v2.apply,
+                           mimo_v2.param_logical_axes, family="mimo_v2")
     raise ValueError(
         f"Unknown model {name!r}. Available: {', '.join(list_models())} "
         f"(HF aliases: {', '.join(sorted(_HF_ALIASES))})"

@@ -31,6 +31,7 @@ def _xla_attention(
     window=None,
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,
+    sink: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -58,15 +59,23 @@ def _xla_attention(
             mask &= (qp - kp) < window
         scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
 
-    probs = jax.nn.softmax(scores, axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+    else:   # one more column a query head holding its sink logit: it takes
+        # probability and gives no value (MiMo-V2's window layers)
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, hkv, groups, 1, 1),
+            scores.shape[:-1] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate([scores, column], -1),
+                               axis=-1)[..., :-1]
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     # tagged so REMAT_POLICIES["attn"] can keep the [B,S,H,D] output: layers
     # downstream then never re-run this attention forward. (This path's own
     # backward still rebuilds scores/probs — the [S,S] recompute is only
     # fully eliminated on the flash path, whose lse residual is also tagged.)
-    return checkpoint_name(out.reshape(b, sq, hq, d).astype(q.dtype),
-                           "attn_out")
+    return checkpoint_name(
+        out.reshape(b, sq, hq, v.shape[-1]).astype(q.dtype), "attn_out")
 
 
 def resolve_attention_impl(impl: str, q_len: int, kv_len: int, head_dim: int,
@@ -103,6 +112,7 @@ def multihead_attention(
     window=None,
     scale: Optional[float] = None,
     logit_softcap: Optional[float] = None,
+    sink: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Scaled-dot-product attention with GQA.
 
@@ -117,8 +127,14 @@ def multihead_attention(
     ``scale``: score scale override (Gemma-2's query_pre_attn_scalar**-0.5;
     default head_dim**-0.5). ``logit_softcap``: Gemma-2 tanh capping of the
     scaled scores — both paths, with the (1 - tanh^2) backward term on the
-    flash path.
+    flash path. ``sink`` [Hq] (the einsum path alone): a learned logit a
+    query head that joins the softmax as one more column with no value.
+    The value heads may be narrower than the key heads (``v [.., Dv]``): the
+    einsum path returns ``[B, S, Hq, Dv]``.
     """
+    if sink is not None and impl != "xla":
+        raise ValueError("a sink logit is implemented on the einsum path "
+                         "(impl='xla') and in the paged kernel only")
     if window is not None and not causal:
         # the band is defined relative to the causal diagonal; the xla path
         # builds its window mask inside the `if causal:` block and would
@@ -143,4 +159,4 @@ def multihead_attention(
                                scale=scale, logit_softcap=logit_softcap)
     note_attention(impl, reason)
     return _xla_attention(q, k, v, causal, positions, kv_positions, window,
-                          scale, logit_softcap)
+                          scale, logit_softcap, sink)

@@ -86,6 +86,15 @@ to the score columns, the v scale to ``p``'s columns (``q . (k*s)`` is
 ``(q . k) * s``), so the int8 payload meets the MXU as it is read and no
 float pool is ever materialized — at any T.
 
+TWO EXTRAS of a family whose attention layers differ in kind
+(``models/mimo_v2.py``), both static and both absent from every other
+family's call: a key WIDER than the value row lies in ``parts`` pool rows of
+one lane tile each (a 192-wide key in two 128-wide rows, the k pool holding
+``parts`` layers a layer), so that the pools are still read where they lie; a
+score is then the sum of the parts' products. And a SINK logit a query head
+joins each row's softmax as a column that holds no value: the running
+statistics start from it (max = the logit, sum = 1) instead of from nothing.
+
 ``interpret=True`` runs the kernel on CPU — the tier-1 parity grids in
 ``tests/test_paged_decode.py`` pin it against the XLA gather path at
 1e-5 across GQA/MHA/window/scale/softcap, shuffled physical layouts,
@@ -132,9 +141,11 @@ def _lane_tiles(columns: int) -> int:
     return -(-columns // 128) * 128
 
 
-def _head_blocks(t, groups, hkv, d, q_dtype, pool_dtype):
+def _head_blocks(t, groups, hkv, d, q_dtype, pool_dtype, *, parts=1,
+                 sink=False):
     """``(hb, fits)``: kv heads one product takes, and the head-block sizes
-    whose q, out and accumulator tiles stay inside ``TILE_BUDGET``."""
+    whose q, out and accumulator tiles stay inside ``TILE_BUDGET``. Pool rows
+    are ``d`` wide; a key is ``parts`` of them (a query ``parts * d``)."""
     tg = t * groups
     words = 4 // jnp.dtype(pool_dtype).itemsize     # rows sharing 32 bits
     # the strided load takes a VMEM block whose rows are one 128-lane tile
@@ -143,7 +154,8 @@ def _head_blocks(t, groups, hkv, d, q_dtype, pool_dtype):
     else:
         hb = words
     q_item = jnp.dtype(q_dtype).itemsize
-    per_head = tg * (4 * d * q_item + d * 4 + 2 * 128 * 4)
+    per_head = tg * (2 * (parts + 1) * d * q_item + d * 4
+                     + (3 if sink else 2) * 128 * 4)
     # a head block's rows are a block of q's second-minor dimension
     fits = [h for h in range(hb, hkv + 1, hb) if hkv % h == 0
             and h * per_head <= TILE_BUDGET
@@ -151,7 +163,7 @@ def _head_blocks(t, groups, hkv, d, q_dtype, pool_dtype):
     return hb, fits
 
 
-def _query_block(t, groups, hkv, d, q_dtype, pool_dtype) -> int:
+def _query_block(t, groups, hkv, d, q_dtype, pool_dtype, **more) -> int:
     """Query tokens one kernel call takes: ``t`` where some head block's
     tile fits the budget, else the largest divisor of ``t`` for which one
     does (``t`` again if none). A chunk of 1,024 tokens at 8 query heads a
@@ -159,21 +171,24 @@ def _query_block(t, groups, hkv, d, q_dtype, pool_dtype) -> int:
     it goes in blocks of 128 tokens, each its own walk of the table."""
     for bt in sorted((b for b in range(1, t + 1) if t % b == 0),
                      reverse=True):
-        if _head_blocks(bt, groups, hkv, d, q_dtype, pool_dtype)[1]:
+        if _head_blocks(bt, groups, hkv, d, q_dtype, pool_dtype, **more)[1]:
             return bt
     return t
 
 
-def _plan(t, groups, hkv, d, page, max_pages, q_dtype, pool_dtype):
+def _plan(t, groups, hkv, d, page, max_pages, q_dtype, pool_dtype, *,
+          parts=1, sink=False):
     """Block parameters from the static shapes: ``(hs, hb, n)`` = kv heads
     a grid step holds, kv heads one product takes (``hs % hb == 0``), pages
     a block of the walk holds."""
     tg = t * groups
-    hb, fits = _head_blocks(t, groups, hkv, d, q_dtype, pool_dtype)
+    hb, fits = _head_blocks(t, groups, hkv, d, q_dtype, pool_dtype,
+                            parts=parts, sink=sink)
     hs = max(fits) if fits else hkv
+    # a page's k rows (each part) and v rows, both buffer halves of each
     page_bytes = page * hkv * d * jnp.dtype(pool_dtype).itemsize
     n = min(MAX_BLOCK_PAGES, max_pages,
-            PAGES_BUDGET // (2 * DEPTH * page_bytes),
+            PAGES_BUDGET // ((parts + 1) * DEPTH * page_bytes),
             SCORE_BUDGET // (hb * tg * _lane_tiles(page * hb) * 4))
     return hs, hb, max(1, n)
 
@@ -194,14 +209,22 @@ def _head_rows(buf, slot, i, head, *, hb, hkv, page):
 
 def _attend_kernel(lens_ref, tabs_ref, band_ref, base_ref, q_ref, k_hbm,
                    v_hbm, *rest, scale, softcap, page, hkv, hs, hb, n,
-                   quantized, block_q, groups):
+                   quantized, block_q, groups, has_sink=False, parts=1):
     """Grid (slot, head block). The walk over the slot's live pages is the
     ``fori_loop`` below; (m, l, acc) carry the online softmax across its
     blocks in VMEM scratch. Query row ``r`` of a head is the slot's token
     ``r // groups`` at position ``lengths[slot] + r // groups``. The pools
     are every layer's, page ``base_ref[0] + p`` being this layer's page
     ``p``. Under ``quantized`` the pages' k/v scale rows (this layer's
-    alone) come along the same walk."""
+    alone) come along the same walk. Under ``has_sink`` a row's running
+    softmax starts from its head's sink logit instead of from nothing: a
+    column of the softmax that holds no value (max = the logit, sum = 1,
+    accumulator 0). With ``parts`` > 1 a key is that many pool rows, part
+    ``j`` of layer ``l`` being the k pool's layer ``l * parts + j`` (its first
+    page ``base_ref[1 + j]``), and a score the sum of the parts' products
+    with the query's matching columns."""
+    if has_sink:
+        sink_ref, *rest = rest
     if quantized:
         (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sems,
          m_scr, l_scr, acc_scr) = rest
@@ -236,12 +259,22 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, base_ref, q_ref, k_hbm,
                              tabs_ref[s_idx, jnp.minimum(col, max_pages - 1)],
                              0)
             for j, (hbm, buf, first) in enumerate(pairs):
+                if parts > 1 and j == 0:    # the key's parts, a page each
+                    for part in range(parts):
+                        out.append(pltpu.make_async_copy(
+                            hbm.at[base_ref[1 + part] + phys],
+                            buf.at[slot, i * parts + part], sems.at[j, slot]))
+                    continue
                 out.append(pltpu.make_async_copy(
                     hbm.at[first + phys], buf.at[slot, i], sems.at[j, slot]))
         return out
 
-    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
+    if has_sink:
+        m_scr[:] = sink_ref[:]
+        l_scr[:] = jnp.ones_like(l_scr)
+    else:
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # a product's rows are (head, token, group), its columns (position,
@@ -276,13 +309,21 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, base_ref, q_ref, k_hbm,
         for g in range(hs // hb):
             rows = slice(g * hb * tg, (g + 1) * hb * tg)
             head = h_idx * hs + g * hb
-            q = q_ref[0, rows, :].astype(mxu)
+            d = kbuf.shape[-1]
+            qs = [q_ref[0, rows, pl.ds(part * d, d)].astype(mxu)
+                  for part in range(parts)] if parts > 1 else [
+                      q_ref[0, rows, :].astype(mxu)]
             scores, masks = [], []
             for i in range(n):
-                k = _head_rows(kbuf, slot, i, head, hb=hb, hkv=hkv, page=page)
-                s = jax.lax.dot_general(
-                    q, k.astype(mxu), (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
+                s = None
+                for part, q in enumerate(qs):
+                    k = _head_rows(kbuf, slot, i * parts + part, head, hb=hb,
+                                   hkv=hkv, page=page)
+                    sp = jax.lax.dot_general(
+                        q, k.astype(mxu), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    s = sp if s is None else s + sp
+                s = s * scale
                 if quantized:   # the k scale, on the score's columns
                     s = s * ksbuf[slot, i, pl.ds(head // hb, 1),
                                   :page * hb]
@@ -328,8 +369,8 @@ def _attend_kernel(lens_ref, tabs_ref, band_ref, base_ref, q_ref, k_hbm,
     o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
-PAGED_GATE = ("pool row width % 128 == 0 (head_dim, or two 64-wide heads a "
-              "row) and page_size % 8 == 0")
+PAGED_GATE = ("pool row width % 128 == 0 (head_dim, two 64-wide heads a "
+              "row, or a 192-wide key in two rows) and page_size % 8 == 0")
 
 
 def paged_decode_eligible(head_dim: int, page_size: int) -> bool:
@@ -352,10 +393,15 @@ def paged_decode_eligible(head_dim: int, page_size: int) -> bool:
     return head_dim % 128 == 0 and page_size % 8 == 0
 
 
-def _layer_base(layer, n_phys: int) -> jnp.ndarray:
+def _layer_base(layer, n_phys: int, parts: int = 1) -> jnp.ndarray:
     """The scalar-prefetched ``[1]`` int32 first page of ``layer`` in pools
-    seen as ``[L * n_phys, ...]``."""
-    return (jnp.asarray(layer, jnp.int32) * n_phys).reshape(1)
+    seen as ``[L * n_phys, ...]``; with a key in ``parts`` rows, ``[1 +
+    parts]``: the v pool's, then each key part's in the k pool."""
+    base = (jnp.asarray(layer, jnp.int32) * n_phys).reshape(1)
+    if parts == 1:
+        return base
+    return jnp.concatenate(
+        [base, (base * parts + jnp.arange(parts, dtype=jnp.int32) * n_phys)])
 
 
 def paged_flash_attend(
@@ -375,12 +421,19 @@ def paged_flash_attend(
     window=None,
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
+    sink: Optional[jnp.ndarray] = None,      # [Hq] a sink logit a query head
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Flash attention through the block table at query-tile size T;
     returns [S, T, Hq, D] (or [S, Hq, D] for a rank-3 q) in q.dtype
     (the output dtype is the QUERY's — a quantized pool still emits
-    float attention).
+    float attention). ``D`` is the width of a pool ROW. A key wider than a
+    row lies in ``parts`` of them (q ``[.., parts * D]``, ``k_pages [parts *
+    L, ...]``: part ``j`` of layer ``l`` is the k pool's layer ``l * parts +
+    j``; a 192-wide key padded to 256 is two rows beside a 128-wide value),
+    each row one lane tile, so the pools are still read where they lie.
+    ``sink`` adds one column to every row's softmax, holding the row's
+    head's logit and no value.
 
     The pools are the stacked ones the engine holds, handed over whole:
     the kernel reads page ``layer * P + tables[s, i]`` of their
@@ -403,8 +456,14 @@ def paged_flash_attend(
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
-    s, t, hq, d = q.shape
-    n_layers, n_phys, page, hkv, _ = k_pages.shape
+    s, t, hq, dq = q.shape
+    n_layers, n_phys, page, hkv, d = v_pages.shape
+    parts = dq // d
+    if k_pages.shape != (parts * n_layers, n_phys, page, hkv, d) or (
+            parts > 1 and quantized):
+        raise ValueError(
+            f"q rows of {dq} over v rows of {d} need a float k pool of "
+            f"{parts} x {n_layers} layers of such rows; got {k_pages.shape}")
     m = tables.shape[1]
     if hkv < 1 or hq % hkv:
         # a silent floor-division here would drop query heads (the
@@ -416,13 +475,19 @@ def paged_flash_attend(
     groups = hq // hkv
     tg = t * groups
     if scale is None:
-        scale = 1.0 / (d ** 0.5)
+        scale = 1.0 / (dq ** 0.5)
     interpret = resolve_interpret(interpret)
     if not interpret and not paged_decode_eligible(d, page):
         raise ValueError(
             f"paged flash attend (compiled) needs {PAGED_GATE}; got "
             f"head_dim={d}, page_size={page} — use impl='xla'")
-    bt = _query_block(t, groups, hkv, d, q.dtype, k_pages.dtype)
+    has_sink = sink is not None
+    more = {}       # what a one-part, sinkless call never passes on
+    if parts > 1:
+        more["parts"] = parts
+    if has_sink:
+        more["sink"] = True
+    bt = _query_block(t, groups, hkv, d, q.dtype, k_pages.dtype, **more)
     if bt < t:
         # a tile no head block fits in VMEM: the query tokens in blocks of
         # bt, one after the other, each with its own walk from the tokens
@@ -432,34 +497,48 @@ def paged_flash_attend(
             return paged_flash_attend(
                 qb, k_pages, v_pages, layer, tables, lengths + first,
                 k_scale=k_scale, v_scale=v_scale, window=window, scale=scale,
-                softcap=softcap, interpret=interpret)
+                softcap=softcap, sink=sink, interpret=interpret)
 
         out = jax.lax.map(rows, (
-            q.reshape(s, t // bt, bt, hq, d).swapaxes(0, 1),
+            q.reshape(s, t // bt, bt, hq, dq).swapaxes(0, 1),
             jnp.arange(t // bt, dtype=lengths.dtype) * bt))
         return out.swapaxes(0, 1).reshape(s, t, hq, d)
     band = _pack_band(window)     # [window|2**30, 0, 0] int32 — the same
                                   # dynamic-band contract as the training
                                   # kernels; traced per-layer windows ride it
-    hs, hb, n = _plan(t, groups, hkv, d, page, m, q.dtype, k_pages.dtype)
+    hs, hb, n = _plan(t, groups, hkv, d, page, m, q.dtype, k_pages.dtype,
+                      **more)
     # rows (head, token, group): row r of a kv head is token r // groups.
     # For T == 1 the transpose is a no-op.
-    qr = (q.reshape(s, t, hkv, groups, d)
-           .transpose(0, 2, 1, 3, 4).reshape(s, hkv * tg, d))
+    qr = (q.reshape(s, t, hkv, groups, dq)
+           .transpose(0, 2, 1, 3, 4).reshape(s, hkv * tg, dq))
 
     kernel = functools.partial(_attend_kernel, scale=scale, softcap=softcap,
                                page=page, hkv=hkv, hs=hs, hb=hb, n=n,
-                               quantized=quantized, block_q=t, groups=groups)
+                               quantized=quantized, block_q=t, groups=groups,
+                               **({"has_sink": True} if has_sink else {}),
+                               **({"parts": parts} if parts > 1 else {}))
     # the pools are handed over where they lie: a page's (position, head)
     # pairs as rows, every layer's pages in one run, the same bytes as
     # [L, P, page, Hkv, D]
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    tile = pl.BlockSpec((1, hs * tg, d),
-                        lambda s_, h, lens, tabs, band_, base: (s_, h, 0))
-    in_specs = [tile, in_hbm, in_hbm]
-    operands = [qr, k_pages.reshape(n_layers * n_phys, page * hkv, d),
+    def tile(width):
+        return pl.BlockSpec((1, hs * tg, width),
+                            lambda s_, h, lens, tabs, band_, base: (s_, h, 0))
+
+    in_specs = [tile(dq), in_hbm, in_hbm]
+    operands = [qr, k_pages.reshape(parts * n_layers * n_phys, page * hkv, d),
                 v_pages.reshape(n_layers * n_phys, page * hkv, d)]
-    scratch = [pltpu.VMEM((DEPTH, n, page * hkv, d), k_pages.dtype),
+    if has_sink:
+        # rows (head, token, group) like q's: row r of kv head h is query
+        # head h * groups + r % groups; one lane tile wide, as m and l are
+        rows = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(hkv, 1, groups, 1),
+            (hkv, t, groups, 128)).reshape(hkv * tg, 128)
+        in_specs.append(pl.BlockSpec(
+            (hs * tg, 128), lambda s_, h, lens, tabs, band_, base: (h, 0)))
+        operands.append(rows)       # the kernel takes it after the pools
+    scratch = [pltpu.VMEM((DEPTH, n * parts, page * hkv, d), k_pages.dtype),
                pltpu.VMEM((DEPTH, n, page * hkv, d), v_pages.dtype)]
     if quantized:
         # a page's scales as one lane vector per product: [hkv/hb,
@@ -488,7 +567,7 @@ def paged_flash_attend(
         num_scalar_prefetch=4,          # lengths, tables, band, layer base
         grid=(s, hkv // hs),
         in_specs=in_specs,
-        out_specs=tile,
+        out_specs=tile(d),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
@@ -499,7 +578,7 @@ def paged_flash_attend(
         interpret=interpret,
         name="paged_attend",
     )(lengths.astype(jnp.int32), tables.astype(jnp.int32), band,
-      _layer_base(layer, n_phys), *operands)
+      _layer_base(layer, n_phys, parts), *operands)
     out = (out.reshape(s, hkv, t, groups, d)
               .transpose(0, 2, 1, 3, 4).reshape(s, t, hq, d))
     return out[:, 0] if squeeze else out

@@ -225,7 +225,7 @@ def main(argv=None) -> None:
     common = dict(n_slots=args.n_slots, page_size=args.page_size,
                   n_pages=args.n_pages, max_len=args.max_len,
                   prefill_chunk=args.prefill_chunk,
-                  prefix_cache=not args.no_prefix_cache,
+                  prefix_cache=False if args.no_prefix_cache else None,
                   attend_impl=args.attend_impl, plan=plan,
                   shard_kv=args.shard_kv, max_queue=args.max_queue,
                   speculate=speculate, spec_k=args.spec_k,

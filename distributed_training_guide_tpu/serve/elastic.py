@@ -60,7 +60,7 @@ from typing import Optional
 
 from ..utils import faults
 from .disagg import DisaggEngine
-from .engine import ServeEngine
+from .engine import ServeEngine, refuse_for_family
 from .kv_pages import pages_for_tokens
 from .scheduler import RequestResult, Scheduler
 from .transport import gather_payload, scatter_payload
@@ -243,6 +243,8 @@ def new_generation(old, *, params=None, **overrides):
     two-call form. A publish mid-swap is rejected by the swap guard (a
     changed layout fails publish validation loudly; that case IS a new
     deployment)."""
+    refuse_for_family(old.programs.mod, old.bundle.family,
+                      {"engine swap": True})
     baked = {"kv_dtype", "weight_dtype", "attend_impl", "plan", "shard_kv"}
     bad = baked & set(overrides)
     if bad:

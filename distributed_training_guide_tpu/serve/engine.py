@@ -72,7 +72,8 @@ from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        init_adapter_stacks, validate_adapter_params)
 from .kv_pages import (resolve_attend_for, copy_pages, init_pages,
                        kv_dtype_name, kv_page_bytes, make_attend, state_layout,
-                       PagePool, pages_for_tokens, pool_nbytes, TRASH_PAGE)
+                       PagePool, pages_for_tokens, pool_nbytes, TRASH_PAGE,
+                       window_layout, window_pages_bound)
 from .scheduler import Admission, Request, RequestResult, Scheduler
 from .spec import Drafter, NgramDrafter, new_spec_counters
 from .tiering import (HostTier, cache_prefix_keys, make_gather,
@@ -386,6 +387,8 @@ def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
         slot = sched.slots[slot_idx]
         start = slot.cache_len
         real = min(chunk, slot.target_len - start)
+        if sched.window is not None:   # the chunk's window-class pages
+            sched.reserve_window(slot_idx, start, real)
         # budget is charged at the PROGRAM cost (the chunk is padded to
         # `chunk` whatever `real` is) — charging real tokens would let N
         # slots with short final chunks run N full-width forwards in one
@@ -1263,11 +1266,13 @@ class ModelPrograms:
         return sizes
 
     # ---- state placement ---------------------------------------------------
-    def init_device_pages(self, n_pages: int, page_size: int) -> dict:
+    def init_device_pages(self, n_pages: int, page_size: int,
+                          n_window_pages: Optional[int] = None) -> dict:
         """Zeroed pools placed per the serve sharding rules (kv-head
         split under shard_kv, replicated under a plain plan)."""
         pages = init_pages(self.config, n_pages, page_size,
-                           kv_dtype=self.kv_dtype)
+                           kv_dtype=self.kv_dtype,
+                           n_window_pages=n_window_pages)
         if self.shard_kv:
             return jax.device_put(pages, self._pool_shardings())
         if self.plan is not None:
@@ -1538,6 +1543,13 @@ class ServeEngine:
     + grow/preempt + admit + prefill work + one batched decode) and
     returns whatever finished.
 
+    A family whose window layers keep pages of their own
+    (``kv_pages.window_layout``) gets its SECOND page class sized by the
+    engine: what the slots and one prefill chunk can hold at once
+    (``kv_pages.window_pages_bound``), so no reservation can fail.
+    ``prefix_cache`` then defaults to off (the family refuses it by name,
+    like a decode horizon).
+
     ``prefix_cache`` (default on): committed prompt pages register in a
     content-keyed cache so identical prefixes share physical pages across
     requests (refcounted, copy-on-write; a match may end mid-page).
@@ -1582,7 +1594,8 @@ class ServeEngine:
                  page_size: int = 16, n_pages: Optional[int] = None,
                  max_len: Optional[int] = None, plan=None,
                  prefill_chunk: Optional[int] = None,
-                 prefix_cache: bool = True, attend_impl: str = "auto",
+                 prefix_cache: Optional[bool] = None,
+                 attend_impl: str = "auto",
                  shard_kv: bool = False, max_queue: Optional[int] = None,
                  programs: Optional[ModelPrograms] = None,
                  speculate=None, spec_k: int = 4, kv_dtype=None,
@@ -1606,7 +1619,12 @@ class ServeEngine:
             bundle.family)
         refuse_for_family(mod, bundle.family, {
             "speculate": speculate is not None,
-            "host_tier_bytes": host_tier_bytes is not None})
+            "host_tier_bytes": host_tier_bytes is not None,
+            "prefix_cache": bool(prefix_cache),
+            "decode_horizon": decode_horizon > 1})
+        if prefix_cache is None:    # on, unless the family cannot serve it
+            prefix_cache = "prefix_cache" not in getattr(
+                mod, "SERVE_REFUSES", {})
         self.drafter = resolve_drafter(speculate, spec_k=spec_k,
                                        n_slots=n_slots)
         self.spec = new_spec_counters()
@@ -1648,7 +1666,14 @@ class ServeEngine:
             # default: full residency + the trash page — backpressure /
             # preemption only engage when the caller sizes the pool below
             n_pages = 1 + n_slots * self.max_pages
-        pool = PagePool(n_pages, page_size)
+        # a family with a second page class (kv_pages.window_layout): its
+        # window layers' pages, held only while a query can see them. The
+        # class is sized so that no reservation can fail, and no larger: a
+        # bounded few pages a slot and one chunk's worth
+        second = window_layout(self.config)
+        n_window_pages = None if second is None else window_pages_bound(
+            second["window"], page_size, n_slots, self.prefill_chunk)
+        pool = PagePool(n_pages, page_size, n_window_pages)
         self.scheduler = Scheduler(
             n_slots=n_slots, pool=pool, max_len=self.max_model_len,
             max_pages_per_slot=self.max_pages, prefix_cache=prefix_cache,
@@ -1659,9 +1684,11 @@ class ServeEngine:
             adapter_pool=self.adapter_pool,
             decode_horizon=decode_horizon,
             # a page's recurrent-state row is the state at its last token
-            partial_page_hits=state_layout(self.config) is None)
+            partial_page_hits=state_layout(self.config) is None,
+            window=None if second is None else second["window"])
 
-        self.pages = self.programs.init_device_pages(n_pages, page_size)
+        self.pages = self.programs.init_device_pages(n_pages, page_size,
+                                                     n_window_pages)
 
         # host-RAM KV tier (serve/tiering.py): spilled prefix pages and
         # preempted sequences park here instead of being recomputed.
@@ -2146,6 +2173,7 @@ class ServeEngine:
             "active_slots": len(sched.active_indices()),
             "prefilling_slots": len(sched.prefilling_indices()),
             "prefill_calls": self.programs.prefill_calls,
+            "live_pages_by_class": sched.live_pages_by_class(),
             **({"routing": dict(self.programs.routing)}
                if self.programs.routing["steps"] else {}),
             # committed prefix keys for the router's fleet directory —

@@ -102,6 +102,25 @@ cache: a row is the state at its page's LAST token, so a hit on such a pool
 ends at a full page (``Scheduler(partial_page_hits=False)``). Only the
 layers that attend have k and v pages (``config.num_kv_layers``).
 
+A family whose layers attend over different REACHES (``models/mimo_v2.py``:
+full layers, and window layers that see the last ``W`` positions) states a
+second PAGE CLASS in ``config.window_kv_layout()``. The pool dict then holds
+two more leaves, ``"k_win"`` / ``"v_win"`` ``[window layers, n_window_pages,
+page, heads, width]``, with their own layout, their own page count and their
+own id space (page 0 of it the trash page again). There is still ONE allocator
+object: ``PagePool.window`` is the second class's free list, and a sequence
+holds, beside its full-class pages, only the window-class pages its next
+query can still see (``window_page_span``): the scheduler RETURNS a window
+page to the free list, on the host and with no device copy, in the engine step
+in which the window slides past its last position. A sequence's two tables
+ride every program as ONE int32 array ``[S, 2 M]``, the full class's columns
+first; both are indexed by the sequence's LOGICAL page, and a window-class
+column whose page was returned (or never held) names the trash page: the
+kernel's walk starts at the oldest page the window reaches, so it never looks
+there, and the gather path masks those positions by the same band.
+``make_attend`` hands each layer the half its ``page_class`` names. A
+one-class family is the case without the second half: none of this runs.
+
 Device-side pieces (``paged_attend``, ``copy_pages``) are pure functions
 of array arguments — block tables and lengths arrive as int32 arrays, so
 requests coming and going never change a traced shape. The allocator
@@ -157,6 +176,15 @@ def pool_layout(config) -> dict:
     return {"k": shape, "v": shape}
 
 
+def key_parts(config) -> int:
+    """Pool rows a key lies in (``config.key_parts``, default 1). A key wider
+    than the value row (``models/mimo_v2.py``: 192 beside 128) is padded to
+    that many rows; part ``j`` of layer ``l`` is the k pool's layer ``l *
+    parts + j``, so every leaf keeps rows of one lane tile, which the
+    compiled kernel reads where they lie."""
+    return getattr(config, "key_parts", 1)
+
+
 def is_latent(config) -> bool:
     """True where the pool holds latent rows (``config.latent_cache``): the
     attend is then the absorbed or the decompressed latent form."""
@@ -174,6 +202,38 @@ def state_layout(config) -> Optional[tuple]:
     family keeps beside k and v (``config.state_layout()``), or None."""
     layout = getattr(config, "state_layout", None)
     return None if layout is None else layout()
+
+
+WINDOW_LEAVES = ("k_win", "v_win")   # the window class's pools
+
+
+def window_layout(config) -> Optional[dict]:
+    """The second page class a family states (``config.window_kv_layout()``):
+    ``{"layers": window layers, "window": W, "k": (heads, width), "v":
+    (heads, width)}``, or None for the one-class families."""
+    layout = getattr(config, "window_kv_layout", None)
+    return None if layout is None else layout()
+
+
+def window_page_span(start: int, n_tokens: int, window: int,
+                     page_size: int) -> range:
+    """The logical pages a window layer must hold while a sequence writes
+    tokens ``start .. start + n_tokens - 1``: from the page of the oldest
+    position the first of them sees to the page of the last."""
+    return range(max(start - (window - 1), 0) // page_size,
+                 (start + n_tokens - 1) // page_size + 1)
+
+
+def window_pages_bound(window: int, page_size: int, n_slots: int,
+                       chunk: int) -> int:
+    """Window-class pages an engine needs so that no reservation can fail:
+    the trash page, what every slot holds between two steps (the pages of
+    ``window`` positions, however they straddle), and what ONE prefill chunk
+    holds while it runs (one chunk runs at a time)."""
+    def most(n_tokens):     # pages that `window - 1 + n_tokens` positions
+        return (window + n_tokens - 3) // page_size + 2     # can straddle
+
+    return 1 + n_slots * most(1) + most(chunk)
 
 
 def kv_dtype_name(config, kv_dtype=None) -> str:
@@ -275,7 +335,7 @@ def _state_dtype(name: str):
 
 
 def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
-                  kv_dtype=None) -> int:
+                  kv_dtype=None, window_class: bool = False) -> int:
     """Resident bytes of ``n_pages`` KV pages for this model at
     ``kv_dtype`` (None = the model's storage dtype): pages x layers x
     page_size x, summed over the pool's leaves (:func:`pool_layout`: k and
@@ -285,12 +345,22 @@ def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
     not hidden]) — the per-slot serving cost is this at ``n_pages =
     pages_for_tokens(context)`` (train/preflight.py reports that table).
     The layers are those that attend (:func:`num_kv_layers`); a page's
-    recurrent-state rows (:func:`state_layout`) are part of its price."""
+    recurrent-state rows (:func:`state_layout`) are part of its price.
+    ``window_class``: the same for a page of the second class
+    (:func:`window_layout`)."""
     name = kv_dtype_name(config, kv_dtype)
+    rows = {"k": key_parts(config)}     # pool rows a token's leaf holds
+    if window_class:
+        layout = window_layout(config)
+        itemsize = jnp.dtype(_KV_FLOAT[name]).itemsize
+        return (n_pages * layout["layers"] * page_size * itemsize
+                * sum(rows.get(leaf, 1) * layout[leaf][0] * layout[leaf][1]
+                      for leaf in ("k", "v")))
     per_token = sum(
-        heads * (width + 4 if name == "int8"
-                 else width * jnp.dtype(_KV_FLOAT[name]).itemsize)
-        for heads, width in pool_layout(config).values())
+        rows.get(leaf, 1) * heads * (
+            width + 4 if name == "int8"
+            else width * jnp.dtype(_KV_FLOAT[name]).itemsize)
+        for leaf, (heads, width) in pool_layout(config).items())
     per_page = num_kv_layers(config) * page_size * per_token
     state = state_layout(config)
     if state is not None:
@@ -300,7 +370,8 @@ def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
     return n_pages * per_page
 
 
-def init_pages(config, n_pages: int, page_size: int, kv_dtype=None) -> dict:
+def init_pages(config, n_pages: int, page_size: int, kv_dtype=None,
+               n_window_pages: Optional[int] = None) -> dict:
     """Zeroed page pools {"k","v"}: STACKED [L, n_pages, page_size, heads,
     width] arrays, which every program takes, carries through its layer scan
     and returns whole (:func:`paged_attend`'s contract; page 0 of every
@@ -311,22 +382,41 @@ def init_pages(config, n_pages: int, page_size: int, kv_dtype=None) -> dict:
     dequantize to the same zero pool the float form starts with. L counts
     the layers that attend (:func:`num_kv_layers`). A family with per-page
     recurrent state (:func:`state_layout`) gets a third leaf ``"state"``
-    ``[state layers, n_pages, rows, width]``, in the pool's float dtype."""
+    ``[state layers, n_pages, rows, width]``, in the pool's float dtype. A
+    family with a second page class (:func:`window_layout`) gets ``"k_win"``
+    and ``"v_win"`` ``[window layers, n_window_pages, page_size, heads,
+    width]``, float."""
     name = kv_dtype_name(config, kv_dtype)
 
-    def pool(heads, width):
-        shape = (num_kv_layers(config), n_pages, page_size, heads, width)
+    parts = key_parts(config)
+    if parts > 1 and name == "int8":
+        raise ValueError("a key in several pool rows is stored in float")
+
+    def pool(heads, width, rows=1):
+        shape = (rows * num_kv_layers(config), n_pages, page_size, heads,
+                 width)
         if name == "int8":
             return Quantized(q=jnp.zeros(shape, jnp.int8),
                              scale=jnp.zeros(shape[:-1] + (1,), jnp.float32))
         return jnp.zeros(shape, _KV_FLOAT[name])
 
-    pages = {leaf: pool(*shape) for leaf, shape in pool_layout(config).items()}
+    pages = {leaf: pool(*shape, rows=parts if leaf == "k" else 1)
+             for leaf, shape in pool_layout(config).items()}
     state = state_layout(config)
     if state is not None:
         layers, rows, width = state
         pages["state"] = jnp.zeros((layers, n_pages, rows, width),
                                    _state_dtype(name))
+    second = window_layout(config)
+    if second is not None:
+        if name == "int8" or n_window_pages is None:
+            raise ValueError("a window page class is stored in float and "
+                             "needs its page count (n_window_pages)")
+        for leaf, key in zip(WINDOW_LEAVES, ("k", "v")):
+            layers = second["layers"] * (parts if key == "k" else 1)
+            pages[leaf] = jnp.zeros(
+                (layers, n_window_pages, page_size, *second[key]),
+                _KV_FLOAT[name])
     return pages
 
 
@@ -344,7 +434,12 @@ class PagePool:
     eviction at large pools.
     """
 
-    def __init__(self, n_pages: int, page_size: int):
+    def __init__(self, n_pages: int, page_size: int,
+                 n_window_pages: Optional[int] = None):
+        # the second page class's free list (kv_pages.window_layout): the
+        # same allocator over its own id space, or None
+        self.window = (None if n_window_pages is None
+                       else PagePool(n_window_pages, page_size))
         if n_pages < 2:
             raise ValueError(f"n_pages must be >= 2 (page {TRASH_PAGE} is "
                              f"the reserved trash page), got {n_pages}")
@@ -431,7 +526,8 @@ class PagePool:
                 self._free_set.add(p)
 
 
-def pool_audit(pool: "PagePool", holder_maps, *, tier=None) -> None:
+def pool_audit(pool: "PagePool", holder_maps, *, tier=None,
+               window_holder_maps=None) -> None:
     """The per-iteration capacity identity, extended for the host tier.
 
     ``holder_maps``: iterables of ``{page: n_refs}`` — one map per
@@ -445,7 +541,11 @@ def pool_audit(pool: "PagePool", holder_maps, *, tier=None) -> None:
     this identity unchanged; the tier's own ledger (``bytes_used ==
     sum(record bytes) <= budget``, ``spilled_pages == sum(record
     pages)``) audits separately via ``tier.audit()`` when one is
-    attached. Raises ``AssertionError`` naming the first imbalance."""
+    attached. ``window_holder_maps``: the same maps for the pool's second
+    page class (``pool.window``), which is audited alike. Raises
+    ``AssertionError`` naming the first imbalance."""
+    if window_holder_maps is not None:
+        pool_audit(pool.window, window_holder_maps)
     held: dict = {}
     for m in holder_maps:
         for p, n in m.items():
@@ -465,7 +565,8 @@ def pool_audit(pool: "PagePool", holder_maps, *, tier=None) -> None:
 
 def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, tables, lengths,
                  *, window=None, scale=None, softcap=None, impl: str = "auto",
-                 n_valid=None, latent_rope=None, expand=None):
+                 n_valid=None, latent_rope=None, expand=None, sink=None,
+                 subscope: Optional[str] = None):
     """Scatter each slot's new k/v into its pages of layer ``layer``, then
     attend q over the slot's block-table context there.
 
@@ -532,6 +633,14 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, tables, lengths,
     slot's rows whatever ``impl`` says, expands them per head and attends
     in blocks of query rows.
 
+    ``sink`` [Hq]: a learned logit a query head that joins each row's
+    softmax as one more column with no value (both impls). A key wider than
+    a pool row lies in several (:func:`key_parts`: q and k_new ``[.., parts *
+    D]``, ``k_pages [parts * L, ...]``); the result is a value row wide,
+    ``[S, T, Hq, D]``. ``subscope`` (``"full"`` / ``"window"``: a two-class
+    family's page class) puts the read half under ``attend_full`` /
+    ``attend_window`` inside ``attend``.
+
     Returns (attn [S, T, Hq, D], (k_pages, v_pages) updated, stacked).
     """
     k_pages, v_pages, t_idx = _scatter_new(k_new, v_new, k_pages, v_pages,
@@ -542,7 +651,7 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, tables, lengths,
                               expand=expand)
     return _attend_pages(q, k_pages, v_pages, layer, tables, lengths, t_idx,
                          window=window, scale=scale, softcap=softcap,
-                         impl=impl)
+                         impl=impl, sink=sink, subscope=subscope)
 
 
 @jax.named_scope("kv_write")
@@ -566,6 +675,14 @@ def _scatter_new(k_new, v_new, k_pages, v_pages, layer, tables, lengths,
                          TRASH_PAGE)
     off = t_idx % page
     at = (layer, phys, off)       # S x T rows of the stacked leaf
+    d = (k_pages.q if quantized else k_pages).shape[-1]   # a pool row
+    parts = k_new.shape[-1] // d
+    if parts > 1:   # a key in `parts` pool rows: part j at layer l*parts + j
+        for j in range(parts):
+            k_pages = k_pages.at[(layer * parts + j, phys, off)].set(
+                k_new[..., j * d:(j + 1) * d].astype(k_pages.dtype))
+        v_pages = v_pages.at[at].set(v_new.astype(v_pages.dtype))
+        return k_pages, v_pages, t_idx
     if quantized:
         # quantize-at-write: each new token's [Hkv, D] vector becomes int8
         # payload + one fp32 scale, scattered to the SAME (page, offset) —
@@ -583,14 +700,23 @@ def _scatter_new(k_new, v_new, k_pages, v_pages, layer, tables, lengths,
 
 @jax.named_scope("attend")
 def _attend_pages(q, k_pages, v_pages, layer, tables, lengths, t_idx, *,
-                  window, scale, softcap, impl):
+                  window, scale, softcap, impl, sink=None, subscope=None):
     """The read half of :func:`paged_attend`: q over each slot's block-table
     context in ``layer``, after the new tokens were scattered."""
+    if subscope is not None:    # a two-class family's layer, by its class
+        with (jax.named_scope("attend_window") if subscope == "window"
+              else jax.named_scope("attend_full")):
+            return _attend_pages(q, k_pages, v_pages, layer, tables, lengths,
+                                 t_idx, window=window, scale=scale,
+                                 softcap=softcap, impl=impl, sink=sink)
+    more = {} if sink is None else {"sink": sink}
     quantized = isinstance(k_pages, Quantized)
     s = q.shape[0]
     page = (k_pages.q if quantized else k_pages).shape[2]
+    # a key wider than a pool row lies in `parts` of them (key_parts)
+    parts = 1 if quantized else q.shape[-1] // k_pages.shape[-1]
     if impl == "auto":
-        impl, reason = resolve_attend_impl(impl, q.shape[-1], page)
+        impl, reason = resolve_attend_impl(impl, q.shape[-1] // parts, page)
         note_choice("paged_attend", impl, reason)
     if impl == "flash":
         # block_q = T: the same kernel serves the decode step (T == 1),
@@ -606,7 +732,7 @@ def _attend_pages(q, k_pages, v_pages, layer, tables, lengths, t_idx, *,
         else:
             attn = paged_flash_attend(q, k_pages, v_pages, layer, tables,
                                       lengths, window=window, scale=scale,
-                                      softcap=softcap)
+                                      softcap=softcap, **more)
         return attn, (k_pages, v_pages)
 
     # one gather from the stacked leaf: the layer's pages the tables name
@@ -620,6 +746,10 @@ def _attend_pages(q, k_pages, v_pages, layer, tables, lengths, t_idx, *,
         vg = dequantize_kv(Quantized(q=v_pages.q[layer, tables],
                                      scale=v_pages.scale[layer, tables]),
                            q.dtype)
+    elif parts > 1:
+        kg = jnp.concatenate([k_pages[layer * parts + j, tables]
+                              for j in range(parts)], axis=-1)
+        vg = v_pages[layer, tables]
     else:
         kg = k_pages[layer, tables]               # [S, M, page, Hkv, D]
         vg = v_pages[layer, tables]
@@ -631,7 +761,7 @@ def _attend_pages(q, k_pages, v_pages, layer, tables, lengths, t_idx, *,
                                positions=t_idx,
                                kv_positions=kv_pos, impl="xla",
                                standard_layout=False, window=window,
-                               scale=scale, logit_softcap=softcap)
+                               scale=scale, logit_softcap=softcap, **more)
     return attn, (k_pages, v_pages)
 
 
@@ -695,14 +825,23 @@ def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None):
     """Bind (tables, lengths, impl, n_valid) into the attend callback the
     family ``paged_decode_step`` hooks call in every layer with the stacked
     pools and the layer's index (:func:`paged_attend`'s contract). A latent
-    family adds ``latent_rope`` (and ``expand`` for a chunk) to its call."""
+    family adds ``latent_rope`` (and ``expand`` for a chunk) to its call. A
+    two-class family names the ``page_class`` of the pools it hands over
+    (``"full"`` / ``"window"``): ``tables`` is then ``[S, 2 M]`` and the call
+    takes its class's half (module docstring)."""
 
     def attend(q, k_new, v_new, k_pages, v_pages, layer, *, window=None,
-               scale=None, softcap=None, **latent):
-        return paged_attend(q, k_new, v_new, k_pages, v_pages, layer, tables,
+               scale=None, softcap=None, page_class=None, **more):
+        mine = tables
+        if page_class is not None:
+            half = tables.shape[1] // 2
+            mine = (tables[:, :half] if page_class == "full"
+                    else tables[:, half:])
+            more["subscope"] = page_class
+        return paged_attend(q, k_new, v_new, k_pages, v_pages, layer, mine,
                             lengths, window=window, scale=scale,
                             softcap=softcap, impl=impl, n_valid=n_valid,
-                            **latent)
+                            **more)
 
     # the same tables address a family's per-page recurrent state
     attend.read_state = partial(read_state, tables=tables, lengths=lengths)
@@ -751,16 +890,26 @@ def write_state(state, layer, page: int, history, *, tables, lengths,
 
 
 @jax.named_scope("kv_write")
-def copy_pages(pools, src, dst):
+def copy_pages(pools, src, dst, window=None):
     """Copy-on-write fork: duplicate physical page ``src`` into ``dst``
     across every layer of every pool leaf ([L, P, ...]: k, v, a family's
     per-page state; src/dst are traced scalars, so one compile serves every
     fork). The scheduler calls this before any write lands in a page whose
     refcount is > 1. Tree-generic over the pool leaves, so a quantized
     pool's scales fork WITH their payload — a dst page whose scales still
-    described the old content would dequantize garbage."""
+    described the old content would dequantize garbage. The second page
+    class's leaves (``WINDOW_LEAVES``) have ids of their own: ``window =
+    (src, dst)`` forks one of ITS pages in the same call, and without it
+    they pass through untouched."""
 
-    def fork(a):
+    def fork(a, src=src, dst=dst):
         return a.at[:, dst].set(a[:, src])
 
-    return jax.tree.map(fork, pools)
+    if not (isinstance(pools, dict) and WINDOW_LEAVES[0] in pools):
+        return jax.tree.map(fork, pools)
+    out = jax.tree.map(fork, {name: leaf for name, leaf in pools.items()
+                              if name not in WINDOW_LEAVES})
+    for name in WINDOW_LEAVES:
+        out[name] = (pools[name] if window is None
+                     else fork(pools[name], *window))
+    return out

@@ -69,7 +69,8 @@ from typing import Optional
 import numpy as np
 
 from ..utils.trace import span
-from .kv_pages import TRASH_PAGE, PagePool, pages_for_tokens
+from .kv_pages import (TRASH_PAGE, PagePool, pages_for_tokens,
+                       window_page_span)
 
 
 class RefusalError(ValueError):
@@ -176,6 +177,10 @@ class _Slot:
     # samples along the way are discarded (they equal the recording
     # bitwise: same program, same cache state)
     replay_pos: int = 0
+    # the second page class (kv_pages.window_layout): logical page ->
+    # physical page of the window layers' pool, only the pages the next query
+    # can still see (and, while a chunk runs, the chunk's)
+    window_pages: dict = dataclasses.field(default_factory=dict)
 
     @property
     def replaying(self) -> bool:
@@ -452,7 +457,8 @@ class Scheduler:
                  max_queue: Optional[int] = None,
                  admission_headroom=None, spec_lookahead: int = 0,
                  adapter_pool=None, decode_horizon: int = 1,
-                 partial_page_hits: bool = True):
+                 partial_page_hits: bool = True,
+                 window: Optional[int] = None):
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if max_queue is not None and max_queue < 1:
@@ -481,6 +487,18 @@ class Scheduler:
         # (kv_pages.state_layout): the row is the state at the page's LAST
         # token, so a hit may not end inside a page, and no fork follows
         self.partial_page_hits = partial_page_hits
+        # the reach, in positions, of the layers whose pages are the pool's
+        # second class (``pool.window``; kv_pages.window_layout): a slot
+        # holds a page of that class only while a query can still see it
+        if (window is None) != (pool.window is None):
+            raise ValueError("a window page class needs both the pool's "
+                             "second free list and the window's length")
+        if window is not None and (self.cache is not None
+                                   or decode_horizon > 1 or spec_lookahead):
+            raise ValueError("a window page class serves without the prefix "
+                             "cache, horizons and speculation: its pages are "
+                             "taken and returned between two single steps")
+        self.window = window
         # extra admission headroom beyond THIS scheduler's running decodes
         # — the disaggregated prefill scheduler has no decoding slots of
         # its own, so its engine threads the DECODE side's count through
@@ -534,6 +552,7 @@ class Scheduler:
                       "deadline_missed_queued": 0,
                       "deadline_missed_running": 0,
                       "spec_lookahead_clamped": 0, "refused": {},
+                      "window_pages_released": 0,
                       # requests submitted per adapter slot (keyed by
                       # adapter_id) — the per-tenant demand signal the
                       # router aggregates fleet-wide
@@ -678,6 +697,63 @@ class Scheduler:
         if not self._ensure_free(n + headroom):
             return None
         return self.pool.alloc(n)
+
+    # ---- the second page class --------------------------------------------
+    def reserve_window(self, slot_idx: int, start: int, n_tokens: int) -> int:
+        """Before a program writes the slot's tokens ``start .. start +
+        n_tokens - 1``: take the window-class pages it needs and does not
+        hold yet (``kv_pages.window_page_span``). Returns how many were
+        taken. The class is sized so that this cannot fail
+        (``kv_pages.window_pages_bound``, which the engine sizes it by): every slot
+        holds a bounded few between steps, one chunk runs at a time."""
+        slot = self.slots[slot_idx]
+        missing = [p for p in window_page_span(
+            start, n_tokens, self.window, self.pool.page_size)
+            if p not in slot.window_pages]
+        got = self.pool.window.alloc(len(missing))
+        if got is None:
+            raise RuntimeError(
+                f"window page class exhausted: slot {slot_idx} needs "
+                f"{len(missing)} pages, {self.pool.window.n_free} free of "
+                f"{self.pool.window.capacity}")
+        slot.window_pages.update(zip(missing, got))
+        return len(missing)
+
+    def _release_window(self, slot: _Slot, everything: bool = False) -> None:
+        """Return to the free list the window-class pages no later query of
+        the slot can see: those wholly before position ``cache_len - (window
+        - 1)`` (``everything``: the slot is leaving). Host bookkeeping alone:
+        no device copy, and the device's table may go on naming a returned
+        page, since no walk starts that early again."""
+        if self.window is None or not slot.window_pages:
+            return
+        first = max(slot.cache_len - (self.window - 1), 0) \
+            // self.pool.page_size
+        dead = [p for p in slot.window_pages if everything or p < first]
+        if not dead:
+            return
+        with span("serve.release", pages=len(dead)):
+            self.pool.window.free([slot.window_pages.pop(p) for p in dead])
+        if not everything:
+            self.stats["window_pages_released"] += len(dead)
+
+    def _free_slot(self, slot: _Slot) -> None:
+        """Drop every page reference a leaving slot holds, of both classes."""
+        self.pool.free(slot.pages)
+        self._release_window(slot, everything=True)
+
+    def live_pages_by_class(self) -> dict:
+        """Pages held by slots, a count a class."""
+        held = [s for s in self.slots if s is not None]
+        out = {"full": sum(len(s.pages) for s in held)}
+        if self.window is not None:
+            out["window"] = sum(len(s.window_pages) for s in held)
+        return out
+
+    def window_holders(self) -> dict:
+        """``{page: refs}`` of the window class, for ``pool_audit``."""
+        return {p: 1 for s in self.slots if s is not None
+                for p in s.window_pages.values()}     # never shared
 
     def cache_pages_held(self) -> int:
         """Pages whose only purpose right now may be prefix reuse — the
@@ -894,6 +970,7 @@ class Scheduler:
         slot.cache_len += n
         assert slot.cache_len <= slot.target_len, \
             f"prefill overran its target on slot {slot_idx}"
+        self._release_window(slot)
         if slot.cache_len == slot.target_len:
             slot.prefilling = False
             if self.cache is not None:
@@ -928,7 +1005,7 @@ class Scheduler:
                       "generated": list(slot.generated),
                       "replay_pos": slot.replay_pos,
                       "admitted_at": slot.admitted_at})
-        self.pool.free(slot.pages)
+        self._free_slot(slot)
         self.slots[slot_idx] = None
         self._queue_insert(_QueueEntry(slot.request, list(slot.generated),
                                        slot.first_token_at), front=True)
@@ -968,6 +1045,8 @@ class Scheduler:
                 preempted += 1
                 if victim == slot_idx:
                     break           # the grower itself was the victim
+            if self.window is not None and self.slots[slot_idx] is slot:
+                grown += self.reserve_window(slot_idx, slot.cache_len, 1)
         return grown, preempted
 
     def ensure_lookahead(self, slot_idx: int, extra: int) -> int:
@@ -1074,6 +1153,7 @@ class Scheduler:
         assert slot is not None, f"record_token on idle slot {slot_idx}"
         if from_decode:
             slot.cache_len += 1
+            self._release_window(slot)
         if slot.replaying:
             slot.replay_pos += 1
             return None
@@ -1089,7 +1169,7 @@ class Scheduler:
             finished = "length"
         if finished is None:
             return None
-        self.pool.free(slot.pages)
+        self._free_slot(slot)
         self.slots[slot_idx] = None
         self.stats["finished"] += 1
         self._adapter_release(req)
@@ -1144,7 +1224,7 @@ class Scheduler:
                 now, where="queued"))
         for i, slot in enumerate(self.slots):
             if slot is not None and expired(slot.request):
-                self.pool.free(slot.pages)
+                self._free_slot(slot)
                 self.slots[i] = None
                 self._adapter_release(slot.request)
                 results.append(self._deadline_result(
@@ -1251,12 +1331,21 @@ class Scheduler:
         """The slot's [max_pages] block table (0 = trash beyond the owned
         pages — the causal mask keeps those positions out of any attend,
         and ``TRASH_PAGE`` never appears among the owned pages)."""
-        row = np.zeros(self.max_pages, np.int32)
+        row = np.zeros(self.table_width, np.int32)
         slot = self.slots[slot_idx]
         if slot is not None:
             assert TRASH_PAGE not in slot.pages
             row[:len(slot.pages)] = slot.pages
+            # the second class's columns follow, by LOGICAL page; a page the
+            # window has passed (or has not reached) names the trash page
+            for logical, phys in slot.window_pages.items():
+                row[self.max_pages + logical] = phys
         return row
+
+    @property
+    def table_width(self) -> int:
+        """Columns of a slot's table row: one class's, or both classes'."""
+        return self.max_pages * (1 if self.window is None else 2)
 
     def decode_arrays(self) -> dict:
         """Flat numpy views of the decoding set, shaped for the ONE
@@ -1267,7 +1356,7 @@ class Scheduler:
         out = {
             "tokens": np.zeros(s, np.int32),
             "lengths": np.zeros(s, np.int32),
-            "tables": np.zeros((s, self.max_pages), np.int32),
+            "tables": np.zeros((s, self.table_width), np.int32),
             "seeds": np.zeros(s, np.int32),
             "temps": np.zeros(s, np.float32),
             "top_ks": np.zeros(s, np.int32),

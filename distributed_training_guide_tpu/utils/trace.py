@@ -31,7 +31,7 @@ SPANS = (
     "serve.step", "serve.expire", "serve.restore", "serve.admit",
     "serve.fork", "serve.prefill", "serve.sample", "serve.draft",
     "serve.reserve", "serve.build", "serve.dispatch", "serve.wait",
-    "serve.book",
+    "serve.book", "serve.release",
     "data.assemble", "data.put",
     "train.data", "train.step", "train.fence", "train.log", "train.ckpt",
 )
@@ -47,12 +47,15 @@ SCOPES = (
 
 # named_scope names INSIDE a scope above, which split it without leaving it:
 # `latent_proj` (in `attn`: MLA's low-rank projections and absorbed products),
-# `shared_expert` (in `experts`) and `conv` (in `attn`, which for a hybrid
+# `shared_expert` (in `experts`), `conv` (in `attn`, which for a hybrid
 # family is the operator sublayer whatever its kind: LFM2's gated short
-# convolution, its norm and projections included). A reader that knows only
-# SCOPES counts their time under the parent; `readers/path_component.py`
-# reads one alone
-SUBSCOPES = ("latent_proj", "shared_expert", "conv")
+# convolution, its norm and projections included) and `attend_full` /
+# `attend_window` (in `attend`: the read half of a two-class family's full
+# and window layers, `serve/kv_pages.py`). A reader that knows only SCOPES
+# counts their time under the parent; `readers/path_component.py` reads one
+# alone
+SUBSCOPES = ("latent_proj", "shared_expert", "conv", "attend_full",
+             "attend_window")
 
 # pallas_call names (ops/)
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",

@@ -58,6 +58,16 @@ chunk (T = 1,024, its query tokens in blocks of 128), each against the gather
 path over the same rows, with one sabotage the bound must refuse (the two
 heads of every pool row swapped).
 
+``--mimo`` runs the two-class cell's attend alone
+(``mimo-v2.5-ep16-l7.serve.mixed64-ctx32k``): keys of 192 in two 128-wide
+pool rows beside values of 128 through ``paged_attend``
+(``models/mimo_v2._paged_attend``: 64 query heads over 4 kv heads in a full
+layer, over 8 with a sink logit a head and a window of 128 in a window layer,
+page 128, 288 table columns a class, bf16), the decode step (contexts 5 to
+36,863 in one call) and a prefill chunk (T = 2,048 after 0 and after 4,096
+tokens, its query tokens in blocks), each against the gather path over the
+same rows, with one sabotage the bound must refuse (the sink left out).
+
 One process; fails (no last line, exit 1) off the chip. Prints the entry
 points' start-up device line, one JSON line per case, and last
 ``{"kernel_parity_ok": true, "cases": N, "controls_refused": 5}``.
@@ -485,6 +495,91 @@ def lfm2_cases(chunks: bool = True) -> int:
     return int(caught)
 
 
+# the two-class cell (mimo-v2.5-ep16-l7.serve.mixed64-ctx32k): keys of 192 in
+# two 128-wide pool rows, values of 128, 64 query heads over 4 kv heads (full
+# layers) or 8 with a sink logit a head and a window of 128 (window layers)
+MIMO = dict(hq=64, dk=192, dv=128, page=128, layers=2, layer=1,
+            full=dict(hkv=4, columns=288, pages=2000),
+            window=dict(hkv=8, columns=288, pages=147))
+
+
+def mimo_attend(impl: str, kind: str, t: int, lengths, sink: bool = True,
+                columns=None):
+    """One attention layer's paged call as ``models/mimo_v2.py`` makes it,
+    over the pools of ONE page class: a full layer walks every live page, a
+    window layer the pages its window reaches (its table names only those,
+    zeros elsewhere, as the scheduler builds it). ``sink`` False leaves the
+    window layers' sink logits out (the control)."""
+    import dataclasses
+
+    from distributed_training_guide_tpu.models import mimo_v2
+
+    m, c = MIMO, dict(MIMO[kind], **({"columns": columns} if columns else {}))
+    cfg = dataclasses.replace(mimo_v2.PRESETS["mimo-v2.5"], dtype=jnp.bfloat16)
+    n = len(lengths)
+    rng = np.random.default_rng(11)
+    draw = lambda shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    q = draw((n, t, m["hq"], m["dk"]))
+    k_new = draw((n, t, c["hkv"], m["dk"]))
+    v_new = draw((n, t, c["hkv"], m["dv"]))
+    k_pool = draw((m["layers"] * cfg.key_parts, c["pages"], m["page"],
+                   c["hkv"], cfg.row_width))
+    v_pool = draw((m["layers"], c["pages"], m["page"], c["hkv"],
+                   cfg.row_width))
+    sinks = jnp.asarray(rng.standard_normal(m["hq"]), jnp.float32)
+    window = cfg.sliding_window if kind == "window" else None
+    tables = np.zeros((n, 2 * c["columns"]), np.int32)
+    first = c["columns"] if kind == "window" else 0     # the class's half
+    free = iter(rng.permutation(np.arange(1, c["pages"])))
+    for i, length in enumerate(lengths):
+        lo = 0 if window is None else max(length - (window - 1), 0) // m["page"]
+        for col in range(lo, -(-(length + t) // m["page"])):
+            tables[i, first + col] = next(free)
+    lens = jnp.asarray(lengths, jnp.int32)
+
+    def call(q, k_new, v_new, k_pool, v_pool, tables, sinks):
+        hook = mimo_v2._paged_attend(
+            cfg, kv_pages.make_attend(tables, lens, impl=impl),
+            (k_pool, v_pool), m["layer"], kind)
+        return hook(q, k_new, v_new, window=window, scale=m["dk"] ** -0.5,
+                    sink=sinks if kind == "window" and sink else None)[0]
+
+    return jax.jit(call)(q, k_new, v_new, k_pool, v_pool,
+                         jnp.asarray(tables), sinks)
+
+
+def mimo_cases() -> int:
+    """Both kinds at the decode step (64 slots, contexts from a few tokens to
+    the 36,864 cap, either side of page and block edges) and at a prefill
+    chunk's query tile (2,048 tokens after 0 and after 4,096), against the
+    gather path over the same pools; then the control: the sink left out of
+    the kernel's call, which the bound must refuse."""
+    # 24 slots: the gather path's copy of a slot's 288 table columns is 75 MB
+    # a pool row in the window class (8 heads), three rows a token
+    spread = [5, 127, 128, 129, 255, 256, 257, 511, 512, 513, 1023, 2047,
+              4095, 4096, 8191, 12000, 16383, 20000, 32767, 36863, 640, 3000,
+              9000, 30000]
+    for kind, lengths in (("full", spread), ("window", spread)):
+        # a chunk against 48 table columns: the gather path's scores of 2,048
+        # query tokens over all 288 would be 19 GB
+        shapes = ((1, lengths, None), (2048, [0], 48), (2048, [4096], 48))
+        for t, lens, columns in shapes:
+            case(f"paged_attend {kind} layer, keys 192 in two rows",
+                 {"out": (mimo_attend("flash", kind, t, lens, columns=columns),
+                          mimo_attend("xla", kind, t, lens, columns=columns))},
+                 pool="bf16", page=128, T=t, kind=kind,
+                 heads=f"64/{MIMO[kind]['hkv']} of 192/128",
+                 slots=len(lens), lengths=f"{min(lens)}..{max(lens)}")
+    want = mimo_attend("xla", "window", 1, spread)
+    err, ref = worst(mimo_attend("flash", "window", 1, spread, sink=False),
+                     want)
+    caught = err > RTOL * max(1.0, ref)
+    print(json.dumps({"control": "sink_left_out", "max_abs_err": err,
+                      "ref_max": ref, "rtol": RTOL, "refused": caught}),
+          flush=True)
+    return int(caught)
+
+
 def int8_matmul_case() -> None:
     qm = importlib.import_module(
         "distributed_training_guide_tpu.ops.quantized_matmul")
@@ -503,15 +598,25 @@ def int8_matmul_case() -> None:
 
 def main(argv) -> int:
     everything, mla_only = argv == ["--all"], argv == ["--mla"]
-    lfm2_only = argv == ["--lfm2"]
-    if argv and not (everything or mla_only or lfm2_only):
-        raise SystemExit("usage: kernel_parity.py [--all|--mla|--lfm2]")
+    lfm2_only, mimo_only = argv == ["--lfm2"], argv == ["--mimo"]
+    if argv and not (everything or mla_only or lfm2_only or mimo_only):
+        raise SystemExit("usage: kernel_parity.py [--all|--mla|--lfm2|--mimo]")
     print_device_line("attend", ("flash", "forced"), CACHE.directory)
     if jax.devices()[0].platform != EXPECT_PLATFORM:
         print(f"kernel_parity FAILED: runs on "
               f"{jax.devices()[0].platform!r}, not {EXPECT_PLATFORM!r}",
               file=sys.stderr)
         return 1
+    if mimo_only:
+        refused = mimo_cases()
+        CACHE.print_line()
+        if FAILED or refused != 1:
+            print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
+                  f"sink left out refused: {refused} of 1", file=sys.stderr)
+            return 1
+        print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
+                          "controls_refused": refused}), flush=True)
+        return 0
     if lfm2_only:
         refused = lfm2_cases()
         CACHE.print_line()

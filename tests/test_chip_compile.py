@@ -615,6 +615,87 @@ def test_hybrid_familys_serve_programs_compile_at_the_cells_size(
                 for leaf in ("gate", "up", "down")))
 
 
+MIMO_CELL = dict(slots=64, columns=288, pages=7170, window_pages=147,
+                 chunk=2048)
+
+
+def _mimo_cell_config():
+    import dataclasses
+    import json
+    from pathlib import Path
+
+    from distributed_training_guide_tpu.models import mimo_v2
+
+    real = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                       / "configs" / "mimo-v2.5-ep16-l7.json").read_text())
+    return dataclasses.replace(
+        mimo_v2.PRESETS["mimo-v2.5"],
+        hybrid_layer_pattern=tuple(real["hybrid_layer_pattern"]),
+        moe_layer_freq=tuple(real["moe_layer_freq"]),
+        vocab_size=real["vocab_size"],
+        experts_held=(real["experts_held_first"], real["n_routed_experts"]),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk2048"])
+def test_two_class_familys_serve_programs_compile_at_the_cells_size(
+        chip_compile, compiled_kernels, program):
+    """``mimo-v2.5-ep16-l7.serve.mixed64-ctx32k``'s decode step (64 slots)
+    and prefill chunk (2,048 tokens), whole, at the cell's size (6.4 GiB of
+    weights, the full class's pools of 2 layers over 7,170 pages and the
+    window class's of 5 layers over 147): every attention layer goes through
+    the compiled ``paged_attend`` at key rows of 256 (192 live) and value
+    rows of 128, 16 query heads a kv head in the full layers and 8 with the
+    sink column in the window layers (the chunk's query tokens in blocks,
+    each inside one loop), ``gmm`` three times an expert layer, and nothing
+    expert-sized or pool-sized is copied: every pool-sized result is a
+    parameter, a rename, an in-place ``scatter`` (two a layer) or the fusion
+    that holds one."""
+    from distributed_training_guide_tpu.models import mimo_v2
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    c = MIMO_CELL
+    cfg = _mimo_cell_config()
+    params = jax.eval_shape(lambda: mimo_v2.init(cfg, jax.random.key(0)))
+    leaves, treedef = jax.tree.flatten(params)
+    weights = [(x.shape, x.dtype) for x in leaves]
+    pools = jax.eval_shape(lambda: kv_pages.init_pages(
+        cfg, c["pages"], 128, n_window_pages=c["window_pages"]))
+    assert pools["k"].shape == (2 * 2, c["pages"], 128, 4, 128)
+    assert pools["v_win"].shape == (5, c["window_pages"], 128, 8, 128)
+    names = ("k", "v", "k_win", "v_win")
+    slots, t = (c["slots"], 1) if program == "decode" else (1, c["chunk"])
+
+    def step(kp, vp, kw, vw, ids, lengths, tables, *flat):
+        logits, cache = mimo_v2.paged_decode_step(
+            cfg, jax.tree.unflatten(treedef, flat), ids, lengths,
+            dict(zip(names, (kp, vp, kw, vw))),
+            kv_pages.make_attend(tables, lengths, impl="flash",
+                                 n_valid=jnp.full((slots,), t)),
+            last_index=jnp.asarray(t - 1))
+        return (jnp.argmax(logits, -1), *(cache[n] for n in names),
+                cache["routing"])
+
+    text = chip_compile(
+        step, *((pools[n].shape, pools[n].dtype) for n in names),
+        ((slots, t), jnp.int32), ((slots,), jnp.int32),
+        ((slots, 2 * c["columns"]), jnp.int32), *weights,
+        donate=(0, 1, 2, 3))
+    calls = kernel_calls(text)
+    assert sum(named(x, "gmm") for x in calls) == 3 * 6, calls
+    assert sum(named(x, "paged_attend") for x in calls) == 7, calls
+    sized = pool_sized_ops(text, *(pools[n].shape for n in names), names=True)
+    assert sum(x.startswith("scatter ") for x in sized) >= 3 * 7, sized
+    moved = [x for x in sized
+             if x.split()[0] not in ("parameter", "bitcast", "scatter",
+                                     "get-tuple-element", "fusion")
+             or "slice" in x or "copy" in x]
+    assert not moved, moved
+    assert_experts_read_in_place(
+        text, *(params["layers"]["moe"][leaf].shape
+                for leaf in ("gate", "up", "down")))
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk512"])
 def test_moe_familys_serve_programs_read_the_experts_in_place(
         chip_compile, compiled_kernels, program):

@@ -308,7 +308,8 @@ def test_latent_family_decode_carries_its_subscopes_and_kernel():
     assert any(f.startswith("experts/shared_expert/") for f in fragments)
     assert any(f.startswith("attn/latent_proj/") for f in fragments)
     assert "paged_latent_attend" in KERNELS and set(SUBSCOPES) == {
-        "latent_proj", "shared_expert", "conv"}
+        "latent_proj", "shared_expert", "conv", "attend_full",
+        "attend_window"}
 
 
 def test_named_gives_jit_the_name():
