@@ -58,3 +58,30 @@ def ppermute(x: jnp.ndarray, axis, *, perm) -> jnp.ndarray:
     """``jax.lax.ppermute`` under the shared guard (the double-buffered EP
     ring's hop primitive, models/moe.py)."""
     return jax.lax.ppermute(x, axis, perm=perm)
+
+
+def gather_with_reduce_scatter_vjp(axis, dim: int, *, wide: bool = False):
+    """All-gather along ``dim`` over ``axis`` whose backward is an explicit
+    reduce-scatter. The cotangent is widened to fp32 for the reduction and
+    narrowed back to the parameter dtype — the same accumulate-wide /
+    store-narrow contract GSPMD applies to its grad reductions, so a
+    scheduled path stays bit-comparable to the unscheduled one. Used by the
+    per-layer schedule (ops/overlap.py) and by the loss head's one gather
+    (ops/cross_entropy.py), which asks for it ``wide``: the gathered array
+    comes out in fp32 (an exact widening), so what the caller's backward
+    sums against it stays fp32 until the reduction has taken it."""
+
+    @jax.custom_vjp
+    def gather(p):
+        out = all_gather(p, axis, dim=dim)
+        return out.astype(jnp.float32) if wide else out
+
+    def fwd(p):
+        return gather(p), jnp.zeros((0,), p.dtype)  # the parameter's dtype
+
+    def bwd(like, ct):
+        return (psum_scatter(ct.astype(jnp.float32), axis,
+                             scatter_dimension=dim).astype(like.dtype),)
+
+    gather.defvjp(fwd, bwd)
+    return gather

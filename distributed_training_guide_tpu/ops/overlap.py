@@ -47,8 +47,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from .collectives import all_gather as _all_gather
+from .collectives import gather_with_reduce_scatter_vjp as _gather_with_rs_vjp
 from .collectives import psum as _psum
-from .collectives import psum_scatter as _psum_scatter
 
 # XLA flags the schedule relies on to turn the flat program's collectives
 # into async start/done pairs hoisted across layer compute (TPU; harmless
@@ -66,29 +66,6 @@ _GATHER_NAME = "fsdp_gather"
 def _is_axes_leaf(x) -> bool:
     return isinstance(x, tuple) and all(isinstance(e, (str, type(None)))
                                         for e in x)
-
-
-def _gather_with_rs_vjp(axis: str, dim: int):
-    """All-gather along ``dim`` over ``axis`` whose backward is an explicit
-    reduce-scatter. The cotangent is widened to fp32 for the reduction and
-    narrowed back to the parameter dtype — the same accumulate-wide /
-    store-narrow contract GSPMD applies to its grad reductions, so the
-    scheduled path stays bit-comparable to the unscheduled one."""
-
-    @jax.custom_vjp
-    def gather(p):
-        return _all_gather(p, axis, dim=dim)
-
-    def fwd(p):
-        return gather(p), None
-
-    def bwd(_, ct):
-        # the gather is cast-free, so ct.dtype == the parameter dtype
-        return (_psum_scatter(ct.astype(jnp.float32), axis,
-                              scatter_dimension=dim).astype(ct.dtype),)
-
-    gather.defvjp(fwd, bwd)
-    return gather
 
 
 class LayerSchedule:

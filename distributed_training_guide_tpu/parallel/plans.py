@@ -46,8 +46,11 @@ STRATEGIES: dict[str, dict[str, Any]] = {
     # chapter 04: FULL_SHARD — every weight matrix sharded on its embed dim
     "fsdp": {
         "embed": "fsdp",
-        "vocab": "fsdp",  # embedding + lm_head shard vocab (big dim, avoids
-                          # resharding the embed dim used in every matmul)
+        "vocab": "fsdp",  # the embedding table ("vocab", "embed") shards its
+                          # vocab dim (embed comes second: fsdp is taken);
+                          # an untied lm_head ("embed", "vocab") shards embed
+                          # like every other matrix (spec_for_leaf gives an
+                          # axis to the FIRST dim that asks for it)
     },
     # chapter 06: megatron TP + sequence parallelism for activations.
     # *_vector axes are the gpt2 biases — a column-parallel projection's

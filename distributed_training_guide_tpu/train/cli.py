@@ -243,11 +243,13 @@ def get_parser() -> argparse.ArgumentParser:
     return parser
 
 
-def _compile_step(lowered, n_chips: int):
+def _compile_step(lowered, n_chips: int, trainer):
     """Compile the lowered step ONCE; the executable that comes back takes
     every step of the run, and the JSON line printed here describes it: the
     compile seconds and, on a multi-device mesh, the collectives it holds
-    (``utils/hlo.collective_summary``)."""
+    (``utils/hlo.collective_summary``) and, under a chunked loss, whether
+    the output matrix is gathered once a step or once a chunk
+    (``Trainer.head_gather``)."""
     t0 = time.perf_counter()
     compiled = lowered.compile()
     program = {"compile_s": round(time.perf_counter() - t0, 3)}
@@ -255,6 +257,8 @@ def _compile_step(lowered, n_chips: int):
         from ..utils.hlo import collective_summary
 
         program["collectives"] = collective_summary(compiled.as_text())
+        if trainer.loss_chunks and trainer.plan.mesh.shape["pp"] == 1:
+            program["loss_head"] = trainer.head_gather["why"]
     if jax.process_index() == 0:
         print(json.dumps({"step_program": program}), flush=True)
     return compiled
@@ -494,7 +498,7 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
     first_step = host_state["global_step"]
     step_fn = trainer.step_fn
     if not hasattr(step_fn, "jitted"):
-        step_fn = _compile_step(lowered, n_chips)
+        step_fn = _compile_step(lowered, n_chips, trainer)
     del lowered
     done = False
     pending_losses = []  # (step, loss, notfinite) banked between fences

@@ -39,6 +39,33 @@ def _per_device_bytes(shapes_tree, shardings_tree) -> int:
     return total
 
 
+def _abstract_state_bytes(trainer) -> tuple:
+    """``(params, optimizer state)`` bytes one device holds of the trainer's
+    state. Abstract shapes only."""
+    params_b = _per_device_bytes(trainer.param_shapes, trainer.param_shardings)
+    opt_shapes = jax.eval_shape(trainer.optimizer.init, trainer.param_shapes)
+    return params_b, _per_device_bytes(opt_shapes,
+                                       trainer.opt_shardings_device)
+
+
+def priced_state_bytes(trainer) -> int:
+    """What one device holds of the trainer's state at the update boundary:
+    its shard of the params, of the optimizer state and of the transient
+    gradients (sharded like the params)."""
+    params_b, opt_b = _abstract_state_bytes(trainer)
+    return 2 * params_b + opt_b
+
+
+def device_bytes_limit(device):
+    """The device's memory in bytes, or None where it reports none (the CPU;
+    a described device, which answers no statistics)."""
+    try:
+        stats = device.memory_stats() or {}
+    except Exception:
+        return None
+    return int(stats["bytes_limit"]) if stats.get("bytes_limit") else None
+
+
 def comm_roofline(trainer, *, global_batch: int, seq_length: int,
                   device_kind: str | None = None,
                   assume_overlap: bool = True) -> dict:
@@ -207,10 +234,7 @@ def price_post_colocation(trainer, *, n_slots: int, page_size: int = 16,
         pages_for_tokens
 
     cfg = trainer.bundle.config
-    params_b = _per_device_bytes(trainer.param_shapes,
-                                 trainer.param_shardings)
-    opt_shapes = jax.eval_shape(trainer.optimizer.init, trainer.param_shapes)
-    opt_b = _per_device_bytes(opt_shapes, trainer.opt_shardings_device)
+    params_b, opt_b = _abstract_state_bytes(trainer)
     grad_b = params_b          # transient, resident at the update boundary
     # the engine serves the MERGED policy (base layout for LoRA bundles),
     # priced at the engine's weight_dtype: the QLoRA colocation is a
@@ -330,12 +354,9 @@ def run_preflight(trainer, *, global_batch: int, seq_length: int,
         "total_state_reduction": (round(total32_b / total_b, 2)
                                   if total_b else 1.0),
     }
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        if stats.get("bytes_limit"):
-            report["device_bytes_limit"] = int(stats["bytes_limit"])
-    except Exception:
-        pass
+    limit = device_bytes_limit(jax.devices()[0])
+    if limit:
+        report["device_bytes_limit"] = limit
     gib = 1 / 2**30
     LOGGER.info(
         f"preflight OK: step lowers on mesh {report['mesh']}; per device "

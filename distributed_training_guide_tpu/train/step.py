@@ -356,6 +356,75 @@ class Trainer:
         jitted = jax.jit(encode, out_shardings=self._device_state_shardings)
         return self._place(jitted(state))
 
+    def _output_matrix_spec(self):
+        """PartitionSpec of the family's [E, V] ``output_weights`` under the
+        plan, or None where it cannot be read off ONE leaf: the leaf the
+        function reads (found in its jaxpr; tied heads read the embedding
+        table transposed) gives its dims by logical name."""
+        from ..models.registry import family_module
+
+        mod, cfg = family_module(self.bundle.family), self.bundle.config
+        jaxpr = jax.make_jaxpr(lambda p: mod.output_weights(cfg, p))(
+            self.param_shapes).jaxpr
+        used = {id(v) for e in jaxpr.eqns for v in e.invars}
+        used.update(id(v) for v in jaxpr.outvars)
+        read = [i for i, v in enumerate(jaxpr.invars) if id(v) in used]
+        axes = jax.tree.leaves(self.logical_axes, is_leaf=_is_axes_leaf)
+        shapes = jax.tree.leaves(self.param_shapes)
+        if len(read) != 1 or sorted(axes[read[0]]) != ["embed", "vocab"]:
+            return None
+        leaf_axes, shape = axes[read[0]], shapes[read[0]].shape
+        spec = tuple(spec_for_leaf(self.plan.mesh, leaf_axes, shape,
+                                   self.plan.rules)) + (None, None)
+        return P(spec[leaf_axes.index("embed")],
+                 spec[leaf_axes.index("vocab")])
+
+    @cached_property
+    def head_gather(self) -> dict:
+        """How the chunked loss meets an output matrix that the plan shards
+        over data axes (FSDP's head): ``{"once": bool, "why": str, "spec"}``.
+
+        ``once``: the matrix is gathered ONCE a step around the chunk loop
+        and its gradient reduce-scattered once after it
+        (``ops/cross_entropy.make_gathered_chunked_loss``). Otherwise the
+        loss is left to GSPMD, which gathers the matrix and reduce-scatters
+        its gradient once a CHUNK. The choice reads the plan, the shapes and
+        the device's memory, nothing else:
+
+        - every mesh axis in use is a data axis (no tp / cp / pp: a vocab- or
+          sequence-parallel chunk body is not built inside the region), and
+          the matrix is sharded on exactly one dim, over data axes;
+        - the whole matrix in the compute dtype and this chip's fp32 partial
+          gradient of it fit the device beside its shard of params,
+          optimizer state and gradients (``preflight.priced_state_bytes``).
+          A device that reports no memory (the CPU) is taken to fit.
+        """
+        from .preflight import device_bytes_limit, priced_state_bytes
+
+        mesh, data = self.plan.mesh, set(self.plan.data_axes)
+        if not set(self.plan.active_axes()) <= data:
+            return {"once": False, "why": "a mesh axis in use is no data axis"}
+        spec = self._output_matrix_spec()
+        named = [e for e in (spec or ()) if e is not None]
+        axes = {a for e in named for a in ((e,) if isinstance(e, str) else e)}
+        if (len(named) != 1 or not axes <= data
+                or all(mesh.shape[a] == 1 for a in axes)):
+            return {"once": False,
+                    "why": "the output matrix is not sharded over data axes"}
+        cfg = self.bundle.config
+        e, v = cfg.hidden_size, cfg.vocab_size
+        need = e * v * (jnp.dtype(cfg.dtype).itemsize + 4)
+        limit = device_bytes_limit(mesh.devices.flat[0])
+        if limit is not None and priced_state_bytes(self) + need > limit:
+            return {"once": False,
+                    "why": f"the gathered matrix and its gradient "
+                           f"({need / 2**30:.2f} GiB) do not fit the "
+                           f"device's {limit / 2**30:.2f} GiB beside its "
+                           f"state"}
+        return {"once": True, "spec": spec,
+                "why": f"output matrix sharded {spec}: one gather, one "
+                       f"reduce-scatter a step"}
+
     def batch_shardings(self, batch_ndim: int = 2):
         ndim = batch_ndim + (1 if self.grad_accum > 1 else 0)
         if self.grad_accum > 1:
@@ -566,6 +635,18 @@ class Trainer:
                 def chunked_ce(params, hidden, labels):
                     w_out = chunk_mod.output_weights(cfg, params)
                     return fused(hidden, w_out, labels)
+            elif self.head_gather["once"]:
+                from ..ops.cross_entropy import make_gathered_chunked_loss
+
+                gathered = make_gathered_chunked_loss(
+                    self.plan.mesh, self.head_gather["spec"],
+                    self.plan.data_axes, num_chunks=n_chunks)
+
+                @jax.named_scope("loss_head")
+                def chunked_ce(params, hidden, labels):
+                    # cast to the compute dtype on the shard, before the gather
+                    w_out = chunk_mod.output_weights(cfg, params)
+                    return gathered(hidden, w_out, labels)
             else:
                 @jax.named_scope("loss_head")
                 def chunked_ce(params, hidden, labels):

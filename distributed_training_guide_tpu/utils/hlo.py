@@ -94,16 +94,41 @@ _SHAPE_RE = re.compile(r"\b([a-z]+\d+|pred)\[([\d,]*)\]")
 _RS_FUSION = "%all-reduce-scatter"
 
 
-def _result_bytes(line: str) -> int:
-    """Bytes of an op's result (summed over a tuple), from its text."""
+def _result_arrays(line: str) -> list:
+    """``(dtype, elements)`` of each array in an op's result (a tuple has
+    several), from its text."""
     m = _OP_RE.match(line)
-    total = 0
+    out = []
     for dtype, dims in _SHAPE_RE.findall(line[m.end(1):m.start(2)]):
         n = 1
         for d in filter(None, dims.split(",")):
             n *= int(d)
-        total += n * _DTYPE_BYTES.get(dtype, 4)
-    return total
+        out.append((dtype, n))
+    return out
+
+
+def _result_bytes(line: str) -> int:
+    """Bytes of an op's result (summed over a tuple), from its text."""
+    return sum(n * _DTYPE_BYTES.get(dtype, 4)
+               for dtype, n in _result_arrays(line))
+
+
+def collectives_moving(text: str, elements: int, *, shards: int = 1) -> list:
+    """``(op, in_a_loop)`` for every all-gather or all-reduce whose result
+    holds an array of ``elements`` elements and every reduce-scatter whose
+    result holds one of ``elements // shards`` (one shard of it); ``-done``
+    halves are left out. What a test of "this matrix crosses the mesh N
+    times a step, never inside a loop" asks of a compiled step."""
+    lines = text.splitlines()
+    loops = while_body_computations(text)
+    out = []
+    for c in find_collectives(text,
+                              ("all-gather", "reduce-scatter", "all-reduce")):
+        want = elements // shards if c.kind == "reduce-scatter" else elements
+        if not c.is_done and want in [n for _, n in
+                                      _result_arrays(lines[c.line])]:
+            out.append((c, c.computation in loops))
+    return out
 
 
 def collective_summary(text: str) -> dict:

@@ -777,3 +777,100 @@ def test_llama_familys_serve_programs_carry_the_pools_in_place(
     calls = kernel_calls(text)
     assert len(calls) == 1 and named(calls[0], "paged_attend"), calls
     assert_pools_carried_in_place(text, pool[0])
+
+
+def test_fsdp_loss_head_gathers_once_at_the_four_chip_cells_shapes(
+        topo, chip_compile):
+    """``olmo2-7b-l8.train.fsdp4.seq4096``'s loss head (hidden 4096, vocab
+    100,352, 16 chunks, 8 x 4096 tokens) under the ``fsdp`` plan on the four
+    described chips, forward and backward: the output matrix is gathered
+    once and its gradient reduce-scattered once, both OUTSIDE the two chunk
+    loops, no collective moves the whole matrix inside a loop, nothing
+    all-reduces it, the backward loop carries the partial gradient in fp32
+    while both loops read the matrix in bf16 (the widened copy is never
+    made), and the temporaries fit beside the cell's state."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_training_guide_tpu.ops.cross_entropy import (
+        make_gathered_chunked_loss)
+    from distributed_training_guide_tpu.parallel import make_mesh, make_plan
+    from distributed_training_guide_tpu.utils import hlo
+
+    e, v, chunks, batch, seq = 4096, 100352, 16, 8, 4096
+    plan = make_plan("fsdp", make_mesh(fsdp=4, devices=topo.devices))
+    mesh = plan.mesh
+    loss = make_gathered_chunked_loss(mesh, P("fsdp", None), plan.data_axes,
+                                      num_chunks=chunks)
+
+    @jax.named_scope("loss_head")
+    def head(hidden, w_master, labels):
+        return loss(hidden, w_master.astype(jnp.bfloat16), labels)
+
+    rows = NamedSharding(mesh, P(plan.data_axes))
+    shard = NamedSharding(mesh, P("fsdp"))
+    compiled = jax.jit(
+        jax.value_and_grad(head, argnums=(0, 1)),
+        out_shardings=(plan.replicated(), (rows, shard))).lower(
+        jax.ShapeDtypeStruct((batch, seq, e), jnp.bfloat16, sharding=rows),
+        jax.ShapeDtypeStruct((e, v), jnp.float32, sharding=shard),
+        jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=rows),
+    ).compile()
+    text = compiled.as_text()
+    lines = text.splitlines()
+    assert len(re.findall(r"\swhile\(", text)) == 2     # the two chunk loops
+    moved = {"all-gather": [], "reduce-scatter": [], "all-reduce": []}
+    for c, in_loop in hlo.collectives_moving(text, e * v, shards=4):
+        assert not in_loop, lines[c.line][:200]
+        moved[c.kind].append(lines[c.line][:400])
+    assert len(moved["all-gather"]) == 1, moved
+    assert len(moved["reduce-scatter"]) == 1, moved
+    assert not moved["all-reduce"], moved
+    for line in moved["all-gather"] + moved["reduce-scatter"]:
+        assert "loss_head" in line and "/head_gather/" in line, line
+    carried = sorted(
+        sorted(set(re.findall(rf"(\w+)\[{e},{v}\]", l.split(" while(")[0])))
+        for l in lines if " while(" in l)
+    assert carried == [["bf16"], ["bf16", "f32"]], carried
+    assert not [l for l in lines if f" = f32[{e},{v}]" in l
+                and "/head_gather/" in l and "transpose(" not in l], "widened"
+    # 2.44 B parameters x 16 B (fp32 weights, two moments, gradients) over
+    # four chips, and what this program plans beside them
+    params = 2 * e * v + 8 * (4 * e * e + 3 * e * 11008)
+    state_gib = params * 16 / 4 / 2**30
+    temp_gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
+    assert temp_gib < 3.5, temp_gib
+    assert state_gib + temp_gib < 15.75, (state_gib, temp_gib)
+
+
+def test_fsdp_heads_reduce_scatter_runs_before_the_layers_backward(
+        topo, chip_compile):
+    """A whole FSDP step on the four described chips (a head of 512 x
+    32,768, four layers): the chip's scheduler, left alone, puts the head's
+    one reduce-scatter AFTER the layers' backward loop and so holds the
+    whole-matrix fp32 partial gradient through it (at the four-chip cell's
+    size 15.50 GiB planned where 12.91 are needed);
+    ``_cotangents_together`` makes that loop wait for it."""
+    import optax
+
+    from distributed_training_guide_tpu.models import get_model
+    from distributed_training_guide_tpu.parallel import make_mesh, make_plan
+    from distributed_training_guide_tpu.train import Trainer
+    from distributed_training_guide_tpu.train.step import lower_step
+
+    trainer = Trainer(
+        bundle=get_model("llama-debug", tie_word_embeddings=False,
+                         hidden_size=512, vocab_size=32768, num_layers=4),
+        optimizer=optax.adamw(1e-3), loss_chunks=4, remat=True,
+        attn_impl="xla",
+        plan=make_plan("fsdp", make_mesh(fsdp=4, devices=topo.devices)))
+    assert trainer.head_gather["once"]
+    lowered, _ = lower_step(trainer, global_batch=8, seq_length=128)
+    text = lowered.compile().as_text()
+    entry = text[text.index("\nENTRY"):].splitlines()
+    at = {what: next(i for i, l in enumerate(entry)
+                     if f" {op}(" in l and f'{what}"' in l)
+          for op, what in (
+              ("while", "transpose(jvp(loss_head))/shard_map/while"),
+              ("reduce-scatter", "head_gather/reduce_scatter"),
+              ("while", "transpose(jvp(layers))/while"))}
+    assert list(at.values()) == sorted(at.values()), at

@@ -68,6 +68,21 @@ def test_start_up_lines_describe_the_step_that_runs(tmp_path, eight_devices,
     assert sum("step_program" in l for l in lines) == 1
 
 
+def test_step_program_line_says_how_the_loss_head_is_gathered(
+        tmp_path, eight_devices, capsys):
+    """Under ``--loss-chunks`` on a mesh the line that describes the compiled
+    step also says what the trainer chose for a sharded output matrix."""
+    import json
+
+    args = make_args(tmp_path, loss_chunks=4)
+    run_training(args, lambda: make_plan(
+        "fsdp", make_mesh(fsdp=4, devices=eight_devices[:4])))
+    program = next(json.loads(l)["step_program"]
+                   for l in capsys.readouterr().out.splitlines()
+                   if l.startswith('{"step_program"'))
+    assert "one gather, one reduce-scatter a step" in program["loss_head"]
+
+
 def test_run_training_sliding_window_flag(tmp_path, eight_devices):
     """--sliding-window W overrides the model config and trains through the
     banded attention; loss differs from the full-causal run (the band binds)."""

@@ -109,8 +109,9 @@ def test_fsdp_overlap_parity_and_hlo_pin(eight_devices):
     assert emitted >= 2 * L * n_gathered, \
         f"schedule emitted {emitted} all-gathers, expected >= " \
         f"{2 * L * n_gathered}"
-    assert "stablehlo.all_gather" not in _lowered_step_text(t_uns), \
-        "the GSPMD program has no explicit gathers to schedule"
+    assert _lowered_step_text(t_uns).count("stablehlo.all_gather") == 1, \
+        "the GSPMD program's one explicit gather is the loss head's " \
+        "(ops/cross_entropy.py): its layers have none to schedule"
     free = hlo_util.collectives_outside_loops(sch, kinds=("all-gather",))
     in_loop = [c for c in hlo_util.find_collectives(sch, ("all-gather",))
                if c.computation in hlo_util.while_body_computations(sch)]

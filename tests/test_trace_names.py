@@ -226,6 +226,30 @@ def test_train_step_carries_scopes_and_module_name():
     assert want <= set(SCOPES)
 
 
+@pytest.mark.parametrize("strategy,gathers", [("fsdp", True), ("ddp", False)])
+def test_head_gather_is_written_inside_loss_head(strategy, gathers):
+    """The sub-scope names the loss head's one gather, forward and (as the
+    region's transpose) backward, where the plan shards the output matrix
+    over data axes; a replicated head has no such scope."""
+    devices = jax.devices()[:4]
+    mesh = (make_mesh(fsdp=4, devices=devices) if strategy == "fsdp"
+            else make_mesh(dp=4, devices=devices))
+    trainer = Trainer(bundle=get_model("llama-debug"),
+                      optimizer=optax.adamw(1e-3),
+                      plan=make_plan(strategy, mesh), loss_chunks=4)
+    lowered, _ = lower_step(trainer, global_batch=4, seq_length=32)
+    assert ("head_gather" in scope_components(
+        lowered.as_text(debug_info=True))) is gathers
+    # the whole path is in the compiled program's op names
+    paths = [p for p in re.findall(r'op_name="([^"]+)"',
+                                   lowered.compile().as_text())
+             if "/head_gather/" in p]
+    assert all("loss_head" in p.split("/head_gather/")[0] for p in paths)
+    assert any(p.endswith("/all_gather") for p in paths) is gathers
+    assert any("transpose(jvp(loss_head))" in p for p in paths) is gathers
+    assert "head_gather" in SUBSCOPES
+
+
 def test_moe_step_carries_router_and_experts():
     plan = make_plan("single", make_mesh(devices=jax.devices()[:1]))
     trainer = Trainer(bundle=get_model("moe-debug"),
@@ -309,7 +333,7 @@ def test_latent_family_decode_carries_its_subscopes_and_kernel():
     assert any(f.startswith("attn/latent_proj/") for f in fragments)
     assert "paged_latent_attend" in KERNELS and set(SUBSCOPES) == {
         "latent_proj", "shared_expert", "conv", "attend_full",
-        "attend_window"}
+        "attend_window", "head_gather"}
 
 
 def test_named_gives_jit_the_name():
