@@ -55,17 +55,19 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import queue as queue_mod
+import time
 from typing import Optional
 
 from ..models.registry import ModelBundle, family_module
 from ..utils.trace import span
 from .adapters import DEFAULT_TARGETS
-from .engine import (LatencyMeter, ModelPrograms, adapter_metrics,
+from .engine import (DecodeArrays, LatencyMeter, ModelPrograms,
+                     adapter_metrics,
                      advance_prefill_chunks, build_adapter_report,
                      build_kv_report, collect_partial_tokens,
                      derived_pool_metrics, dispatch_horizon,
                      drop_stale_pending, horizon_dev,
-                     process_horizon_block, refuse_for_family,
+                     no_dev, process_horizon_block, refuse_for_family,
                      resolve_context_bounds, resolve_drafter,
                      resolve_prefill_chunk, run_decode_iteration, run_fork,
                      spec_metrics)
@@ -368,7 +370,7 @@ class PrefillEngine:
         return finished
 
 
-class DecodeEngine:
+class DecodeEngine(DecodeArrays):
     """The decode half: a fixed ``[n_slots]`` batch fed exclusively from
     the handoff queue, running the ONE compiled decode program. Keeps the
     monolith's device-resident steady state (tokens/lengths live on
@@ -387,7 +389,7 @@ class DecodeEngine:
         # bandwidth-bound half; prefill never sees a draft)
         self.drafter = drafter
         self.spec = new_spec_counters()
-        self._dev: Optional[dict] = None
+        self._dev = no_dev("first")
         # fused-horizon state: the knob and the dispatched-but-unbooked
         # block (the double buffer — see ServeEngine.step)
         self.decode_horizon = decode_horizon
@@ -409,7 +411,7 @@ class DecodeEngine:
                 generated=h.generated, submitted_at=h.submitted_at,
                 admitted_at=h.admitted_at, first_token_at=h.first_token_at,
                 resumed=h.resumed)
-            self._dev = None
+            self.drop_dev("admitted")
 
     def _horizon_ready(self) -> bool:
         """Mirror of ``ServeEngine._horizon_ready`` for the decode half:
@@ -434,7 +436,7 @@ class DecodeEngine:
         finished = []
         sched = self.sched
         if self._inflight is not None:
-            if (self._horizon_ready() and self._dev is not None
+            if (self._horizon_ready() and self._dev["kind"] == "horizon"
                     and not self.handoff.pending and not sched.queue
                     and not sched.deadline_due()
                     and sched.active_indices()):
@@ -457,17 +459,17 @@ class DecodeEngine:
                     return fin, []
             fin, emitted = process_horizon_block(sched, self._inflight)
             self._inflight = None
-            self._dev = None
+            self.drop_dev("drained")
             self.decode_tokens += emitted
             finished.extend(fin)
         expired = sched.expire_deadlines()
         if expired:
-            self._dev = None
+            self.drop_dev("expired")
             finished.extend(expired)
         self._seat_handoffs()
         grown, preempted = sched.grow_for_decode()
         if grown or preempted:
-            self._dev = None
+            self.drop_dev("preempted" if preempted else "grown")
         # a preempted sequence lands in THIS scheduler's queue, but only
         # the prefill engine can recompute its prompt — hand the entries
         # back for requeue-at-head over there (with their submit times)
@@ -478,8 +480,8 @@ class DecodeEngine:
                 k0 = max(1, min(sched.reserve_horizon(self.decode_horizon),
                                 self.decode_horizon,
                                 sched.max_remaining_budget()))
-                if self._dev is None or self._dev.get("kind") != "horizon":
-                    self._dev = horizon_dev(sched)
+                if self._dev["kind"] != "horizon":
+                    self._dev = horizon_dev(sched, self._dev)
                 self._inflight = dispatch_horizon(
                     self.programs, self.pages, sched, self._dev, k0)
                 self._note_dispatch(k0)
@@ -495,7 +497,7 @@ class DecodeEngine:
                 self.decode_tokens += emitted
                 finished.extend(fin)
                 if fin:
-                    self._dev = None       # a slot left the batch
+                    self.drop_dev("left")
         return finished, entries
 
 
@@ -763,11 +765,11 @@ class DisaggEngine:
         if on and dec.drafter is None and self._parked_drafter is not None:
             dec.drafter = self._parked_drafter
             self._parked_drafter = None
-            dec._dev = None
+            dec.drop_dev("speculation")
         elif not on and dec.drafter is not None:
             self._parked_drafter = dec.drafter
             dec.drafter = None
-            dec._dev = None
+            dec.drop_dev("speculation")
         return dec.drafter is not None
 
     def set_decode_horizon(self, k: int) -> int:
@@ -950,7 +952,7 @@ class DisaggEngine:
                     first_token_at=entry.first_token_at, resumed=True,
                     replay_pos=m["replay_pos"])
             tier.take(("seq", rid))
-            self.decode._dev = None
+            self.decode.drop_dev("restored")
             restored += 1
         return restored
 
@@ -967,8 +969,11 @@ class DisaggEngine:
                 "before swap_generation would decode old-policy k/v "
                 "under the new weights; run the swap first")
         self.stats_seq += 1
-        with span("serve.step", seq=self.stats_seq):
-            return self._iterate()
+        with span("serve.step", seq=self.stats_seq) as sp:
+            cpu0 = time.thread_time()
+            finished = self._iterate()
+            sp.set_metadata(cpu_ms=1e3 * (time.thread_time() - cpu0))
+            return finished
 
     def _iterate(self) -> list[RequestResult]:
         if self.host_tier is not None:

@@ -392,7 +392,7 @@ def _swap_generation_locked(old, new, force_replay: bool):
         queued = (old.decode.sched.drain_queue()
                   + old.prefill.sched.drain_queue())
         old.prefill._pending.clear()
-        old.decode._dev = None
+        old.decode.drop_dev("swapped")
         stats["cache_dropped"] = _drain_cache(old.prefill.sched)
     else:
         residents = _export_residents(old.scheduler, old.pages,
@@ -401,7 +401,7 @@ def _swap_generation_locked(old, new, force_replay: bool):
         _preempt_prefilling(old.scheduler)
         queued = old.scheduler.drain_queue()
         old._pending.clear()
-        old._dev = None
+        old.drop_dev("swapped")
         stats["cache_dropped"] = _drain_cache(old.scheduler)
 
     # ---- seat on the new generation ----------------------------------------
@@ -410,13 +410,13 @@ def _swap_generation_locked(old, new, force_replay: bool):
         queue_sched = new.prefill.sched
         capacities = [new.pool.capacity, new.decode_pool.capacity]
         now = queue_sched._clock()
-        new.decode._dev = None
+        new.decode.drop_dev("swapped")
     else:
         seat_sched = queue_sched = new.scheduler
         seat_pages = new.pages
         capacities = [new.scheduler.pool.capacity]
         now = new.scheduler._clock()
-        new._dev = None
+        new.drop_dev("swapped")
     results = []
     max_id = -1
     for exp in residents:

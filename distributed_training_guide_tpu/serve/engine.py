@@ -58,7 +58,8 @@ pool).
 from __future__ import annotations
 
 import contextlib
-from typing import Optional
+import time
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +67,7 @@ import numpy as np
 
 from ..models.registry import ModelBundle, family_module
 from ..train.precision import Quantized
-from ..utils.trace import named, span
+from ..utils.trace import install_gc_span, named, span
 from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        adapter_nbytes, adapter_pool_bytes, adapter_shapes,
                        init_adapter_stacks, validate_adapter_params)
@@ -416,9 +417,58 @@ def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
     return finished
 
 
+def no_dev(reason: str) -> dict:
+    """``_dev`` with nothing resident: ``kind`` None, and ``reason``, the
+    event that took the arrays off the device (``utils/trace.py``'s
+    ``REBUILD_REASONS``), which the next ``serve.build`` span reports. The
+    cause travels in the handle itself, so ``run_decode_iteration`` and
+    ``run_spec_decode`` need nothing of the engine that calls them."""
+    return {"kind": None, "reason": reason}
+
+
+class DecodeArrays:
+    """What the monolith and the disaggregated decode engine share about
+    ``_dev``, the decode arrays resident on the device between scheduler
+    events: a dict of them under their program's ``kind`` (plain / spec /
+    horizon), or :func:`no_dev`. Every event that invalidates them goes
+    through :meth:`drop_dev`, never an assignment."""
+
+    _dev: dict
+
+    def drop_dev(self, reason: str) -> None:
+        """The next decode rebuilds the arrays from the scheduler. The
+        FIRST cause since the last build is the one kept: a slot that left
+        and whose successor was admitted and prefilled in the next
+        iteration rebuilt because it ``left``."""
+        if self._dev["kind"] is not None:
+            self._dev = no_dev(reason)
+
+
+def upload_decode_arrays(dev: dict, kind: str,
+                         make_arrays: Callable[[], dict]) -> dict:
+    """Build decode arrays on the host and upload them: the ONE place a
+    decode program's inputs go up from. ``make_arrays()`` is the numpy work
+    (span ``serve.arrays``); the transfers are one ``jnp.asarray`` an array
+    (``serve.upload``, which says how many arrays and bytes); both lie
+    inside ``serve.build``, whose ``reason`` is why ``dev`` could not be
+    used as it stood. Returns the uploaded arrays."""
+    if dev["kind"] is None:
+        reason = dev["reason"]
+    else:
+        reason = "kind" if dev["kind"] != kind else "lookahead"
+    with span("serve.build", reason=reason):
+        with span("serve.arrays"):
+            arrays = make_arrays()
+        with span("serve.upload") as up:
+            out = {key: jnp.asarray(v) for key, v in arrays.items()}
+            up.set_metadata(arrays=len(arrays),
+                            bytes=sum(v.nbytes for v in arrays.values()))
+    return out
+
+
 def run_spec_decode(programs: "ModelPrograms", pages: dict,
                     sched: Scheduler, drafter: Drafter, spec: dict,
-                    dev: Optional[dict]) -> tuple[list, int, dict]:
+                    dev: dict) -> tuple[list, int, dict]:
     """One SPECULATIVE decode iteration over the decoding slots, shared
     verbatim by the monolithic engine and the disaggregated decode
     engine (speculation semantics must never fork between them):
@@ -442,9 +492,9 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
        returns the rolled-back lengths; the dead k/v past them is
        overwritten by the next scatter in place — no page churn).
 
-    ``dev`` is the engine-managed device cache (None after any scheduler
-    event, exactly like the plain path's ``_dev``): lengths roll forward
-    ON DEVICE via the verify program's ``new_lengths`` output and the
+    ``dev`` is the engine-managed device cache (``no_dev`` after any
+    scheduler event, exactly like the plain path's ``_dev``): lengths roll
+    forward ON DEVICE via the verify program's ``new_lengths`` output and the
     slow-changing arrays (tables, sampling knobs, actives) stay resident,
     so a steady spec iteration uploads only the [S, k+1] candidate ids +
     per-slot validity and reads back only (targets, n_acc) — the PR-6
@@ -474,14 +524,14 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
             # the verify scatter targets positions up to cache_len +
             # n_drafts, which must stay inside the position table
             sched.max_len - 1 - slot.cache_len))
-    with span("serve.draft", k=k):
+    with span("serve.draft"):
         proposals = drafter.propose_many(contexts, budgets)
     if not any(proposals.get(i) and budgets[i] > 0 for i in active):
         return None
     ids = np.zeros((sched.n_slots, t), np.int32)
     n_valid = np.ones(sched.n_slots, np.int32)
     grew = False
-    with span("serve.reserve", lookahead=k):
+    with span("serve.reserve"):
         for i in active:
             slot = sched.slots[i]
             ids[i, 0] = slot.generated[slot.replay_pos]
@@ -492,16 +542,17 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
             props = props[:granted]
             ids[i, 1:1 + len(props)] = props
             n_valid[i] = 1 + len(props)
-    if dev is None or dev.get("kind") != "spec":
-        with span("serve.build"):
+    if dev["kind"] != "spec":
+        def spec_arrays():
             arr = sched.decode_arrays()
-            dev = {"kind": "spec",
-                   **{key: jnp.asarray(arr[key])
-                      for key in ("lengths", "tables", "seeds", "temps",
-                                  "top_ks", "top_ps", "actives", "adapters")}}
+            return {key: arr[key]
+                    for key in ("lengths", "tables", "seeds", "temps",
+                                "top_ks", "top_ps", "actives", "adapters")}
+        dev = {"kind": "spec",
+               **upload_decode_arrays(dev, "spec", spec_arrays)}
     elif grew:      # lookahead growth extended a block table mid-flight
-        with span("serve.build"):
-            dev["tables"] = jnp.asarray(sched.decode_arrays()["tables"])
+        dev.update(upload_decode_arrays(
+            dev, "spec", lambda: {"tables": sched.decode_arrays()["tables"]}))
     # static greedy specialization: when every active slot decodes at
     # temperature 0 the target draw is argmax and the verify program
     # skips the t-position sorted-space sampler entirely (exact — see
@@ -542,8 +593,8 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
 
 def run_decode_iteration(programs: "ModelPrograms", pages: dict,
                          sched: Scheduler, drafter: Optional[Drafter],
-                         spec: dict, dev: Optional[dict]) \
-        -> tuple[list, int, Optional[dict]]:
+                         spec: dict, dev: dict) \
+        -> tuple[list, int, dict]:
     """ONE decode iteration over the active slots — the spec/plain
     dispatch, single-sourced for the monolith and the disaggregated
     decode engine (like ``run_spec_decode`` itself: neither the
@@ -565,11 +616,9 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
         out = run_spec_decode(programs, pages, sched, drafter, spec, dev)
         if out is not None:
             return out
-    if dev is None or dev.get("kind") != "plain":
-        with span("serve.build"):
-            dev = {"kind": "plain",
-                   **{key: jnp.asarray(v)
-                      for key, v in sched.decode_arrays().items()}}
+    if dev["kind"] != "plain":
+        dev = {"kind": "plain",
+               **upload_decode_arrays(dev, "plain", sched.decode_arrays)}
     with span("serve.dispatch", program="serve_decode"):
         nxt, new_len, pools, *counted = programs._decode_fn(
             programs.params, dict(pages),
@@ -592,17 +641,15 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
     return finished, len(active), dev
 
 
-def horizon_dev(sched: Scheduler) -> dict:
+def horizon_dev(sched: Scheduler, dev: dict) -> dict:
     """Device-resident arrays for the fused K-step decode horizon (kind
     "horizon"): the plain decode set plus the per-slot live/budget/eos
     lanes the in-device masking consumes. Built at a horizon boundary
     (host and device state agree there); between boundaries the horizon
     program itself carries tokens/lengths/live/budgets forward ON DEVICE
-    — the host never reads them back."""
-    with span("serve.build"):
-        return {"kind": "horizon",
-                **{key: jnp.asarray(v)
-                   for key, v in sched.decode_arrays().items()}}
+    — the host never reads them back. ``dev`` is what they replace."""
+    return {"kind": "horizon",
+            **upload_decode_arrays(dev, "horizon", sched.decode_arrays)}
 
 
 def dispatch_horizon(programs: "ModelPrograms", pages: dict,
@@ -625,13 +672,15 @@ def dispatch_horizon(programs: "ModelPrograms", pages: dict,
     Returns the in-flight record ``process_horizon_block`` consumes:
     the ``[n_slots, k]`` token-block future, the realized k, and the
     (slot, request_id) pairs active at dispatch."""
-    with span("serve.build"):
-        tables = np.zeros((sched.n_slots, sched.max_pages), np.int32)
-        active = []
+    active = []
+
+    def tables():
+        rows = np.zeros((sched.n_slots, sched.max_pages), np.int32)
         for i in sched.active_indices():
-            tables[i] = sched.table_row(i)
+            rows[i] = sched.table_row(i)
             active.append((i, sched.slots[i].request.request_id))
-        dev["tables"] = jnp.asarray(tables)
+        return {"tables": rows}
+    dev.update(upload_decode_arrays(dev, "horizon", tables))
     with span("serve.dispatch", program=f"serve_horizon_k{k}"):
         (block, dev["tokens"], dev["lengths"], dev["actives"],
          dev["budgets"], pools) = programs.horizon_for(k)(
@@ -1534,7 +1583,7 @@ class ModelPrograms:
                 f"prompt ids {bad[:5]} out of range for vocab_size {v}")
 
 
-class ServeEngine:
+class ServeEngine(DecodeArrays):
     """Multi-request generation over a model family's KV-cache decode.
 
     Drive it either through ``serve/api.py`` (``generate_many`` /
@@ -1705,9 +1754,10 @@ class ServeEngine:
             self.programs.attach_host_tier(self.host_tier)
 
         # chunked-prefill state per slot + the device-resident steady
-        # decode arrays (None = rebuild from the scheduler next decode)
+        # decode arrays (no_dev = rebuild from the scheduler next decode)
         self._pending: dict[int, Admission] = {}
-        self._dev: Optional[dict] = None
+        self._dev = no_dev("first")
+        install_gc_span()
         # the dispatched-but-unprocessed horizon block (decode_horizon >
         # 1): the double buffer's slot — the device computes horizon h
         # while the host books h−1 (see dispatch_horizon)
@@ -1808,11 +1858,11 @@ class ServeEngine:
         if on and self.drafter is None and self._parked_drafter is not None:
             self.drafter = self._parked_drafter
             self._parked_drafter = None
-            self._dev = None
+            self.drop_dev("speculation")
         elif not on and self.drafter is not None:
             self._parked_drafter = self.drafter
             self.drafter = None
-            self._dev = None
+            self.drop_dev("speculation")
         return self.drafter is not None
 
     def set_decode_horizon(self, k: int) -> int:
@@ -1930,7 +1980,7 @@ class ServeEngine:
         """The slot's pages are fully committed: it joins the decode
         batch (device arrays rebuild) with its first token sampled —
         unless it is a resumed sequence, whose tokens already exist."""
-        self._dev = None
+        self.drop_dev("prefilled")
         if adm.resumed:
             return None
         return self._sample_first(adm, logit)
@@ -1995,15 +2045,19 @@ class ServeEngine:
         # the whole iteration as one host span; its children (expire,
         # restore, admit, fork, prefill, sample, reserve, build, dispatch,
         # wait, book) are emitted where that work happens
-        with span("serve.step", seq=self.stats_seq):
-            return self._iterate()
+        with span("serve.step", seq=self.stats_seq) as sp:
+            cpu0 = time.thread_time()
+            finished = self._iterate()
+            sp.set_metadata(cpu_ms=1e3 * (time.thread_time() - cpu0))
+            return finished
 
     def _iterate(self) -> list[RequestResult]:
         finished = []
         sched = self.scheduler
         if self._inflight is not None:
             if (self._horizon_ready() and self._pipeline_steady()
-                    and self._dev is not None and sched.active_indices()):
+                    and self._dev["kind"] == "horizon"
+                    and sched.active_indices()):
                 pending_k = self._inflight["k"]
                 cov = sched.reserve_horizon(
                     pending_k + self.decode_horizon)
@@ -2028,12 +2082,12 @@ class ServeEngine:
             # boundary runs
             fin, emitted = process_horizon_block(sched, self._inflight)
             self._inflight = None
-            self._dev = None
+            self.drop_dev("drained")
             self.decode_tokens += emitted
             finished.extend(fin)
         expired = sched.expire_deadlines()
         if expired:
-            self._dev = None
+            self.drop_dev("expired")
             drop_stale_pending(sched, self._pending)
             finished.extend(expired)
         if self.host_tier is not None:
@@ -2047,7 +2101,7 @@ class ServeEngine:
             with span("serve.restore"):
                 if restore_queued(sched, self.host_tier, self.scatter_pages,
                                   self._tier_alloc):
-                    self._dev = None
+                    self.drop_dev("restored")
                 if sched.queue and sched.cache is not None:
                     head = sched.queue[0].request
                     restore_prefixes(
@@ -2057,7 +2111,7 @@ class ServeEngine:
                         free=sched.pool.free)
         admissions = sched.try_admit()
         for adm in admissions:
-            self._dev = None
+            self.drop_dev("admitted")
             if adm.fork is not None:
                 run_fork(self.programs, self.pages, adm)
             self._pending[adm.slot_idx] = adm
@@ -2072,7 +2126,7 @@ class ServeEngine:
         # its next write lands in
         grown, preempted = sched.grow_for_decode()
         if grown or preempted:
-            self._dev = None
+            self.drop_dev("preempted" if preempted else "grown")
             if preempted:
                 drop_stale_pending(sched, self._pending)
 
@@ -2086,8 +2140,8 @@ class ServeEngine:
                 k0 = max(1, min(sched.reserve_horizon(self.decode_horizon),
                                 self.decode_horizon,
                                 sched.max_remaining_budget()))
-                if self._dev is None or self._dev.get("kind") != "horizon":
-                    self._dev = horizon_dev(sched)
+                if self._dev["kind"] != "horizon":
+                    self._dev = horizon_dev(sched, self._dev)
                 self._inflight = dispatch_horizon(self.programs, self.pages,
                                                   sched, self._dev, k0)
                 self._note_dispatch(k0)
@@ -2101,7 +2155,7 @@ class ServeEngine:
                 self.decode_tokens += emitted
                 finished.extend(fin)
                 if fin:
-                    self._dev = None       # a slot left the batch
+                    self.drop_dev("left")
         self._lat.note(finished)
         return finished
 
@@ -2118,7 +2172,7 @@ class ServeEngine:
         out = scatter_payload(self.pages, list(page_ids), payload)
         for name in out:
             self.pages[name] = out[name]
-        self._dev = None
+        self.drop_dev("restored")
 
     def _tier_alloc(self, n: int):
         """Allocate ``n`` pages for a restore, refusing unless the free

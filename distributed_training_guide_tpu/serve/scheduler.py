@@ -1100,10 +1100,8 @@ class Scheduler:
         them (no un-grow, same as speculation's lookahead)."""
         if want < 1:
             raise ValueError(f"horizon must be >= 1, got {want}")
-        with span("serve.reserve", want=want) as sp:
-            covered = self._reserve_horizon(want)
-            sp.set_metadata(covered=covered)
-        return covered
+        with span("serve.reserve"):
+            return self._reserve_horizon(want)
 
     def _reserve_horizon(self, want: int) -> int:
         page = self.pool.page_size

@@ -294,7 +294,7 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
     from ..train import Trainer
     from ..train.optimizer import OPTIMIZERS, lr_at_step
     from ..train.state import host_state_dict
-    from ..utils.trace import span
+    from ..utils.trace import install_gc_span, span
     from ..utils import (LocalTimer, compute_mfu, get_mem_stats, init_logging,
                          is_process0, transformer_flops_per_token)
     from ..utils.logging import print_device_line
@@ -460,6 +460,7 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
     if getattr(args, "timer_sync", False):
         from ..utils.timers import device_sync
         sync_fn = device_sync
+    install_gc_span()
     # each timer is also the host span dtg.train.<k> (utils/trace.py)
     timers = {k: LocalTimer(sync_fn=sync_fn, name=f"train.{k}")
               for k in ["data", "step"]}

@@ -94,9 +94,6 @@ class LocalTimer:
             return 0.0
         return 1000.0 * (sum(self.measurements) / len(self.measurements))
 
-    def total_elapsed_ms(self) -> float:
-        return 1000.0 * sum(self.measurements)
-
     def reset(self) -> None:
         self.measurements = []
         self.start_time = None

@@ -13,6 +13,15 @@ thread. Arguments known only when the work is done go on with
         grown, preempted = sched.grow_for_decode()
         s.set_metadata(grown=grown, preempted=preempted)
 
+Statistics that say what a span cannot show by its length: ``serve.build``
+carries ``reason`` (one of :data:`REBUILD_REASONS`: why the decode arrays
+left the device), its child ``serve.upload`` carries ``arrays`` and ``bytes``
+(what went up), ``serve.step`` carries ``cpu_ms`` (the thread's own CPU time
+over the iteration: a long step with little of it was waiting or
+descheduled; where that clock ticks every 10 ms, as on the v5e hosts, only a
+long step says anything), and ``gc`` carries ``generation`` and ``collected``
+(:func:`install_gc_span`: one span over each garbage collection).
+
 On the device the names are HLO metadata and cost nothing at run time:
 ``jax.named_scope`` per model part (:data:`SCOPES`, :data:`SUBSCOPES`), a stable ``__name__`` on
 every jitted program (:data:`PROGRAMS`; the trace's ``XLA Modules`` line then
@@ -22,6 +31,8 @@ tests hold the package to them and the benchmark's readers match on them.
 """
 from __future__ import annotations
 
+import gc
+
 import jax
 
 PREFIX = "dtg."
@@ -30,10 +41,21 @@ PREFIX = "dtg."
 SPANS = (
     "serve.step", "serve.expire", "serve.restore", "serve.admit",
     "serve.fork", "serve.prefill", "serve.sample", "serve.draft",
-    "serve.reserve", "serve.build", "serve.dispatch", "serve.wait",
-    "serve.book", "serve.release",
+    "serve.reserve", "serve.build", "serve.arrays", "serve.upload",
+    "serve.dispatch", "serve.wait", "serve.book", "serve.release",
     "data.assemble", "data.put",
     "train.data", "train.step", "train.fence", "train.log", "train.ckpt",
+    "gc",
+)
+
+# `reason` of a serve.build span: the FIRST event since the last build that
+# took the decode arrays off the device (`serve/engine.py::DecodeArrays`).
+# `first`: nothing did, the engine has not built any yet; `kind`: resident,
+# but another decode program's set (plain / spec / horizon); `lookahead`:
+# the block tables alone, after a speculation or horizon reservation
+REBUILD_REASONS = (
+    "first", "grown", "preempted", "admitted", "prefilled", "left", "expired",
+    "restored", "drained", "kind", "speculation", "swapped", "lookahead",
 )
 
 # jax.named_scope names: model parts (`layers` is the layer scan's own work,
@@ -78,6 +100,34 @@ PROGRAMS = (
 def span(name: str, **args) -> jax.profiler.TraceAnnotation:
     """A host span ``dtg.<name>`` with ``args`` as its stats."""
     return jax.profiler.TraceAnnotation(PREFIX + name, **args)
+
+
+class _GcSpan:
+    """The ``gc.callbacks`` hook: ``dtg.gc`` from a collection's start to its
+    stop, on the thread that collects (collections do not nest)."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._open = span("gc", generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.set_metadata(collected=info["collected"])
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+_gc_span = _GcSpan()
+
+
+def install_gc_span() -> None:
+    """Hook the collector once a process (the serve engine and the train
+    loop both call this): nothing runs unless a collection does, and outside
+    a profiler session the span is a flag test like any other."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 def named(fn, name: str):

@@ -4,6 +4,7 @@ kernel names in the lowered programs. The kernels' names where the chip's
 compiler lowers them are in ``tests/test_chip_compile.py``; the readers of
 these names are under ``tests/benchmarks/``."""
 import contextlib
+import gc
 import re
 
 import jax
@@ -18,9 +19,10 @@ from distributed_training_guide_tpu.train import Trainer
 from distributed_training_guide_tpu.train.step import lower_step
 from distributed_training_guide_tpu.utils import trace as trace_mod
 from distributed_training_guide_tpu.utils.trace import (KERNELS, PREFIX,
-                                                        PROGRAMS, SCOPES,
-                                                        SPANS, SUBSCOPES,
-                                                        named, span)
+                                                        PROGRAMS,
+                                                        REBUILD_REASONS,
+                                                        SCOPES, SPANS,
+                                                        SUBSCOPES, named, span)
 
 SERVE_CHILDREN = {s for s in SPANS if s.startswith("serve.")} - {"serve.step"}
 
@@ -169,6 +171,150 @@ def test_train_loop_span_tree(tmp_path, eight_devices):
                                           "train.log", "train.ckpt")]
     lo, hi = min(e[1] for e in loop), max(e[2] for e in loop)
     assert covered((e[1], e[2]) for e in loop) >= 0.75 * (hi - lo)
+
+
+# ---- (a2) serve.build says why and what it uploaded -------------------------
+
+def roomy_engine(debug_model, page_size):
+    bundle, params = debug_model
+    return ServeEngine(bundle, params, n_slots=2, page_size=page_size,
+                       max_len=64)
+
+
+def request(prompt, n_new, seed=0):
+    return Request(prompt_ids=prompt, max_new_tokens=n_new, temperature=0.0,
+                   eos_id=None, seed=seed)
+
+
+def until_resident(engine):
+    """Step until the decode arrays are on the device and a step has run
+    on them: whatever drops them next is the first cause since a build."""
+    while engine._dev["kind"] != "plain":
+        engine.step()
+    engine.step()
+    assert engine._dev["kind"] == "plain"
+
+
+def provoke_left(engine):
+    """Two slots decode, one reply ends: the other's next step rebuilds."""
+    engine.submit(request([3, 17, 42, 5], 4))
+    engine.submit(request([8, 1, 30, 2], 14, seed=1))
+    until_resident(engine)
+
+
+def provoke_grown(engine):
+    """One slot decodes across the end of its first page of 8."""
+    engine.submit(request([3, 17, 42, 5], 14))
+    until_resident(engine)
+
+
+def provoke_admitted(engine):
+    """One slot decodes; a second request arrives inside the session."""
+    engine.submit(request([3, 17, 42, 5], 14))
+    until_resident(engine)
+    return request([8, 1, 30, 2], 3, seed=1)
+
+
+@pytest.mark.parametrize("reason,page_size,provoke", [
+    ("left", 32, provoke_left), ("grown", 8, provoke_grown),
+    ("admitted", 32, provoke_admitted)])
+def test_build_says_why_and_what_it_uploaded(debug_model, tmp_path, reason,
+                                             page_size, provoke):
+    engine = roomy_engine(debug_model, page_size)
+    run_requests(engine, n_new=4)          # compile outside the session
+    late = provoke(engine)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        if late is not None:
+            engine.submit(late)
+        while engine.has_work:
+            engine.step()
+    finally:
+        jax.profiler.stop_trace()
+    events = program_events(tmp_path)
+    by_name = lambda n: sorted((e for e in events if e[0] == n),
+                               key=lambda e: e[1])
+    builds = by_name("serve.build")
+    assert builds, "the session saw no rebuild"
+    # the FIRST cause since the last build, of the closed set
+    assert builds[0][4]["reason"] == reason
+    assert {b[4]["reason"] for b in builds} <= set(REBUILD_REASONS)
+    # the two children lie inside every build, arrays before upload, and
+    # leave it next to nothing of its own
+    arrays, uploads = by_name("serve.arrays"), by_name("serve.upload")
+    assert len(arrays) == len(uploads) == len(builds)
+    for b, a, u in zip(builds, arrays, uploads):
+        assert b[1] <= a[1] <= a[2] <= u[1] <= u[2] <= b[2]
+        assert a[3] == u[3] == b[3]
+    # what went up: the scheduler's eleven arrays, byte for byte
+    host = engine.scheduler.decode_arrays()
+    assert len(host) == 11
+    for u in uploads:
+        assert u[4]["arrays"] == 11
+        assert u[4]["bytes"] == sum(v.nbytes for v in host.values())
+    # the thread's own CPU time on every step, inside its wall time
+    steps = by_name("serve.step")
+    assert steps and all(
+        0 <= s[4]["cpu_ms"] <= (s[2] - s[1]) / 1e6 + 1.0 for s in steps)
+
+
+def test_a_collection_is_a_span_under_a_session_and_nothing_without(
+        debug_model, tmp_path):
+    roomy_engine(debug_model, 32)          # the engine installs the hook
+    from distributed_training_guide_tpu.utils.trace import install_gc_span
+
+    before = list(gc.callbacks)
+    install_gc_span()                      # once a process, whoever asks
+    assert gc.callbacks == before
+    gc.collect()                           # no session: nothing is recorded
+    was_enabled = gc.isenabled()
+    gc.disable()                           # no collection of the runtime's own
+    try:
+        jax.profiler.start_trace(str(tmp_path / "quiet"))
+        jnp.zeros(4).block_until_ready()
+        jax.profiler.stop_trace()
+        jax.profiler.start_trace(str(tmp_path / "collected"))
+        garbage = [[] for _ in range(100)]
+        for g in garbage:
+            g.append(g)                    # cycles only a collection frees
+        del garbage, g
+        gc.collect()
+        jax.profiler.stop_trace()
+    finally:
+        if was_enabled:
+            gc.enable()
+    assert [e for e in program_events(tmp_path / "quiet")
+            if e[0] == "gc"] == []
+    (event,) = [e for e in program_events(tmp_path / "collected")
+                if e[0] == "gc"]
+    assert event[4]["generation"] == 2 and event[4]["collected"] >= 100
+    assert event[2] > event[1]
+
+
+def test_no_engine_drops_the_decode_arrays_by_assignment():
+    """Every event that takes ``_dev`` off the device says which it is,
+    through ``DecodeArrays.drop_dev``; the reasons in the source are the
+    closed set, no more and no fewer."""
+    from pathlib import Path
+
+    serve = Path(trace_mod.__file__).resolve().parents[1] / "serve"
+    said = set()
+    for path in serve.glob("*.py"):
+        src = path.read_text()
+        assert not re.search(r"_dev\s*(?::[^=\n]+)?=\s*None", src), path.name
+        for call in re.findall(r"\b(?:drop_dev|no_dev)\(([^)]*)\)", src):
+            said |= set(re.findall(r'"(\w+)"', call))
+    # the two that no event causes: the builder finds the arrays resident,
+    # but another program's set, or refreshes the tables of its own
+    import inspect
+
+    from distributed_training_guide_tpu.serve import engine
+
+    built = set(re.findall(r'"(\w+)"', inspect.getsource(
+        engine.upload_decode_arrays).split('"""')[2])) & set(REBUILD_REASONS)
+    assert built == {"kind", "lookahead"} and not built & said
+    assert said | built == set(REBUILD_REASONS)
+    assert len(REBUILD_REASONS) == len(set(REBUILD_REASONS))
 
 
 # ---- (b) no session: nothing recorded, nothing changed ---------------------
