@@ -66,8 +66,8 @@ from .engine import (DecodeArrays, LatencyMeter, ModelPrograms,
                      advance_prefill_chunks, build_adapter_report,
                      build_kv_report, collect_partial_tokens,
                      derived_pool_metrics, dispatch_horizon,
-                     drop_stale_pending, horizon_dev,
-                     no_dev, process_horizon_block, refuse_for_family,
+                     drop_stale_pending, no_dev, process_horizon_block,
+                     refuse_for_family,
                      resolve_context_bounds, resolve_drafter,
                      resolve_prefill_chunk, run_decode_iteration, run_fork,
                      spec_metrics)
@@ -449,8 +449,8 @@ class DecodeEngine(DecodeArrays):
                 k_new = min(cov - pending_k, self.decode_horizon,
                             sched.max_remaining_budget() - pending_k)
                 if k_new >= 1:
-                    nxt = dispatch_horizon(self.programs, self.pages,
-                                           sched, self._dev, k_new)
+                    nxt, self._dev = dispatch_horizon(
+                        self.programs, self.pages, sched, self._dev, k_new)
                     self._note_dispatch(k_new)
                     fin, emitted = process_horizon_block(sched,
                                                          self._inflight)
@@ -468,8 +468,10 @@ class DecodeEngine(DecodeArrays):
             finished.extend(expired)
         self._seat_handoffs()
         grown, preempted = sched.grow_for_decode()
-        if grown or preempted:
-            self.drop_dev("preempted" if preempted else "grown")
+        if preempted:           # a slot left the batch
+            self.drop_dev("preempted")
+        elif grown:             # the same slots, longer block tables
+            self.stale_tables("grown")
         # a preempted sequence lands in THIS scheduler's queue, but only
         # the prefill engine can recompute its prompt — hand the entries
         # back for requeue-at-head over there (with their submit times)
@@ -480,9 +482,7 @@ class DecodeEngine(DecodeArrays):
                 k0 = max(1, min(sched.reserve_horizon(self.decode_horizon),
                                 self.decode_horizon,
                                 sched.max_remaining_budget()))
-                if self._dev["kind"] != "horizon":
-                    self._dev = horizon_dev(sched, self._dev)
-                self._inflight = dispatch_horizon(
+                self._inflight, self._dev = dispatch_horizon(
                     self.programs, self.pages, sched, self._dev, k0)
                 self._note_dispatch(k0)
             else:

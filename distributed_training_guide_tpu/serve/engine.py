@@ -59,7 +59,7 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -429,41 +429,75 @@ def no_dev(reason: str) -> dict:
 class DecodeArrays:
     """What the monolith and the disaggregated decode engine share about
     ``_dev``, the decode arrays resident on the device between scheduler
-    events: a dict of them under their program's ``kind`` (plain / spec /
-    horizon), or :func:`no_dev`. Every event that invalidates them goes
-    through :meth:`drop_dev`, never an assignment."""
+    events. It is in one of three states: :func:`no_dev` (nothing resident,
+    and why); a dict of the arrays under their program's ``kind`` (plain /
+    spec / horizon) with ``stale`` None (the next decode uses them as they
+    stand); or the same with ``stale`` the event that changed the block
+    tables and nothing else (the next decode uploads ``tables`` alone).
+    Every event that invalidates any of them goes through :meth:`drop_dev`
+    or :meth:`stale_tables`, never an assignment."""
 
     _dev: dict
 
     def drop_dev(self, reason: str) -> None:
-        """The next decode rebuilds the arrays from the scheduler. The
-        FIRST cause since the last build is the one kept: a slot that left
-        and whose successor was admitted and prefilled in the next
-        iteration rebuilt because it ``left``."""
+        """The next decode rebuilds every array from the scheduler: the set
+        of decoding slots changed, or what a slot decodes under. The FIRST
+        cause since the last build is the one kept: a slot that left and
+        whose successor was admitted and prefilled in the next iteration
+        rebuilt because it ``left``."""
         if self._dev["kind"] is not None:
-            self._dev = no_dev(reason)
+            first = self._dev["stale"] or reason
+            self._dev = no_dev(first)
+
+    def stale_tables(self, reason: str) -> None:
+        """Slots took pages and nothing else about the decoding set changed:
+        the arrays stay on the device and the next decode uploads the block
+        tables alone. Tokens and lengths have rolled forward there, and the
+        sampling lanes belong to requests that did not change."""
+        if self._dev["kind"] is not None and self._dev["stale"] is None:
+            self._dev["stale"] = reason
 
 
-def upload_decode_arrays(dev: dict, kind: str,
-                         make_arrays: Callable[[], dict]) -> dict:
-    """Build decode arrays on the host and upload them: the ONE place a
-    decode program's inputs go up from. ``make_arrays()`` is the numpy work
-    (span ``serve.arrays``); the transfers are one ``jnp.asarray`` an array
+# what the verify program takes of ``Scheduler.decode_arrays()``: the
+# candidate ids go up with every call, and it masks no lane by budget or eos
+SPEC_ARRAYS = ("lengths", "tables", "seeds", "temps", "top_ks", "top_ps",
+               "actives", "adapters")
+
+
+def upload_decode_arrays(dev: dict, kind: str, sched: Scheduler, *,
+                         keys: Optional[tuple] = None,
+                         lookahead: bool = False) -> dict:
+    """Make ``dev`` what the ``kind`` program may be called with, and return
+    it: the ONE place a decode program's inputs go up from, and the one that
+    decides what goes. Nothing resident, or another program's set: the whole
+    of ``sched.decode_arrays()`` (``keys`` of it). Resident with stale
+    tables (:meth:`DecodeArrays.stale_tables`), or after the caller's own
+    ``lookahead`` reservation: ``tables`` alone, one transfer. Otherwise
+    nothing, and no span. The numpy work is ``serve.arrays`` and fills only
+    what will go; the transfers are one ``jnp.asarray`` an array
     (``serve.upload``, which says how many arrays and bytes); both lie
     inside ``serve.build``, whose ``reason`` is why ``dev`` could not be
-    used as it stood. Returns the uploaded arrays."""
-    if dev["kind"] is None:
-        reason = dev["reason"]
+    used as it stood."""
+    whole = dev["kind"] != kind
+    if whole:
+        reason = dev["reason"] if dev["kind"] is None else "kind"
+    elif dev["stale"] is not None or lookahead:
+        reason = dev["stale"] or "lookahead"
     else:
-        reason = "kind" if dev["kind"] != kind else "lookahead"
+        return dev
     with span("serve.build", reason=reason):
         with span("serve.arrays"):
-            arrays = make_arrays()
+            if whole:
+                arrays = sched.decode_arrays()
+                if keys is not None:
+                    arrays = {key: arrays[key] for key in keys}
+            else:
+                arrays = {"tables": sched.decode_tables()}
         with span("serve.upload") as up:
             out = {key: jnp.asarray(v) for key, v in arrays.items()}
             up.set_metadata(arrays=len(arrays),
                             bytes=sum(v.nbytes for v in arrays.values()))
-    return out
+    return {**({} if whole else dev), "kind": kind, **out, "stale": None}
 
 
 def run_spec_decode(programs: "ModelPrograms", pages: dict,
@@ -492,12 +526,14 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
        returns the rolled-back lengths; the dead k/v past them is
        overwritten by the next scatter in place — no page churn).
 
-    ``dev`` is the engine-managed device cache (``no_dev`` after any
-    scheduler event, exactly like the plain path's ``_dev``): lengths roll
-    forward ON DEVICE via the verify program's ``new_lengths`` output and the
-    slow-changing arrays (tables, sampling knobs, actives) stay resident,
-    so a steady spec iteration uploads only the [S, k+1] candidate ids +
-    per-slot validity and reads back only (targets, n_acc) — the PR-6
+    ``dev`` is the engine-managed device cache (``no_dev`` after an event
+    that changed the decoding set, its tables marked stale after one that
+    only gave slots pages, exactly like the plain path's ``_dev``): lengths
+    roll forward ON DEVICE via the verify program's ``new_lengths`` output
+    and the slow-changing arrays (tables, sampling knobs, actives) stay
+    resident, so a steady spec iteration uploads only the [S, k+1] candidate
+    ids + per-slot validity (and the tables, when a lookahead grew one) and
+    reads back only (targets, n_acc) — the PR-6
     host-round-trip lesson, kept under speculation. The emitted tokens
     themselves come back in that read (the host needs them anyway for
     EOS checks and streaming).
@@ -542,17 +578,9 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
             props = props[:granted]
             ids[i, 1:1 + len(props)] = props
             n_valid[i] = 1 + len(props)
-    if dev["kind"] != "spec":
-        def spec_arrays():
-            arr = sched.decode_arrays()
-            return {key: arr[key]
-                    for key in ("lengths", "tables", "seeds", "temps",
-                                "top_ks", "top_ps", "actives", "adapters")}
-        dev = {"kind": "spec",
-               **upload_decode_arrays(dev, "spec", spec_arrays)}
-    elif grew:      # lookahead growth extended a block table mid-flight
-        dev.update(upload_decode_arrays(
-            dev, "spec", lambda: {"tables": sched.decode_arrays()["tables"]}))
+    # lookahead growth extended a block table since the last upload
+    dev = upload_decode_arrays(dev, "spec", sched, keys=SPEC_ARRAYS,
+                               lookahead=grew)
     # static greedy specialization: when every active slot decodes at
     # temperature 0 the target draw is argmax and the verify program
     # skips the t-position sorted-space sampler entirely (exact — see
@@ -605,7 +633,13 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
     PR-6 finding), and at least one slot actually drafted; otherwise the
     plain single-token program steps with its device-resident arrays.
     The two paths keep separate device caches keyed by ``kind`` —
-    switching costs one rebuild, a scheduler-event-sized expense.
+    switching costs one upload of the whole set, what a slot joining or
+    leaving costs; a slot that only grew a page costs either path its
+    tables alone (``upload_decode_arrays``). The plain program runs on the
+    tokens and lengths it left on the device itself: before every dispatch
+    each resident array holds, for every active slot, what
+    ``sched.decode_arrays()`` would upload (a replayed token the device
+    sampled IS the recorded one, the bitwise-recompute rule above).
 
     Returns (finished, tokens emitted, dev). The caller owns the
     decode_steps/decode_tokens counters and must drop ``dev`` when a
@@ -616,9 +650,7 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
         out = run_spec_decode(programs, pages, sched, drafter, spec, dev)
         if out is not None:
             return out
-    if dev["kind"] != "plain":
-        dev = {"kind": "plain",
-               **upload_decode_arrays(dev, "plain", sched.decode_arrays)}
+    dev = upload_decode_arrays(dev, "plain", sched)
     with span("serve.dispatch", program="serve_decode"):
         nxt, new_len, pools, *counted = programs._decode_fn(
             programs.params, dict(pages),
@@ -641,46 +673,34 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
     return finished, len(active), dev
 
 
-def horizon_dev(sched: Scheduler, dev: dict) -> dict:
-    """Device-resident arrays for the fused K-step decode horizon (kind
-    "horizon"): the plain decode set plus the per-slot live/budget/eos
-    lanes the in-device masking consumes. Built at a horizon boundary
-    (host and device state agree there); between boundaries the horizon
-    program itself carries tokens/lengths/live/budgets forward ON DEVICE
-    — the host never reads them back. ``dev`` is what they replace."""
-    return {"kind": "horizon",
-            **upload_decode_arrays(dev, "horizon", sched.decode_arrays)}
-
-
 def dispatch_horizon(programs: "ModelPrograms", pages: dict,
-                     sched: Scheduler, dev: dict, k: int) -> dict:
+                     sched: Scheduler, dev: dict, k: int) \
+        -> tuple[dict, dict]:
     """Dispatch ONE fused K-step horizon — no host synchronization: jax's
     async dispatch returns futures, and the only blocking read is the
     ``np.asarray`` in :func:`process_horizon_block`, which the engine
     runs AFTER dispatching the next horizon (the double buffer: the
     device computes horizon h while the host books horizon h−1).
 
-    The block tables re-upload every dispatch — they are host-owned and
-    may have grown via ``reserve_horizon`` since the last one — while
-    tokens/lengths/live/budgets stay device-resident (the previous
-    horizon's outputs feed this one's inputs without readback). A slot
-    that finished inside a still-unprocessed block is DEAD on device
+    The device-resident arrays of kind "horizon" are the plain decode set
+    plus the per-slot live/budget/eos lanes the in-device masking consumes.
+    They go up whole at a horizon boundary (``dev`` is then ``no_dev`` or
+    another program's set; host and device state agree there). Between
+    boundaries the block tables alone go up, at every dispatch — they are
+    host-owned and may have grown via ``reserve_horizon`` since the last
+    one — while tokens/lengths/live/budgets stay device-resident (the
+    previous horizon's outputs feed this one's inputs without readback). A
+    slot that finished inside a still-unprocessed block is DEAD on device
     (its live lane went False in that block's scan), so its stale table
     row is masked to the trash page in-program and its freed pages may
     be re-issued to a later admission without corruption.
 
-    Returns the in-flight record ``process_horizon_block`` consumes:
-    the ``[n_slots, k]`` token-block future, the realized k, and the
-    (slot, request_id) pairs active at dispatch."""
-    active = []
-
-    def tables():
-        rows = np.zeros((sched.n_slots, sched.max_pages), np.int32)
-        for i in sched.active_indices():
-            rows[i] = sched.table_row(i)
-            active.append((i, sched.slots[i].request.request_id))
-        return {"tables": rows}
-    dev.update(upload_decode_arrays(dev, "horizon", tables))
+    Returns the in-flight record ``process_horizon_block`` consumes (the
+    ``[n_slots, k]`` token-block future, the realized k, and the (slot,
+    request_id) pairs active at dispatch) and the updated dev cache."""
+    dev = upload_decode_arrays(dev, "horizon", sched, lookahead=True)
+    active = [(i, sched.slots[i].request.request_id)
+              for i in sched.active_indices()]
     with span("serve.dispatch", program=f"serve_horizon_k{k}"):
         (block, dev["tokens"], dev["lengths"], dev["actives"],
          dev["budgets"], pools) = programs.horizon_for(k)(
@@ -690,7 +710,7 @@ def dispatch_horizon(programs: "ModelPrograms", pages: dict,
             dev["budgets"], dev["eos_ids"],
             *programs.lora_call_args(dev["adapters"]))
         pages.update(pools)
-    return {"block": block, "k": k, "active": active}
+    return {"block": block, "k": k, "active": active}, dev
 
 
 def process_horizon_block(sched: Scheduler, inflight: dict) \
@@ -1754,7 +1774,8 @@ class ServeEngine(DecodeArrays):
             self.programs.attach_host_tier(self.host_tier)
 
         # chunked-prefill state per slot + the device-resident steady
-        # decode arrays (no_dev = rebuild from the scheduler next decode)
+        # decode arrays (no_dev = the next decode uploads them all from the
+        # scheduler; DecodeArrays has the states)
         self._pending: dict[int, Admission] = {}
         self._dev = no_dev("first")
         install_gc_span()
@@ -2068,8 +2089,8 @@ class ServeEngine(DecodeArrays):
                 k_new = min(cov - pending_k, self.decode_horizon,
                             sched.max_remaining_budget() - pending_k)
                 if k_new >= 1:
-                    nxt = dispatch_horizon(self.programs, self.pages,
-                                           sched, self._dev, k_new)
+                    nxt, self._dev = dispatch_horizon(
+                        self.programs, self.pages, sched, self._dev, k_new)
                     self._note_dispatch(k_new)
                     fin, emitted = process_horizon_block(sched,
                                                          self._inflight)
@@ -2125,10 +2146,11 @@ class ServeEngine(DecodeArrays):
         # whose prefill ended exactly on a page boundary — owns the page
         # its next write lands in
         grown, preempted = sched.grow_for_decode()
-        if grown or preempted:
-            self.drop_dev("preempted" if preempted else "grown")
-            if preempted:
-                drop_stale_pending(sched, self._pending)
+        if preempted:           # a slot left the batch
+            self.drop_dev("preempted")
+            drop_stale_pending(sched, self._pending)
+        elif grown:             # the same slots, longer block tables
+            self.stale_tables("grown")
 
         if sched.active_indices():
             if self._horizon_ready():
@@ -2140,10 +2162,8 @@ class ServeEngine(DecodeArrays):
                 k0 = max(1, min(sched.reserve_horizon(self.decode_horizon),
                                 self.decode_horizon,
                                 sched.max_remaining_budget()))
-                if self._dev["kind"] != "horizon":
-                    self._dev = horizon_dev(sched, self._dev)
-                self._inflight = dispatch_horizon(self.programs, self.pages,
-                                                  sched, self._dev, k0)
+                self._inflight, self._dev = dispatch_horizon(
+                    self.programs, self.pages, sched, self._dev, k0)
                 self._note_dispatch(k0)
                 # no blocking read here: the block books next step (or at
                 # the next drain) — the first half of the double buffer

@@ -1345,6 +1345,16 @@ class Scheduler:
         """Columns of a slot's table row: one class's, or both classes'."""
         return self.max_pages * (1 if self.window is None else 2)
 
+    def decode_tables(self) -> np.ndarray:
+        """The block tables of the decoding set, ``decode_arrays()["tables"]``
+        alone: all that an event which gave slots pages and changed nothing
+        else (``grow_for_decode``, a lookahead or horizon reservation) has
+        made stale on the device."""
+        rows = np.zeros((self.n_slots, self.table_width), np.int32)
+        for i in self.active_indices():
+            rows[i] = self.table_row(i)
+        return rows
+
     def decode_arrays(self) -> dict:
         """Flat numpy views of the decoding set, shaped for the ONE
         compiled decode step: idle and still-prefilling slots carry token
@@ -1354,7 +1364,7 @@ class Scheduler:
         out = {
             "tokens": np.zeros(s, np.int32),
             "lengths": np.zeros(s, np.int32),
-            "tables": np.zeros((s, self.table_width), np.int32),
+            "tables": self.decode_tables(),
             "seeds": np.zeros(s, np.int32),
             "temps": np.zeros(s, np.float32),
             "top_ks": np.zeros(s, np.int32),
@@ -1379,7 +1389,6 @@ class Scheduler:
             # token whose k/v needs rewriting
             out["tokens"][i] = slot.generated[slot.replay_pos]
             out["lengths"][i] = slot.cache_len
-            out["tables"][i] = self.table_row(i)
             out["seeds"][i] = req.seed
             out["temps"][i] = req.temperature
             out["top_ks"][i] = req.top_k

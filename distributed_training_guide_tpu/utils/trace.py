@@ -14,9 +14,10 @@ thread. Arguments known only when the work is done go on with
         s.set_metadata(grown=grown, preempted=preempted)
 
 Statistics that say what a span cannot show by its length: ``serve.build``
-carries ``reason`` (one of :data:`REBUILD_REASONS`: why the decode arrays
-left the device), its child ``serve.upload`` carries ``arrays`` and ``bytes``
-(what went up), ``serve.step`` carries ``cpu_ms`` (the thread's own CPU time
+carries ``reason`` (one of :data:`REBUILD_REASONS`: why the decode arrays on
+the device could not be used as they stood), its child ``serve.upload``
+carries ``arrays`` and ``bytes`` (what went up: the whole set, or the block
+tables alone), ``serve.step`` carries ``cpu_ms`` (the thread's own CPU time
 over the iteration: a long step with little of it was waiting or
 descheduled; where that clock ticks every 10 ms, as on the v5e hosts, only a
 long step says anything), and ``gc`` carries ``generation`` and ``collected``
@@ -49,10 +50,14 @@ SPANS = (
 )
 
 # `reason` of a serve.build span: the FIRST event since the last build that
-# took the decode arrays off the device (`serve/engine.py::DecodeArrays`).
-# `first`: nothing did, the engine has not built any yet; `kind`: resident,
-# but another decode program's set (plain / spec / horizon); `lookahead`:
-# the block tables alone, after a speculation or horizon reservation
+# left the decode arrays on the device unfit for the next decode
+# (`serve/engine.py::DecodeArrays`). `first`: nothing did, the engine has not
+# built any yet; `kind`: resident, but another decode program's set (plain /
+# spec / horizon). Two reasons mean the same ONE transfer, the block tables
+# alone, for different causes: `grown` (`grow_for_decode` gave a slot the page
+# of its next write) and `lookahead` (a speculation or horizon reservation
+# gave it pages ahead of that). Every other build carries the whole set;
+# `serve.upload`'s `arrays` tells the two apart
 REBUILD_REASONS = (
     "first", "grown", "preempted", "admitted", "prefilled", "left", "expired",
     "restored", "drained", "kind", "speculation", "swapped", "lookahead",

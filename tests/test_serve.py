@@ -676,6 +676,193 @@ def test_random_trace_disagg_handoff(llama):
     _check_completions(bundle, params, done, submitted, max_len=16)
 
 
+# ---- the resident decode arrays ----------------------------------------------
+
+# lanes a decode program carries forward on the device itself: they agree
+# with the host wherever the host is not a block behind (always at K=1)
+_ROLLING = ("tokens", "lengths", "actives", "budgets")
+# the leading operands of each program after (params, pools), by kind
+_OPERANDS = {
+    "plain": ("tokens", "lengths", "tables", "seeds", "temps", "top_ks",
+              "top_ps", "actives"),
+    "spec": ("ids", "lengths", "tables", "seeds", "temps", "top_ks",
+             "top_ps", "actives"),
+    "horizon": ("tokens", "lengths", "tables", "seeds", "temps", "top_ks",
+                "top_ps", "actives", "budgets", "eos_ids"),
+}
+_PATHS = ["monolith", "disagg", "window", "spec", "horizon4"]
+
+
+def _path_engine(path, llama):
+    """An engine of each decode path over a pool its requests outgrow."""
+    from distributed_training_guide_tpu.serve.disagg import DisaggEngine
+
+    bundle, params = llama
+    tight = dict(n_slots=4, page_size=4, max_len=16, n_pages=7)
+    if path == "disagg":
+        return DisaggEngine(bundle, params, n_slots=4, n_prefill_slots=1,
+                            page_size=4, max_len=16, n_pages=10,
+                            prefill_chunk=4)
+    if path == "window":        # two page classes, a table row of both
+        bundle = get_model("mimo-v2-debug", dtype=jnp.float32)
+        params = bundle.init(bundle.config, jax.random.key(0))
+        return ServeEngine(bundle, params, n_slots=3, page_size=8,
+                           max_len=48, n_pages=10, prefill_chunk=16)
+    if path == "spec":
+        return ServeEngine(bundle, params, speculate="ngram", spec_k=3,
+                           **tight)
+    if path == "horizon4":
+        return ServeEngine(bundle, params, decode_horizon=4, **tight)
+    return ServeEngine(bundle, params, **tight)
+
+
+def _path_requests(path, temperature=None):
+    """Eight requests that cross pages, finish at different steps and
+    together want twice the pool: greedy and sampled by turns, or all at
+    one ``temperature``."""
+    if path == "window":
+        shape = lambda i: ([3 + i, 17, 42, 9, 8, 7, 9, 8][:3 + i % 5],
+                           24 + 3 * (i % 4))
+    elif path == "spec":        # repetition, so the drafter drafts
+        shape = lambda i: ([9, 8, 7, 9, 8, 7][:2 + i % 4] + [3 + i],
+                           7 + (i % 3))
+    else:
+        shape = lambda i: ([3 + i, 17, 42][:1 + i % 3], 8 + (i % 5))
+    reqs = []
+    for i in range(8):
+        prompt, n_new = shape(i)
+        t = (0.8 if i % 2 else 0.0) if temperature is None else temperature
+        reqs.append(Request(prompt_ids=prompt, max_new_tokens=n_new,
+                            temperature=t, seed=i))
+    return reqs
+
+
+def _decode_side(eng):
+    """``(holder of _dev, programs, scheduler)`` of the engine's decode
+    half: the engine itself, or the disaggregated pair's decode engine."""
+    dec = getattr(eng, "decode", None)
+    return (eng, eng.programs, eng.scheduler) if dec is None \
+        else (dec, dec.programs, dec.sched)
+
+
+class _Checked:
+    """A decode program that first holds its operands to the invariant:
+    for every active slot each resident array is what a whole rebuild from
+    the scheduler would upload at this instant."""
+
+    def __init__(self, fn, kind, holder, sched, seen):
+        self.fn, self.kind, self.holder = fn, kind, holder
+        self.sched, self.seen = sched, seen
+
+    def __getattr__(self, name):            # _cache_size, lower, ...
+        return getattr(self.fn, name)
+
+    def __call__(self, params, pools, *operands):
+        sched = self.sched
+        want = sched.decode_arrays()
+        active = sched.active_indices()
+        assert active
+        # a horizon dispatched over an unbooked block: the host is a block
+        # behind on the lanes the device carries, by construction
+        behind = getattr(self.holder, "_inflight", None) is not None
+        n_full = sched.max_pages
+        for key, got in zip(_OPERANDS[self.kind], operands):
+            if key == "ids" or (behind and key in _ROLLING):
+                continue
+            got = np.asarray(got)
+            for i in active:
+                if key != "tables":
+                    assert got[i] == want[key][i], (key, i, self.kind)
+                    continue
+                np.testing.assert_array_equal(
+                    got[i, :n_full], want[key][i, :n_full], f"slot {i}")
+                # the second class: the device may go on naming a page the
+                # window has passed and the host has taken back
+                # (Scheduler._release_window); never the other way round
+                stale = got[i, n_full:] != want[key][i, n_full:]
+                assert not (stale & (want[key][i, n_full:] != 0)).any(), i
+        self.seen[self.kind] = self.seen.get(self.kind, 0) + 1
+        return self.fn(params, pools, *operands)
+
+
+def _run_checked(eng, reqs, monkeypatch):
+    """The session through ``eng`` with every dispatch checked; returns
+    ``(results, dispatches by kind, serve.build reasons -> arrays sent)``."""
+    from distributed_training_guide_tpu.serve import engine as engine_mod
+
+    holder, programs, sched = _decode_side(eng)
+    seen, builds = {}, []
+    check = lambda fn, kind: _Checked(fn, kind, holder, sched, seen)
+    programs._decode_fn = check(programs._decode_fn, "plain")
+    verify_for, horizon_for = programs.verify_for, programs.horizon_for
+    programs.verify_for = lambda t, greedy=False: check(
+        verify_for(t, greedy=greedy), "spec")
+    programs.horizon_for = lambda k: check(horizon_for(k), "horizon")
+    upload = engine_mod.upload_decode_arrays
+
+    def counted(dev, kind, sched, **kw):
+        out = upload(dev, kind, sched, **kw)
+        if out is not dev:
+            whole = dev["kind"] != kind
+            builds.append((dev["reason"] if dev["kind"] is None else
+                           "kind" if whole else dev["stale"] or "lookahead",
+                           "whole" if whole else "tables"))
+        return out
+    monkeypatch.setattr(engine_mod, "upload_decode_arrays", counted)
+    res = generate_many(eng, reqs, max_iterations=3000)
+    return res, seen, builds
+
+
+@pytest.mark.parametrize("path", _PATHS)
+def test_resident_decode_arrays_are_what_a_whole_rebuild_would_upload(
+        llama, monkeypatch, path):
+    """Before EVERY dispatch of a session that crosses pages, admits,
+    finishes, preempts and replays, on every decode path: the arrays on the
+    device equal the scheduler's, slot by active slot, though a slot that
+    only grew a page sent up its block tables alone."""
+    eng = _path_engine(path, llama)
+    res, seen, builds = _run_checked(eng, _path_requests(path), monkeypatch)
+    assert len(res) == 8 and all(r.finish_reason == "length" for r in res)
+    sched = _decode_side(eng)[2]
+    assert sched.stats["preempted"] > 0, "the session never preempted"
+    assert sched.stats["finished"] == 8
+    want = {"spec": {"spec", "plain"}, "horizon4": {"horizon", "plain"}}
+    assert set(seen) == want.get(path, {"plain"}), seen
+    # the mechanism engaged: growth sent the tables alone, every event that
+    # changed the decoding set the whole set, and no other pairing exists
+    by_reason = {}
+    for reason, sent in builds:
+        by_reason.setdefault(reason, set()).add(sent)
+    assert by_reason.get("grown") == {"tables"}, by_reason
+    assert by_reason.get("lookahead", {"tables"}) == {"tables"}
+    assert all(sent == {"whole"} for reason, sent in by_reason.items()
+               if reason not in ("grown", "lookahead")), by_reason
+    assert {"preempted", "left"} & set(by_reason), by_reason
+    if path == "horizon4":      # every dispatch after a boundary's
+        assert "lookahead" in by_reason
+    prefill = eng.prefill.sched if path == "disagg" else sched
+    assert sched.pool.n_free + prefill.cache_pages_held() \
+        == sched.pool.capacity
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("path", _PATHS)
+def test_tokens_are_those_of_an_engine_whose_every_build_is_whole(
+        llama, monkeypatch, path, temperature):
+    """The parent's behaviour, forced in the test: a slot that grew a page
+    drops the whole resident set. The token ids are the same."""
+    from distributed_training_guide_tpu.serve.engine import DecodeArrays
+
+    reqs = _path_requests(path, temperature)
+    got = generate_many(_path_engine(path, llama),
+                        [_fresh(r) for r in reqs], max_iterations=3000)
+    monkeypatch.setattr(DecodeArrays, "stale_tables", DecodeArrays.drop_dev)
+    eng = _path_engine(path, llama)
+    want = generate_many(eng, [_fresh(r) for r in reqs], max_iterations=3000)
+    assert _decode_side(eng)[2].stats["preempted"] > 0
+    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+
+
 # ---- chunked prefill --------------------------------------------------------
 
 def test_chunked_prefill_interleaves_with_resident_decode(llama):

@@ -246,12 +246,19 @@ def test_build_says_why_and_what_it_uploaded(debug_model, tmp_path, reason,
     for b, a, u in zip(builds, arrays, uploads):
         assert b[1] <= a[1] <= a[2] <= u[1] <= u[2] <= b[2]
         assert a[3] == u[3] == b[3]
-    # what went up: the scheduler's eleven arrays, byte for byte
+    # what went up: the block tables alone where a slot only grew a page,
+    # the scheduler's eleven arrays, byte for byte, for every other reason
     host = engine.scheduler.decode_arrays()
     assert len(host) == 11
-    for u in uploads:
-        assert u[4]["arrays"] == 11
-        assert u[4]["bytes"] == sum(v.nbytes for v in host.values())
+    for b, u in zip(builds, uploads):
+        if b[4]["reason"] == "grown":
+            assert u[4]["arrays"] == 1
+            assert u[4]["bytes"] == host["tables"].nbytes
+        else:
+            assert u[4]["arrays"] == 11
+            assert u[4]["bytes"] == sum(v.nbytes for v in host.values())
+    if reason == "grown":       # 4 + 14 tokens cross two pages of 8
+        assert [b[4]["reason"] for b in builds].count("grown") == 2
     # the thread's own CPU time on every step, inside its wall time
     steps = by_name("serve.step")
     assert steps and all(
@@ -292,9 +299,10 @@ def test_a_collection_is_a_span_under_a_session_and_nothing_without(
 
 
 def test_no_engine_drops_the_decode_arrays_by_assignment():
-    """Every event that takes ``_dev`` off the device says which it is,
-    through ``DecodeArrays.drop_dev``; the reasons in the source are the
-    closed set, no more and no fewer."""
+    """Every event that takes ``_dev`` off the device, or leaves its tables
+    stale, says which it is, through ``DecodeArrays.drop_dev`` or
+    ``DecodeArrays.stale_tables``; the reasons in the source are the closed
+    set, no more and no fewer."""
     from pathlib import Path
 
     serve = Path(trace_mod.__file__).resolve().parents[1] / "serve"
@@ -302,7 +310,10 @@ def test_no_engine_drops_the_decode_arrays_by_assignment():
     for path in serve.glob("*.py"):
         src = path.read_text()
         assert not re.search(r"_dev\s*(?::[^=\n]+)?=\s*None", src), path.name
-        for call in re.findall(r"\b(?:drop_dev|no_dev)\(([^)]*)\)", src):
+        assert not re.search(r'\["stale"\]\s*=[^=]', src) \
+            or path.name == "engine.py", path.name
+        for call in re.findall(
+                r"\b(?:drop_dev|no_dev|stale_tables)\(([^)]*)\)", src):
             said |= set(re.findall(r'"(\w+)"', call))
     # the two that no event causes: the builder finds the arrays resident,
     # but another program's set, or refreshes the tables of its own
