@@ -40,6 +40,16 @@ over the data axes: ep > 1 exchanges sorted groups by all-gather +
 reduce-scatter; plain dp/fsdp bodies are collective-free). The decode
 ``no_drop`` path always runs ragged — O(t*k*d) transients instead of the
 old worst-case O(E*k*t*d) capacity buffers.
+
+``config.experts_held = (first, count)`` (one chip's share of an
+expert-parallel layer; :func:`experts_held`): the router keeps its width and
+the local ragged dispatch leaves out the pairs of absent experts. The share
+TRAINS as well as serves: ``_moe_ffn`` is differentiable over it (``gmm``
+forward, ``gmm`` against the transposed matrices and ``tgmm`` backward, the
+expert leaves' gradients the uncut gradient's slices), ``models/laguna.py``
+trains through it, and ``train/step.py`` takes it on one device and refuses
+it by name on a mesh (the exchange of the shares' partial sums is not
+written).
 """
 from __future__ import annotations
 

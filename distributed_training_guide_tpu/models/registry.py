@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional
 
-from . import gpt2, lfm2, llama, mimo_v2, mla, moe, neox
+from . import gpt2, laguna, lfm2, llama, mimo_v2, mla, moe, neox
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +55,7 @@ _HF_ALIASES = {
     "mistralai/mistral-small-4-119b-2603": "mistral-small-4-119b",
     "liquidai/lfm2-24b-a2b": "lfm2-24b-a2b",
     "xiaomimimo/mimo-v2.5": "mimo-v2.5",
+    "poolside/laguna-xs.2": "laguna-xs.2",
 }
 
 
@@ -62,7 +63,8 @@ def family_module(family: str):
     """The module implementing a model family (block/embed/head helpers used
     by the pipeline schedule and chunked losses)."""
     mods = {"llama": llama, "gpt2": gpt2, "moe": moe, "neox": neox,
-            "mla_moe": mla, "lfm2_moe": lfm2, "mimo_v2": mimo_v2}
+            "mla_moe": mla, "lfm2_moe": lfm2, "mimo_v2": mimo_v2,
+            "laguna": laguna}
     if family not in mods:
         raise KeyError(f"unknown model family {family!r}")
     return mods[family]
@@ -71,7 +73,8 @@ def family_module(family: str):
 def list_models() -> list[str]:
     return (sorted(gpt2.PRESETS) + sorted(llama.PRESETS) + sorted(moe.PRESETS)
             + sorted(neox.PRESETS) + sorted(mla.PRESETS)
-            + sorted(lfm2.PRESETS) + sorted(mimo_v2.PRESETS))
+            + sorted(lfm2.PRESETS) + sorted(mimo_v2.PRESETS)
+            + sorted(laguna.PRESETS))
 
 
 def get_model(name: str, **overrides) -> ModelBundle:
@@ -132,6 +135,13 @@ def get_model(name: str, **overrides) -> ModelBundle:
             config = dataclasses.replace(config, **overrides)
         return ModelBundle(key, config, mimo_v2.init, mimo_v2.apply,
                            mimo_v2.param_logical_axes, family="mimo_v2")
+    if key in laguna.PRESETS:
+        config = laguna.PRESETS[key]
+        if overrides:
+            config = dataclasses.replace(config, **overrides)
+        return ModelBundle(key, config, laguna.init, laguna.apply,
+                           laguna.param_logical_axes, family="laguna",
+                           apply_with_aux=laguna.apply_with_aux)
     raise ValueError(
         f"Unknown model {name!r}. Available: {', '.join(list_models())} "
         f"(HF aliases: {', '.join(sorted(_HF_ALIASES))})"

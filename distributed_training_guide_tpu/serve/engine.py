@@ -893,6 +893,7 @@ class ModelPrograms:
         self.config = bundle.config
         self.mod = family_module(bundle.family)
         refuse_for_family(self.mod, bundle.family, {
+            "serving": True,    # a train-only family lists it (laguna)
             "kv_dtype='int8'": str(kv_dtype).lower() == "int8",
             "weight_dtype='int8'": str(weight_dtype).lower() == "int8",
             "max_adapters": max_adapters is not None,

@@ -551,6 +551,16 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                             or (host_state["global_step"] + 1)
                             % args.log_freq == 0):
                         drain_losses()
+                        # the step's integer counts (a sparse family's
+                        # moe_pairs_held, models/laguna.py TRAIN_METRICS) as
+                        # the span's statistics: the fence above has landed,
+                        # so reading them waits for nothing
+                        counts = {k: v for k, v in metrics.items()
+                                  if np.issubdtype(v.dtype, np.integer)}
+                        if counts:
+                            timers["step"].set_metadata(**{
+                                k: int(v) for k, v in
+                                jax.device_get(counts).items()})
 
                 host_state["global_step"] += 1
                 host_state["epoch_step"] += 1

@@ -254,6 +254,18 @@ class Trainer:
                     "moe_dispatch='ragged' under tensor parallelism is "
                     "not implemented (grouped GEMMs over mlp-sharded "
                     "expert weights); use moe_dispatch='dense' or tp=1")
+        if (getattr(self.bundle.config, "experts_held", None) is not None
+                and self.plan.mesh.size > 1):
+            # a held share is one chip's partial sum (models/moe.py
+            # _ragged_dispatch): across chips the partial sums of the
+            # shares would have to be exchanged, which no plan does yet
+            raise ValueError(
+                f"experts_held (models/moe.py: one chip's share of an "
+                f"expert-parallel layer) trains on one device; plan "
+                f"{self.plan.strategy!r} over {self.plan.mesh.size} devices "
+                f"would need the exchange of the shares' partial sums, "
+                f"which is not implemented: use make_plan('single') or a "
+                f"one-device mesh, or drop experts_held")
         if self.overlap_schedule:
             if self.plan.mesh.shape.get("pp", 1) > 1:
                 raise ValueError(
@@ -656,9 +668,10 @@ class Trainer:
                         logits_sharding=logits_sharding)
 
         # every loss branch returns (loss, extras) where extras is a dict of
-        # auxiliary scalar metrics with the static key set ``extra_keys``
+        # auxiliary scalar metrics with the static key set ``extra_reduce``
+        # (name -> how microbatches join it: "mean", "sum" or "max")
         grad_fn = None
-        extra_keys: tuple = ()
+        extra_reduce: dict = {}
         if self.plan.mesh.shape["pp"] > 1:
             from ..parallel.pipeline import make_pipeline_value_and_grad
 
@@ -675,7 +688,12 @@ class Trainer:
         elif self.bundle.apply_with_aux is not None:
             apply_aux = self.bundle.apply_with_aux
             aux_coef = getattr(cfg, "router_aux_coef", 0.0)
-            extra_keys = ("moe_dropped_frac",)
+            from ..models.registry import family_module
+
+            # the family's extra metrics and how microbatches join them
+            extra_reduce = getattr(family_module(self.bundle.family),
+                                   "TRAIN_METRICS",
+                                   {"moe_dropped_frac": "mean"})
             # ragged dropless dispatch on a sharded mesh: the sorted-group
             # dispatch runs in a manual shard_map over the data axes (GSPMD
             # cannot partition the data-dependent sort the way it does the
@@ -766,18 +784,25 @@ class Trainer:
                         # over the data axes (reduce-scatter per microbatch)
                         grads_sum = jax.lax.with_sharding_constraint(
                             grads_sum, grad_sh)
-                    return (loss_sum + loss,
-                            jax.tree.map(jnp.add, extras_sum, extras),
-                            grads_sum), None
+                    extras_sum = {
+                        k: (jnp.maximum if extra_reduce[k] == "max"
+                            else jnp.add)(extras_sum[k], v)
+                        for k, v in extras.items()}
+                    return (loss_sum + loss, extras_sum, grads_sum), None
 
                 accum_dtype = self.precision.accum_dtype
                 zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, accum_dtype),
                                      params)
-                zero_extras = {k: jnp.zeros((), jnp.float32) for k in extra_keys}
+                zero_extras = {
+                    k: jnp.zeros((), jnp.float32 if how == "mean"
+                                 else jnp.int32)
+                    for k, how in extra_reduce.items()}
                 (loss_sum, extras, grads), _ = jax.lax.scan(
                     accum, (jnp.zeros((), jnp.float32), zero_extras, zeros), batch)
                 loss = loss_sum / self.grad_accum
-                extras = {k: v / self.grad_accum for k, v in extras.items()}
+                extras = {k: v / self.grad_accum
+                          if extra_reduce[k] == "mean" else v
+                          for k, v in extras.items()}
                 grads = jax.tree.map(lambda g: (g / self.grad_accum).astype(jnp.float32), grads)
             else:
                 (loss, extras), grads = grad_fn(params, batch)
@@ -795,7 +820,9 @@ class Trainer:
             metrics = {
                 "loss": loss.astype(jnp.float32),
                 "grad_norm": grad_norm,
-                **{k: v.astype(jnp.float32) for k, v in extras.items()},
+                # counts stay int32 (moe_pairs_held), fractions are float32
+                **{k: v if jnp.issubdtype(v.dtype, jnp.integer)
+                   else v.astype(jnp.float32) for k, v in extras.items()},
             }
             new_state = TrainState(step=state.step + 1, params=new_params,
                                    opt_state=new_opt, rng=state.rng)
@@ -811,7 +838,7 @@ class Trainer:
                            "grad_norm": self.plan.replicated(),
                            **({"notfinite": self.plan.replicated()}
                               if self.guard_policy != "off" else {}),
-                           **{k: self.plan.replicated() for k in extra_keys}}
+                           **{k: self.plan.replicated() for k in extra_reduce}}
         offloading = self.offload_params or self.offload_opt_state
         jitted = jax.jit(
             train_step,

@@ -89,6 +89,11 @@ class LocalTimer:
             self._span = None
         self.start_time = None
 
+    def set_metadata(self, **stats) -> None:
+        """Statistics known only inside the phase, onto its open span."""
+        if self._span is not None:
+            self._span.set_metadata(**stats)
+
     def avg_elapsed_ms(self) -> float:
         if not self.measurements:
             return 0.0

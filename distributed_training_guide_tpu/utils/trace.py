@@ -78,14 +78,17 @@ SCOPES = (
 # family is the operator sublayer whatever its kind: LFM2's gated short
 # convolution, its norm and projections included) and `attend_full` /
 # `attend_window` (in `attend`: the read half of a two-class family's full
-# and window layers, `serve/kv_pages.py`) and `head_gather` (in `loss_head`:
+# and window layers, `serve/kv_pages.py`), `attn_full` / `attn_window` (in
+# `attn`: the WHOLE attention sublayer of a full / a window layer of a family
+# whose layers differ in kind on the train path, `models/laguna.py`: norm,
+# projections, rope, the flash kernels, gate and output) and `head_gather` (in `loss_head`:
 # the ONE all-gather of a data-sharded output matrix around the chunked loss
 # and, as `transpose(jvp(loss_head))/.../head_gather`, the one reduce-scatter
 # of its gradient, `ops/cross_entropy.py`; a plan that leaves the loss to
 # GSPMD has no such events). A reader that knows only SCOPES counts their
 # time under the parent; `readers/path_component.py` reads one alone
 SUBSCOPES = ("latent_proj", "shared_expert", "conv", "attend_full",
-             "attend_window", "head_gather")
+             "attend_window", "head_gather", "attn_full", "attn_window")
 
 # pallas_call names (ops/)
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",

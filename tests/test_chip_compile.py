@@ -874,3 +874,63 @@ def test_fsdp_heads_reduce_scatter_runs_before_the_layers_backward(
               ("reduce-scatter", "head_gather/reduce_scatter"),
               ("while", "transpose(jvp(layers))/while"))}
     assert list(at.values()) == sorted(at.values()), at
+
+
+def test_sparse_train_cells_step_compiles_at_the_cells_size(
+        topo, chip_compile, compiled_kernels, monkeypatch):
+    """``laguna-xs.2-ep8-l5.train.seq8192``'s whole train step, built from the
+    cell's own files as its runner builds it (``single`` plan, AdamW, fp32
+    parameters, remat ``all``, 16 loss chunks, batch 2 x 8192), for one
+    described chip: the 691,623,936 parameters held with their two moments
+    (arguments, 7.73 GiB) and the temporaries beside them (5.29) fit the
+    chip's 15.75 GiB. Kernel calls, by the reckoning: a layer's attention is
+    ``flash_fwd`` in the forward, ``flash_fwd`` again under remat,
+    ``flash_dq`` and ``flash_dkv`` in the backward, 5 layers; a sparse
+    layer's experts are 3 ``gmm`` in the forward, 3 rematted, 3 against the
+    transposed matrices and 3 ``tgmm`` in the backward, 4 layers: 36 and 12.
+    No fp32 expert leaf (a parameter or a moment, 134 MB each) is copied:
+    every ``copy`` of a layer's ``[32, 2048, 512]`` is of the bf16 cast the
+    kernels read (6 a sparse layer: the backward's three transposed stacks
+    and three relaid for ``tgmm``'s results)."""
+    import json
+    from pathlib import Path
+
+    from benchmarks import harness
+    from benchmarks.runners import _laguna
+    from distributed_training_guide_tpu.ops import flash_attention as fa
+    from distributed_training_guide_tpu.parallel import make_mesh, make_plan
+    from distributed_training_guide_tpu.train import Trainer
+    from distributed_training_guide_tpu.train.optimizer import OPTIMIZERS
+    from distributed_training_guide_tpu.train.step import lower_step
+
+    monkeypatch.setattr(fa, "resolve_interpret", lambda i: False)
+    root = Path(__file__).resolve().parents[1]
+    cell = harness.load_cell(json.loads((root / "BENCHMARK.json").read_text()),
+                             "laguna-xs.2-ep8-l5.train.seq8192")
+    cfg, job, mix = cell["config_data"], cell["job"], cell["traffic_data"]
+    opt = dict(job["optimizer"])
+    trainer = Trainer(
+        bundle=_laguna.bundle_for(cfg, cell["config"]),
+        optimizer=OPTIMIZERS[opt.pop("name")](opt.pop("lr"), **opt),
+        plan=make_plan(job["plan"]["strategy"],
+                       make_mesh(devices=topo.devices[:1])),
+        remat=job["remat"], remat_policy=job["remat_policy"],
+        loss_chunks=job["loss_chunks"], attn_impl="flash",
+        precision=job["precision"])
+    lowered, _ = lower_step(trainer, global_batch=mix["global_batch"],
+                            seq_length=mix["seq_len"])
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    held = 691_623_936
+    assert memory.argument_size_in_bytes >= 12 * held
+    gib = (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2**30
+    assert gib < 15.75, gib
+    text = compiled.as_text()
+    calls = kernel_calls(text)
+    count = lambda name: sum(named(x, name) for x in calls)
+    assert (count("gmm"), count("tgmm")) == (36, 12), calls
+    assert (count("flash_fwd"), count("flash_dq"), count("flash_dkv")) == (
+        10, 5, 5), calls
+    copies = re.findall(r"= (\w+)\[32,(?:2048,512|512,2048)\]\S* copy\(",
+                        text)
+    assert set(copies) <= {"bf16"} and len(copies) <= 6 * 4, copies

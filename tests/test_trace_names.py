@@ -416,6 +416,29 @@ def test_moe_step_carries_router_and_experts():
     assert {"router", "experts", "attn", "layers", "loss_head"} <= found
 
 
+def test_sparse_walked_step_carries_a_scope_a_layer_kind():
+    """``models/laguna.py``: the attention sublayer of a full / a window
+    layer lies under ``attn_full`` / ``attn_window`` INSIDE ``attn``, the
+    dense layer's FFN under ``mlp``, a sparse layer's under ``experts`` with
+    ``router`` and ``shared_expert`` inside, forward and backward."""
+    plan = make_plan("single", make_mesh(devices=jax.devices()[:1]))
+    trainer = Trainer(bundle=get_model("laguna-debug"), remat=True,
+                      optimizer=optax.adamw(1e-3), plan=plan, loss_chunks=4)
+    lowered, _ = lower_step(trainer, global_batch=2, seq_length=32)
+    text = lowered.as_text(debug_info=True)
+    assert {"attn", "attn_full", "attn_window", "mlp", "experts", "router",
+            "shared_expert", "layers", "loss_head", "optimizer"} \
+        <= scope_components(text)
+    fragments = re.findall(r'loc\("([^"]+)"', text)
+    for kind in ("attn_full", "attn_window"):
+        inside = [f for f in fragments if f"/{kind}/" in f or
+                  f"({kind})" in f]
+        assert inside and all("attn" in scope_components(f'loc("{f}"')
+                              for f in inside), kind
+        assert any("transpose(" in f for f in inside), kind   # the backward
+    assert {"attn_full", "attn_window"} <= set(SUBSCOPES)
+
+
 @pytest.mark.parametrize("family", ["gpt2-debug", "neox-debug"])
 def test_other_families_carry_the_shared_scopes(family):
     bundle = get_model(family)
@@ -490,7 +513,7 @@ def test_latent_family_decode_carries_its_subscopes_and_kernel():
     assert any(f.startswith("attn/latent_proj/") for f in fragments)
     assert "paged_latent_attend" in KERNELS and set(SUBSCOPES) == {
         "latent_proj", "shared_expert", "conv", "attend_full",
-        "attend_window", "head_gather"}
+        "attend_window", "head_gather", "attn_full", "attn_window"}
 
 
 def test_named_gives_jit_the_name():
