@@ -46,7 +46,7 @@ import jax.numpy as jnp
 
 from . import llama
 from .llama import _rmsnorm, mlp_sublayer
-from .moe import _moe_ffn, experts_held
+from .moe import _moe_ffn, experts_held, rows_walked
 from ..ops.attention import multihead_attention
 from ..ops.rope import apply_rope, freeze_rope_scaling
 
@@ -63,9 +63,12 @@ SERVE_REFUSES = {
 }
 
 # the train step's extra metrics and how grad accumulation joins them over
-# microbatches (train/step.py): int32 counts from _moe_ffn's return_counts
+# microbatches (train/step.py): int32 counts from _moe_ffn's return_counts,
+# and the sorted rows the sparse layers' dispatches walked (moe.rows_walked:
+# walked / held is about 2 while the held pairs fit the compact prefix, and
+# a layer that overflowed it adds its whole k T)
 TRAIN_METRICS = {"moe_pairs_routed": "sum", "moe_pairs_held": "sum",
-                 "moe_fullest_expert_rows": "max"}
+                 "moe_fullest_expert_rows": "max", "moe_rows_walked": "sum"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -371,11 +374,13 @@ def apply_with_aux(
     aux = jnp.zeros((), jnp.float32)
     if not return_metrics:
         return out, aux
-    counts = jnp.stack(counts) if counts else jnp.zeros((1, 4), jnp.int32)
+    counts = jnp.stack(counts) if counts else jnp.zeros((0, 4), jnp.int32)
     return out, aux, {
         "moe_pairs_routed": jnp.sum(counts[:, 0]),
         "moe_pairs_held": jnp.sum(counts[:, 1]),
-        "moe_fullest_expert_rows": jnp.max(counts[:, 3])}
+        "moe_fullest_expert_rows": jnp.max(counts[:, 3], initial=0),
+        "moe_rows_walked": jnp.sum(
+            rows_walked(config, input_ids.size, counts[:, 1]))}
 
 
 def apply(config, params, input_ids, positions=None, **kw):

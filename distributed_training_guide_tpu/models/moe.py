@@ -43,7 +43,15 @@ old worst-case O(E*k*t*d) capacity buffers.
 
 ``config.experts_held = (first, count)`` (one chip's share of an
 expert-parallel layer; :func:`experts_held`): the router keeps its width and
-the local ragged dispatch leaves out the pairs of absent experts. The share
+the local ragged dispatch leaves out the pairs of absent experts. The held
+pairs sort to the front, so the dispatch WALKS A STATIC PREFIX of the sorted
+order and not all ``k T`` pairs: :func:`compact_rows` = twice the even share
+``k T count / num_experts``, to a whole ``grouped_matmul`` row tile. It
+gathers that many rows, multiplies them and adds them into ``[T, D]`` at
+their tokens (a scatter-add of that many rows). A routing that holds more
+pairs than the prefix takes the full-width walk in that step
+(:func:`rows_walked` is the rule and what a step's ``moe_rows_walked``
+counts), so no held pair is ever dropped. The share
 TRAINS as well as serves: ``_moe_ffn`` is differentiable over it (``gmm``
 forward, ``gmm`` against the transposed matrices and ``tgmm`` backward, the
 expert leaves' gradients the uncut gradient's slices), ``models/laguna.py``
@@ -313,34 +321,86 @@ def experts_held(config) -> tuple[int, int]:
     return (0, config.num_experts) if held is None else tuple(held)
 
 
-def _ragged_sort(xt: jnp.ndarray, topk_idx, topk_probs, ex: int, k: int, cdt,
-                 first: int = 0):
-    """Flatten (token, choice) pairs choice-rank-major, sort by expert id
-    counted from expert ``first`` (mod ``ex``), so the experts of a held
-    share ``first .. first + count`` come first in the sorted buffer.
-    Returns (order, group_sizes, x_sorted [kT, D], weight_flat [kT]);
-    ``group_sizes[j]`` is expert ``(first + j) % ex``'s.
+# the compact dispatch's buffer: this many times the even share of the pairs,
+# rounded up to grouped_matmul's row tile (its ``block_rows``)
+_COMPACT_ROOM = 2
+_ROW_TILE = 512
 
-    Pair i is token (i mod t): sorted rows gather straight from xt — row
-    movement is gather-only, like the dense path; the one int32 scatter
-    lives in ``_ragged_combine``'s permutation inversion."""
-    t = xt.shape[0]
+
+def compact_rows(config, t: int) -> int:
+    """Sorted pairs the local ragged dispatch walks for ``t`` tokens while the
+    held ones fit: ``k t`` (all of them) unless a held share makes twice its
+    even share of the pairs, to a whole row tile, fewer. Static: shapes and
+    ``experts_held`` decide it."""
+    m = config.experts_per_token * t
+    share = _COMPACT_ROOM * m * experts_held(config)[1] / config.num_experts
+    return min(m, _ROW_TILE * math.ceil(share / _ROW_TILE))
+
+
+def rows_walked(config, t: int, pairs_held) -> jnp.ndarray:
+    """Rows one routed layer's dispatch gathers, multiplies and combines for
+    ``t`` tokens of which ``pairs_held`` (token, expert) pairs are held here:
+    :func:`compact_rows` where they fit it, else all ``k t``. THE rule: the
+    dispatch's ``cond`` reads its predicate from it and
+    ``models/laguna.py`` sums it into the step's ``moe_rows_walked``."""
+    rows = compact_rows(config, t)
+    return jnp.where(pairs_held <= rows, rows,
+                     config.experts_per_token * t).astype(jnp.int32)
+
+
+def _ragged_order(topk_idx, topk_probs, ex: int, k: int, first: int = 0):
+    """Flatten (token, choice) pairs choice-rank-major and sort them by
+    expert id counted from expert ``first`` (mod ``ex``), so the experts of a
+    held share ``first .. first + count`` come FIRST in the sorted order.
+    Returns (order [kT], group_sizes [ex], weight_flat [kT]);
+    ``group_sizes[j]`` is expert ``(first + j) % ex``'s. Integers and the
+    weights only: no row moves here."""
+    t = topk_idx.shape[0]
     expert_flat = topk_idx.T.reshape(k * t)                      # [kT]
     if first:
         expert_flat = (expert_flat - first) % ex
     weight_flat = topk_probs.T.reshape(k * t)
     order = jnp.argsort(expert_flat, stable=True)
     group_sizes = jnp.bincount(expert_flat, length=ex).astype(jnp.int32)
-    x_sorted = xt[order % t].astype(cdt)                         # [kT, D]
+    return order, group_sizes, weight_flat
+
+
+def _ragged_sort(xt: jnp.ndarray, topk_idx, topk_probs, ex: int, k: int, cdt,
+                 first: int = 0):
+    """:func:`_ragged_order` and the FULL-WIDTH row buffer: returns (order,
+    group_sizes, x_sorted [kT, D], weight_flat [kT]). What the sharded
+    exchange (``make_ragged_ep_dispatch``) sorts with; the local dispatch
+    gathers its own rows, a prefix of them where a share is held
+    (``_ragged_dispatch``).
+
+    Pair i is token (i mod t): sorted rows gather straight from xt. On this
+    full-width path row movement is gather-only, like the dense path; the
+    one int32 scatter lives in ``_ragged_combine``'s permutation inversion."""
+    order, group_sizes, weight_flat = _ragged_order(topk_idx, topk_probs,
+                                                    ex, k, first)
+    x_sorted = xt[order % xt.shape[0]].astype(cdt)               # [kT, D]
     return order, group_sizes, x_sorted, weight_flat
 
 
 def _ragged_combine(out_sorted: jnp.ndarray, order, weight_flat,
                     k: int, t: int, cdt) -> jnp.ndarray:
-    """Unsort (int32 inversion scatter + row gather), weight, and combine
-    the k contributions of each token (adjacent in the choice-rank-major
-    layout — a reshape and a dense sum, no scatter-add). -> [t, D]."""
+    """Weight the sorted output rows and sum each token's -> [t, D].
+
+    ``out_sorted`` ``[kT, D]`` (every pair): unsort (int32 inversion scatter
+    + row gather), weight, and combine the k contributions of each token
+    (adjacent in the choice-rank-major layout: a reshape and a dense sum, no
+    scatter-add). ``out_sorted`` ``[rows, D]`` with ``rows < kT`` (the
+    compact dispatch: the first ``rows`` sorted pairs): ADD row i, weighted,
+    into the output at pair ``order[i]``'s token, a scatter-add of ``rows``
+    rows whose transpose is a gather of ``rows`` rows; rows past the held
+    pairs are zero (``grouped_matmul``'s contract) and add nothing. Neither
+    the ``[kT, D]`` buffers nor the ``[kT]`` inversion exist on that path."""
     m, d = k * t, out_sorted.shape[1]
+    rows = out_sorted.shape[0]
+    if rows < m:
+        head = order[:rows]
+        weighted = out_sorted * weight_flat[head][:, None].astype(cdt)
+        return jnp.zeros((t, d), weighted.dtype).at[head % t].add(weighted)
     inv = (jnp.zeros((m,), jnp.int32)
            .at[order].set(jnp.arange(m, dtype=jnp.int32)))
     y_choice = out_sorted[inv]                                   # pair order
@@ -348,12 +408,37 @@ def _ragged_combine(out_sorted: jnp.ndarray, order, weight_flat,
                    .reshape(k, t, d), axis=0)
 
 
+def _walk(order, group_sizes, xt, weight_flat, gate, up, down, layer_index,
+          *, rows: int, k: int, held: int, cdt):
+    """The first ``rows`` sorted pairs: gather their rows, multiply, combine
+    -> ``(y [t, D], the held group sizes)``. (The sizes are cut here, after
+    the gather, and handed back, so a dispatch that walks every pair lowers
+    to the text it always had.)"""
+    t = xt.shape[0]
+    x_sorted = xt[order[:rows] % t].astype(cdt)                  # [rows, D]
+    sizes = group_sizes[:held]
+    out_sorted = _ragged_expert_compute(
+        x_sorted, gate, up, down, sizes, cdt,
+        None if layer_index is None else layer_index * held)
+    return _ragged_combine(out_sorted, order, weight_flat, k, t, cdt), sizes
+
+
+# ONE trace and one lowering for every call of a shape: a step holds both
+# branches of the dispatch's cond in every sparse layer, forward, rematted
+# and transposed, and tracing each afresh (24 Pallas calls a layer) doubled
+# the step's lowering time; a family's sparse layers call it with the same
+# shapes. What the trace reads outside its arguments (grouped_matmul's
+# choice of implementation for the backend) is fixed at the first call of a
+# shape: a test that steers that choice clears it (``_walk_jit.clear_cache()``)
+_walk_jit = jax.jit(_walk, static_argnames=("rows", "k", "held", "cdt"))
+
+
 def _ragged_dispatch(config: MoELlamaConfig, xt: jnp.ndarray, topk_idx,
                      topk_probs, moe: dict, cdt,
                      layer_index=None) -> jnp.ndarray:
     """Dropless sorted dispatch (single-shard form): sort (token, choice)
-    pairs by expert id, run the experts as grouped GEMMs over the sorted
-    [kT, D] buffer, unsort, weight, combine. No capacity buffers, no drops;
+    pairs by expert id, gather their rows, run the experts as grouped GEMMs
+    over the sorted buffer, weight, combine. No capacity buffers, no drops;
     transients are O(k*T*D) — at decode (t == 1..few) that is O(t*k*d) vs
     the dense no_drop path's O(E*k*t*d) worst-case buffers.
 
@@ -362,20 +447,36 @@ def _ragged_dispatch(config: MoELlamaConfig, xt: jnp.ndarray, topk_idx,
     alone, and the pairs of absent experts lie past ``sum(group_sizes)``,
     where the grouped matmul returns zeros: their part of the sum is left
     out, which is one chip's output before an expert-parallel exchange.
+    Such a dispatch walks the first :func:`compact_rows` sorted pairs only
+    (static; twice the even share of the pairs, to a row tile) where that
+    is fewer than ``k T``: the gather, the three grouped products and the
+    combine (a scatter-add, ``_ragged_combine``) move that many rows. A
+    routing that holds MORE pairs than that (every choice of a token may be
+    a held expert) walks all ``k T`` in that step, chosen by ``lax.cond`` on
+    :func:`rows_walked`, so the result is exact whatever the routing; that
+    full-width branch is its own ``jax.checkpoint``, so a step that does
+    not take it writes none of its ``[kT, .]`` residuals.
     ``layer_index``: the expert leaves are every layer's, stacked ``[L *
     held, K, N]``, and this layer's begin at ``layer_index * held``.
     Returns ``(y, group_sizes)``."""
     t = xt.shape[0]
     ex, k = config.num_experts, config.experts_per_token
     first, held = experts_held(config)
-    order, group_sizes, x_sorted, weight_flat = _ragged_sort(
-        xt, topk_idx, topk_probs, ex, k, cdt, first)
-    group_sizes = group_sizes[:held]
-    out_sorted = _ragged_expert_compute(
-        x_sorted, moe["gate"], moe["up"], moe["down"], group_sizes, cdt,
-        None if layer_index is None else layer_index * held)
-    return (_ragged_combine(out_sorted, order, weight_flat, k, t, cdt),
-            group_sizes)
+    order, group_sizes, weight_flat = _ragged_order(topk_idx, topk_probs,
+                                                    ex, k, first)
+    # the leaves in the compute dtype BEFORE the cond: both branches keep
+    # the same three stacks for their backward, so they pass the cond as
+    # its operands and neither branch writes a copy (or zeros) of them
+    operands = (order, group_sizes, xt, weight_flat,
+                *(moe[n].astype(cdt) for n in EXPERT_LEAVES), layer_index)
+    rows = compact_rows(config, t)
+    static = dict(k=k, held=held, cdt=cdt)
+    if rows == k * t:     # every pair is walked: the one program there is
+        return _walk(*operands, rows=rows, **static)
+    fits = rows_walked(config, t, jnp.sum(group_sizes[:held])) == rows
+    return jax.lax.cond(
+        fits, partial(_walk_jit, rows=rows, **static),
+        jax.checkpoint(partial(_walk_jit, rows=k * t, **static)), *operands)
 
 
 @jax.named_scope("experts")
@@ -412,6 +513,8 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
     - ``"ragged"``: dropless sorted dispatch + grouped GEMMs over the
       [kT, D] sorted buffer (MegaBlocks, arXiv:2211.15841) — no padding
       compute, no capacity/quality trade, ``dropped_frac`` identically 0.
+      A held share walks a static prefix of that buffer
+      (``_ragged_dispatch``, :func:`compact_rows`).
 
     ``no_drop`` (the decode path) always runs ragged: it is dropless by
     construction at O(t*k*d) transients, where the old dense no_drop

@@ -481,12 +481,17 @@ def compiled_kernels(monkeypatch):
     topology ``jax.default_backend()`` is the CPU, so ``interpret=None`` and
     ``impl="auto"`` would pick the interpreter and the scan; the test steers
     them here, not the program."""
+    from distributed_training_guide_tpu.models import moe
     from distributed_training_guide_tpu.ops import paged_decode
 
     monkeypatch.setattr(paged_decode, "resolve_interpret", lambda i: False)
     monkeypatch.setattr(gmm_mod, "resolve_interpret", lambda i: False)
     monkeypatch.setattr(gmm_mod, "_resolve_impl",
                         lambda impl: "pallas" if impl == "auto" else impl)
+    # the dispatch's walk is traced once a shape, whatever it resolved then
+    moe._walk_jit.clear_cache()
+    yield
+    moe._walk_jit.clear_cache()
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk2048"])
@@ -545,7 +550,9 @@ def test_latent_familys_serve_programs_compile_at_the_cells_size(
                             donate=(0, 1))
         calls = kernel_calls(text)
         assert not any(named(x, "paged_latent_attend") for x in calls)
-        assert sum(named(x, "gmm") for x in calls) == 3
+        # 8,192 pairs against a prefix of 4,096 (moe.compact_rows): the
+        # compact walk's three and its full-width overflow branch's three
+        assert sum(named(x, "gmm") for x in calls) == 2 * 3
     assert_pools_carried_in_place(text, *(shape for shape, _ in pools.values()))
     assert_experts_read_in_place(
         text, *(params["layers"]["moe"][leaf].shape
@@ -682,7 +689,10 @@ def test_two_class_familys_serve_programs_compile_at_the_cells_size(
         ((slots, 2 * c["columns"]), jnp.int32), *weights,
         donate=(0, 1, 2, 3))
     calls = kernel_calls(text)
-    assert sum(named(x, "gmm") for x in calls) == 3 * 6, calls
+    # a chunk's 16,384 pairs a layer against a prefix of 2,048
+    # (moe.compact_rows): the compact walk and its overflow branch
+    walks = 1 if program == "decode" else 2
+    assert sum(named(x, "gmm") for x in calls) == walks * 3 * 6, calls
     assert sum(named(x, "paged_attend") for x in calls) == 7, calls
     sized = pool_sized_ops(text, *(pools[n].shape for n in names), names=True)
     assert sum(x.startswith("scatter ") for x in sized) >= 3 * 7, sized
@@ -882,16 +892,19 @@ def test_sparse_train_cells_step_compiles_at_the_cells_size(
     cell's own files as its runner builds it (``single`` plan, AdamW, fp32
     parameters, remat ``all``, 16 loss chunks, batch 2 x 8192), for one
     described chip: the 691,623,936 parameters held with their two moments
-    (arguments, 7.73 GiB) and the temporaries beside them (5.29) fit the
+    (arguments, 7.73 GiB) and the temporaries beside them (5.90) fit the
     chip's 15.75 GiB. Kernel calls, by the reckoning: a layer's attention is
     ``flash_fwd`` in the forward, ``flash_fwd`` again under remat,
     ``flash_dq`` and ``flash_dkv`` in the backward, 5 layers; a sparse
     layer's experts are 3 ``gmm`` in the forward, 3 rematted, 3 against the
-    transposed matrices and 3 ``tgmm`` in the backward, 4 layers: 36 and 12.
-    No fp32 expert leaf (a parameter or a moment, 134 MB each) is copied:
-    every ``copy`` of a layer's ``[32, 2048, 512]`` is of the bf16 cast the
-    kernels read (6 a sparse layer: the backward's three transposed stacks
-    and three relaid for ``tgmm``'s results)."""
+    transposed matrices and 3 ``tgmm`` in the backward, 4 layers, ONCE FOR
+    EACH BRANCH of the dispatch's ``cond`` (``moe._ragged_dispatch``: the
+    compact walk of 32,768 sorted rows with its scatter-add into ``[16384,
+    2048]``, and the full-width walk of 131,072 that an overflow takes; a
+    step runs one of them): 72 and 24. No fp32 expert leaf (a parameter or a
+    moment, 134 MB each) is copied: every ``copy`` of a layer's ``[32, 2048,
+    512]`` is of the bf16 cast the kernels read (at most 4 in a branch of a
+    sparse layer's backward, 7 in both)."""
     import json
     from pathlib import Path
 
@@ -928,9 +941,10 @@ def test_sparse_train_cells_step_compiles_at_the_cells_size(
     text = compiled.as_text()
     calls = kernel_calls(text)
     count = lambda name: sum(named(x, name) for x in calls)
-    assert (count("gmm"), count("tgmm")) == (36, 12), calls
+    assert (count("gmm"), count("tgmm")) == (2 * 36, 2 * 12), calls
     assert (count("flash_fwd"), count("flash_dq"), count("flash_dkv")) == (
         10, 5, 5), calls
     copies = re.findall(r"= (\w+)\[32,(?:2048,512|512,2048)\]\S* copy\(",
                         text)
-    assert set(copies) <= {"bf16"} and len(copies) <= 6 * 4, copies
+    assert set(copies) <= {"bf16"} and len(copies) <= 7 * 4, copies
+    assert len(re.findall(r" conditional\(", text)) == 3 * 4
