@@ -72,7 +72,8 @@ from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        adapter_nbytes, adapter_pool_bytes, adapter_shapes,
                        init_adapter_stacks, validate_adapter_params)
 from .kv_pages import (resolve_attend_for, copy_pages, init_pages,
-                       kv_dtype_name, kv_page_bytes, make_attend, state_layout,
+                       kv_dtype_name, kv_page_bytes, make_attend,
+                      sequence_state_layout, state_layout,
                        PagePool, pages_for_tokens, pool_nbytes, TRASH_PAGE,
                        window_layout, window_pages_bound)
 from .scheduler import Admission, Request, RequestResult, Scheduler
@@ -1337,12 +1338,14 @@ class ModelPrograms:
 
     # ---- state placement ---------------------------------------------------
     def init_device_pages(self, n_pages: int, page_size: int,
-                          n_window_pages: Optional[int] = None) -> dict:
+                          n_window_pages: Optional[int] = None,
+                          n_state_blocks: Optional[int] = None) -> dict:
         """Zeroed pools placed per the serve sharding rules (kv-head
         split under shard_kv, replicated under a plain plan)."""
         pages = init_pages(self.config, n_pages, page_size,
                            kv_dtype=self.kv_dtype,
-                           n_window_pages=n_window_pages)
+                           n_window_pages=n_window_pages,
+                           n_state_blocks=n_state_blocks)
         if self.shard_kv:
             return jax.device_put(pages, self._pool_shardings())
         if self.plan is not None:
@@ -1365,7 +1368,10 @@ class ModelPrograms:
 
             return make_sharded_attend(self.mesh, tables, lengths,
                                        impl=impl, n_valid=n_valid)
-        return make_attend(tables, lengths, impl=impl, n_valid=n_valid)
+        # a state class's block ids are the tables' last column
+        return make_attend(
+            tables, lengths, impl=impl, n_valid=n_valid,
+            state_class=sequence_state_layout(self.config) is not None)
 
     # ---- compiled programs -------------------------------------------------
     def _lora_ctx(self, lora_args) -> Optional[dict]:
@@ -1618,7 +1624,10 @@ class ServeEngine(DecodeArrays):
     engine: what the slots and one prefill chunk can hold at once
     (``kv_pages.window_pages_bound``), so no reservation can fail.
     ``prefix_cache`` then defaults to off (the family refuses it by name,
-    like a decode horizon).
+    like a decode horizon). A family with a STATE CLASS
+    (``kv_pages.sequence_state_layout``: a recurrent state addressed by
+    sequence) gets ``n_slots + 1`` blocks of it, one a slot and the trash
+    block, so admission never waits on the class.
 
     ``prefix_cache`` (default on): committed prompt pages register in a
     content-keyed cache so identical prefixes share physical pages across
@@ -1743,7 +1752,11 @@ class ServeEngine(DecodeArrays):
         second = window_layout(self.config)
         n_window_pages = None if second is None else window_pages_bound(
             second["window"], page_size, n_slots, self.prefill_chunk)
-        pool = PagePool(n_pages, page_size, n_window_pages)
+        # a family with a state class (kv_pages.sequence_state_layout): a
+        # block a slot, and block 0 for the slots that hold no sequence
+        n_state_blocks = (None if sequence_state_layout(self.config) is None
+                          else n_slots + 1)
+        pool = PagePool(n_pages, page_size, n_window_pages, n_state_blocks)
         self.scheduler = Scheduler(
             n_slots=n_slots, pool=pool, max_len=self.max_model_len,
             max_pages_per_slot=self.max_pages, prefix_cache=prefix_cache,
@@ -1757,8 +1770,8 @@ class ServeEngine(DecodeArrays):
             partial_page_hits=state_layout(self.config) is None,
             window=None if second is None else second["window"])
 
-        self.pages = self.programs.init_device_pages(n_pages, page_size,
-                                                     n_window_pages)
+        self.pages = self.programs.init_device_pages(
+            n_pages, page_size, n_window_pages, n_state_blocks)
 
         # host-RAM KV tier (serve/tiering.py): spilled prefix pages and
         # preempted sequences park here instead of being recomputed.
@@ -2249,6 +2262,7 @@ class ServeEngine(DecodeArrays):
             "prefilling_slots": len(sched.prefilling_indices()),
             "prefill_calls": self.programs.prefill_calls,
             "live_pages_by_class": sched.live_pages_by_class(),
+            "state_blocks_live": sched.live_state_blocks(),
             **({"routing": dict(self.programs.routing)}
                if self.programs.routing["steps"] else {}),
             # committed prefix keys for the router's fleet directory —

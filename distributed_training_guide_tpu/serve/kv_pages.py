@@ -121,6 +121,25 @@ there, and the gather path masks those positions by the same band.
 ``make_attend`` hands each layer the half its ``page_class`` names. A
 one-class family is the case without the second half: none of this runs.
 
+A family whose recurrent state is too large to keep a row a page
+(``models/solar_open2.py``: a ``heads x d x d`` float32 matrix a layer,
+megabytes a sequence) states a STATE CLASS in
+``config.sequence_state_layout()``, ADDRESSED BY SEQUENCE: the pool dict
+gains the leaves it names (:data:`SEQUENCE_LEAVES`), each ``[state layers,
+n_blocks, ...]``, a BLOCK a live sequence, with an id space of its own
+(block 0 the trash block, which idle slots carry). ``PagePool.state`` is its
+free list: the scheduler takes a block when it admits a sequence and returns
+it when the sequence leaves its slot (finished, expired or preempted: a
+preempted sequence is prefilled again), so ``n_slots + 1`` blocks always
+suffice. A slot's block id rides every program as ONE MORE COLUMN of its
+table row, the last (``make_attend(state_class=True)`` takes it off and hands
+it to the family as ``attend.state_blocks``). A block is not zeroed when it
+changes hands: a sequence that starts at position 0 reads zeros instead of it
+(the family's step, on ``attend.lengths == 0``). The state has no page
+identity, so what moves pages by id (``copy_pages``, the prefix cache, the
+host tier, the handoff, an engine swap) does not move it, and such a family
+refuses them by name.
+
 Device-side pieces (``paged_attend``, ``copy_pages``) are pure functions
 of array arguments — block tables and lengths arrive as int32 arrays, so
 requests coming and going never change a traced shape. The allocator
@@ -128,6 +147,7 @@ requests coming and going never change a traced shape. The allocator
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
@@ -234,6 +254,31 @@ def window_pages_bound(window: int, page_size: int, n_slots: int,
         return (window + n_tokens - 3) // page_size + 2     # can straddle
 
     return 1 + n_slots * most(1) + most(chunk)
+
+
+SEQUENCE_LEAVES = ("seq_state", "seq_conv")   # the state class's pools
+
+
+def sequence_state_layout(config) -> Optional[dict]:
+    """The state class a family states (``config.sequence_state_layout()``):
+    ``{leaf: (shape of one sequence's block, state layers first; "fp32" or
+    None for the pool's float dtype)}`` over :data:`SEQUENCE_LEAVES`, or None
+    for the families whose state, if any, rides the pages."""
+    layout = getattr(config, "sequence_state_layout", None)
+    return None if layout is None else layout()
+
+
+def sequence_state_bytes(config, n_blocks: int = 1, kv_dtype=None) -> int:
+    """Resident bytes of ``n_blocks`` blocks of the state class (0 where the
+    family has none): what ONE live sequence costs beside its pages at
+    ``n_blocks = 1``."""
+    layout = sequence_state_layout(config)
+    if layout is None:
+        return 0
+    name = kv_dtype_name(config, kv_dtype)
+    return n_blocks * sum(
+        math.prod(shape) * jnp.dtype(_state_dtype(storage or name)).itemsize
+        for shape, storage in layout.values())
 
 
 def kv_dtype_name(config, kv_dtype=None) -> str:
@@ -371,7 +416,8 @@ def kv_page_bytes(config, *, page_size: int, n_pages: int = 1,
 
 
 def init_pages(config, n_pages: int, page_size: int, kv_dtype=None,
-               n_window_pages: Optional[int] = None) -> dict:
+               n_window_pages: Optional[int] = None,
+               n_state_blocks: Optional[int] = None) -> dict:
     """Zeroed page pools {"k","v"}: STACKED [L, n_pages, page_size, heads,
     width] arrays, which every program takes, carries through its layer scan
     and returns whole (:func:`paged_attend`'s contract; page 0 of every
@@ -385,7 +431,9 @@ def init_pages(config, n_pages: int, page_size: int, kv_dtype=None,
     ``[state layers, n_pages, rows, width]``, in the pool's float dtype. A
     family with a second page class (:func:`window_layout`) gets ``"k_win"``
     and ``"v_win"`` ``[window layers, n_window_pages, page_size, heads,
-    width]``, float."""
+    width]``, float. A family with a state class
+    (:func:`sequence_state_layout`) gets its leaves ``[state layers,
+    n_state_blocks, ...]``."""
     name = kv_dtype_name(config, kv_dtype)
 
     parts = key_parts(config)
@@ -417,6 +465,14 @@ def init_pages(config, n_pages: int, page_size: int, kv_dtype=None,
             pages[leaf] = jnp.zeros(
                 (layers, n_window_pages, page_size, *second[key]),
                 _KV_FLOAT[name])
+    blocks = sequence_state_layout(config)
+    if blocks is not None:
+        if name == "int8" or n_state_blocks is None:
+            raise ValueError("a state class is stored in float and needs "
+                             "its block count (n_state_blocks)")
+        for leaf, ((layers, *shape), storage) in blocks.items():
+            pages[leaf] = jnp.zeros((layers, n_state_blocks, *shape),
+                                    _state_dtype(storage or name))
     return pages
 
 
@@ -435,11 +491,16 @@ class PagePool:
     """
 
     def __init__(self, n_pages: int, page_size: int,
-                 n_window_pages: Optional[int] = None):
+                 n_window_pages: Optional[int] = None,
+                 n_state_blocks: Optional[int] = None):
         # the second page class's free list (kv_pages.window_layout): the
         # same allocator over its own id space, or None
         self.window = (None if n_window_pages is None
                        else PagePool(n_window_pages, page_size))
+        # the state class's (kv_pages.sequence_state_layout): a block a
+        # sequence, ids 1..n_state_blocks-1 (block 0 the trash block), or None
+        self.state = (None if n_state_blocks is None
+                      else PagePool(n_state_blocks, 1))
         if n_pages < 2:
             raise ValueError(f"n_pages must be >= 2 (page {TRASH_PAGE} is "
                              f"the reserved trash page), got {n_pages}")
@@ -527,7 +588,7 @@ class PagePool:
 
 
 def pool_audit(pool: "PagePool", holder_maps, *, tier=None,
-               window_holder_maps=None) -> None:
+               window_holder_maps=None, state_holder_maps=None) -> None:
     """The per-iteration capacity identity, extended for the host tier.
 
     ``holder_maps``: iterables of ``{page: n_refs}`` — one map per
@@ -542,10 +603,13 @@ def pool_audit(pool: "PagePool", holder_maps, *, tier=None,
     sum(record bytes) <= budget``, ``spilled_pages == sum(record
     pages)``) audits separately via ``tier.audit()`` when one is
     attached. ``window_holder_maps``: the same maps for the pool's second
-    page class (``pool.window``), which is audited alike. Raises
-    ``AssertionError`` naming the first imbalance."""
+    page class (``pool.window``), which is audited alike, as are
+    ``state_holder_maps`` for the state class's blocks (``pool.state``).
+    Raises ``AssertionError`` naming the first imbalance."""
     if window_holder_maps is not None:
         pool_audit(pool.window, window_holder_maps)
+    if state_holder_maps is not None:
+        pool_audit(pool.state, state_holder_maps)
     held: dict = {}
     for m in holder_maps:
         for p, n in m.items():
@@ -821,14 +885,21 @@ def _attend_latent(q, k_pages, v_pages, layer, tables, lengths, t_idx, *,
     return attn, (k_pages, v_pages)
 
 
-def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None):
+def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None,
+                state_class: bool = False):
     """Bind (tables, lengths, impl, n_valid) into the attend callback the
     family ``paged_decode_step`` hooks call in every layer with the stacked
     pools and the layer's index (:func:`paged_attend`'s contract). A latent
     family adds ``latent_rope`` (and ``expand`` for a chunk) to its call. A
     two-class family names the ``page_class`` of the pools it hands over
     (``"full"`` / ``"window"``): ``tables`` is then ``[S, 2 M]`` and the call
-    takes its class's half (module docstring)."""
+    takes its class's half (module docstring). ``state_class``: the tables'
+    LAST column is each slot's block of the state class, taken off here and
+    handed to the family as ``attend.state_blocks`` beside ``attend.lengths``
+    and ``attend.n_valid``."""
+    blocks = None
+    if state_class:
+        tables, blocks = tables[:, :-1], tables[:, -1]
 
     def attend(q, k_new, v_new, k_pages, v_pages, layer, *, window=None,
                scale=None, softcap=None, page_class=None, **more):
@@ -847,6 +918,8 @@ def make_attend(tables, lengths, *, impl: str = "auto", n_valid=None):
     attend.read_state = partial(read_state, tables=tables, lengths=lengths)
     attend.write_state = partial(write_state, tables=tables, lengths=lengths,
                                  n_valid=n_valid)
+    attend.state_blocks, attend.lengths, attend.n_valid = (blocks, lengths,
+                                                           n_valid)
     return attend
 
 
@@ -900,16 +973,19 @@ def copy_pages(pools, src, dst, window=None):
     described the old content would dequantize garbage. The second page
     class's leaves (``WINDOW_LEAVES``) have ids of their own: ``window =
     (src, dst)`` forks one of ITS pages in the same call, and without it
-    they pass through untouched."""
+    they pass through untouched. The state class's leaves
+    (``SEQUENCE_LEAVES``) have no page identity and always pass through."""
 
     def fork(a, src=src, dst=dst):
         return a.at[:, dst].set(a[:, src])
 
-    if not (isinstance(pools, dict) and WINDOW_LEAVES[0] in pools):
+    apart = WINDOW_LEAVES + SEQUENCE_LEAVES
+    if not (isinstance(pools, dict) and any(n in pools for n in apart)):
         return jax.tree.map(fork, pools)
     out = jax.tree.map(fork, {name: leaf for name, leaf in pools.items()
-                              if name not in WINDOW_LEAVES})
-    for name in WINDOW_LEAVES:
-        out[name] = (pools[name] if window is None
-                     else fork(pools[name], *window))
+                              if name not in apart})
+    for name in apart:
+        if name in pools:
+            forks = name in WINDOW_LEAVES and window is not None
+            out[name] = fork(pools[name], *window) if forks else pools[name]
     return out

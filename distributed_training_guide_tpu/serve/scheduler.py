@@ -181,6 +181,9 @@ class _Slot:
     # physical page of the window layers' pool, only the pages the next query
     # can still see (and, while a chunk runs, the chunk's)
     window_pages: dict = dataclasses.field(default_factory=dict)
+    # the state class (kv_pages.sequence_state_layout): the sequence's block,
+    # held from admission until it leaves the slot (0: the family has none)
+    state_block: int = 0
 
     @property
     def replaying(self) -> bool:
@@ -499,6 +502,14 @@ class Scheduler:
                              "cache, horizons and speculation: its pages are "
                              "taken and returned between two single steps")
         self.window = window
+        # the state class (``pool.state``; kv_pages.sequence_state_layout): a
+        # block a sequence, whose id is the last column of its table row
+        if pool.state is not None and (self.cache is not None
+                                       or decode_horizon > 1
+                                       or spec_lookahead):
+            raise ValueError("a state class serves without the prefix cache, "
+                             "horizons and speculation: a block holds a "
+                             "sequence's newest state alone")
         # extra admission headroom beyond THIS scheduler's running decodes
         # — the disaggregated prefill scheduler has no decoding slots of
         # its own, so its engine threads the DECODE side's count through
@@ -553,6 +564,7 @@ class Scheduler:
                       "deadline_missed_running": 0,
                       "spec_lookahead_clamped": 0, "refused": {},
                       "window_pages_released": 0,
+                      "state_blocks_taken": 0, "state_blocks_returned": 0,
                       # requests submitted per adapter slot (keyed by
                       # adapter_id) — the per-tenant demand signal the
                       # router aggregates fleet-wide
@@ -737,10 +749,51 @@ class Scheduler:
         if not everything:
             self.stats["window_pages_released"] += len(dead)
 
+    # ---- the state class ---------------------------------------------------
+    def _take_state_block(self) -> int:
+        """A block of the state class for a sequence entering a slot (0 where
+        the family has none). The engine sizes the class at ``n_slots + 1``
+        blocks, so a free slot always finds one."""
+        if self.pool.state is None:
+            return 0
+        with span("serve.state", taken=1, returned=0) as sp:
+            got = self.pool.state.alloc(1)
+            sp.set_metadata(live=self.live_state_blocks())
+        if got is None:
+            raise RuntimeError(
+                f"state class exhausted: {self.pool.state.capacity} blocks "
+                f"for {self.n_slots} slots")
+        self.stats["state_blocks_taken"] += 1
+        return got[0]
+
+    def _return_state_block(self, slot: _Slot) -> None:
+        """The leaving slot's block back to the free list: host bookkeeping
+        alone, the block keeps its bytes and its next owner starts from
+        zeros (``attend.lengths == 0``)."""
+        if not slot.state_block:
+            return
+        with span("serve.state", taken=0, returned=1) as sp:
+            self.pool.state.free([slot.state_block])
+            sp.set_metadata(live=self.live_state_blocks())
+        slot.state_block = 0
+        self.stats["state_blocks_returned"] += 1
+
+    def live_state_blocks(self) -> int:
+        """Blocks of the state class that slots hold."""
+        state = self.pool.state
+        return 0 if state is None else state.capacity - state.n_free
+
+    def state_holders(self) -> dict:
+        """``{block: refs}`` of the state class, for ``pool_audit``."""
+        return {s.state_block: 1 for s in self.slots
+                if s is not None and s.state_block}       # never shared
+
     def _free_slot(self, slot: _Slot) -> None:
-        """Drop every page reference a leaving slot holds, of both classes."""
+        """Drop every page reference a leaving slot holds, of both classes,
+        and its block of the state class."""
         self.pool.free(slot.pages)
         self._release_window(slot, everything=True)
+        self._return_state_block(slot)
 
     def live_pages_by_class(self) -> dict:
         """Pages held by slots, a count a class."""
@@ -952,6 +1005,7 @@ class Scheduler:
             target_len=len(tokens), prefilling=True,
             shared_len=shared_len, resumed=bool(entry.generated),
             replay_pos=0, first_token_at=entry.first_token_at)
+        self.slots[slot_idx].state_block = self._take_state_block()
         self.stats["admitted"] += 1
         return Admission(
             slot_idx=slot_idx, request=req, tokens=tokens,
@@ -1292,6 +1346,9 @@ class Scheduler:
         a tier restore (serve/tiering.py) seats the sequence at the
         EXACT position its preemption recorded (the victim may itself
         have been mid-replay, so neither 0 nor the end is right)."""
+        if self.pool.state is not None:
+            raise ValueError("adopt seats page ids alone: a sequence's block "
+                             "of the state class does not come with them")
         slot_idx = next((i for i, s in enumerate(self.slots) if s is None),
                         None)
         if slot_idx is None:
@@ -1338,12 +1395,16 @@ class Scheduler:
             # window has passed (or has not reached) names the trash page
             for logical, phys in slot.window_pages.items():
                 row[self.max_pages + logical] = phys
+            if slot.state_block:    # the state class's block: the last column
+                row[-1] = slot.state_block
         return row
 
     @property
     def table_width(self) -> int:
-        """Columns of a slot's table row: one class's, or both classes'."""
-        return self.max_pages * (1 if self.window is None else 2)
+        """Columns of a slot's table row: one class's, or both classes', and
+        one more for the block of a state class."""
+        return (self.max_pages * (1 if self.window is None else 2)
+                + (self.pool.state is not None))
 
     def decode_tables(self) -> np.ndarray:
         """The block tables of the decoding set, ``decode_arrays()["tables"]``

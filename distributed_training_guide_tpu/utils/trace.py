@@ -44,6 +44,7 @@ SPANS = (
     "serve.fork", "serve.prefill", "serve.sample", "serve.draft",
     "serve.reserve", "serve.build", "serve.arrays", "serve.upload",
     "serve.dispatch", "serve.wait", "serve.book", "serve.release",
+    "serve.state",
     "data.assemble", "data.put",
     "train.data", "train.step", "train.fence", "train.log", "train.ckpt",
     "gc",
@@ -85,14 +86,20 @@ SCOPES = (
 # the ONE all-gather of a data-sharded output matrix around the chunked loss
 # and, as `transpose(jvp(loss_head))/.../head_gather`, the one reduce-scatter
 # of its gradient, `ops/cross_entropy.py`; a plan that leaves the loss to
-# GSPMD has no such events). A reader that knows only SCOPES counts their
-# time under the parent; `readers/path_component.py` reads one alone
+# GSPMD has no such events) and `kda` (in `attn`: a KDA layer's whole mixer,
+# `models/solar_open2.py`: norm, projections, the short convolutions, the
+# `kda_step` / `kda_chunk` recurrence, the gated read-out and `W_o`; the
+# state's writes are under `kv_write`). A reader that knows only SCOPES counts
+# their time under the parent; `readers/path_component.py` reads one alone
 SUBSCOPES = ("latent_proj", "shared_expert", "conv", "attend_full",
-             "attend_window", "head_gather", "attn_full", "attn_window")
+             "attend_window", "head_gather", "attn_full", "attn_window", "kda")
 
-# pallas_call names (ops/)
+# pallas_call names (ops/); `kda_step` and `kda_chunk` name the two forms of
+# the KDA recurrence whatever implements them (a Pallas kernel or a
+# `named_scope` around plain jnp: `ops/kda.py`)
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",
-           "paged_latent_attend", "gmm", "tgmm", "qmm")
+           "paged_latent_attend", "gmm", "tgmm", "qmm", "kda_step",
+           "kda_chunk")
 
 # jitted programs; a name ending in _k or _t takes the static size that
 # keys the program (serve_horizon_k4, serve_chunk_t64, serve_verify_t5 and

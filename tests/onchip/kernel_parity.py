@@ -68,6 +68,16 @@ page 128, 288 table columns a class, bf16), the decode step (contexts 5 to
 tokens, its query tokens in blocks), each against the gather path over the
 same rows, with one sabotage the bound must refuse (the sink left out).
 
+``--kda`` runs the state-class cell's two KDA computations alone
+(``solar-open2-ep8-l4.serve.gen192``; ``ops/kda.py``): the recurrent step's
+Pallas kernel at the cell's size (192 slots of 64 heads of 128 x 128, the
+pool of 3 layers x 193 blocks updated in place at layer 1, slots of which a
+few carry the trash block) against the gather / ``jnp`` / scatter path, the
+blocks no slot holds left as they were; the chunked scan of 2,048 tokens from
+a non-zero state with 1,900 of them real against the step applied token by
+token (64 heads of 128, decays down to 0.2 a step); and one sabotage the
+bound must refuse (the step's decay left out).
+
 One process; fails (no last line, exit 1) off the chip. Prints the entry
 points' start-up device line, one JSON line per case, and last
 ``{"kernel_parity_ok": true, "cases": N, "controls_refused": 5}``.
@@ -580,6 +590,75 @@ def mimo_cases() -> int:
     return int(caught)
 
 
+def kda_inputs(shape, low_rate=True):
+    """Rows of the recurrence at ``shape = (..., H)`` with 128-wide heads:
+    unit k, q of length 1 / sqrt(128), decays 0.2-0.999 a step, beta in
+    (0, 2)."""
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    d = 128
+    q = unit(normal((*shape, d), jnp.float32)) * d ** -0.5
+    k = unit(normal((*shape, d), jnp.float32))
+    v = normal((*shape, d), jnp.float32)
+    g = -jnp.asarray(RNG.uniform(0.001, 1.6, (*shape, d)), jnp.float32)
+    beta = 2 * jax.nn.sigmoid(normal(shape, jnp.float32))
+    return q, k, v, g, beta
+
+
+def kda_cases() -> int:
+    from distributed_training_guide_tpu.ops import kda
+
+    slots, heads, layers, blocks = 192, 64, 3, 193
+    pool = normal((layers, blocks, heads, 128, 128), jnp.float32)
+    ids = RNG.permutation(np.arange(1, blocks)).astype(np.int32)
+    ids[[5, 77, 190]] = kda.TRASH_BLOCK
+    ids = jnp.asarray(ids)
+    rows = kda_inputs((slots, heads))
+
+    def step(impl, rows=rows):
+        return jax.jit(lambda pool: kda.kda_step(pool, ids, 1, *rows,
+                                                 impl=impl))(pool)
+
+    (o, new), (o_ref, new_ref) = step("pallas"), step("xla")
+    # the slots that hold a sequence: the trash block is read and written by
+    # every idle slot in turn, and what they read out means nothing
+    held = np.flatnonzero(np.asarray(ids) != kda.TRASH_BLOCK)
+    live = np.asarray(ids)[held]
+    idle = np.setdiff1d(np.arange(1, blocks), live)
+    case("kda_step", {"o": (o[held], o_ref[held]),
+                      "state": (new[1, live], new_ref[1, live])},
+         slots=slots, heads=heads, blocks=blocks)
+    untouched = bool(jnp.array_equal(new[1, idle], pool[1, idle])
+                     and jnp.array_equal(new[0], pool[0])
+                     and jnp.array_equal(new[2], pool[2]))
+    case("kda_step_leaves_other_blocks", {"same": (
+        jnp.asarray(float(untouched)), jnp.asarray(1.0))})
+    # the sabotage: no decay
+    q, k, v, g, beta = rows
+    o_bad, _ = step("pallas", (q, k, v, jnp.zeros_like(g), beta))
+    refused = int(not case("kda_step_control_no_decay",
+                           {"o": (o_bad[held], o_ref[held])}))
+    if refused:     # the control is meant to fail its bound
+        FAILED.remove("kda_step_control_no_decay")
+
+    t, real = 2048, 1900
+    s0 = normal((1, heads, 128, 128), jnp.float32)
+    crow = kda_inputs((1, t, heads))
+    o, s_t = jax.jit(kda.kda_chunk)(s0, *crow, jnp.asarray([real]))
+
+    def token(s, row):
+        o, s = kda.delta_step(s, *row)
+        return s, o
+
+    want_s, want_o = jax.jit(lambda s0, rows: jax.lax.scan(
+        token, s0, jax.tree.map(lambda x: jnp.moveaxis(x[:, :real], 1, 0),
+                                rows)))(s0, crow)
+    case("kda_chunk", {"o": (o[:, :real], jnp.moveaxis(want_o, 0, 1)),
+                       "state": (s_t, want_s)}, tokens=t, real=real)
+    return refused
+
+
 def int8_matmul_case() -> None:
     qm = importlib.import_module(
         "distributed_training_guide_tpu.ops.quantized_matmul")
@@ -599,14 +678,27 @@ def int8_matmul_case() -> None:
 def main(argv) -> int:
     everything, mla_only = argv == ["--all"], argv == ["--mla"]
     lfm2_only, mimo_only = argv == ["--lfm2"], argv == ["--mimo"]
-    if argv and not (everything or mla_only or lfm2_only or mimo_only):
-        raise SystemExit("usage: kernel_parity.py [--all|--mla|--lfm2|--mimo]")
+    kda_only = argv == ["--kda"]
+    if argv and not (everything or mla_only or lfm2_only or mimo_only
+                     or kda_only):
+        raise SystemExit(
+            "usage: kernel_parity.py [--all|--mla|--lfm2|--mimo|--kda]")
     print_device_line("attend", ("flash", "forced"), CACHE.directory)
     if jax.devices()[0].platform != EXPECT_PLATFORM:
         print(f"kernel_parity FAILED: runs on "
               f"{jax.devices()[0].platform!r}, not {EXPECT_PLATFORM!r}",
               file=sys.stderr)
         return 1
+    if kda_only:
+        refused = kda_cases()
+        CACHE.print_line()
+        if FAILED or refused != 1:
+            print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
+                  f"decay left out refused: {refused} of 1", file=sys.stderr)
+            return 1
+        print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
+                          "controls_refused": refused}), flush=True)
+        return 0
     if mimo_only:
         refused = mimo_cases()
         CACHE.print_line()

@@ -706,6 +706,104 @@ def test_two_class_familys_serve_programs_compile_at_the_cells_size(
                 for leaf in ("gate", "up", "down")))
 
 
+SOLAR_CELL = dict(slots=192, columns=28, pages=5377, chunk=2048)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk2048"])
+def test_state_class_familys_serve_programs_compile_at_the_cells_size(
+        one_chip, chip_compile, compiled_kernels, monkeypatch, program):
+    """``solar-open2-ep8-l4.serve.gen192``'s decode step (192 slots) and
+    prefill chunk (2,048 tokens), whole, at the cell's size (6.16 GiB of
+    weights, k and v of the one GQA layer over 5,377 pages of 128, the state
+    class of 3 KDA layers over 193 blocks: 2.26 GiB in float32): the decode
+    step updates every slot's state where it lies through ``kda_step`` (one
+    call a KDA layer, the pool aliased in and out), the GQA layer goes
+    through the compiled ``paged_attend``, ``gmm`` three times a layer in the
+    compact walk and its overflow branch (1,536 pairs a layer against a
+    prefix of 512), and nothing expert-sized, pool-sized or sized like the
+    state ``S`` is copied; arguments and temporaries of either program stay
+    under 14.5 GiB."""
+    import dataclasses
+    import json
+    from pathlib import Path
+
+    from distributed_training_guide_tpu.models import solar_open2
+    from distributed_training_guide_tpu.ops import kda
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    monkeypatch.setattr(kda, "resolve_interpret", lambda i: False)
+    monkeypatch.setattr(kda, "_resolve_impl",
+                        lambda impl: "pallas" if impl == "auto" else impl)
+    c = SOLAR_CELL
+    real = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                       / "configs" / "solar-open2-ep8-l4.json").read_text())
+    cfg = dataclasses.replace(
+        solar_open2.PRESETS["solar-open2-250b"],
+        num_layers=real["num_hidden_layers"],
+        gqa_layers=tuple(real["gqa_layers"]), vocab_size=real["vocab_size"],
+        experts_held=(real["experts_held_first"], real["n_routed_experts"]),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: solar_open2.init(cfg, jax.random.key(0)))
+    leaves, treedef = jax.tree.flatten(params)
+    pools = jax.eval_shape(lambda: kv_pages.init_pages(
+        cfg, c["pages"], 128, n_state_blocks=c["slots"] + 1))
+    names = ("k", "v", "seq_state", "seq_conv")
+    assert pools["seq_state"].shape == (3, c["slots"] + 1, 64, 128, 128)
+    # float32 beside bf16 weights, k and v: the state's precision is not the
+    # pool's (no comparison of served tokens would see it narrower)
+    assert pools["seq_state"].dtype == jnp.float32 \
+        and pools["k"].dtype == pools["seq_conv"].dtype == jnp.bfloat16
+    slots, t = (c["slots"], 1) if program == "decode" else (1, c["chunk"])
+
+    def step(kp, vp, sp, cp, ids, lengths, tables, *flat):
+        logits, cache = solar_open2.paged_decode_step(
+            cfg, jax.tree.unflatten(treedef, flat), ids, lengths,
+            dict(zip(names, (kp, vp, sp, cp))),
+            kv_pages.make_attend(tables, lengths, impl="flash",
+                                 n_valid=jnp.full((slots,), t),
+                                 state_class=True),
+            last_index=jnp.asarray(t - 1))
+        return (jnp.argmax(logits, -1), *(cache[n] for n in names),
+                cache["routing"])
+
+    specs = [(pools[n].shape, pools[n].dtype) for n in names] + [
+        ((slots, t), jnp.int32), ((slots,), jnp.int32),
+        ((slots, c["columns"] + 1), jnp.int32)] + [
+        (x.shape, x.dtype) for x in leaves]
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2, 3)).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in specs)).compile()
+    text = compiled.as_text()
+    calls = kernel_calls(text)
+    assert sum(named(x, "gmm") for x in calls) == 2 * 3 * 4, calls
+    assert sum(named(x, "paged_attend") for x in calls) == 1, calls
+    assert sum(named(x, "kda_step") for x in calls) == (
+        3 if program == "decode" else 0), calls
+    # k, v and S. The conv leaf (85 MB: three rows a block tile badly) is
+    # re-laid by the compiler four times a decode step (1.2 ms on the chip);
+    # a flat leaf compiled without them and ran 6 ms SLOWER (its scatter
+    # became a loop over the slots): PERF.md section 6, PR 44
+    sized = pool_sized_ops(text, *(pools[n].shape for n in names[:3]),
+                           names=True)
+    # (the chunk's one slot writes its state back by an in-place
+    # `dynamic-update-slice`, the decode step's 192 through the kernel)
+    in_place = ("parameter", "bitcast", "scatter", "get-tuple-element",
+                "fusion", "custom-call", "tuple", "dynamic-update-slice")
+    moved = [x for x in sized
+             if x.split()[0] not in in_place or "copy" in x
+             or ("slice" in x and x.split()[0] != "dynamic-update-slice")]
+    assert not moved, moved
+    assert_experts_read_in_place(
+        text, *(params["layers"]["moe"][leaf].shape
+                for leaf in ("gate", "up", "down")))
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes >= sum(
+        math.prod(pools[n].shape) * pools[n].dtype.itemsize for n in names)
+    assert held < 14.5 * 2 ** 30, held / 2 ** 30
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk512"])
 def test_moe_familys_serve_programs_read_the_experts_in_place(
         chip_compile, compiled_kernels, program):
