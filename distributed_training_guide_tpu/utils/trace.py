@@ -95,8 +95,12 @@ SUBSCOPES = ("latent_proj", "shared_expert", "conv", "attend_full",
              "attend_window", "head_gather", "attn_full", "attn_window", "kda")
 
 # pallas_call names (ops/); `kda_step` and `kda_chunk` name the two forms of
-# the KDA recurrence whatever implements them (a Pallas kernel or a
-# `named_scope` around plain jnp: `ops/kda.py`)
+# the KDA recurrence whatever implements them: on a TPU each is ONE Pallas
+# kernel a KDA layer (a decode program shows three `kda_step` events, a chunk
+# program three `kda_chunk`: the chunk's whole gated delta rule, its state in
+# VMEM from the first block to the last), elsewhere plain jnp; both sit
+# under a `named_scope` of the same name, so a path reads
+# `attn/kda/kda_chunk/...` either way (`ops/kda.py`)
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",
            "paged_latent_attend", "gmm", "tgmm", "qmm", "kda_step",
            "kda_chunk")

@@ -713,7 +713,8 @@ SOLAR_CELL = dict(slots=192, columns=28, pages=5377, chunk=2048)
 def test_state_class_familys_serve_programs_compile_at_the_cells_size(
         one_chip, chip_compile, compiled_kernels, monkeypatch, program):
     """``solar-open2-ep8-l4.serve.gen192``'s decode step (192 slots) and
-    prefill chunk (2,048 tokens), whole, at the cell's size (6.16 GiB of
+    prefill chunk (2,048 tokens: its three KDA layers each through ONE
+    ``kda_chunk`` kernel), whole, at the cell's size (6.16 GiB of
     weights, k and v of the one GQA layer over 5,377 pages of 128, the state
     class of 3 KDA layers over 193 blocks: 2.26 GiB in float32): the decode
     step updates every slot's state where it lies through ``kda_step`` (one
@@ -732,8 +733,9 @@ def test_state_class_familys_serve_programs_compile_at_the_cells_size(
     from distributed_training_guide_tpu.serve import kv_pages
 
     monkeypatch.setattr(kda, "resolve_interpret", lambda i: False)
-    monkeypatch.setattr(kda, "_resolve_impl",
-                        lambda impl: "pallas" if impl == "auto" else impl)
+    monkeypatch.setattr(
+        kda, "_resolve_impl",
+        lambda impl, op=None: "pallas" if impl == "auto" else impl)
     c = SOLAR_CELL
     real = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
                        / "configs" / "solar-open2-ep8-l4.json").read_text())
@@ -779,6 +781,12 @@ def test_state_class_familys_serve_programs_compile_at_the_cells_size(
     assert sum(named(x, "paged_attend") for x in calls) == 1, calls
     assert sum(named(x, "kda_step") for x in calls) == (
         3 if program == "decode" else 0), calls
+    # a chunk's recurrence is ONE kernel a KDA layer: no triangular solve,
+    # and no pairwise decay written out over [.., block, block, d_k]
+    assert sum(named(x, "kda_chunk") for x in calls) == (
+        0 if program == "decode" else 3), calls
+    assert "triangular" not in text
+    assert not re.search(rf"f32\[[\d,]*{kda.BLOCK},{kda.BLOCK},128\]", text)
     # k, v and S. The conv leaf (85 MB: three rows a block tile badly) is
     # re-laid by the compiler four times a decode step (1.2 ms on the chip);
     # a flat leaf compiled without them and ran 6 ms SLOWER (its scatter

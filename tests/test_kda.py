@@ -1,7 +1,10 @@
 """``ops/kda.py`` on the CPU: the chunked scan against the recurrent step
 applied token by token against a plain scan written here from the three
-lines of the recurrence; the step's Pallas kernel (interpreted) against the
-gather / scatter path on a pool it must update in place."""
+lines of the recurrence, in both forms (``jnp`` and the Pallas kernel,
+interpreted); the step's Pallas kernel (interpreted) against the gather /
+scatter path on a pool it must update in place."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,11 +13,22 @@ import pytest
 from distributed_training_guide_tpu.ops import kda
 
 H, D = 3, 16
+IMPLS = pytest.mark.parametrize("impl", ["xla", "pallas"])
+
+
+def form(impl):
+    """The keywords that pick one form (a kernel interpreted: this is the
+    CPU)."""
+    return {"impl": impl, **({"interpret": True} if impl == "pallas" else {})}
+
+
+def chunk(impl):
+    return jax.jit(lambda *a: kda.kda_chunk(*a, **form(impl)))
 
 
 def rows(rng, shape, decay=(0.001, 1.7)):
-    """Rows of the recurrence at ``shape = (..., H)``: unit k, q of length 1
-    / sqrt(d), log-decays in ``-decay``, beta in (0, 2)."""
+    """Rows of the recurrence at ``shape = (..., heads)``: unit k, q of
+    length 1 / sqrt(d), log-decays in ``-decay``, beta in (0, 2)."""
     def unit(x):
         return x / np.linalg.norm(x, axis=-1, keepdims=True)
 
@@ -43,17 +57,22 @@ def plain_scan(s0, q, k, v, g, beta, n_valid):
     return out, s
 
 
-@pytest.mark.parametrize("t, n_valid", [(150, (150, 97)), (64, (64, 1)),
-                                        (7, (7, 3))],
-                         ids=["two_blocks_and_a_part", "one_block", "short"])
-def test_chunk_is_the_step_token_by_token_is_the_plain_scan(t, n_valid):
-    """T not a multiple of the block, ``n_valid`` < T, a chunk that starts
-    from a non-zero state."""
+@IMPLS
+@pytest.mark.parametrize("t, n_valid, heads", [
+    (150, (150, 97), H), (64, (64, 1), H), (7, (7, 3), H),
+    (130, (130, 5), 32)],
+    ids=["two_blocks_and_a_part", "one_block", "short", "four_head_tiles"])
+def test_chunk_is_the_step_token_by_token_is_the_plain_scan(t, n_valid,
+                                                            heads, impl):
+    """T not a multiple of the block, ``n_valid`` < T and inside the first
+    block, a chunk that starts from a non-zero state; 3 heads are one tile
+    of the kernel, 32 are four."""
     rng = np.random.default_rng(t)
-    r = rows(rng, (2, t, H))
-    s0 = jnp.asarray(rng.normal(size=(2, H, D, D)), jnp.float32)
+    r = rows(rng, (2, t, heads))
+    s0 = jnp.asarray(rng.normal(size=(2, heads, D, D)), jnp.float32)
     nv = jnp.asarray(n_valid)
-    o, s_t = jax.jit(kda.kda_chunk)(s0, *r, nv)
+    assert heads % kda.CHUNK_HEAD_TILE == 0 or heads < kda.CHUNK_HEAD_TILE
+    o, s_t = chunk(impl)(s0, *r, nv)
     want_o, want_s = plain_scan(s0, *r, n_valid)
     s, outs = s0, []
     for i in range(t):
@@ -99,7 +118,8 @@ def test_a_pool_narrower_than_float32_is_refused_by_name(impl):
                      impl=impl, interpret=True)
 
 
-def test_a_whole_block_at_the_strongest_decay_does_not_overflow():
+@IMPLS
+def test_a_whole_block_at_the_strongest_decay_does_not_overflow(impl):
     """alpha = 0.2 a step over two whole blocks: the running log-decay of a
     block reaches -103, whose negative no float32 ``exp`` survives; every
     ``exp`` the chunk takes is of a difference <= 0."""
@@ -109,18 +129,90 @@ def test_a_whole_block_at_the_strongest_decay_does_not_overflow():
     g = jnp.full((1, t, H, D), float(np.log(0.2)), jnp.float32)
     assert float(-jnp.sum(g[0, :kda.BLOCK, 0, 0])) > 88.8   # log(float32 max)
     s0 = jnp.asarray(rng.normal(size=(1, H, D, D)), jnp.float32)
-    o, s_t = kda.kda_chunk(s0, q, k, v, g, beta)
+    o, s_t = chunk(impl)(s0, q, k, v, g, beta)
     want_o, want_s = plain_scan(s0, q, k, v, g, beta, (t,))
     assert bool(jnp.all(jnp.isfinite(o))) and bool(jnp.all(jnp.isfinite(s_t)))
     assert np.max(np.abs(o - want_o)) < 2e-5
     assert np.max(np.abs(s_t - want_s)) < 2e-5
 
 
-def test_a_chunk_without_a_real_token_hands_its_state_on():
+@IMPLS
+def test_a_factor_of_the_decay_that_underflows_is_where_the_value_does(impl):
+    """The kernel takes ``exp(G_t - G_s)`` across sub-blocks as ``exp(G_t -
+    G_r) exp(G_r - G_s)`` with r the first row of t's sub-block. alpha = 0.2
+    a step leaves the second factor ``exp(-77)`` from the block's first row
+    to its fourth sub-block (48 steps), and a channel at alpha = 0.1 leaves
+    ``exp(-110)``, which float32 holds as 0: the product is 0 only where the
+    pairwise decay itself is, so the float64 scan is still met; the channels
+    that hardly decay keep early tokens in play beside them."""
+    rng = np.random.default_rng(6)
+    t = kda.BLOCK + 3 * kda.SUB
+    q, k, v, _, beta = rows(rng, (1, t, H))
+    g = np.full((1, t, H, D), np.log(0.2))
+    g[..., :4], g[..., 4:8] = np.log(0.1), -0.001
+    assert np.exp(np.float32(3 * kda.SUB * g[0, 0, 0, 0])) == 0.0
+    g = jnp.asarray(g, jnp.float32)
+    s0 = jnp.asarray(rng.normal(size=(1, H, D, D)), jnp.float32)
+    o, s_t = chunk(impl)(s0, q, k, v, g, beta)
+    want_o, want_s = plain_scan(s0, q, k, v, g, beta, (t,))
+    assert np.max(np.abs(o - want_o)) < 2e-5
+    assert np.max(np.abs(s_t - want_s)) < 2e-5
+
+
+def test_a_kernel_on_bfloat16_operands_is_outside_the_tolerance(monkeypatch):
+    """What the tolerance holds of the kernel's products: float32 operands
+    at full precision. The same kernel with every product's operands rounded
+    to bfloat16 (what ``Precision.DEFAULT`` does to a float32 product on the
+    MXU) ends tens of tolerances away; the benchmark's comparison of served
+    tokens would not see it (PERF.md section 7)."""
+    rng = np.random.default_rng(12)
+    r = rows(rng, (1, 150, H), decay=(0.001, 0.1))
+    s0 = jnp.asarray(rng.normal(size=(1, H, D, D)), jnp.float32)
+    want_o, want_s = plain_scan(s0, *r, (150,))
+    o, s_t = chunk("pallas")(s0, *r)
+    assert np.max(np.abs(o - want_o)) < 2e-5
+    assert np.max(np.abs(s_t - want_s)) < 2e-5
+    sound = kda._mm
+
+    def rounded(a, b, contract):
+        return sound(*(x.astype(jnp.bfloat16).astype(jnp.float32)
+                       for x in (a, b)), contract)
+
+    # the kernel's call is traced once a shape (``_chunk_pallas`` is a jit)
+    monkeypatch.setattr(kda, "_mm", rounded)
+    kda._chunk_pallas.clear_cache()
+    try:
+        o, s_t = chunk("pallas")(s0, *r)
+    finally:
+        kda._chunk_pallas.clear_cache()
+    assert np.max(np.abs(s_t - want_s)) > 50 * 2e-5
+    assert np.max(np.abs(o - want_o)) > 20 * 2e-5
+
+
+def test_a_gradient_through_the_kernel_is_the_jnp_forms():
+    """The kernel has no backward of its own: a stack that trains on a TPU
+    differentiates the ``jnp`` form at the kernel's inputs."""
+    rng = np.random.default_rng(8)
+    r = rows(rng, (1, 70, H))
+    s0 = jnp.asarray(rng.normal(size=(1, H, D, D)), jnp.float32)
+
+    def loss(impl, s0, *r):
+        o, s_t = kda.kda_chunk(s0, *r, **form(impl))
+        return jnp.sum(o * o) + jnp.sum(s_t)
+
+    got, want = (jax.grad(functools.partial(loss, impl), argnums=range(6))(
+        s0, *r) for impl in ("pallas", "xla"))
+    for a, b in zip(got, want):
+        assert float(jnp.max(jnp.abs(b))) > 1e-3
+        assert np.max(np.abs(a - b)) < 2e-5 * max(1, float(jnp.max(jnp.abs(b))))
+
+
+@IMPLS
+def test_a_chunk_without_a_real_token_hands_its_state_on(impl):
     rng = np.random.default_rng(2)
     r = rows(rng, (1, 20, H))
     s0 = jnp.asarray(rng.normal(size=(1, H, D, D)), jnp.float32)
-    _, s_t = kda.kda_chunk(s0, *r, jnp.asarray([0]))
+    _, s_t = chunk(impl)(s0, *r, jnp.asarray([0]))
     assert np.array_equal(np.asarray(s_t), np.asarray(s0))
 
 
@@ -149,20 +241,19 @@ def test_step_kernel_updates_the_pool_where_it_lies(heads):
         assert np.array_equal(new[1, [1, 3]], pool[1, [1, 3]])
 
 
-def test_a_log_decay_of_minus_infinity_is_the_zero_state():
+@IMPLS
+def test_a_log_decay_of_minus_infinity_is_the_zero_state(impl):
     """What ``models/solar_open2.py`` hands a decode step for a sequence at
     position 0: the block's last owner's state decays to exactly zero."""
     rng = np.random.default_rng(4)
     pool = jnp.asarray(rng.normal(size=(1, 3, H, D, D)), jnp.float32)
     q, k, v, g, beta = rows(rng, (2, H))
     fresh = jnp.full_like(g, -jnp.inf)
-    for impl, more in (("xla", {}), ("pallas", {"interpret": True})):
-        o, new = kda.kda_step(pool, jnp.asarray([1, 2]), 0, q, k, v, fresh,
-                              beta, impl=impl, **more)
-        want_o, want_s = kda.delta_step(jnp.zeros((2, H, D, D)), q, k, v, g,
-                                        beta)
-        assert np.max(np.abs(new[0, 1:] - want_s)) < 1e-6
-        assert np.max(np.abs(o - want_o)) < 1e-6
+    o, new = kda.kda_step(pool, jnp.asarray([1, 2]), 0, q, k, v, fresh, beta,
+                          **form(impl))
+    want_o, want_s = kda.delta_step(jnp.zeros((2, H, D, D)), q, k, v, g, beta)
+    assert np.max(np.abs(new[0, 1:] - want_s)) < 1e-6
+    assert np.max(np.abs(o - want_o)) < 1e-6
 
 
 def test_both_forms_carry_their_names():
@@ -173,8 +264,9 @@ def test_both_forms_carry_their_names():
     rng = np.random.default_rng(5)
     r = rows(rng, (1, 8, H))
     s0 = jnp.zeros((1, H, D, D))
-    chunk = jax.jit(kda.kda_chunk).lower(s0, *r).as_text(debug_info=True)
-    assert "kda_chunk" in chunk and "kda_chunk" in trace.KERNELS
+    for impl in ("xla", "pallas"):
+        text = chunk(impl).lower(s0, *r).as_text(debug_info=True)
+        assert "kda_chunk" in text and "kda_chunk" in trace.KERNELS
     pool = jnp.zeros((1, 2, H, D, D))
     step = jax.jit(lambda p, *r: kda.kda_step(p, jnp.asarray([1]), 0, *r,
                                               impl="xla")).lower(

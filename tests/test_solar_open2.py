@@ -348,14 +348,32 @@ def test_disaggregation_and_an_engine_swap_are_refused_by_name(model):
         "plan / shard_kv", "disaggregation", "engine swap"}
 
 
-def test_the_decode_program_carries_the_familys_names(model):
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_decode_program_carries_the_familys_names(model, monkeypatch,
+                                                      impl):
     """``attn/kda/kda_step`` on the decode step's KDA layers, ``kda_chunk``
-    on a chunk's, the state's writes under ``kv_write``; the scheduler's
-    ``serve.state`` span is in the vocabulary (``utils/trace.py``)."""
+    on a chunk's, whichever form computes them (``pallas``: the kernels,
+    interpreted here, as ``auto`` takes them on a TPU), the state's writes
+    under ``kv_write``; each program's dispatch notes its choice under its
+    own name; the scheduler's ``serve.state`` span is in the vocabulary
+    (``utils/trace.py``)."""
     import re
 
+    from distributed_training_guide_tpu.ops import kda
     from distributed_training_guide_tpu.utils import trace
 
+    class OnTpu:    # what ``ops/kda.py`` alone sees of the backend
+        default_backend = staticmethod(lambda: "tpu")
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+    noted = []
+    if impl == "pallas":
+        monkeypatch.setattr(kda, "jax", OnTpu())
+        monkeypatch.setattr(kda, "resolve_interpret", lambda i: True)
+    monkeypatch.setattr(kda, "note_choice",
+                        lambda op, took, why: noted.append((op, took)))
     bundle, params = model
     eng = ServeEngine(bundle, params, n_slots=2, page_size=PAGE,
                       max_len=MAX_LEN, prefill_chunk=CHUNK)
@@ -375,6 +393,9 @@ def test_the_decode_program_carries_the_familys_names(model):
     assert any("attn/kda/kda_step/" in f for f in in_decode)
     assert not any("kda_chunk" in f for f in in_decode)
     assert any("attn/kda/kda_chunk/" in f for f in in_chunk)
+    assert set(noted) == {("kda_step", impl), ("kda_chunk", impl)}
+    # the kernel solves (I + A) itself; the ``jnp`` form asks XLA to
+    assert ("triangular_solve" in chunk) == (impl == "xla")
     assert any("attn/kv_write/" in f for f in in_decode)
     assert arr["tables"].shape == (2, eng.max_pages + 1)
     assert {"kda"} <= set(trace.SUBSCOPES) and "serve.state" in trace.SPANS
