@@ -7,9 +7,19 @@ and the SURVEY.md build plan's deliberate custom-kernel deliverable.
 
 Design (standard blockwise online-softmax, laid out for the MXU/VMEM):
 
-- inputs are processed as [B, H, S, D]; TPU grids execute sequentially per
-  core, so the online-softmax running state (m, l, acc) lives in VMEM scratch
-  carried across the kv tiles of a q tile's walk. m and l stay ``[BQ, 128]``,
+- q, k, v, o, do and the three gradients are read and written where the
+  model holds them: ``[B, S, H, D]`` viewed ``[B, S, H*D]``, a ``[block, D]``
+  tile being the column block of its head (``_Operands``; every entry,
+  ``_flash_fwd`` and ``flash_bwd_with_stats`` included, takes and returns
+  ``[B, S, H, D]``, and nothing is transposed on the way in or out). A
+  128-lane column block of an (8, 128)-tiled array is whole tiles, so the
+  DMA strides over tiles; a ``head_dim`` that does not fill 128 lanes (64)
+  keeps ``[B, H, S, D]`` operands behind two transposes, chosen from the
+  shape in that one class. lse and delta are ``[B, H, S]`` float32 (128
+  lanes wide on their way through the kernels);
+- TPU grids execute sequentially per core, so the online-softmax running
+  state (m, l, acc) lives in VMEM scratch carried across the kv tiles of a
+  q tile's walk. m and l stay ``[BQ, 128]``,
   one value a row replicated over the lanes, from the scratch to the
   accumulator (``_row_stat``, ``_widen``): no one-lane column is read out of
   them inside the tile loop;
@@ -38,9 +48,10 @@ Design (standard blockwise online-softmax, laid out for the MXU/VMEM):
 - backward recomputes attention blockwise (flash-bwd): a dq kernel with the
   forward's walk, and a dk/dv kernel walking (batch, kv-head, kv-block,
   q-block, group) — or (batch, kv-head, live tile, group) — that also
-  reduces over the GQA group on-chip. The logsumexp from the forward and
-  ``delta = rowsum(dO * O)`` (cheap XLA einsum) are the only residuals —
-  activation memory is O(B*H*S), not O(B*H*S^2).
+  reduces over the GQA group on-chip. The residuals are the raw q, k, v,
+  the output (tagged ``flash_out``) and the logsumexp (``flash_lse``);
+  ``delta = rowsum(dO * O)`` is taken in XLA (``row_dots``) — activation
+  memory is O(B*H*S), not O(B*H*S^2).
 
 ``interpret=True`` runs the same kernels on CPU (used by the test suite's
 numerics goldens against the XLA reference implementation).
@@ -136,19 +147,23 @@ def tile_counts(causal, window, sq, sk, block_q, block_k):
 
 
 def describe_walk(q, k, causal, window, block_q=_TILE_CEILING,
-                  block_k=_TILE_CEILING) -> str:
+                  block_k=_TILE_CEILING, seq_major=True) -> str:
     """What ``note_attention`` says of a flash call on ``[B, S, H, D]``
-    operands: the tiles chosen and, under a static band, how many of a
-    walk's tiles are interior, edge and dead (a traced band is walked
-    whole and decides tile by tile at run time)."""
+    operands: the layout the kernels address them in (``_Operands``), the
+    tiles chosen and, under a static band, how many of a walk's tiles are
+    interior, edge and dead (a traced band is walked whole and decides tile
+    by tile at run time)."""
     block_q = _pick_block(q.shape[1], block_q, q.dtype, q.shape[-1], window)
     block_k = _pick_block(k.shape[1], block_k, k.dtype, q.shape[-1], window)
     tiles = f"tiles {block_q}x{block_k}"
+    operands = f"{_Operands(q.shape[-1], seq_major)} operands"
     if not (window is None or isinstance(window, int)):
-        return f"{tiles}, traced window: the whole grid is walked"
+        return (f"{tiles}, traced window: the whole grid is walked; "
+                f"{operands}")
     interior, edge, dead = tile_counts(causal, window, q.shape[1], k.shape[1],
                                        block_q, block_k)
-    return f"{tiles}, a walk: {interior} interior / {edge} edge / {dead} dead"
+    return (f"{tiles}, a walk: {interior} interior / {edge} edge / {dead} "
+            f"dead; {operands}")
 
 
 def _live_tiles(causal, window, nq, nk, block_q, block_k, by_rows: bool):
@@ -268,8 +283,8 @@ def _fwd_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)          # [BQ, D]
-        k = k_ref[0, 0].astype(jnp.float32)          # [BK, D]
+        q = q_ref[...].astype(jnp.float32)           # [BQ, D]
+        k = k_ref[...].astype(jnp.float32)           # [BK, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap is not None:  # Gemma-2: tanh cap BEFORE the mask
@@ -295,7 +310,7 @@ def _fwd_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
             # (Pure causal never hits this: every row's walk opens on a
             # tile that holds one of its keys.)
             p = jnp.where(mask, p, 0.0)
-        v = v_ref[0, 0].astype(jnp.float32)           # [BK, D]
+        v = v_ref[...].astype(jnp.float32)           # [BK, D]
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         l_scr[:] = alpha * l_prev + _row_stat(p, jnp.add, jnp.sum)
@@ -306,8 +321,8 @@ def _fwd_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
     def _finalize():
         l = l_scr[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / _widen(safe_l, d)).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:] + jnp.log(safe_l)
+        o_ref[...] = (acc_scr[:] / _widen(safe_l, d)).astype(o_ref.dtype)
+        lse_ref[...] = m_scr[:] + jnp.log(safe_l)
 
 
 def check_static_window(window):
@@ -350,67 +365,132 @@ def _band_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _plan(q, k, causal, window, block_q, block_k, band):
-    """What the three pallas_calls share: the tiles chosen, the static
-    window and dynamic band operand, and whether the band is static, in
-    which case the grids hold its live tiles alone (``_live_tiles``): a dead
-    tile then costs no grid step and no DMA. A dynamic band (traced window,
-    ring offsets) is not known where the grid is built, so its kernels walk
-    every tile and skip the dead ones' compute; without a band every tile
-    is live."""
+class _Operands:
+    """How the three kernels address q, k, v, o, do and the three gradients,
+    which every caller holds as ``[B, S, H, D]``; the one place that chooses.
+
+    SEQ-MAJOR, where ``head_dim`` fills whole 128-lane tiles: the array as
+    it lies, viewed ``[B, S, H*D]`` (a reshape that moves nothing), with head
+    ``h``'s row tile ``i`` the ``(block, D)`` block at column block ``h``.
+    That block is whole (8, 128) tiles of the array, so its DMA strides over
+    tiles, not rows, and no relayout stands before or behind a kernel.
+    HEAD-MAJOR otherwise: the call pays the transposes to ``[B, H, S, D]``
+    and back that every call paid until PR 46. That is ``head_dim`` 64 (a
+    64-lane column block is not a block the chip takes), and the sharded
+    wrapper's maps, which ask for it (``seq_major=False``): what the
+    compiler makes of the view is the MODEL's to decide, not the kernels'.
+    A per-head reduction between projection and rope (Qwen3's QK-norm) lets
+    the projection's own layout run through norm and rope up to one bf16
+    copy in front of the kernel; without one (OLMo-2's flat norm, Laguna)
+    rope is laid out anew around the view and the four-chip cell's step
+    read 1.2% slower (PERF.md section 6, PR 46)."""
+
+    def __init__(self, head_dim: int, seq_major: bool = True):
+        self.d = head_dim
+        self.seq_major = seq_major and head_dim % _LANES == 0
+
+    def __str__(self):
+        if self.seq_major:
+            return "seq-major"
+        return ("head-major" if self.d % _LANES == 0
+                else f"head-major (head_dim {self.d})")
+
+    def shape(self, b, s, h):
+        return (b, s, h * self.d) if self.seq_major else (b, h, s, self.d)
+
+    def to_kernel(self, *xs):
+        if self.seq_major:
+            return tuple(x.reshape(*x.shape[:2], -1) for x in xs)
+        return tuple(x.transpose(0, 2, 1, 3) for x in xs)
+
+    def from_kernel(self, *xs):
+        if self.seq_major:
+            return tuple(x.reshape(*x.shape[:2], -1, self.d) for x in xs)
+        return tuple(x.transpose(0, 2, 1, 3) for x in xs)
+
+    def spec(self, block, index_map):
+        """The ``[block, D]`` tile that ``index_map`` names as (batch row,
+        head, row tile)."""
+        if self.seq_major:
+            def where(*a):
+                b, h, i = index_map(*a)
+                return b, i, h
+            return pl.BlockSpec((None, block, self.d), where,
+                                memory_space=_VMEM)
+        return pl.BlockSpec((None, None, block, self.d),
+                            lambda *a: (*index_map(*a), 0),
+                            memory_space=_VMEM)
+
+
+def _stat_spec(block_q, index_map):
+    """lse's and delta's ``[block_q, 128]`` tile of ``[B, H, S, 128]``."""
+    return pl.BlockSpec((None, None, block_q, _LANES),
+                        lambda *a: (*index_map(*a), 0), memory_space=_VMEM)
+
+
+def _plan(q, k, causal, window, block_q, block_k, band, seq_major=True):
+    """What the three pallas_calls share, from ``[B, S, H, D]`` operands:
+    the tiles chosen, the static window and dynamic band operand, whether
+    the band is static, in which case the grids hold its live tiles alone
+    (``_live_tiles``): a dead tile then costs no grid step and no DMA, and
+    how the kernels address the operands (``_Operands``). A dynamic band
+    (traced window, ring offsets) is not known where the grid is built, so
+    its kernels walk every tile and skip the dead ones' compute; without a
+    band every tile is live."""
     d = q.shape[-1]
     if band is None:
         window, band = _resolve_band(window)
     else:
         window = None  # caller-packed dynamic band (the custom_vjp/ring path)
-    block_q = _pick_block(q.shape[2], block_q, q.dtype, d, window)
-    block_k = _pick_block(k.shape[2], block_k, k.dtype, d, window)
-    return block_q, block_k, window, band, causal and band is None
+    block_q = _pick_block(q.shape[1], block_q, q.dtype, d, window)
+    block_k = _pick_block(k.shape[1], block_k, k.dtype, d, window)
+    return (block_q, block_k, window, band, causal and band is None,
+            _Operands(d, seq_major))
 
 
 def _row_walk(tiled, causal, window, b, hq, groups, nq, nk, block_q, block_k):
     """``flash_fwd``'s and ``flash_dq``'s walk by q row: the prefetched
-    operands, the grid, and the q-side and kv-side index maps (q-head h
-    reads kv-head ``h // groups``)."""
+    operands, the grid, and the q-side and kv-side index maps as (batch row,
+    head, row tile): q-head h reads kv-head ``h // groups``."""
     if tiled:
         tiles = _live_tiles(causal, window, nq, nk, block_q, block_k, True)
 
         def q_map(b_, h, t, iq_ref, ik_ref):
-            return b_, h, iq_ref[t], 0
+            return b_, h, iq_ref[t]
 
         def kv_map(b_, h, t, iq_ref, ik_ref):
-            return b_, h // groups, ik_ref[t], 0
+            return b_, h // groups, ik_ref[t]
 
         return tiles, (b, hq, len(tiles[0])), q_map, kv_map
 
     def q_map(b_, h, iq, ik):
-        return b_, h, iq, 0
+        return b_, h, iq
 
     def kv_map(b_, h, iq, ik):
-        return b_, h // groups, ik, 0
+        return b_, h // groups, ik
 
     return (), (b, hq, nq, nk), q_map, kv_map
 
 
 def _flash_fwd(q, k, v, causal, window, block_q, block_k, interpret,
-               scale=None, softcap=None, band=None):
-    b, hq, sq, d = q.shape
-    _, hkv, sk, _ = k.shape
+               scale=None, softcap=None, band=None, seq_major=True):
+    """``[B, S, H, D]`` q, k, v -> (o ``[B, S, Hq, D]``, lse ``[B, Hq, S]``
+    float32). ``seq_major=False`` keeps head-major operands whatever the
+    ``head_dim`` (``_Operands``)."""
+    b, sq, hq, d = q.shape
+    _, sk, hkv, _ = k.shape
     groups = hq // hkv
-    block_q, block_k, window, band, tiled = _plan(q, k, causal, window,
-                                                  block_q, block_k, band)
+    block_q, block_k, window, band, tiled, ops = _plan(
+        q, k, causal, window, block_q, block_k, band, seq_major)
     nq, nk = sq // block_q, sk // block_k
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
     tiles, grid, q_map, kv_map = _row_walk(tiled, causal, window, b, hq, groups,
                                            nq, nk, block_q, block_k)
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), q_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_k, d), kv_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, block_k, d), kv_map, memory_space=_VMEM),
-    ]
-    args = [q, k, v]
+    q_spec, kv_spec = ops.spec(block_q, q_map), ops.spec(block_k, kv_map)
+    in_specs = [q_spec, kv_spec, kv_spec]
+    args = list(ops.to_kernel(q, k, v))
     if band is not None:
         in_specs.append(_band_spec())
         args.append(band)
@@ -423,23 +503,20 @@ def _flash_fwd(q, k, v, causal, window, block_q, block_k, interpret,
             num_scalar_prefetch=len(tiles),
             grid=grid,
             in_specs=in_specs,
-            out_specs=(
-                pl.BlockSpec((1, 1, block_q, d), q_map, memory_space=_VMEM),
-                pl.BlockSpec((1, 1, block_q, 128), q_map, memory_space=_VMEM),
-            ),
+            out_specs=(q_spec, _stat_spec(block_q, q_map)),
             scratch_shapes=[
                 _VMEM((block_q, 128), jnp.float32),
                 _VMEM((block_q, 128), jnp.float32),
                 _VMEM((block_q, d), jnp.float32),
             ]),
         out_shape=(
-            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+            jax.ShapeDtypeStruct(ops.shape(b, sq, hq), q.dtype),
             jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32),  # lane-padded
         ),
         interpret=interpret,
         name="flash_fwd",
     )(*tiles, *args)
-    return o, lse[..., 0]
+    return ops.from_kernel(o)[0], lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -484,12 +561,12 @@ def _dq_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, 0:1]
-        delta = delta_ref[0, 0][:, 0:1]
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        lse = lse_ref[:, 0:1]
+        delta = delta_ref[:, 0:1]
 
         mask = _band_mask(causal, window, iq, ik, block_q, block_k,
                           (block_q, block_k), q_off, k_off)
@@ -505,7 +582,7 @@ def _dq_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
 
     @pl.when(last)
     def _finalize():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
@@ -535,12 +612,12 @@ def _dkv_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                   # [BQ, D]
-        k = k_ref[0, 0].astype(jnp.float32)                   # [BK, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, 0:1]
-        delta = delta_ref[0, 0][:, 0:1]
+        q = q_ref[...].astype(jnp.float32)                    # [BQ, D]
+        k = k_ref[...].astype(jnp.float32)                    # [BK, D]
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        lse = lse_ref[:, 0:1]
+        delta = delta_ref[:, 0:1]
 
         mask = _band_mask(causal, window, iq, ik, block_q, block_k,
                           (block_q, block_k), q_off, k_off)
@@ -560,14 +637,16 @@ def _dkv_kernel(*refs, scale, softcap, causal, window, banded, tiled, block_q,
 
     @pl.when(last & (ig == groups - 1))
     def _finalize():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
                          block_q=512, block_k=512, interpret=False,
-                         scale=None, softcap=None, band=None):
-    """Flash backward from caller-supplied softmax stats -> (dq, dk, dv).
+                         scale=None, softcap=None, band=None,
+                         seq_major=True):
+    """Flash backward from caller-supplied softmax stats -> (dq, dk, dv),
+    ``[B, S, H, D]`` like q, k, v and do.
 
     ``lse``/``delta`` ([B, Hq, Sq] fp32) are normally the forward's
     logsumexp and ``rowsum(do * o)``; ring attention passes the *global*
@@ -577,18 +656,19 @@ def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
     recompute (including the tanh cap, whose ``(1 - tanh^2)`` factor
     threads through ds) must run in backward for the identity to hold.
     """
-    b, hq, sq, d = q.shape
-    _, hkv, sk, _ = k.shape
+    b, sq, hq, d = q.shape
+    _, sk, hkv, _ = k.shape
     groups = hq // hkv
-    block_q, block_k, window, band, tiled = _plan(q, k, causal, window,
-                                                  block_q, block_k, band)
+    block_q, block_k, window, band, tiled, ops = _plan(
+        q, k, causal, window, block_q, block_k, band, seq_major)
     nq, nk = sq // block_q, sk // block_k
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
     lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, 128))
     delta_l = jnp.broadcast_to(delta[..., None], (*delta.shape, 128))
-    args = [q, k, v, do, lse_l, delta_l] + ([] if band is None else [band])
+    args = [*ops.to_kernel(q, k, v, do), lse_l, delta_l,
+            *([] if band is None else [band])]
     band_specs = [] if band is None else [_band_spec()]
     static = dict(scale=scale, softcap=softcap, causal=causal, window=window,
                   banded=band is not None, tiled=tiled, block_q=block_q,
@@ -597,9 +677,8 @@ def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
     # dq: flash_fwd's walk
     tiles, grid, q_map, kv_map = _row_walk(tiled, causal, window, b, hq, groups,
                                            nq, nk, block_q, block_k)
-    q_spec = pl.BlockSpec((1, 1, block_q, d), q_map, memory_space=_VMEM)
-    kv_spec = pl.BlockSpec((1, 1, block_k, d), kv_map, memory_space=_VMEM)
-    stat_spec = pl.BlockSpec((1, 1, block_q, 128), q_map, memory_space=_VMEM)
+    q_spec, kv_spec = ops.spec(block_q, q_map), ops.spec(block_k, kv_map)
+    stat_spec = _stat_spec(block_q, q_map)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, num_kv_blocks=nk, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -608,7 +687,7 @@ def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
                       *band_specs],
             out_specs=q_spec,
             scratch_shapes=[_VMEM((block_q, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(ops.shape(b, sq, hq), q.dtype),
         interpret=interpret,
         name="flash_dq",
     )(*tiles, *args)
@@ -620,22 +699,21 @@ def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
         grid = (b, hkv, len(tiles[0]), groups)
 
         def q_idx(b_, hkv_, t, ig, ik_ref, iq_ref):
-            return b_, hkv_ * groups + ig, iq_ref[t], 0
+            return b_, hkv_ * groups + ig, iq_ref[t]
 
         def kv_idx(b_, hkv_, t, ig, ik_ref, iq_ref):
-            return b_, hkv_, ik_ref[t], 0
+            return b_, hkv_, ik_ref[t]
     else:
         grid = (b, hkv, nk, nq, groups)
 
         def q_idx(b_, hkv_, ik, iq, ig):
-            return b_, hkv_ * groups + ig, iq, 0
+            return b_, hkv_ * groups + ig, iq
 
         def kv_idx(b_, hkv_, ik, iq, ig):
-            return b_, hkv_, ik, 0
+            return b_, hkv_, ik
 
-    q_spec = pl.BlockSpec((1, 1, block_q, d), q_idx, memory_space=_VMEM)
-    kv_spec = pl.BlockSpec((1, 1, block_k, d), kv_idx, memory_space=_VMEM)
-    stat_spec = pl.BlockSpec((1, 1, block_q, 128), q_idx, memory_space=_VMEM)
+    q_spec, kv_spec = ops.spec(block_q, q_idx), ops.spec(block_k, kv_idx)
+    stat_spec = _stat_spec(block_q, q_idx)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, num_q_blocks=nq, groups=groups,
                           **static),
@@ -646,25 +724,42 @@ def flash_bwd_with_stats(q, k, v, do, lse, delta, *, causal, window=None,
             out_specs=(kv_spec, kv_spec),
             scratch_shapes=[_VMEM((block_k, d), jnp.float32),
                             _VMEM((block_k, d), jnp.float32)]),
-        out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        out_shape=(jax.ShapeDtypeStruct(ops.shape(b, sk, hkv), k.dtype),
+                   jax.ShapeDtypeStruct(ops.shape(b, sk, hkv), v.dtype)),
         interpret=interpret,
         name="flash_dkv",
     )(*tiles, *args)
 
-    return dq, dk, dv
+    return ops.from_kernel(dq, dk, dv)
+
+
+def row_dots(do, o):
+    """``delta = rowsum(do * o)`` a head: ``[B, S, H, D]`` -> ``[B, H, S]``
+    float32. The sums are taken where the rows lie, by a product with the
+    ``[H*D, H]`` matrix that says which head a column belongs to (exact: its
+    entries are 0 and 1, and ``HIGHEST`` keeps float32 through the MXU). A
+    ``sum(axis=-1)`` over the 4-D view writes the float32 product out and
+    relayouts it whole before it reduces (2 x 134 MB a layer at the seq2048
+    cell's shape, compiled for a v5e)."""
+    b, s, h, d = o.shape
+    # viewed [B, S, H*D] BEFORE the product, as the kernel wrote o and the
+    # output projection's backward wrote do: a 4-D product is laid out anew
+    do, o = (x.reshape(b, s, h * d).astype(jnp.float32) for x in (do, o))
+    head_of = jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=0)
+    return jnp.einsum("bsn,nh->bhs", do * o, head_of,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _flash_bwd(causal, window, block_q, block_k, interpret, scale, softcap,
-               residuals, g):
+               residuals, g, seq_major=True):
     q, k, v, o, lse, band = residuals
     do = g
-    delta = jnp.einsum("bhsd,bhsd->bhs", do.astype(jnp.float32),
-                       o.astype(jnp.float32))                  # [B,H,S]
+    delta = row_dots(do, o)
     grads = flash_bwd_with_stats(q, k, v, do, lse, delta, causal=causal,
                                  window=window, block_q=block_q,
                                  block_k=block_k, interpret=interpret,
-                                 scale=scale, softcap=softcap, band=band)
+                                 scale=scale, softcap=softcap, band=band,
+                                 seq_major=seq_major)
     # the dynamic band is integer-valued: its cotangent type is float0
     dband = (None if band is None
              else np.zeros(band.shape, jax.dtypes.float0))
@@ -827,8 +922,13 @@ def make_sharded_flash_attention(
     the RAW inputs plus the (checkpoint_name-tagged) primal output and lse
     — nothing residual-only leaves the fwd map, because a shard_map eqn is
     atomic under jax.checkpoint's partial-eval and rebuilding any such
-    output would re-run the kernel (vjp_bwd re-derives the kernel layouts
-    by transposing outside the map).
+    output would re-run the kernel. The core takes ``[B, S, H, D]`` as
+    the maps' specs shard it, so the bwd map is handed the residuals as
+    they are: no layout is re-derived outside a map. INSIDE the maps the
+    kernels keep head-major operands (``_Operands``, ``seq_major=False``):
+    with seq-major ones the four-chip cell lost 1.2% (OLMo-2's flat QK-norm
+    leaves the compiler nothing to carry the projection's layout through
+    rope with; PERF.md section 6, PR 46).
     """
     from jax.sharding import PartitionSpec as P
 
@@ -838,49 +938,43 @@ def make_sharded_flash_attention(
     if not manual:
         return None
     interpret = resolve_interpret(None)
-    spec_bshd = P(b_spec, None, head_axis, None)   # q/k/v/do/out [B, S, H, D]
-    spec_bhsd = P(b_spec, head_axis, None, None)   # residuals    [B, H, S, D]
-    spec_bhs = P(b_spec, head_axis, None)          # lse          [B, H, S]
+    spec_bshd = P(b_spec, None, head_axis, None)   # q/k/v/o/do [B, S, H, D]
+    spec_bhs = P(b_spec, head_axis, None)          # lse        [B, H, S]
+
+    # ONLY the primal output + lse leave the fwd map: a shard_map eqn is
+    # atomic under jax.checkpoint's partial-eval, so any residual-only
+    # output would force the whole map — pallas call included — to re-run
+    # in backward just to rebuild it. The core takes and returns the
+    # model's [B, S, H, D], so the residuals ARE the raw inputs + the
+    # tagged outputs. The maps' kernels keep head-major operands: seq-major
+    # ones cost the four-chip cell 1.2% (``_Operands``).
+    head_major = dict(seq_major=False)
 
     def fwd_body(q, k, v):
-        qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        o, lse = _flash_fwd(qt, kt, vt, causal, window, block_q, block_k,
-                            interpret, scale=scale, softcap=logit_softcap)
-        # ONLY the primal output + lse leave the map: a shard_map eqn is
-        # atomic under jax.checkpoint's partial-eval, so any residual-only
-        # output (the in-map transposes, or a separate kernel-layout o)
-        # would force the whole map — pallas call included — to re-run in
-        # backward just to rebuild values that are a transpose away.
-        # vjp_fwd keeps the raw inputs + tagged outputs as residuals and
-        # vjp_bwd re-transposes outside the map.
-        return o.transpose(0, 2, 1, 3), lse
+        return _flash_fwd(q, k, v, causal, window, block_q, block_k,
+                          interpret, scale=scale, softcap=logit_softcap,
+                          **head_major)
 
-    def bwd_body(qt, kt, vt, o, lse, do):
-        dq, dk, dv, _ = _flash_bwd(causal, window, block_q, block_k,
-                                   interpret, scale, logit_softcap,
-                                   (qt, kt, vt, o, lse, None),
-                                   do.transpose(0, 2, 1, 3))
-        return tuple(g.transpose(0, 2, 1, 3) for g in (dq, dk, dv))
+    def bwd_body(q, k, v, o, lse, do):
+        return _flash_bwd(causal, window, block_q, block_k, interpret, scale,
+                          logit_softcap, (q, k, v, o, lse, None), do,
+                          **head_major)[:3]
 
     # dynamic-window twins: the per-layer window (Gemma-2's alternating
     # schedule) arrives as a traced scalar per call, packed into the [3]
     # band operand and riding the maps as a replicated arg — the kernels'
     # tile skipping is a runtime predicate either way
     def fwd_body_dyn(band, q, k, v):
-        qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        o, lse = _flash_fwd(qt, kt, vt, causal, None, block_q, block_k,
-                            interpret, scale=scale, softcap=logit_softcap,
-                            band=band)
-        return o.transpose(0, 2, 1, 3), lse
+        return _flash_fwd(q, k, v, causal, None, block_q, block_k, interpret,
+                          scale=scale, softcap=logit_softcap, band=band,
+                          **head_major)
 
-    def bwd_body_dyn(band, qt, kt, vt, o, lse, do):
-        dq, dk, dv, _ = _flash_bwd(causal, None, block_q, block_k,
-                                   interpret, scale, logit_softcap,
-                                   (qt, kt, vt, o, lse, band),
-                                   do.transpose(0, 2, 1, 3))
-        return tuple(g.transpose(0, 2, 1, 3) for g in (dq, dk, dv))
+    def bwd_body_dyn(band, q, k, v, o, lse, do):
+        return _flash_bwd(causal, None, block_q, block_k, interpret, scale,
+                          logit_softcap, (q, k, v, o, lse, band), do,
+                          **head_major)[:3]
 
-    res_specs = (spec_bhsd, spec_bhsd, spec_bhsd, spec_bhsd, spec_bhs)
+    res_specs = (spec_bshd, spec_bshd, spec_bshd, spec_bshd, spec_bhs)
     band_spec = P(None)   # [3] int32, replicated across every manual axis
 
     def _maps(dyn=False):
@@ -906,18 +1000,13 @@ def make_sharded_flash_attention(
         out, lse = _maps()[0](q, k, v)
         # same remat tags as the plain path (_flash_vjp_fwd): a
         # REMAT_POLICIES["attn"] policy keeps the attention output + lse so
-        # backward never re-runs the forward kernel. The tag sits on the
-        # PRIMAL output (the kernel-layout residual is a transpose of it,
-        # rebuilt in vjp_bwd) — tagging a residual-only map output instead
-        # would leave `out` unsaved and drag the map into the recompute
+        # backward never re-runs the forward kernel
         out = checkpoint_name(out, "flash_out")
         lse = checkpoint_name(lse, "flash_lse")
         return out, (q, k, v, out, lse)
 
     def vjp_bwd(res, do):
-        q, k, v, out, lse = res
-        qt, kt, vt, o = (x.transpose(0, 2, 1, 3) for x in (q, k, v, out))
-        return _maps()[1](qt, kt, vt, o, lse, do)
+        return _maps()[1](*res, do)
 
     sharded_flash.defvjp(vjp_fwd, vjp_bwd)
 
@@ -932,9 +1021,8 @@ def make_sharded_flash_attention(
         return out, (q, k, v, out, lse, band)
 
     def vjp_bwd_dyn(res, do):
-        q, k, v, out, lse, band = res
-        qt, kt, vt, o = (x.transpose(0, 2, 1, 3) for x in (q, k, v, out))
-        grads = _maps(dyn=True)[1](band, qt, kt, vt, o, lse, do)
+        *res, band = res
+        grads = _maps(dyn=True)[1](band, *res, do)
         return (*grads, np.zeros(band.shape, jax.dtypes.float0))
 
     sharded_flash_dyn.defvjp(vjp_fwd_dyn, vjp_bwd_dyn)
@@ -1000,7 +1088,7 @@ def make_sharded_flash_attention(
         note_attention("flash", ("forced" if forced else
                                  "auto: a shape the sharded kernel takes")
                        + "; " + describe_walk(q, k, causal, wcall, block_q,
-                                              block_k))
+                                              block_k, seq_major=False))
         in_manual = _in_manual_context()
         if wcall is window_default or (isinstance(wcall, int)
                                        and wcall == window_default):
@@ -1064,10 +1152,6 @@ def flash_attention(
             f"flash_attention needs seq divisible by 8 and head_dim by 64; "
             f"got seq_q={q.shape[1]}, seq_k={k.shape[1]}, head_dim={d} — "
             f"pad the sequence or use impl='xla'")
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
     static_window, band = _resolve_band(window)
-    o = _flash(qt, kt, vt, band, causal, static_window, block_q, block_k,
-               interpret, scale, logit_softcap)
-    return o.transpose(0, 2, 1, 3)
+    return _flash(q, k, v, band, causal, static_window, block_q, block_k,
+                  interpret, scale, logit_softcap)

@@ -20,9 +20,11 @@ manual:
   hops), overlapping transfer with compute; per-pair partial results merge
   with the standard (o, lse) online-softmax combine in fp32.
 - **flash kernel per chunk pair**: each live (q-chunk, kv-chunk) pair runs
-  the Pallas flash kernel (``flash_attention._flash_fwd``) — scores never
-  materialize outside VMEM tiles, and GQA is kernel-native (no K/V
-  expansion). Future pairs are *skipped* by ``lax.cond`` (no FLOPs issued);
+  the Pallas flash kernel (``flash_attention._flash_fwd``) on the
+  ``[B, S_c, H, D]`` chunks as the ring holds them (no relayout of its own)
+  — scores never materialize outside VMEM tiles, and GQA is kernel-native
+  (no K/V expansion). Future pairs are *skipped* by ``lax.cond`` (no
+  FLOPs issued);
   diagonal pairs use the kernel's causal mode.
 - **hand-written ring backward** (``jax.custom_vjp``): the backward re-runs
   the ring with the *global* logsumexp and ``delta = rowsum(do*o)`` feeding
@@ -57,7 +59,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .dispatch import note_attention, resolve_interpret
 from .flash_attention import (_flash_fwd, _pack_band, check_static_window,
-                              flash_bwd_with_stats)
+                              flash_bwd_with_stats, row_dots)
 
 NEG_INF = -1e30
 
@@ -111,14 +113,18 @@ def _from_zigzag(x, idx, axis_name, cp):
 
 
 def _merge(o, lse, o_i, lse_i):
-    """Combine two normalized flash partials ([B,H,S,D] fp32, [B,H,S] fp32)."""
+    """Combine two normalized flash partials (o ``[B,S,H,D]`` fp32 as the
+    kernels take and return it, lse ``[B,H,S]`` fp32 as they keep it: the
+    weights, one a row, cross to o's layout)."""
     mx = jnp.maximum(lse, lse_i)
     mx_safe = jnp.where(mx < NEG_INF / 2, 0.0, mx)  # both-empty rows
     w0 = jnp.exp(lse - mx_safe)
     w1 = jnp.exp(lse_i - mx_safe)
     tot = w0 + w1
     safe_tot = jnp.where(tot == 0.0, 1.0, tot)
-    o_new = (o * w0[..., None] + o_i * w1[..., None]) / safe_tot[..., None]
+    w0_o, w1_o, tot_o = (w.swapaxes(1, 2)[..., None]
+                         for w in (w0, w1, safe_tot))
+    o_new = (o * w0_o + o_i * w1_o) / tot_o
     lse_new = jnp.where(tot == 0.0, NEG_INF, mx_safe + jnp.log(safe_tot))
     return o_new, lse_new
 
@@ -179,8 +185,8 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
         across chunk boundaries."""
         for a in range(2):
             for c in range(2):
-                qa, kc, vc = qz[a], k_blk[c], v_blk[c]
-                o_a, lse_a = o[a], lse[a]
+                qa, kc, vc = qz[:, a], k_blk[:, c], v_blk[:, c]
+                o_a, lse_a = o[:, a], lse[:, a]
 
                 if window is not None:
                     band = _pack_band(window, my_chunks[a] * s_c,
@@ -213,8 +219,8 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
                         lambda: jax.lax.cond(rel == 1,
                                              functools.partial(live, True),
                                              functools.partial(live, False)))
-                o = o.at[a].set(o_a)
-                lse = lse.at[a].set(lse_a)
+                o = o.at[:, a].set(o_a)
+                lse = lse.at[:, a].set(lse_a)
         return o, lse
 
     def ring_fwd_body(member, q, k, v, window=None):
@@ -226,16 +232,17 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
                              f"chunks); pad seq to a multiple of {2 * cp}")
         s_c = s_loc // 2
 
-        # zigzag chunks in kernel layout [2, B, H, S_c, D]
-        qz = _to_zigzag(q, idx, axis_name, cp).transpose(1, 0, 3, 2, 4)
-        kz = _to_zigzag(k, idx, axis_name, cp).transpose(1, 0, 3, 2, 4)
-        vz = _to_zigzag(v, idx, axis_name, cp).transpose(1, 0, 3, 2, 4)
+        # zigzag chunks [B, 2, S_c, H, D]: a chunk goes to the kernels as
+        # it lies; lse stays as they keep it, [B, 2, H, S_c]
+        qz = _to_zigzag(q, idx, axis_name, cp)
+        kz = _to_zigzag(k, idx, axis_name, cp)
+        vz = _to_zigzag(v, idx, axis_name, cp)
 
         my_chunks = (idx, 2 * cp - 1 - idx)
         w = None if window is None else window[0]
 
-        o = jnp.zeros((2, b, hq, s_c, d), jnp.float32)
-        lse = jnp.full((2, b, hq, s_c), NEG_INF, jnp.float32)
+        o = jnp.zeros((b, 2, s_c, hq, d), jnp.float32)
+        lse = jnp.full((b, 2, hq, s_c), NEG_INF, jnp.float32)
 
         if use_scan:
             def hop(carry, i):
@@ -261,8 +268,7 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
                 if i < cp - 1:
                     k_blk, v_blk = k_nxt, v_nxt
 
-        out = _from_zigzag(o.astype(q.dtype).transpose(1, 0, 3, 2, 4),
-                           idx, axis_name, cp)
+        out = _from_zigzag(o.astype(q.dtype), idx, axis_name, cp)
         # ONLY the primal output + seq-layout lse leave the map (cf. the
         # sharded-flash wrapper): a shard_map eqn is atomic under
         # jax.checkpoint's partial-eval, so zigzag-layout residual outputs
@@ -271,7 +277,7 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
         # The bwd body re-zigzags from the raw inputs + saved outputs
         # instead (a few ppermutes), which is what lets the
         # REMAT_POLICIES["attn"] tags actually skip the fwd ring.
-        lse_seq = _from_zigzag(lse.transpose(1, 0, 3, 2), idx, axis_name, cp)
+        lse_seq = _from_zigzag(lse.swapaxes(2, 3), idx, axis_name, cp)
         return out, lse_seq
 
     def ring_bwd_body(member, q, k, v, out, lse_seq, do, window=None):
@@ -280,27 +286,26 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
         my_chunks = (idx, 2 * cp - 1 - idx)
         w = None if window is None else window[0]
 
-        # rebuild the zigzag/kernel layouts the fwd used (cheap ppermutes;
-        # see the fwd-body note on why these are not residuals)
-        qz = _to_zigzag(q, idx, axis_name, cp).transpose(1, 0, 3, 2, 4)
-        kz = _to_zigzag(k, idx, axis_name, cp).transpose(1, 0, 3, 2, 4)
-        vz = _to_zigzag(v, idx, axis_name, cp).transpose(1, 0, 3, 2, 4)
-        o = (_to_zigzag(out, idx, axis_name, cp).transpose(1, 0, 3, 2, 4)
-             .astype(jnp.float32))
-        lse = _to_zigzag(lse_seq, idx, axis_name, cp).transpose(1, 0, 3, 2)
+        # rebuild the zigzag chunks the fwd used (cheap ppermutes; see the
+        # fwd-body note on why these are not residuals)
+        qz = _to_zigzag(q, idx, axis_name, cp)
+        kz = _to_zigzag(k, idx, axis_name, cp)
+        vz = _to_zigzag(v, idx, axis_name, cp)
+        o = _to_zigzag(out, idx, axis_name, cp).astype(jnp.float32)
+        lse = _to_zigzag(lse_seq, idx, axis_name, cp).swapaxes(2, 3)
 
-        doz = _to_zigzag(do, idx, axis_name, cp).transpose(1, 0, 3, 2, 4)
-        doz = doz.astype(jnp.float32)
+        doz = _to_zigzag(do, idx, axis_name, cp).astype(jnp.float32)
         # global softmax stats: the flash-bwd identity needs the FINAL lse and
         # delta = rowsum(do * o_final) — per-pair contributions then sum to
         # the exact gradient
-        delta = jnp.einsum("abhsd,abhsd->abhs", doz, o)        # [2,B,H,S_c]
+        delta = jnp.stack([row_dots(doz[:, a], o[:, a]) for a in range(2)],
+                          axis=1)                              # [B,2,H,S_c]
 
         dq = jnp.zeros(qz.shape, jnp.float32)
         dk = jnp.zeros(kz.shape, jnp.float32)
         dv = jnp.zeros(vz.shape, jnp.float32)
 
-        s_c = qz.shape[3]
+        s_c = qz.shape[2]
 
         def _bwd_pairs(k_blk, v_blk, dq, dk, dv, kv_chunks):
             """One hop's 4 flash-bwd calls; accumulation runs INSIDE the
@@ -311,9 +316,9 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
             chunk-pair skip predicate keeps dead pairs free."""
             for a in range(2):
                 for c in range(2):
-                    qa, kc, vc = qz[a], k_blk[c], v_blk[c]
-                    doa, lsea, dta = doz[a], lse[a], delta[a]
-                    dq_a, dk_c, dv_c = dq[a], dk[c], dv[c]
+                    qa, kc, vc = qz[:, a], k_blk[:, c], v_blk[:, c]
+                    doa, lsea, dta = doz[:, a], lse[:, a], delta[:, a]
+                    dq_a, dk_c, dv_c = dq[:, a], dk[:, c], dv[:, c]
 
                     if w is not None:
                         band = _pack_band(w, my_chunks[a] * s_c,
@@ -352,9 +357,9 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
                             lambda: jax.lax.cond(
                                 rel == 1, functools.partial(live, True),
                                 functools.partial(live, False)))
-                    dq = dq.at[a].set(dq_a)
-                    dk = dk.at[c].set(dk_c)
-                    dv = dv.at[c].set(dv_c)
+                    dq = dq.at[:, a].set(dq_a)
+                    dk = dk.at[:, c].set(dk_c)
+                    dv = dv.at[:, c].set(dv_c)
             return dq, dk, dv
 
         if use_scan:
@@ -390,8 +395,7 @@ def _build_ring(axis_name: str, cp: int, causal: bool, interpret: bool,
                     k_blk, v_blk = k_nxt, v_nxt
 
         def back(x):
-            return _from_zigzag(x.astype(in_dtype).transpose(1, 0, 3, 2, 4),
-                                idx, axis_name, cp)
+            return _from_zigzag(x.astype(in_dtype), idx, axis_name, cp)
 
         return back(dq), back(dk), back(dv)
 

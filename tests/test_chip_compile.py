@@ -103,6 +103,11 @@ def named(call: str, name: str) -> bool:
     return re.search(rf"(^|_){name}(_|\.|$)", call) is not None
 
 
+# an instruction with an array result: its name, dtype, dims and opcode
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]+)\][^ ]* "
+                          r"([\w\-]+)\(")
+
+
 def pool_sized_ops(text: str, *pool_shapes, names: bool = False) -> list:
     """Opcodes (with ``names``: the instructions' names too, ``opcode
     name``) of the compiled program's instructions whose array result holds
@@ -112,12 +117,11 @@ def pool_sized_ops(text: str, *pool_shapes, names: bool = False) -> list:
               for n in (math.prod(shape), math.prod(shape[1:]))}
     sized = []
     for line in text.splitlines():
-        found = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\][^ ]* "
-                         r"([\w\-]+)\(", line)
-        if found and found.group(2) and math.prod(
-                int(x) for x in found.group(2).split(",")) in counts:
-            sized.append(f"{found.group(3)} {found.group(1)}" if names
-                         else found.group(3))
+        found = _INSTRUCTION.match(line)
+        if found and math.prod(
+                int(x) for x in found.group(3).split(",")) in counts:
+            sized.append(f"{found.group(4)} {found.group(1)}" if names
+                         else found.group(4))
     return sized
 
 
@@ -154,22 +158,48 @@ def assert_experts_read_in_place(text: str, *leaf_shapes) -> None:
 
 # ---- training attention ----------------------------------------------------
 
-# the three train cells' attention, (batch, seq, q heads, kv heads), bf16
+# the four train cells' attention, (batch, seq, q heads, kv heads), bf16
 # at head_dim 128: qwen3-0.6b at 2048 and 8192, olmo2-7b's one-chip share of
-# the FSDP cell
+# the FSDP cell, Laguna's window and full layers
 CELL_SHAPES = {"seq2048": (8, 2048, 16, 8), "seq8192": (2, 8192, 16, 8),
-               "fsdp4.seq4096": (2, 4096, 32, 32)}
+               "fsdp4.seq4096": (2, 4096, 32, 32),
+               "laguna.window": (2, 8192, 64, 8),
+               "laguna.full": (2, 8192, 48, 8)}
 FLASH_CASES = ([("seq2048", extras) for extras in (
     {}, {"window": 512}, {"logit_softcap": 30.0})]
-    + [(cell, {}) for cell in ("seq8192", "fsdp4.seq4096")])
+    + [(cell, {}) for cell in ("seq8192", "fsdp4.seq4096")]
+    + [("laguna.window", {"window": 512}), ("laguna.full", {})])
+FLASH_IDS = ["causal", "banded", "softcap", "seq8192", "fsdp4.seq4096",
+             "laguna.window", "laguna.full"]
+FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+
+
+def operand_sized_moves(text: str, *shapes, dtypes=("bf16", "f32"),
+                        rank=None) -> list:
+    """``opcode name dtype[dims]`` of the compiled program's relayouts of an
+    array as large as one of ``shapes``: a ``copy``, a ``transpose``, a
+    ``reshape`` the compiler could not make a bitcast, or a fusion named
+    after one (``rank``: of results with that many dimensions alone)."""
+    counts = {math.prod(shape) for shape in shapes}
+    moves = []
+    for line in text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        name, dtype, dims, op = found.groups()
+        dims = [int(x) for x in dims.split(",")]
+        if (math.prod(dims) in counts and dtype in dtypes
+                and rank in (None, len(dims))
+                and (op in ("copy", "transpose", "reshape") or op == "fusion"
+                     and re.search("copy|transpose", name))):
+            moves.append(f"{op} {name} {dtype}{dims}")
+    return moves
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
-@pytest.mark.parametrize(
-    "cell,extras", FLASH_CASES,
-    ids=["causal", "banded", "softcap", "seq8192", "fsdp4.seq4096"])
+@pytest.mark.parametrize("cell,extras", FLASH_CASES, ids=FLASH_IDS)
 def test_flash_attention_compiles(chip_compile, cell, extras, backward):
-    """The flash kernels at the three train cells' shapes (and the smoke's
+    """The flash kernels at the four train cells' shapes (and the smoke's
     banded and soft-capped ones), forward and backward, under their own
     names and no other kernel."""
     def fwd(q, k, v):
@@ -184,11 +214,97 @@ def test_flash_attention_compiles(chip_compile, cell, extras, backward):
     qs = ((b, seq, hq, D), jnp.bfloat16)
     ks = ((b, seq, hkv, D), jnp.bfloat16)
     calls = kernel_calls(chip_compile(fwd_bwd if backward else fwd, qs, ks, ks))
-    names = ("flash_fwd", "flash_dq", "flash_dkv") if backward else (
-        "flash_fwd",)
+    names = FLASH_KERNELS if backward else FLASH_KERNELS[:1]
     assert len(calls) == len(names), calls
     for name in names:
         assert any(named(c, name) for c in calls), (name, calls)
+
+
+@pytest.mark.parametrize("cell,extras", FLASH_CASES, ids=FLASH_IDS)
+def test_flash_kernels_take_the_projections_arrays_as_they_lie(
+        chip_compile, cell, extras):
+    """Forward and backward on q, k, v as the projections leave them and o
+    as the output projection takes it, ``[B, S, H*D]``, viewed ``[B, S, H,
+    D]`` for the call as ``attention_sublayer`` views them: the three
+    kernels under their names and NOTHING of q's, k's, v's or o's size
+    moved before, between or behind them: no ``copy``, ``transpose``,
+    relayouting ``reshape`` or fusion named after one (until PR 46 the
+    kernels addressed ``[B, H, S, D]`` and each operand and gradient was
+    transposed). The operands are 3-D on purpose: a 4-D PARAMETER's tiled
+    layout on the chip tiles (H, D), so its ``[B, S, H*D]`` view is a
+    relayout there; in a model the 4-D array lives inside the fusions
+    between a projection and the call alone (the test below)."""
+    b, seq, hq, hkv = CELL_SHAPES[cell]
+
+    def heads(x):
+        return x.reshape(b, seq, -1, D)
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            o = flash_attention(heads(q), heads(k), heads(v), causal=True,
+                                interpret=False, **extras)
+            return o.reshape(b, seq, -1).astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    qs = ((b, seq, hq * D), jnp.bfloat16)
+    ks = ((b, seq, hkv * D), jnp.bfloat16)
+    text = chip_compile(fwd_bwd, qs, ks, ks)
+    calls = kernel_calls(text)
+    assert len(calls) == 3, calls
+    for name in FLASH_KERNELS:
+        assert any(named(c, name) for c in calls), (name, calls)
+    assert not operand_sized_moves(text, qs[0], ks[0])
+
+
+def test_attention_sublayers_relayout_no_heads_in_float32(chip_compile,
+                                                          monkeypatch):
+    """Two of ``models/llama.py``'s attention sublayers at the seq2048
+    cell's shape (qwen3-0.6b: ``[8, 2048, 1024]`` bf16, 16 / 8 heads of 128,
+    per-head QK-norm, rope), scanned with ``jax.checkpoint`` round each as
+    the model scans its layers, forward and backward with the Mosaic kernels
+    on. Until PR 46 each projection's float32 result was copied whole into
+    the kernels' head-major layout in front of the QK-norm (134 MB for q,
+    67 for k, forward and rematted) and each gradient back (four float32
+    4-D copies in this program). Now the norm and rope fusions run in the
+    projection's own layout and NO float32 ``copy`` or ``transpose`` of a
+    ``[B, S, H, D]`` array is left; what is (PERF.md section 7): one bf16
+    copy of q and of k in front of ``flash_fwd``, and dq's and dk's float32
+    3-D copies into the layout the projections' backward products take."""
+    from distributed_training_guide_tpu.models import get_model, llama
+    from distributed_training_guide_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "resolve_interpret", lambda i: False)
+    config = get_model("qwen3-0.6b").config
+    b, seq, layers = CELL_SHAPES["seq2048"][0], SEQ, 2
+    e, d = config.hidden_size, config.head_size
+    hq, hkv = config.num_heads, config.num_kv_heads
+    assert (e, hq, hkv, d, config.qk_norm) == (1024, HQ, HKV, D, True)
+    shapes = {"wq": (e, hq * d), "wk": (e, hkv * d), "wv": (e, hkv * d),
+              "wo": (hq * d, e), "q_norm": (d,), "k_norm": (d,), "norm": (e,)}
+    names = sorted(shapes)
+
+    def fwd_bwd(x, *leaves):
+        def loss(x, params):
+            positions = jnp.broadcast_to(jnp.arange(seq)[None], (b, seq))
+
+            def layer(x, p):
+                p = dict(p)
+                return x + llama.attention_sublayer(
+                    config, x, p, p.pop("norm"), positions, "flash"), None
+
+            x, _ = jax.lax.scan(jax.checkpoint(layer), x, params)
+            return x.astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1))(x, dict(zip(names, leaves)))
+
+    text = chip_compile(fwd_bwd, ((b, seq, e), config.dtype), *(
+        ((layers, *shapes[n]), config.param_dtype) for n in names))
+    calls = kernel_calls(text)
+    assert [sum(named(c, n) for c in calls) for n in FLASH_KERNELS] == [
+        2, 1, 1], calls            # forward, rematted forward; backward
+    assert not operand_sized_moves(text, (b, seq, hq, d), (b, seq, hkv, d),
+                                   dtypes=("f32",), rank=4)
 
 
 # ---- the serve attend: decode, verify, chunk ------------------------------

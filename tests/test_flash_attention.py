@@ -53,10 +53,18 @@ def test_noncausal_forward():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
+# head_dim 128 takes the kernels' seq-major operands ([B, S, H*D] as the
+# model holds them), 32 and 64 the head-major ones behind their transposes
+# (``fa._Operands``): every grid below runs both, the seq-major ones also at
+# Laguna's 64 / 8 heads (eight query heads read one kv column block)
+HEADS_AND_DIMS = [(4, 4, 32), (4, 2, 32), (4, 2, 128), (64, 8, 128),
+                  (4, 2, 64)]
+
+
 @DTYPES
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
-def test_grads_match_xla(hq, hkv, dtype):
-    q, k, v = make_qkv(1, 64, hq, hkv, 32, dtype, seed=1)
+@pytest.mark.parametrize("hq,hkv,d", HEADS_AND_DIMS)
+def test_grads_match_xla(hq, hkv, d, dtype):
+    q, k, v = make_qkv(1, 64, hq, hkv, d, dtype, seed=1)
 
     def loss_flash(q, k, v):
         o = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
@@ -90,7 +98,9 @@ def test_forced_flash_rejects_untiled_shapes():
         flash_attention(q, k, v, causal=True, interpret=False)
 
 
-def test_sharded_flash_partitions_instead_of_replicating(eight_devices):
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_sharded_flash_partitions_instead_of_replicating(eight_devices,
+                                                         head_dim):
     """GSPMD's fallback for the Mosaic custom call is gather-and-replicate;
     the shard_map wrapper must instead keep the kernel local: numerics match
     the dense reference AND the output/grad shardings keep their mesh axes
@@ -100,10 +110,17 @@ def test_sharded_flash_partitions_instead_of_replicating(eight_devices):
     from distributed_training_guide_tpu.ops.flash_attention import (
         make_sharded_flash_attention)
 
+    from distributed_training_guide_tpu.ops import dispatch
+
     mesh = Mesh(np.array(eight_devices).reshape(2, 4), ("dp", "tp"))
-    q, k, v = make_qkv(4, 128, 8, 4, 64, seed=2)
+    q, k, v = make_qkv(4, 128, 8, 4, head_dim, seed=2)
     attn = make_sharded_flash_attention(mesh, batch_axes=("dp",),
                                         head_axis="tp", forced=True)
+    with dispatch.record_attention() as record:   # the maps' kernels keep
+        attn(q, k, v)                             # head-major operands
+    assert record["flash"].endswith(
+        "; head-major operands" if head_dim == 128
+        else "; head-major (head_dim 64) operands"), record
     sh = NamedSharding(mesh, P("dp", None, "tp", None))
     qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
 
@@ -131,7 +148,7 @@ def test_sharded_flash_partitions_instead_of_replicating(eight_devices):
     # partitionable XLA path instead of crashing in shard_map
     attn_auto = make_sharded_flash_attention(mesh, batch_axes=("dp",),
                                              head_axis="tp", forced=False)
-    q3, k3, v3 = make_qkv(3, 128, 8, 4, 64, seed=4)
+    q3, k3, v3 = make_qkv(3, 128, 8, 4, head_dim, seed=4)
     ref3 = _xla_attention(q3, k3, v3, True, None, None)
     np.testing.assert_allclose(np.asarray(attn_auto(q3, k3, v3)),
                                np.asarray(ref3), rtol=2e-4, atol=2e-4)
@@ -256,12 +273,12 @@ EXTRAS_GRID = [
 
 @pytest.mark.parametrize("extras", EXTRAS_GRID,
                          ids=lambda e: "+".join(sorted(e)))
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
-def test_attention_extras_fwd_and_grads_match_xla(extras, hq, hkv):
+@pytest.mark.parametrize("hq,hkv,d", HEADS_AND_DIMS)
+def test_attention_extras_fwd_and_grads_match_xla(extras, hq, hkv, d):
     from distributed_training_guide_tpu.ops.attention import (
         multihead_attention)
 
-    q, k, v = make_qkv(1, 64, hq, hkv, 32, seed=3)
+    q, k, v = make_qkv(1, 64, hq, hkv, d, seed=3)
 
     def loss(attn_fn):
         def f(q, k, v):
@@ -372,6 +389,7 @@ TILE_S, TILE_BQ, TILE_BK = 1024, 128, 256      # an 8 x 4 grid of tiles
 # the middle of a tile (query 640 sees keys from 193 on); the ring's chunk
 # pairs: the same chunk, and a past chunk under a window
 BANDS = {"causal": (None, 0, 0), "window": (448, 0, 0),
+         "window-512": (512, 0, 0),      # Laguna's window layers
          "ring-diagonal": (None, 1024, 1024), "ring-past": (1400, 1024, 0)}
 
 
@@ -409,7 +427,7 @@ def test_tile_kinds_match_the_element_mask(band):
 
 
 @pytest.mark.parametrize("by_rows", [True, False], ids=["rows", "columns"])
-@pytest.mark.parametrize("band", ["causal", "window"])
+@pytest.mark.parametrize("band", ["causal", "window", "window-512"])
 def test_live_tiles_are_the_grid_a_static_band_walks(band, by_rows):
     """The prefetched tile lists hold exactly the live tiles, outer index
     first and each walk in order; an outer index with no live tile (kv
@@ -458,24 +476,46 @@ def test_the_line_a_flash_call_leaves_counts_its_tiles():
     assert line(8192, jnp.bfloat16, window=4096).startswith("tiles 1024x1024")
     assert "traced window" in line(4096, jnp.bfloat16,
                                    window=jnp.asarray(1024))
+    # which operand layout the kernels took: by head_dim, and head-major
+    # where the caller asks for it (the sharded wrapper's maps do)
+    for seq, kw in [(2048, {}), (4096, dict(window=jnp.asarray(1024)))]:
+        assert line(seq, jnp.bfloat16, **kw).endswith("; seq-major operands")
+        assert line(seq, jnp.bfloat16, seq_major=False, **kw).endswith(
+            "; head-major operands")
     q, k, v = make_qkv(1, 64, 4, 2, 32)
     with dispatch.record_attention() as record:
         multihead_attention(q, k, v, impl="flash")
-    assert record["flash"].startswith("forced; tiles 64x64, a walk: 0 "
-                                      "interior / 1 edge / 0 dead")
+    assert record["flash"] == ("forced; tiles 64x64, a walk: 0 interior / 1 "
+                               "edge / 0 dead; head-major (head_dim 32) "
+                               "operands")
 
 
-@pytest.mark.parametrize("hq,hkv", [(4, 2), (8, 1)])
-@pytest.mark.parametrize("band", ["causal", "window", "traced-window",
-                                  "ring-diagonal", "ring-past"])
-def test_tiles_of_every_kind_fwd_and_grads_match_xla(band, hq, hkv):
-    """Forward and all three gradients against ``_xla_attention`` on a grid
-    that holds interior, edge and dead tiles at once. The static and traced
+EVERY_BAND = ["causal", "window", "traced-window", "ring-diagonal",
+              "ring-past"]
+# (band, hq, hkv, head_dim): every band at head_dim 32 (head-major operands);
+# seq-major operands at the dense cells' 16 / 8 heads of 128 under a static
+# and a traced band, and as the ring packs them; 64-wide heads (64 / 8 heads
+# run the small grids above: a 1024-token walk of them is minutes interpreted)
+TILE_CASES = ([(band, hq, hkv, 32) for hq, hkv in [(4, 2), (8, 1)]
+               for band in EVERY_BAND]
+              + [(band, 16, 8, 128)
+                 for band in ["causal", "window-512", "traced-window"]]
+              + [(band, 4, 2, 128) for band in ["ring-diagonal", "ring-past"]]
+              + [(band, 4, 2, 64) for band in ["window-512", "ring-past"]])
+
+
+@pytest.mark.parametrize("band,hq,hkv,d", TILE_CASES)
+def test_tiles_of_every_kind_fwd_and_grads_match_xla(band, hq, hkv, d):
+    """Forward and all three gradients against ``_xla_attention`` (float32)
+    on a grid that holds interior, edge and dead tiles at once, on
+    ``[B, S, H, D]`` operands as the model holds them. The static and traced
     bands go through the public entry; the ring's offsets through
     ``_flash_fwd`` / ``flash_bwd_with_stats`` with a packed band, as
-    ``ops/ring_attention.py`` calls them."""
+    ``ops/ring_attention.py`` calls them on its chunks."""
     window, q_off, k_off = BANDS["window" if band == "traced-window" else band]
-    q, k, v = make_qkv(1, TILE_S, hq, hkv, 32, seed=7)
+    assert fa._Operands(d).seq_major == (d == 128)
+    assert not fa._Operands(d, seq_major=False).seq_major
+    q, k, v = make_qkv(1, TILE_S, hq, hkv, d, seed=7)
     do = jax.random.normal(jax.random.key(8), q.shape, q.dtype)
     blocks = dict(block_q=TILE_BQ, block_k=TILE_BK, interpret=True)
 
@@ -487,12 +527,10 @@ def test_tiles_of_every_kind_fwd_and_grads_match_xla(band, hq, hkv):
     want_grads = vjp(do)
     if band.startswith("ring"):
         packed = fa._pack_band(window, q_off, k_off)
-        qt, kt, vt, dot = (x.transpose(0, 2, 1, 3) for x in (q, k, v, do))
-        o, lse = fa._flash_fwd(qt, kt, vt, True, None, band=packed, **blocks)
-        delta = jnp.einsum("bhsd,bhsd->bhs", dot, o)
-        grads = fa.flash_bwd_with_stats(qt, kt, vt, dot, lse, delta,
+        got, lse = fa._flash_fwd(q, k, v, True, None, band=packed, **blocks)
+        delta = jnp.einsum("bshd,bshd->bhs", do, got)
+        grads = fa.flash_bwd_with_stats(q, k, v, do, lse, delta,
                                         causal=True, band=packed, **blocks)
-        got, *grads = (x.transpose(0, 2, 1, 3) for x in (o, *grads))
     else:
         if band == "traced-window":
             fn = jax.jit(lambda q, k, v, w: flash_attention(
@@ -504,4 +542,29 @@ def test_tiles_of_every_kind_fwd_and_grads_match_xla(band, hq, hkv):
         grads = vjp(do)
     assert_close(got, want, 1e-5)
     for name, a, b in zip("qkv", grads, want_grads):
+        assert_close(a, b, 1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["causal", "window"])
+@pytest.mark.parametrize("d", [128, 64], ids=["seq-major", "head-major"])
+def test_ring_hops_on_model_layout_chunks_match_dense(eight_devices, d,
+                                                      window):
+    """The ring hands each hop's ``[B, S_c, H, D]`` chunks to the kernels as
+    it holds them (no relayout of its own since PR 46) and merges their
+    partials in that layout: forward and the three gradients against the
+    dense float32 reference, as they matched it before, GQA, under both
+    operand layouts, with and without a window across chunk boundaries."""
+    from distributed_training_guide_tpu.ops.ring_attention import (
+        make_ring_attention)
+    from distributed_training_guide_tpu.parallel import make_mesh
+
+    ring = make_ring_attention(make_mesh(cp=2, devices=eight_devices[:2]),
+                               window=window)
+    q, k, v = make_qkv(2, 64, 4, 2, d, seed=11)
+    do = jax.random.normal(jax.random.key(12), q.shape, q.dtype)
+    want, vjp = jax.vjp(lambda *a: _xla_attention(*a, True, None, None,
+                                                  window), q, k, v)
+    got, ring_vjp = jax.vjp(ring, q, k, v)
+    assert_close(got, want, 1e-5)
+    for name, a, b in zip("qkv", ring_vjp(do), vjp(do)):
         assert_close(a, b, 1e-4, err_msg=f"d{name}")
