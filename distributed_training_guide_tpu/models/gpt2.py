@@ -238,7 +238,6 @@ def apply(
     attn_impl: str = "auto",
     activation_sharding: Optional[Any] = None,
     return_hidden: bool = False,
-    layer_schedule=None,
 ) -> jnp.ndarray:
     del activation_sharding  # gpt2 path is small; SP constraint not needed
     standard_layout = positions is None
@@ -251,18 +250,15 @@ def apply(
     block = partial(_block, config, positions=positions, attn_impl=attn_impl,
                     standard_layout=standard_layout)
 
-    if layer_schedule is not None:  # explicit latency-hiding schedule
-        x = layer_schedule(block, x, params["layers"])  # (ops/overlap.py)
-    else:
-        def scan_body(carry, layer_params):
-            return block(carry, layer_params), None
+    def scan_body(carry, layer_params):
+        return block(carry, layer_params), None
 
-        if remat:
-            policy = remat_policy or jax.checkpoint_policies.nothing_saveable
-            scan_body = jax.checkpoint(scan_body, policy=policy,
-                                       prevent_cse=False)
+    if remat:
+        policy = remat_policy or jax.checkpoint_policies.nothing_saveable
+        scan_body = jax.checkpoint(scan_body, policy=policy,
+                                   prevent_cse=False)
 
-        x, _ = jax.lax.scan(scan_body, x, params["layers"])
+    x, _ = jax.lax.scan(scan_body, x, params["layers"])
     if return_hidden:
         return final_hidden(config, params, x)
     return lm_head_logits(config, params, x)

@@ -337,7 +337,6 @@ def apply_with_aux(
     return_metrics: bool = False,
     return_hidden: bool = False,
     moe_ep=None,
-    layer_schedule=None,
 ):
     """Forward -> (logits [B, S, V] fp32, aux loss (0: none is published)[,
     metrics]): ``models/moe.apply_with_aux``'s contract over walked layers.
@@ -345,11 +344,11 @@ def apply_with_aux(
     (the chunked loss; pair with ``output_weights``). ``return_metrics``
     adds :data:`TRAIN_METRICS`. ``remat`` puts every layer under its own
     ``jax.checkpoint`` with ``remat_policy``."""
-    if moe_ep is not None or layer_schedule is not None or callable(attn_impl):
+    if moe_ep is not None or callable(attn_impl):
         raise NotImplementedError(
             "models/laguna.py trains on one device: the sharded ragged "
-            "exchange, the overlap schedule and the sharded attention "
-            "wrappers are not threaded through its walked layers")
+            "exchange and the sharded attention wrappers are not threaded "
+            "through its walked layers")
     standard_layout = positions is None
     if positions is None:
         positions = jnp.arange(input_ids.shape[1])[None, :]

@@ -537,7 +537,6 @@ def apply(
     attn_impl: str = "auto",
     activation_sharding: Optional[Any] = None,
     return_hidden: bool = False,
-    layer_schedule=None,
 ) -> jnp.ndarray:
     """Forward pass -> logits [B, S, V] in float32 (or the final-normed
     hidden states [B, S, E] when ``return_hidden``, for chunked losses).
@@ -547,11 +546,6 @@ def apply(
     ``06-tensor-parallel/train_llm.py:210-212``.
     ``activation_sharding`` optionally constrains the inter-block residual
     stream (e.g. P('dp', 'tp', None) for sequence parallelism).
-    ``layer_schedule`` (ops/overlap.py, --overlap-schedule): replaces the
-    layer ``lax.scan`` with the explicit latency-hiding schedule — unrolled
-    layers, manual per-layer fsdp all-gather/reduce-scatter, per-cell remat
-    owned by the schedule (the ``remat``/``remat_policy`` args were baked in
-    at schedule build).
     """
     standard_layout = positions is None
     if positions is None:
@@ -565,11 +559,6 @@ def apply(
                     standard_layout=standard_layout)
 
     wins = _layer_window_column(config)
-    if layer_schedule is not None:
-        x = layer_schedule(block, x, params["layers"], wins)
-        if return_hidden:
-            return final_hidden(config, params, x)
-        return lm_head_logits(config, params, x)
     if wins is not None:
         # per-layer sliding-window pattern (Gemma-2 alternates sliding /
         # full): the window rides the scan as a traced per-layer scalar;
@@ -870,10 +859,10 @@ PRESETS = {
     "llama-650m": LlamaConfig(vocab_size=32000, hidden_size=1536, intermediate_size=6144,
                               num_layers=16, num_heads=12, num_kv_heads=4,
                               max_position_embeddings=4096),
-    # the 1B-class experiment behind tinyllama's 33.6% MFU measurement
-    # (BENCH.md): same param count, but 16 heads x 128 where tinyllama runs
+    # tinyllama's parameter count with 16 heads x 128 where tinyllama runs
     # 32 x 64 — half-width head tiles waste half of every 128x128 MXU pass,
-    # so this preset isolates the head-dim lever at 1B scale
+    # so this preset isolates the head-dim lever at 1B scale (not measured
+    # on this tree)
     "llama-1b-hd128": LlamaConfig(vocab_size=32000, hidden_size=2048,
                                   intermediate_size=8192, num_layers=16,
                                   num_heads=16, num_kv_heads=4,

@@ -72,7 +72,6 @@ from jax.ad_checkpoint import checkpoint_name
 
 from . import llama
 from .llama import _rmsnorm, attention_sublayer
-from ..ops.collectives import ppermute as _ppermute
 from ..ops.collectives import psum as _psum
 from ..ops.collectives import psum_scatter as _psum_scatter
 from ..ops.grouped_matmul import grouped_matmul
@@ -508,8 +507,8 @@ def _moe_ffn(config: MoELlamaConfig, x: jnp.ndarray, moe: dict,
       (Switch/GShard). Row data moves by GATHER only (the single scatter is
       the int32 slot-map inversion; the combine is a reshape+sum over the
       choice-rank-major pair layout) — TPU scatters serialize on write
-      hazards and dominated the first on-chip MoE measurement (BENCH.md,
-      20% MFU). Capacity priority is greedy by choice rank then token order.
+      hazards (what a row scatter costs is not measured on this tree).
+      Capacity priority is greedy by choice rank then token order.
     - ``"ragged"``: dropless sorted dispatch + grouped GEMMs over the
       [kT, D] sorted buffer (MegaBlocks, arXiv:2211.15841) — no padding
       compute, no capacity/quality trade, ``dropped_frac`` identically 0.
@@ -697,8 +696,7 @@ def _local_groups_compute(x_sorted: jnp.ndarray, sizes: jnp.ndarray, gate,
 
 def make_ragged_ep_dispatch(mesh, config: MoELlamaConfig, *,
                             data_axes=("dp", "fsdp", "ep"), ep_axis="ep",
-                            embed_axis: Optional[str] = None,
-                            overlap: bool = False):
+                            embed_axis: Optional[str] = None):
     """Sharded dropless dispatch: a shard_map over the data axes that
     exchanges *sorted expert groups* instead of the dense path's [E, C, D]
     capacity buffer.
@@ -730,21 +728,7 @@ def make_ragged_ep_dispatch(mesh, config: MoELlamaConfig, *,
     ``embed_axis``: mesh axis sharding the weights' embed dim (ep_fsdp
     plans pass "fsdp"); the body all-gathers that dim before compute and the
     transpose reduce-scatters the weight cotangent — exactly FSDP semantics,
-    hand-spelled because the region is manual. (This stays true under
-    ``--overlap-schedule``: expert weights are excluded from the layer
-    schedule's gathers — feeding one partial-manual region's output into
-    another trips the jax 0.4.37 partitioner.)
-
-    ``overlap=True`` (the latency-hiding schedule, ops/overlap.py) swaps the
-    bulk all-gather + global sort for a DOUBLE-BUFFERED RING: token blocks
-    rotate around ``ep`` one hop per step, each visiting block is sorted and
-    run through this member's experts while the ppermute bringing hop j+1's
-    block is already in flight, and each partial output ppermutes straight
-    back to its owner (the return hop of step j rides behind step j+1's
-    compute). Same O(T*D) wire bytes as the bulk form, same math (per-row
-    expert results are sort-granularity independent; owners sum the ep
-    partials), but every transfer has compute to hide behind — and peak
-    transients drop from O(ep*t_loc) sorted rows to O(t_loc) per hop.
+    hand-spelled because the region is manual.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -768,17 +752,6 @@ def make_ragged_ep_dispatch(mesh, config: MoELlamaConfig, *,
     gu_spec = P(ep_axis if ep > 1 else None, embed_axis, None)
     down_spec = P(ep_axis if ep > 1 else None, None, embed_axis)
 
-    def _member_partial(xt_blk, idx_blk, probs_blk, gate, up, down):
-        """This member's experts applied to one block of rows -> the block's
-        partial combine [t_blk, D] (zeros for rows routed elsewhere)."""
-        e0 = jax.lax.axis_index(ep_axis) * e_local
-        order, sizes, x_sorted, weight_flat = _ragged_sort(
-            xt_blk, idx_blk, probs_blk, ex, k, cdt)
-        out_sorted = _local_groups_compute(x_sorted, sizes, gate, up, down,
-                                           e0, e_local, cdt)
-        return _ragged_combine(out_sorted, order, weight_flat, k,
-                               xt_blk.shape[0], cdt)
-
     def body(xt, topk_idx, topk_probs, gate, up, down):
         if embed_axis is not None:
             gate = jax.lax.all_gather(gate, embed_axis, axis=1, tiled=True)
@@ -793,33 +766,21 @@ def make_ragged_ep_dispatch(mesh, config: MoELlamaConfig, *,
                                                 sizes, cdt)
             return _ragged_combine(out_sorted, order, weight_flat, k,
                                    xt.shape[0], cdt)
-        if overlap:
-            # double-buffered ring: blocks of rows rotate +1 per hop; while
-            # hop j's block computes, the ppermute bringing hop j+1's block
-            # is in flight, and hop j's partial output permutes straight
-            # back to its owner behind hop j+1's compute
-            fwd_perm = [(i, (i + 1) % ep) for i in range(ep)]
-            blk = (xt, topk_idx, topk_probs)
-            acc = jnp.zeros_like(xt, dtype=cdt)
-            for j in range(ep):
-                nxt = (jax.tree.map(
-                    lambda a: _ppermute(a, ep_axis, perm=fwd_perm), blk)
-                    if j + 1 < ep else None)
-                y_blk = _member_partial(*blk, gate, up, down)
-                if j:  # return the visiting block's partial to its owner
-                    back = [(i, (i - j) % ep) for i in range(ep)]
-                    y_blk = _ppermute(y_blk, ep_axis, perm=back)
-                acc = acc + y_blk
-                blk = nxt
-            return acc
-        # bulk form: pull the whole (dp, fsdp) row's tokens + routing in,
-        # sort once globally, compute the local experts' contiguous window,
-        # reduce-scatter the partials back to each token's home shard
+        # pull the whole (dp, fsdp) row's tokens + routing in, sort once
+        # globally, compute the local experts' contiguous window (zeros for
+        # rows routed elsewhere), reduce-scatter the partials back to each
+        # token's home shard
         xt = jax.lax.all_gather(xt, ep_axis, axis=0, tiled=True)
         topk_idx = jax.lax.all_gather(topk_idx, ep_axis, axis=0, tiled=True)
         topk_probs = jax.lax.all_gather(topk_probs, ep_axis, axis=0,
                                         tiled=True)
-        y = _member_partial(xt, topk_idx, topk_probs, gate, up, down)
+        e0 = jax.lax.axis_index(ep_axis) * e_local
+        order, sizes, x_sorted, weight_flat = _ragged_sort(
+            xt, topk_idx, topk_probs, ex, k, cdt)
+        out_sorted = _local_groups_compute(x_sorted, sizes, gate, up, down,
+                                           e0, e_local, cdt)
+        y = _ragged_combine(out_sorted, order, weight_flat, k, xt.shape[0],
+                            cdt)
         return _psum_scatter(y, ep_axis)
 
     sm = jax.shard_map(body, mesh=mesh, axis_names=manual, check_vma=False,
@@ -862,7 +823,6 @@ def apply_with_aux(
     return_metrics: bool = False,
     return_hidden: bool = False,
     moe_ep=None,
-    layer_schedule=None,
 ):
     """Forward -> (logits [B,S,V] fp32, mean router aux loss[, metrics]).
 
@@ -873,9 +833,7 @@ def apply_with_aux(
     logits for the final-normed hidden states [B, S, E] (chunked-loss path —
     pair with ``output_weights``). ``moe_ep``: expert-parallel ragged
     dispatch callable (``make_ragged_ep_dispatch``), threaded to every
-    layer's routed FFN. ``layer_schedule`` (ops/overlap.py): replaces the
-    layer scan with the explicit latency-hiding schedule, which owns remat
-    per cell (``remat``/``remat_policy`` are then unused here)."""
+    layer's routed FFN."""
     standard_layout = positions is None
     if positions is None:
         positions = jnp.arange(input_ids.shape[1])[None, :]
@@ -889,39 +847,27 @@ def apply_with_aux(
     wins = llama._layer_window_column(config)
     zero = jnp.zeros((), jnp.float32)
 
-    if layer_schedule is not None:
-        def sched_block(carry, layer_params, window_override=None):
-            new_carry = block(carry, layer_params,
-                              window_override=window_override)
-            if activation_sharding is not None:
-                new_carry = (jax.lax.with_sharding_constraint(
-                    new_carry[0], activation_sharding), *new_carry[1:])
-            return new_carry
+    def scan_body(carry, xs):
+        if wins is not None:   # per-layer window column rides the scan
+            layer_params, w = xs
+            new_carry = block(carry, layer_params, window_override=w)
+        else:
+            new_carry = block(carry, xs)
+        if activation_sharding is not None:
+            new_carry = (jax.lax.with_sharding_constraint(
+                new_carry[0], activation_sharding), *new_carry[1:])
+        return new_carry, None
 
-        x, aux, dropped = layer_schedule(sched_block, (x, zero, zero),
-                                         params["layers"], wins)
-    else:
-        def scan_body(carry, xs):
-            if wins is not None:   # per-layer window column rides the scan
-                layer_params, w = xs
-                new_carry = block(carry, layer_params, window_override=w)
-            else:
-                new_carry = block(carry, xs)
-            if activation_sharding is not None:
-                new_carry = (jax.lax.with_sharding_constraint(
-                    new_carry[0], activation_sharding), *new_carry[1:])
-            return new_carry, None
+    if remat:
+        policy = remat_policy or jax.checkpoint_policies.nothing_saveable
+        scan_body = jax.checkpoint(scan_body, policy=policy,
+                                   prevent_cse=False)
 
-        if remat:
-            policy = remat_policy or jax.checkpoint_policies.nothing_saveable
-            scan_body = jax.checkpoint(scan_body, policy=policy,
-                                       prevent_cse=False)
-
-        scan_xs = (params["layers"] if wins is None
-                   else (params["layers"], wins))
-        with jax.named_scope("layers"):   # the scan's own slicing and stacking
-            (x, aux, dropped), _ = jax.lax.scan(scan_body, (x, zero, zero),
-                                                scan_xs)
+    scan_xs = (params["layers"] if wins is None
+               else (params["layers"], wins))
+    with jax.named_scope("layers"):   # the scan's own slicing and stacking
+        (x, aux, dropped), _ = jax.lax.scan(scan_body, (x, zero, zero),
+                                            scan_xs)
 
     out = (llama.final_hidden(config, params, x) if return_hidden
            else llama.lm_head_logits(config, params, x))

@@ -294,13 +294,11 @@ def apply(
     attn_impl: str = "auto",
     activation_sharding: Optional[Any] = None,
     return_hidden: bool = False,
-    layer_schedule=None,
 ) -> jnp.ndarray:
     """Forward -> float32 logits [B, S, V] (or final-normed hiddens [B, S, E]
     when ``return_hidden``, for chunked losses). Same contract as
     ``llama.apply`` — explicit ``positions`` required when the sequence dim
-    is sharded (context parallelism); ``layer_schedule`` (ops/overlap.py)
-    replaces the layer scan with the explicit latency-hiding schedule."""
+    is sharded (context parallelism)."""
     standard_layout = positions is None
     if positions is None:
         positions = jnp.arange(input_ids.shape[1])[None, :]
@@ -317,18 +315,15 @@ def apply(
             y = jax.lax.with_sharding_constraint(y, activation_sharding)
         return y
 
-    if layer_schedule is not None:  # explicit latency-hiding schedule
-        x = layer_schedule(constrained_block, x, params["layers"])
-    else:
-        def scan_body(carry, layer_params):
-            return constrained_block(carry, layer_params), None
+    def scan_body(carry, layer_params):
+        return constrained_block(carry, layer_params), None
 
-        if remat:
-            policy = remat_policy or jax.checkpoint_policies.nothing_saveable
-            scan_body = jax.checkpoint(scan_body, policy=policy,
-                                       prevent_cse=False)
+    if remat:
+        policy = remat_policy or jax.checkpoint_policies.nothing_saveable
+        scan_body = jax.checkpoint(scan_body, policy=policy,
+                                   prevent_cse=False)
 
-        x, _ = jax.lax.scan(scan_body, x, params["layers"])
+    x, _ = jax.lax.scan(scan_body, x, params["layers"])
     if return_hidden:
         return final_hidden(config, params, x)
     return lm_head_logits(config, params, x)

@@ -7,8 +7,8 @@ bf16 all-reduce), which would otherwise kill the virtual-mesh test suite.
 ``sub_fp32_guard`` factors that upcast-around-the-collective into one
 decorator: off-TPU, bf16/fp16 operands are widened to fp32 for the
 collective and narrowed back; on TPU the native low-precision collective
-runs (half the ICI bytes). The guard is exact for data-movement collectives
-(all-gather / ppermute) and changes only the reduction arithmetic width for
+runs (half the ICI bytes). The guard is exact for the data-movement
+collective (all-gather) and changes only the reduction arithmetic width for
 psum / psum_scatter — fp32 accumulation off-TPU, never worse than native.
 """
 from __future__ import annotations
@@ -48,33 +48,23 @@ def psum_scatter(x: jnp.ndarray, axis, *, scatter_dimension: int = 0) -> jnp.nda
 
 @sub_fp32_guard
 def all_gather(x: jnp.ndarray, axis, *, dim: int = 0) -> jnp.ndarray:
-    """Tiled all-gather along ``dim`` (the latency-hiding schedules'
-    parameter prefetch primitive, ops/overlap.py)."""
+    """Tiled all-gather along ``dim`` under the shared guard."""
     return jax.lax.all_gather(x, axis, axis=dim, tiled=True)
 
 
-@sub_fp32_guard
-def ppermute(x: jnp.ndarray, axis, *, perm) -> jnp.ndarray:
-    """``jax.lax.ppermute`` under the shared guard (the double-buffered EP
-    ring's hop primitive, models/moe.py)."""
-    return jax.lax.ppermute(x, axis, perm=perm)
-
-
-def gather_with_reduce_scatter_vjp(axis, dim: int, *, wide: bool = False):
+def gather_with_reduce_scatter_vjp(axis, dim: int):
     """All-gather along ``dim`` over ``axis`` whose backward is an explicit
     reduce-scatter. The cotangent is widened to fp32 for the reduction and
     narrowed back to the parameter dtype — the same accumulate-wide /
     store-narrow contract GSPMD applies to its grad reductions, so a
-    scheduled path stays bit-comparable to the unscheduled one. Used by the
-    per-layer schedule (ops/overlap.py) and by the loss head's one gather
-    (ops/cross_entropy.py), which asks for it ``wide``: the gathered array
-    comes out in fp32 (an exact widening), so what the caller's backward
-    sums against it stays fp32 until the reduction has taken it."""
+    hand-spelled gather stays bit-comparable to GSPMD's own. The gathered
+    array comes out in fp32 (an exact widening), so what the caller's
+    backward sums against it stays fp32 until the reduction has taken it
+    (the loss head's one gather, ops/cross_entropy.py)."""
 
     @jax.custom_vjp
     def gather(p):
-        out = all_gather(p, axis, dim=dim)
-        return out.astype(jnp.float32) if wide else out
+        return all_gather(p, axis, dim=dim).astype(jnp.float32)
 
     def fwd(p):
         return gather(p), jnp.zeros((0,), p.dtype)  # the parameter's dtype

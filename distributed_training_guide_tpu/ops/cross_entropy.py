@@ -10,8 +10,6 @@ position out of the mean.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax.numpy as jnp
 import jax
 
@@ -144,7 +142,7 @@ def make_gathered_chunked_loss(mesh, w_spec, data_axes, *, num_chunks: int):
     from .flash_attention import wrapper_shard_map
 
     (dim, axes), = [(i, e) for i, e in enumerate(w_spec) if e is not None]
-    gather = gather_with_reduce_scatter_vjp(axes, dim, wide=True)
+    gather = gather_with_reduce_scatter_vjp(axes, dim)
     batch = tuple(a for a in data_axes if mesh.shape[a] > 1)
 
     def body(hidden, w_shard, labels):
@@ -157,137 +155,6 @@ def make_gathered_chunked_loss(mesh, w_spec, data_axes, *, num_chunks: int):
     return wrapper_shard_map(
         mesh, in_specs=(P(batch, None, None), w_spec, P(batch, None)),
         out_specs=P())(body)
-
-
-def _fused_nll_kernel(vocab_axis: Optional[str]):
-    """custom-VJP core of the fused hidden->loss: chunked NLL straight from
-    (hidden rows, output weights) with the logits recomputed per chunk in
-    backward — peak live logits are one ``[chunk, V_local]`` fp32 slice in
-    BOTH passes, and the only residual beyond the inputs is the [rows] fp32
-    logz vector.
-
-    vs the ``jax.checkpoint``-based ``chunked_causal_lm_loss``: same forward
-    math, but the backward skips the checkpoint replay's logsumexp/gather
-    recompute (logz is a saved residual) and spells the softmax-minus-onehot
-    cotangent directly; with ``vocab_axis`` set the chunk math runs
-    vocab-parallel (ops/vocab_parallel.py psums), composing the chunked loss
-    with a tp logits shard — the combination the separate paths could not
-    express. Weight cotangents accumulate in fp32 across chunks and narrow
-    once at the end.
-
-    Operands (shard-local): h [C, c, E] chunked rows, w [E, V_local],
-    t [C, c] GLOBAL target ids (-100 = ignore). Returns the local nll sum.
-    """
-    from .vocab_parallel import (shard_local_targets, sharded_logsumexp,
-                                 sharded_pick)
-
-    def chunk_logits(h_c, w):
-        return jnp.dot(h_c, w, preferred_element_type=jnp.float32)
-
-    @jax.custom_vjp
-    def nll_sum(h, w, t):
-        def body(acc, xs):
-            acc, _ = fwd_chunk(acc, xs, w)
-            return acc, None
-
-        acc, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (h, t))
-        return acc
-
-    def fwd_chunk(acc, xs, w):
-        h_c, t_c = xs
-        logits = chunk_logits(h_c, w)
-        valid = t_c != IGNORE_INDEX
-        if vocab_axis is not None:
-            logz = sharded_logsumexp(logits, vocab_axis)
-            picked = sharded_pick(logits, t_c, valid, vocab_axis)
-        else:
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            safe = jnp.where(valid, t_c, 0)
-            picked = jnp.take_along_axis(logits, safe[..., None],
-                                         axis=-1)[..., 0]
-        return acc + jnp.sum((logz - picked) * valid), logz
-
-    def fwd(h, w, t):
-        def body(carry, xs):
-            acc, logz = fwd_chunk(carry, xs, w)
-            return acc, logz
-
-        acc, logzs = jax.lax.scan(body, jnp.zeros((), jnp.float32), (h, t))
-        return acc, (h, w, t, logzs)
-
-    def bwd(res, g):
-        h, w, t, logzs = res
-        v_local = w.shape[-1]
-        if vocab_axis is not None:
-            # the enclosing region's replicated scalar output splits its
-            # cotangent 1/axis_size across the manual vocab axis
-            # (check_vma=False adjoint of an out_spec that drops the axis).
-            # w_local feeds every member's (identical) loss output, so its
-            # true cotangent is the members' SUM — psum restores it. dh
-            # needs no such correction: its exit collectives (the SP
-            # gather's psum_scatter transpose, or the unmentioned-axis psum
-            # on a replicated hidden) already sum the split pieces back.
-            # Pinned at grad level vs the dense reference in
-            # tests/test_overlap.py (the trajectory tests alone can't catch
-            # a uniform scale — Adam updates are invariant to it).
-            g_w = jax.lax.psum(g, vocab_axis)
-        else:
-            g_w = g
-
-        def body(dw_acc, xs):
-            h_c, t_c, logz_c = xs
-            logits = chunk_logits(h_c, w)           # recompute, one chunk live
-            p = jnp.exp(logits - logz_c[..., None])  # softmax w/ GLOBAL logz
-            valid = t_c != IGNORE_INDEX
-            if vocab_axis is not None:
-                safe, in_shard = shard_local_targets(t_c, valid, v_local,
-                                                     vocab_axis)
-                onehot = ((jnp.arange(v_local) == safe[..., None]) & in_shard[..., None])
-            else:
-                safe = jnp.where(valid, t_c, 0)
-                onehot = jnp.arange(v_local) == safe[..., None]
-            dl = (p - onehot.astype(jnp.float32)) * (valid * g)[..., None]
-            dh_c = jnp.dot(dl, w.T, preferred_element_type=jnp.float32)
-            if vocab_axis is not None:   # sum over the full vocab dim
-                dh_c = jax.lax.psum(dh_c, vocab_axis)
-            dl_w = (dl if g_w is g else
-                    (p - onehot.astype(jnp.float32)) * (valid * g_w)[..., None])
-            dw_acc = dw_acc + jnp.dot(h_c.T, dl_w,
-                                      preferred_element_type=jnp.float32)
-            return dw_acc, dh_c.astype(h.dtype)
-
-        dw, dh = jax.lax.scan(body, jnp.zeros(w.shape, jnp.float32),
-                              (h, t, logzs))
-        return dh, dw.astype(w.dtype), None
-
-    nll_sum.defvjp(fwd, bwd)
-    return nll_sum
-
-
-def fused_linear_cross_entropy(hidden: jnp.ndarray, w_out: jnp.ndarray,
-                               labels: jnp.ndarray, *, num_chunks: int = 8,
-                               vocab_axis: Optional[str] = None):
-    """Shard-local fused hidden->loss: shift, flatten to rows, pad to the
-    chunk grid, run the custom-VJP kernel. Returns ``(nll_sum, count)`` as
-    fp32 scalars — LOCAL sums; the caller owns the cross-shard mean (see
-    ``ops.overlap.make_fused_loss`` for the shard_map wrapper).
-
-    hidden [B, S, E]; w_out [E, V_local]; labels [B, S] with -100 ignored.
-    """
-    b, s, e = hidden.shape
-    h = hidden[:, :-1, :].reshape(b * (s - 1), e)
-    t = labels[:, 1:].reshape(b * (s - 1))
-    n = h.shape[0]
-    pad = (-n) % num_chunks
-    if pad:
-        h = jnp.pad(h, ((0, pad), (0, 0)))
-        t = jnp.pad(t, (0, pad), constant_values=IGNORE_INDEX)
-    chunk = (n + pad) // num_chunks
-    h = h.reshape(num_chunks, chunk, e)
-    t = t.reshape(num_chunks, chunk)
-    nll = _fused_nll_kernel(vocab_axis)(h, w_out, t)
-    count = jnp.sum(t != IGNORE_INDEX).astype(jnp.float32)
-    return nll, count
 
 
 def validate_chunked_loss_support(family_mod, family: str, loss_fn) -> None:

@@ -65,8 +65,7 @@ def sharded_logsumexp(logits: jnp.ndarray, axis: str) -> jnp.ndarray:
     The stabilizing max is constant w.r.t. AD (the exact gradient of
     logsumexp doesn't depend on the shift); pmax has no JVP rule, so the
     cross-shard max rides an all_gather of the (tiny) per-shard maxes.
-    logits: [..., V/axis_size] fp32 -> [...]. Shared by the loss above and
-    the fused hidden->loss kernel (ops/cross_entropy.py)."""
+    logits: [..., V/axis_size] fp32 -> [...]."""
     m = jax.lax.stop_gradient(jnp.max(
         jax.lax.all_gather(jnp.max(logits, axis=-1), axis), axis=0))
     sumexp = jax.lax.psum(
@@ -74,22 +73,14 @@ def sharded_logsumexp(logits: jnp.ndarray, axis: str) -> jnp.ndarray:
     return jnp.log(sumexp) + m
 
 
-def shard_local_targets(targets: jnp.ndarray, valid: jnp.ndarray,
-                        v_local: int, axis: str):
-    """GLOBAL target ids -> (ids clipped into this member's vocab slice,
-    in-shard mask). Shared by ``sharded_pick`` and the fused kernel's
-    backward (one-hot against the local slice)."""
-    offset = jax.lax.axis_index(axis) * v_local
-    local_t = jnp.where(valid, targets, 0) - offset
-    in_shard = (local_t >= 0) & (local_t < v_local)
-    return jnp.clip(local_t, 0, v_local - 1), in_shard
-
-
 def sharded_pick(logits: jnp.ndarray, targets: jnp.ndarray,
                  valid: jnp.ndarray, axis: str) -> jnp.ndarray:
     """The target's logit out of a vocab-sharded last dim: masked local
     gather + psum. logits [..., V/axis], targets/valid [...] -> [...]."""
-    safe, in_shard = shard_local_targets(targets, valid, logits.shape[-1],
-                                         axis)
+    v_local = logits.shape[-1]
+    # GLOBAL target ids -> ids clipped into this member's vocab slice
+    local_t = jnp.where(valid, targets, 0) - jax.lax.axis_index(axis) * v_local
+    in_shard = (local_t >= 0) & (local_t < v_local)
+    safe = jnp.clip(local_t, 0, v_local - 1)
     picked_local = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
     return jax.lax.psum(jnp.where(in_shard, picked_local, 0.0), axis)

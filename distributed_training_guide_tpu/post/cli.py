@@ -19,8 +19,8 @@ Examples::
         --teacher-model llama-debug --teacher-seed 1 --iterations 5
 
 Each iteration prints one JSON line (reward, loss, rollout tok/s,
-publish latency) — the same schema the ``post_loop_cpu`` bench rung
-records. ``--ledger`` makes rollout batches crash-recoverable;
+publish latency) — the schema ``tests/test_post.py`` reads.
+``--ledger`` makes rollout batches crash-recoverable;
 re-running the same command resumes from it. ``--memory-budget-gb``
 prices the co-resident policy + teacher + pool BEFORE anything
 compiles and refuses an impossible colocation (train/preflight.py).

@@ -29,7 +29,7 @@ end:
 fully on-policy; larger values trade policy freshness for fewer
 merge+publish walls (the related-topics/post-training chapter has the
 tradeoff discussion). ``frozen=True`` runs rollout+score only — the
-one-new-variable control the bench rung measures against.
+one-new-variable control a live run is measured against.
 """
 from __future__ import annotations
 

@@ -162,7 +162,7 @@ def generate_rollouts(engine, prompts, *, iteration: int, base_seed: int,
     ones regenerate bitwise (derived seeds + position-keyed sampling).
 
     ``stats``: generated token count, wall seconds, tokens/s — the
-    rollout-throughput numbers the bench rung records."""
+    rollout-throughput numbers the loop's JSON line carries."""
     done = ledger.completed(iteration) if ledger is not None else {}
     resumed_idx = frozenset(done)
     pending: dict[int, int] = {}
@@ -203,7 +203,7 @@ def generate_rollouts(engine, prompts, *, iteration: int, base_seed: int,
     rollouts = [done[i] for i in range(len(prompts))]
     # throughput counts only tokens THIS call generated — resumed
     # samples came off the ledger, and counting them would report a
-    # resumed iteration at millions of tok/s (poisoning every bench
+    # resumed iteration at millions of tok/s (poisoning every
     # mean the number lands in)
     gen = sum(len(r.generated_ids) for i, r in enumerate(rollouts)
               if i not in resumed_idx)

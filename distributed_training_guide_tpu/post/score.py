@@ -5,8 +5,8 @@ One interface (``Scorer.score(rollouts) -> [Score]``) behind which the
 three post-training reward shapes live:
 
 - ``ProgrammaticScorer`` — a host function of (prompt_ids,
-  generated_ids); the synthetic-preference tasks tests and the CPU bench
-  rung use, and the shape real rule-based rewards (length penalties,
+  generated_ids); what the synthetic-preference tasks of the tests
+  use, and the shape real rule-based rewards (length penalties,
   format checks, unit tests) take.
 - ``RewardModelScorer`` — a model forward as the reward: the mean
   log-probability the scoring model assigns to the sampled continuation
@@ -67,8 +67,8 @@ class ProgrammaticScorer(Scorer):
 def match_reward(target_id: int):
     """Sparse synthetic preference: reward = fraction of generated
     tokens equal to ``target_id`` (~1/vocab at init — a hard
-    exploration task; ``band_reward`` is the dense variant the tests and
-    the bench rung actually learn on)."""
+    exploration task; ``band_reward`` is the dense variant the tests
+    actually learn on)."""
     def fn(prompt_ids, generated_ids):
         if not generated_ids:
             return 0.0
@@ -78,8 +78,8 @@ def match_reward(target_id: int):
 
 
 def band_reward(max_id: int):
-    """The DENSE synthetic preference task (tests + the
-    ``post_loop_cpu`` bench rung): reward = fraction of generated tokens
+    """The DENSE synthetic preference task (the tests' and the post
+    CLI's default): reward = fraction of generated tokens
     with id < ``max_id``. At a random init the rate is ~max_id/vocab, so
     every rollout carries signal and REINFORCE-with-baseline moves the
     reward measurably within a few iterations on a debug model —

@@ -1,7 +1,6 @@
 """Serving front-ends over the engine: an offline batch API and a minimal
 stdlib HTTP endpoint with per-token streaming. Both emit per-request
-latency + TTFT/ITL and aggregate tokens/sec (the numbers bench.py's
-``decode_tput`` rung records).
+latency + TTFT/ITL and aggregate tokens/sec.
 
 ``generate_many`` is synchronous continuous batching: all requests enter
 the scheduler queue up front and the engine iterates until the queue

@@ -1816,7 +1816,7 @@ class ServeEngine(DecodeArrays):
         # spec-off identity is what makes the mid-stream toggle legal)
         self._parked_drafter = None
 
-    # ---- delegation (kept public: tests/bench lower these directly) --------
+    # ---- delegation (kept public: tests and benchmarks lower these) --------
     @property
     def params(self):
         return self.programs.params

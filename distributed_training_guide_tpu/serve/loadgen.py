@@ -1,7 +1,7 @@
 """Open-loop load generation for the serving fleet: goodput under real
 traffic shapes.
 
-Every serve number before this module came from a CLOSED loop: the bench
+Every serve number before this module came from a CLOSED loop: the driver
 submits a batch, drives the engine flat out, and measures throughput —
 the generator waits on the engine, so the engine never sees more work
 than it can absorb. Production traffic is OPEN loop: clients arrive on
@@ -217,7 +217,7 @@ def build_schedule(arrivals: list[float], scenarios: list[Scenario], *,
     arrival draws a scenario by weight, then samples a request from it.
     Deterministic in (arrivals, scenarios, vocab, seed) — the SAME
     schedule replays against different fleet configurations, which is
-    what makes A/B rungs honest."""
+    what makes an A/B honest."""
     rng = random.Random(seed)
     weights = [s.weight for s in scenarios]
     out = []
@@ -438,7 +438,7 @@ def saturation_sweep(engine_factory, rates, *, duration_s: float,
     engine each (no warm queue leaking between points), goodput and
     latency tails per point. Offered load climbs; the knee where
     goodput stops following it IS the fleet's capacity — the number a
-    closed-loop bench structurally cannot produce."""
+    closed-loop run structurally cannot produce."""
     out = []
     for rate in rates:
         engine = engine_factory()

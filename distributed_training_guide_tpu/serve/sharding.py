@@ -20,10 +20,10 @@ paged flash-decode kernel / gather reference) runs under a FULL-MANUAL
 no collective appears inside the region, and the only cross-chip traffic
 of a decode step is what GSPMD inserts around it anyway (the out
 projection's row-parallel psum and the vocab-sharded sampling psums).
-Full-manual (every mesh axis) rather than partial-auto because jax
-0.4.37's partitioner rejects programs mixing manual subgroups of
-different shapes (the ops/overlap.py finding) — which also means the
-serve mesh must have tp as its only non-trivial axis
+Full-manual (every mesh axis) rather than partial-auto because the
+chip's lowering refuses a Pallas call in a region that leaves a mesh axis
+auto ("Mosaic kernels cannot be automatically partitioned") — which also
+means the serve mesh must have tp as its only non-trivial axis
 (``validate_kv_shard``).
 
 The Mosaic kernel is the forcing function: GSPMD cannot partition a

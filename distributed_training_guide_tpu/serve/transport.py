@@ -50,8 +50,8 @@ path, not a mock.
 
 ``python -m distributed_training_guide_tpu.serve.transport --echo``
 serves one connection as a receive-validate-commit echo endpoint over
-real TCP and prints a payload digest — the cross-PROCESS leg of the
-``handoff_crossproc`` bench rung (bench.py).
+real TCP and prints a payload digest — the cross-PROCESS leg of a
+handoff.
 
 The ICI/DCN path is the TPU rung of this seam; everything above it —
 framing, the commit protocol, the requeue discipline — is
@@ -376,7 +376,7 @@ def loopback_channel(*, ack_timeout_s: float = 2.0) \
     return sender, receiver
 
 
-# ---- cross-process echo (the handoff_crossproc bench leg) ------------------
+# ---- cross-process echo (a handoff's cross-process leg) --------------------
 
 def run_echo_server(port: int = 0, expect: Optional[int] = None,
                     out=None) -> dict:
@@ -420,7 +420,7 @@ def main(argv=None) -> None:
 
     parser = argparse.ArgumentParser(
         prog="python -m distributed_training_guide_tpu.serve.transport",
-        description="cross-process handoff echo endpoint (bench leg)")
+        description="cross-process handoff echo endpoint")
     parser.add_argument("--echo", action="store_true", required=True)
     parser.add_argument("--port", type=int, default=0)
     parser.add_argument("--expect", type=int, default=None)

@@ -70,7 +70,7 @@ def get_parser() -> argparse.ArgumentParser:
                              "everything (min memory); dots=keep matmul outputs "
                              "(most memory); attn=keep attention outputs + flash "
                              "lse so backward never re-runs the attention kernel "
-                             "(best measured MFU, small memory cost); attn_mlp="
+                             "(a small memory cost); attn_mlp="
                              "attn plus the [B,S,I] MLP inner activations "
                              "(also skips the gate/up matmul recompute)")
     parser.add_argument("--attn-impl", default="auto", choices=["auto", "xla", "flash"])
@@ -172,19 +172,6 @@ def get_parser() -> argparse.ArgumentParser:
                              "kernel, O(S*W) attention). Overrides the "
                              "model config; hf: checkpoints with "
                              "sliding_window set enable this automatically")
-    parser.add_argument("--overlap-schedule", action="store_true",
-                        help="latency-hiding schedules (ops/overlap.py): "
-                             "unroll the layer loop with explicit per-layer "
-                             "fsdp all-gather prefetch + grad reduce-scatter "
-                             "collectives the scheduler can slide across "
-                             "layer compute, double-buffer the ragged EP "
-                             "exchange as a ppermute ring, and fuse the "
-                             "chunked/vocab-parallel loss into one "
-                             "hidden->loss kernel (no [B*S,V] fp32 logits). "
-                             "Parity-tested vs the default GSPMD program; "
-                             "pair with the XLA latency-hiding-scheduler "
-                             "flags (performance-tuning README) on TPU. "
-                             "Rejected under pp/cp plans")
     parser.add_argument("--precision-policy", default="fp32",
                         metavar="POLICY",
                         help="storage-precision policy (train/precision.py): "
@@ -203,9 +190,8 @@ def get_parser() -> argparse.ArgumentParser:
                         help="parameter STORAGE dtype (compute is bf16 "
                              "either way). bfloat16 halves resident param "
                              "memory and also stores the optimizer moments "
-                             "in bf16 — a measured throughput lever with a "
-                             "documented numerics trade (BENCH.md's "
-                             "bf16-state note); fp32 (default) is the "
+                             "in bf16 (the numerics trade: "
+                             "train/precision.py); fp32 (default) is the "
                              "reference's mixed-precision policy")
     parser.add_argument("--fence-every", type=_positive_int, default=1,
                         metavar="N",
@@ -213,16 +199,10 @@ def get_parser() -> argparse.ArgumentParser:
                              "every step. 1 (default) is the reference's "
                              "per-step `.item()` sync (01:163); N>1 lets the "
                              "host dispatch N steps ahead so the chip never "
-                             "idles on dispatch latency — measured 695->637 "
-                             "ms/step as the sole change at the bench "
-                             "headline shape (BENCH.md). The group fence is "
-                             "still hard: each step consumes the previous "
-                             "state on device")
-    parser.add_argument("--timer-sync", action="store_true",
-                        help="device-fence both edges of the per-phase "
-                             "timers (reference LocalTimer/cuda.synchronize "
-                             "semantics) in addition to the loss host-read "
-                             "that ends every timed step")
+                             "idles on dispatch latency (not measured on "
+                             "this tree). The group fence is still hard: "
+                             "each step consumes the previous state on "
+                             "device")
     parser.add_argument("--profile-dir", default=None,
                         help="capture a jax.profiler trace of steps 10-15 into this dir "
                              "(view with xprof/tensorboard; see diagnosing-errors/)")
@@ -358,7 +338,6 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
         offload_params=offload_params,
         pp_microbatches=pp_microbatches,
         precision=getattr(args, "precision_policy", "fp32"),
-        overlap_schedule=getattr(args, "overlap_schedule", False),
     )
     from .guards import GuardMonitor
 
@@ -456,14 +435,9 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
         args, mode="per-host" if getattr(args, "wandb_per_host", False) else "process0",
         exp_dir=exp_dir if is_experiment else None, config=vars(args))
 
-    sync_fn = None
-    if getattr(args, "timer_sync", False):
-        from ..utils.timers import device_sync
-        sync_fn = device_sync
     install_gc_span()
     # each timer is also the host span dtg.train.<k> (utils/trace.py)
-    timers = {k: LocalTimer(sync_fn=sync_fn, name=f"train.{k}")
-              for k in ["data", "step"]}
+    timers = {k: LocalTimer(name=f"train.{k}") for k in ["data", "step"]}
     flops_per_token = transformer_flops_per_token(
         bundle.num_active_params(), cfg.num_layers, cfg.hidden_size, seq_length,
         vocab_size=cfg.vocab_size)
@@ -537,9 +511,7 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                     # the device scalar and let the host dispatch ahead;
                     # drain_losses() materializes the bank at every point
                     # where running_loss is observed (fence, log boundary,
-                    # checkpoint save, end of run). Measured 695->637
-                    # ms/step as the only change at the bench headline
-                    # shape (BENCH.md `fence4`). A log boundary drains
+                    # checkpoint save, end of run). A log boundary drains
                     # HERE, inside the step timer, so the awaited device
                     # work of the whole group is charged to time/step —
                     # draining after the timer closed would let untimed
@@ -623,7 +595,7 @@ def run_training(args, plan_factory: Callable, *, extra_log: Optional[dict] = No
                     # log_freq, the awaited device work of this fence group
                     # is untimed and that window's tokens_per_s/MFU reads
                     # slightly high. Align ckpt_freq to log_freq for
-                    # benchmark-grade numbers (bench.py's harness does)
+                    # benchmark-grade numbers
                     drain_losses()
                     LOGGER.info("Saving checkpoint.")
                     with span("train.ckpt", step=host_state["global_step"]):

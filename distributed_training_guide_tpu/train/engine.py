@@ -16,9 +16,8 @@ and covers the WHOLE strategy space beyond the reference's engine:
 ``tensor_parallel``, ``pipeline_parallel`` (+ ``pp_microbatches``),
 ``context_parallel`` (+ ``context_impl``: "ring"/"ulysses"),
 ``expert_parallel``, ``moe_dispatch`` ("dense" capacity buffers / "ragged"
-dropless sorted dispatch, MoE models only), ``attn_impl``, ``loss_chunks``, ``overlap_schedule`` (latency-hiding
-comm/compute schedules, ops/overlap.py), and
-``activation_checkpointing`` as a bool or
+dropless sorted dispatch, MoE models only), ``attn_impl``, ``loss_chunks``,
+and ``activation_checkpointing`` as a bool or
 ``{"enabled": true, "policy": "attn"}`` (a REMAT_POLICIES key). Storage
 precision is a named policy (``train/precision.py``): spell it
 ``optimizer.params.precision`` (DeepSpeed-style, next to lr/betas) or
@@ -276,10 +275,6 @@ class TrainingEngine:
             loss_chunks=config.get("loss_chunks", 0),
             pp_microbatches=config.get("pp_microbatches"),
             precision=precision,
-            # latency-hiding schedules (ops/overlap.py): explicit fsdp
-            # all-gather prefetch / per-layer grad reduce-scatter, ring EP
-            # exchange, fused hidden->loss kernel. Opt-in, default off
-            overlap_schedule=config.get("overlap_schedule", False),
             # both spellings: our top-level key, and DeepSpeed's nested
             # zero_optimization.offload_optimizer/offload_param — there a
             # bool, or a dict whose device decides ({"device": "none"} is
@@ -318,7 +313,7 @@ class TrainingEngine:
         Returns the metric dict with DEVICE scalars: nothing here forces a
         host sync, so the host can dispatch the next step(s) while this one
         still runs (the CLI's banked-loss pattern; a per-step ``float(v)``
-        here measured 695 -> 637 ms/step at the bench headline shape). Each
+        here would put the host's dispatch latency between steps). Each
         value materializes lazily when the caller reads it — the caller's
         logging cadence IS the fence cadence. With step guards enabled the
         per-step host read comes back by construction: the skip/abort policy

@@ -86,7 +86,7 @@ def adafactor_cosine(
     The TPU-native memory lever the reference doesn't have: the second
     moment is stored FACTORED (row + column accumulators, Shazeer & Stern
     2018) and the first moment is dropped, so optimizer state is ~1/1000 of
-    AdamW's 2x-fp32 (e.g. ~5.2 GB -> ~7 MB for the 650M bench model) —
+    AdamW's 2x-fp32 (e.g. ~5.2 GB -> ~7 MB for the ``llama-650m`` preset) —
     often the difference between fitting a model on a chip with the Adam
     recipe (reference ``05:69-72``'s CPU offload) and just training it.
 
@@ -142,7 +142,7 @@ def lion_cosine(
 
 
 # name -> constructor, the dispatch shared by the chapter CLI (--optimizer)
-# and bench.py rung specs; the engine facade adds its own config mapping
+# and the benchmark's runners; the engine facade adds its own config mapping
 OPTIMIZERS = {"adamw": adamw_cosine, "adafactor": adafactor_cosine,
               "lion": lion_cosine}
 

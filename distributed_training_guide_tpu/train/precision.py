@@ -28,7 +28,7 @@ Per-parameter storage (the table 05-training-llama-405b/README.md reproduces):
   therefore materialized transiently per step by XLA rather than persisted —
   that is what makes the policy a 2x memory win instead of a loss. The trade:
   per-step updates smaller than ~2^-8 of a weight round away (no stochastic
-  rounding); BENCH.md's bf16-state rung documents the observed numerics.
+  rounding).
 - ``adam8bit`` (Dettmers et al., 8-bit Optimizers via Block-wise
   Quantization): params stay fp32 (they ARE the master copy), but both Adam
   moments are stored as int8 with one fp32 scale per block of ~128

@@ -15,10 +15,9 @@ It also prints a per-collective ICI comm model + roofline
 fsdp all-gathers / grad reduce-scatters / megatron tp all-reduces / dp grad
 all-reduce / MoE EP exchange / vocab-parallel loss psums, divided by the
 target chip's ICI bandwidth, against the step's compute time at peak —
-with an exposed-vs-overlapped split so the latency-hiding schedules'
-(ops/overlap.py) win is priced before launch — the scaling-book first-order answer to "is the
-fsdp=32 x tp=8 405B plan compute-bound on a v5p pod". The collective KINDS
-in the model are cross-checked against the compiled HLO at small scale by
+the scaling-book first-order answer to "is the fsdp=32 x tp=8 405B plan
+compute-bound on a v5p pod". The collective KINDS in the model are
+cross-checked against the compiled HLO at small scale by
 ``tests/test_405b_recipe.py``.
 """
 from __future__ import annotations
@@ -67,8 +66,7 @@ def device_bytes_limit(device):
 
 
 def comm_roofline(trainer, *, global_batch: int, seq_length: int,
-                  device_kind: str | None = None,
-                  assume_overlap: bool = True) -> dict:
+                  device_kind: str | None = None) -> dict:
     """Analytical per-collective ICI bytes + roofline for the trainer's plan.
 
     Ring-collective cost model (bytes crossing each chip's ICI links, one
@@ -122,13 +120,13 @@ def comm_roofline(trainer, *, global_batch: int, seq_length: int,
         return 2 * (k - 1) / k * n if k > 1 else 0.0
 
     # MoE EP exchange (ragged dispatch, models/moe.py): per MoE layer the
-    # token rows [t_loc, D] bf16 cross ep once out (gather/ring) and once
-    # back (reduce-scatter/return ppermute); forward AND backward transpose
+    # token rows [t_loc, D] bf16 cross ep once out (all-gather) and once
+    # back (reduce-scatter); forward AND backward transpose
     ep_exchange = (4 * n_layers * ag_rs(act_bytes, ep)
                    if n_experts > 1 else 0.0)
     # vocab-parallel loss psums ([b_loc, S] fp32 rows: max-gather, sumexp,
     # picked — fwd + the bwd dh reduce), counted when the plan shards vocab
-    # on tp (the fused hidden->loss kernel's collectives)
+    # on tp
     loss_bytes = rows_local * seq_length * 4
     loss_psum = 4 * ar(loss_bytes, tp) if tp > 1 else 0.0
     table = {
@@ -154,7 +152,7 @@ def comm_roofline(trainer, *, global_batch: int, seq_length: int,
     # Attention is priced BANDED — O(S*window) per the config's window
     # schedule, not dense O(S^2) — because the roofline's job is the honest
     # time estimate for THIS program (the banded kernel skips out-of-band
-    # kv tiles); bench/cli MFU keep the conventional dense count so numbers
+    # kv tiles); the cli's MFU keeps the conventional dense count so numbers
     # stay comparable with published figures (compare step_ms across
     # windowed A/Bs, not the MFU column)
     attn_kv = banded_attention_kv_length(cfg, seq_length)
@@ -164,28 +162,7 @@ def comm_roofline(trainer, *, global_batch: int, seq_length: int,
     t_comp = (flops_per_token * global_batch * seq_length) / (peak * n_chips)
     t_comm = comm_bytes / ici
 
-    # exposed-vs-overlapped pricing for the latency-hiding schedules
-    # (ops/overlap.py): with --overlap-schedule, the per-layer weight
-    # all-gather/reduce-scatter and the EP exchange are issued with layer
-    # compute to hide behind, so only their overflow past t_compute is
-    # exposed; everything else (tp activation all-reduces sit on the
-    # critical path between matmuls, loss psums at the end, dp bulk
-    # reduce without a schedule) stays serial. Without the flag the whole
-    # comm budget is priced exposed — the overlap win is therefore a
-    # REPORTED number before any TPU time is spent
-    overlap_on = bool(getattr(trainer, "overlap_schedule", False))
-    schedulable = (table["fsdp_allgather_weights"]
-                   + table["fsdp_reducescatter_grads"]
-                   + table["ep_exchange"])
-    if overlap_on:
-        exposed_bytes = comm_bytes - schedulable
-        t_exposed = (exposed_bytes / ici
-                     + max(0.0, schedulable / ici - t_comp))
-        overlapped_bytes = comm_bytes - exposed_bytes
-    else:
-        exposed_bytes, overlapped_bytes = comm_bytes, 0.0
-        t_exposed = t_comm
-    report = {
+    return {
         "attn_kv_len": attn_kv,   # mean keys/query: < seq_length iff banded
         "per_collective_bytes_per_chip": {k: int(v) for k, v in table.items()},
         "comm_bytes_per_chip": int(comm_bytes),
@@ -198,19 +175,7 @@ def comm_roofline(trainer, *, global_batch: int, seq_length: int,
         # excluded): overlapped = comm hides behind compute; serial = none
         "mfu_ceiling_overlapped": t_comp / max(t_comp, t_comm) if t_comp else 0.0,
         "mfu_ceiling_serial": t_comp / (t_comp + t_comm) if t_comp else 0.0,
-        "overlap_schedule": overlap_on,
-        "overlappable_bytes_per_chip": int(schedulable),
-        "exposed_bytes_per_chip": int(exposed_bytes),
-        "overlapped_bytes_per_chip": int(overlapped_bytes),
-        "t_exposed_s": t_exposed,
-        # the ceiling THIS configuration is priced at: serial comm exposed,
-        # scheduled comm hidden up to t_compute
-        "mfu_ceiling_scheduled": (t_comp / (t_comp + t_exposed)
-                                  if t_comp else 0.0),
     }
-    if not assume_overlap:
-        report["mfu_ceiling_overlapped"] = report["mfu_ceiling_serial"]
-    return report
 
 
 def _tree_bytes(shapes_tree) -> int:
@@ -686,14 +651,5 @@ def run_preflight(trainer, *, global_batch: int, seq_length: int,
         f"{comm['t_compute_s'] * 1e3:.1f} ms -> MFU ceiling "
         f"{comm['mfu_ceiling_overlapped']:.1%} overlapped / "
         f"{comm['mfu_ceiling_serial']:.1%} serial{banded}")
-    LOGGER.info(
-        f"overlap schedule {'ON' if comm['overlap_schedule'] else 'off'}: "
-        f"{comm['overlappable_bytes_per_chip'] * mib:.0f} MiB/chip "
-        f"schedulable (param all-gather + grad reduce-scatter + EP "
-        f"exchange), {comm['exposed_bytes_per_chip'] * mib:.0f} MiB "
-        f"exposed -> t_exposed {comm['t_exposed_s'] * 1e3:.1f} ms, "
-        f"scheduled MFU ceiling {comm['mfu_ceiling_scheduled']:.1%}"
-        + ("" if comm['overlap_schedule'] else
-           " (enable --overlap-schedule to hide the schedulable bytes)"))
     del lowered
     return report

@@ -144,13 +144,6 @@ class Trainer:
     # or a PrecisionPolicy. The optimizer handed in stays the single entry
     # point — the policy wraps it here, so fp32 runs are bit-identical
     precision: Any = "fp32"
-    # latency-hiding schedules (ops/overlap.py, --overlap-schedule): unroll
-    # the layer loop with explicit per-layer fsdp all-gather prefetch /
-    # grad reduce-scatter collectives, ring-double-buffer the ragged EP
-    # exchange, and fuse the chunked + vocab-parallel loss into one
-    # hidden->loss kernel. Default off — the unscheduled GSPMD program is
-    # the parity baseline
-    overlap_schedule: bool = False
     # LoRA-param-only optimizer path (models/lora.py): the bundle must be
     # lora_bundle-wrapped; the optimizer is mask_optimizer-wrapped here so
     # base updates are ZEROED and moments exist only for the adapter
@@ -266,19 +259,6 @@ class Trainer:
                 f"would need the exchange of the shares' partial sums, "
                 f"which is not implemented: use make_plan('single') or a "
                 f"one-device mesh, or drop experts_held")
-        if self.overlap_schedule:
-            if self.plan.mesh.shape.get("pp", 1) > 1:
-                raise ValueError(
-                    "--overlap-schedule cannot run under pipeline "
-                    "parallelism: the pipeline hand-rolls its own 1F1B "
-                    "schedule and its pp-manual region cannot nest the "
-                    "per-layer gather shard_maps; use dp/fsdp/tp/ep plans")
-            if self.plan.mesh.shape.get("cp", 1) > 1:
-                raise ValueError(
-                    "--overlap-schedule under context parallelism is not "
-                    "implemented (the ring/Ulysses attention wrappers are "
-                    "already their own comm schedule, and the fused loss "
-                    "has no cp-sharded-sequence form); use cp=1")
         if self.offload_opt_state or self.offload_params:
             kinds = {m.kind for m in jax.local_devices()[0].addressable_memories()}
             if "pinned_host" not in kinds:
@@ -588,47 +568,8 @@ class Trainer:
                              f"choose from {sorted(REMAT_POLICIES)}")
         policy = REMAT_POLICIES[self.remat_policy]
 
-        # latency-hiding schedules (ops/overlap.py): the layer scan becomes
-        # an unrolled flat program with explicit per-layer fsdp all-gather /
-        # grad reduce-scatter collectives and per-cell remat; the loss (when
-        # the setup supports it) becomes the fused hidden->loss kernel
-        layer_schedule = None
-        use_fused_loss = False
-        if self.overlap_schedule:
-            from ..models.registry import family_module
-            from ..ops.overlap import (fused_loss_supported,
-                                       make_layer_schedule)
-
-            import inspect
-
-            family_apply = (self.bundle.apply_with_aux
-                            or self.bundle.apply)
-            sig = inspect.signature(family_apply).parameters
-            if not ("layer_schedule" in sig
-                    or any(p.kind is inspect.Parameter.VAR_KEYWORD
-                           for p in sig.values())):
-                raise ValueError(
-                    f"--overlap-schedule: family {self.bundle.family!r} "
-                    f"apply does not take a layer_schedule")
-            if "layers" not in self.param_shapes:
-                # e.g. a LoRA-wrapped bundle: params = {"base","lora"} and
-                # the merge runs before the base apply, so the schedule's
-                # leaf indices would not line up with what the blocks see
-                raise ValueError(
-                    "--overlap-schedule needs the family's stacked "
-                    "params['layers'] layout; wrapped bundles (LoRA) are "
-                    "not supported")
-            layer_schedule = make_layer_schedule(
-                self.plan, self.logical_axes["layers"],
-                self.param_shapes["layers"],
-                remat=self.remat, remat_policy=policy)
-            use_fused_loss = fused_loss_supported(
-                self.plan, cfg, family_module(self.bundle.family),
-                self.loss_fn) is None
-
         chunked_ce = None
-        if ((self.loss_chunks > 0 or use_fused_loss)
-                and self.plan.mesh.shape["pp"] == 1):
+        if self.loss_chunks > 0 and self.plan.mesh.shape["pp"] == 1:
             from ..models.registry import family_module
             from ..ops.cross_entropy import (chunked_causal_lm_loss,
                                              validate_chunked_loss_support)
@@ -636,23 +577,13 @@ class Trainer:
             chunk_mod = family_module(self.bundle.family)
             validate_chunked_loss_support(chunk_mod, self.bundle.family,
                                           self.loss_fn)
-            n_chunks = self.loss_chunks or 8
 
-            if use_fused_loss:
-                from ..ops.overlap import make_fused_loss
-
-                fused = make_fused_loss(self.plan, num_chunks=n_chunks)
-
-                @jax.named_scope("loss_head")
-                def chunked_ce(params, hidden, labels):
-                    w_out = chunk_mod.output_weights(cfg, params)
-                    return fused(hidden, w_out, labels)
-            elif self.head_gather["once"]:
+            if self.head_gather["once"]:
                 from ..ops.cross_entropy import make_gathered_chunked_loss
 
                 gathered = make_gathered_chunked_loss(
                     self.plan.mesh, self.head_gather["spec"],
-                    self.plan.data_axes, num_chunks=n_chunks)
+                    self.plan.data_axes, num_chunks=self.loss_chunks)
 
                 @jax.named_scope("loss_head")
                 def chunked_ce(params, hidden, labels):
@@ -664,7 +595,7 @@ class Trainer:
                 def chunked_ce(params, hidden, labels):
                     w_out = chunk_mod.output_weights(cfg, params)
                     return chunked_causal_lm_loss(
-                        hidden, w_out, labels, num_chunks=n_chunks,
+                        hidden, w_out, labels, num_chunks=self.loss_chunks,
                         logits_sharding=logits_sharding)
 
         # every loss branch returns (loss, extras) where extras is a dict of
@@ -711,8 +642,7 @@ class Trainer:
                               else None)
                 moe_ep = make_ragged_ep_dispatch(
                     self.plan.mesh, cfg, data_axes=self.plan.data_axes,
-                    embed_axis=embed_axis,
-                    overlap=self.overlap_schedule)
+                    embed_axis=embed_axis)
 
             def loss_on_microbatch(params, mb):
                 out, aux, moe_metrics = apply_aux(
@@ -721,8 +651,7 @@ class Trainer:
                     remat=self.remat, remat_policy=policy,
                     attn_impl=attn_impl,
                     activation_sharding=act_sharding, return_metrics=True,
-                    return_hidden=chunked_ce is not None, moe_ep=moe_ep,
-                    layer_schedule=layer_schedule)
+                    return_hidden=chunked_ce is not None, moe_ep=moe_ep)
                 if chunked_ce is not None:
                     ce = chunked_ce(params, out, mb["labels"])
                 else:
@@ -738,8 +667,7 @@ class Trainer:
                                remat=self.remat, remat_policy=policy,
                                attn_impl=attn_impl,
                                activation_sharding=act_sharding,
-                               return_hidden=True,
-                               layer_schedule=layer_schedule)
+                               return_hidden=True)
                 return chunked_ce(params, hidden, mb["labels"]), {}
         else:
             def loss_on_microbatch(params, mb):
@@ -747,8 +675,7 @@ class Trainer:
                                positions=mb.get("positions"),
                                remat=self.remat, remat_policy=policy,
                                attn_impl=attn_impl,
-                               activation_sharding=act_sharding,
-                               layer_schedule=layer_schedule)
+                               activation_sharding=act_sharding)
                 if logits_sharding is not None:  # loss-parallel (vocab sharded)
                     logits = jax.lax.with_sharding_constraint(logits, logits_sharding)
                 with jax.named_scope("loss_head"):
@@ -863,8 +790,8 @@ class Trainer:
         # untestable off-TPU. Whole-state transfers match the reference's
         # CPU offload semantics anyway (full grad D2H + host optimizer.step,
         # 05/README.md:191-224); HBM still only holds params/opt state for
-        # the duration of the step. The sweep's offload_opt_b8 rung measures
-        # the actual round-trip cost on the real chip.
+        # the duration of the step (the round trip's cost on the chip is
+        # not measured on this tree).
         def step_and_offload(state, batch):
             state = jax.device_put(state, self._device_state_shardings)
             new_state, metrics = jitted(state, batch)

@@ -1,7 +1,7 @@
 """The one place that turns on JAX's persistent compilation cache.
 
 Every entry point (``train/cli.py``, ``serve/__main__.py``,
-``post/cli.py``, ``bench.py``, ``tests/conftest.py``) calls
+``post/cli.py``, ``tests/conftest.py``) calls
 ``enable_compile_cache()`` before its first compile. The cache directory
 is part of the cache key, so it has to be the same path in every process
 that should share compiled programs:

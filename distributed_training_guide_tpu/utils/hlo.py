@@ -1,35 +1,29 @@
-"""HLO inspection helpers: collective schedules and tensor-shape pins.
+"""HLO inspection helpers: collectives and tensor-shape pins.
 
 Tests in this repo pin two kinds of compiled-program properties:
 
 - *shape pins* — a tensor of a given dtype/shape must (not) exist in the
-  lowered or compiled text ("the fused loss never materializes [B*S, V]
+  lowered or compiled text ("the chunked loss never materializes [B*S, V]
   fp32 logits", "no device holds the full-E expert stack"). Lowered
   StableHLO spells avals ``tensor<8x16xf32>``; compiled HLO spells them
   ``f32[8,16]``. ``has_aval`` matches both so a pin survives the
   lowered/compiled choice.
-- *schedule pins* — the latency-hiding schedules (ops/overlap.py) are only
-  real if their collectives can overlap compute: on TPU the compiled module
-  shows async ``all-gather-start``/``all-gather-done`` pairs with compute
-  scheduled between them; everywhere, the collectives must sit in the FLAT
-  entry program, not trapped inside a ``while`` body (a ``lax.scan`` over
-  layers structurally cannot issue layer i+1's gather during layer i —
-  that is exactly what the schedules replace).
+- *collective pins* — which collectives a compiled program holds, how many
+  of each kind, and whether one that moves a given array sits inside a
+  ``while`` body (``collectives_moving``: "the head's matrix is gathered
+  once a step, outside both chunk loops").
 
-Shared by tests/test_overlap.py, test_moe.py, test_serve.py,
-test_paged_decode.py, test_405b_recipe.py.
+Shared by tests/test_moe.py, test_serve.py, test_paged_decode.py,
+test_405b_recipe.py, test_chunked_loss.py, test_chip_compile.py.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Iterable, Optional, Sequence
+from typing import Iterable, Sequence
 
 COLLECTIVE_KINDS = ("all-gather", "reduce-scatter", "all-reduce",
                     "collective-permute", "all-to-all")
-
-# ops that count as "compute" when asserting an async pair spans work
-COMPUTE_OPS = ("fusion", "dot", "convolution", "custom-call")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,72 +191,6 @@ def while_body_computations(text: str) -> set[str]:
         seen.add(c)
         stack.extend(graph.get(c, ()))
     return seen
-
-
-def collectives_outside_loops(text: str,
-                              kinds: Sequence[str] = COLLECTIVE_KINDS
-                              ) -> list[CollectiveOp]:
-    """Collectives NOT (transitively) inside a while body — the ones a
-    latency-hiding scheduler is free to slide across layer boundaries. A
-    scan-over-layers program reports its per-layer collectives as inside
-    the loop; the unrolled overlap schedule reports them all free."""
-    loops = while_body_computations(text)
-    return [c for c in find_collectives(text, kinds)
-            if c.computation not in loops]
-
-
-def async_collective_pairs(text: str,
-                           kinds: Sequence[str] = COLLECTIVE_KINDS
-                           ) -> list[tuple[CollectiveOp, CollectiveOp]]:
-    """(start, done) pairs, matched by the done op referencing the start op
-    by name (the HLO async-pair contract). Sync spellings yield no pairs —
-    CPU lowers collectives synchronously, TPU's latency-hiding scheduler
-    emits the async form."""
-    cols = find_collectives(text, kinds)
-    starts = {c.name: c for c in cols if c.is_start}
-    pairs = []
-    lines = text.splitlines()
-    for done in cols:
-        if not done.is_done:
-            continue
-        # the done op references its start by name somewhere in its operand
-        # list (which may carry a spaced tuple type — don't try to parse the
-        # grammar, just scan the references; [0] is the done's own name)
-        refs = re.findall(r"%[\w.\-]+", lines[done.line])
-        start = next((starts[r] for r in refs[1:] if r in starts), None)
-        if start is None:  # fall back: same kind, same computation, before it
-            cands = [s for s in starts.values()
-                     if s.kind == done.kind and s.computation == done.computation
-                     and s.line < done.line]
-            start = max(cands, key=lambda s: s.line) if cands else None
-        if start is not None:
-            pairs.append((start, done))
-    return pairs
-
-
-def assert_async_pairs_span_compute(text: str, *, min_pairs: int = 1,
-                                    kinds: Sequence[str] = COLLECTIVE_KINDS,
-                                    compute_ops: Sequence[str] = COMPUTE_OPS
-                                    ) -> int:
-    """Assert >= ``min_pairs`` async collective pairs exist and at least one
-    of them brackets compute (an op from ``compute_ops`` scheduled between
-    start and done) — the literal "collective in flight while the chip
-    works" property. Returns the number of compute-spanning pairs."""
-    pairs = async_collective_pairs(text, kinds)
-    assert len(pairs) >= min_pairs, (
-        f"expected >= {min_pairs} async collective pairs, found {len(pairs)}")
-    lines = text.splitlines()
-    spanning = 0
-    for start, done in pairs:
-        if start.computation != done.computation:
-            continue
-        for i in range(start.line + 1, done.line):
-            m = _OP_RE.match(lines[i])
-            if m and m.group(2) in compute_ops:
-                spanning += 1
-                break
-    assert spanning >= 1, "no async collective pair spans any compute op"
-    return spanning
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
-"""Walk the measured lever ladder (README.md here) on YOUR model and report
-the winning flag set.
+"""Walk the lever ladder (README.md here) on YOUR model, measuring each step,
+and report the winning flag set.
 
 The reference tunes these knobs by hand, chapter by chapter (batch in its
 ``02``, activation checkpointing + offload in ``04``/``05``); this walks
-them automatically the way the round-4 bench sweep was run: every probe in
+them automatically: every probe in
 a kill-able subprocess (an OOM or a pool stall costs one probe, never the
 walk), keep a lever only if measured time-per-token improves, re-walk batch
 last because every earlier lever moves the HBM knee.
@@ -30,7 +30,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 RUNNER = os.path.join(REPO, "01-single-chip", "train_llm.py")
 
-# the measured-order ladder (README.md table); each entry: (name, extra flags)
+# the ladder in the README's order; each entry: (name, extra flags)
 REMAT_LADDER = ["all", "attn", "attn_mlp"]
 
 
@@ -52,8 +52,8 @@ def parse_mfu(out: str) -> float | None:
 
 
 def classify_failure(err: str) -> str:
-    """Same canonical XLA markers as bench.py's child classifier: device HBM
-    exhaustion is retire-the-config, pool-capacity rejection is retryable."""
+    """By XLA's canonical markers: device HBM exhaustion is
+    retire-the-config, pool-capacity rejection is retryable."""
     if ("Out of memory" in err or "Largest program allocations" in err
             or "Error allocating device buffer" in err):
         return "oom"
@@ -108,10 +108,10 @@ def plan_walk(args) -> list[dict]:
                                 "--remat-policy", policy]})
     steps.append({"name": "adafactor", "batch": args.batch,
                   "flags": ["--optimizer", "adafactor"]})
-    # re-walk the remat ladder AFTER adafactor: the measured headline
-    # (fence4 + adafactor + attn_mlp, BENCH.md) is only reachable this way —
-    # attn_mlp's bigger saved set needs the HBM adafactor frees, so its
-    # first probe (AdamW still active) can OOM and must get a second chance.
+    # re-walk the remat ladder AFTER adafactor: fence4 + adafactor +
+    # attn_mlp is only reachable this way — attn_mlp's bigger saved set
+    # needs the HBM adafactor frees, so its first probe (AdamW still
+    # active) can OOM and must get a second chance.
     # The walk skips any retry whose composed config it already measured.
     for policy in REMAT_LADDER[1:]:
         steps.append({"name": f"remat_{policy}_after_adafactor",

@@ -303,7 +303,7 @@ def test_compile_cache_helper(monkeypatch, env_dir):
 def test_only_the_helper_names_the_cache_directory():
     hits = subprocess.run(
         ["grep", "-rln", "--include=*.py", "jax_compilation_cache_dir",
-         "distributed_training_guide_tpu", "bench.py", "chip_smoke.py",
+         "distributed_training_guide_tpu", "chip_smoke.py",
          "tests/conftest.py", "01-single-chip",
          "04-fully-sharded-data-parallel"],
         cwd=REPO, capture_output=True, text=True).stdout.split()

@@ -108,10 +108,10 @@ def test_run_training_profile_trace(tmp_path, eight_devices):
 
 def test_run_training_fence_every_matches_per_step(tmp_path, eight_devices):
     """--fence-every N banks device losses and drains at fence/log/ckpt
-    boundaries (the bench-measured 695->618 ms dispatch-ahead lever,
-    BENCH.md). The computation is unchanged, so the logged running_loss
-    trajectory must be BIT-identical to the per-step-fenced default —
-    including a fence group (3) that doesn't divide log_freq (2)."""
+    boundaries (the dispatch-ahead lever). The computation is unchanged, so
+    the logged running_loss trajectory must be BIT-identical to the
+    per-step-fenced default — including a fence group (3) that doesn't
+    divide log_freq (2)."""
     out1 = run_training(make_args(tmp_path / "f1"),
                         lambda: make_plan("ddp", make_mesh()))
     out3 = run_training(make_args(tmp_path / "f3", fence_every=3),
@@ -136,37 +136,6 @@ def test_run_training_fence_every_rejects_zero(tmp_path, eight_devices):
     with pytest.raises(SystemExit):
         run_training(make_args(tmp_path, fence_every=0),
                      lambda: make_plan("ddp", make_mesh()))
-
-
-def test_run_training_timer_sync(tmp_path, eight_devices):
-    """--timer-sync (VERDICT r3 item 9): the device-fenced per-phase timer
-    mode — C17's reference semantics — runs the loop and produces nonzero
-    phase walltimes."""
-    args = make_args(tmp_path, timer_sync=True)
-    out = run_training(args, lambda: make_plan("ddp", make_mesh()))
-    assert out["host_state"]["global_step"] == 4
-    assert out["last_info"]["time/step"] > 0
-
-
-def test_device_sync_fences_dispatched_work(eight_devices):
-    """device_sync must actually wait for in-flight device work: timing an
-    async dispatch with the fence measures the compute, without it only the
-    dispatch."""
-    import jax.numpy as jnp
-
-    from distributed_training_guide_tpu.utils.timers import LocalTimer, device_sync
-
-    f = jax.jit(lambda x: (x @ x) @ x)
-    x = jnp.ones((1500, 1500))
-    jax.block_until_ready(f(x))     # compile outside the timed region
-    unsynced, synced = LocalTimer(), LocalTimer(sync_fn=device_sync)
-    for _ in range(3):
-        with unsynced:
-            f(x)                    # async dispatch returns immediately
-        jax.block_until_ready(f(x))  # drain so the next dispatch is clean
-        with synced:
-            f(x)                    # fence on __exit__ waits for the matmuls
-    assert synced.avg_elapsed_ms() > unsynced.avg_elapsed_ms()
 
 
 def test_run_training_tp_fsdp_with_accum(tmp_path, eight_devices):

@@ -5,7 +5,7 @@ controller hysteresis + cooldowns, two-phase drain-before-remove
 scale-down (with the chaos-abandon races), the degradation ladder's
 declared order and unwind, the staleness fence, and a randomized
 property drill over chaotic stats traces. Real-engine chaos drills live
-in test_chaos_serve.py; the measured rungs in bench.py --check-load.
+in test_chaos_serve.py.
 """
 import dataclasses
 import random

@@ -24,9 +24,7 @@ What is pinned here, and why these meters:
 - QUALITY METER: spec-decoding acceptance is a sensitive function of KV
   fidelity (a perturbed verify logit breaks a drafted run immediately,
   long before evals would move). Acceptance under the int8 pool must be
-  within 0.02 of the fp32-KV control on the lookup-friendly workload —
-  the same meter bench.py's kvq_spec_accept rung records (CPU point:
-  0.862 int8 vs 0.852 fp32).
+  within 0.02 of the fp32-KV control on the lookup-friendly workload.
 - ENGINE INVARIANTS carry over because quantization is pure per token
   (one absmax scale per written vector — never a function of co-resident
   page content): batch-1 identity, spec-on == spec-off, preemption

@@ -132,3 +132,26 @@ def test_init_fingerprints_are_stable():
         got = sum(float(np.asarray(leaf, np.float64).sum())
                   for leaf in jax.tree.leaves(p))
         np.testing.assert_allclose(got, want, rtol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("name", [
+    "gpt2-debug", "llama-debug", "neox-debug", "moe-debug", "laguna-debug",
+    "mla-moe-debug", "lfm2-moe-debug", "mimo-v2-debug", "solar-open2-debug"])
+def test_one_way_through_the_layers(name):
+    """A family's forward has ONE traversal of its layers and the trainer one
+    loss head a plan: no argument swaps the layer loop for another program
+    (the ``layer_schedule=`` / ``overlap=`` fork went in PR 47), and no
+    Trainer field asks for one."""
+    import dataclasses
+    import inspect
+
+    from distributed_training_guide_tpu.models.moe import (
+        make_ragged_ep_dispatch)
+    from distributed_training_guide_tpu.train import Trainer
+
+    bundle = get_model(name)
+    forks = {"layer_schedule", "overlap", "overlap_schedule"}
+    for fn in (bundle.apply, bundle.apply_with_aux, make_ragged_ep_dispatch):
+        if fn is not None:
+            assert not forks & set(inspect.signature(fn).parameters), fn
+    assert not forks & {f.name for f in dataclasses.fields(Trainer)}

@@ -1,5 +1,5 @@
 """Fast unit tests for aux subsystems: supervisor, monitor, data pipeline,
-loss masking, LR schedule host mirror, error files."""
+loss masking, LR schedule host mirror, error files, the HLO text parser."""
 import json
 import subprocess
 import sys
@@ -9,6 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 
 import pytest
+
+from distributed_training_guide_tpu.utils import hlo as hlo_util
 
 REPO = Path(__file__).parent.parent
 
@@ -175,3 +177,89 @@ def test_multi_slice_mesh_fallback(eight_devices):
     mesh = make_mesh(fsdp=4, multi_slice=True)
     assert mesh.shape["fsdp"] == 4 and mesh.shape["dp"] == 2
     assert mesh.devices.size == len(jax.devices())
+
+
+# ---- utils/hlo.py parser units (no device work) ---------------------------
+
+_SYNTH = """\
+HloModule synth
+
+%loop_body (p: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %ag.9 = f32[32] all-gather(f32[8] %x9), dimensions={0}
+  ROOT %t = (s32[], f32[8]) tuple(%i, %y)
+}
+
+%cond (p: (s32[], f32[8])) -> pred[] {
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}
+
+ENTRY %main (a: f32[16,8]) -> f32[] {
+  %ag-start.1 = (f32[16,8]{1,0}, f32[64,8]{1,0}) all-gather-start(f32[16,8] %a), dimensions={0}
+  %fusion.1 = f32[16,8] fusion(f32[16,8] %a), kind=kLoop, calls=%fc
+  %ag-done.1 = f32[64,8]{1,0} all-gather-done((f32[16,8], f32[64,8]) %ag-start.1)
+  %w = (s32[], f32[8]) while((s32[], f32[8]) %init), condition=%cond, body=%loop_body
+  %rs.2 = f32[4,8] reduce-scatter(f32[16,8] %fusion.1), dimensions={0}
+  ROOT %r = f32[] constant(0)
+}
+"""
+
+
+def test_hlo_parser_units():
+    cols = hlo_util.find_collectives(_SYNTH)
+    kinds = sorted(c.kind for c in cols)
+    assert kinds == ["all-gather", "all-gather", "all-gather",
+                     "reduce-scatter"]
+    assert hlo_util.while_body_computations(_SYNTH) >= {"%loop_body",
+                                                        "%cond"}
+    # which collectives move an array of a given size, and from inside a loop?
+    def moving(elements, **kw):
+        return [(c.name, in_loop) for c, in_loop in
+                hlo_util.collectives_moving(_SYNTH, elements, **kw)]
+
+    assert moving(64 * 8) == [("%ag-start.1", False)]   # the -done is left out
+    assert moving(32) == [("%ag.9", True), ("%rs.2", False)]
+    assert moving(128, shards=4) == [("%ag-start.1", False), ("%rs.2", False)]
+
+    assert hlo_util.has_aval(_SYNTH, "f32", (16, 8))
+    assert hlo_util.has_aval("tensor<16x8xf32>", "f32", (16, 8))
+    assert not hlo_util.has_aval(_SYNTH, "f32", (16, 9))
+    assert hlo_util.has_shape_run("tensor<4x16x8xbf16>", (16, 8))
+    assert not hlo_util.has_shape_run("tensor<116x8xbf16>", (16, 8))
+
+
+# lines as the chip's compiler prints them (a described-v5e compile of ch04's
+# step): tiled layouts nest parentheses inside tuple result types, and a
+# reduce-scatter is a custom fusion around an all-reduce
+_CHIP = """\
+HloModule chip
+
+%all-reduce-scatter (input: f32[8,2048,1024]) -> f32[4104,8,128] {
+  %all-reduce.41 = f32[16416,8,128]{2,1,0:T(8,128)} all-reduce(%pad.225), channel_id=200, replica_groups={{0,1,2,3}}, to_apply=%add
+}
+
+ENTRY %main (a: f32[16,8]) -> f32[] {
+  %collective-permute-start = (bf16[1,2,128,1024]{3,2,1,0:T(8,128)(2,1)S(1)}, bf16[1,2,128,1024]{3,2,1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%x), source_target_pairs={{0,1}}
+  %collective-permute-done = bf16[1,2,128,1024]{3,2,1,0:T(8,128)(2,1)S(1)} collective-permute-done(%collective-permute-start)
+  %all-gather.82 = bf16[1,1024,3072]{2,1,0:T(8,128)(2,1)} all-gather(%p), replica_groups=[1,4]<=[4], dimensions={0}
+  %all-reduce.51 = (f32[8,128]{1,0:T(8,128)S(1)}, f32[8,128,1]{1,0,2:T(8,128)S(1)}) all-reduce(%a, %b), replica_groups=[1,4]<=[4], to_apply=%add
+  %fusion.10 = f32[4104,8,128]{2,1,0:T(8,128)S(1)} fusion(%sel), kind=kCustom, calls=%all-reduce-scatter
+  ROOT %r = f32[] constant(0)
+}
+"""
+
+
+def test_hlo_parser_reads_chip_layouts():
+    """Tuple results with tiled layouts parse (they were skipped whole:
+    every collective-permute and tuple all-reduce of a chip program), and
+    the summary keeps the fused reduce-scatter apart from real all-reduces."""
+    kinds = sorted(c.kind for c in hlo_util.find_collectives(_CHIP)
+                   if not c.is_done)
+    assert kinds == ["all-gather", "all-reduce", "all-reduce",
+                     "collective-permute"]
+    assert hlo_util.collective_summary(_CHIP) == {
+        "counts": {"collective-permute": 1, "all-gather": 1,
+                   "all-reduce": 1, "reduce-scatter-fusion": 1},
+        "largest_all_reduce_bytes": 2 * 8 * 128 * 4}
+    # an explicit reduce-scatter op (the CPU compiler's form) is counted too
+    assert hlo_util.collective_summary(_SYNTH)["counts"] == {
+        "all-gather": 2, "reduce-scatter": 1}

@@ -340,7 +340,7 @@ def test_int8_engine_batch1_spec_and_chunk_identity(llama):
 
 
 def test_int8_spec_acceptance_meter_vs_snapped_fp(llama):
-    """THE quality meter (bench wq_spec_accept's CI pin): acceptance on
+    """THE quality meter: acceptance on
     the lookup-friendly workload under int8 weights within 0.02 of the
     snapped-fp control — same rounded policy, fp storage — so the gated
     variable is the storage + in-kernel-dequant path, not the rounding
