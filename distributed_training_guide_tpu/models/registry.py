@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional
 
-from . import (gpt2, laguna, lfm2, llama, mimo_v2, mla, moe, neox,
+from . import (gpt2, jamba, laguna, lfm2, llama, mimo_v2, mla, moe, neox,
                solar_open2)
 
 
@@ -58,6 +58,7 @@ _HF_ALIASES = {
     "xiaomimimo/mimo-v2.5": "mimo-v2.5",
     "poolside/laguna-xs.2": "laguna-xs.2",
     "upstage/solar-open2-250b": "solar-open2-250b",
+    "ai21labs/ai21-jamba2-3b": "jamba2-3b",
 }
 
 
@@ -66,7 +67,7 @@ def family_module(family: str):
     by the pipeline schedule and chunked losses)."""
     mods = {"llama": llama, "gpt2": gpt2, "moe": moe, "neox": neox,
             "mla_moe": mla, "lfm2_moe": lfm2, "mimo_v2": mimo_v2,
-            "laguna": laguna, "solar_open2": solar_open2}
+            "laguna": laguna, "solar_open2": solar_open2, "jamba": jamba}
     if family not in mods:
         raise KeyError(f"unknown model family {family!r}")
     return mods[family]
@@ -76,7 +77,8 @@ def list_models() -> list[str]:
     return (sorted(gpt2.PRESETS) + sorted(llama.PRESETS) + sorted(moe.PRESETS)
             + sorted(neox.PRESETS) + sorted(mla.PRESETS)
             + sorted(lfm2.PRESETS) + sorted(mimo_v2.PRESETS)
-            + sorted(laguna.PRESETS) + sorted(solar_open2.PRESETS))
+            + sorted(laguna.PRESETS) + sorted(solar_open2.PRESETS)
+            + sorted(jamba.PRESETS))
 
 
 def get_model(name: str, **overrides) -> ModelBundle:
@@ -151,6 +153,12 @@ def get_model(name: str, **overrides) -> ModelBundle:
         return ModelBundle(key, config, solar_open2.init, solar_open2.apply,
                            solar_open2.param_logical_axes,
                            family="solar_open2")
+    if key in jamba.PRESETS:
+        config = jamba.PRESETS[key]
+        if overrides:
+            config = dataclasses.replace(config, **overrides)
+        return ModelBundle(key, config, jamba.init, jamba.apply,
+                           jamba.param_logical_axes, family="jamba")
     raise ValueError(
         f"Unknown model {name!r}. Available: {', '.join(list_models())} "
         f"(HF aliases: {', '.join(sorted(_HF_ALIASES))})"

@@ -56,33 +56,16 @@ import jax.numpy as jnp
 from . import llama
 from .llama import _rmsnorm
 from .moe import _moe_ffn, experts_held, experts_in_place
+from .state_class import STATE_CLASS_REFUSES
 from ..ops.attention import multihead_attention
 from ..ops.kda import kda_chunk, kda_step
 
 GQA, KDA = "gqa", "kda"
 
 # what ServeEngine refuses for this family, by the option's name, each with
-# the module that would have to change
-SERVE_REFUSES = {
-    "prefix_cache": "a hit needs the KDA state AT the hit's last token, and "
-                    "the state class keeps a sequence's newest alone "
-                    "(snapshots at page boundaries: scheduler.PrefixCache)",
-    "speculate": "a rejected draft would have to roll the KDA state back "
-                 "(serve/spec.py verifies into the live state)",
-    "decode_horizon": "a lane that ends inside a horizon would go on "
-                      "updating its state block (engine.horizon_for masks "
-                      "page tables alone)",
-    "host_tier_bytes": "serve/tiering.py gathers and scatters page ids "
-                       "alone, and the state has none",
-    "disaggregation": "serve/transport.py hands over page ids alone, and "
-                      "the state has none",
-    "engine swap": "Scheduler.adopt seats page ids alone",
-    "plan / shard_kv": "the tp serve mesh splits kv heads; the state class "
-                       "has no sharding rule (serve/sharding.py)",
-    "kv_dtype='int8'": "the state class is stored in float",
-    "weight_dtype='int8'": "serve/weights.py selects llama leaves only",
-    "max_adapters": "the LoRA hooks wrap llama's projections",
-}
+# the module that would have to change: what the state class refuses for
+# every family that keeps one, and nothing of its own
+SERVE_REFUSES = STATE_CLASS_REFUSES
 
 
 @dataclasses.dataclass(frozen=True)

@@ -121,24 +121,28 @@ there, and the gather path masks those positions by the same band.
 ``make_attend`` hands each layer the half its ``page_class`` names. A
 one-class family is the case without the second half: none of this runs.
 
-A family whose recurrent state is too large to keep a row a page
-(``models/solar_open2.py``: a ``heads x d x d`` float32 matrix a layer,
-megabytes a sequence) states a STATE CLASS in
-``config.sequence_state_layout()``, ADDRESSED BY SEQUENCE: the pool dict
-gains the leaves it names (:data:`SEQUENCE_LEAVES`), each ``[state layers,
-n_blocks, ...]``, a BLOCK a live sequence, with an id space of its own
-(block 0 the trash block, which idle slots carry). ``PagePool.state`` is its
-free list: the scheduler takes a block when it admits a sequence and returns
-it when the sequence leaves its slot (finished, expired or preempted: a
-preempted sequence is prefilled again), so ``n_slots + 1`` blocks always
-suffice. A slot's block id rides every program as ONE MORE COLUMN of its
-table row, the last (``make_attend(state_class=True)`` takes it off and hands
-it to the family as ``attend.state_blocks``). A block is not zeroed when it
-changes hands: a sequence that starts at position 0 reads zeros instead of it
-(the family's step, on ``attend.lengths == 0``). The state has no page
-identity, so what moves pages by id (``copy_pages``, the prefix cache, the
-host tier, the handoff, an engine swap) does not move it, and such a family
-refuses them by name.
+A family whose recurrent state is too large to keep a row a page states a
+STATE CLASS in ``config.sequence_state_layout()``, ADDRESSED BY SEQUENCE. Two
+families do: ``models/solar_open2.py`` (KDA: a ``heads x d x d`` float32
+matrix a layer, 4 MB, in 3 of its 4 layers) and ``models/jamba.py`` (Mamba: a
+``[d_state, channels]`` float32 block a layer, channels on the lanes, 320 KB,
+in 26 of its 28 layers; megabytes a sequence either way). The pool dict gains
+the leaves the layout names (:data:`SEQUENCE_LEAVES`: the state, and the last
+rows of the family's short convolution), each ``[state layers, n_blocks,
+...]``, a BLOCK a live sequence, with an id space of its own (block 0 the
+trash block, which idle slots carry). ``PagePool.state`` is its free list: the
+scheduler takes a block when it admits a sequence and returns it when the
+sequence leaves its slot (finished, expired or preempted: a preempted
+sequence is prefilled again), so ``n_slots + 1`` blocks always suffice. A
+slot's block id rides every program as ONE MORE COLUMN of its table row, the
+last (``make_attend(state_class=True)`` takes it off and hands it to the
+family as ``attend.state_blocks``). A block is not zeroed when it changes
+hands: a sequence that starts at position 0 reads zeros instead of it (the
+family's step, on ``attend.lengths == 0``). The state has no page identity,
+so what moves pages by id (``copy_pages``, the prefix cache, the host tier,
+the handoff, an engine swap) does not move it, and every such family refuses
+them by name, in the same words: ``models/state_class.STATE_CLASS_REFUSES``
+(beside the models, because this package imports them).
 
 Device-side pieces (``paged_attend``, ``copy_pages``) are pure functions
 of array arguments — block tables and lengths arrive as int32 arrays, so

@@ -89,10 +89,15 @@ SCOPES = (
 # GSPMD has no such events) and `kda` (in `attn`: a KDA layer's whole mixer,
 # `models/solar_open2.py`: norm, projections, the short convolutions, the
 # `kda_step` / `kda_chunk` recurrence, the gated read-out and `W_o`; the
-# state's writes are under `kv_write`). A reader that knows only SCOPES counts
-# their time under the parent; `readers/path_component.py` reads one alone
+# state's writes are under `kv_write`) and `ssm` (in `attn`: a Mamba layer's
+# whole mixer, `models/jamba.py`: norm, `W_in`, the convolution, `W_x`, the
+# inner norms, `W_dt`, the `ssm_step` / `ssm_chunk` recurrence, the gate and
+# `W_out`; the state's writes under `kv_write` again). A reader that knows
+# only SCOPES counts their time under the parent;
+# `readers/path_component.py` reads one alone
 SUBSCOPES = ("latent_proj", "shared_expert", "conv", "attend_full",
-             "attend_window", "head_gather", "attn_full", "attn_window", "kda")
+             "attend_window", "head_gather", "attn_full", "attn_window", "kda",
+             "ssm")
 
 # pallas_call names (ops/); `kda_step` and `kda_chunk` name the two forms of
 # the KDA recurrence whatever implements them: on a TPU each is ONE Pallas
@@ -100,10 +105,12 @@ SUBSCOPES = ("latent_proj", "shared_expert", "conv", "attend_full",
 # program three `kda_chunk`: the chunk's whole gated delta rule, its state in
 # VMEM from the first block to the last), elsewhere plain jnp; both sit
 # under a `named_scope` of the same name, so a path reads
-# `attn/kda/kda_chunk/...` either way (`ops/kda.py`)
+# `attn/kda/kda_chunk/...` either way (`ops/kda.py`); `ssm_step` and
+# `ssm_chunk` likewise name the two forms of Mamba's selective scan
+# (`ops/ssm.py`: one kernel a Mamba layer on a TPU, `attn/ssm/ssm_step/...`)
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",
            "paged_latent_attend", "gmm", "tgmm", "qmm", "kda_step",
-           "kda_chunk")
+           "kda_chunk", "ssm_step", "ssm_chunk")
 
 # jitted programs; a name ending in _k or _t takes the static size that
 # keys the program (serve_horizon_k4, serve_chunk_t64, serve_verify_t5 and

@@ -82,6 +82,23 @@ itself 2.5e-6 from float64, and a case says so); bfloat16 operands would
 read 1e-3; and two sabotages the bound must refuse (the step's and the
 chunk's decay left out).
 
+``--ssm`` runs the state-space cell's two scans alone
+(``jamba2-3b.serve.chat256``; ``ops/ssm.py``): the recurrent step's Pallas
+kernel at the cell's size (256 slots of a 16 x 5,120 state, the pool of 26
+layers x 257 blocks updated in place at layer 3, a few slots on the trash
+block and a few at position 0) against the gather / ``jnp`` / scatter path,
+the blocks no slot holds left as they were; the chunk's Pallas kernel over
+1,024 tokens from a non-zero state with 1,000 of them real (``Delta A`` down
+to -1.6 a step) against the ``jnp`` scan over tokens (1e-5 of the largest
+element; read 0.0 on the chip: the same float32 operations in the same
+order) and against the recurrence in float64 on the host (64 channels, 1e-4:
+on the chip both float32 forms end 0.95e-5 of the largest ``y`` and 4.5e-5 of
+the largest state element from it after 1,000 steps, the chip's float32
+``exp`` and a thousand roundings a channel; no product runs on the MXU, so
+nothing is rounded to bfloat16, which would read 1e-2); one sabotage each
+the bound must refuse (the decay left out); and
+each kernel's time at that size, a line each (``ssm_timing``).
+
 One process; fails (no last line, exit 1) off the chip. Prints the entry
 points' start-up device line, one JSON line per case, and last
 ``{"kernel_parity_ok": true, "cases": N, "controls_refused": 5}``.
@@ -89,6 +106,7 @@ points' start-up device line, one JSON line per case, and last
 import importlib
 import json
 import sys
+import time
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -704,6 +722,109 @@ def kda_cases() -> int:
     return refused
 
 
+def ssm_inputs(shape, channels=5120, n=16):
+    """Rows of the scan at ``shape = (...)``: x of order one, a step
+    log-uniform in [0.001, 0.1], B and C of order one; A = -(1..N), D = 1."""
+    x = normal((*shape, channels), jnp.float32)
+    delta = jnp.asarray(np.exp(RNG.uniform(
+        np.log(0.001), np.log(0.1), (*shape, channels))), jnp.float32)
+    b, c = normal((*shape, n), jnp.float32), normal((*shape, n), jnp.float32)
+    a = -jnp.broadcast_to(jnp.arange(1.0, n + 1)[:, None], (n, channels))
+    return x, delta, b, c, a, jnp.ones((channels,), jnp.float32)
+
+
+def timed(fn, x, carry=False, reps=20) -> float:
+    """Milliseconds a call of ``fn(x)``, after one that compiles; ``carry``:
+    each call takes the last one's result (a program that donates ``x``)."""
+    out = jax.block_until_ready(fn(x))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(out if carry else x)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / reps
+
+
+def ssm_cases() -> int:
+    from distributed_training_guide_tpu.ops import ssm
+
+    slots, layers, blocks, n, ch = 256, 26, 257, 16, 5120
+    pool = normal((layers, blocks, n, ch), jnp.float32)
+    ids = RNG.permutation(np.arange(1, blocks)).astype(np.int32)
+    ids[[5, 77, 190]] = ssm.TRASH_BLOCK
+    fresh = np.zeros(slots, bool)
+    fresh[[9, 100]] = True
+    ids, fresh = jnp.asarray(ids), jnp.asarray(fresh)
+    rows = ssm_inputs((slots,))
+
+    def step(impl, rows=rows):
+        return jax.jit(lambda pool: ssm.ssm_step(pool, ids, 3, *rows, fresh,
+                                                 impl=impl))(pool)
+
+    (y, new), (y_ref, new_ref) = step("pallas"), step("xla")
+    held = np.flatnonzero(np.asarray(ids) != ssm.TRASH_BLOCK)
+    live = np.asarray(ids)[held]
+    idle = np.setdiff1d(np.arange(1, blocks), live)
+    case("ssm_step", {"y": (y[held], y_ref[held]),
+                      "state": (new[3, live], new_ref[3, live])},
+         rtol=1e-5, slots=slots, blocks=blocks, layers=layers)
+    others = np.setdiff1d(np.arange(layers), [3])
+    untouched = bool(jnp.array_equal(new[3, idle], pool[3, idle])
+                     and jnp.array_equal(new[others], pool[others]))
+    case("ssm_step_leaves_other_blocks", {"same": (
+        jnp.asarray(float(untouched)), jnp.asarray(1.0))})
+    x, delta, b, c, a, d = rows
+    y_bad, _ = step("pallas", (x, delta, b, c, jnp.zeros_like(a), d))
+    refused = int(not case("ssm_step_control_no_decay",
+                           {"y": (y_bad[held], y_ref[held])}, rtol=1e-5))
+    if refused:     # the control is meant to fail its bound
+        FAILED.remove("ssm_step_control_no_decay")
+    del new, new_ref
+    step_ms = timed(jax.jit(lambda pool: ssm.ssm_step(
+        pool, ids, 3, *rows, fresh, impl="pallas")[1], donate_argnums=0),
+        pool, carry=True)
+    del pool
+
+    t, real = 1024, 1000
+    h0 = normal((1, n, ch), jnp.float32)
+    crow = ssm_inputs((1, t))
+    nv = jnp.asarray([real])
+
+    def chunk(impl, rows=crow):
+        return jax.jit(lambda h0: ssm.ssm_chunk(h0, *rows, nv, impl=impl))(h0)
+
+    (y, h_t), (y_jnp, h_jnp) = chunk("pallas"), chunk("xla")
+    some = np.arange(0, ch, ch // 64)
+    x64, d64, b64, c64 = (np.asarray(v, np.float64)[0, :real] for v in crow[:4])
+    a64 = np.asarray(crow[4], np.float64)[:, some]
+    h64 = np.asarray(h0, np.float64)[0][:, some]
+    y64 = np.zeros((real, len(some)))
+    for i in range(real):
+        h64 = (np.exp(d64[i, some][None, :] * a64) * h64
+               + (d64[i, some] * x64[i, some])[None, :] * b64[i][:, None])
+        y64[i] = c64[i] @ h64 + x64[i, some]
+    case("ssm_chunk", {"y": (y[0, :real][:, some], y64),
+                       "state": (h_t[0][:, some], h64)},
+         rtol=1e-4, tokens=t, real=real, against="float64 scan")
+    case("ssm_chunk_jnp_form", {"y": (y[:, :real], y_jnp[:, :real]),
+                                "state": (h_t, h_jnp)},
+         rtol=1e-5, tokens=t, real=real)
+    x, delta, b, c, a, d = crow
+    y_bad, h_bad = chunk("pallas", (x, delta, b, c, jnp.zeros_like(a), d))
+    if not case("ssm_chunk_control_no_decay",
+                {"y": (y_bad[:, :real], y_jnp[:, :real]),
+                 "state": (h_bad, h_jnp)}, rtol=1e-5):
+        FAILED.remove("ssm_chunk_control_no_decay")
+        refused += 1
+    chunk_ms = timed(jax.jit(lambda h0: ssm.ssm_chunk(
+        h0, *crow, nv, impl="pallas")), h0)
+    print(json.dumps({"ssm_timing": {
+        "ssm_step_ms_a_layer_256_slots": step_ms,
+        "ssm_chunk_ms_a_layer_1024_tokens": chunk_ms,
+        "channels_a_block": ssm.CHANNELS, "tokens_a_block": ssm.TOKENS}}),
+        flush=True)
+    return refused
+
+
 def int8_matmul_case() -> None:
     qm = importlib.import_module(
         "distributed_training_guide_tpu.ops.quantized_matmul")
@@ -723,17 +844,27 @@ def int8_matmul_case() -> None:
 def main(argv) -> int:
     everything, mla_only = argv == ["--all"], argv == ["--mla"]
     lfm2_only, mimo_only = argv == ["--lfm2"], argv == ["--mimo"]
-    kda_only = argv == ["--kda"]
+    kda_only, ssm_only = argv == ["--kda"], argv == ["--ssm"]
     if argv and not (everything or mla_only or lfm2_only or mimo_only
-                     or kda_only):
+                     or kda_only or ssm_only):
         raise SystemExit(
-            "usage: kernel_parity.py [--all|--mla|--lfm2|--mimo|--kda]")
+            "usage: kernel_parity.py [--all|--mla|--lfm2|--mimo|--kda|--ssm]")
     print_device_line("attend", ("flash", "forced"), CACHE.directory)
     if jax.devices()[0].platform != EXPECT_PLATFORM:
         print(f"kernel_parity FAILED: runs on "
               f"{jax.devices()[0].platform!r}, not {EXPECT_PLATFORM!r}",
               file=sys.stderr)
         return 1
+    if ssm_only:
+        refused = ssm_cases()
+        CACHE.print_line()
+        if FAILED or refused != 2:
+            print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
+                  f"decay left out refused: {refused} of 2", file=sys.stderr)
+            return 1
+        print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
+                          "controls_refused": refused}), flush=True)
+        return 0
     if kda_only:
         refused = kda_cases()
         CACHE.print_line()

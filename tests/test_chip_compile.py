@@ -928,6 +928,122 @@ def test_state_class_familys_serve_programs_compile_at_the_cells_size(
     assert held < 14.5 * 2 ** 30, held / 2 ** 30
 
 
+# ---- the state-space family's cell -------------------------------------------
+# jamba2-3b.serve.chat256: the WHOLE model (28 layers, 65,536 rows), 256 slots
+# of 20 query heads on ONE kv head, page 128, 12 table columns, 3,073 pages,
+# 257 state blocks of 26 Mamba layers, a chunk of 1,024
+
+JAMBA_CELL = dict(slots=256, columns=12, pages=3073, chunk=1024)
+
+
+@pytest.mark.parametrize("slots,t", [(256, 1), (1, 1024)],
+                         ids=["decode", "chunk1024"])
+def test_paged_attend_compiles_at_20_query_heads_on_one_kv_head(
+        chip_compile, slots, t):
+    """A decode query block of 20 rows (not a whole sublane tile of 8s per
+    kv head as 4, 8 or 16 a group are) and a k page of 128 tokens x 1 head
+    x 128 = 32 KB."""
+    c = JAMBA_CELL
+    pool = ((2, c["pages"], 128, 1, D), jnp.bfloat16)
+    text = chip_compile(
+        lambda *a: paged_flash_attend(*a, interpret=False),
+        ((slots, t, 20, D), jnp.bfloat16), pool, pool, LAYER,
+        ((slots, c["columns"]), jnp.int32), ((slots,), jnp.int32))
+    calls = kernel_calls(text)
+    assert len(calls) == 1 and named(calls[0], "paged_attend"), calls
+    # (the chunk's call sits in a loop over query blocks: its tuple too)
+    assert set(pool_sized_ops(text, pool[0])) <= {
+        "bitcast", "parameter", "get-tuple-element", "tuple", "while"}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk1024"])
+def test_state_space_familys_serve_programs_compile_at_the_cells_size(
+        one_chip, chip_compile, compiled_kernels, monkeypatch, program):
+    """``jamba2-3b.serve.chat256``'s decode step (256 slots) and prefill
+    chunk (1,024 tokens), whole, at the cell's size (5.64 GiB of weights, k
+    and v of the two attention layers over 3,073 pages of 128, the state
+    class of 26 Mamba layers over 257 blocks: 2.04 GiB in float32 and 0.19
+    of conv rows): the decode step updates every slot's state where it lies
+    through ``ssm_step`` (one call a Mamba layer, the pool aliased in and
+    out), a chunk scans each Mamba layer through ONE ``ssm_chunk`` kernel,
+    the two attention layers go through the compiled ``paged_attend``, and
+    nothing weight-sized, pool-sized or state-class-sized is copied;
+    arguments and temporaries of either program stay under 14.5 GiB."""
+    import dataclasses
+
+    from distributed_training_guide_tpu.models import jamba
+    from distributed_training_guide_tpu.ops import ssm
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    monkeypatch.setattr(ssm, "resolve_interpret", lambda i: False)
+    monkeypatch.setattr(
+        ssm, "_resolve_impl",
+        lambda impl, op=None: "pallas" if impl == "auto" else impl)
+    c = JAMBA_CELL
+    cfg = dataclasses.replace(jamba.PRESETS["jamba2-3b"], dtype=jnp.bfloat16,
+                              param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: jamba.init(cfg, jax.random.key(0)))
+    leaves, treedef = jax.tree.flatten(params)
+    pools = jax.eval_shape(lambda: kv_pages.init_pages(
+        cfg, c["pages"], 128, n_state_blocks=c["slots"] + 1))
+    names = ("k", "v", "seq_state", "seq_conv")
+    assert pools["seq_state"].shape == (26, c["slots"] + 1, 16, 5120)
+    assert pools["seq_conv"].shape == (26, c["slots"] + 1, 3, 5120)
+    assert pools["k"].shape == (2, c["pages"], 128, 1, 128)
+    # float32 beside bf16 weights, k and v: the state's precision is not the
+    # pool's
+    assert pools["seq_state"].dtype == jnp.float32 \
+        and pools["k"].dtype == pools["seq_conv"].dtype == jnp.bfloat16
+    slots, t = (c["slots"], 1) if program == "decode" else (1, c["chunk"])
+
+    def step(kp, vp, sp, cp, ids, lengths, tables, *flat):
+        logits, cache = jamba.paged_decode_step(
+            cfg, jax.tree.unflatten(treedef, flat), ids, lengths,
+            dict(zip(names, (kp, vp, sp, cp))),
+            kv_pages.make_attend(tables, lengths, impl="flash",
+                                 n_valid=jnp.full((slots,), t),
+                                 state_class=True),
+            last_index=jnp.asarray(t - 1))
+        return (jnp.argmax(logits, -1), *(cache[n] for n in names))
+
+    specs = [(pools[n].shape, pools[n].dtype) for n in names] + [
+        ((slots, t), jnp.int32), ((slots,), jnp.int32),
+        ((slots, c["columns"] + 1), jnp.int32)] + [
+        (x.shape, x.dtype) for x in leaves]
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2, 3)).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in specs)).compile()
+    text = compiled.as_text()
+    calls = kernel_calls(text)
+    assert sum(named(x, "paged_attend") for x in calls) == 2, calls
+    assert sum(named(x, "ssm_step") for x in calls) == (
+        26 if program == "decode" else 0), calls
+    assert sum(named(x, "ssm_chunk") for x in calls) == (
+        0 if program == "decode" else 26), calls
+    # k, v and h (the conv leaf, three rows a block, is re-laid by the
+    # compiler as Solar's is: PERF.md section 6, PR 44) and the largest
+    # weights: the embedding (also the head), W_in, the FFN's three
+    sized = pool_sized_ops(text, *(pools[n].shape for n in names[:3]),
+                           names=True)
+    # (the chunk's one slot writes its state back by an in-place
+    # `dynamic-update-slice`, the decode step's 256 through the kernel)
+    in_place = ("parameter", "bitcast", "scatter", "get-tuple-element",
+                "fusion", "custom-call", "tuple", "dynamic-update-slice")
+    moved = [x for x in sized
+             if x.split()[0] not in in_place or "copy" in x
+             or ("slice" in x and x.split()[0] != "dynamic-update-slice")]
+    assert not moved, moved
+    weights = [(65536, 2560), (2560, 10240), (2560, 8192), (8192, 2560),
+               (5120, 2560)]
+    assert not operand_sized_moves(text, *weights, dtypes=("bf16",))
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes >= sum(
+        math.prod(pools[n].shape) * pools[n].dtype.itemsize for n in names)
+    assert held < 14.5 * 2 ** 30, held / 2 ** 30
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk512"])
 def test_moe_familys_serve_programs_read_the_experts_in_place(
         chip_compile, compiled_kernels, program):

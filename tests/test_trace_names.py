@@ -513,7 +513,8 @@ def test_latent_family_decode_carries_its_subscopes_and_kernel():
     assert any(f.startswith("attn/latent_proj/") for f in fragments)
     assert "paged_latent_attend" in KERNELS and set(SUBSCOPES) == {
         "latent_proj", "shared_expert", "conv", "attend_full",
-        "attend_window", "head_gather", "attn_full", "attn_window", "kda"}
+        "attend_window", "head_gather", "attn_full", "attn_window", "kda",
+        "ssm"}
 
 
 def test_named_gives_jit_the_name():
@@ -525,8 +526,8 @@ def test_the_vocabulary_is_what_the_package_uses():
     """``grep`` over the package: every ``span("...")``, ``named_scope("...")``
     and ``pallas_call`` name is in the tuples, and ``TraceAnnotation`` is
     used by ``utils/trace.py`` alone. A computation that is a kernel on the
-    chip and plain ``jnp`` elsewhere (``ops/kda.py``) carries its KERNELS name
-    as a ``named_scope`` too, whatever implements it."""
+    chip and plain ``jnp`` elsewhere (``ops/kda.py``, ``ops/ssm.py``) carries
+    its KERNELS name as a ``named_scope`` too, whatever implements it."""
     from pathlib import Path
 
     root = Path(trace_mod.__file__).resolve().parents[1]
@@ -543,5 +544,6 @@ def test_the_vocabulary_is_what_the_package_uses():
     assert spans | {"train.data", "train.step"} == set(SPANS)
     assert scopes - set(KERNELS) == set(SCOPES) | set(SUBSCOPES)
     assert not set(SCOPES) & set(SUBSCOPES)
-    assert scopes & set(KERNELS) == {"kda_step", "kda_chunk"}
+    assert scopes & set(KERNELS) == {"kda_step", "kda_chunk", "ssm_step",
+                                     "ssm_chunk"}
     assert kernels | (scopes & set(KERNELS)) == set(KERNELS)
