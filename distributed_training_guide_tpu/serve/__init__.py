@@ -36,6 +36,15 @@ actuates the elastic seams with hysteresis, cooldowns, drain-before-
 remove scale-down, and an explicit degradation ladder).
 See related-topics/serving/README.md.
 
+One engine iteration (``ServeEngine.step``): expire deadlines, restore
+from the host tier, admit, run one chunk budget of prefill, grow the
+decoding slots, then one batched decode. A step that completes a prefill
+on the plain path (K=1, no drafter) enqueues the chunk program and the
+decode program back to back: the first token is sampled and seated in the
+decode's token lanes on the device, the decode arrays go up while the
+chunk program runs, and the host reads once the decode is enqueued, the
+first token and then the decode's tokens.
+
     from distributed_training_guide_tpu.serve import (
         Request, ServeEngine, DisaggEngine, generate_many)
 """

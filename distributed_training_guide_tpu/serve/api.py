@@ -129,6 +129,11 @@ def throughput_stats(results: list[RequestResult],
         "host_dispatches": es.get("host_dispatches", 0),
         "tokens_per_dispatch": es.get("tokens_per_dispatch", 0.0),
         "horizon_effective": es.get("horizon_effective", 0.0),
+        # steps that completed a prefill, and those of them that dispatched
+        # the decode behind the chunk program before reading the first
+        # token (ServeEngine.step; the disaggregated pair has neither)
+        "chunk_steps": es.get("chunk_steps", 0),
+        "chunk_steps_overlapped": es.get("chunk_steps_overlapped", 0),
     }
 
 

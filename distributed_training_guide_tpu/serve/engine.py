@@ -377,7 +377,13 @@ def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
     logit)`` fires when a slot's final chunk lands (the engines differ
     there: the monolith samples the first token into the decode batch,
     the disaggregated prefill engine emits a Handoff); a non-None return
-    is a finished RequestResult. Single-sourced so budget discipline —
+    is a finished RequestResult. The chunk program is only ENQUEUED here
+    (``serve.prefill`` is its call, not its run): nothing in this function
+    reads the device, so an ``on_complete`` that reads nothing either (the
+    monolith's plain path, which leaves the first token on the device)
+    hands the step on with the chunk still running, and the decode arrays
+    and the decode program go up behind it.
+    Single-sourced so budget discipline —
     charged at the padded PROGRAM cost, not real tokens (the PR-6 review
     fix) — cannot fork between the engines."""
     finished = []
@@ -466,7 +472,7 @@ SPEC_ARRAYS = ("lengths", "tables", "seeds", "temps", "top_ks", "top_ps",
 
 
 def upload_decode_arrays(dev: dict, kind: str, sched: Scheduler, *,
-                         keys: Optional[tuple] = None,
+                         placement, keys: Optional[tuple] = None,
                          lookahead: bool = False) -> dict:
     """Make ``dev`` what the ``kind`` program may be called with, and return
     it: the ONE place a decode program's inputs go up from, and the one that
@@ -475,10 +481,14 @@ def upload_decode_arrays(dev: dict, kind: str, sched: Scheduler, *,
     tables (:meth:`DecodeArrays.stale_tables`), or after the caller's own
     ``lookahead`` reservation: ``tables`` alone, one transfer. Otherwise
     nothing, and no span. The numpy work is ``serve.arrays`` and fills only
-    what will go; the transfers are one ``jnp.asarray`` an array
+    what will go; the transfers are one ``jax.device_put`` an array
     (``serve.upload``, which says how many arrays and bytes); both lie
     inside ``serve.build``, whose ``reason`` is why ``dev`` could not be
-    used as it stood."""
+    used as it stood. The arrays go to ``placement``
+    (``ModelPrograms.operand_placement``) COMMITTED, as a program's own
+    outputs are: a decode program then has ONE signature, whether a lane
+    comes from the host, from the last step's outputs or from the one-lane
+    token write of a chunk step, and the first call warms them all."""
     whole = dev["kind"] != kind
     if whole:
         reason = dev["reason"] if dev["kind"] is None else "kind"
@@ -495,7 +505,8 @@ def upload_decode_arrays(dev: dict, kind: str, sched: Scheduler, *,
             else:
                 arrays = {"tables": sched.decode_tables()}
         with span("serve.upload") as up:
-            out = {key: jnp.asarray(v) for key, v in arrays.items()}
+            out = {key: jax.device_put(v, placement)
+                   for key, v in arrays.items()}
             up.set_metadata(arrays=len(arrays),
                             bytes=sum(v.nbytes for v in arrays.values()))
     return {**({} if whole else dev), "kind": kind, **out, "stale": None}
@@ -581,6 +592,7 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
             n_valid[i] = 1 + len(props)
     # lookahead growth extended a block table since the last upload
     dev = upload_decode_arrays(dev, "spec", sched, keys=SPEC_ARRAYS,
+                               placement=programs.operand_placement,
                                lookahead=grew)
     # static greedy specialization: when every active slot decodes at
     # temperature 0 the target draw is argmax and the verify program
@@ -620,9 +632,26 @@ def run_spec_decode(programs: "ModelPrograms", pages: dict,
     return finished, emitted_total, dev
 
 
+def book_first_tokens(sched: Scheduler, first) -> tuple[list, set]:
+    """Read the first tokens that ``ServeEngine._on_prefill_complete`` left
+    on the device, ``(admission, token)`` each, and record them: each read
+    is a ``serve.sample`` and waits for the chunk program that made the
+    logit. Returns the requests their first token finished (eos) and those
+    requests' slots."""
+    finished, ended = [], set()
+    for adm, token in first:
+        with span("serve.sample", request_id=adm.request.request_id):
+            token = int(token)
+        res = sched.record_token(adm.slot_idx, token, from_decode=False)
+        if res is not None:
+            finished.append(res)
+            ended.add(adm.slot_idx)
+    return finished, ended
+
+
 def run_decode_iteration(programs: "ModelPrograms", pages: dict,
                          sched: Scheduler, drafter: Optional[Drafter],
-                         spec: dict, dev: dict) \
+                         spec: dict, dev: dict, first: list = ()) \
         -> tuple[list, int, dict]:
     """ONE decode iteration over the active slots — the spec/plain
     dispatch, single-sourced for the monolith and the disaggregated
@@ -642,6 +671,21 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
     ``sched.decode_arrays()`` would upload (a replayed token the device
     sampled IS the recorded one, the bitwise-recompute rule above).
 
+    ``first``: ``(admission, token)`` of the slots whose prefill completed
+    in this step and whose first token is still ON THE DEVICE
+    (``ServeEngine._on_prefill_complete``; the caller passes it only where
+    the plain program will run: no drafter). Their ``tokens`` lanes went up
+    as placeholders; each token is written into its lane there
+    (``serve_seat_token``), the decode is dispatched behind the chunk
+    program that is still running, and only then does the host read: the
+    first tokens (``book_first_tokens``; they are ready when the chunk
+    program ends, and are recorded at that instant, so a request's
+    first-token time stays the moment the host had it), then the decode's
+    (the step's ONE ``serve.wait``). A first token that ENDS its request
+    (eos) means the decode ran one lane too many: that lane's token is not
+    booked, and its write went to a page and a state block the slot owned
+    and has freed, which no later program reads before it writes them.
+
     Returns (finished, tokens emitted, dev). The caller owns the
     decode_steps/decode_tokens counters and must drop ``dev`` when a
     finished slot leaves the batch."""
@@ -651,7 +695,13 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
         out = run_spec_decode(programs, pages, sched, drafter, spec, dev)
         if out is not None:
             return out
-    dev = upload_decode_arrays(dev, "plain", sched)
+    dev = upload_decode_arrays(dev, "plain", sched,
+                               placement=programs.operand_placement)
+    # in front of the span, not inside it: ``serve.dispatch`` stays the
+    # enqueue of ONE program, the one the step's waterfall joins it with
+    for adm, token in first:
+        dev["tokens"] = programs._seat_fn(
+            dev["tokens"], jnp.asarray(adm.slot_idx, jnp.int32), token)
     with span("serve.dispatch", program="serve_decode"):
         nxt, new_len, pools, *counted = programs._decode_fn(
             programs.params, dict(pages),
@@ -660,18 +710,19 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
             *programs.lora_call_args(dev["adapters"]))
         pages.update(pools)
     dev["tokens"], dev["lengths"] = nxt, new_len
+    finished, ended = book_first_tokens(sched, first)
     with span("serve.wait"):
         nxt_host = np.asarray(counted[0] if counted else nxt)
     if counted:
         programs.note_routing(nxt_host[sched.n_slots:])
-    finished = []
-    with span("serve.book", tokens=len(active)):
-        for slot_idx in active:
+    booked = [i for i in active if i not in ended]
+    with span("serve.book", tokens=len(booked)):
+        for slot_idx in booked:
             res = sched.record_token(slot_idx, int(nxt_host[slot_idx]),
                                      from_decode=True)
             if res is not None:
                 finished.append(res)
-    return finished, len(active), dev
+    return finished, len(booked), dev
 
 
 def dispatch_horizon(programs: "ModelPrograms", pages: dict,
@@ -699,7 +750,8 @@ def dispatch_horizon(programs: "ModelPrograms", pages: dict,
     Returns the in-flight record ``process_horizon_block`` consumes (the
     ``[n_slots, k]`` token-block future, the realized k, and the (slot,
     request_id) pairs active at dispatch) and the updated dev cache."""
-    dev = upload_decode_arrays(dev, "horizon", sched, lookahead=True)
+    dev = upload_decode_arrays(dev, "horizon", sched, lookahead=True,
+                               placement=programs.operand_placement)
     active = [(i, sched.slots[i].request.request_id)
               for i in sched.active_indices()]
     with span("serve.dispatch", program=f"serve_horizon_k{k}"):
@@ -988,6 +1040,11 @@ class ModelPrograms:
             # program once, breaking the cache-flat-across-publishes pin
             params = jax.device_put(params, jax.devices()[0])
         self.params = params
+        # where a program's small operands go (the decode arrays, the
+        # adapter stacks): the params' own COMMITTED placement, replicated
+        # over a plan's mesh
+        self.operand_placement = (plan.replicated() if plan is not None
+                                  else jax.devices()[0])
 
         # ---- pooled multi-LoRA adapters (serve/adapters.py) ----
         # the stacked A/B buffers are program ARGUMENTS (fixed avals, like
@@ -1009,11 +1066,8 @@ class ModelPrograms:
             stacks = init_adapter_stacks(
                 self.config, max_adapters=max_adapters, rank=adapter_rank,
                 targets=adapter_targets, bundle=bundle)
-            if plan is not None:
-                stacks = jax.device_put(stacks, plan.replicated())
-            else:
-                stacks = jax.device_put(stacks, jax.devices()[0])
-            self.adapter_stacks = stacks
+            self.adapter_stacks = jax.device_put(stacks,
+                                                 self.operand_placement)
             # ONE compiled insert for every slot: the slot index is a
             # TRACED scalar, so publishing into slot 3 and slot 7 hit the
             # same executable (jit-cache-flat across inserts)
@@ -1036,6 +1090,13 @@ class ModelPrograms:
             lambda logit, seed, pos, t, tk, tp: _sample_tokens(
                 logit[None], seed[None], pos[None], t[None], tk[None],
                 tp[None])[0], "serve_sample_one"))
+        # a first token into its lane of the decode's ``tokens`` where both
+        # lie on the device (``run_decode_iteration``). The slot is an
+        # OPERAND: one executable for every slot, compiled by the first
+        # chunk step an engine runs
+        self._seat_fn = jax.jit(named(
+            lambda tokens, slot, token: tokens.at[slot].set(token),
+            "serve_seat_token"))
         # weight-publish bookkeeping (post-training: post/loop.py). A
         # publish swaps refreshed buffers into self.params WITHOUT touching
         # the jit caches above — the programs take params as an argument,
@@ -1325,6 +1386,7 @@ class ModelPrograms:
             "decode": self._decode_fn._cache_size(),
             "copy": self._copy_fn._cache_size(),
             "sample_one": self._sample_one._cache_size(),
+            "seat_token": self._seat_fn._cache_size(),
         }
         if self._insert_fn is not None:
             sizes["adapter_insert"] = self._insert_fn._cache_size()
@@ -1585,18 +1647,23 @@ class ModelPrograms:
                 **({"out_shardings": kv_out} if kv_out else {}))
         return self._verify_fns[key]
 
-    def sample_one(self, logit, request: Request, position: int) -> int:
+    def launch_sample(self, logit, request: Request, position: int):
         """Batch-1 sample off prefill logits (the request's first token),
-        read back to the host: the span holds the wait for the prefill
-        that made the logits."""
+        enqueued behind the prefill that makes the logits and LEFT ON THE
+        DEVICE: a scalar the host has not read."""
+        return self._sample_one(
+            logit.astype(jnp.float32),
+            jnp.asarray(request.seed, jnp.int32),
+            jnp.asarray(position, jnp.int32),
+            jnp.asarray(request.temperature, jnp.float32),
+            jnp.asarray(request.top_k, jnp.int32),
+            jnp.asarray(request.top_p, jnp.float32))
+
+    def sample_one(self, logit, request: Request, position: int) -> int:
+        """:meth:`launch_sample` read back to the host at once: the span
+        holds the wait for the prefill that made the logits."""
         with span("serve.sample", request_id=request.request_id):
-            return int(self._sample_one(
-                logit.astype(jnp.float32),
-                jnp.asarray(request.seed, jnp.int32),
-                jnp.asarray(position, jnp.int32),
-                jnp.asarray(request.temperature, jnp.float32),
-                jnp.asarray(request.top_k, jnp.int32),
-                jnp.asarray(request.top_p, jnp.float32)))
+            return int(self.launch_sample(logit, request, position))
 
     def check_prompt(self, request: Request) -> None:
         """Range-check prompt ids (the scheduler is model-agnostic): under
@@ -1792,6 +1859,15 @@ class ServeEngine(DecodeArrays):
         # scheduler; DecodeArrays has the states)
         self._pending: dict[int, Admission] = {}
         self._dev = no_dev("first")
+        # this step's completed prefills: how many, and the (admission,
+        # token) of those whose first token is sampled and still on the
+        # device (_on_prefill_complete); both empty between steps
+        self._step_prefills = 0
+        self._first: list[tuple] = []
+        # steps in which a prefill completed, and those of them whose decode
+        # was dispatched before the first token was read (stats())
+        self.chunk_steps = 0
+        self.chunk_steps_overlapped = 0
         install_gc_span()
         # the dispatched-but-unprocessed horizon block (decode_horizon >
         # 1): the double buffer's slot — the device computes horizon h
@@ -2003,22 +2079,45 @@ class ServeEngine(DecodeArrays):
         shard_kv each chip holds 1/tp of this."""
         return pool_nbytes(self.pages)
 
-    def _sample_first(self, adm: Admission, logit) -> Optional[RequestResult]:
-        """First token off the prefill logits (skipped for preempted
-        sequences — their next token was generated before preemption)."""
-        t0 = self.programs.sample_one(logit, adm.request, len(adm.tokens))
-        return self.scheduler.record_token(adm.slot_idx, t0,
-                                           from_decode=False)
-
     def _on_prefill_complete(self, adm: Admission,
                              logit) -> Optional[RequestResult]:
         """The slot's pages are fully committed: it joins the decode
         batch (device arrays rebuild) with its first token sampled —
-        unless it is a resumed sequence, whose tokens already exist."""
+        unless it is a resumed sequence, whose tokens already exist (the
+        host has them: nothing to sample, nothing to read).
+
+        On the plain path the first token is sampled and LEFT on the
+        device: the step goes on to build and dispatch the decode while the
+        chunk program runs, and reads the token after that
+        (``run_decode_iteration``'s ``first``). The older order, the token
+        read here, before anything else of the step, is kept where the
+        step cannot know that the plain single-token program comes next, or
+        gains nothing by it:
+
+        - a drafter is configured: whether the verify program runs is
+          decided by what the drafter proposes, from the host's tokens;
+        - ``decode_horizon > 1``: the horizon's lanes (``budgets``, the
+          live mask) are built from the host's record of each slot;
+        - ``max_new_tokens == 1``: the first token ends the request, known
+          beforehand, and the slot never decodes.
+
+        And a token left here is read before growth after all where the
+        step's growth will preempt (``_iterate``): a victim goes back to the
+        queue, or to the host tier, with every token it has."""
         self.drop_dev("prefilled")
+        self._step_prefills += 1
         if adm.resumed:
             return None
-        return self._sample_first(adm, logit)
+        req = adm.request
+        if (self.drafter is not None or self.decode_horizon > 1
+                or req.max_new_tokens <= 1):
+            t0 = self.programs.sample_one(logit, req, len(adm.tokens))
+            return self.scheduler.record_token(adm.slot_idx, t0,
+                                               from_decode=False)
+        with span("serve.sample", request_id=req.request_id):
+            self._first.append((adm, self.programs.launch_sample(
+                logit, req, len(adm.tokens))))
+        return None
 
     def _horizon_ready(self) -> bool:
         """Whether the active batch may run a fused K-step horizon: the
@@ -2050,12 +2149,22 @@ class ServeEngine(DecodeArrays):
 
     def step(self) -> list[RequestResult]:
         """One scheduler iteration: expire deadlines (clean eviction at
-        the boundary), grow running decodes (preempting the cheapest on
-        true exhaustion), admit whatever now fits (sharing cached
-        prefixes), advance prefill work (one chunk-budget's worth), then
+        the boundary), admit whatever now fits (sharing cached prefixes),
+        advance prefill work (one chunk-budget's worth), grow the decoding
+        slots (preempting the cheapest on true exhaustion), then
         ONE batched decode over the decoding
         slots — a single step at decode_horizon=1, a fused K-step
         horizon program otherwise. Returns finished requests.
+
+        A step that COMPLETES a prefill on the plain path dispatches its
+        two programs back to back and reads the host once: the chunk
+        program is enqueued; while it runs the first token is sampled and
+        seated on the device, the slots grow, the decode arrays are built
+        and go up and the decode program is enqueued behind the chunk; only
+        then does the host read, the first token and then the decode's
+        tokens (``_on_prefill_complete`` has the paths that keep the older
+        order, the first token read before anything else; ``stats()``
+        counts both kinds, ``chunk_steps`` / ``chunk_steps_overlapped``).
 
         With a horizon the dispatch is DOUBLE-BUFFERED: in the steady
         state (nothing queued, no prefill, no deadline due) this method
@@ -2082,8 +2191,11 @@ class ServeEngine(DecodeArrays):
         # wait, book) are emitted where that work happens
         with span("serve.step", seq=self.stats_seq) as sp:
             cpu0 = time.thread_time()
+            overlapped = self.chunk_steps_overlapped
             finished = self._iterate()
-            sp.set_metadata(cpu_ms=1e3 * (time.thread_time() - cpu0))
+            sp.set_metadata(
+                cpu_ms=1e3 * (time.thread_time() - cpu0),
+                overlapped=self.chunk_steps_overlapped - overlapped)
             return finished
 
     def _iterate(self) -> list[RequestResult]:
@@ -2150,21 +2262,34 @@ class ServeEngine(DecodeArrays):
             if adm.fork is not None:
                 run_fork(self.programs, self.pages, adm)
             self._pending[adm.slot_idx] = adm
+        self._step_prefills = 0
         if self._pending:
             finished.extend(advance_prefill_chunks(
                 self.programs, self.pages, sched, self._pending,
                 self.prefill_chunk, self._on_prefill_complete))
+        self.chunk_steps += bool(self._step_prefills)
 
         # growth runs LAST before the decode so every slot in the batch —
         # including one admitted or chunk-completed this very iteration
         # whose prefill ended exactly on a page boundary — owns the page
-        # its next write lands in
+        # its next write lands in. That holds in a chunk step's order too:
+        # the chunk program may still be running, but what comes after
+        # growth is the build of the decode arrays and the decode's
+        # dispatch, and nothing of the scheduler's in between
+        if self._first and not sched.growth_fits():
+            # growth will preempt, and a victim takes with it what the host
+            # has of it (its tokens to the queue, its pages to a host tier):
+            # the first tokens are read now, the older order
+            finished.extend(book_first_tokens(sched, self._first)[0])
+            self._first = []
         grown, preempted = sched.grow_for_decode()
         if preempted:           # a slot left the batch
             self.drop_dev("preempted")
             drop_stale_pending(sched, self._pending)
         elif grown:             # the same slots, longer block tables
             self.stale_tables("grown")
+        first, self._first = self._first, []
+        self.chunk_steps_overlapped += bool(first)
 
         if sched.active_indices():
             if self._horizon_ready():
@@ -2184,7 +2309,7 @@ class ServeEngine(DecodeArrays):
             else:
                 fin, emitted, self._dev = run_decode_iteration(
                     self.programs, self.pages, sched, self.drafter,
-                    self.spec, self._dev)
+                    self.spec, self._dev, first)
                 self._note_dispatch(1)
                 self.decode_tokens += emitted
                 finished.extend(fin)
@@ -2261,6 +2386,8 @@ class ServeEngine(DecodeArrays):
             "active_slots": len(sched.active_indices()),
             "prefilling_slots": len(sched.prefilling_indices()),
             "prefill_calls": self.programs.prefill_calls,
+            "chunk_steps": self.chunk_steps,
+            "chunk_steps_overlapped": self.chunk_steps_overlapped,
             "live_pages_by_class": sched.live_pages_by_class(),
             "state_blocks_live": sched.live_state_blocks(),
             **({"routing": dict(self.programs.routing)}

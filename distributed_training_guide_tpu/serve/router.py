@@ -909,7 +909,10 @@ class Router:
         # fused-horizon raw counters: RAW SUMS cross replica boundaries
         # (the per-replica ratios do not), so the fleet-level
         # tokens_per_dispatch/horizon_effective re-derive from these
-        "host_dispatches", "horizon_ksum")
+        "host_dispatches", "horizon_ksum",
+        # steps that completed a prefill / that ran their two programs
+        # back to back (ServeEngine.step)
+        "chunk_steps", "chunk_steps_overlapped")
 
     def stats(self) -> dict:
         """Fleet aggregate + per-replica health, all host-side (each

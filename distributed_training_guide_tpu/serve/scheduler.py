@@ -1065,6 +1065,16 @@ class Scheduler:
                                        slot.first_token_at), front=True)
         self.stats["preempted"] += 1
 
+    def growth_fits(self) -> bool:
+        """Whether the next ``grow_for_decode`` finds every page it needs
+        without preempting. Idle prefix-cache pages are evicted for it here,
+        the same ones, least recently used first, that growth would evict
+        page by page."""
+        page = self.pool.page_size
+        return self._ensure_free(sum(
+            max(0, s.cache_len // page + 1 - len(s.pages))
+            for s in self.slots if s is not None and not s.prefilling))
+
     def grow_for_decode(self) -> tuple[int, int]:
         """Before a decode step: every decoding slot must own the page its
         next write lands in. Oldest slots grow first; on exhaustion the
@@ -1447,8 +1457,12 @@ class Scheduler:
                 continue
             req = slot.request
             # normally the newest sample; during replay, the next recorded
-            # token whose k/v needs rewriting
-            out["tokens"][i] = slot.generated[slot.replay_pos]
+            # token whose k/v needs rewriting; 0, a placeholder, for a slot
+            # whose first token is sampled and still on the device (the
+            # engine writes it into the lane there, serve/engine.py
+            # run_decode_iteration)
+            if slot.generated:
+                out["tokens"][i] = slot.generated[slot.replay_pos]
             out["lengths"][i] = slot.cache_len
             out["seeds"][i] = req.seed
             out["temps"][i] = req.temperature

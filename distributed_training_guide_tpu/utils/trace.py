@@ -20,8 +20,13 @@ carries ``arrays`` and ``bytes`` (what went up: the whole set, or the block
 tables alone), ``serve.step`` carries ``cpu_ms`` (the thread's own CPU time
 over the iteration: a long step with little of it was waiting or
 descheduled; where that clock ticks every 10 ms, as on the v5e hosts, only a
-long step says anything), and ``gc`` carries ``generation`` and ``collected``
-(:func:`install_gc_span`: one span over each garbage collection).
+long step says anything) and ``overlapped`` (1 where the step completed a
+prefill and dispatched its decode BEFORE it read the first token, so the
+chunk program and the decode program ran back to back: such a step has a
+``serve.sample`` in front of its ``serve.dispatch``, the sampler's launch,
+and one behind it, the token's read; 0 in every other step), and ``gc``
+carries ``generation`` and ``collected`` (:func:`install_gc_span`: one span
+over each garbage collection).
 
 On the device the names are HLO metadata and cost nothing at run time:
 ``jax.named_scope`` per model part (:data:`SCOPES`, :data:`SUBSCOPES`), a stable ``__name__`` on
@@ -117,7 +122,7 @@ KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",
 # its all-greedy twin serve_verify_t5_greedy)
 PROGRAMS = (
     "train_step", "serve_decode", "serve_horizon_k", "serve_chunk_t",
-    "serve_verify_t", "serve_copy", "serve_sample_one",
+    "serve_verify_t", "serve_copy", "serve_sample_one", "serve_seat_token",
     "serve_adapter_insert", "serve_snapshot", "serve_requant",
     "serve_draft_step", "serve_draft_catchup",
 )

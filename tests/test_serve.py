@@ -748,11 +748,15 @@ def _decode_side(eng):
 class _Checked:
     """A decode program that first holds its operands to the invariant:
     for every active slot each resident array is what a whole rebuild from
-    the scheduler would upload at this instant."""
+    the scheduler would upload at this instant. One lane the host does not
+    know at a chunk step's dispatch, the ``tokens`` of a slot whose first
+    token is sampled and still on the device: ``first`` keeps the device's
+    value by request, and ``_run_checked`` holds it to the token that was
+    booked."""
 
-    def __init__(self, fn, kind, holder, sched, seen):
+    def __init__(self, fn, kind, holder, sched, seen, first):
         self.fn, self.kind, self.holder = fn, kind, holder
-        self.sched, self.seen = sched, seen
+        self.sched, self.seen, self.first = sched, seen, first
 
     def __getattr__(self, name):            # _cache_size, lower, ...
         return getattr(self.fn, name)
@@ -771,6 +775,11 @@ class _Checked:
                 continue
             got = np.asarray(got)
             for i in active:
+                slot = sched.slots[i]
+                if key == "tokens" and not slot.generated:
+                    assert self.kind == "plain"
+                    self.first[slot.request.request_id] = int(got[i])
+                    continue
                 if key != "tables":
                     assert got[i] == want[key][i], (key, i, self.kind)
                     continue
@@ -791,8 +800,8 @@ def _run_checked(eng, reqs, monkeypatch):
     from distributed_training_guide_tpu.serve import engine as engine_mod
 
     holder, programs, sched = _decode_side(eng)
-    seen, builds = {}, []
-    check = lambda fn, kind: _Checked(fn, kind, holder, sched, seen)
+    seen, builds, first = {}, [], {}
+    check = lambda fn, kind: _Checked(fn, kind, holder, sched, seen, first)
     programs._decode_fn = check(programs._decode_fn, "plain")
     verify_for, horizon_for = programs.verify_for, programs.horizon_for
     programs.verify_for = lambda t, greedy=False: check(
@@ -810,6 +819,12 @@ def _run_checked(eng, reqs, monkeypatch):
         return out
     monkeypatch.setattr(engine_mod, "upload_decode_arrays", counted)
     res = generate_many(eng, reqs, max_iterations=3000)
+    # the lanes the host could not check at their dispatch: the device's
+    # value IS the first token that was then booked
+    for r in res:
+        if r.request_id in first:
+            assert first[r.request_id] == r.generated_ids[0], r.request_id
+    seen["first"] = len(first)
     return res, seen, builds
 
 
@@ -826,6 +841,10 @@ def test_resident_decode_arrays_are_what_a_whole_rebuild_would_upload(
     sched = _decode_side(eng)[2]
     assert sched.stats["preempted"] > 0, "the session never preempted"
     assert sched.stats["finished"] == 8
+    # first tokens left on the device at their dispatch: the monolith's
+    # plain path alone (the others keep the older order, by construction)
+    n_first = seen.pop("first")
+    assert (n_first > 0) == (path in ("monolith", "window")), (path, n_first)
     want = {"spec": {"spec", "plain"}, "horizon4": {"horizon", "plain"}}
     assert set(seen) == want.get(path, {"plain"}), seen
     # the mechanism engaged: growth sent the tables alone, every event that

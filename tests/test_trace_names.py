@@ -471,8 +471,8 @@ def test_serve_programs_have_stable_names_and_scopes(debug_model):
             "final_norm", "loss_head", "sample"}
     assert want <= scope_components(text), want - scope_components(text)
     # every jitted program of the two engines, by the name jit gave it
-    fns = [programs._decode_fn, programs._copy_fn,
-           programs._sample_one, *programs._chunk_fns.values(),
+    fns = [programs._decode_fn, programs._copy_fn, programs._sample_one,
+           plain.programs._seat_fn, *programs._chunk_fns.values(),
            *programs._verify_fns.values(),
            *plain.programs._chunk_fns.values(),
            *plain.programs._horizon_fns.values()]
@@ -480,8 +480,8 @@ def test_serve_programs_have_stable_names_and_scopes(debug_model):
     # the engine that names no chunk size prefills through the chunk program
     # at its own size: one slot's capacity here (64 < the ceiling of 512)
     assert {"serve_decode", "serve_copy", "serve_sample_one",
-            "serve_chunk_t4", "serve_chunk_t64", "serve_verify_t3_greedy",
-            "serve_horizon_k2"} <= got
+            "serve_seat_token", "serve_chunk_t4", "serve_chunk_t64",
+            "serve_verify_t3_greedy", "serve_horizon_k2"} <= got
     for name in got:
         assert name != "fn" and "lambda" not in name
         assert any(name == p or (name.startswith(p) and p[-1] in "kt")
