@@ -46,7 +46,8 @@ expert-parallel layer; :func:`experts_held`): the router keeps its width and
 the local ragged dispatch leaves out the pairs of absent experts. The held
 pairs sort to the front, so the dispatch WALKS A STATIC PREFIX of the sorted
 order and not all ``k T`` pairs: :func:`compact_rows` = twice the even share
-``k T count / num_experts``, to a whole ``grouped_matmul`` row tile. It
+``k T count / num_experts``, to a whole ``grouped_matmul`` row tile (its
+largest, 512 rows). It
 gathers that many rows, multiplies them and adds them into ``[T, D]`` at
 their tokens (a scatter-add of that many rows). A routing that holds more
 pairs than the prefix takes the full-width walk in that step
@@ -321,7 +322,9 @@ def experts_held(config) -> tuple[int, int]:
 
 
 # the compact dispatch's buffer: this many times the even share of the pairs,
-# rounded up to grouped_matmul's row tile (its ``block_rows``)
+# rounded up to the LARGEST row tile grouped_matmul takes (its ``block_rows``
+# cap; the tile itself follows rows-per-group, ``gmm_blocks``: a power of two
+# no larger, so every tile it may choose divides the buffer)
 _COMPACT_ROOM = 2
 _ROW_TILE = 512
 

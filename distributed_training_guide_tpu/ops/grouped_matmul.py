@@ -35,8 +35,17 @@ Three implementations behind one dispatch:
   tests.
 
 The Pallas kernels keep the whole K (contraction) dim resident per tile —
-fine for transformer hidden/FFN widths (K * block * 4 B must fit VMEM); a
+fine for transformer hidden/FFN widths (the blocks must fit VMEM); a
 K-tiled variant is a follow-up if a model outgrows that.
+
+The work list's geometry follows the call (:func:`gmm_blocks`, static per
+compiled shape): the row tile is the mean rows a group up to a power of two
+(64 rows, the least, at a decode step's 1-6 rows an expert; 512 at a train
+step's thousand), the column tile is what the kernel's own blocks leave room
+for at its operands' own widths (512 columns of a bf16 ``[4096, N]`` matrix,
+a 4 MB DMA), and a padded work item skips its product. So a memory-bound
+call's time is the touched groups' matrices streamed once. The backward
+prices its two fp32 kernels for itself (:func:`_bwd_blocks`).
 """
 from __future__ import annotations
 
@@ -124,6 +133,12 @@ def _tgmm_einsum(lhs, dy, group_sizes, g, out_dtype):
 # Pallas kernel (MegaBlocks-style work list over the sorted token axis)
 # ---------------------------------------------------------------------------
 
+def work_items(m: int, g: int, bm: int) -> int:
+    """The work list's static length ``nw``: every row tile, and a boundary
+    item a group (see :func:`_work_list`)."""
+    return pl.cdiv(m, bm) + g
+
+
 def _work_list(group_sizes, m, bm, nw):
     """Static-size (group, row-tile) work list + metadata scalars.
 
@@ -131,7 +146,8 @@ def _work_list(group_sizes, m, bm, nw):
     (group, tile) intersections is at most m_tiles + G (each group spans
     ceil(size/bm) tiles plus at most one boundary tile) — ``nw`` is that
     bound. Padding entries repeat the last real pair (so they trigger no
-    accumulator init/flush edges) and are masked off via ``n_valid``.
+    DMA and no accumulator init/flush edges) and the kernels skip their
+    product (``w >= n_valid``).
     Enumeration is group-major; because groups tile a contiguous axis, the
     emitted row-tile sequence is non-decreasing, which is what lets the
     kernels treat "previous work item had a different tile/group" as the
@@ -171,13 +187,16 @@ def _gmm_kernel(offs_ref, wg_ref, wm_ref, nvalid_ref, *refs, bm, nw):
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # a [bm, 1] column from the start: Mosaic has no 1-D -> 2-D shape cast
-    rows = mt * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-    member = ((rows >= offs_ref[g]) & (rows < offs_ref[g + 1])
-              & (w < nvalid_ref[0]))
-    x = jnp.where(member, lhs_ref[...], 0)
-    acc_ref[...] += jnp.dot(x, rhs_ref[0],
-                            preferred_element_type=jnp.float32)
+    # a padded item repeats the last real pair: no block of it is fetched,
+    # and it multiplies nothing
+    @pl.when(w < nvalid_ref[0])
+    def _():
+        # a [bm, 1] column from the start: Mosaic has no 1-D -> 2-D shape cast
+        rows = mt * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+        member = (rows >= offs_ref[g]) & (rows < offs_ref[g + 1])
+        x = jnp.where(member, lhs_ref[...], 0)
+        acc_ref[...] += jnp.dot(x, rhs_ref[0],
+                                preferred_element_type=jnp.float32)
 
     is_last = jnp.logical_or(w == nw - 1,
                              wm_ref[jnp.minimum(w + 1, nw - 1)] != mt)
@@ -198,14 +217,15 @@ def _tgmm_kernel(offs_ref, wg_ref, wm_ref, nvalid_ref, lhs_ref, dy_ref,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # a [bm, 1] column from the start: Mosaic has no 1-D -> 2-D shape cast
-    rows = mt * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-    member = ((rows >= offs_ref[g]) & (rows < offs_ref[g + 1])
-              & (w < nvalid_ref[0]))
-    x = jnp.where(member, lhs_ref[...], 0)
-    acc_ref[...] += jax.lax.dot_general(
-        x, dy_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    @pl.when(w < nvalid_ref[0])      # as in _gmm_kernel: the same list
+    def _():
+        # a [bm, 1] column from the start: Mosaic has no 1-D -> 2-D shape cast
+        rows = mt * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+        member = (rows >= offs_ref[g]) & (rows < offs_ref[g + 1])
+        x = jnp.where(member, lhs_ref[...], 0)
+        acc_ref[...] += jax.lax.dot_general(
+            x, dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     is_last = jnp.logical_or(w == nw - 1,
                              wg_ref[jnp.minimum(w + 1, nw - 1)] != g)
@@ -223,9 +243,8 @@ def _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn, interpret,
     stay group-local."""
     m, k = lhs.shape
     g, n = group_sizes.shape[0], rhs.shape[2]
-    m_tiles = pl.cdiv(m, bm)
     n_tiles = pl.cdiv(n, bn)
-    nw = m_tiles + g
+    nw = work_items(m, g, bm)
     scalars = _work_list(group_sizes, m, bm, nw)   # offs, wg, wm, n_valid
     if group_offset is None:
         def rhs_block(ni, w, offs, wg, wm, nv):
@@ -267,9 +286,8 @@ def _pallas_tgmm_raw(lhs, dy, group_sizes, g, out_dtype, bm, bn, interpret):
     """d_rhs [G, K, N] = per-group lhs_g^T @ dy_g (the 'tgmm')."""
     m, k = lhs.shape
     n = dy.shape[1]
-    m_tiles = pl.cdiv(m, bm)
     n_tiles = pl.cdiv(n, bn)
-    nw = m_tiles + g
+    nw = work_items(m, g, bm)
     offs, wg, wm, n_valid = _work_list(group_sizes, m, bm, nw)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -294,21 +312,101 @@ def _pallas_tgmm_raw(lhs, dy, group_sizes, g, out_dtype, bm, bn, interpret):
     return jnp.where((group_sizes > 0)[:, None, None], out, 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _pallas_gmm(lhs, rhs, group_sizes, group_offset, out_dtype, bm, bn,
-                interpret):
+# Bytes of VMEM one kernel instance may plan for: the chip's compiler gives
+# a kernel 16 MiB of scoped VMEM by default (v5e), and refuses the program
+# when the double-buffered blocks plus the accumulator exceed it.
+_VMEM_BUDGET = 12 * 2**20
+
+# The smallest row tile the rule asks for. The MXU holds a 128 x 128 tile of
+# the matrix and streams the rows past it, so any row tile up to 128 costs
+# the same cycles: a narrower one saves nothing and only lays more groups
+# across two tiles (each of which multiplies).
+_MIN_ROWS = 64
+
+
+def _gmm_bytes(bm, bn, k, lhs_b, rhs_b, out_b):
+    """``gmm``'s blocks, double-buffered, and its fp32 accumulator."""
+    blocks = bm * k * lhs_b + k * bn * rhs_b + bm * bn * out_b
+    return 2 * blocks + bm * bn * 4
+
+
+def _tgmm_bytes(bm, bn, k, lhs_b, dy_b, out_b):
+    blocks = bm * k * lhs_b + bm * bn * dy_b + k * bn * out_b
+    return 2 * blocks + k * bn * 4
+
+
+def _halve_to_fit(bm, bn, k, least_rows, footprint):
+    """Halve the larger of (bn, bm) until ``footprint(bm, bn)`` fits
+    ``_VMEM_BUDGET``. The K dim stays resident, so a K too wide for the
+    smallest blocks is an error."""
+    while footprint(bm, bn) > _VMEM_BUDGET:
+        if bn > 128 and bn >= bm:
+            bn //= 2
+        elif bm > least_rows:
+            bm //= 2
+        else:
+            raise ValueError(
+                f"grouped_matmul impl='pallas' keeps the contraction dim "
+                f"resident in VMEM; K={k} does not fit {_VMEM_BUDGET} bytes "
+                f"even at {bm}x{bn} blocks — use impl='ragged'")
+    return bm, bn
+
+
+def gmm_blocks(m: int, g: int, k: int, n: int, lhs_dtype, rhs_dtype, *,
+               block_rows: int = 512,
+               block_cols: int = 512) -> tuple[int, int]:
+    """``(bm, bn)`` of one ``grouped_matmul(lhs [m, k], rhs [g, k, n])`` call
+    (all the serve path runs): static, a function of the call's own shapes
+    and dtypes; ``block_rows`` / ``block_cols`` cap it.
+
+    The ROW tile follows rows-per-group: the mean ``m / g`` up to a power of
+    two, no smaller than ``_MIN_ROWS`` (nor larger than ``m``). A decode
+    step's expert holds 1-6 rows; row tiles past ``sum(group_sizes)`` are
+    never visited, so a tile smaller than the buffer only adds padded items,
+    which the kernel skips. The COLUMN tile is what ``gmm``'s own blocks
+    leave room for at the operands' own widths (the output priced at the
+    accumulator's 4 B): at bf16 and 64 rows, ``[4096, 512]`` twice is 8 MiB,
+    one DMA 4 MB.
+    """
+    lhs_b, rhs_b = jnp.dtype(lhs_dtype).itemsize, jnp.dtype(rhs_dtype).itemsize
+    mean_rows = -(-m // g)
+    bm = min(max(_MIN_ROWS, 1 << (mean_rows - 1).bit_length()), block_rows, m)
+    return _halve_to_fit(
+        bm, min(block_cols, n), k, min(16, bm),
+        lambda bm, bn: _gmm_bytes(bm, bn, k, lhs_b, rhs_b, 4))
+
+
+def _bwd_blocks(m: int, k: int, n: int, *, block_rows: int = 512,
+                block_cols: int = 512) -> tuple[int, int]:
+    """``(bm, bn)`` of the backward of a ``[m, k] x [g, k, n]`` call: its
+    ``gmm`` against the transposed matrices and its ``tgmm`` run in fp32 on
+    ONE pair of blocks, so both footprints are priced at 4 B with the wider
+    of (k, n) resident, from the caps down."""
+    wide = max(k, n)
+    return _halve_to_fit(
+        min(block_rows, m), min(block_cols, n), wide, 128,
+        lambda bm, bn: max(_gmm_bytes(bm, bn, wide, 4, 4, 4),
+                           _tgmm_bytes(bm, bn, wide, 4, 4, 4)))
+
+
+def _gmm_forward(lhs, rhs, group_sizes, group_offset, out_dtype, block_rows,
+                 block_cols, interpret):
+    (m, k), g, n = lhs.shape, group_sizes.shape[0], rhs.shape[2]
+    bm, bn = gmm_blocks(m, g, k, n, lhs.dtype, rhs.dtype,
+                        block_rows=block_rows, block_cols=block_cols)
     return _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn,
                            interpret, group_offset)
 
 
-def _pallas_gmm_fwd(lhs, rhs, group_sizes, group_offset, out_dtype, bm, bn,
-                    interpret):
-    out = _pallas_gmm_raw(lhs, rhs, group_sizes, out_dtype, bm, bn,
-                          interpret, group_offset)
-    return out, (lhs, rhs, group_sizes, group_offset)
+_pallas_gmm = jax.custom_vjp(_gmm_forward, nondiff_argnums=(4, 5, 6, 7))
 
 
-def _pallas_gmm_bwd(out_dtype, bm, bn, interpret, res, dy):
+def _pallas_gmm_fwd(lhs, rhs, group_sizes, group_offset, *static):
+    return (_gmm_forward(lhs, rhs, group_sizes, group_offset, *static),
+            (lhs, rhs, group_sizes, group_offset))
+
+
+def _pallas_gmm_bwd(out_dtype, block_rows, block_cols, interpret, res, dy):
     lhs, stack, group_sizes, group_offset = res
     g = group_sizes.shape[0]
     # with an offset the backward slices its G matrices out and writes
@@ -317,6 +415,10 @@ def _pallas_gmm_bwd(out_dtype, bm, bn, interpret, res, dy):
     # per-layer leaves, whose gradient is per layer)
     rhs = (stack if group_offset is None
            else jax.lax.dynamic_slice_in_dim(stack, group_offset, g))
+    # both kernels in fp32, on blocks the backward prices for itself
+    (m, k), n = lhs.shape, rhs.shape[2]
+    bm, bn = _bwd_blocks(m, k, n, block_rows=block_rows,
+                         block_cols=block_cols)
     dy = dy.astype(jnp.float32)
     # d_lhs: the same grouped matmul against rhs^T — rows outside every
     # group get zero gradient (matching their zero primal output)
@@ -331,36 +433,6 @@ def _pallas_gmm_bwd(out_dtype, bm, bn, interpret, res, dy):
 
 
 _pallas_gmm.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
-
-
-# Bytes of VMEM one kernel instance may plan for: the chip's compiler gives
-# a kernel 16 MiB of scoped VMEM by default (v5e), and refuses the program
-# when the double-buffered blocks plus the accumulator exceed it.
-_VMEM_BUDGET = 12 * 2**20
-
-
-def _fit_blocks(bm: int, bn: int, k: int) -> tuple[int, int]:
-    """Halve the larger of (bn, bm) until the larger of the gmm and tgmm footprints
-    fits ``_VMEM_BUDGET``. Sized at 4 B/element whatever the input dtype:
-    the backward runs both kernels in fp32 with these same blocks. The K
-    dim stays resident, so a K too wide for 128x128 blocks is an error."""
-
-    def footprint(bm, bn):
-        gmm = 2 * (bm * k + k * bn + bm * bn) * 4 + bm * bn * 4
-        tgmm = 2 * (bm * k + bm * bn + k * bn) * 4 + k * bn * 4
-        return max(gmm, tgmm)
-
-    while footprint(bm, bn) > _VMEM_BUDGET:
-        if max(bm, bn) <= 128:
-            raise ValueError(
-                f"grouped_matmul impl='pallas' keeps the contraction dim "
-                f"resident in VMEM; K={k} does not fit {_VMEM_BUDGET} bytes "
-                f"even at 128x128 blocks — use impl='ragged'")
-        if bn >= bm:
-            bn //= 2
-        else:
-            bm //= 2
-    return bm, bn
 
 
 # ---------------------------------------------------------------------------
@@ -435,8 +507,6 @@ def grouped_matmul(
     if impl == "einsum":
         return _gmm_einsum(lhs, rhs, group_sizes, out_dtype)
     interpret = resolve_interpret(interpret)
-    bm, bn = _fit_blocks(min(block_rows, lhs.shape[0]),
-                         min(block_cols, rhs.shape[2]),
-                         max(lhs.shape[1], rhs.shape[2]))
     return _pallas_gmm(lhs, rhs, group_sizes, group_offset,
-                       jnp.dtype(out_dtype), bm, bn, interpret)
+                       jnp.dtype(out_dtype), block_rows, block_cols,
+                       interpret)

@@ -47,7 +47,10 @@ by hand. ``--mla`` runs the latent-attention cell's kernels alone
 one call) against the gathered rows, with three sabotaged kernels the bound
 must refuse, and ``gmm`` at hidden 4096 x expert width 2048 and back with 32
 groups of 0-4 rows in a 128-row buffer (the decode step's held pairs), the
-way back once more in place, at layer 5 of the stacked leaf.
+way back once more in place, at layer 5 of the stacked leaf; then the three
+other MoE serve cells' decode shapes (``GMM_CELL_SHAPES``: chat64, MiMo,
+Solar), into an expert plainly and out of it in place. Every ``gmm decode``
+line carries the ``(bm, bn, nw)`` the call chose (``gmm_blocks``).
 
 ``--lfm2`` runs the hybrid cell's attend alone
 (``lfm2-24b-a2b-l9.serve.chat64``): 64-wide heads through ``paged_attend``
@@ -422,36 +425,51 @@ def latent_cases() -> int:
     return refused
 
 
-def gmm_decode_case(k: int, n: int, layer=None) -> None:
-    """The decode step's expert products: 32 held experts, 0-4 pairs each,
-    in the 128-row buffer of 32 tokens x top-4 (rows past the held pairs
-    come back zero). With ``layer`` the kernel reads them where the serve
-    path's layer scan leaves them: in the whole ``[6 * 32, K, N]`` leaf, from
-    ``group_offset = layer * 32`` on (the other layers hold ones, fifty times
-    the weights' size, so a kernel that read another layer is far outside
-    the bound), against the einsum on that layer's matrices ALONE."""
+def gmm_decode_case(k: int, n: int, layer=None, rows: int = 128,
+                    groups: int = 32, most: int = 4) -> None:
+    """The decode step's expert products: ``groups`` held experts, 0 to
+    ``most`` pairs each, in the ``rows``-row buffer (Mistral's 32 experts
+    and the 128 rows of 32 tokens x top-4 unless said; rows past the held
+    pairs come back zero), at the ``(bm, bn, nw)`` the call chose, which the
+    case prints. With ``layer`` the kernel reads them where the serve
+    path's layer scan leaves them: in the whole ``[6 * groups, K, N]`` leaf,
+    from ``group_offset = layer * groups`` on (the other layers hold ones,
+    fifty times the weights' size, so a kernel that read another layer is far
+    outside the bound), against the einsum on that layer's matrices ALONE."""
     gm = importlib.import_module(
         "distributed_training_guide_tpu.ops.grouped_matmul")
-    rows, groups, layers = 128, 32, 6
-    sizes = jnp.asarray(RNG.integers(0, 5, groups), jnp.int32)
+    layers = 6
+    assert groups * most <= rows
+    sizes = jnp.asarray(RNG.integers(0, most + 1, groups), jnp.int32)
     lhs = normal((rows, k), jnp.bfloat16)
     rhs = normal((groups, k, n), jnp.bfloat16) * jnp.asarray(0.02, jnp.bfloat16)
+    bm, bn = gm.gmm_blocks(rows, groups, k, n, lhs.dtype, rhs.dtype)
+    said = dict(hidden=k, expert_width=n, experts=groups, rows=rows,
+                held_pairs=int(sizes.sum()),
+                bm_bn_nw=[bm, bn, gm.work_items(rows, groups, bm)])
     out_e = jax.jit(lambda a, b: gm.grouped_matmul(
         a, b, sizes, impl="einsum"))(lhs, rhs)
     if layer is None:
         out_p = jax.jit(lambda a, b: gm.grouped_matmul(
             a, b, sizes, impl="pallas"))(lhs, rhs)
-        case("gmm decode", {"out": (out_p, out_e)}, hidden=k, expert_width=n,
-             experts=groups, rows=rows, held_pairs=int(sizes.sum()))
+        case("gmm decode", {"out": (out_p, out_e)}, **said)
         return
     stack = jax.jit(lambda b: jax.lax.dynamic_update_slice_in_dim(
         jnp.ones((layers * groups, k, n), b.dtype), b, layer * groups, 0))(rhs)
     out_p = jax.jit(lambda a, b, at: gm.grouped_matmul(
         a, b, sizes, group_offset=at, impl="pallas"))(
             lhs, stack, jnp.int32(layer * groups))
-    case("gmm decode in place", {"out": (out_p, out_e)}, hidden=k,
-         expert_width=n, experts=groups, rows=rows, layer=layer,
-         leaf=list(stack.shape), held_pairs=int(sizes.sum()))
+    case("gmm decode in place", {"out": (out_p, out_e)}, layer=layer,
+         leaf=list(stack.shape), **said)
+
+
+# the three other serve cells' decode steps, into an expert and out of it:
+# chat64 (64 of 64 experts, 0-4 rows each), MiMo (16 held, 512 rows walked),
+# Solar (40 held; 512 columns do not divide its 1,280); ``groups * most``
+# is at most ``rows``, whatever the draw
+GMM_CELL_SHAPES = [dict(k=2048, n=1536, rows=256, groups=64, most=4),
+                   dict(k=4096, n=2048, rows=512, groups=16, most=24),
+                   dict(k=4096, n=1280, rows=512, groups=40, most=12)]
 
 
 def mla_cases() -> int:
@@ -459,6 +477,10 @@ def mla_cases() -> int:
     gmm_decode_case(4096, 2048)
     gmm_decode_case(2048, 4096)
     gmm_decode_case(2048, 4096, layer=5)
+    for shape in GMM_CELL_SHAPES:
+        gmm_decode_case(**shape)
+        gmm_decode_case(**{**shape, "k": shape["n"], "n": shape["k"]},
+                        layer=2)
     return refused
 
 

@@ -565,30 +565,55 @@ def test_latent_attend_compiles_at_the_cells_shape(chip_compile):
     assert f"bf16[6,{c['pages']},{c['page']},1,{c['latent']}]" in text
 
 
-@pytest.mark.parametrize("k,n", [(4096, 2048), (2048, 4096)])
-def test_grouped_matmul_compiles_at_the_decode_steps_shape(chip_compile, k, n):
-    """32 held experts, the 128-row buffer of 32 tokens x top-4."""
+# the four serve cells' decode steps as ``gmm`` sees them: (rows M, held
+# experts G, layers of the stacked leaf, K, N), both ways through an expert
+GMM_DECODE_SHAPES = {
+    "mistral-in": (128, 32, 6, 4096, 2048),
+    "mistral-out": (128, 32, 6, 2048, 4096),
+    "chat64-in": (256, 64, 8, 2048, 1536),
+    "chat64-out": (256, 64, 8, 1536, 2048),
+    "mimo-in": (512, 16, 6, 4096, 2048),
+    "mimo-out": (512, 16, 6, 2048, 4096),
+    "solar-in": (512, 40, 4, 4096, 1280),    # bn 512 does not divide N
+    "solar-out": (512, 40, 4, 1280, 4096),
+}
+GMM_DECODE = pytest.mark.parametrize("shape", sorted(GMM_DECODE_SHAPES))
+
+
+@GMM_DECODE
+def test_grouped_matmul_compiles_at_the_decode_steps_shape(chip_compile,
+                                                           shape):
+    """The held experts over the decode step's row buffer (Mistral: 32
+    experts, the 128 rows of 32 tokens x top-4), at the blocks the call
+    chooses for itself (a 64-row tile, not the buffer's rows,
+    and 512 columns of the matrix a DMA; ``tests/test_grouped_matmul.py`` has
+    the table): the described v5e holds their VMEM."""
+    m, g, _, k, n = GMM_DECODE_SHAPES[shape]
+    bm, bn = gmm_mod.gmm_blocks(m, g, k, n, jnp.bfloat16, jnp.bfloat16)
+    assert bm < m and bn == 512
     text = chip_compile(
         lambda lhs, rhs, sizes: gmm_mod.grouped_matmul(
             lhs, rhs, sizes, impl="pallas", interpret=False),
-        ((128, k), jnp.bfloat16), ((32, k, n), jnp.bfloat16),
-        ((32,), jnp.int32))
+        ((m, k), jnp.bfloat16), ((g, k, n), jnp.bfloat16),
+        ((g,), jnp.int32))
     assert any(named(c, "gmm") for c in kernel_calls(text))
 
 
-@pytest.mark.parametrize("k,n", [(4096, 2048), (2048, 4096)])
-def test_grouped_matmul_reads_a_layer_of_the_stacked_leaf(chip_compile, k, n):
-    """The same call on the whole ``[6 * 32, K, N]`` leaf with a traced group
-    offset (``layer * 32``): the kernel takes the parameter as it lies, and
+@GMM_DECODE
+def test_grouped_matmul_reads_a_layer_of_the_stacked_leaf(chip_compile,
+                                                          shape):
+    """The same call on the whole ``[L * G, K, N]`` leaf with a traced group
+    offset (``layer * G``): the kernel takes the parameter as it lies, and
     nothing of the leaf's size or of one layer's share is sliced or copied."""
+    m, g, layers, k, n = GMM_DECODE_SHAPES[shape]
     text = chip_compile(
         lambda lhs, rhs, sizes, offset: gmm_mod.grouped_matmul(
             lhs, rhs, sizes, group_offset=offset, impl="pallas",
             interpret=False),
-        ((128, k), jnp.bfloat16), ((6 * 32, k, n), jnp.bfloat16),
-        ((32,), jnp.int32), ((), jnp.int32))
+        ((m, k), jnp.bfloat16), ((layers * g, k, n), jnp.bfloat16),
+        ((g,), jnp.int32), ((), jnp.int32))
     assert any(named(c, "gmm") for c in kernel_calls(text))
-    assert_experts_read_in_place(text, (6, 32, k, n))
+    assert_experts_read_in_place(text, (layers, g, k, n))
 
 
 @pytest.fixture()
