@@ -11,8 +11,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional
 
-from . import (gpt2, jamba, laguna, lfm2, llama, mimo_v2, mla, moe, neox,
-               solar_open2)
+from . import (brumby, gpt2, jamba, laguna, lfm2, llama, mimo_v2, mla, moe,
+               neox, solar_open2)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +59,7 @@ _HF_ALIASES = {
     "poolside/laguna-xs.2": "laguna-xs.2",
     "upstage/solar-open2-250b": "solar-open2-250b",
     "ai21labs/ai21-jamba2-3b": "jamba2-3b",
+    "manifestai/brumby-14b-base": "brumby-14b",
 }
 
 
@@ -67,7 +68,8 @@ def family_module(family: str):
     by the pipeline schedule and chunked losses)."""
     mods = {"llama": llama, "gpt2": gpt2, "moe": moe, "neox": neox,
             "mla_moe": mla, "lfm2_moe": lfm2, "mimo_v2": mimo_v2,
-            "laguna": laguna, "solar_open2": solar_open2, "jamba": jamba}
+            "laguna": laguna, "solar_open2": solar_open2, "jamba": jamba,
+            "brumby": brumby}
     if family not in mods:
         raise KeyError(f"unknown model family {family!r}")
     return mods[family]
@@ -78,7 +80,7 @@ def list_models() -> list[str]:
             + sorted(neox.PRESETS) + sorted(mla.PRESETS)
             + sorted(lfm2.PRESETS) + sorted(mimo_v2.PRESETS)
             + sorted(laguna.PRESETS) + sorted(solar_open2.PRESETS)
-            + sorted(jamba.PRESETS))
+            + sorted(jamba.PRESETS) + sorted(brumby.PRESETS))
 
 
 def get_model(name: str, **overrides) -> ModelBundle:
@@ -159,6 +161,12 @@ def get_model(name: str, **overrides) -> ModelBundle:
             config = dataclasses.replace(config, **overrides)
         return ModelBundle(key, config, jamba.init, jamba.apply,
                            jamba.param_logical_axes, family="jamba")
+    if key in brumby.PRESETS:
+        config = brumby.PRESETS[key]
+        if overrides:
+            config = dataclasses.replace(config, **overrides)
+        return ModelBundle(key, config, brumby.init, brumby.apply,
+                           brumby.param_logical_axes, family="brumby")
     raise ValueError(
         f"Unknown model {name!r}. Available: {', '.join(list_models())} "
         f"(HF aliases: {', '.join(sorted(_HF_ALIASES))})"

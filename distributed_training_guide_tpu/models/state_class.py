@@ -2,7 +2,8 @@
 (``serve/kv_pages.py``: a block a live sequence, addressed by sequence and
 not by page) refuses on the serve path, stated ONCE for every such family
 (``models/solar_open2.py``: KDA's matrix a head; ``models/jamba.py``:
-Mamba's ``[d_state, channels]`` block). It lives beside the models because
+Mamba's ``[d_state, channels]`` block; ``models/brumby.py``: power
+retention's ``S`` and ``Z`` a kv head). It lives beside the models because
 ``serve`` imports them; ``ServeEngine`` reads it as the family's
 ``SERVE_REFUSES`` (``serve/engine.refuse_for_family``)."""
 

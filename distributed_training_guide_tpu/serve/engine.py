@@ -403,7 +403,7 @@ def advance_prefill_chunks(programs: "ModelPrograms", pages: dict,
         # iteration, exactly the latency spike the budget bounds
         budget -= chunk
         with span("serve.prefill", request_id=adm.request.request_id,
-                  tokens=real, program=f"serve_chunk_t{chunk}"):
+                  tokens=real, start=start, program=f"serve_chunk_t{chunk}"):
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :real] = adm.tokens[start:start + real]
             programs.prefill_calls += 1
@@ -841,7 +841,9 @@ def build_kv_report(programs: "ModelPrograms", *, page_size: int,
         "pages_cached": cached_pages,
         "bytes_per_page": per_page,
         "bytes_per_page_fp32": per_page_fp32,
-        "bytes_vs_fp32": round(per_page / per_page_fp32, 4),
+        # a family with no attending layer: a page holds nothing
+        "bytes_vs_fp32": (round(per_page / per_page_fp32, 4)
+                          if per_page_fp32 else 0.0),
         "kv_shards": shards,
         "bytes_per_page_per_chip": per_page // shards,
         "pool_bytes": pool_bytes,

@@ -122,27 +122,41 @@ there, and the gather path masks those positions by the same band.
 one-class family is the case without the second half: none of this runs.
 
 A family whose recurrent state is too large to keep a row a page states a
-STATE CLASS in ``config.sequence_state_layout()``, ADDRESSED BY SEQUENCE. Two
-families do: ``models/solar_open2.py`` (KDA: a ``heads x d x d`` float32
-matrix a layer, 4 MB, in 3 of its 4 layers) and ``models/jamba.py`` (Mamba: a
+STATE CLASS in ``config.sequence_state_layout()``, ADDRESSED BY SEQUENCE.
+Three families do: ``models/solar_open2.py`` (KDA: a ``heads x d x d`` float32
+matrix a layer, 4 MB, in 3 of its 4 layers), ``models/jamba.py`` (Mamba: a
 ``[d_state, channels]`` float32 block a layer, channels on the lanes, 320 KB,
-in 26 of its 28 layers; megabytes a sequence either way). The pool dict gains
-the leaves the layout names (:data:`SEQUENCE_LEAVES`: the state, and the last
-rows of the family's short convolution), each ``[state layers, n_blocks,
-...]``, a BLOCK a live sequence, with an id space of its own (block 0 the
-trash block, which idle slots carry). ``PagePool.state`` is its free list: the
-scheduler takes a block when it admits a sequence and returns it when the
-sequence leaves its slot (finished, expired or preempted: a preempted
-sequence is prefilled again), so ``n_slots + 1`` blocks always suffice. A
-slot's block id rides every program as ONE MORE COLUMN of its table row, the
-last (``make_attend(state_class=True)`` takes it off and hands it to the
-family as ``attend.state_blocks``). A block is not zeroed when it changes
-hands: a sequence that starts at position 0 reads zeros instead of it (the
-family's step, on ``attend.lengths == 0``). The state has no page identity,
-so what moves pages by id (``copy_pages``, the prefix cache, the host tier,
-the handoff, an engine swap) does not move it, and every such family refuses
-them by name, in the same words: ``models/state_class.STATE_CLASS_REFUSES``
-(beside the models, because this package imports them).
+in 26 of its 28 layers; megabytes a sequence either way) and
+``models/brumby.py`` (power retention: ``kv heads x 9,216 x d`` float32 and a
+``d x d`` normaliser a kv head, 38 MB a layer, in EVERY layer). The pool dict
+gains the leaves the layout names (:data:`SEQUENCE_LEAVES`: the state, the
+last rows of the family's short convolution, the state's normaliser), each
+``[state layers, n_blocks, ...]``, a BLOCK a live sequence, with an id space
+of its own (block 0 the trash block, which idle slots carry).
+``PagePool.state`` is its free list: the scheduler takes a block when it
+admits a sequence and returns it when the sequence leaves its slot (finished,
+expired or preempted: a preempted sequence is prefilled again), so ``n_slots +
+1`` blocks always suffice. A slot's block id rides every program as ONE MORE
+COLUMN of its table row, the last (``make_attend(state_class=True)`` takes it
+off and hands it to the family as ``attend.state_blocks``). A block is not
+zeroed when it changes hands: a sequence that starts at position 0 reads zeros
+instead of it (the family's step, on ``attend.lengths == 0``). The state has
+no page identity, so what moves pages by id (``copy_pages``, the prefix cache,
+the host tier, the handoff, an engine swap) does not move it, and every such
+family refuses them by name, in the same words:
+``models/state_class.STATE_CLASS_REFUSES`` (beside the models, because this
+package imports them).
+
+A family with NO ATTENDING LAYER (``models/brumby.py``: ``kv_layout()`` empty,
+``num_kv_layers`` 0) has no ``k`` and no ``v`` leaf: its pool dict is the
+state class alone, no program of it calls :func:`paged_attend` or writes a
+page, and a page costs no byte (:func:`kv_page_bytes` 0), so ``max_len``
+costs no memory and what bounds the batch is the state class's blocks. The
+page ids and block tables STAY, as the host's bookkeeping: the scheduler
+counts a sequence's length, its admission and its ``max_len`` in pages, the
+table row is what carries the block id to the program, and a second
+accounting for one family would be a second scheduler. They name nothing on
+the device.
 
 Device-side pieces (``paged_attend``, ``copy_pages``) are pure functions
 of array arguments — block tables and lengths arrive as int32 arrays, so
@@ -260,7 +274,9 @@ def window_pages_bound(window: int, page_size: int, n_slots: int,
     return 1 + n_slots * most(1) + most(chunk)
 
 
-SEQUENCE_LEAVES = ("seq_state", "seq_conv")   # the state class's pools
+# the state class's pools: the state, a short convolution's last rows, the
+# state's normaliser
+SEQUENCE_LEAVES = ("seq_state", "seq_conv", "seq_norm")
 
 
 def sequence_state_layout(config) -> Optional[dict]:
@@ -372,6 +388,8 @@ def resolve_attend_impl(impl: str, head_dim: int, page_size: int,
 def resolve_attend_for(config, impl: str, page_size: int) -> tuple[str, str]:
     """:func:`resolve_attend_impl` for a model's own cache layout."""
     layout = pool_layout(config)
+    if not layout:      # no attending layer: there is no attend to resolve
+        return "none", "the family has no attending layer"
     if not is_latent(config):   # the pool ROW's width (a packed row: 128)
         return resolve_attend_impl(impl, layout["k"][1], page_size)
     return resolve_attend_impl(impl, layout["v"][1], page_size,

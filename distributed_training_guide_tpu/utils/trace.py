@@ -97,12 +97,16 @@ SCOPES = (
 # state's writes are under `kv_write`) and `ssm` (in `attn`: a Mamba layer's
 # whole mixer, `models/jamba.py`: norm, `W_in`, the convolution, `W_x`, the
 # inner norms, `W_dt`, the `ssm_step` / `ssm_chunk` recurrence, the gate and
-# `W_out`; the state's writes under `kv_write` again). A reader that knows
+# `W_out`; the state's writes under `kv_write` again) and `retention` (in
+# `attn`: a power-retention layer's whole mixer, `models/brumby.py`: norm,
+# projections, the gate, QK-norm and rope, the `retention_step` /
+# `retention_chunk` recurrence and `W_o`; the state is updated where it lies,
+# inside those two). A reader that knows
 # only SCOPES counts their time under the parent;
 # `readers/path_component.py` reads one alone
 SUBSCOPES = ("latent_proj", "shared_expert", "conv", "attend_full",
              "attend_window", "head_gather", "attn_full", "attn_window", "kda",
-             "ssm")
+             "ssm", "retention")
 
 # pallas_call names (ops/); `kda_step` and `kda_chunk` name the two forms of
 # the KDA recurrence whatever implements them: on a TPU each is ONE Pallas
@@ -112,10 +116,14 @@ SUBSCOPES = ("latent_proj", "shared_expert", "conv", "attend_full",
 # under a `named_scope` of the same name, so a path reads
 # `attn/kda/kda_chunk/...` either way (`ops/kda.py`); `ssm_step` and
 # `ssm_chunk` likewise name the two forms of Mamba's selective scan
-# (`ops/ssm.py`: one kernel a Mamba layer on a TPU, `attn/ssm/ssm_step/...`)
+# (`ops/ssm.py`: one kernel a Mamba layer on a TPU, `attn/ssm/ssm_step/...`),
+# `retention_step` and `retention_chunk` the two forms of power retention
+# (`ops/retention.py`: `attn/retention/retention_step/...`; the scope holds
+# the kernel and, for the step, XLA's feature rows and normaliser beside it)
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "paged_attend",
            "paged_latent_attend", "gmm", "tgmm", "qmm", "kda_step",
-           "kda_chunk", "ssm_step", "ssm_chunk")
+           "kda_chunk", "ssm_step", "ssm_chunk", "retention_step",
+           "retention_chunk")
 
 # jitted programs; a name ending in _k or _t takes the static size that
 # keys the program (serve_horizon_k4, serve_chunk_t64, serve_verify_t5 and

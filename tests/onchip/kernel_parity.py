@@ -102,6 +102,19 @@ nothing is rounded to bfloat16, which would read 1e-2); one sabotage each
 the bound must refuse (the decay left out); and
 each kernel's time at that size, a line each (``ssm_timing``).
 
+``--retention`` runs the power-retention cell's two kernels alone
+(``brumby-14b-l8.serve.doc16``; ``ops/retention.py``): one sequence of 40
+query heads on 8 kv heads of 128 in bfloat16 through a FRESH chunk of 1,024
+tokens, a carried chunk with 1,000 of 1,024 real and eight decode steps, on a
+block of a ``[2, 17, 8, 36, 256, 128]`` float32 pool at layer 1, against the
+attention form in float64 on the host (kv head 0's five query heads: no state
+and no feature map in it) and against the ``jnp`` forms, outputs and state;
+the decode step at the cell's 16 slots (two on the trash block, two at
+position 0) against the gather / ``jnp`` / scatter path, the blocks no slot
+holds left as they were; two sabotages the bound must refuse (the gate left
+out of the chunk and of the step); and each kernel's time at that size, a
+line (``retention_timing``).
+
 One process; fails (no last line, exit 1) off the chip. Prints the entry
 points' start-up device line, one JSON line per case, and last
 ``{"kernel_parity_ok": true, "cases": N, "controls_refused": 5}``.
@@ -847,6 +860,124 @@ def ssm_cases() -> int:
     return refused
 
 
+def retention_cases(hq=40, hkv=8, d=128, t=1024, real=1000, steps=8) -> int:
+    from distributed_training_guide_tpu.ops import retention as ret
+
+    layers, blocks, layer, block = 2, 17, 1, 3
+    g, total = hq // hkv, 2 * t + steps
+    shapes = ret.state_shapes(hkv, d)
+    pools = tuple(normal((layers, blocks, *shape), jnp.float32)
+                  for shape in shapes)
+    q, k, v = (normal((1, total, h, d), jnp.bfloat16)
+               for h in (hq, hkv, hkv))
+    gate = jnp.asarray(RNG.standard_normal((1, total, hkv)) * 1.4 + 6.906768,
+                       jnp.float32)
+    log_gamma = jax.nn.log_sigmoid(gate)
+    ids = jnp.asarray([block])
+    # the rows the sequence really has: chunk 1 whole, 1,000 of chunk 2, then
+    # the steps' (the 24 padded rows of chunk 2 belong to no sequence)
+    rows = np.r_[0:t, t:t + real, 2 * t:total]
+
+    def run(impl, lg):
+        """Outputs at the real rows, and the pools after."""
+        def chunk(pools, lo, fresh, n):
+            return jax.jit(lambda s, z: ret.retention_chunk(
+                s, z, ids, layer, q[:, lo:lo + t], k[:, lo:lo + t],
+                v[:, lo:lo + t], lg[:, lo:lo + t], jnp.asarray([fresh]),
+                jnp.asarray([n]), impl=impl))(*pools)
+
+        o1, *state = chunk(pools, 0, True, t)
+        o2, *state = chunk(state, t, False, real)
+        outs = [o1[0], o2[0, :real]]
+        step = jax.jit(lambda s, z, i: ret.retention_step(
+            s, z, ids, layer, q[:, i], k[:, i], v[:, i], lg[:, i],
+            jnp.asarray([False]), impl=impl))
+        for i in range(2 * t, total):
+            o, *state = step(*state, i)
+            outs.append(o)
+        return jnp.concatenate(outs), state
+
+    (o, state), (o_jnp, state_jnp) = run("pallas", log_gamma), run(
+        "xla", log_gamma)
+    # the attention form in float64, kv head 0's query heads
+    q64, k64, v64, c64 = (np.asarray(x, np.float64)[0][rows] for x in (
+        q[:, :, :g], k[:, :, 0], v[:, :, 0], log_gamma[:, :, 0]))
+    run_c = np.cumsum(c64)
+    o64 = np.zeros((len(rows), g, d))
+    for j in range(g):
+        score = q64[:, j] @ k64.T
+        w = np.tril(score * score * np.exp(np.minimum(
+            run_c[:, None] - run_c[None, :], 0.0)))
+        o64[:, j] = (w @ v64) / w.sum(1, keepdims=True)
+    case("retention_chunk_and_step", {"o": (o[:, :g], o64)}, rtol=1e-4,
+         tokens=total, real=len(rows), against="float64 attention form")
+    case("retention_jnp_forms", {
+        "o": (o, o_jnp), "S": (state[0][layer, block],
+                               state_jnp[0][layer, block]),
+        "Z": (state[1][layer, block], state_jnp[1][layer, block])},
+        rtol=1e-5)
+    o_bad, _ = run("pallas", jnp.zeros_like(log_gamma))
+    refused = 0
+    for name, at in (("retention_chunk_control_no_gate", slice(t, t + real)),
+                     ("retention_step_control_no_gate", slice(-steps, None))):
+        if not case(name, {"o": (o_bad[at], o_jnp[at])}, rtol=1e-5):
+            FAILED.remove(name)
+            refused += 1
+    # the decode step at the cell's 16 slots, every slot on the sequence's
+    # state (its block copied into all 16), rows of its own
+    slots = 16
+    pools = tuple(jnp.broadcast_to(leaf[:, block][:, None], leaf.shape)
+                  for leaf in state)
+    slot_ids = np.arange(1, blocks).astype(np.int32)
+    slot_ids[[2, 11]] = ret.TRASH_BLOCK
+    fresh = np.zeros(slots, bool)
+    fresh[[5, 9]] = True
+    slot_ids, fresh = jnp.asarray(slot_ids), jnp.asarray(fresh)
+    sq, sk, sv = (normal((slots, h, d), jnp.bfloat16) for h in (hq, hkv, hkv))
+    slg = log_gamma[0, :slots]
+
+    def step16(impl):
+        return jax.jit(lambda s, z: ret.retention_step(
+            s, z, slot_ids, layer, sq, sk, sv, slg, fresh, impl=impl))(*pools)
+
+    (o, s_new, z_new), (o_ref, s_ref, z_ref) = step16("pallas"), step16("xla")
+    held = np.flatnonzero(np.asarray(slot_ids) != ret.TRASH_BLOCK)
+    live = np.asarray(slot_ids)[held]
+    # (a slot at position 0 reads out ONE token's state: num and den are
+    # both (q . k)^2 times something, and where q . k is near 0 the quotient
+    # is rounding's; its S and Z are compared, its o is not)
+    old = np.flatnonzero((np.asarray(slot_ids) != ret.TRASH_BLOCK)
+                         & ~np.asarray(fresh))
+    case("retention_step_16_slots", {
+        "o": (o[old], o_ref[old]),
+        "S": (s_new[layer, live], s_ref[layer, live]),
+        "Z": (z_new[layer, live], z_ref[layer, live])}, rtol=1e-5,
+        slots=slots, blocks=blocks)
+    idle = np.setdiff1d(np.arange(1, blocks), live)
+    untouched = bool(jnp.array_equal(s_new[layer, idle], pools[0][layer, idle])
+                     and jnp.array_equal(s_new[0], pools[0][0]))
+    case("retention_step_leaves_other_blocks", {"same": (
+        jnp.asarray(float(untouched)), jnp.asarray(1.0))})
+    del s_new, z_new, s_ref, z_ref
+    step_ms = timed(jax.jit(lambda sz: ret.retention_step(
+        *sz, slot_ids, layer, sq, sk, sv, slg, fresh, impl="pallas")[1:],
+        donate_argnums=0), pools, carry=True)
+    chunk_ms = {}
+    for name, is_fresh in (("carried", False), ("fresh", True)):
+        chunk_ms[name] = timed(jax.jit(lambda sz, f=is_fresh:
+            ret.retention_chunk(*sz, ids, layer, q[:, :t], k[:, :t], v[:, :t],
+                                log_gamma[:, :t], jnp.asarray([f]),
+                                impl="pallas")[1:], donate_argnums=0),
+            tuple(state), carry=True, reps=10)
+        state = [jnp.array(x) for x in state_jnp]
+    print(json.dumps({"retention_timing": {
+        "retention_step_ms_a_layer_16_slots": step_ms,
+        "retention_chunk_ms_a_layer_1024_tokens": chunk_ms,
+        "pairs_a_step_tile": ret.PAIR_TILE, "tokens_a_block": ret.TOKENS}}),
+        flush=True)
+    return refused
+
+
 def int8_matmul_case() -> None:
     qm = importlib.import_module(
         "distributed_training_guide_tpu.ops.quantized_matmul")
@@ -867,16 +998,27 @@ def main(argv) -> int:
     everything, mla_only = argv == ["--all"], argv == ["--mla"]
     lfm2_only, mimo_only = argv == ["--lfm2"], argv == ["--mimo"]
     kda_only, ssm_only = argv == ["--kda"], argv == ["--ssm"]
+    retention_only = argv == ["--retention"]
     if argv and not (everything or mla_only or lfm2_only or mimo_only
-                     or kda_only or ssm_only):
-        raise SystemExit(
-            "usage: kernel_parity.py [--all|--mla|--lfm2|--mimo|--kda|--ssm]")
+                     or kda_only or ssm_only or retention_only):
+        raise SystemExit("usage: kernel_parity.py [--all|--mla|--lfm2|--mimo|"
+                         "--kda|--ssm|--retention]")
     print_device_line("attend", ("flash", "forced"), CACHE.directory)
     if jax.devices()[0].platform != EXPECT_PLATFORM:
         print(f"kernel_parity FAILED: runs on "
               f"{jax.devices()[0].platform!r}, not {EXPECT_PLATFORM!r}",
               file=sys.stderr)
         return 1
+    if retention_only:
+        refused = retention_cases()
+        CACHE.print_line()
+        if FAILED or refused != 2:
+            print(f"kernel_parity FAILED: cases over the bound: {FAILED}; "
+                  f"gate left out refused: {refused} of 2", file=sys.stderr)
+            return 1
+        print(json.dumps({"kernel_parity_ok": True, "cases": N_CASES,
+                          "controls_refused": refused}), flush=True)
+        return 0
     if ssm_only:
         refused = ssm_cases()
         CACHE.print_line()

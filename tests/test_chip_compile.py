@@ -1069,6 +1069,102 @@ def test_state_space_familys_serve_programs_compile_at_the_cells_size(
     assert held < 14.5 * 2 ** 30, held / 2 ** 30
 
 
+# ---- the power-retention family's cell ----------------------------------------
+# brumby-14b-l8.serve.doc16: 8 of 40 layers at every width, the whole untied
+# vocabulary, 16 slots of 40 query heads on 8 kv heads, NO attending layer (no
+# k or v leaf), 100 table columns and the block id, 17 state blocks of 8
+# layers (S [8, 36, 256, 128] and Z [8, 128, 128] float32), a chunk of 1,024
+
+BRUMBY_CELL = dict(slots=16, columns=100, chunk=1024, layers=8)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk1024"])
+def test_retention_familys_serve_programs_compile_at_the_cells_size(
+        one_chip, chip_compile, monkeypatch, capsys, program):
+    """``brumby-14b-l8.serve.doc16``'s decode step (16 slots) and prefill
+    chunk (1,024 tokens), whole, at the cell's size (7.82 GiB of weights, the
+    state class of 8 layers over 17 blocks: 4.85 GiB in float32, no k or v
+    pool at all): the decode step updates every slot's state where it lies
+    through ``retention_step`` (one call a layer, the pool aliased in and
+    out), a chunk goes through ONE ``retention_chunk`` kernel a layer, also
+    in place, no ``paged_attend`` exists, and nothing weight-sized or
+    pool-sized is copied; arguments and temporaries of either program stay
+    under 14.5 GiB, and are printed."""
+    import dataclasses
+
+    from distributed_training_guide_tpu.models import brumby
+    from distributed_training_guide_tpu.ops import retention
+    from distributed_training_guide_tpu.serve import kv_pages
+
+    monkeypatch.setattr(retention, "resolve_interpret", lambda i: False)
+    monkeypatch.setattr(
+        retention, "_resolve_impl",
+        lambda impl, op=None: "pallas" if impl == "auto" else impl)
+    c = BRUMBY_CELL
+    cfg = dataclasses.replace(brumby.PRESETS["brumby-14b"],
+                              num_layers=c["layers"], dtype=jnp.bfloat16,
+                              param_dtype=jnp.bfloat16)
+    assert cfg.num_params() == 4_198_652_928
+    params = jax.eval_shape(lambda: brumby.init(cfg, jax.random.key(0)))
+    leaves, treedef = jax.tree.flatten(params)
+    pools = jax.eval_shape(lambda: kv_pages.init_pages(
+        cfg, 1 + c["slots"] * c["columns"], 128,
+        n_state_blocks=c["slots"] + 1))
+    names = ("seq_state", "seq_norm")
+    assert set(pools) == set(names)         # no k, no v
+    assert pools["seq_state"].shape == (8, c["slots"] + 1, 8, 36, 256, 128)
+    assert pools["seq_norm"].shape == (8, c["slots"] + 1, 8, 128, 128)
+    assert all(pools[n].dtype == jnp.float32 for n in names)
+    slots, t = (c["slots"], 1) if program == "decode" else (1, c["chunk"])
+
+    def step(sp, zp, ids, lengths, tables, *flat):
+        logits, cache = brumby.paged_decode_step(
+            cfg, jax.tree.unflatten(treedef, flat), ids, lengths,
+            dict(zip(names, (sp, zp))),
+            kv_pages.make_attend(tables, lengths, impl="flash",
+                                 n_valid=jnp.full((slots,), t),
+                                 state_class=True),
+            last_index=jnp.asarray(t - 1))
+        return (jnp.argmax(logits, -1), *(cache[n] for n in names))
+
+    specs = [(pools[n].shape, pools[n].dtype) for n in names] + [
+        ((slots, t), jnp.int32), ((slots,), jnp.int32),
+        ((slots, c["columns"] + 1), jnp.int32)] + [
+        (x.shape, x.dtype) for x in leaves]
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in specs)).compile()
+    text = compiled.as_text()
+    calls = kernel_calls(text)
+    assert not any(named(x, "paged_attend") for x in calls), calls
+    assert sum(named(x, "retention_step") for x in calls) == (
+        8 if program == "decode" else 0), calls
+    assert sum(named(x, "retention_chunk") for x in calls) == (
+        0 if program == "decode" else 8), calls
+    # S is carried in place through the kernels (the normaliser, 9 MB a
+    # layer, is gathered and scattered by XLA in the decode step)
+    sized = pool_sized_ops(text, pools["seq_state"].shape, names=True)
+    in_place = ("parameter", "bitcast", "get-tuple-element", "fusion",
+                "custom-call", "tuple")
+    moved = [x for x in sized if x.split()[0] not in in_place or "copy" in x]
+    assert not moved, moved
+    # the embedding, the head and the FFN's three (W_q or W_o, 52 MB, the
+    # compiler itself stages into its fast memory ahead of the product: a
+    # `copy` to memory space 1, eight a decode program)
+    weights = [(151936, 5120), (5120, 151936), (5120, 17408), (17408, 5120)]
+    assert not operand_sized_moves(text, *weights, dtypes=("bf16",))
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\n{program}: arguments {mem.argument_size_in_bytes:,} B, "
+              f"temporaries {mem.temp_size_in_bytes:,} B, held "
+              f"{held / 2 ** 30:.2f} GiB")
+    assert mem.alias_size_in_bytes >= sum(
+        math.prod(pools[n].shape) * 4 for n in names)
+    assert held < 14.5 * 2 ** 30, held / 2 ** 30
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk512"])
 def test_moe_familys_serve_programs_read_the_experts_in_place(
         chip_compile, compiled_kernels, program):

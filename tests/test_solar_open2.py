@@ -253,7 +253,7 @@ def test_copy_pages_leaves_the_state_class_alone(model):
     pages = jax.tree.map(lambda a: jnp.arange(a.size, dtype=a.dtype)
                          .reshape(a.shape), pages)
     out = kv_pages.copy_pages(pages, jnp.int32(2), jnp.int32(4))
-    for leaf in kv_pages.SEQUENCE_LEAVES:
+    for leaf in bundle.config.sequence_state_layout():
         assert np.array_equal(out[leaf], pages[leaf])
     assert np.array_equal(out["k"][:, 4], pages["k"][:, 2])
 

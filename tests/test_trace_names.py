@@ -514,7 +514,7 @@ def test_latent_family_decode_carries_its_subscopes_and_kernel():
     assert "paged_latent_attend" in KERNELS and set(SUBSCOPES) == {
         "latent_proj", "shared_expert", "conv", "attend_full",
         "attend_window", "head_gather", "attn_full", "attn_window", "kda",
-        "ssm"}
+        "ssm", "retention"}
 
 
 def test_named_gives_jit_the_name():
@@ -526,7 +526,8 @@ def test_the_vocabulary_is_what_the_package_uses():
     """``grep`` over the package: every ``span("...")``, ``named_scope("...")``
     and ``pallas_call`` name is in the tuples, and ``TraceAnnotation`` is
     used by ``utils/trace.py`` alone. A computation that is a kernel on the
-    chip and plain ``jnp`` elsewhere (``ops/kda.py``, ``ops/ssm.py``) carries
+    chip and plain ``jnp`` elsewhere (``ops/kda.py``, ``ops/ssm.py``,
+    ``ops/retention.py``) carries
     its KERNELS name as a ``named_scope`` too, whatever implements it."""
     from pathlib import Path
 
@@ -545,5 +546,6 @@ def test_the_vocabulary_is_what_the_package_uses():
     assert scopes - set(KERNELS) == set(SCOPES) | set(SUBSCOPES)
     assert not set(SCOPES) & set(SUBSCOPES)
     assert scopes & set(KERNELS) == {"kda_step", "kda_chunk", "ssm_step",
-                                     "ssm_chunk"}
+                                     "ssm_chunk", "retention_step",
+                                     "retention_chunk"}
     assert kernels | (scopes & set(KERNELS)) == set(KERNELS)
