@@ -63,10 +63,11 @@ from ..utils.trace import span
 from .adapters import DEFAULT_TARGETS
 from .engine import (DecodeArrays, LatencyMeter, ModelPrograms,
                      adapter_metrics,
-                     advance_prefill_chunks, build_adapter_report,
+                     advance_prefill_chunks, book_inflight,
+                     build_adapter_report,
                      build_kv_report, collect_partial_tokens,
                      derived_pool_metrics, dispatch_horizon,
-                     drop_stale_pending, no_dev, process_horizon_block,
+                     drop_stale_pending, no_dev,
                      refuse_for_family,
                      resolve_context_bounds, resolve_drafter,
                      resolve_prefill_chunk, run_decode_iteration, run_fork,
@@ -425,12 +426,17 @@ class DecodeEngine(DecodeArrays):
         self.horizon_ksum += k
         self.decode_steps += k
 
-    def step(self) -> tuple[list[RequestResult], list]:
+    def step(self, seq: int = 0) -> tuple[list[RequestResult], list]:
         """One decode iteration — a fused, double-buffered K-step horizon
         when ``decode_horizon > 1`` (the ServeEngine.step discipline:
         steady state dispatches h before booking h−1; any boundary event
         — a pending handoff to seat, a preemption requeue, a deadline
-        due — drains the pipeline first). Returns (finished,
+        due — drains the pipeline first). The plain single-token program
+        keeps the SYNCHRONOUS order here, enqueued and read in one step:
+        the monolith's pipelined order would need the quiet test to see
+        the prefill engine and the handoff queue between them, and no
+        deployment measures this pair yet. ``seq``: the facade's step, for
+        the spans. Returns (finished,
         preempted_entries) — preempted entries (request + generated
         suffix) must be requeued on the prefill side by the caller."""
         finished = []
@@ -441,23 +447,25 @@ class DecodeEngine(DecodeArrays):
                     and not sched.deadline_due()
                     and sched.active_indices()):
                 pending_k = self._inflight["k"]
-                cov = sched.reserve_horizon(
-                    pending_k + self.decode_horizon)
-                # budget clamp (see ServeEngine.step): a pending block
+                cov = self.reserve_ahead(sched,
+                                         pending_k + self.decode_horizon)
+                # budget clamp (see ServeEngine._ahead): a pending block
                 # that provably finishes every slot drains instead of
                 # burning an all-dead trailing horizon
                 k_new = min(cov - pending_k, self.decode_horizon,
                             sched.max_remaining_budget() - pending_k)
                 if k_new >= 1:
                     nxt, self._dev = dispatch_horizon(
-                        self.programs, self.pages, sched, self._dev, k_new)
+                        self.programs, self.pages, sched, self._dev, k_new,
+                        seq=seq)
                     self._note_dispatch(k_new)
-                    fin, emitted = process_horizon_block(sched,
-                                                         self._inflight)
+                    fin, emitted = book_inflight(self.programs, sched,
+                                                 self._inflight)
                     self._inflight = nxt
                     self.decode_tokens += emitted
                     return fin, []
-            fin, emitted = process_horizon_block(sched, self._inflight)
+            fin, emitted = book_inflight(self.programs, sched,
+                                         self._inflight)
             self._inflight = None
             self.drop_dev("drained")
             self.decode_tokens += emitted
@@ -479,20 +487,21 @@ class DecodeEngine(DecodeArrays):
 
         if sched.active_indices():
             if self._horizon_ready():
-                k0 = max(1, min(sched.reserve_horizon(self.decode_horizon),
-                                self.decode_horizon,
-                                sched.max_remaining_budget()))
+                k0 = max(1, min(
+                    self.reserve_ahead(sched, self.decode_horizon),
+                    self.decode_horizon, sched.max_remaining_budget()))
                 self._inflight, self._dev = dispatch_horizon(
-                    self.programs, self.pages, sched, self._dev, k0)
+                    self.programs, self.pages, sched, self._dev, k0,
+                    seq=seq)
                 self._note_dispatch(k0)
             else:
                 # the spec/plain dispatch is the monolith's, verbatim
                 # (engine.run_decode_iteration — replay pauses
                 # speculation, empty-draft iterations fall back to the
-                # plain program)
-                fin, emitted, self._dev = run_decode_iteration(
+                # plain program), and never enqueues ahead
+                fin, emitted, self._dev, _ = run_decode_iteration(
                     self.programs, self.pages, sched, self.drafter,
-                    self.spec, self._dev)
+                    self.spec, self._dev, seq=seq)
                 self._note_dispatch(1)
                 self.decode_tokens += emitted
                 finished.extend(fin)
@@ -991,7 +1000,7 @@ class DisaggEngine:
                         free=self.pool.free)
         finished = self.prefill.step()
         finished.extend(self._expire_in_transit())
-        decoded, preempted = self.decode.step()
+        decoded, preempted = self.decode.step(self.stats_seq)
         finished.extend(decoded)
         # requeue preempted entries at the head of their priority class on
         # the prefill side, oldest-preempted last so relative order holds

@@ -364,6 +364,14 @@ def _swap_generation_locked(old, new, force_replay: bool):
              "queued_moved": 0, "tier_records_carried": 0,
              "tier_records_dropped": 0}
     old.drain()
+    # a decode program in flight on the old generation is booked before the
+    # export reads its scheduler; a request that booking finishes (an eos)
+    # is reported with the swap's results, as the old engine steps no more
+    settle = getattr(old, "settle", None)
+    settled = []
+    if settle is not None:
+        settle()
+        settled = old.take_settled()
     with_payload = _payload_compatible(old, new) and not force_replay
     disagg = isinstance(old, DisaggEngine)
 
@@ -417,7 +425,7 @@ def _swap_generation_locked(old, new, force_replay: bool):
         capacities = [new.scheduler.pool.capacity]
         now = new.scheduler._clock()
         new.drop_dev("swapped")
-    results = []
+    results = list(settled)
     max_id = -1
     for exp in residents:
         max_id = max(max_id, exp.request.request_id)

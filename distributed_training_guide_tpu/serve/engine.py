@@ -464,6 +464,16 @@ class DecodeArrays:
         if self._dev["kind"] is not None and self._dev["stale"] is None:
             self._dev["stale"] = reason
 
+    def reserve_ahead(self, sched: Scheduler, want: int) -> int:
+        """``Scheduler.reserve_horizon`` for a program that will be enqueued
+        behind host state the device has run ahead of: how many writes are
+        covered for every decoding slot. Pages it gave are on no table of
+        the device yet, whether or not the program goes up."""
+        covered, grown = sched.reserve_horizon(want)
+        if grown:
+            self.stale_tables("lookahead")
+        return covered
+
 
 # what the verify program takes of ``Scheduler.decode_arrays()``: the
 # candidate ids go up with every call, and it masks no lane by budget or eos
@@ -649,11 +659,61 @@ def book_first_tokens(sched: Scheduler, first) -> tuple[list, set]:
     return finished, ended
 
 
+def live_lanes(sched: Scheduler) -> list:
+    """``(slot, request id)`` of the decoding slots at a dispatch: what the
+    booking of that program matches its lanes by, since the host may book it
+    a step later, when a lane's request has left."""
+    return [(i, sched.slots[i].request.request_id)
+            for i in sched.active_indices()]
+
+
+def dispatch_decode(programs: "ModelPrograms", pages: dict,
+                    sched: Scheduler, dev: dict, *, seq: int,
+                    first: list = (), ahead: bool = False) \
+        -> tuple[list, dict]:
+    """Enqueue the plain single-token program, no host synchronization, on
+    the tokens and lengths the last one left on the device (or the host's,
+    where ``upload_decode_arrays`` finds none resident); with ``ahead`` the
+    program AFTER it behind it, on what it leaves in turn. Both lie under
+    the step's ONE ``serve.dispatch`` (``programs`` says how many): a step
+    has one such span and one ``serve.wait`` after it, whichever order it
+    takes. ``first`` as ``run_decode_iteration`` has it.
+
+    Returns the in-flight records :func:`book_inflight` consumes, one a
+    program (the ``[n_slots]`` token future, a routing family's counters
+    behind it; ``seq``, the step that enqueued it; the lanes live at the
+    dispatch), and the updated dev cache."""
+    dev = upload_decode_arrays(dev, "plain", sched,
+                               placement=programs.operand_placement)
+    # in front of the span, not inside it: ``serve.dispatch`` stays the
+    # enqueue of the decode program, which the step's waterfall joins it with
+    for adm, token in first:
+        dev["tokens"] = programs._seat_fn(
+            dev["tokens"], jnp.asarray(adm.slot_idx, jnp.int32), token)
+    active = live_lanes(sched)
+    records = []
+    with span("serve.dispatch", program="serve_decode", programs=1 + ahead):
+        for _ in range(1 + ahead):
+            nxt, new_len, pools, *counted = programs._decode_fn(
+                programs.params, dict(pages),
+                dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
+                dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
+                *programs.lora_call_args(dev["adapters"]))
+            pages.update(pools)
+            dev["tokens"], dev["lengths"] = nxt, new_len
+            records.append({"kind": "plain", "k": 1, "seq": seq,
+                            "block": counted[0] if counted else nxt,
+                            "active": active})
+    return records, dev
+
+
 def run_decode_iteration(programs: "ModelPrograms", pages: dict,
                          sched: Scheduler, drafter: Optional[Drafter],
-                         spec: dict, dev: dict, first: list = ()) \
-        -> tuple[list, int, dict]:
-    """ONE decode iteration over the active slots — the spec/plain
+                         spec: dict, dev: dict, first: list = (), *,
+                         seq: int = 0, ahead: bool = False) \
+        -> tuple[list, int, dict, Optional[dict]]:
+    """ONE decode iteration over the active slots, dispatched and read in
+    the same step — the spec/plain
     dispatch, single-sourced for the monolith and the disaggregated
     decode engine (like ``run_spec_decode`` itself: neither the
     semantics NOR the scaffolding around them may fork between the two).
@@ -686,51 +746,32 @@ def run_decode_iteration(programs: "ModelPrograms", pages: dict,
     booked, and its write went to a page and a state block the slot owned
     and has freed, which no later program reads before it writes them.
 
-    Returns (finished, tokens emitted, dev). The caller owns the
-    decode_steps/decode_tokens counters and must drop ``dev`` when a
-    finished slot leaves the batch."""
-    active = sched.active_indices()
+    ``ahead`` (the caller's ``ServeEngine._ahead``; never with a drafter):
+    the plain program for the token AFTER this one is enqueued behind this
+    one before anything is read, and comes back as the record the next step
+    books: the step enters the pipeline.
+
+    Returns (finished, tokens emitted, dev, the record in flight or None).
+    The caller owns the decode_steps/decode_tokens counters and must drop
+    ``dev`` when a finished slot leaves the batch."""
     if drafter is not None and not any(sched.slots[i].replaying
-                                       for i in active):
+                                       for i in sched.active_indices()):
         out = run_spec_decode(programs, pages, sched, drafter, spec, dev)
         if out is not None:
-            return out
-    dev = upload_decode_arrays(dev, "plain", sched,
-                               placement=programs.operand_placement)
-    # in front of the span, not inside it: ``serve.dispatch`` stays the
-    # enqueue of ONE program, the one the step's waterfall joins it with
-    for adm, token in first:
-        dev["tokens"] = programs._seat_fn(
-            dev["tokens"], jnp.asarray(adm.slot_idx, jnp.int32), token)
-    with span("serve.dispatch", program="serve_decode"):
-        nxt, new_len, pools, *counted = programs._decode_fn(
-            programs.params, dict(pages),
-            dev["tokens"], dev["lengths"], dev["tables"], dev["seeds"],
-            dev["temps"], dev["top_ks"], dev["top_ps"], dev["actives"],
-            *programs.lora_call_args(dev["adapters"]))
-        pages.update(pools)
-    dev["tokens"], dev["lengths"] = nxt, new_len
-    finished, ended = book_first_tokens(sched, first)
-    with span("serve.wait"):
-        nxt_host = np.asarray(counted[0] if counted else nxt)
-    if counted:
-        programs.note_routing(nxt_host[sched.n_slots:])
-    booked = [i for i in active if i not in ended]
-    with span("serve.book", tokens=len(booked)):
-        for slot_idx in booked:
-            res = sched.record_token(slot_idx, int(nxt_host[slot_idx]),
-                                     from_decode=True)
-            if res is not None:
-                finished.append(res)
-    return finished, len(booked), dev
+            return (*out, None)
+    (record, *nxt), dev = dispatch_decode(programs, pages, sched, dev,
+                                          seq=seq, first=first, ahead=ahead)
+    finished, _ = book_first_tokens(sched, first)
+    fin, emitted = book_inflight(programs, sched, record)
+    return finished + fin, emitted, dev, (nxt[0] if nxt else None)
 
 
 def dispatch_horizon(programs: "ModelPrograms", pages: dict,
-                     sched: Scheduler, dev: dict, k: int) \
-        -> tuple[dict, dict]:
+                     sched: Scheduler, dev: dict, k: int, *,
+                     seq: int = 0) -> tuple[dict, dict]:
     """Dispatch ONE fused K-step horizon — no host synchronization: jax's
     async dispatch returns futures, and the only blocking read is the
-    ``np.asarray`` in :func:`process_horizon_block`, which the engine
+    ``np.asarray`` in :func:`book_inflight`, which the engine
     runs AFTER dispatching the next horizon (the double buffer: the
     device computes horizon h while the host books horizon h−1).
 
@@ -738,22 +779,23 @@ def dispatch_horizon(programs: "ModelPrograms", pages: dict,
     plus the per-slot live/budget/eos lanes the in-device masking consumes.
     They go up whole at a horizon boundary (``dev`` is then ``no_dev`` or
     another program's set; host and device state agree there). Between
-    boundaries the block tables alone go up, at every dispatch — they are
-    host-owned and may have grown via ``reserve_horizon`` since the last
-    one — while tokens/lengths/live/budgets stay device-resident (the
-    previous horizon's outputs feed this one's inputs without readback). A
+    boundaries the block tables alone go up, where a reservation grew one
+    since the last dispatch (``DecodeArrays.reserve_ahead``: they are
+    host-owned) — while tokens/lengths/live/budgets stay device-resident
+    (the previous horizon's outputs feed this one's inputs without
+    readback). A
     slot that finished inside a still-unprocessed block is DEAD on device
     (its live lane went False in that block's scan), so its stale table
     row is masked to the trash page in-program and its freed pages may
     be re-issued to a later admission without corruption.
 
-    Returns the in-flight record ``process_horizon_block`` consumes (the
-    ``[n_slots, k]`` token-block future, the realized k, and the (slot,
-    request_id) pairs active at dispatch) and the updated dev cache."""
-    dev = upload_decode_arrays(dev, "horizon", sched, lookahead=True,
+    Returns the in-flight record ``book_inflight`` consumes (the
+    ``[n_slots, k]`` token-block future, the realized k, ``seq``, the step
+    that enqueued it, and the (slot, request_id) pairs active at dispatch)
+    and the updated dev cache."""
+    dev = upload_decode_arrays(dev, "horizon", sched,
                                placement=programs.operand_placement)
-    active = [(i, sched.slots[i].request.request_id)
-              for i in sched.active_indices()]
+    active = live_lanes(sched)
     with span("serve.dispatch", program=f"serve_horizon_k{k}"):
         (block, dev["tokens"], dev["lengths"], dev["actives"],
          dev["budgets"], pools) = programs.horizon_for(k)(
@@ -763,21 +805,33 @@ def dispatch_horizon(programs: "ModelPrograms", pages: dict,
             dev["budgets"], dev["eos_ids"],
             *programs.lora_call_args(dev["adapters"]))
         pages.update(pools)
-    return {"block": block, "k": k, "active": active}, dev
+    return {"kind": "horizon", "k": k, "seq": seq, "block": block,
+            "active": active}, dev
 
 
-def process_horizon_block(sched: Scheduler, inflight: dict) \
-        -> tuple[list, int]:
-    """Book one finished horizon's ``[n_slots, k]`` token block: the ONE
-    blocking device read per horizon. Per slot, tokens record in order
-    through the same ``record_token`` the K=1 path uses and stop at the
-    first finish — record_token's eos-then-budget rule is exactly the
-    scan's live-mask update, so the host stops precisely where the
-    device lane died (everything past it is masked zeros). A slot that
-    already finished in an EARLIER block (or was evicted at a boundary)
-    is skipped by request-id match. Returns (finished, tokens_emitted)."""
-    with span("serve.wait"):
+def book_inflight(programs: "ModelPrograms", sched: Scheduler,
+                  inflight: dict) -> tuple[list, int]:
+    """Read and book one dispatched decode program, plain or horizon: the
+    ONE blocking device read of the step (``serve.wait``, whose
+    ``waits_for`` is the step that enqueued the program: this step's own,
+    or the one before in a pipelined step). Per lane, tokens record in
+    order through ``record_token`` and stop at the first finish —
+    record_token's eos-then-budget rule is exactly a horizon scan's
+    live-mask update, so the host stops precisely where the device lane
+    died (everything past it is masked zeros). A lane whose request has
+    left its slot since the dispatch is skipped by request-id match: it
+    finished in an EARLIER block or was evicted at a boundary (a horizon),
+    or its first token or the token before this one was its eos (the plain
+    program enqueued ahead ran ONE lane too many for ONE step: its write
+    went to a page and a state block the slot owned and has freed, which no
+    later program reads before it writes them; a state block's next owner
+    starts from zeros at position 0). Returns (finished, tokens_emitted)."""
+    with span("serve.wait", waits_for=inflight["seq"]):
         block = np.asarray(inflight["block"])
+    if block.ndim == 1:     # the plain program's tokens, and behind them a
+        if len(block) > sched.n_slots:      # routing family's counters
+            programs.note_routing(block[sched.n_slots:])
+        block = block[:sched.n_slots, None]
     finished, emitted = [], 0
     with span("serve.book") as sp:
         for slot_idx, rid in inflight["active"]:
@@ -1871,15 +1925,21 @@ class ServeEngine(DecodeArrays):
         self.chunk_steps = 0
         self.chunk_steps_overlapped = 0
         install_gc_span()
-        # the dispatched-but-unprocessed horizon block (decode_horizon >
-        # 1): the double buffer's slot — the device computes horizon h
-        # while the host books h−1 (see dispatch_horizon)
+        # the decode program that is enqueued and not yet booked, plain
+        # or horizon (dispatch_decode / dispatch_horizon's record): the
+        # double buffer's ONE slot — the device runs it while the host books
+        # the one before, returns to its caller and is called again
         self._inflight: Optional[dict] = None
+        # what `settle` finished outside a step: the next step returns it
+        self._settled: list[RequestResult] = []
         self.draining = False
         # decode throughput + latency counters (api.py metrics; all
         # host-side — see stats())
         self.decode_steps = 0
         self.decode_tokens = 0
+        # steps that enqueued their decode program BEFORE they read the one
+        # in flight (the pipelined order: step())
+        self.decode_steps_pipelined = 0
         self.host_dispatches = 0
         self.horizon_ksum = 0
         self._lat = LatencyMeter()
@@ -1946,8 +2006,35 @@ class ServeEngine(DecodeArrays):
         step() as usual — the graceful half of SIGTERM/stop. The router
         reads ``draining`` from stats() and marks this replica
         unroutable; the HTTP worker keeps stepping until pending futures
-        empty (api.py ``_EngineWorker.stop(drain=True)``)."""
+        empty (api.py ``_EngineWorker.stop(drain=True)``). A flag and
+        nothing else, as that caller is another thread than the one that
+        steps: a decode program in flight is booked by the steps that
+        follow, and ``has_work`` holds until it is."""
         self.draining = True
+
+    def settle(self) -> None:
+        """Book the decode program in flight NOW, outside a step, on the
+        thread that steps: the scheduler then holds every token the device
+        has made, and the next step runs the synchronous order on it. For
+        what reads or rewrites the host's state WHOLE between two steps: an
+        engine swap's export (``serve/elastic.py``) and a forced publish.
+        Everything else that changes what a step may do is seen by the next
+        step's quiet test, which drains (a drafter or a horizon switched
+        on, a queued request, a deadline), or is ordered behind the program
+        on the device and needs nothing of the host (``gather_pages`` /
+        ``scatter_pages``: the pool's arrays are that program's outputs).
+        Requests that the booking finished (an eos) are returned by the
+        next ``step``, or taken by ``take_settled``; ``has_work`` holds
+        until then."""
+        if self._inflight is not None:
+            fin = self._book_inflight()
+            self._lat.note(fin)
+            self._settled.extend(fin)
+
+    def take_settled(self) -> list[RequestResult]:
+        """The finished requests ``settle`` holds, handed over once."""
+        settled, self._settled = self._settled, []
+        return settled
 
     def set_speculation(self, on: bool) -> bool:
         """Turn speculative decoding on/off at an iteration boundary —
@@ -1987,9 +2074,10 @@ class ServeEngine(DecodeArrays):
         mid-stream BECAUSE the horizon is observation granularity, not
         semantics: position-keyed sampling makes the K-step stream
         token-identical to K single steps, so in-flight sequences
-        continue bitwise across the change. Any in-flight block finishes
-        booking under its own dispatched K; admission margins follow the
-        new K immediately. Returns the horizon now in force."""
+        continue bitwise across the change. Any in-flight block (or plain
+        program) finishes booking under its own dispatched K, in the next
+        step, which drains; admission margins follow the new K
+        immediately. Returns the horizon now in force."""
         if k < 1:
             raise ValueError(f"decode_horizon must be >= 1, got {k}")
         if k > 1 and (self.drafter is not None
@@ -2026,6 +2114,7 @@ class ServeEngine(DecodeArrays):
                 f"breaks bitwise replay for them (preemption/resubmit "
                 f"would rewrite history under new weights) — finish or "
                 f"drain first, or pass force=True to accept that")
+        self.settle()   # forced mid-stream: what is in flight ran the old
         return self.programs.publish_params(new_params)
 
     def publish_adapter(self, adapter_params, *,
@@ -2051,6 +2140,7 @@ class ServeEngine(DecodeArrays):
                 f"{len(self.scheduler.active_indices()) + len(self.scheduler.prefilling_indices())} "
                 f"resident sequences in flight — finish or drain first, "
                 f"or pass force=True to accept mid-stream adapter churn")
+        self.settle()
         slot_id = self.programs.publish_adapter(adapter_params, name=name,
                                                 slot=slot)
         if self.scheduler.cache is not None:
@@ -2071,7 +2161,11 @@ class ServeEngine(DecodeArrays):
 
     @property
     def has_work(self) -> bool:
-        return self.scheduler.has_work
+        """Queued or resident sequences, a program in flight (its lanes'
+        requests may all have ended by an eos the host has yet to read), or
+        a finished request ``settle`` holds for the next step."""
+        return (self.scheduler.has_work or self._inflight is not None
+                or bool(self._settled))
 
     def kv_cache_bytes(self) -> int:
         """Resident KV bytes — scales with the page pool, NOT with
@@ -2132,17 +2226,78 @@ class ServeEngine(DecodeArrays):
                             for i in sched.active_indices()))
 
     def _pipeline_steady(self) -> bool:
-        """Whether the NEXT horizon may dispatch before the pending one
-        is booked — i.e. no scheduler event can need the host state the
-        pending block carries: nothing queued (admission), no prefill in
-        flight, and no deadline due (expiry stays a boundary event).
-        Finishes hiding in the pending block are fine: their lanes are
-        already dead on device, and booking them after the dispatch
-        frees their pages for the NEXT boundary."""
+        """Whether the NEXT decode program, plain or horizon, may be
+        enqueued before the pending one is booked — i.e. no scheduler event
+        can need the host state the pending tokens carry: slots are
+        decoding, nothing queued (admission), no prefill in flight, no
+        deadline due (expiry stays a boundary event), no drafter (what it
+        proposes comes from the host's tokens) and no slot mid-replay (it
+        consumes recorded tokens, from the host)."""
         sched = self.scheduler
-        return (not sched.queue and not self._pending
+        active = sched.active_indices()
+        return (bool(active) and self.drafter is None
+                and not sched.queue and not self._pending
                 and not sched.prefilling_indices()
+                and not any(sched.slots[i].replaying for i in active)
                 and not sched.deadline_due())
+
+    def _ahead(self, pending_k: int, first: list = (),
+               resident: Optional[str] = None) -> Optional[int]:
+        """The QUIET test, one for both decode programs. ``pending_k``
+        device steps are not booked yet: a program in flight, whose
+        ``resident`` arrays the one after it needs as they stand on the
+        device, tables apart (the host's tokens and lengths are ``pending_k``
+        behind: a whole set cannot go up from them); or the one this step is
+        about to enqueue (``first``: its slots whose first token is still on
+        the device). Returns how many steps the program AFTER them may run,
+        enqueued before they are read, or None: the pending tokens are then
+        read first, and the boundary runs on authoritative host state.
+
+        It may where the pipeline is steady (``_pipeline_steady``) and the
+        pages of its writes fit without preempting: they are reserved here,
+        ``pending_k`` writes past the host's lengths, which lag the
+        device's by as much (``reserve_ahead``). A horizon masks a lane that
+        ends inside the pending block in-device (finishes hiding there are
+        fine: booking them after the dispatch frees their pages for the
+        NEXT boundary) and is only clamped to the largest budget left; the
+        plain program masks nothing, so a budget that ends with a pending
+        token is a boundary (an eos cannot be known: ``book_inflight`` has
+        the rule for that one lane)."""
+        sched = self.scheduler
+        if not self._pipeline_steady():
+            return None
+        if self.decode_horizon == 1 and sched.min_remaining_budget(
+                {adm.slot_idx for adm, _ in first}) <= pending_k:
+            return None
+        covered = self.reserve_ahead(sched, pending_k + self.decode_horizon)
+        if resident is not None and self._dev["kind"] != resident:
+            # the arrays went: a lane left and they name it still (or the
+            # reservation's growth dropped them, which stale_tables never
+            # does); the pages just taken arrive early
+            return None
+        # clamp by the largest remaining budget MINUS the steps already
+        # pending: when they provably finish every slot, k drops below 1
+        # and the step drains instead of burning an all-dead trailing
+        # horizon
+        k = min(covered - pending_k, self.decode_horizon,
+                sched.max_remaining_budget() - pending_k)
+        return k if k >= 1 else None
+
+    def _book_inflight(self, behind: Optional[dict] = None) -> list:
+        """Read and book the program in flight; ``behind``, the one this
+        step enqueued after it (or None), takes its place. The arrays on
+        the device go where they name a lane that is gone: a plain program
+        whose booking finished a request (the one enqueued behind it ran
+        that lane once more, ``book_inflight``), a horizon that drains."""
+        pending, self._inflight = self._inflight, behind
+        fin, emitted = book_inflight(self.programs, self.scheduler, pending)
+        self.decode_tokens += emitted
+        if pending["kind"] == "plain":
+            if fin:
+                self.drop_dev("left")
+        elif behind is None:
+            self.drop_dev("drained")
+        return fin
 
     def _note_dispatch(self, k: int) -> None:
         self.host_dispatches += 1
@@ -2150,35 +2305,74 @@ class ServeEngine(DecodeArrays):
         self.decode_steps += k
 
     def step(self) -> list[RequestResult]:
-        """One scheduler iteration: expire deadlines (clean eviction at
-        the boundary), admit whatever now fits (sharing cached prefixes),
-        advance prefill work (one chunk-budget's worth), grow the decoding
-        slots (preempting the cheapest on true exhaustion), then
-        ONE batched decode over the decoding
-        slots — a single step at decode_horizon=1, a fused K-step
-        horizon program otherwise. Returns finished requests.
+        """One scheduler iteration, which books ONE decode token for every
+        decoding slot (a horizon: one block) and returns what finished. It
+        takes one of two orders, by what it can observe and no knob.
 
-        A step that COMPLETES a prefill on the plain path dispatches its
-        two programs back to back and reads the host once: the chunk
-        program is enqueued; while it runs the first token is sampled and
-        seated on the device, the slots grow, the decode arrays are built
-        and go up and the decode program is enqueued behind the chunk; only
-        then does the host read, the first token and then the decode's
-        tokens (``_on_prefill_complete`` has the paths that keep the older
-        order, the first token read before anything else; ``stats()``
-        counts both kinds, ``chunk_steps`` / ``chunk_steps_overlapped``).
+        SYNCHRONOUS, with nothing in flight at its start: expire deadlines
+        (clean eviction at the boundary), admit whatever now fits (sharing
+        cached prefixes), advance prefill work (one chunk-budget's worth),
+        grow the decoding slots (preempting the cheapest on true
+        exhaustion), then ONE batched decode over the decoding slots,
+        enqueued and read in this step (a fused K-step horizon at
+        ``decode_horizon > 1`` is enqueued and left in flight).
 
-        With a horizon the dispatch is DOUBLE-BUFFERED: in the steady
-        state (nothing queued, no prefill, no deadline due) this method
-        dispatches horizon h first and only then blocks on h−1's token
-        block to book it — the device computes h while the host runs
-        record_token/EOS/streaming bookkeeping for h−1, so host work
-        overlaps device compute instead of serializing with it. Any
-        scheduler event (admission, prefill, deadline, preemption,
-        replay, a horizon the pool can't pre-reserve) DRAINS the
-        pipeline first: the block books synchronously and the boundary
-        runs on authoritative host state. Finished results therefore
-        surface at most one step after their tokens were computed."""
+        A synchronous step that COMPLETES a prefill on the plain path
+        dispatches its two programs back to back and reads the host once:
+        the chunk program is enqueued; while it runs the first token is
+        sampled and seated on the device, the slots grow, the decode
+        arrays are built and go up and the decode program is enqueued
+        behind the chunk; only then does the host read, the first token and
+        then the decode's tokens (``_on_prefill_complete`` has the paths
+        that keep the older order, the first token read before anything
+        else; ``stats()`` counts both kinds, ``chunk_steps`` /
+        ``chunk_steps_overlapped``).
+
+        PIPELINED, with a decode program D(n) in flight at its start and
+        the step QUIET (``_ahead``: slots decoding, nothing queued, no
+        prefill pending, no deadline due, no drafter, no replaying slot, no
+        budget that ends with token n, and the pages of write n+1 fit
+        without preempting): the pages of write n+1 are reserved, the block
+        tables alone go up if they grew, D(n+1) is enqueued on the tokens
+        and lengths D(n) leaves on the device, and THEN the host waits on
+        D(n) and books it. D(n+1) runs while the host books, returns to its
+        caller and is called again: the round trip, the booking and the
+        caller's own bookkeeping cost the device nothing
+        (``decode_steps_pipelined`` counts these steps, ``serve.step``'s
+        ``pipelined`` marks them, ``serve.wait``'s ``waits_for`` names the
+        step that enqueued what was read). A synchronous plain step that
+        ends quiet ENTERS the pipeline: it enqueues D(n) and D(n+1) under
+        its one ``serve.dispatch`` and reads D(n) alone.
+
+        With a program in flight and the step NOT quiet, the plain pipeline
+        DRAINS: D(n) is waited on and booked, and the step returns; the
+        boundary (expiry, admission, a chunk, preempting growth, a
+        budget's end) runs in the next step, synchronous, on authoritative
+        host state. A horizon's drain books its block and goes on into the
+        boundary in the same step, as it has no token of this step's to
+        book twice. Finished results therefore surface at most one step
+        after their tokens were computed.
+
+        One lane too many: a token n that ends its request by EOS means
+        D(n+1) ran that lane once more. Its token is not booked (lanes are
+        matched by request id), and its write went to a page and a state
+        block that the slot owned and has freed, which no later program
+        reads before it writes them (whatever the pool re-issues is written
+        by a program enqueued after D(n+1); a state block's next owner
+        starts from zeros). The arrays on the device are dropped, so the
+        next step drains. What booking n RELEASES is what D(n+1) does not
+        read: a window layer's page is released by the host's length, which
+        is D(n+1)'s own (one behind the device's once D(n+1) has run: late,
+        never early).
+
+        What needs the host's state whole: a drafter or a horizon switched
+        on, a queued request, a deadline and a lane that left are seen by
+        the next step's quiet test, which drains; an engine swap and a
+        forced publish book what is in flight at once, outside a step
+        (``settle``); ``drain`` stays a flag (another thread may call it)
+        and ``gather_pages`` / ``scatter_pages`` are ordered behind the
+        program on the device. ``partial_tokens`` is what has been BOOKED,
+        and ``has_work`` holds while a program is in flight."""
         if getattr(self, "_publish_pending_swap", False):
             raise RuntimeError(
                 "new_generation(params=...) already published the next "
@@ -2188,51 +2382,50 @@ class ServeEngine(DecodeArrays):
                 "mixed-policy tokens; run the swap (or build the new "
                 "generation without params=)")
         self.stats_seq += 1
+        settled = self.take_settled()
         # the whole iteration as one host span; its children (expire,
         # restore, admit, fork, prefill, sample, reserve, build, dispatch,
         # wait, book) are emitted where that work happens
         with span("serve.step", seq=self.stats_seq) as sp:
             cpu0 = time.thread_time()
             overlapped = self.chunk_steps_overlapped
+            pipelined = self.decode_steps_pipelined
             finished = self._iterate()
             sp.set_metadata(
                 cpu_ms=1e3 * (time.thread_time() - cpu0),
-                overlapped=self.chunk_steps_overlapped - overlapped)
-            return finished
+                overlapped=self.chunk_steps_overlapped - overlapped,
+                pipelined=self.decode_steps_pipelined - pipelined)
+            return settled + finished
 
     def _iterate(self) -> list[RequestResult]:
         finished = []
         sched = self.scheduler
         if self._inflight is not None:
-            if (self._horizon_ready() and self._pipeline_steady()
-                    and self._dev["kind"] == "horizon"
-                    and sched.active_indices()):
-                pending_k = self._inflight["k"]
-                cov = sched.reserve_horizon(
-                    pending_k + self.decode_horizon)
-                # clamp by the largest remaining budget MINUS the steps
-                # already in flight: when the pending block provably
-                # finishes every slot, k_new drops below 1 and we drain
-                # instead of burning an all-dead trailing horizon
-                k_new = min(cov - pending_k, self.decode_horizon,
-                            sched.max_remaining_budget() - pending_k)
-                if k_new >= 1:
-                    nxt, self._dev = dispatch_horizon(
-                        self.programs, self.pages, sched, self._dev, k_new)
-                    self._note_dispatch(k_new)
-                    fin, emitted = process_horizon_block(sched,
-                                                         self._inflight)
-                    self._inflight = nxt
-                    self.decode_tokens += emitted
-                    self._lat.note(fin)
-                    return fin
-            # drain: a boundary event needs host state the pending block
-            # still holds — book it now, rebuild device arrays after the
-            # boundary runs
-            fin, emitted = process_horizon_block(sched, self._inflight)
-            self._inflight = None
-            self.drop_dev("drained")
-            self.decode_tokens += emitted
+            kind = self._inflight["kind"]
+            k = None
+            if kind == ("plain" if self.decode_horizon == 1 else "horizon"):
+                k = self._ahead(self._inflight["k"], resident=kind)
+            behind = None
+            if k is not None:
+                if kind == "plain":
+                    (behind,), self._dev = dispatch_decode(
+                        self.programs, self.pages, sched, self._dev,
+                        seq=self.stats_seq)
+                else:
+                    behind, self._dev = dispatch_horizon(
+                        self.programs, self.pages, sched, self._dev, k,
+                        seq=self.stats_seq)
+                self._note_dispatch(k)
+                self.decode_steps_pipelined += 1
+            fin = self._book_inflight(behind)
+            if behind is not None or kind == "plain":
+                # pipelined, or a plain drain: this step's token is booked,
+                # and the boundary is the next step's
+                self._lat.note(fin)
+                return fin
+            # a horizon's drain: a boundary event needs host state the
+            # pending block still held; the device arrays are rebuilt after
+            # the boundary runs
             finished.extend(fin)
         expired = sched.expire_deadlines()
         if expired:
@@ -2300,19 +2493,26 @@ class ServeEngine(DecodeArrays):
                 # reservation only decides how much of K the pool grants,
                 # and the budget clamp keeps the final horizon of a
                 # batch from running steps past every slot's max_new
-                k0 = max(1, min(sched.reserve_horizon(self.decode_horizon),
-                                self.decode_horizon,
-                                sched.max_remaining_budget()))
+                k0 = max(1, min(
+                    self.reserve_ahead(sched, self.decode_horizon),
+                    self.decode_horizon, sched.max_remaining_budget()))
                 self._inflight, self._dev = dispatch_horizon(
-                    self.programs, self.pages, sched, self._dev, k0)
+                    self.programs, self.pages, sched, self._dev, k0,
+                    seq=self.stats_seq)
                 self._note_dispatch(k0)
                 # no blocking read here: the block books next step (or at
                 # the next drain) — the first half of the double buffer
             else:
-                fin, emitted, self._dev = run_decode_iteration(
-                    self.programs, self.pages, sched, self.drafter,
-                    self.spec, self._dev, first)
-                self._note_dispatch(1)
+                # the plain program enters the pipeline where the step ends
+                # quiet: the one after it goes up behind it, unread
+                ahead = self._ahead(1, first) is not None
+                fin, emitted, self._dev, self._inflight = \
+                    run_decode_iteration(
+                        self.programs, self.pages, sched, self.drafter,
+                        self.spec, self._dev, first, seq=self.stats_seq,
+                        ahead=ahead)
+                for _ in range(1 + ahead):
+                    self._note_dispatch(1)
                 self.decode_tokens += emitted
                 finished.extend(fin)
                 if fin:
@@ -2323,13 +2523,17 @@ class ServeEngine(DecodeArrays):
     # ---- host tier plumbing ------------------------------------------------
     def gather_pages(self, page_ids) -> dict:
         """Bitwise host copy of the given pages, every pool leaf (int8
-        payload AND scale rows) — the tier's and the wire's unit."""
+        payload AND scale rows) — the tier's and the wire's unit. A read
+        of the pool alone, ordered on the device behind a decode program
+        in flight (the arrays are its outputs); the scheduler is not
+        touched."""
         return gather_payload(self.pages, list(page_ids))
 
     def scatter_pages(self, page_ids, payload) -> None:
         """Seat a gathered payload back into this engine's pool at the
         given (freshly allocated) page ids. Functional pool update, so
-        the device decode arrays must rebuild."""
+        the device decode arrays must rebuild (with a program in flight
+        the scatter is ordered behind it, and the next step drains)."""
         out = scatter_payload(self.pages, list(page_ids), payload)
         for name in out:
             self.pages[name] = out[name]
@@ -2381,6 +2585,7 @@ class ServeEngine(DecodeArrays):
             "stats_seq": self.stats_seq,
             "preemptions": s.get("preempted", 0),
             "decode_horizon": self.decode_horizon,
+            "decode_steps_pipelined": self.decode_steps_pipelined,
             "draining": self.draining,
             "max_queue": sched.max_queue,
             "queued": len(sched.queue),

@@ -266,12 +266,13 @@ def window_pages_bound(window: int, page_size: int, n_slots: int,
                        chunk: int) -> int:
     """Window-class pages an engine needs so that no reservation can fail:
     the trash page, what every slot holds between two steps (the pages of
-    ``window`` positions, however they straddle), and what ONE prefill chunk
-    holds while it runs (one chunk runs at a time)."""
+    ``window`` positions and of ONE write more, the one a decode program
+    enqueued behind the one in flight makes, however they straddle), and what
+    ONE prefill chunk holds while it runs (one chunk runs at a time)."""
     def most(n_tokens):     # pages that `window - 1 + n_tokens` positions
         return (window + n_tokens - 3) // page_size + 2     # can straddle
 
-    return 1 + n_slots * most(1) + most(chunk)
+    return 1 + n_slots * most(2) + most(chunk)
 
 
 # the state class's pools: the state, a short convolution's last rows, the

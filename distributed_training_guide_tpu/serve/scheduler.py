@@ -1144,32 +1144,40 @@ class Scheduler:
             slot.pages.extend(got)
         return extra
 
-    def reserve_horizon(self, want: int) -> int:
-        """Worst-case page reservation for a fused decode horizon: extend
-        every active slot's pages to cover up to ``want`` decode writes
-        past its current cache_len, so the K-step device loop NEVER
-        needs a mid-horizon host allocation. Opportunistic like
-        ``ensure_lookahead`` — allocation failure (after cache-eviction
-        pressure) SHORTENS the horizon instead of preempting; the
-        mandatory single next write stays ``grow_for_decode``'s job with
-        its refuse-or-preempt discipline.
+    def reserve_horizon(self, want: int) -> tuple[int, int]:
+        """Worst-case page reservation for decode steps the host does not
+        attend: extend every active slot's pages to cover up to ``want``
+        decode writes past its current cache_len, so that neither a fused
+        K-step device loop nor a single-token program enqueued behind one
+        still in flight (``want = 2``: the host's lengths are then one
+        token behind the device's) needs a host allocation in between.
+        Opportunistic like ``ensure_lookahead`` — allocation failure (after
+        cache-eviction pressure) SHORTENS what is covered instead of
+        preempting; the mandatory single next write stays
+        ``grow_for_decode``'s job with its refuse-or-preempt discipline. A
+        window page class gives the pages those writes need as well
+        (``reserve_window``; it is sized so that it cannot fail).
 
-        Returns the number of writes covered for EVERY active slot — the
-        horizon the engine may run unattended. A slot whose own
-        remaining budget ``r < want`` only needs ``r`` pages' worth (its
-        lane goes dead in-device after r tokens), so a nearly-finished
-        request never clamps the batch's horizon below what its budget
-        already guarantees. Pages granted for a horizon that later
-        shortens simply arrive early — the next horizon's writes land in
-        them (no un-grow, same as speculation's lookahead)."""
+        Returns ``(covered, grown)``: the number of writes covered for
+        EVERY active slot — the steps the engine may run unattended — and
+        how many pages were taken, of both classes (any at all: the block
+        tables on the device are stale). A slot whose own remaining budget
+        ``r < want`` only needs ``r`` pages' worth (a horizon's lane goes
+        dead in-device after r tokens), so a nearly-finished request never
+        clamps the batch's horizon below what its budget already
+        guarantees. Pages granted for a horizon that later shortens simply
+        arrive early — the next writes land in them (no un-grow, same as
+        speculation's lookahead)."""
         if want < 1:
             raise ValueError(f"horizon must be >= 1, got {want}")
-        with span("serve.reserve"):
-            return self._reserve_horizon(want)
+        with span("serve.reserve") as sp:
+            covered, grown = self._reserve_horizon(want)
+            sp.set_metadata(grown=grown)
+        return covered, grown
 
-    def _reserve_horizon(self, want: int) -> int:
+    def _reserve_horizon(self, want: int) -> tuple[int, int]:
         page = self.pool.page_size
-        covered = want
+        covered, grown = want, 0
         for slot_idx in self.active_indices():
             slot = self.slots[slot_idx]
             r = max(1, slot.request.max_new_tokens - len(slot.generated))
@@ -1179,11 +1187,15 @@ class Scheduler:
                 if got is None:
                     break
                 slot.pages.extend(got)
+                grown += 1
             can = len(slot.pages) * page - slot.cache_len
+            if self.window is not None:
+                grown += self.reserve_window(slot_idx, slot.cache_len,
+                                             min(need, can))
             if can >= r:
                 continue            # budget dies before the pages run out
             covered = min(covered, can)
-        return max(0, min(covered, want))
+        return max(0, min(covered, want)), grown
 
     def max_remaining_budget(self) -> int:
         """The largest remaining token budget over active slots — the
@@ -1199,6 +1211,17 @@ class Scheduler:
             rem = max(rem,
                       slot.request.max_new_tokens - len(slot.generated))
         return rem
+
+    def min_remaining_budget(self, unbooked=()) -> int:
+        """The smallest remaining token budget over active slots: how many
+        single-token steps may run before SOME lane's request ends by its
+        length, which the plain decode program does not mask in-device (a
+        horizon does). ``unbooked``: slots that hold one token more than
+        the host has recorded (a first token sampled and still on the
+        device)."""
+        return min((self.slots[i].request.max_new_tokens
+                    - len(self.slots[i].generated) - (i in unbooked)
+                    for i in self.active_indices()), default=0)
 
     # ---- decode bookkeeping ------------------------------------------------
     def record_token(self, slot_idx: int, token: int, *,

@@ -24,7 +24,18 @@ long step says anything) and ``overlapped`` (1 where the step completed a
 prefill and dispatched its decode BEFORE it read the first token, so the
 chunk program and the decode program ran back to back: such a step has a
 ``serve.sample`` in front of its ``serve.dispatch``, the sampler's launch,
-and one behind it, the token's read; 0 in every other step), and ``gc``
+and one behind it, the token's read; 0 in every other step) and
+``pipelined`` (1 where the step enqueued its decode program BEFORE it read
+the one in flight from the step before, ``ServeEngine.step``'s pipelined
+order: its ``serve.dispatch`` ends before its ``serve.wait`` starts as in
+every step, but the program that ran under the wait is the step before's; 0
+in every other step), ``serve.wait`` carries ``waits_for`` (the ``seq`` of
+the ``serve.step`` that enqueued the program it read: its own step's, or the
+one before in a pipelined step and in one that drains; a reader that joins a
+wait with a device program follows this, not the dispatch beside it),
+``serve.dispatch`` carries ``program`` and, for the plain decode program,
+``programs`` (2 where a synchronous step entered the pipeline and enqueued
+the next step's program behind its own, else 1), and ``gc``
 carries ``generation`` and ``collected`` (:func:`install_gc_span`: one span
 over each garbage collection).
 
@@ -61,8 +72,9 @@ SPANS = (
 # built any yet; `kind`: resident, but another decode program's set (plain /
 # spec / horizon). Two reasons mean the same ONE transfer, the block tables
 # alone, for different causes: `grown` (`grow_for_decode` gave a slot the page
-# of its next write) and `lookahead` (a speculation or horizon reservation
-# gave it pages ahead of that). Every other build carries the whole set;
+# of its next write) and `lookahead` (a reservation gave it pages ahead of
+# that: speculation's, a horizon's, or the one write ahead of a plain program
+# enqueued behind the one in flight). Every other build carries the whole set;
 # `serve.upload`'s `arrays` tells the two apart
 REBUILD_REASONS = (
     "first", "grown", "preempted", "admitted", "prefilled", "left", "expired",

@@ -1,0 +1,577 @@
+"""The plain decode step's two orders (``ServeEngine.step``): with a decode
+program in flight and the step quiet, the program for token n+1 is enqueued
+BEFORE the host reads token n; everything else reads first and runs the
+boundary in the next step. What the order may not change is a single token
+or, without an eos, the step in which anything is booked or admitted; what
+rides on it is the shape of a step's spans, which
+``benchmarks/readers/step_waterfall.py`` cuts, and one counter.
+
+Every case is held to the SYNCHRONOUS order, forced in the test (an engine
+whose quiet test never passes: the parent's step, link for link), one request
+at a time through one slot where the case says batch-1.
+"""
+import contextlib
+import dataclasses
+import sys
+import time
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_training_guide_tpu.models import get_model
+from distributed_training_guide_tpu.serve import Request, ServeEngine
+from distributed_training_guide_tpu.serve import engine as engine_mod
+from distributed_training_guide_tpu.serve import scheduler as scheduler_mod
+from distributed_training_guide_tpu.serve.engine import ModelPrograms
+from distributed_training_guide_tpu.serve.kv_pages import (pool_audit,
+                                                           window_page_span)
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from benchmarks.readers import program_span, step_waterfall  # noqa: E402
+
+pytestmark = pytest.mark.serve
+
+PAGE, CHUNK, MAX_LEN = 8, 16, 96
+
+# llama serves with the prefix cache on (its default); the state-class and
+# the two-class families refuse it
+FAMILIES = {"llama": "llama-debug", "jamba": "jamba-debug",
+            "mimo": "mimo-v2-debug"}
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """One program cache a family for every engine of the module: the order
+    under test is the host's, and the compiles are the slow part."""
+    made = {}
+
+    def of(family):
+        if family not in made:
+            bundle = get_model(FAMILIES[family], dtype=jnp.float32)
+            made[family] = ModelPrograms(
+                bundle, bundle.init(bundle.config, jax.random.key(0)))
+        return made[family]
+    return of
+
+
+def engine_of(progs, synchronous=False, **kw):
+    kw = {"n_slots": 3, "page_size": PAGE, "max_len": MAX_LEN,
+          "prefill_chunk": CHUNK, **kw}
+    eng = ServeEngine(progs.bundle, progs.params, programs=progs, **kw)
+    if synchronous:     # the parent's order: no step is ever quiet
+        eng._ahead = lambda pending_k, first=(): None
+    return eng
+
+
+def prompt_of(n, start=3):
+    return [start + (5 * i) % 90 for i in range(n)]
+
+
+def request(n_prompt, n_new, i=0, **kw):
+    """Request ``i``: greedy where ``i`` is even, seeded otherwise."""
+    sampling = {} if i % 2 == 0 else {
+        "temperature": 0.8, "top_k": 40, "top_p": 0.9, "seed": 11 + i}
+    return Request(prompt_ids=prompt_of(n_prompt, 3 + 7 * i),
+                   max_new_tokens=n_new, **sampling, **kw)
+
+
+def audit(eng):
+    sched = eng.scheduler
+    holders = [{}]
+    for s in sched.slots:
+        if s is not None:
+            for p in s.pages:
+                holders[0][p] = holders[0].get(p, 0) + 1
+    if sched.cache is not None:
+        cached, stack = {}, list(sched.cache._roots.values())
+        while stack:
+            node = stack.pop()
+            stack.extend(node.children.values())
+            if node.page is not None:
+                cached[node.page] = 1
+        holders.append(cached)
+    pool_audit(sched.pool, holders,
+               window_holder_maps=(None if sched.window is None
+                                   else [sched.window_holders()]),
+               state_holder_maps=(None if sched.pool.state is None
+                                  else [sched.state_holders()]))
+
+
+def run(eng, reqs, clients=None, on_step=None):
+    """Drive ``eng`` as the benchmark's closed loop does: ``clients``
+    requests in flight, the next one submitted when one finishes. Returns
+    the results in request order and a row a step: ``(tokens booked by
+    request, requests admitted, pipelined)``."""
+    todo = [dataclasses.replace(r) for r in reqs]
+    clients = len(todo) if clients is None else clients
+    order, done, rows = [], {}, []
+    have: dict[int, int] = {}
+    live = 0
+    for _ in range(3000):
+        while todo and live < clients:
+            order.append(eng.submit(todo.pop(0)))
+            live += 1
+        if not eng.has_work:
+            break
+        admitted = eng.scheduler.stats["admitted"]
+        pipelined = eng.decode_steps_pipelined
+        finished = eng.step()
+        now = {rid: len(t) for rid, t in eng.partial_tokens().items()}
+        for res in finished:
+            now[res.request_id] = len(res.generated_ids)
+            done[res.request_id] = res
+            live -= 1
+        booked = {order.index(rid): n - have.get(rid, 0)
+                  for rid, n in now.items() if n != have.get(rid, 0)}
+        have.update(now)
+        rows.append((booked, eng.scheduler.stats["admitted"] - admitted,
+                     eng.decode_steps_pipelined - pipelined))
+        audit(eng)
+        if on_step is not None:
+            on_step(eng, finished)
+    assert not eng.has_work and not todo
+    return [done[rid] for rid in order], rows
+
+
+def one_token_a_step(rows):
+    """No step books two decode tokens of one slot, or none for a slot that
+    was decoding: a request that had a token before a step has exactly one
+    more after it, until it is done (its first step books its first token
+    and, where its prefill completed there, the decode's beside it)."""
+    seen: dict[int, int] = {}
+    for booked, _, _ in rows:
+        for i in seen:
+            if i not in booked:
+                seen[i] = -1            # done: never booked again
+        for i, n in booked.items():
+            assert seen.get(i, 0) >= 0, (i, "booked after it was done")
+            assert n == 1 if seen.get(i) else n in (1, 2), (i, n)
+            seen[i] = seen.get(i, 0) + n
+    live_rows = [set(b) for b, _, _ in rows]
+    for i in seen:      # booked in every step from its first to its last
+        at = [j for j, b in enumerate(live_rows) if i in b]
+        assert at == list(range(at[0], at[-1] + 1)), (i, at)
+
+
+def batch1(progs, reqs):
+    """One request at a time through one slot, in the synchronous order."""
+    ref = engine_of(progs, synchronous=True, n_slots=1)
+    out, _ = run(ref, reqs, clients=1)
+    assert ref.stats()["decode_steps_pipelined"] == 0
+    return out
+
+
+def same_tokens(got, want, reqs):
+    for g, w, r in zip(got, want, reqs):
+        assert g.generated_ids == w.generated_ids, r
+        assert g.finish_reason == w.finish_reason, r
+
+
+# ---- (a) (d) a quiet run ----------------------------------------------------
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_quiet_run_is_pipelined_and_changes_no_token(programs, family):
+    """Three replies of 44 tokens decode side by side across five page
+    boundaries each with nothing queued: from the step that completes the
+    last prefill to the one that ends the first reply, every step enqueues
+    ahead, and every token is the one-at-a-time engine's."""
+    progs = programs(family)
+    reqs = [request(5 + 4 * i, 44, i) for i in range(3)]
+    eng = engine_of(progs)
+    got, rows = run(eng, reqs)
+    same_tokens(got, batch1(progs, reqs), reqs)
+    stats = eng.stats()
+    # one prefill completes a step: steps 1 and 2 have prefills pending,
+    # step 3 enters, steps 4 to 42 are pipelined, step 43 reads the first
+    # reply's last token (its budget's end, known a step ahead: a drain),
+    # and the two steps left each end a reply
+    assert [p for *_, p in rows] == [0] * 3 + [1] * 39 + [0] * 3
+    assert stats["decode_steps_pipelined"] == 39
+    # a decode program a booked token of each slot, and none beside them
+    assert stats["decode_steps"] == 45
+    assert stats["decode_tokens"] == 3 * 43
+    one_token_a_step(rows)
+    assert eng._inflight is None and eng._first == []
+    sched = eng.scheduler
+    assert sched.pool.n_free + sched.cache_pages_held() == sched.pool.capacity
+
+
+# ---- (b) (d) budgets that end at different steps -----------------------------
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_budgets_end_at_different_steps_and_the_schedule_is_the_parents(
+        programs, family):
+    """A closed loop of three clients over nine replies of 5 to 21 tokens:
+    the step in which each token is booked and each request admitted is the
+    synchronous order's, step for step, and no step books two decode tokens
+    of one slot or none."""
+    progs = programs(family)
+    reqs = [request(3 + (5 * i) % 17, 5 + (7 * i) % 17, i) for i in range(9)]
+    eng = engine_of(progs)
+    got, rows = run(eng, reqs, clients=3)
+    sync = engine_of(progs, synchronous=True)
+    want, sync_rows = run(sync, reqs, clients=3)
+    same_tokens(got, want, reqs)
+    same_tokens(got, batch1(progs, reqs), reqs)
+    assert [(b, a) for b, a, _ in rows] == [(b, a) for b, a, _ in sync_rows]
+    one_token_a_step(rows)
+    one_token_a_step(sync_rows)
+    assert all(r.finish_reason == "length" for r in got)
+    stats = eng.stats()
+    assert 0 < stats["decode_steps_pipelined"] < stats["decode_steps"]
+    assert sync.stats()["decode_steps_pipelined"] == 0
+    # a budget's end is a boundary, known a step ahead: no program ever ran
+    # a lane too many, so both engines ran the same number
+    assert stats["decode_steps"] == sync.stats()["decode_steps"]
+    assert stats["decode_tokens"] == sync.stats()["decode_tokens"]
+
+
+# ---- (c) (d) an eos that arrives mid-pipeline --------------------------------
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_an_eos_mid_pipeline_books_nothing_of_the_lane_too_many(
+        programs, family):
+    """Two replies end by an eos that the host reads while the program for
+    the token after it is already enqueued: that lane's token is not booked,
+    the pages and the state block the slot freed go to the next admission,
+    and every stream is the one-at-a-time engine's."""
+    progs = programs(family)
+    plain = [request(6 + 3 * i, 30, i) for i in range(5)]
+    free_run = batch1(progs, plain)
+    # the eos of the two seeded requests: a token of the free-running reply,
+    # eight or more in, that did not occur earlier in it
+    reqs, ends = list(plain), {}
+    for i in (1, 3):
+        tokens = free_run[i].generated_ids
+        at = next(j for j in range(8 + i, 28) if tokens[j] not in tokens[:j])
+        reqs[i] = dataclasses.replace(plain[i], eos_id=tokens[at])
+        ends[i] = at + 1
+    eng = engine_of(progs, n_slots=2)
+    behind = []
+
+    def on_step(eng, finished):
+        for res in finished:
+            if res.finish_reason == "eos":
+                # the program after the eos is in flight, that lane in it
+                behind.append(eng._inflight is not None)
+    got, rows = run(eng, reqs, clients=2, on_step=on_step)
+    same_tokens(got, batch1(progs, reqs), reqs)
+    assert [r.finish_reason for r in got] == [
+        "eos" if i in ends else "length" for i in range(5)]
+    assert {i: len(got[i].generated_ids) for i in ends} == ends
+    assert len(behind) == 2 and any(behind), behind
+    one_token_a_step(rows)
+    stats = eng.stats()
+    # a program or two ran a lane too many; their tokens are in no count
+    assert stats["decode_tokens"] == sum(len(r.generated_ids) - 1 for r in got)
+    assert stats["decode_steps_pipelined"] > 0
+    assert eng._inflight is None
+    sched = eng.scheduler
+    assert sched.pool.n_free + sched.cache_pages_held() == sched.pool.capacity
+    if sched.pool.state is not None:
+        assert sched.stats["state_blocks_taken"] == 5 \
+            == sched.stats["state_blocks_returned"]
+
+
+# ---- what is released at booking is not what the enqueued program reads ------
+
+def test_a_window_page_released_at_booking_is_none_the_next_program_reads(
+        programs, monkeypatch):
+    """The window class (pages of 8 under a window of 12): every decode
+    program, when it is enqueued and again after the step has booked and
+    released, finds the pages its query sees held by its slot and off the
+    free list. The host's length at a booking is the enqueued program's own:
+    a release reckoned from it is late for the device, never early."""
+    progs = programs("mimo")
+    eng = engine_of(progs)
+    sched = eng.scheduler
+    window, n_full = sched.window, sched.max_pages
+    decode = progs._decode_fn
+    inflight, checked = [], [0, 0]
+
+    def reads(tables, lengths):
+        """``{slot: physical window pages}`` a program's queries see."""
+        out = {}
+        for i in sched.active_indices():
+            span = window_page_span(int(lengths[i]), 1, window, PAGE)
+            out[i] = [int(tables[i, n_full + p]) for p in span]
+        return out
+
+    def held(pages_by_slot):
+        for i, pages in pages_by_slot.items():
+            slot = sched.slots[i]
+            if slot is None:            # left: its lane ran for nothing
+                continue
+            assert 0 not in pages, (i, pages)
+            assert set(pages) <= set(slot.window_pages.values()), (i, pages)
+            assert not set(pages) & sched.pool.window._free_set, (i, pages)
+
+    def checked_decode(params, pools, tokens, lengths, tables, *rest):
+        pages = reads(np.asarray(tables), np.asarray(lengths))
+        held(pages)
+        inflight.append(pages)
+        checked[0] += 1
+        return decode(params, pools, tokens, lengths, tables, *rest)
+
+    def on_step(eng, finished):
+        if eng._inflight is not None:   # after this step's release
+            held(inflight[-1])
+            checked[1] += 1
+    monkeypatch.setattr(progs, "_decode_fn", checked_decode)
+    reqs = [request(9 + 5 * i, 40, i) for i in range(3)]
+    got, _ = run(eng, reqs, on_step=on_step)
+    monkeypatch.undo()
+    same_tokens(got, batch1(progs, reqs), reqs)
+    assert sched.stats["window_pages_released"] >= 9
+    assert checked[0] >= 40 and checked[1] >= 30
+    assert eng.stats()["decode_steps_pipelined"] >= 30
+
+
+# ---- (e) memory pressure ------------------------------------------------------
+
+@pytest.mark.parametrize("family", ["llama", "jamba"])
+def test_growth_that_would_preempt_drains_first(programs, family,
+                                                monkeypatch):
+    """A pool the replies outgrow: the page of write n+1 is not there, so
+    the step is not quiet, the token in flight is booked, and growth
+    preempts in the next step, on the host's state whole: the victim goes
+    back with every token it has, and every stream is batch-1's."""
+    progs = programs(family)
+    reqs = [request(4 + i, 30 + i, i) for i in range(3)]
+    eng = engine_of(progs, n_pages=9, max_len=48)
+    preempt = eng.scheduler.preempt
+    victims = []
+
+    def checked(slot_idx):
+        assert eng._inflight is None, "preempted under a program in flight"
+        victims.append(len(eng.scheduler.slots[slot_idx].generated))
+        preempt(slot_idx)
+    monkeypatch.setattr(eng.scheduler, "preempt", checked)
+    got, rows = run(eng, reqs)
+    same_tokens(got, batch1(progs, reqs), reqs)
+    stats = eng.stats()
+    assert stats["preemptions"] > 0 and any(victims)
+    assert stats["decode_steps_pipelined"] > 0
+    sched = eng.scheduler
+    assert sched.pool.n_free + sched.cache_pages_held() == sched.pool.capacity
+
+
+# ---- (f) what needs the host's state whole ------------------------------------
+
+def act_publish(eng, progs):
+    """A forced mid-stream publish books what is in flight at once."""
+    eng.publish_params(jax.tree.map(jnp.copy, progs.params), force=True)
+    return eng, "settled"
+
+
+def act_swap(eng, progs):
+    """An engine swap books what is in flight before its export reads the
+    scheduler; the sequences go on in the new generation."""
+    from distributed_training_guide_tpu.serve.elastic import swap_engine
+
+    new, evicted, stats = swap_engine(eng)
+    assert evicted == [] and stats["seated"] + stats["requeued"] == 2
+    assert not eng.has_work
+    return new, "settled"
+
+
+def act_drain(eng, progs):
+    """A flag (another thread may set it): the steps go on as they were."""
+    eng.drain()
+    assert eng.draining
+    return eng, "flying"
+
+
+def act_gather(eng, progs):
+    """A read of the pool, ordered behind the program on the device."""
+    slot = next(s for s in eng.scheduler.slots if s is not None)
+    assert eng.gather_pages(slot.pages[:1])
+    return eng, "flying"
+
+
+def act_publish_refused(eng, progs):
+    with pytest.raises(RuntimeError, match="in flight"):
+        eng.publish_params(jax.tree.map(jnp.copy, progs.params))
+    return eng, "flying"
+
+
+def act_horizon(eng, progs):
+    """Seen by the next step's quiet test, which drains."""
+    assert eng.set_decode_horizon(2) == 2
+    assert eng._inflight is not None
+    assert eng.step() == [] and eng._inflight is None   # the plain drain
+    return eng, "drained"
+
+
+@pytest.mark.parametrize("act", [act_publish, act_swap, act_drain,
+                                 act_gather, act_publish_refused,
+                                 act_horizon],
+                         ids=lambda a: a.__name__[len("act_"):])
+def test_what_needs_the_host_state_whole_finds_it_whole(programs, act):
+    """``partial_tokens`` is what has been BOOKED, one token a step and
+    request, while a program is in flight. A swap and a forced publish book
+    that program at once, outside a step; a horizon switched on is seen by
+    the next step, which drains; a drain flag, a refused publish and a page
+    gather leave it flying. The streams go on to batch-1's tokens."""
+    progs = programs("llama")
+    reqs = [request(5 + 3 * i, 24, i) for i in range(2)]
+    eng = engine_of(progs)
+    rids = [eng.submit(dataclasses.replace(r)) for r in reqs]
+    for n in range(1, 9):
+        assert eng.step() == []
+        # a prefill completes a step: the first token and n more, and the
+        # second request a step behind
+        assert [len(t) for t in eng.partial_tokens().values()] \
+            == [n + 1, n][:n]
+    assert eng._inflight is not None and eng.has_work
+    steps = eng.stats()["decode_steps"]
+    eng, state = act(eng, progs)
+    booked = [len(t) for t in eng.partial_tokens().values()]
+    if state == "settled":
+        assert eng._inflight is None                # booked, outside a step
+        assert booked == [10, 9]
+        assert eng.stats()["decode_steps"] in (0, steps)    # none enqueued
+    elif state == "flying":
+        assert eng._inflight is not None and booked == [9, 8]
+        assert eng.stats()["decode_steps"] == steps
+    else:
+        assert booked == [10, 9]
+    done = {}
+    while eng.has_work:
+        done.update((r.request_id, r) for r in eng.step())
+    same_tokens([done[rid] for rid in rids], batch1(progs, reqs), reqs)
+
+
+def test_has_work_holds_while_the_last_lane_ran_for_nothing(programs):
+    """The only request ends by an eos read behind an enqueued program: the
+    scheduler is empty, the engine is not, and the next step books nothing
+    and returns nothing; ``settle`` at that instant books it outside a
+    step."""
+    progs = programs("llama")
+    tokens = batch1(progs, [request(6, 20, 1)])[0].generated_ids
+    at = next(j for j in range(6, 18) if tokens[j] not in tokens[:j])
+    req = request(6, 20, 1, eos_id=tokens[at])
+    for settle in (False, True):
+        eng = engine_of(progs)
+        eng.submit(dataclasses.replace(req))
+        # the step of the prefill books two tokens, every other one one
+        (res,) = [r for _ in range(at) for r in eng.step()]
+        assert res.finish_reason == "eos"
+        assert res.generated_ids == tokens[:at + 1]
+        assert not eng.scheduler.has_work and eng.has_work
+        if settle:
+            eng.settle()
+            assert eng.take_settled() == []
+        else:
+            assert eng.step() == []
+        assert not eng.has_work and eng._inflight is None
+        assert eng.stats()["decode_tokens"] == at
+        sched = eng.scheduler
+        assert sched.pool.n_free + sched.cache_pages_held() \
+            == sched.pool.capacity
+
+
+# ---- (g) the order, by the step's own spans -----------------------------------
+
+class _Recorded(contextlib.AbstractContextManager):
+    """``utils.trace.span`` for a test: the span as the readers take it,
+    ``(name, start_ns, end_ns, thread, stats)``, appended when it closes."""
+
+    def __init__(self, into, name, args):
+        self.into, self.name, self.args = into, name, dict(args)
+
+    def __enter__(self):
+        self.start = time.perf_counter_ns()
+        return self
+
+    def set_metadata(self, **args):
+        self.args.update(args)
+
+    def __exit__(self, *exc):
+        self.into.append((self.name, self.start, time.perf_counter_ns(),
+                          "python3", self.args))
+        return False
+
+
+def named_children(children, name):
+    return sorted((c for c in children if c[0] == name), key=lambda c: c[1])
+
+
+def test_a_pipelined_step_dispatches_then_waits_for_the_step_before(
+        programs, monkeypatch):
+    progs = programs("llama")
+    eng = engine_of(progs)
+    reqs = [request(5, 20, 0), request(9, 12, 1), request(7, 16, 2)]
+    run(eng, reqs[:1])                          # compile outside the record
+    before = eng.stats()["decode_steps_pipelined"]
+    spans = []
+    for mod in (engine_mod, scheduler_mod):
+        monkeypatch.setattr(
+            mod, "span", lambda name, **args: _Recorded(spans, name, args))
+    run(eng, reqs, clients=2)
+    steps = program_span.steps_with_children(spans, 0, 2 ** 63)
+    assert sum(s[4]["pipelined"] for s, _ in steps) \
+        == eng.stats()["decode_steps_pipelined"] - before > 10
+    seen = {"pipelined": 0, "entering": 0, "drained": 0, "synchronous": 0}
+    modules = []
+    for step, children in steps:
+        seq = step[4]["seq"]
+        assert step[4]["pipelined"] in (0, 1)
+        dispatches = named_children(children, "serve.dispatch")
+        waits = named_children(children, "serve.wait")
+        # one of each at most, whichever order the step took
+        assert len(dispatches) <= 1 and len(waits) <= 1, seq
+        if not waits:
+            continue                # a step of prefill chunks alone
+        (wait,) = waits
+        (book,) = named_children(children, "serve.book")
+        assert wait[2] <= book[1]
+        if step[4]["pipelined"]:
+            # enqueued BEFORE the read, of what the step before enqueued;
+            # nothing of the boundary in between
+            (dispatch,) = dispatches
+            assert dispatch[4] == {"program": "serve_decode", "programs": 1}
+            assert dispatch[2] <= wait[1]
+            assert wait[4]["waits_for"] == seq - 1
+            assert not {c[0] for c in children} & {
+                "serve.expire", "serve.admit", "serve.prefill",
+                "serve.sample"}
+            builds = named_children(children, "serve.build")
+            assert all(b[4]["reason"] == "lookahead" and b[2] <= dispatch[1]
+                       for b in builds)
+            seen["pipelined"] += 1
+            # a made-up device line: the program in flight was running
+            # when this step enqueued the next, and ran into its wait
+            modules.append(("jit_serve_decode(1)", dispatch[1] - 1000,
+                            wait[2] - 1))
+        elif not dispatches:
+            # a drain: the read of the step before's program, and the step
+            # is over; the boundary is the next step's
+            assert wait[4]["waits_for"] == seq - 1
+            assert {c[0] for c in children} <= {"serve.wait", "serve.book",
+                                                "serve.release",
+                                                "serve.state"}
+            seen["drained"] += 1
+        else:
+            (dispatch,) = dispatches
+            assert dispatch[2] <= wait[1]
+            assert wait[4]["waits_for"] == seq      # its own program
+            ahead = dispatch[4]["programs"] == 2
+            seen["entering" if ahead else "synchronous"] += 1
+    assert seen["pipelined"] > 10 and seen["entering"] >= 2
+    assert seen["drained"] >= 2, seen
+    # the benchmark's reader keeps a pipelined step (one dispatch, one wait
+    # after it) and joins it with the program that was in flight
+    piped = [(s, c) for s, c in steps if s[4]["pipelined"]]
+    joined, skipped = step_waterfall.join(piped, modules)
+    assert skipped == 0 and len(joined) == len(piped)
+    for step, children, dispatch, wait, device, _ in joined:
+        cut = step_waterfall.cut(step, children, dispatch, wait, device)
+        assert sum(cut["phases"].values()) == cut["ns"]
+        assert cut["phases"]["launch"] == 0     # it ran before the dispatch

@@ -208,6 +208,16 @@ def provoke_grown(engine):
     until_resident(engine)
 
 
+def provoke_grown_synchronous(engine):
+    """The same under the SYNCHRONOUS order of a plain step, forced here (no
+    step is quiet): the page of the next write comes from
+    ``grow_for_decode``, in the step that writes it. In the pipelined order,
+    which this session takes by itself, it comes a step earlier, from the
+    reservation for the program enqueued ahead: ``lookahead``."""
+    engine._ahead = lambda pending_k, first=(), resident=None: None
+    provoke_grown(engine)
+
+
 def provoke_admitted(engine):
     """One slot decodes; a second request arrives inside the session."""
     engine.submit(request([3, 17, 42, 5], 14))
@@ -216,8 +226,8 @@ def provoke_admitted(engine):
 
 
 @pytest.mark.parametrize("reason,page_size,provoke", [
-    ("left", 32, provoke_left), ("grown", 8, provoke_grown),
-    ("admitted", 32, provoke_admitted)])
+    ("left", 32, provoke_left), ("grown", 8, provoke_grown_synchronous),
+    ("lookahead", 8, provoke_grown), ("admitted", 32, provoke_admitted)])
 def test_build_says_why_and_what_it_uploaded(debug_model, tmp_path, reason,
                                              page_size, provoke):
     engine = roomy_engine(debug_model, page_size)
@@ -251,14 +261,16 @@ def test_build_says_why_and_what_it_uploaded(debug_model, tmp_path, reason,
     host = engine.scheduler.decode_arrays()
     assert len(host) == 11
     for b, u in zip(builds, uploads):
-        if b[4]["reason"] == "grown":
+        if b[4]["reason"] in ("grown", "lookahead"):
             assert u[4]["arrays"] == 1
             assert u[4]["bytes"] == host["tables"].nbytes
         else:
             assert u[4]["arrays"] == 11
             assert u[4]["bytes"] == sum(v.nbytes for v in host.values())
-    if reason == "grown":       # 4 + 14 tokens cross two pages of 8
-        assert [b[4]["reason"] for b in builds].count("grown") == 2
+    if reason in ("grown", "lookahead"):    # 4 + 14 tokens cross two
+        assert [b[4]["reason"] for b in builds].count(reason) == 2  # pages
+        pipelined = engine.stats()["decode_steps_pipelined"]
+        assert (pipelined > 0) == (reason == "lookahead")
     # the thread's own CPU time on every step, inside its wall time
     steps = by_name("serve.step")
     assert steps and all(
@@ -315,15 +327,18 @@ def test_no_engine_drops_the_decode_arrays_by_assignment():
         for call in re.findall(
                 r"\b(?:drop_dev|no_dev|stale_tables)\(([^)]*)\)", src):
             said |= set(re.findall(r'"(\w+)"', call))
-    # the two that no event causes: the builder finds the arrays resident,
-    # but another program's set, or refreshes the tables of its own
+    # the two that the builder says itself: it finds the arrays resident,
+    # but another program's set, or refreshes the tables of its own after
+    # its caller's reservation (speculation); a reservation for a program
+    # enqueued AHEAD says ``lookahead`` as an event too
+    # (``DecodeArrays.reserve_ahead``: its pages may wait a step for a build)
     import inspect
 
     from distributed_training_guide_tpu.serve import engine
 
     built = set(re.findall(r'"(\w+)"', inspect.getsource(
         engine.upload_decode_arrays).split('"""')[2])) & set(REBUILD_REASONS)
-    assert built == {"kind", "lookahead"} and not built & said
+    assert built == {"kind", "lookahead"} and built & said == {"lookahead"}
     assert said | built == set(REBUILD_REASONS)
     assert len(REBUILD_REASONS) == len(set(REBUILD_REASONS))
 
