@@ -67,7 +67,8 @@ import numpy as np
 
 from ..models.registry import ModelBundle, family_module
 from ..train.precision import Quantized
-from ..utils.trace import install_gc_span, named, span
+from ..utils.trace import (NOT_QUIET, STEP_ORDERS, install_gc_span, named,
+                           span)
 from .adapters import (AdapterPool, DEFAULT_TARGETS, ZERO_ADAPTER,
                        adapter_nbytes, adapter_pool_bytes, adapter_shapes,
                        init_adapter_stacks, validate_adapter_params)
@@ -1937,9 +1938,11 @@ class ServeEngine(DecodeArrays):
         # host-side — see stats())
         self.decode_steps = 0
         self.decode_tokens = 0
-        # steps that enqueued their decode program BEFORE they read the one
-        # in flight (the pipelined order: step())
-        self.decode_steps_pipelined = 0
+        # every step under the order it took, and the step's first failing
+        # quiet test under its cause (step(): what the spans' `order` and
+        # `held_by` say, for the operator who has no profiler session)
+        self.steps_by_order = dict.fromkeys(STEP_ORDERS, 0)
+        self.not_quiet = dict.fromkeys(NOT_QUIET, 0)
         self.host_dispatches = 0
         self.horizon_ksum = 0
         self._lat = LatencyMeter()
@@ -2160,6 +2163,12 @@ class ServeEngine(DecodeArrays):
             self.scheduler.cache.drop_namespace(slot)
 
     @property
+    def decode_steps_pipelined(self) -> int:
+        """Steps that enqueued their decode program BEFORE they read the one
+        in flight: a view of ``steps_by_order``."""
+        return self.steps_by_order["pipelined"]
+
+    @property
     def has_work(self) -> bool:
         """Queued or resident sequences, a program in flight (its lanes'
         requests may all have ended by an eos the host has yet to read), or
@@ -2225,35 +2234,58 @@ class ServeEngine(DecodeArrays):
                 and not any(sched.slots[i].replaying
                             for i in sched.active_indices()))
 
-    def _pipeline_steady(self) -> bool:
-        """Whether the NEXT decode program, plain or horizon, may be
-        enqueued before the pending one is booked — i.e. no scheduler event
-        can need the host state the pending tokens carry: slots are
-        decoding, nothing queued (admission), no prefill in flight, no
-        deadline due (expiry stays a boundary event), no drafter (what it
-        proposes comes from the host's tokens) and no slot mid-replay (it
-        consumes recorded tokens, from the host)."""
+    def _pipeline_steady(self) -> str:
+        """What keeps the NEXT decode program, plain or horizon, from being
+        enqueued before the pending one is booked, the FIRST of the checks
+        that fails (one of ``utils/trace.py``'s ``NOT_QUIET``), or ``""``:
+        no scheduler event can need the host state the pending tokens
+        carry. Slots are decoding (else ``inactive``), no drafter (what it
+        proposes comes from the host's tokens), nothing ``queued``
+        (admission), no ``prefill`` pending or running, no slot
+        ``replaying`` (it consumes recorded tokens, from the host) and no
+        ``deadline`` due (expiry stays a boundary event)."""
         sched = self.scheduler
         active = sched.active_indices()
-        return (bool(active) and self.drafter is None
-                and not sched.queue and not self._pending
-                and not sched.prefilling_indices()
-                and not any(sched.slots[i].replaying for i in active)
-                and not sched.deadline_due())
+        if not active:
+            return "inactive"
+        if self.drafter is not None:
+            return "drafter"
+        if sched.queue:
+            return "queued"
+        if self._pending or sched.prefilling_indices():
+            return "prefill"
+        if any(sched.slots[i].replaying for i in active):
+            return "replaying"
+        if sched.deadline_due():
+            return "deadline"
+        return ""
 
     def _ahead(self, pending_k: int, first: list = (),
-               resident: Optional[str] = None) -> Optional[int]:
-        """The QUIET test, one for both decode programs. ``pending_k``
+               resident: Optional[str] = None) -> tuple[Optional[int], str]:
+        """The QUIET test, one for both decode programs, as a span where it
+        runs (``serve.quiet``: ``held_by``, the first check that failed or
+        ``""``; ``_quiet`` has the checks). Returns ``(k, "")``: how many
+        steps the program AFTER the pending ones may run, enqueued before
+        they are read; or ``(None, held_by)``: the pending tokens are then
+        read first, and the boundary runs on authoritative host state."""
+        with span("serve.quiet") as sp:
+            k, held_by = self._quiet(pending_k, first, resident)
+            sp.set_metadata(held_by=held_by)
+        return k, held_by
+
+    def _quiet(self, pending_k: int, first: list,
+               resident: Optional[str]) -> tuple[Optional[int], str]:
+        """``(k, "")`` or ``(None, cause)``. ``pending_k``
         device steps are not booked yet: a program in flight, whose
         ``resident`` arrays the one after it needs as they stand on the
         device, tables apart (the host's tokens and lengths are ``pending_k``
         behind: a whole set cannot go up from them); or the one this step is
         about to enqueue (``first``: its slots whose first token is still on
-        the device). Returns how many steps the program AFTER them may run,
-        enqueued before they are read, or None: the pending tokens are then
-        read first, and the boundary runs on authoritative host state.
+        the device).
 
-        It may where the pipeline is steady (``_pipeline_steady``) and the
+        The next program may go up where the one in flight is of the
+        ``kind`` the engine would enqueue now, the pipeline is steady
+        (``_pipeline_steady``) and the
         pages of its writes fit without preempting: they are reserved here,
         ``pending_k`` writes past the host's lengths, which lag the
         device's by as much (``reserve_ahead``). A horizon masks a lane that
@@ -2264,24 +2296,30 @@ class ServeEngine(DecodeArrays):
         token is a boundary (an eos cannot be known: ``book_inflight`` has
         the rule for that one lane)."""
         sched = self.scheduler
-        if not self._pipeline_steady():
-            return None
+        if resident is not None and resident != (
+                "plain" if self.decode_horizon == 1 else "horizon"):
+            return None, "kind"
+        held_by = self._pipeline_steady()
+        if held_by:
+            return None, held_by
         if self.decode_horizon == 1 and sched.min_remaining_budget(
                 {adm.slot_idx for adm, _ in first}) <= pending_k:
-            return None
+            return None, "budget"
         covered = self.reserve_ahead(sched, pending_k + self.decode_horizon)
         if resident is not None and self._dev["kind"] != resident:
             # the arrays went: a lane left and they name it still (or the
             # reservation's growth dropped them, which stale_tables never
             # does); the pages just taken arrive early
-            return None
+            return None, "arrays"
+        if covered - pending_k < 1:
+            return None, "pages"
         # clamp by the largest remaining budget MINUS the steps already
         # pending: when they provably finish every slot, k drops below 1
         # and the step drains instead of burning an all-dead trailing
         # horizon
         k = min(covered - pending_k, self.decode_horizon,
                 sched.max_remaining_budget() - pending_k)
-        return k if k >= 1 else None
+        return (k, "") if k >= 1 else (None, "budget")
 
     def _book_inflight(self, behind: Optional[dict] = None) -> list:
         """Read and book the program in flight; ``behind``, the one this
@@ -2338,9 +2376,10 @@ class ServeEngine(DecodeArrays):
         D(n) and books it. D(n+1) runs while the host books, returns to its
         caller and is called again: the round trip, the booking and the
         caller's own bookkeeping cost the device nothing
-        (``decode_steps_pipelined`` counts these steps, ``serve.step``'s
-        ``pipelined`` marks them, ``serve.wait``'s ``waits_for`` names the
-        step that enqueued what was read). A synchronous plain step that
+        (``serve.step``'s ``order`` names the order each step took, one of
+        ``utils/trace.py``'s ``STEP_ORDERS``, and ``stats()["steps_by_order"]``
+        counts them; ``serve.wait``'s ``waits_for`` names the step that
+        enqueued what was read). A synchronous plain step that
         ends quiet ENTERS the pipeline: it enqueues D(n) and D(n+1) under
         its one ``serve.dispatch`` and reads D(n) alone.
 
@@ -2389,22 +2428,28 @@ class ServeEngine(DecodeArrays):
         with span("serve.step", seq=self.stats_seq) as sp:
             cpu0 = time.thread_time()
             overlapped = self.chunk_steps_overlapped
-            pipelined = self.decode_steps_pipelined
-            finished = self._iterate()
+            finished, order, held_by = self._iterate()
+            self.steps_by_order[order] += 1
+            if held_by:
+                self.not_quiet[held_by] += 1
             sp.set_metadata(
                 cpu_ms=1e3 * (time.thread_time() - cpu0),
                 overlapped=self.chunk_steps_overlapped - overlapped,
-                pipelined=self.decode_steps_pipelined - pipelined)
+                order=order)
             return settled + finished
 
-    def _iterate(self) -> list[RequestResult]:
+    def _iterate(self) -> tuple[list[RequestResult], str, str]:
+        """The iteration, the order it took (``STEP_ORDERS``) and what its
+        FIRST failing quiet test named (``NOT_QUIET``; a horizon's drain may
+        run the plain program, and its test, in the same step), ``""``
+        where none ran or none failed."""
         finished = []
         sched = self.scheduler
+        order, held_by = "idle", ""
         if self._inflight is not None:
             kind = self._inflight["kind"]
-            k = None
-            if kind == ("plain" if self.decode_horizon == 1 else "horizon"):
-                k = self._ahead(self._inflight["k"], resident=kind)
+            k, held_by = self._ahead(self._inflight["k"], resident=kind)
+            order = "drain" if k is None else "pipelined"
             behind = None
             if k is not None:
                 if kind == "plain":
@@ -2416,13 +2461,12 @@ class ServeEngine(DecodeArrays):
                         self.programs, self.pages, sched, self._dev, k,
                         seq=self.stats_seq)
                 self._note_dispatch(k)
-                self.decode_steps_pipelined += 1
             fin = self._book_inflight(behind)
             if behind is not None or kind == "plain":
                 # pipelined, or a plain drain: this step's token is booked,
                 # and the boundary is the next step's
                 self._lat.note(fin)
-                return fin
+                return fin, order, held_by
             # a horizon's drain: a boundary event needs host state the
             # pending block still held; the device arrays are rebuilt after
             # the boundary runs
@@ -2502,10 +2546,13 @@ class ServeEngine(DecodeArrays):
                 self._note_dispatch(k0)
                 # no blocking read here: the block books next step (or at
                 # the next drain) — the first half of the double buffer
+                if order != "drain":
+                    order = "enter"
             else:
                 # the plain program enters the pipeline where the step ends
                 # quiet: the one after it goes up behind it, unread
-                ahead = self._ahead(1, first) is not None
+                k, cause = self._ahead(1, first)
+                ahead, held_by = k is not None, held_by or cause
                 fin, emitted, self._dev, self._inflight = \
                     run_decode_iteration(
                         self.programs, self.pages, sched, self.drafter,
@@ -2517,8 +2564,10 @@ class ServeEngine(DecodeArrays):
                 finished.extend(fin)
                 if fin:
                     self.drop_dev("left")
+                if order != "drain":
+                    order = "enter" if ahead else "sync"
         self._lat.note(finished)
-        return finished
+        return finished, order, held_by
 
     # ---- host tier plumbing ------------------------------------------------
     def gather_pages(self, page_ids) -> dict:
@@ -2576,7 +2625,18 @@ class ServeEngine(DecodeArrays):
         every value is host-side Python the scheduler/engine already
         maintains, so ``/healthz`` answers mid-decode-iteration (reads
         are individually atomic under the GIL; the snapshot is
-        best-effort consistent, which is what a health probe wants)."""
+        best-effort consistent, which is what a health probe wants).
+
+        Two of its keys say what a profiler session would, without one.
+        ``steps_by_order``: every step since the engine was built under the
+        order it took (``utils/trace.py``'s ``STEP_ORDERS``);
+        ``decode_steps_pipelined`` is its ``pipelined``. ``not_quiet``: each
+        ``sync`` and ``drain`` step under the first thing that kept it from
+        pipelining (``NOT_QUIET``). To read them: ``queued`` high and
+        ``admission_blocked`` rising: the pool, not the host, holds the
+        pipeline back; ``budget`` and ``arrays`` in step with ``finished``:
+        replies ending, the price of a closed loop; ``prefill``: prompts
+        longer than a chunk."""
         sched = self.scheduler
         s = {k: (dict(v) if isinstance(v, dict) else v)
              for k, v in sched.stats.items()}
@@ -2586,6 +2646,8 @@ class ServeEngine(DecodeArrays):
             "preemptions": s.get("preempted", 0),
             "decode_horizon": self.decode_horizon,
             "decode_steps_pipelined": self.decode_steps_pipelined,
+            "steps_by_order": dict(self.steps_by_order),
+            "not_quiet": dict(self.not_quiet),
             "draining": self.draining,
             "max_queue": sched.max_queue,
             "queued": len(sched.queue),

@@ -912,24 +912,33 @@ class Scheduler:
         while self.queue:
             slot_idx = next((i for i, s in enumerate(self.slots)
                              if s is None), None)
-            if slot_idx is None:
-                break
             entry = self.queue[0]
             now = self._clock()
             rid = entry.request.request_id
+            # one span an ATTEMPT: `admitted` tells the head that got in
+            # (its `queue_ms` is the wait it paid) from the one that stays
+            # queued, and `blocked_by` says what kept it (ADMIT_BLOCKS)
             with span("serve.admit", request_id=rid, queue_ms=round(
-                    1e3 * (now - self._submit_times[rid]), 3)):
-                adm = self._admit_head(entry, slot_idx, now)
+                    1e3 * (now - self._submit_times[rid]), 3)) as sp:
+                if slot_idx is None:
+                    adm = None
+                    sp.set_metadata(blocked_by="slots")
+                else:
+                    adm = self._admit_head(entry, slot_idx, now, sp)
+                sp.set_metadata(admitted=int(adm is not None))
             if adm is None:
                 break
             admissions.append(adm)
         return admissions
 
     def _admit_head(self, entry: _QueueEntry, slot_idx: int,
-                    now: float) -> Optional[Admission]:
+                    now: float, sp) -> Optional[Admission]:
         """One admission: the queue head into ``slot_idx`` at time ``now``,
         or None when the pool (after prefix sharing) cannot grant its
-        pages — the head then blocks and stays queued."""
+        pages — the head then blocks and stays queued, and ``sp``, the
+        attempt's ``serve.admit`` span, says so: ``blocked_by`` with the
+        pages the head needs, those free after the cache gave what it
+        could, and the headroom kept for the running decodes."""
         page = self.pool.page_size
         req = entry.request
         # the prefill target is the PROMPT alone, resumed or not: a
@@ -978,6 +987,8 @@ class Scheduler:
             # release the speculative references and stay queued
             self.pool.free(shared_pages)
             self.stats["admission_blocked"] += 1
+            sp.set_metadata(blocked_by="pages", need=n_priv,
+                            free=self.pool.n_free, headroom=headroom)
             return None
         fork = None
         if partial is not None:

@@ -24,15 +24,27 @@ long step says anything) and ``overlapped`` (1 where the step completed a
 prefill and dispatched its decode BEFORE it read the first token, so the
 chunk program and the decode program ran back to back: such a step has a
 ``serve.sample`` in front of its ``serve.dispatch``, the sampler's launch,
-and one behind it, the token's read; 0 in every other step) and
-``pipelined`` (1 where the step enqueued its decode program BEFORE it read
-the one in flight from the step before, ``ServeEngine.step``'s pipelined
-order: its ``serve.dispatch`` ends before its ``serve.wait`` starts as in
-every step, but the program that ran under the wait is the step before's; 0
-in every other step), ``serve.wait`` carries ``waits_for`` (the ``seq`` of
+and one behind it, the token's read; 0 in every other step) and ``order``
+(one of :data:`STEP_ORDERS`: which of ``ServeEngine.step``'s orders the
+iteration took; in a ``pipelined`` step the ``serve.dispatch`` ends before
+the ``serve.wait`` starts as in every step, but the program that ran under
+the wait is the step before's), ``serve.quiet`` (the quiet test where it
+runs, ``ServeEngine._ahead``: may the NEXT decode program be enqueued before
+the pending one is read) carries ``held_by`` (one of :data:`NOT_QUIET`: the
+FIRST check that kept the step from pipelining, as ``serve.build``'s
+``reason`` names the first event; ``""`` where the step is quiet, and the
+profiler keeps no empty statistic: a quiet test's span arrives with none);
+the one-write-ahead ``serve.reserve`` is its child. ``serve.wait`` carries
+``waits_for`` (the ``seq`` of
 the ``serve.step`` that enqueued the program it read: its own step's, or the
 one before in a pipelined step and in one that drains; a reader that joins a
 wait with a device program follows this, not the dispatch beside it),
+``serve.admit`` carries ``request_id``, ``queue_ms`` (how long the queue's
+head has waited since its submit) and ``admitted`` (1: the head took a slot
+and its pages, and ``queue_ms`` is the wait it paid; 0: it stays queued, and
+``blocked_by`` says by what, one of :data:`ADMIT_BLOCKS`, for ``pages`` with
+``need``, ``free`` and ``headroom`` in pages: one span an attempt, so a head
+that waits ten steps leaves ten spans with ``admitted`` 0 and one with 1),
 ``serve.dispatch`` carries ``program`` and, for the plain decode program,
 ``programs`` (2 where a synchronous step entered the pipeline and enqueued
 the next step's program behind its own, else 1), and ``gc``
@@ -58,7 +70,7 @@ PREFIX = "dtg."
 SPANS = (
     "serve.step", "serve.expire", "serve.restore", "serve.admit",
     "serve.fork", "serve.prefill", "serve.sample", "serve.draft",
-    "serve.reserve", "serve.build", "serve.arrays", "serve.upload",
+    "serve.quiet", "serve.reserve", "serve.build", "serve.arrays", "serve.upload",
     "serve.dispatch", "serve.wait", "serve.book", "serve.release",
     "serve.state",
     "data.assemble", "data.put",
@@ -80,6 +92,43 @@ REBUILD_REASONS = (
     "first", "grown", "preempted", "admitted", "prefilled", "left", "expired",
     "restored", "drained", "kind", "speculation", "swapped", "lookahead",
 )
+
+# `order` of a serve.step span: which of `ServeEngine.step`'s orders the
+# iteration took. `sync`: nothing in flight at its start, the decode program
+# enqueued AND read in the step (a chunk step that decodes too; a speculative
+# step). `enter`: as `sync`, but the step ended quiet and LEFT a program in
+# flight: the plain program for the token after its own, behind its own under
+# the one serve.dispatch (`programs=2`), or a horizon's first block, which is
+# never read in the step that enqueues it. `pipelined`: a program in flight
+# and the step quiet, the next enqueued BEFORE the one in flight was read.
+# `drain`: in flight and not quiet, waited and booked (the plain program's
+# drain returns there; a horizon's goes on into the boundary in the same
+# step). `idle`: no decoding slot, so no decode program (prefill chunks
+# alone, or nothing). The `serve.step` of `serve/disagg.py`'s pair carries no
+# `order` (and the pair emits no serve.quiet): no cell, reader or `stats()` key
+# observes the pair, its plain program is always synchronous and its horizon's
+# quiet test is its own, inline; `tests/test_trace_names.py` exempts it by name
+# until a cell measures it
+STEP_ORDERS = ("sync", "enter", "pipelined", "drain", "idle")
+
+# `held_by` of a serve.quiet span, in the order the checks run; the FIRST
+# that fails is named. `kind`: the program in flight is not the one the
+# engine would enqueue now (plain / horizon: the knob moved); `inactive`: no
+# slot decodes; `drafter`: what it proposes comes from the host's tokens;
+# `queued`: a request waits for admission; `prefill`: an admitted prompt has
+# chunks to run; `replaying`: a slot consumes recorded tokens, from the host;
+# `deadline`: an expiry is due; `budget`: a reply ends with a pending token
+# (the plain program masks no lane), or every lane ends inside the pending
+# block (a horizon); `arrays`: the resident set went (a lane left, or the
+# reservation's growth dropped it); `pages`: the reservation for the writes
+# ahead covered too little. A quiet step has `held_by` ""
+NOT_QUIET = ("kind", "inactive", "drafter", "queued", "prefill", "replaying",
+             "deadline", "budget", "arrays", "pages")
+
+# `blocked_by` of a serve.admit span with `admitted` 0: the pool could not
+# grant the head's pages and keep its headroom (`need`, `free`, `headroom`
+# ride with it), or no slot is free
+ADMIT_BLOCKS = ("pages", "slots")
 
 # jax.named_scope names: model parts (`layers` is the layer scan's own work,
 # outside any sublayer), the train step's tail, the paged serve path. The

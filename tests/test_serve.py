@@ -407,6 +407,80 @@ def test_admission_eviction_cannot_stale_matched_prefix():
     assert len(set(pages)) == len(pages) == 3
 
 
+class _Span:
+    """``utils.trace.span`` for a test: name and statistics, when it closes."""
+
+    def __init__(self, into, name, args):
+        self.into, self.name, self.args = into, name, dict(args)
+
+    def __enter__(self):
+        return self
+
+    def set_metadata(self, **args):
+        self.args.update(args)
+
+    def __exit__(self, *exc):
+        self.into.append((self.name, self.args))
+        return False
+
+
+@pytest.mark.parametrize("blocked_by", ["pages", "slots"])
+def test_an_admission_attempt_says_whether_it_admitted_and_what_blocked_it(
+        monkeypatch, blocked_by):
+    """``serve.admit`` is one span an ATTEMPT: a head that waits three
+    steps leaves three spans with ``admitted`` 0 and ``blocked_by`` (for
+    ``pages`` with what it needs, what is free and the headroom kept), then
+    one with ``admitted`` 1 whose ``queue_ms`` is the wait it paid: a reader
+    of queue wait counts each admitted request once."""
+    from distributed_training_guide_tpu.serve import PagePool, Scheduler
+    from distributed_training_guide_tpu.serve import scheduler as sched_mod
+    from distributed_training_guide_tpu.utils.trace import ADMIT_BLOCKS
+
+    assert blocked_by in ADMIT_BLOCKS
+    spans, now = [], [0.0]
+    monkeypatch.setattr(sched_mod, "span",
+                        lambda name, **args: _Span(spans, name, args))
+    pool = PagePool(n_pages=6, page_size=4)          # 5 usable
+    sched = Scheduler(n_slots=2 if blocked_by == "pages" else 1, pool=pool,
+                      max_len=16, max_pages_per_slot=4, prefix_cache=False,
+                      clock=lambda: now[0])
+    dummy = pool.alloc(3)                            # 2 left
+    rid_a = sched.submit(Request(prompt_ids=list(range(1, 9)),
+                                 max_new_tokens=1))
+    [adm] = sched.try_admit()                        # takes both
+    rid_b = sched.submit(Request(prompt_ids=list(range(20, 25)),
+                                 max_new_tokens=2))
+    for _ in range(3):                               # the head waits
+        now[0] += 0.010
+        assert sched.try_admit() == []
+    if blocked_by == "pages":
+        pool.free(dummy)
+    else:                                            # the first reply ends
+        sched.commit_tokens(adm.slot_idx, 8)
+        assert sched.record_token(adm.slot_idx, 7, from_decode=False)
+    now[0] += 0.010
+    assert len(sched.try_admit()) == 1
+    admits = [args for name, args in spans if name == "serve.admit"]
+    assert [(a["request_id"], a["admitted"]) for a in admits] == [
+        (rid_a, 1), (rid_b, 0), (rid_b, 0), (rid_b, 0), (rid_b, 1)]
+    refused = [a for a in admits if not a["admitted"]]
+    assert {a["blocked_by"] for a in refused} == {blocked_by}
+    if blocked_by == "pages":
+        assert all((a["need"], a["free"], a["headroom"]) == (2, 0, 0)
+                   for a in refused)
+    else:
+        assert all("need" not in a for a in refused)
+    # `admission_blocked` counts the pool's refusals, as it did
+    assert sched.stats["admission_blocked"] == len(refused) * (
+        blocked_by == "pages")
+    assert all("blocked_by" not in a for a in admits if a["admitted"])
+    # the wait, once a request: the spans with `admitted` 1
+    waits = [a["queue_ms"] for a in admits if a["admitted"]]
+    assert waits == [0.0, 40.0]
+    assert len(waits) == sched.stats["admitted"] == 2
+    assert [a["queue_ms"] for a in refused] == [10.0, 20.0, 30.0]
+
+
 # ---- preemption-by-recompute ------------------------------------------------
 
 def test_preemption_recompute_token_identity(llama):

@@ -34,6 +34,8 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from benchmarks.readers import program_span, step_waterfall  # noqa: E402
+from distributed_training_guide_tpu.utils.trace import (  # noqa: E402
+    NOT_QUIET, STEP_ORDERS)
 
 pytestmark = pytest.mark.serve
 
@@ -65,7 +67,7 @@ def engine_of(progs, synchronous=False, **kw):
           "prefill_chunk": CHUNK, **kw}
     eng = ServeEngine(progs.bundle, progs.params, programs=progs, **kw)
     if synchronous:     # the parent's order: no step is ever quiet
-        eng._ahead = lambda pending_k, first=(): None
+        eng._ahead = lambda pending_k, first=(), resident=None: (None, "")
     return eng
 
 
@@ -503,75 +505,279 @@ def named_children(children, name):
     return sorted((c for c in children if c[0] == name), key=lambda c: c[1])
 
 
+def recorded(monkeypatch):
+    """Every span of the engine and the scheduler from here on, as the
+    benchmark's readers take them."""
+    spans = []
+    for mod in (engine_mod, scheduler_mod):
+        monkeypatch.setattr(
+            mod, "span", lambda name, **args: _Recorded(spans, name, args))
+    return spans
+
+
+def order_by_structure(step, children):
+    """The order a step took, from the shape of its spans alone: what the
+    file derived before ``serve.step`` said it."""
+    seq = step[4]["seq"]
+    dispatches = named_children(children, "serve.dispatch")
+    waits = named_children(children, "serve.wait")
+    # one of each at most, whichever order the step took
+    assert len(dispatches) <= 1 and len(waits) <= 1, seq
+    if not dispatches and not waits:
+        return "idle"               # prefill chunks alone, or nothing
+    if not dispatches:
+        # the read of the step before's program, and the wait says so
+        assert waits[0][4]["waits_for"] == seq - 1, seq
+        return "drain"
+    (dispatch,) = dispatches
+    if not waits:
+        return "enter"              # a horizon's first block, left unread
+    (wait,) = waits
+    if wait[2] <= dispatch[1]:
+        # a horizon's drain: the step before's block read, the boundary,
+        # the next block
+        assert wait[4]["waits_for"] == seq - 1, seq
+        return "drain"
+    assert dispatch[2] <= wait[1], seq
+    waits_for = wait[4].get("waits_for", seq)    # a speculative step: its own
+    if waits_for == seq - 1:
+        return "pipelined"
+    assert waits_for == seq, seq                # its own program
+    return "enter" if dispatch[4].get("programs") == 2 else "sync"
+
+
+def first_held(children):
+    """``held_by`` of the step's first quiet test that failed, or None."""
+    return next((q[4]["held_by"] for q in named_children(children,
+                                                          "serve.quiet")
+                 if q[4].get("held_by")), None)
+
+
+def counts_close(eng, spans, before):
+    """The sums ISSUE 53 holds a traced run to, on the CPU engine: every
+    step under one order of the vocabulary, the counters what the spans
+    say, and a cause for each ``sync`` and ``drain`` step and no other."""
+    steps = program_span.steps_with_children(spans, 0, 2 ** 63)
+    stats = eng.stats()
+    orders = dict.fromkeys(STEP_ORDERS, 0)
+    causes = dict.fromkeys(NOT_QUIET, 0)
+    for step, children in steps:
+        order = step[4]["order"]
+        assert order == order_by_structure(step, children), step[4]
+        assert "pipelined" not in step[4]
+        orders[order] += 1
+        held = first_held(children)
+        quiet = named_children(children, "serve.quiet")
+        assert all(q[4]["held_by"] in ("", *NOT_QUIET) for q in quiet)
+        if order in ("sync", "drain"):
+            assert held is not None, (step[4], children)
+            causes[held] += 1
+        elif order == "idle":
+            assert not quiet
+        else:                       # a horizon's first block asks nothing
+            assert held is None and (quiet or eng.decode_horizon > 1)
+    assert orders == {k: v - before["steps_by_order"][k]
+                      for k, v in stats["steps_by_order"].items()}
+    assert causes == {k: v - before["not_quiet"][k]
+                      for k, v in stats["not_quiet"].items()}
+    assert sum(orders.values()) == len(steps) \
+        == stats["stats_seq"] - before["stats_seq"]
+    assert sum(causes.values()) == orders["sync"] + orders["drain"]
+    assert stats["decode_steps_pipelined"] \
+        == stats["steps_by_order"]["pipelined"]
+    return steps, orders, causes
+
+
 def test_a_pipelined_step_dispatches_then_waits_for_the_step_before(
         programs, monkeypatch):
     progs = programs("llama")
     eng = engine_of(progs)
     reqs = [request(5, 20, 0), request(9, 12, 1), request(7, 16, 2)]
     run(eng, reqs[:1])                          # compile outside the record
-    before = eng.stats()["decode_steps_pipelined"]
-    spans = []
-    for mod in (engine_mod, scheduler_mod):
-        monkeypatch.setattr(
-            mod, "span", lambda name, **args: _Recorded(spans, name, args))
+    before = eng.stats()
+    spans = recorded(monkeypatch)
     run(eng, reqs, clients=2)
-    steps = program_span.steps_with_children(spans, 0, 2 ** 63)
-    assert sum(s[4]["pipelined"] for s, _ in steps) \
-        == eng.stats()["decode_steps_pipelined"] - before > 10
-    seen = {"pipelined": 0, "entering": 0, "drained": 0, "synchronous": 0}
+    steps, orders, causes = counts_close(eng, spans, before)
+    assert orders["pipelined"] > 10 and orders["enter"] >= 2
+    assert orders["drain"] >= 2 and orders["sync"] >= 1, orders
+    # two clients over three requests: a reply's end is a budget's end, the
+    # third request waits for a slot, its prompt is one chunk
+    assert causes["budget"] >= 2 and set(
+        k for k, v in causes.items() if v) <= {"budget", "queued", "arrays",
+                                               "prefill"}, causes
     modules = []
     for step, children in steps:
-        seq = step[4]["seq"]
-        assert step[4]["pipelined"] in (0, 1)
-        dispatches = named_children(children, "serve.dispatch")
+        seq, order = step[4]["seq"], step[4]["order"]
         waits = named_children(children, "serve.wait")
-        # one of each at most, whichever order the step took
-        assert len(dispatches) <= 1 and len(waits) <= 1, seq
         if not waits:
             continue                # a step of prefill chunks alone
         (wait,) = waits
         (book,) = named_children(children, "serve.book")
         assert wait[2] <= book[1]
-        if step[4]["pipelined"]:
+        if order == "pipelined":
             # enqueued BEFORE the read, of what the step before enqueued;
             # nothing of the boundary in between
-            (dispatch,) = dispatches
+            (dispatch,) = named_children(children, "serve.dispatch")
             assert dispatch[4] == {"program": "serve_decode", "programs": 1}
-            assert dispatch[2] <= wait[1]
-            assert wait[4]["waits_for"] == seq - 1
             assert not {c[0] for c in children} & {
                 "serve.expire", "serve.admit", "serve.prefill",
                 "serve.sample"}
             builds = named_children(children, "serve.build")
             assert all(b[4]["reason"] == "lookahead" and b[2] <= dispatch[1]
                        for b in builds)
-            seen["pipelined"] += 1
+            # the quiet test in front of it all, the reservation its child
+            (quiet,) = named_children(children, "serve.quiet")
+            assert quiet[4] == {"held_by": ""}
+            assert quiet[2] <= dispatch[1]
+            (reserve,) = named_children(children, "serve.reserve")
+            assert quiet[1] <= reserve[1] and reserve[2] <= quiet[2]
             # a made-up device line: the program in flight was running
             # when this step enqueued the next, and ran into its wait
             modules.append(("jit_serve_decode(1)", dispatch[1] - 1000,
                             wait[2] - 1))
-        elif not dispatches:
-            # a drain: the read of the step before's program, and the step
-            # is over; the boundary is the next step's
-            assert wait[4]["waits_for"] == seq - 1
-            assert {c[0] for c in children} <= {"serve.wait", "serve.book",
-                                                "serve.release",
-                                                "serve.state"}
-            seen["drained"] += 1
-        else:
-            (dispatch,) = dispatches
-            assert dispatch[2] <= wait[1]
-            assert wait[4]["waits_for"] == seq      # its own program
-            ahead = dispatch[4]["programs"] == 2
-            seen["entering" if ahead else "synchronous"] += 1
-    assert seen["pipelined"] > 10 and seen["entering"] >= 2
-    assert seen["drained"] >= 2, seen
+        elif order == "drain":
+            # the read of the step before's program, and the step is over;
+            # the boundary is the next step's
+            assert {c[0] for c in children} <= {
+                "serve.quiet", "serve.reserve", "serve.wait", "serve.book",
+                "serve.release", "serve.state"}
+            (quiet,) = named_children(children, "serve.quiet")
+            assert quiet[2] <= wait[1]
     # the benchmark's reader keeps a pipelined step (one dispatch, one wait
     # after it) and joins it with the program that was in flight
-    piped = [(s, c) for s, c in steps if s[4]["pipelined"]]
+    piped = [(s, c) for s, c in steps if s[4]["order"] == "pipelined"]
     joined, skipped = step_waterfall.join(piped, modules)
     assert skipped == 0 and len(joined) == len(piped)
     for step, children, dispatch, wait, device, _ in joined:
         cut = step_waterfall.cut(step, children, dispatch, wait, device)
         assert sum(cut["phases"].values()) == cut["ns"]
         assert cut["phases"]["launch"] == 0     # it ran before the dispatch
+
+
+def test_a_horizon_takes_its_orders_from_the_same_vocabulary(programs,
+                                                            monkeypatch):
+    """``decode_horizon`` 4: the first block ENTERS (enqueued, never read in
+    its step, and no quiet test asked), the blocks after it are pipelined,
+    and a drain goes on into the boundary in the same step. The counts close
+    as they do for the plain program."""
+    progs = programs("llama")
+    eng = engine_of(progs, decode_horizon=4, n_slots=2)
+    reqs = [request(5 + 3 * i, 30 + 7 * i, i) for i in range(3)]
+    run(eng, reqs[:1])                          # compile outside the record
+    want = batch1(progs, reqs)
+    before = eng.stats()
+    spans = recorded(monkeypatch)
+    got, _ = run(eng, reqs, clients=2)
+    same_tokens(got, want, reqs)
+    steps, orders, causes = counts_close(eng, spans, before)
+    assert orders["enter"] >= 1 and orders["pipelined"] >= 3, orders
+    assert orders["drain"] >= 2 and orders["sync"] == 0, orders
+    # a horizon's drain runs the boundary, and may enqueue the next block,
+    # in the same step
+    assert any(named_children(c, "serve.dispatch") for s, c in steps
+               if s[4]["order"] == "drain")
+
+
+# ---- (h) the first thing that kept a step from pipelining ----------------------
+
+def held_queued(progs, monkeypatch):
+    """Three clients on two slots: one request waits for a slot."""
+    eng = engine_of(progs, n_slots=2)
+    return eng, [request(5 + i, 12, i) for i in range(3)], {}
+
+
+def held_prefill(progs, monkeypatch):
+    """A prompt of three chunks beside a slot that decodes."""
+    eng = engine_of(progs, n_slots=2)
+    return eng, [request(5, 12, 0), request(40, 6, 2)], {}
+
+
+def held_budget(progs, monkeypatch):
+    """A reply's last token is known a step ahead."""
+    return engine_of(progs), [request(5, 9, 0)], {}
+
+
+def held_arrays(progs, monkeypatch):
+    """A lane that left by an eos, read behind the program enqueued after
+    it: the arrays on the device name it still."""
+    plain = [request(6, 30, 0), request(9, 30, 1)]
+    tokens = batch1(progs, plain)[1].generated_ids
+    at = next(j for j in range(8, 28) if tokens[j] not in tokens[:j])
+    reqs = [plain[0], dataclasses.replace(plain[1], eos_id=tokens[at])]
+    return engine_of(progs, n_slots=2), reqs, {}
+
+
+def held_pages(progs, monkeypatch):
+    """A pool too full to reserve the write ahead; the victim of the
+    preemption that follows comes back and replays what it had."""
+    eng = engine_of(progs, n_pages=9, max_len=48)
+    return eng, [request(4 + i, 30 + i, i) for i in range(3)], {
+        "also": ("replaying",)}
+
+
+def held_deadline(progs, monkeypatch):
+    """A deadline that falls due while the slot decodes."""
+    eng = engine_of(progs)
+    now = [0.0]
+    eng.scheduler._clock = lambda: now[0]
+
+    def on_step(eng, finished):
+        if eng.stats()["stats_seq"] % 100 == 8:
+            now[0] += 100.0
+    return eng, [request(5, 30, 0, deadline_s=50.0)], {
+        "on_step": on_step, "unfinished": True}
+
+
+def held_drafter(progs, monkeypatch):
+    """What a drafter proposes comes from the host's tokens."""
+    eng = engine_of(progs, speculate="ngram", spec_k=2)
+    return eng, [request(5, 12, 0)], {}
+
+
+def held_kind(progs, monkeypatch):
+    """A horizon switched on under a plain program in flight."""
+    eng = engine_of(progs)
+
+    def on_step(eng, finished):
+        if eng.stats()["stats_seq"] % 100 == 6:
+            eng.set_decode_horizon(2)
+    return eng, [request(5, 20, 0)], {"on_step": on_step}
+
+
+def held_inactive(progs, monkeypatch):
+    """The only reply ends by an eos read behind an enqueued program: the
+    next step has a program to read and no slot that decodes."""
+    tokens = batch1(progs, [request(6, 20, 1)])[0].generated_ids
+    at = next(j for j in range(6, 18) if tokens[j] not in tokens[:j])
+    return engine_of(progs), [request(6, 20, 1, eos_id=tokens[at])], {}
+
+
+@pytest.mark.parametrize("cause,scene", [
+    ("queued", held_queued), ("prefill", held_prefill),
+    ("budget", held_budget), ("arrays", held_arrays), ("pages", held_pages),
+    ("deadline", held_deadline), ("drafter", held_drafter),
+    ("kind", held_kind), ("inactive", held_inactive)])
+def test_a_step_that_does_not_pipeline_names_the_first_thing_in_its_way(
+        programs, monkeypatch, cause, scene):
+    """One case a cause a CPU engine can reach: the ``serve.quiet`` span of
+    the step says ``held_by``, ``stats()["not_quiet"]`` counts it, and the
+    counts close over the run (``counts_close``)."""
+    assert cause in NOT_QUIET
+    progs = programs("llama")
+    eng, reqs, how = scene(progs, monkeypatch)
+    base = 100 * (1 + eng.stats()["stats_seq"] // 100)
+    eng.stats_seq = base            # the scenes count steps from here
+    before = eng.stats()
+    spans = recorded(monkeypatch)
+    got, _ = run(eng, reqs, on_step=how.get("on_step"))
+    steps, orders, causes = counts_close(eng, spans, before)
+    for want in (cause, *how.get("also", ())):
+        assert causes[want] >= 1, (want, causes)
+        assert eng.stats()["not_quiet"][want] == causes[want]
+    if not how.get("unfinished"):
+        assert all(r.finish_reason in ("length", "eos") for r in got)
+    # the step the cause held drained, or stayed synchronous: it never
+    # enqueued ahead
+    for step, children in steps:
+        if first_held(children) == cause:
+            assert step[4]["order"] in ("sync", "drain")

@@ -18,10 +18,12 @@ from distributed_training_guide_tpu.serve import Request, ServeEngine
 from distributed_training_guide_tpu.train import Trainer
 from distributed_training_guide_tpu.train.step import lower_step
 from distributed_training_guide_tpu.utils import trace as trace_mod
-from distributed_training_guide_tpu.utils.trace import (KERNELS, PREFIX,
+from distributed_training_guide_tpu.utils.trace import (ADMIT_BLOCKS, KERNELS,
+                                                        NOT_QUIET, PREFIX,
                                                         PROGRAMS,
                                                         REBUILD_REASONS,
                                                         SCOPES, SPANS,
+                                                        STEP_ORDERS,
                                                         SUBSCOPES, named, span)
 
 SERVE_CHILDREN = {s for s in SPANS if s.startswith("serve.")} - {"serve.step"}
@@ -97,8 +99,8 @@ def test_serve_span_tree(debug_model, tmp_path, engine_kw):
     names = {e[0] for e in events}
     assert names <= set(SPANS), names - set(SPANS)
     want = {"serve.step", "serve.expire", "serve.admit", "serve.prefill",
-            "serve.sample", "serve.reserve", "serve.build", "serve.dispatch",
-            "serve.wait", "serve.book"}
+            "serve.sample", "serve.quiet", "serve.reserve", "serve.build",
+            "serve.dispatch", "serve.wait", "serve.book"}
     assert want <= names, want - names
     steps = [e for e in events if e[0] == "serve.step"]
     children = [e for e in events if e[0] in SERVE_CHILDREN]
@@ -131,6 +133,46 @@ def test_serve_span_tree(debug_model, tmp_path, engine_kw):
                if e[0] == "serve.dispatch")
     reserves = [e[4] for e in events if e[0] == "serve.reserve"]
     assert sum(r.get("preempted", 0) for r in reserves) >= 1
+    # every step says which order it took and every quiet test what held
+    # it, both of the closed sets; an attempt at admission says how it ended
+    assert {s[4]["order"] for s in steps} <= set(STEP_ORDERS)
+    assert all("pipelined" not in s[4] for s in steps)
+    quiet = [e[4] for e in events if e[0] == "serve.quiet"]
+    # (a quiet test's empty `held_by` does not reach a trace)
+    assert quiet and {q["held_by"] for q in quiet if "held_by" in q} \
+        <= set(NOT_QUIET)
+    assert any("held_by" not in q for q in quiet)
+    assert {1} <= {e[4]["admitted"] for e in admits} <= {0, 1}
+    assert {e[4]["blocked_by"] for e in admits if not e[4]["admitted"]} \
+        <= set(ADMIT_BLOCKS)
+    assert all("blocked_by" not in e[4] for e in admits if e[4]["admitted"])
+    stats = engine.stats()
+    assert set(stats["steps_by_order"]) == set(STEP_ORDERS)
+    assert set(stats["not_quiet"]) == set(NOT_QUIET)
+    assert sum(stats["steps_by_order"].values()) == stats["stats_seq"]
+
+
+def test_the_disaggregated_pairs_step_is_the_one_without_an_order(
+        debug_model, tmp_path):
+    """``serve/disagg.py`` opens a ``serve.step`` of its own, exempted here
+    by name as ``utils/trace.py`` says beside ``STEP_ORDERS``: ``cpu_ms``
+    and ``seq`` as before, no ``order`` and no ``serve.quiet``."""
+    from distributed_training_guide_tpu.serve import DisaggEngine
+
+    bundle, params = debug_model
+    engine = DisaggEngine(bundle, params, n_slots=2, n_prefill_slots=1,
+                          page_size=8, max_len=64)
+    run_requests(engine, n_new=4)          # compile outside the session
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tokens = run_requests(engine, n_new=6)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(tokens) == 2
+    events = program_events(tmp_path)
+    steps = [e for e in events if e[0] == "serve.step"]
+    assert steps and all(set(s[4]) == {"seq", "cpu_ms"} for s in steps)
+    assert "serve.quiet" not in {e[0] for e in events}
 
 
 def test_train_loop_span_tree(tmp_path, eight_devices):
@@ -214,7 +256,7 @@ def provoke_grown_synchronous(engine):
     ``grow_for_decode``, in the step that writes it. In the pipelined order,
     which this session takes by itself, it comes a step earlier, from the
     reservation for the program enqueued ahead: ``lookahead``."""
-    engine._ahead = lambda pending_k, first=(), resident=None: None
+    engine._ahead = lambda pending_k, first=(), resident=None: (None, "")
     provoke_grown(engine)
 
 
@@ -341,6 +383,38 @@ def test_no_engine_drops_the_decode_arrays_by_assignment():
     assert built == {"kind", "lookahead"} and built & said == {"lookahead"}
     assert said | built == set(REBUILD_REASONS)
     assert len(REBUILD_REASONS) == len(set(REBUILD_REASONS))
+
+
+def test_the_orders_and_the_causes_in_the_source_are_the_closed_sets():
+    """As ``REBUILD_REASONS`` above: what ``serve/engine.py`` says of a
+    step's ``order``, what the quiet test returns as ``held_by`` and what
+    admission says blocked it are the three tuples of ``utils/trace.py``, no
+    more and no fewer."""
+    import inspect
+
+    from distributed_training_guide_tpu.serve import engine, scheduler
+
+    said = set()
+    for line in inspect.getsource(engine).splitlines():
+        if re.search(r"\border(, held_by)? = ", line):
+            said |= set(re.findall(r'"(\w+)"', line))
+    assert said == set(STEP_ORDERS), said
+    quiet = inspect.getsource(engine.ServeEngine._quiet).split('"""')[2]
+    steady = inspect.getsource(
+        engine.ServeEngine._pipeline_steady).split('"""')[2]
+    returned = r'(?:return None, |return |else \(None, )"(\w+)"'
+    # in the order the checks run: the first that fails is the one named
+    # (`budget` twice: the plain program's before the reservation, a
+    # horizon's, all lanes ending inside the pending block, after it)
+    assert re.findall(returned, quiet)[:1] == ["kind"]
+    assert tuple(re.findall(returned, quiet)[:1] + re.findall(returned, steady)
+                 + re.findall(returned, quiet)[1:-1]) == NOT_QUIET
+    assert re.findall(returned, quiet)[-1] == "budget"
+    blocks = set(re.findall(r'blocked_by="(\w+)"',
+                            inspect.getsource(scheduler)))
+    assert blocks == set(ADMIT_BLOCKS)
+    for closed in (STEP_ORDERS, NOT_QUIET, ADMIT_BLOCKS):
+        assert len(closed) == len(set(closed))
 
 
 # ---- (b) no session: nothing recorded, nothing changed ---------------------
