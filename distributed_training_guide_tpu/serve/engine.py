@@ -450,12 +450,15 @@ class DecodeArrays:
     def drop_dev(self, reason: str) -> None:
         """The next decode rebuilds every array from the scheduler: the set
         of decoding slots changed, or what a slot decodes under. The FIRST
-        cause since the last build is the one kept: a slot that left and
-        whose successor was admitted and prefilled in the next iteration
-        rebuilt because it ``left``."""
+        such cause since the last build is the one kept: a slot that left
+        and whose successor was admitted and prefilled in the next iteration
+        rebuilt because it ``left``. Tables that were stale go up with the
+        whole set, and their cause (``grown``, ``lookahead``: the names of a
+        table refresh alone) is not the build's: a write ahead reserved
+        under a program in flight, a drain, and then a preemption rebuilds
+        because a slot was ``preempted``."""
         if self._dev["kind"] is not None:
-            first = self._dev["stale"] or reason
-            self._dev = no_dev(first)
+            self._dev = no_dev(reason)
 
     def stale_tables(self, reason: str) -> None:
         """Slots took pages and nothing else about the decoding set changed:
@@ -2240,17 +2243,21 @@ class ServeEngine(DecodeArrays):
         that fails (one of ``utils/trace.py``'s ``NOT_QUIET``), or ``""``:
         no scheduler event can need the host state the pending tokens
         carry. Slots are decoding (else ``inactive``), no drafter (what it
-        proposes comes from the host's tokens), nothing ``queued``
-        (admission), no ``prefill`` pending or running, no slot
-        ``replaying`` (it consumes recorded tokens, from the host) and no
-        ``deadline`` due (expiry stays a boundary event)."""
+        proposes comes from the host's tokens), nothing ``queued`` that
+        might get in (a head whose last refusal still stands,
+        ``Scheduler.head_refusal_stands``, would be refused again: nothing
+        a drain could learn; what ends the refusal, a reply's end, is the
+        ``budget`` check's to see a step ahead), no ``prefill`` pending or
+        running, no slot ``replaying`` (it consumes recorded tokens, from
+        the host) and no ``deadline`` due, a queued request's too (expiry
+        stays a boundary event)."""
         sched = self.scheduler
         active = sched.active_indices()
         if not active:
             return "inactive"
         if self.drafter is not None:
             return "drafter"
-        if sched.queue:
+        if sched.queue and not sched.head_refusal_stands():
             return "queued"
         if self._pending or sched.prefilling_indices():
             return "prefill"
@@ -2302,8 +2309,11 @@ class ServeEngine(DecodeArrays):
         held_by = self._pipeline_steady()
         if held_by:
             return None, held_by
-        if self.decode_horizon == 1 and sched.min_remaining_budget(
-                {adm.slot_idx for adm, _ in first}) <= pending_k:
+        if (self.decode_horizon == 1 or sched.queue) \
+                and sched.min_remaining_budget(
+                    {adm.slot_idx for adm, _ in first}) <= pending_k:
+            # a horizon too where a refused head waits for what the ending
+            # reply returns: it is admitted in the step that books the end
             return None, "budget"
         covered = self.reserve_ahead(sched, pending_k + self.decode_horizon)
         if resident is not None and self._dev["kind"] != resident:
@@ -2367,13 +2377,13 @@ class ServeEngine(DecodeArrays):
         ``chunk_steps_overlapped``).
 
         PIPELINED, with a decode program D(n) in flight at its start and
-        the step QUIET (``_ahead``: slots decoding, nothing queued, no
-        prefill pending, no deadline due, no drafter, no replaying slot, no
-        budget that ends with token n, and the pages of write n+1 fit
-        without preempting): the pages of write n+1 are reserved, the block
-        tables alone go up if they grew, D(n+1) is enqueued on the tokens
-        and lengths D(n) leaves on the device, and THEN the host waits on
-        D(n) and books it. D(n+1) runs while the host books, returns to its
+        the step QUIET (``_ahead``: slots decoding, nothing queued that
+        might get in, no prefill pending, no deadline due, no drafter, no
+        replaying slot, no budget that ends with token n, and the pages of
+        write n+1 fit without preempting): the pages of write n+1 are
+        reserved, the block tables alone go up if they grew, D(n+1) is
+        enqueued on the tokens and lengths D(n) leaves on the device, and
+        THEN the host waits on D(n) and books it. D(n+1) runs while the host books, returns to its
         caller and is called again: the round trip, the booking and the
         caller's own bookkeeping cost the device nothing
         (``serve.step``'s ``order`` names the order each step took, one of
@@ -2392,6 +2402,20 @@ class ServeEngine(DecodeArrays):
         book twice. Finished results therefore surface at most one step
         after their tokens were computed.
 
+        A queue head whose last refusal STILL STANDS does not make a step
+        unquiet (``Scheduler.head_refusal_stands``: the same head, nothing
+        come back to the pool or the slots since, the headroom not lower, no
+        host tier): a real attempt would be refused again, so the step goes
+        ahead past it, makes none, and says that the head waited there too
+        (``Scheduler.hold_head``: a ``serve.admit`` span with ``held`` 1;
+        ``stats()["admission_held"]``). What ends the refusal is a reply's
+        end, which the ``budget`` check sees a step ahead (for a horizon
+        too while a head is held): that step drains, its booking returns
+        the slot and the pages, and the next step, synchronous, admits: the
+        step the parent's order admitted in. An end by EOS, read behind an
+        enqueued program, costs the head one step, as it costs an empty
+        queue's refill.
+
         One lane too many: a token n that ends its request by EOS means
         D(n+1) ran that lane once more. Its token is not booked (lanes are
         matched by request id), and its write went to a page and a state
@@ -2405,9 +2429,10 @@ class ServeEngine(DecodeArrays):
         never early).
 
         What needs the host's state whole: a drafter or a horizon switched
-        on, a queued request, a deadline and a lane that left are seen by
-        the next step's quiet test, which drains; an engine swap and a
-        forced publish book what is in flight at once, outside a step
+        on, a request that arrived (or a head whose refusal ended), a
+        deadline and a lane that left are seen by the next step's quiet
+        test, which drains; an engine swap and a forced publish book what
+        is in flight at once, outside a step
         (``settle``); ``drain`` stays a flag (another thread may call it)
         and ``gather_pages`` / ``scatter_pages`` are ordered behind the
         program on the device. ``partial_tokens`` is what has been BOOKED,
@@ -2452,6 +2477,10 @@ class ServeEngine(DecodeArrays):
             order = "drain" if k is None else "pipelined"
             behind = None
             if k is not None:
+                if sched.queue:
+                    # quiet with a request queued: its refusal stands, and
+                    # the step says that the head waited here too
+                    sched.hold_head()
                 if kind == "plain":
                     (behind,), self._dev = dispatch_decode(
                         self.programs, self.pages, sched, self._dev,
@@ -2632,11 +2661,17 @@ class ServeEngine(DecodeArrays):
         order it took (``utils/trace.py``'s ``STEP_ORDERS``);
         ``decode_steps_pipelined`` is its ``pipelined``. ``not_quiet``: each
         ``sync`` and ``drain`` step under the first thing that kept it from
-        pipelining (``NOT_QUIET``). To read them: ``queued`` high and
-        ``admission_blocked`` rising: the pool, not the host, holds the
-        pipeline back; ``budget`` and ``arrays`` in step with ``finished``:
-        replies ending, the price of a closed loop; ``prefill``: prompts
-        longer than a chunk."""
+        pipelining (``NOT_QUIET``). To read them: ``queued`` counts the
+        drains for a request that MIGHT get in, an arrival under a program
+        in flight or a head whose refusal ended: it rises with the arrival
+        rate, not with the queue's depth. A head the pool goes on refusing
+        costs no drain: ``admission_held`` counts the steps that went ahead
+        past it (``admission_blocked`` the real attempts the pool refused),
+        so ``admission_held`` high beside ``budget`` in step with
+        ``finished`` is a pool that admits only as replies end, with the
+        pipeline flowing in between; ``budget`` and ``arrays`` in step with
+        ``finished``: replies ending, the price of a closed loop;
+        ``prefill``: prompts longer than a chunk."""
         sched = self.scheduler
         s = {k: (dict(v) if isinstance(v, dict) else v)
              for k, v in sched.stats.items()}

@@ -214,6 +214,18 @@ class Admission:
     resumed: bool
 
 
+@dataclasses.dataclass(frozen=True)
+class _Refusal:
+    """The queue head's last refusal, kept where it happened
+    (``Scheduler.try_admit`` / ``_admit_head``): what
+    ``Scheduler.head_refusal_stands`` compares with the scheduler as it is
+    now."""
+    request_id: int
+    blocked_by: str                 # one of utils/trace.py's ADMIT_BLOCKS
+    headroom: int                   # the pages it kept free (`pages` alone)
+    came_back: int                  # Scheduler._came_back at the refusal
+
+
 class _PrefixNode:
     """One registered page in the prefix tree: children are keyed by the
     NEXT page's full token content, so a chain of dict hits walks shared
@@ -551,7 +563,13 @@ class Scheduler:
         # recompute path stays the universal fallback.
         self._tier = None
         self._tier_gather = None
-        self.stats = {"admission_blocked": 0, "admitted": 0, "finished": 0,
+        # the queue head's last refusal (``_Refusal``, or None), and the
+        # counter it is held against: bumped wherever something comes BACK
+        # that admission could use (``try_admit`` names the sites)
+        self._refusal: Optional[_Refusal] = None
+        self._came_back = 0
+        self.stats = {"admission_blocked": 0, "admission_held": 0,
+                      "admitted": 0, "finished": 0,
                       "preempted": 0, "prefix_hits": 0,
                       "prefix_tokens_shared": 0, "cow_forks": 0,
                       "cache_evicted_pages": 0, "deadline_expired": 0,
@@ -790,10 +808,12 @@ class Scheduler:
 
     def _free_slot(self, slot: _Slot) -> None:
         """Drop every page reference a leaving slot holds, of both classes,
-        and its block of the state class."""
+        and its block of the state class. A slot and its main-class pages
+        come back: a refusal of the queue's head no longer stands."""
         self.pool.free(slot.pages)
         self._release_window(slot, everything=True)
         self._return_state_block(slot)
+        self._came_back += 1
 
     def live_pages_by_class(self) -> dict:
         """Pages held by slots, a count a class."""
@@ -907,7 +927,17 @@ class Scheduler:
         their priority class and re-admit first — their context includes
         the tokens already generated (recompute). The engine runs each
         admission's fork copy + prefill, reporting progress through
-        ``commit_tokens``."""
+        ``commit_tokens``.
+
+        A refusal is REMEMBERED where it happens (``_refuse_head``: here for
+        ``slots``, in ``_admit_head`` for ``pages``): the head's request id,
+        the headroom it was held to, and ``_came_back`` as it stood, the
+        counter bumped wherever something comes back that admission could
+        use (``_free_slot``: a reply's end, a preemption, a deadline's
+        eviction, each a slot and its pages; ``release_slot``: a slot;
+        ``commit_tokens``: a prefix registered that the head shares).
+        ``head_refusal_stands`` compares that memo with the scheduler as it
+        is now; every call here is a real attempt and refreshes it."""
         admissions = []
         while self.queue:
             slot_idx = next((i for i, s in enumerate(self.slots)
@@ -922,7 +952,7 @@ class Scheduler:
                     1e3 * (now - self._submit_times[rid]), 3)) as sp:
                 if slot_idx is None:
                     adm = None
-                    sp.set_metadata(blocked_by="slots")
+                    self._refuse_head(entry, sp, "slots")
                 else:
                     adm = self._admit_head(entry, slot_idx, now, sp)
                 sp.set_metadata(admitted=int(adm is not None))
@@ -938,7 +968,8 @@ class Scheduler:
         pages — the head then blocks and stays queued, and ``sp``, the
         attempt's ``serve.admit`` span, says so: ``blocked_by`` with the
         pages the head needs, those free after the cache gave what it
-        could, and the headroom kept for the running decodes."""
+        could, and the headroom kept for the running decodes. The refusal
+        goes into the memo with that headroom (``_refuse_head``)."""
         page = self.pool.page_size
         req = entry.request
         # the prefill target is the PROMPT alone, resumed or not: a
@@ -962,18 +993,7 @@ class Scheduler:
         protect = [partial[0].page] if partial else []
         if protect:              # the CoW source must survive too — the
             self.pool.share(protect)   # engine copies it after we return
-        # headroom: every running decode may need a page within one
-        # page_size worth of steps — admitting into that margin would
-        # trade one prompt's admission for immediate preemption churn
-        # (decodes running in a sibling scheduler count via the hook).
-        # Under speculation each decode can consume 1 + spec_lookahead
-        # positions per iteration, and under a K-step horizon K
-        # positions per BOUNDARY, so the margin scales to the pages
-        # that worth of tokens can claim.
-        per_decode = pages_for_tokens(
-            self.decode_horizon + self.spec_lookahead, page)
-        headroom = (len(self.active_indices()) + (
-            self._headroom_fn() if self._headroom_fn else 0)) * per_decode
+        headroom = self._admission_headroom()
         priv = self._alloc(n_priv, headroom=headroom)
         if protect:
             # safe to release now: if the source node was evicted
@@ -987,8 +1007,9 @@ class Scheduler:
             # release the speculative references and stay queued
             self.pool.free(shared_pages)
             self.stats["admission_blocked"] += 1
-            sp.set_metadata(blocked_by="pages", need=n_priv,
-                            free=self.pool.n_free, headroom=headroom)
+            self._refuse_head(entry, sp, "pages", headroom)
+            sp.set_metadata(need=n_priv, free=self.pool.n_free,
+                            headroom=headroom)
             return None
         fork = None
         if partial is not None:
@@ -1023,6 +1044,61 @@ class Scheduler:
             shared_len=shared_len, fork=fork,
             resumed=bool(entry.generated))
 
+    def _admission_headroom(self) -> int:
+        """The pages admission keeps free: every running decode may need a
+        page within one page_size worth of steps — admitting into that
+        margin would trade one prompt's admission for immediate preemption
+        churn (decodes running in a sibling scheduler count via the hook).
+        Under speculation each decode can consume 1 + spec_lookahead
+        positions per iteration, and under a K-step horizon K positions
+        per BOUNDARY, so the margin scales to the pages that worth of
+        tokens can claim."""
+        per_decode = pages_for_tokens(
+            self.decode_horizon + self.spec_lookahead, self.pool.page_size)
+        return (len(self.active_indices()) + (
+            self._headroom_fn() if self._headroom_fn else 0)) * per_decode
+
+    # ---- a refusal that still stands ---------------------------------------
+    def _refuse_head(self, entry: _QueueEntry, sp, blocked_by: str,
+                     headroom: int = 0) -> None:
+        """The head stays queued: its ``serve.admit`` span says by what, and
+        the memo keeps what ``head_refusal_stands`` will ask about."""
+        sp.set_metadata(blocked_by=blocked_by)
+        self._refusal = _Refusal(entry.request.request_id, blocked_by,
+                                 headroom, self._came_back)
+
+    def head_refusal_stands(self) -> bool:
+        """Whether a real attempt to admit the queue's head would end as its
+        last one did, from what the scheduler can see in O(1): the queue is
+        not empty, its head is the request that was refused (a
+        higher-priority arrival or a preempted entry put in front is
+        another head), nothing came back since (``_came_back``: pages taken,
+        by growth or a write ahead, and window-class pages released do not
+        count, they cannot help the head), and the headroom is not under
+        the refusal's (a sibling scheduler's decodes, ``_headroom_fn``, can
+        fall without this pool seeing it; a ``slots`` refusal is held to
+        none: its memo keeps 0). What it cannot see cheaply answers False:
+        with a host tier attached ``restore_queued`` seats a queued request
+        by scatter ahead of admission."""
+        memo = self._refusal
+        return (memo is not None and self._tier is None and bool(self.queue)
+                and self.queue[0].request.request_id == memo.request_id
+                and self._came_back == memo.came_back
+                and self._admission_headroom() >= memo.headroom)
+
+    def hold_head(self) -> None:
+        """A step goes ahead past the head, whose refusal stands: no attempt
+        is made, and the step says all the same that the head waited in it,
+        a ``serve.admit`` span with ``admitted`` 0, the refusal's
+        ``blocked_by`` and ``held`` 1 (nothing was reckoned: no ``need``,
+        ``free`` or ``headroom``). ``admission_held`` counts these steps;
+        ``admission_blocked`` stays the count of real attempts."""
+        memo = self._refusal
+        with span("serve.admit", request_id=memo.request_id, queue_ms=round(
+                1e3 * (self._clock() - self._submit_times[memo.request_id]),
+                3), admitted=0, blocked_by=memo.blocked_by, held=1):
+            self.stats["admission_held"] += 1
+
     # ---- prefill progress --------------------------------------------------
     def commit_tokens(self, slot_idx: int, n: int) -> None:
         """The engine committed ``n`` more context tokens into the slot's
@@ -1045,6 +1121,21 @@ class Scheduler:
                                          * self.pool.page_size]),
                                     slot.pages[:n_full],
                                     ns=int(slot.request.adapter_id))
+                self._note_registered()
+
+    def _note_registered(self) -> None:
+        """A prefix went into the cache. The refused head's match may have
+        grown, and a longer match needs fewer pages: that comes back where
+        the head now finds a page of its own prompt there. A ``pages``
+        refusal left the cache EMPTY (``_ensure_free`` evicts until the
+        pages are there or nothing is left to evict), so any page the head
+        finds is one it did not have; a ``slots`` refusal matched nothing
+        and gains nothing."""
+        if self.head_refusal_stands() and self._refusal.blocked_by == "pages":
+            head = self.queue[0].request
+            if self.cache.chain_depth(list(head.prompt_ids),
+                                      ns=int(head.adapter_id)):
+                self._came_back += 1
 
     # ---- growth + preemption ----------------------------------------------
     def preempt(self, slot_idx: int) -> None:
@@ -1355,6 +1446,7 @@ class Scheduler:
         assert slot is not None and not slot.prefilling, \
             f"release_slot on idle/prefilling slot {slot_idx}"
         self.slots[slot_idx] = None
+        self._came_back += 1            # a slot: a `slots` refusal ends
         self._adapter_release(slot.request)
         return slot, self._submit_times.pop(slot.request.request_id)
 

@@ -44,7 +44,11 @@ head has waited since its submit) and ``admitted`` (1: the head took a slot
 and its pages, and ``queue_ms`` is the wait it paid; 0: it stays queued, and
 ``blocked_by`` says by what, one of :data:`ADMIT_BLOCKS`, for ``pages`` with
 ``need``, ``free`` and ``headroom`` in pages: one span an attempt, so a head
-that waits ten steps leaves ten spans with ``admitted`` 0 and one with 1),
+that waits ten steps leaves ten spans with ``admitted`` 0 and one with 1;
+``held`` 1 on a span with ``admitted`` 0 marks a step that made NO attempt:
+it went ahead past a head whose last refusal still stands,
+``Scheduler.hold_head``, and repeats that refusal's ``blocked_by`` with
+nothing reckoned, so no ``need``, ``free`` or ``headroom``),
 ``serve.dispatch`` carries ``program`` and, for the plain decode program,
 ``programs`` (2 where a synchronous step entered the pipeline and enqueued
 the next step's program behind its own, else 1), and ``gc``
@@ -115,19 +119,23 @@ STEP_ORDERS = ("sync", "enter", "pipelined", "drain", "idle")
 # that fails is named. `kind`: the program in flight is not the one the
 # engine would enqueue now (plain / horizon: the knob moved); `inactive`: no
 # slot decodes; `drafter`: what it proposes comes from the host's tokens;
-# `queued`: a request waits for admission; `prefill`: an admitted prompt has
-# chunks to run; `replaying`: a slot consumes recorded tokens, from the host;
-# `deadline`: an expiry is due; `budget`: a reply ends with a pending token
-# (the plain program masks no lane), or every lane ends inside the pending
-# block (a horizon); `arrays`: the resident set went (a lane left, or the
-# reservation's growth dropped it); `pages`: the reservation for the writes
-# ahead covered too little. A quiet step has `held_by` ""
+# `queued`: a request waits for admission AND might get in (a head whose last
+# refusal still stands, `Scheduler.head_refusal_stands`, is no cause: the step
+# goes ahead past it and says so with a `serve.admit` of `held` 1); `prefill`:
+# an admitted prompt has chunks to run; `replaying`: a slot consumes recorded
+# tokens, from the host; `deadline`: an expiry is due; `budget`: a reply ends
+# with a pending token (the plain program masks no lane; a horizon too while a
+# refused head waits for what that reply returns), or every lane ends inside
+# the pending block (a horizon); `arrays`: the resident set went (a lane left,
+# or the reservation's growth dropped it); `pages`: the reservation for the
+# writes ahead covered too little. A quiet step has `held_by` ""
 NOT_QUIET = ("kind", "inactive", "drafter", "queued", "prefill", "replaying",
              "deadline", "budget", "arrays", "pages")
 
 # `blocked_by` of a serve.admit span with `admitted` 0: the pool could not
 # grant the head's pages and keep its headroom (`need`, `free`, `headroom`
-# ride with it), or no slot is free
+# ride with it), or no slot is free. A span with `held` 1 repeats the cause of
+# the refusal that still stands (`queued` above), without those three
 ADMIT_BLOCKS = ("pages", "slots")
 
 # jax.named_scope names: model parts (`layers` is the layer scan's own work,

@@ -35,7 +35,7 @@ if str(ROOT) not in sys.path:
 
 from benchmarks.readers import program_span, step_waterfall  # noqa: E402
 from distributed_training_guide_tpu.utils.trace import (  # noqa: E402
-    NOT_QUIET, STEP_ORDERS)
+    ADMIT_BLOCKS, NOT_QUIET, STEP_ORDERS)
 
 pytestmark = pytest.mark.serve
 
@@ -107,16 +107,18 @@ def audit(eng):
 
 def run(eng, reqs, clients=None, on_step=None):
     """Drive ``eng`` as the benchmark's closed loop does: ``clients``
-    requests in flight, the next one submitted when one finishes. Returns
-    the results in request order and a row a step: ``(tokens booked by
-    request, requests admitted, pipelined)``."""
+    requests in flight (a function of the steps taken so far where callers
+    arrive as the run goes), the next one submitted when one finishes.
+    Returns the results in request order and a row a step: ``(tokens booked
+    by request, requests admitted, pipelined)``."""
     todo = [dataclasses.replace(r) for r in reqs]
     clients = len(todo) if clients is None else clients
     order, done, rows = [], {}, []
     have: dict[int, int] = {}
     live = 0
     for _ in range(3000):
-        while todo and live < clients:
+        while todo and live < (clients(len(rows)) if callable(clients)
+                               else clients):
             order.append(eng.submit(todo.pop(0)))
             live += 1
         if not eng.has_work:
@@ -556,16 +558,32 @@ def first_held(children):
 def counts_close(eng, spans, before):
     """The sums ISSUE 53 holds a traced run to, on the CPU engine: every
     step under one order of the vocabulary, the counters what the spans
-    say, and a cause for each ``sync`` and ``drain`` step and no other."""
+    say, and a cause for each ``sync`` and ``drain`` step and no other. And
+    ISSUE 54's: ``admission_held`` is the ``serve.admit`` spans with ``held``
+    1, one a pipelined step that went ahead past a refused head, each with
+    that refusal's cause and nothing reckoned."""
     steps = program_span.steps_with_children(spans, 0, 2 ** 63)
     stats = eng.stats()
     orders = dict.fromkeys(STEP_ORDERS, 0)
     causes = dict.fromkeys(NOT_QUIET, 0)
+    held_steps = 0
     for step, children in steps:
         order = step[4]["order"]
         assert order == order_by_structure(step, children), step[4]
         assert "pipelined" not in step[4]
         orders[order] += 1
+        admits = named_children(children, "serve.admit")
+        was_held = [a[4] for a in admits if a[4].get("held")]
+        if was_held:
+            # the only attempt-shaped span of its step, which pipelined
+            assert order == "pipelined" and len(admits) == 1, step[4]
+            assert set(was_held[0]) == {"request_id", "queue_ms", "admitted",
+                                        "blocked_by", "held"}, was_held
+            assert was_held[0]["admitted"] == 0 and was_held[0]["held"] == 1
+            assert was_held[0]["blocked_by"] in ADMIT_BLOCKS
+            held_steps += 1
+        else:
+            assert order != "pipelined" or not admits, step[4]
         held = first_held(children)
         quiet = named_children(children, "serve.quiet")
         assert all(q[4]["held_by"] in ("", *NOT_QUIET) for q in quiet)
@@ -585,6 +603,7 @@ def counts_close(eng, spans, before):
     assert sum(causes.values()) == orders["sync"] + orders["drain"]
     assert stats["decode_steps_pipelined"] \
         == stats["steps_by_order"]["pipelined"]
+    assert held_steps == stats["admission_held"] - before["admission_held"]
     return steps, orders, causes
 
 
@@ -681,9 +700,13 @@ def test_a_horizon_takes_its_orders_from_the_same_vocabulary(programs,
 # ---- (h) the first thing that kept a step from pipelining ----------------------
 
 def held_queued(progs, monkeypatch):
-    """Three clients on two slots: one request waits for a slot."""
+    """A second caller arrives under a program in flight: no attempt has
+    refused it, so it might get in, and the step drains for it. (A head that
+    a full attempt refused is no cause while that refusal stands: the tests
+    of the standing refusal, below.)"""
     eng = engine_of(progs, n_slots=2)
-    return eng, [request(5 + i, 12, i) for i in range(3)], {}
+    return eng, [request(5 + i, 12, i) for i in range(2)], {
+        "clients": lambda steps: 1 if steps < 5 else 2}
 
 
 def held_prefill(progs, monkeypatch):
@@ -769,7 +792,8 @@ def test_a_step_that_does_not_pipeline_names_the_first_thing_in_its_way(
     eng.stats_seq = base            # the scenes count steps from here
     before = eng.stats()
     spans = recorded(monkeypatch)
-    got, _ = run(eng, reqs, on_step=how.get("on_step"))
+    got, _ = run(eng, reqs, clients=how.get("clients"),
+                 on_step=how.get("on_step"))
     steps, orders, causes = counts_close(eng, spans, before)
     for want in (cause, *how.get("also", ())):
         assert causes[want] >= 1, (want, causes)
@@ -781,3 +805,387 @@ def test_a_step_that_does_not_pipeline_names_the_first_thing_in_its_way(
     for step, children in steps:
         if first_held(children) == cause:
             assert step[4]["order"] in ("sync", "drain")
+
+
+# ---- (i) a queue head whose refusal still stands -------------------------------
+
+def scene_pages(progs, **kw):
+    """Three slots over a pool of 16 pages that two replies nearly fill: a
+    prompt is five pages, and the headroom rule (its pages and one a running
+    decode, free) refuses the third caller until a reply ENDS."""
+    eng = engine_of(progs, n_slots=3, n_pages=16, max_len=64, **kw)
+    return eng, [request(40, 12 + 4 * (i % 3), i) for i in range(6)]
+
+
+def scene_slots(progs, **kw):
+    """Three callers on two slots, pages to spare: the third waits for a
+    slot."""
+    eng = engine_of(progs, n_slots=2, **kw)
+    return eng, [request(5 + 2 * i, 14 + 3 * (i % 3), i) for i in range(6)]
+
+
+def arriving(steps):
+    """Two callers from the start, a third once they decode."""
+    return 2 if steps < 7 else 3
+
+
+@pytest.mark.parametrize("horizon", [1, 4], ids=["plain", "horizon4"])
+@pytest.mark.parametrize("scene", [scene_pages, scene_slots],
+                         ids=["pages", "slots"])
+def test_steps_go_ahead_past_a_refused_head_and_the_schedule_is_the_parents(
+        programs, monkeypatch, scene, horizon):
+    """The head's refusal stands for a reply's length: those steps pipeline
+    (each says that the head waited: ``held`` 1), every token and the step of
+    every admission are those of the same run with the predicate forced
+    False, the parent's ``queued``, and the counts close."""
+    progs = programs("llama")
+    eng, reqs = scene(progs, decode_horizon=horizon)
+    before = eng.stats()
+    spans = recorded(monkeypatch)
+    got, rows = run(eng, reqs, clients=arriving)
+    _, orders, causes = counts_close(eng, spans, before)
+    stats = eng.stats()
+
+    parent, _ = scene(progs, decode_horizon=horizon)
+    parent.scheduler.head_refusal_stands = lambda: False
+    want, parent_rows = run(parent, reqs, clients=arriving)
+    same_tokens(got, want, reqs)
+    if horizon == 1:
+        same_tokens(got, batch1(progs, reqs), reqs)
+        one_token_a_step(rows)
+    assert [(b, a) for b, a, _ in rows] == [(b, a) for b, a, _ in parent_rows]
+    assert stats["preemptions"] == 0 == parent.stats()["preemptions"]
+
+    # the parent drained at every step with a request queued; here only an
+    # arrival under a program in flight does, and a reply's end (`budget`)
+    was = parent.stats()
+    assert was["admission_held"] == 0
+    assert was["not_quiet"]["queued"] >= (20 if horizon == 1 else 6)
+    assert causes["queued"] <= 4, causes
+    assert stats["admission_held"] - before["admission_held"] \
+        >= (15 if horizon == 1 else 1)
+    assert orders["pipelined"] > was["steps_by_order"]["pipelined"]
+    # a real attempt refreshes the memo, a held step makes none: the pool's
+    # refusals are counted where they happen, and far fewer than the parent's
+    assert stats["admission_blocked"] <= was["admission_blocked"]
+    if scene is scene_slots:
+        assert stats["admission_blocked"] == 0
+    sched = eng.scheduler
+    assert sched.pool.n_free + sched.cache_pages_held() == sched.pool.capacity
+
+
+# what ends a standing refusal: (name, the cause the draining step names)
+def ends_budget(eng, ctx):
+    """Nothing done: a running reply's last token comes due."""
+
+
+def ends_eos(eng, ctx):
+    """The eos set on a running reply is read behind an enqueued program."""
+
+
+def ends_priority(eng, ctx):
+    eng.submit(request(5, 4, 6, priority=1))
+
+
+def ends_front(eng, ctx):
+    """An entry put in front of its class, as a preempted sequence is."""
+    eng.resubmit(request(5, 4, 6))
+
+
+def ends_deadline(eng, ctx):
+    ctx["now"][0] += 100.0
+
+
+def ends_headroom(eng, ctx):
+    ctx["extra"][0] = 0
+
+
+def ends_tier(eng, ctx):
+    from distributed_training_guide_tpu.serve.tiering import HostTier
+
+    eng.scheduler.attach_tier(HostTier(1 << 20), eng.gather_pages)
+
+
+ENDS = [("pages", ends_budget, "budget"), ("slots", ends_budget, "budget"),
+        ("pages", ends_eos, "queued"), ("slots", ends_eos, "queued"),
+        ("pages", ends_priority, "queued"), ("slots", ends_priority, "queued"),
+        ("pages", ends_front, "queued"), ("slots", ends_front, "queued"),
+        ("pages", ends_deadline, "deadline"),
+        ("slots", ends_deadline, "deadline"),
+        ("pages", ends_headroom, "queued"),
+        ("pages", ends_tier, "queued"), ("slots", ends_tier, "queued")]
+
+
+@pytest.mark.parametrize(
+    "blocked_by,event,cause", ENDS,
+    ids=[f"{e.__name__[len('ends_'):]}-{b}" for b, e, _ in ENDS])
+def test_what_ends_a_standing_refusal_drains_and_then_a_real_attempt_runs(
+        programs, monkeypatch, blocked_by, event, cause):
+    """Two replies decode, two callers wait, the head refused by
+    ``blocked_by`` and held for steps. Then the event: the first step that
+    does not pipeline is a DRAIN that names ``cause``, makes no attempt and
+    holds nothing, and the step after it runs a REAL attempt (a
+    ``serve.admit`` without ``held``, which refreshes the memo)."""
+    progs = programs("llama")
+    now, extra = [0.0], [0]
+    ctx = {"now": now, "extra": extra}
+    running = [request(32 if blocked_by == "pages" else 6, 30, i)
+               for i in range(2)]
+    if event is ends_eos:
+        tokens = batch1(progs, running[1:])[0].generated_ids
+        at = next(j for j in range(14, 28) if tokens[j] not in tokens[:j])
+        running[1] = dataclasses.replace(running[1], eos_id=tokens[at])
+    if event is ends_budget:
+        running[1] = dataclasses.replace(running[1], max_new_tokens=16)
+    if event is ends_headroom:
+        # pages to spare, and a sibling's decodes that the hook reports
+        eng = engine_of(progs, n_slots=3, n_pages=40, max_len=64)
+        eng.scheduler._headroom_fn = lambda: extra[0]
+    else:
+        eng, _ = (scene_pages if blocked_by == "pages" else scene_slots)(progs)
+    sched = eng.scheduler
+    sched._clock = lambda: now[0]
+    for r in running:
+        eng.submit(dataclasses.replace(r))
+    while len(sched.active_indices()) < 2:
+        assert eng.step() == [] and eng.stats_seq < 20
+    extra[0] = 100
+    waiting = [request(32 if blocked_by == "pages" else 6, 4, 2 + i,
+                       deadline_s=50.0 if event is ends_deadline and i == 0
+                       else None) for i in range(2)]
+    rids = [eng.submit(w) for w in waiting]
+    spans = recorded(monkeypatch)
+    before = eng.stats()
+    while sched.stats["admission_held"] < 3:
+        assert eng.step() == [] and eng.stats_seq < 40
+    assert eng._inflight is not None and sched.head_refusal_stands()
+    assert sched._refusal.blocked_by == blocked_by
+    assert sched._refusal.request_id == rids[0]
+    assert sched.stats["admitted"] == 2
+
+    event(eng, ctx)
+    del spans[:]
+    drains = eng.steps_by_order["drain"]
+    finished = []
+    while eng.steps_by_order["drain"] == drains:
+        held = sched.stats["admission_held"]
+        finished += eng.step()
+        assert eng.stats_seq < 60
+        if eng.steps_by_order["drain"] == drains:
+            # it went ahead past the head once more, and said so
+            assert sched.stats["admission_held"] == held + 1
+    assert eng._inflight is None
+    attempts = sched.stats["admitted"], sched.stats["admission_blocked"]
+    finished += eng.step()                      # the boundary's step
+    steps = program_span.steps_with_children(spans, 0, 2 ** 63)
+    (drain, drained), (boundary, bounded) = steps[-2:]
+    assert drain[4]["order"] == "drain" and first_held(drained) == cause
+    assert not named_children(drained, "serve.admit")
+    assert all(s[4]["order"] == "pipelined" for s, _ in steps[:-2])
+    assert boundary[4]["order"] in ("sync", "enter")
+    real = [a[4] for a in named_children(bounded, "serve.admit")]
+    assert real and not any("held" in a for a in real), real
+    # only an event the step ahead cannot see costs the head steps
+    assert len(steps) - 2 <= (0 if event not in (ends_budget, ends_eos)
+                              else 20), len(steps)
+    if event is ends_eos:
+        assert [r.finish_reason for r in finished] == ["eos"]
+        assert steps[-3][0][4]["order"] == "pipelined"  # read behind D(n+1)
+    if event is ends_budget:
+        assert [r.finish_reason for r in finished] == ["length"]
+    if event in (ends_budget, ends_eos, ends_headroom):
+        assert real[0] == {"request_id": rids[0], "admitted": 1,
+                           "queue_ms": real[0]["queue_ms"]}
+    if event is ends_deadline:
+        assert [r.finish_reason for r in finished] == ["deadline"]
+        assert real[0]["request_id"] == rids[1]
+    if event in (ends_priority, ends_front):
+        assert real[0]["request_id"] not in rids
+    if event is ends_tier:
+        # today's behaviour from here on: an attempt a step, nothing held
+        assert real[0]["request_id"] == rids[0] and not real[0]["admitted"]
+        held = sched.stats["admission_held"]
+        for _ in range(3):
+            eng.step()
+        assert sched.stats["admission_held"] == held
+        assert eng.not_quiet["queued"] >= before["not_quiet"]["queued"] + 3
+    assert (sched.stats["admitted"], sched.stats["admission_blocked"]) \
+        != attempts or blocked_by == "slots"
+    while eng.has_work:
+        eng.step()
+    assert sched.pool.n_free + sched.cache_pages_held() == sched.pool.capacity
+
+
+@pytest.mark.parametrize("shares", [True, False], ids=["shared", "unrelated"])
+def test_a_registered_prefix_ends_the_refusal_where_the_head_shares_it(
+        programs, shares):
+    """Two callers wait behind two replies: the first fits, the second is
+    refused for pages in the same attempt, and then the first one's prompt
+    registers in the prefix cache. Where the refused head shares its first
+    page, its match may grow: the step does not enter the pipeline and the
+    next runs a real attempt. An unrelated prompt changes nothing for the
+    head, and the chunk step enters with the head held behind it."""
+    progs = programs("llama")
+    eng = engine_of(progs, n_slots=4, n_pages=16, max_len=64)
+    sched = eng.scheduler
+    for i in range(2):
+        eng.submit(request(32, 30, i))
+    while len(sched.active_indices()) < 2:
+        assert eng.step() == []
+    big = request(32, 4, 3)
+    small = Request(prompt_ids=(big.prompt_ids[:PAGE] if shares
+                                else prompt_of(PAGE, 77)) + [1, 2],
+                    max_new_tokens=4)
+    eng.submit(small)
+    rid = eng.submit(big)
+    while sched.stats["admitted"] < 3:
+        eng.step()
+    # the step that admitted `small` refused `big` and completed the prefill
+    assert sched._refusal.request_id == rid
+    assert sched._refusal.blocked_by == "pages"
+    assert len(sched.active_indices()) == 3
+    assert sched.head_refusal_stands() is (not shares)
+    assert (eng._inflight is not None) is (not shares)
+    blocked = sched.stats["admission_blocked"]
+    eng.step()
+    if shares:      # a real attempt, which finds the shared page
+        assert sched.stats["admission_blocked"] == blocked + 1
+        assert sched.stats["admission_held"] == 0
+    else:
+        assert sched.stats["admission_blocked"] == blocked
+        assert sched.stats["admission_held"] == 1
+    while eng.has_work:
+        eng.step()
+    assert sched.pool.n_free + sched.cache_pages_held() == sched.pool.capacity
+
+
+# ---- the predicate alone, on a scheduler without an engine ---------------------
+
+def refused_scheduler(blocked_by, **kw):
+    """Two sequences decode and a third is the queue's head, refused by
+    ``blocked_by``: pages of 4, a prompt of 8, a pool of 9 pages of which
+    the two hold 6 and the head wants 2 and 2 of headroom, one more than are
+    there (``pages``), or two slots both taken (``slots``)."""
+    from distributed_training_guide_tpu.serve import PagePool, Scheduler
+
+    pool = PagePool(n_pages=10 if blocked_by == "pages" else 17, page_size=4)
+    sched = Scheduler(n_slots=3 if blocked_by == "pages" else 2, pool=pool,
+                      max_len=32, max_pages_per_slot=8, **kw)
+    for i in range(2):
+        sched.submit(Request(prompt_ids=prompt_of(8, 3 + 7 * i),
+                             max_new_tokens=9, deadline_s=50.0 + 100 * i))
+        (adm,) = sched.try_admit()
+        sched.commit_tokens(adm.slot_idx, 8)
+        assert sched.record_token(adm.slot_idx, 5, from_decode=False) is None
+    assert sched.grow_for_decode() == (2, 0)        # the page of write 9
+    rid = sched.submit(Request(prompt_ids=prompt_of(8, 40), max_new_tokens=4))
+    assert sched.try_admit() == []
+    assert sched._refusal.blocked_by == blocked_by
+    assert sched._refusal.request_id == rid and sched.head_refusal_stands()
+    return sched
+
+
+def came_back_end(sched):
+    assert sched.record_token(0, 9, from_decode=True) is None
+    for _ in range(7):
+        done = sched.record_token(0, 9, from_decode=True)
+    assert done.finish_reason == "length"
+
+
+def came_back_preempt(sched):
+    sched.preempt(1)            # and its entry is the head now
+
+
+def came_back_expired(sched):
+    (gone,) = sched.expire_deadlines(now=sched._clock() + 60.0)
+    assert gone.finish_reason == "deadline"
+
+
+def came_back_released(sched):
+    slot, _ = sched.release_slot(0)
+    sched.pool.free(slot.pages)
+
+
+def another_head_priority(sched):
+    sched.submit(Request(prompt_ids=[1, 2, 3], max_new_tokens=2, priority=1))
+
+
+def another_head_front(sched):
+    sched.requeue(Request(prompt_ids=[1, 2, 3], max_new_tokens=2), [7])
+
+
+def another_head_drained(sched):
+    assert len(sched.drain_queue()) == 1
+
+
+def tier_attached(sched):
+    sched.attach_tier(object(), lambda pages: {})
+
+
+def stays_growth(sched):
+    for i in (0, 1):
+        for _ in range(4):
+            sched.record_token(i, 9, from_decode=True)
+    assert sched.grow_for_decode() == (2, 0)
+
+
+def stays_write_ahead(sched):
+    assert sched.reserve_horizon(6) == (6, 2)
+
+
+def stays_arrival_behind(sched):
+    sched.submit(Request(prompt_ids=[1, 2, 3], max_new_tokens=2))
+
+
+def stays_headroom_rose(sched):
+    sched._headroom_fn = lambda: 3
+
+
+ENDS_MEMO = [came_back_end, came_back_preempt, came_back_expired,
+             came_back_released, another_head_priority,
+             another_head_front, another_head_drained, tier_attached]
+KEEPS_MEMO = [stays_growth, stays_write_ahead, stays_arrival_behind,
+              stays_headroom_rose]
+
+
+@pytest.mark.parametrize("blocked_by", ["pages", "slots"])
+@pytest.mark.parametrize("event", ENDS_MEMO + KEEPS_MEMO,
+                         ids=lambda e: e.__name__)
+def test_the_refusal_stands_until_something_comes_back_or_the_head_changes(
+        blocked_by, event):
+    """``head_refusal_stands`` by itself: what returns a slot or a page of
+    the main class, another head and a host tier end it; pages TAKEN, an
+    arrival behind the head and more headroom do not, and there a real
+    attempt is refused again, which is what the predicate promised."""
+    sched = refused_scheduler(blocked_by)
+    rid = sched._refusal.request_id
+    event(sched)
+    assert sched.head_refusal_stands() is (event in KEEPS_MEMO)
+    if event in KEEPS_MEMO:
+        blocked = sched.stats["admission_blocked"]
+        assert sched.try_admit() == []
+        assert sched.queue[0].request.request_id == rid
+        assert sched.stats["admission_blocked"] - blocked \
+            == (blocked_by == "pages")
+        assert sched.head_refusal_stands()
+
+
+def test_a_sibling_schedulers_decodes_falling_end_a_pages_refusal():
+    """The disaggregated prefill side counts the decode side's running
+    replies through ``admission_headroom``: they can fall without this pool
+    seeing anything, and the refusal was held to them."""
+    from distributed_training_guide_tpu.serve import PagePool, Scheduler
+
+    there = [6]
+    sched = Scheduler(n_slots=2, pool=PagePool(n_pages=9, page_size=4),
+                      max_len=32, max_pages_per_slot=8,
+                      admission_headroom=lambda: there[0])
+    sched.submit(Request(prompt_ids=prompt_of(12), max_new_tokens=4))
+    assert sched.try_admit() == []              # 3 pages + 6 of 8
+    assert sched._refusal.headroom == 6 and sched.head_refusal_stands()
+    there[0] = 7
+    assert sched.head_refusal_stands()          # more of them: stands
+    there[0] = 5
+    assert not sched.head_refusal_stands()
+    (adm,) = sched.try_admit()
+    assert adm.slot_idx == 0 and not sched.head_refusal_stands()

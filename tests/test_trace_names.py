@@ -146,10 +146,80 @@ def test_serve_span_tree(debug_model, tmp_path, engine_kw):
     assert {e[4]["blocked_by"] for e in admits if not e[4]["admitted"]} \
         <= set(ADMIT_BLOCKS)
     assert all("blocked_by" not in e[4] for e in admits if e[4]["admitted"])
+    assert set().union(*(e[4] for e in admits)) <= ADMIT_STATS
     stats = engine.stats()
     assert set(stats["steps_by_order"]) == set(STEP_ORDERS)
     assert set(stats["not_quiet"]) == set(NOT_QUIET)
     assert sum(stats["steps_by_order"].values()) == stats["stats_seq"]
+
+
+# every statistic a `serve.admit` span may carry (utils/trace.py's docstring)
+ADMIT_STATS = {"request_id", "queue_ms", "admitted", "blocked_by", "need",
+               "free", "headroom", "held"}
+
+
+def test_a_step_that_goes_ahead_past_a_refused_head_says_held(debug_model,
+                                                              tmp_path):
+    """Three requests on two slots, under a session: the third is refused
+    for a slot once, and the steps that pipeline past it while that refusal
+    stands each leave a ``serve.admit`` with ``held`` 1: ``admitted`` 0, the
+    refusal's ``blocked_by``, nothing reckoned; ``stats()`` counts them."""
+    bundle, params = debug_model
+    engine = ServeEngine(bundle, params, n_slots=2, page_size=8, max_len=64)
+    run_requests(engine, n_new=4)          # compile outside the session
+
+    def three():
+        return [engine.submit(Request(prompt_ids=[3 + i, 17, 42, 5],
+                                      max_new_tokens=12))
+                for i in range(3)]
+    before = engine.stats()["admission_held"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        rids = three()
+        while engine.has_work:
+            engine.step()
+    finally:
+        jax.profiler.stop_trace()
+    events = program_events(tmp_path)
+    admits = [e[4] for e in events if e[0] == "serve.admit"]
+    assert set().union(*admits) <= ADMIT_STATS
+    held = [a for a in admits if a.get("held")]
+    assert len(held) >= 5
+    assert len(held) == engine.stats()["admission_held"] - before
+    for a in held:
+        assert set(a) == {"request_id", "queue_ms", "admitted", "blocked_by",
+                          "held"}
+        assert a["admitted"] == 0 and a["held"] == 1
+        assert a["blocked_by"] == "slots" and a["blocked_by"] in ADMIT_BLOCKS
+        assert a["request_id"] == rids[2]
+    # the held steps pipelined: each lies in a step whose order says so
+    steps = {e[4]["seq"]: e for e in events if e[0] == "serve.step"}
+    spans = [e for e in events if e[0] == "serve.admit" and e[4].get("held")]
+    for _, a, b, _, _ in spans:
+        (step,) = [s for s in steps.values() if s[1] <= a and b <= s[2]]
+        assert step[4]["order"] == "pipelined"
+    # a real refusal by the same cause set the memo they repeat (one a
+    # synchronous step: the two steps of the prompts' chunks), and they are
+    # many more than it
+    real = [a for a in admits if not a["admitted"] and not a.get("held")]
+    assert {a["blocked_by"] for a in real} == {"slots"}
+    assert 1 <= len(real) <= 3 < len(held)
+
+
+def test_the_vocabularys_text_says_what_queued_means_now():
+    """``utils/trace.py`` beside ``NOT_QUIET`` and ``ADMIT_BLOCKS``, and its
+    docstring on ``serve.admit``: a head whose refusal stands is no cause,
+    and the step that goes ahead past it says ``held``."""
+    import inspect
+
+    source = inspect.getsource(trace_mod)
+    beside_causes = source.split("NOT_QUIET = (")[0].rsplit("\n\n", 1)[1]
+    assert "head_refusal_stands" in " ".join(beside_causes.split())
+    assert "`held` 1" in " ".join(beside_causes.split())
+    beside_blocks = source.split("ADMIT_BLOCKS = (")[0].rsplit("\n\n", 1)[1]
+    assert "`held` 1" in " ".join(beside_blocks.split())
+    said = " ".join(trace_mod.__doc__.split())
+    assert "``held`` 1" in said and "Scheduler.hold_head" in said
 
 
 def test_the_disaggregated_pairs_step_is_the_one_without_an_order(
@@ -410,9 +480,11 @@ def test_the_orders_and_the_causes_in_the_source_are_the_closed_sets():
     assert tuple(re.findall(returned, quiet)[:1] + re.findall(returned, steady)
                  + re.findall(returned, quiet)[1:-1]) == NOT_QUIET
     assert re.findall(returned, quiet)[-1] == "budget"
-    blocks = set(re.findall(r'blocked_by="(\w+)"',
+    # a refusal is written in ONE place, which also keeps the memo
+    blocks = set(re.findall(r'self\._refuse_head\(entry, sp, "(\w+)"',
                             inspect.getsource(scheduler)))
     assert blocks == set(ADMIT_BLOCKS)
+    assert not re.findall(r'blocked_by="', inspect.getsource(scheduler))
     for closed in (STEP_ORDERS, NOT_QUIET, ADMIT_BLOCKS):
         assert len(closed) == len(set(closed))
 
